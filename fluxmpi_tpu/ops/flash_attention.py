@@ -28,6 +28,8 @@ interpret mode, which is how the CPU test suite exercises them.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 
 import numpy as np
@@ -35,14 +37,16 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
 
-from ..parallel._compat import pallas_tpu_compiler_params
+from ..parallel._compat import pallas_tpu_compiler_params, shard_map_unchecked
 
 __all__ = [
     "flash_attention",
     "flash_attention_with_lse",
     "flash_attention_fn",
     "padding_to_segment_ids",
+    "spmd_attention_layout",
 ]
 
 _NEG_INF = -1e30
@@ -1269,6 +1273,95 @@ def _dense_dropout_attention(
     )
 
 
+# ---------------------------------------------------------------------------
+# Kernels inside a program XLA partitions. The SPMD partitioner cannot
+# split a Mosaic kernel: lowering one inside a jit over a multi-device
+# mesh fails on the chip with "Mosaic kernels cannot be automatically
+# partitioned. Please wrap the call in a shard_map" (interpret mode never
+# reaches that check, so the CPU suite cannot see it). Attention is
+# independent per (batch row, head), so the wrap is mechanical — but only
+# the code that builds the partitioned program knows its mesh and batch
+# layout. It declares them here while it traces; flash_attention_fn then
+# runs its kernels per device.
+# ---------------------------------------------------------------------------
+
+_SPMD_LAYOUT: contextvars.ContextVar = contextvars.ContextVar(
+    "fluxmpi_tpu_flash_spmd_layout", default=None
+)
+
+
+@contextlib.contextmanager
+def spmd_attention_layout(mesh, batch_axes, head_axis=None):
+    """Declare, while tracing a program that XLA partitions over
+    ``mesh`` (a jit with mesh shardings — what
+    ``make_train_step(style="auto")`` builds), how attention operands
+    are laid out: the batch dimension over ``batch_axes`` (a mesh axis
+    name or a tuple of them) and, optionally, heads over ``head_axis``.
+
+    Inside the context :func:`flash_attention_fn` runs its kernels
+    per device under ``shard_map`` over the whole mesh — every axis
+    manual, which is what a Mosaic kernel requires. A dimension the
+    named axes do not divide stays replicated (each device then computes
+    all of it — correct, never silently wrong). A one-device mesh needs
+    no wrap and the context is a no-op. Code already inside a
+    ``shard_map`` (ring/Ulysses attention, ``style="shard_map"`` steps)
+    calls the kernels directly and does not use this."""
+    layout = (mesh, batch_axes, head_axis) if mesh.size > 1 else None
+    token = _SPMD_LAYOUT.set(layout)
+    try:
+        yield
+    finally:
+        _SPMD_LAYOUT.reset(token)
+
+
+def _axis_names(axes) -> tuple:
+    """A PartitionSpec entry (None, a name, or a tuple of names) as a
+    tuple of mesh axis names."""
+    if axes is None:
+        return ()
+    return axes if isinstance(axes, tuple) else (axes,)
+
+
+def _per_device(attend, q, k, v, segment_ids, seed):
+    """``attend(q, k, v, segment_ids, seed)`` — directly, or per device
+    under the layout :func:`spmd_attention_layout` declared."""
+    layout = _SPMD_LAYOUT.get()
+    if layout is None:
+        return attend(q, k, v, segment_ids, seed)
+    mesh, batch_axes, head_axis = layout
+    if q.shape[0] % int(
+        np.prod([mesh.shape[a] for a in _axis_names(batch_axes)])
+    ):
+        batch_axes = None
+    if head_axis is not None and (
+        head_axis not in mesh.shape
+        or q.shape[2] % mesh.shape[head_axis]
+        or k.shape[2] % mesh.shape[head_axis]
+    ):
+        head_axis = None
+    qkv = P(batch_axes, None, head_axis, None)
+    # Optional operands ride as one pytree each; an absent one is an
+    # empty pytree and takes no spec leaf.
+    seg_spec = None if segment_ids is None else (P(batch_axes, None),) * 2
+    seed_spec = None if seed is None else P()
+    split = _axis_names(batch_axes) + _axis_names(head_axis)
+
+    def body(q, k, v, segment_ids, seed):
+        if seed is not None and split:
+            # bh in the kernels' dropout hash is the LOCAL (batch, head)
+            # index: give every shard its own stream.
+            seed = seed + jax.lax.axis_index(split).astype(
+                jnp.uint32
+            ) * jnp.uint32(0x9E3779B1)
+        return attend(q, k, v, segment_ids, seed)
+
+    return shard_map_unchecked(
+        body, mesh,
+        in_specs=(qkv, qkv, qkv, seg_spec, seed_spec),
+        out_specs=qkv,
+    )(q, k, v, segment_ids, seed)
+
+
 def flash_attention_fn(
     causal: bool = False,
     *,
@@ -1367,18 +1460,22 @@ def flash_attention_fn(
                     )
             elif mask_check:
                 fidelity = _mask_fidelity(mask, *segment_ids, causal)
-        out = flash_attention(
-            query,
-            key,
-            value,
-            causal=causal,
-            window=window,
-            segment_ids=segment_ids,
-            block_q=block_q,
-            block_k=block_k,
-            interpret=interpret,
-            dropout_rate=dropout_rate,
-            dropout_seed=dropout_seed,
+
+        def attend(q, k, v, segment_ids, seed):
+            return flash_attention(
+                q, k, v,
+                causal=causal,
+                window=window,
+                segment_ids=segment_ids,
+                block_q=block_q,
+                block_k=block_k,
+                interpret=interpret,
+                dropout_rate=dropout_rate,
+                dropout_seed=seed,
+            )
+
+        out = _per_device(
+            attend, query, key, value, segment_ids, dropout_seed
         ).astype(query.dtype)
         if fidelity is not None:
             # Unrepresentable traced mask → NaN-poison that batch row:

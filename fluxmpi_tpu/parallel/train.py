@@ -111,12 +111,8 @@ _DEFAULT_REGISTRY = object()  # sentinel: re-read get_registry() every step
 def _tag_scan_steps(step: Any, scan_steps: int) -> None:
     """Record the step's scan width as an attribute so the pipelined
     driver (:func:`fluxmpi_tpu.parallel.train_loop`) can pick it up
-    without the caller restating it. Best-effort: a jit wrapper that
-    refuses attributes just loses the convenience."""
-    try:
-        step.scan_steps = scan_steps
-    except (AttributeError, TypeError):  # pragma: no cover - jax-version
-        pass
+    without the caller restating it."""
+    step.scan_steps = scan_steps
 
 
 def _bank_aux_meta(
@@ -127,17 +123,13 @@ def _bank_aux_meta(
 ) -> None:
     """Record the compiled step's auxiliary-output structure (and, with
     model stats baked in, the plane metadata) so ``train_loop`` can
-    unpack the flush values without guessing. Best-effort like
-    :func:`_tag_scan_steps`."""
-    try:
-        compiled.__fluxmpi_aux__ = aux_names
-        if stats_depth is not None:
-            compiled.__fluxmpi_model_stats_meta__ = {
-                "depth": stats_depth,
-                "workers": workers,
-            }
-    except (AttributeError, TypeError):  # pragma: no cover - jax-version
-        pass
+    unpack the flush values without guessing."""
+    compiled.__fluxmpi_aux__ = aux_names
+    if stats_depth is not None:
+        compiled.__fluxmpi_model_stats_meta__ = {
+            "depth": stats_depth,
+            "workers": workers,
+        }
 
 
 def _resolve_metrics(metrics: Any) -> tuple[Any, Any, Any]:
@@ -333,6 +325,21 @@ def _installed_plan_defaults(
     return plan, mesh, plan.dp_axis_name, plan.batch_spec
 
 
+def _kernel_layout(mesh: Mesh, batch_spec: P, plan: Any):
+    """The context a ``style="auto"`` factory traces user code in: XLA
+    partitions the program but cannot partition a Pallas kernel, so the
+    kernels are told the layout — batch over the batch spec's leading
+    entry, heads over the plan's tp axis — and run per device (see
+    :func:`fluxmpi_tpu.ops.flash_attention.spmd_attention_layout`)."""
+    from ..ops.flash_attention import spmd_attention_layout
+
+    return spmd_attention_layout(
+        mesh,
+        batch_spec[0] if len(batch_spec) else None,
+        plan.axis_name("tp") if plan is not None else None,
+    )
+
+
 def make_train_step(
     loss_fn: Callable[[Any, Any, Any], tuple[jax.Array, Any]],
     optimizer: optax.GradientTransformation,
@@ -414,9 +421,9 @@ def make_train_step(
         extra leading ``scan_steps`` axis, and the step returns the
         ``[scan_steps]`` per-update losses. One host→device dispatch then
         drives K updates — amortizing per-step dispatch latency, which on
-        remote/tunneled or very fast chips can otherwise dominate small
-        step times (no analogue in the reference: its per-step NCCL
-        launches are host-driven by construction). Composes with
+        very fast chips can otherwise dominate small step times (no
+        analogue in the reference: its per-step NCCL launches are
+        host-driven by construction). Composes with
         ``grad_accum_steps`` (accumulation nests inside each scanned
         update). ``style="auto"`` only.
       policy: optional :class:`fluxmpi_tpu.utils.Policy` — the params are
@@ -443,8 +450,8 @@ def make_train_step(
         ``style="shard_map"`` with ``grad_reduce=None``),
         ``train.examples_per_sec``, and cumulative ``train.steps`` /
         ``train.examples``. The per-step block on the loss serializes
-        async dispatch — on remote/tunneled targets prefer a larger
-        effective step (``scan_steps``) when enabling this.
+        async dispatch — prefer a larger effective step (``scan_steps``)
+        when enabling this on short steps.
       model_stats: fold the model-internals plane's per-layer stats tree
         into the compiled program (``None``, the default, follows the
         installed :class:`~fluxmpi_tpu.telemetry.ModelStats` plane —
@@ -516,6 +523,14 @@ def make_train_step(
 
         def loss_fn(p, mstate, batch):  # noqa: F811 - deliberate rewrap
             return inner_loss(policy.cast_to_compute(p), mstate, batch)
+
+    single_spec = P(name) if batch_spec is None else batch_spec
+    if style == "auto":
+        spmd_loss = loss_fn
+
+        def loss_fn(p, mstate, batch):  # noqa: F811 - deliberate rewrap
+            with _kernel_layout(mesh, single_spec, plan):
+                return spmd_loss(p, mstate, batch)
 
     if remat:
         if remat == "dots":
@@ -671,7 +686,6 @@ def make_train_step(
 
         replicated = NamedSharding(mesh, P())
         state_in = replicated if state_sharding is None else state_sharding
-        single_spec = P(name) if batch_spec is None else batch_spec
         spec = single_spec
         if scan_steps > 1:
             # Leading scan axis is time, not data: unsharded.
@@ -691,19 +705,16 @@ def make_train_step(
         # metric reduction in a single lax.scan). The SINGLE-update body
         # rides along — the window does its own scan, so a scan_steps
         # wrapper here is irrelevant to the fused path.
-        try:
-            compiled.__fluxmpi_window_meta__ = {
-                "single": single_update,
-                "state_in": state_in,
-                "batch_spec": single_spec,
-                "mesh": mesh,
-                "donate": donate,
-                "instrument": instrument,
-                "aux": aux_names,
-                "stats_depth": stats_depth,
-            }
-        except (AttributeError, TypeError):  # pragma: no cover - jax-version
-            pass
+        compiled.__fluxmpi_window_meta__ = {
+            "single": single_update,
+            "state_in": state_in,
+            "batch_spec": single_spec,
+            "mesh": mesh,
+            "donate": donate,
+            "instrument": instrument,
+            "aux": aux_names,
+            "stats_depth": stats_depth,
+        }
         _bank_aux_meta(compiled, aux_names, stats_depth, dp_workers)
         if instrument or stats_on:
             return _instrument_step(
@@ -935,27 +946,27 @@ def make_eval_step(
     compute dtype entering ``metric_fn``, same as training.
     """
     if parallel is not None:
-        _, mesh, axis_name, batch_spec, state_sharding = _plan_defaults(
+        plan, mesh, axis_name, batch_spec, state_sharding = _plan_defaults(
             parallel, mesh, axis_name, batch_spec, state_sharding,
             "make_eval_step",
         )
     else:
-        _, mesh, axis_name, batch_spec = _installed_plan_defaults(
+        plan, mesh, axis_name, batch_spec = _installed_plan_defaults(
             mesh, axis_name, batch_spec
         )
     mesh = mesh or global_mesh()
     name = axis_name or config.DP_AXIS_NAME
+    spec = P(name) if batch_spec is None else batch_spec
 
     def step(ts: TrainState, batch):
         params = ts.params if policy is None else policy.cast_to_compute(
             ts.params)
-        return metric_fn(params, ts.model_state, batch)
+        with _kernel_layout(mesh, spec, plan):
+            return metric_fn(params, ts.model_state, batch)
 
     replicated = NamedSharding(mesh, P())
     state_in = replicated if state_sharding is None else state_sharding
-    batch_sharding = NamedSharding(
-        mesh, P(name) if batch_spec is None else batch_spec
-    )
+    batch_sharding = NamedSharding(mesh, spec)
     return jax.jit(
         step,
         in_shardings=(state_in, batch_sharding),

@@ -210,29 +210,36 @@ def _configure_preemption(spec: Any = None) -> None:
 # ---------------------------------------------------------------------------
 
 _COMPILE_CACHE_ENV = "FLUXMPI_TPU_COMPILE_CACHE"
-_COMPILE_CACHE_DEFAULT_DIR = "/tmp/fluxmpi_tpu_xla_cache"
+# JAX's own variable: when it is set the cache lives there and this
+# module sets no directory at all (the path is part of the cache key, so
+# whoever placed the cache must be the only one to name it).
+_JAX_COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+_COMPILE_CACHE_DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
 
 
 def enable_compile_cache(cache_dir: str | None = None) -> bool:
-    """Point XLA's persistent compilation cache at ``cache_dir`` (default
-    ``FLUXMPI_TPU_COMPILE_CACHE``, else ``/tmp/fluxmpi_tpu_xla_cache``)
-    so repeat runs — and, on shared storage, every host of a fleet —
-    skip the slow first compile. Returns True when enabled.
+    """Turn on XLA's persistent compilation cache so repeat runs — and,
+    on shared storage, every host of a fleet — skip the slow first
+    compile. Returns True when enabled.
+
+    Where the cache lives: ``JAX_COMPILATION_CACHE_DIR``, when set, wins
+    outright — jax already reads it, and this function then sets no
+    directory (``cache_dir`` and ``FLUXMPI_TPU_COMPILE_CACHE`` are
+    ignored). Otherwise ``cache_dir``, else ``FLUXMPI_TPU_COMPILE_CACHE``,
+    else ``<checkout>/.jax_cache`` next to the package (a fixed path:
+    the directory is part of the cache key, so one that moves never
+    hits).
 
     TPU only: XLA:CPU persists AOT executables keyed too loosely — an
     entry compiled on a host with different CPU features loads anyway
     ("may SIGILL") and in practice kills device threads, wedging
     multi-device collective rendezvous. On other backends this is a
     no-op (with a warning when the cache was explicitly requested)."""
-    import jax
-
     explicit = cache_dir is not None or bool(
         os.environ.get(_COMPILE_CACHE_ENV)
     )
-    if cache_dir is None:
-        cache_dir = (
-            os.environ.get(_COMPILE_CACHE_ENV) or _COMPILE_CACHE_DEFAULT_DIR
-        )
     if jax.default_backend() != "tpu":
         if explicit:
             warnings.warn(
@@ -243,11 +250,13 @@ def enable_compile_cache(cache_dir: str | None = None) -> bool:
                 stacklevel=2,
             )
         return False
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:  # pragma: no cover - jax-version dependent
-        return False
+    if not os.environ.get(_JAX_COMPILE_CACHE_ENV):
+        jax.config.update(
+            "jax_compilation_cache_dir",
+            cache_dir
+            or os.environ.get(_COMPILE_CACHE_ENV)
+            or _COMPILE_CACHE_DEFAULT_DIR,
+        )
     return True
 
 

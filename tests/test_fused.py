@@ -802,6 +802,51 @@ def test_compile_cache_wiring(world, monkeypatch):
         fm.init(compile_cache="/tmp/cache")
 
 
+@pytest.mark.parametrize(
+    "jax_env, flux_env, arg, want",
+    [
+        # JAX_COMPILATION_CACHE_DIR set: the cache lives there and the
+        # code sets no directory at all, whatever else asks for one.
+        ("/some/dir", None, None, None),
+        ("/some/dir", "/flux/dir", "/arg/dir", None),
+        # Unset: <checkout>/.jax_cache, resolved from the package's own
+        # location — never /tmp, a pid or a time.
+        (None, None, None, "<checkout>/.jax_cache"),
+        (None, "/flux/dir", None, "/flux/dir"),
+        (None, "/flux/dir", "/arg/dir", "/arg/dir"),
+    ],
+)
+def test_compile_cache_placement(monkeypatch, jax_env, flux_env, arg, want):
+    """Where the persistent cache goes, on the one backend that gets one
+    (the TPU branch, steered here; config updates are recorded, not
+    applied — XLA:CPU must never get a persistent cache)."""
+    import os
+
+    import fluxmpi_tpu
+    from fluxmpi_tpu import runtime
+
+    for var, val in (("JAX_COMPILATION_CACHE_DIR", jax_env),
+                     ("FLUXMPI_TPU_COMPILE_CACHE", flux_env)):
+        if val is None:
+            monkeypatch.delenv(var, raising=False)
+        else:
+            monkeypatch.setenv(var, val)
+    updates = {}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        jax.config, "update", lambda k, v: updates.__setitem__(k, v)
+    )
+    assert runtime.enable_compile_cache(arg) is True
+    if want is None:
+        assert "jax_compilation_cache_dir" not in updates
+        return
+    checkout = os.path.dirname(os.path.dirname(fluxmpi_tpu.__file__))
+    assert updates["jax_compilation_cache_dir"] == want.replace(
+        "<checkout>", checkout
+    )
+    assert os.path.exists(os.path.join(checkout, "chip_smoke.py"))
+
+
 def test_fused_respects_tiny_budget_fallback(world, monkeypatch):
     # Auto mode: dataset over the staging budget -> host path -> the
     # fused window quietly disengages.

@@ -23,16 +23,14 @@ def _free_port() -> int:
     os.environ.get("JAX_PLATFORMS", "cpu") == "cpu"
     and os.environ.get("FLUXMPI_TEST_FORCE_MULTIPROCESS", "") != "1",
     reason=(
-        "CPU-backend limitation in this jax/jaxlib (0.4.37/0.4.36): without the "
-        "gloo opt-in the backend rejects every cross-process computation "
-        "('Multiprocess computations aren't implemented on the CPU backend'); "
-        "with it (parallel/_compat.enable_cpu_cross_process_collectives, applied "
-        "by runtime.init) the world comes up and runs real collectives but the "
-        "gloo TCP transport aborts when XLA and multihost_utils collectives "
-        "interleave on one pair (gloo/transport/tcp/pair.cc:446 'op.preamble."
-        "length <= op.nbytes', SIGABRT) — an upstream transport bug, even with "
-        "async dispatch serialized. Set FLUXMPI_TEST_FORCE_MULTIPROCESS=1 to "
-        "run anyway (e.g. on a jax with a fixed gloo, or a TPU/GPU backend)."
+        "multi-process CPU worlds ride gloo's TCP transport "
+        "(parallel/_compat.enable_cpu_cross_process_collectives, applied by "
+        "runtime.init), which aborts when XLA and multihost_utils collectives "
+        "interleave on one pair (gloo/transport/tcp/pair.cc 'op.preamble."
+        "length <= op.nbytes', SIGABRT). On jax 0.9.0 worlds of 2-4 processes "
+        "pass on an idle machine (13-17 s each) and fail under load; 8 "
+        "processes fail (tried once, PR 21) — too unsteady for tier-1. Set "
+        "FLUXMPI_TEST_FORCE_MULTIPROCESS=1 to run them anyway."
     ),
 )
 @pytest.mark.parametrize("nprocs", [2, 3, 4, 8])

@@ -1379,3 +1379,87 @@ def test_tp_unembed_ce_label_smoothing_matches_dense(world):
     for a, b in zip(gf, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-5, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# spmd_attention_layout: kernels inside a program XLA partitions (the
+# chip's compiler refuses a Mosaic kernel there — tests/test_tpu_compile.py
+# holds that half; here the per-device results are held to the direct call)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "axes, batch, heads, kv_heads",
+    [
+        ({"dp": 4, "tp": 2}, 4, 4, 2),   # batch over dp, GQA heads over tp
+        ({"dp": 8}, 2, 2, 2),            # 8 does not divide 2: replicated
+        ({"dp": 2, "fsdp": 4}, 8, 2, 2),  # batch over a tuple of axes
+    ],
+)
+def test_flash_fn_per_device_under_spmd_layout(world, axes, batch, heads,
+                                               kv_heads):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from fluxmpi_tpu import ParallelConfig
+    from fluxmpi_tpu.ops import flash_attention_fn
+    from fluxmpi_tpu.ops.flash_attention import spmd_attention_layout
+
+    plan = ParallelConfig(**axes).resolve()
+    mesh = plan.mesh
+    lead = plan.batch_spec[0]
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.normal(size=(batch, 64, heads, 32)), jnp.float32)
+    k, v = (
+        jnp.asarray(rng.normal(size=(batch, 64, kv_heads, 32)), jnp.float32)
+        for _ in range(2)
+    )
+    # A padding mask, so segment ids travel through the shard_map too.
+    valid = jnp.arange(64)[None, :] < jnp.asarray(
+        rng.integers(32, 64, size=(batch, 1))
+    )
+    mask = (valid[:, None, :, None] & valid[:, None, None, :])
+    attend = flash_attention_fn(causal=True)
+
+    def loss(q, k, v, mask):
+        out = attend(q, k, v, mask=mask)
+        return jnp.sum(jnp.where(valid[:, :, None, None], out, 0.0) ** 2), out
+
+    grad = jax.grad(loss, (0, 1, 2), has_aux=True)
+    want_g, want = jax.jit(grad)(q, k, v, mask)
+
+    def spmd(q, k, v, mask):
+        with spmd_attention_layout(mesh, lead, plan.axis_name("tp")):
+            return grad(q, k, v, mask)
+
+    sharding = NamedSharding(
+        mesh, P(lead) if batch % plan.data_parallel_size == 0 else P()
+    )
+    got_g, got = jax.jit(spmd, in_shardings=(sharding,) * 4)(q, k, v, mask)
+    assert "shard_map" in str(jax.make_jaxpr(spmd)(q, k, v, mask))
+    rows = np.asarray(valid)[:, :, None, None]
+    np.testing.assert_allclose(
+        np.asarray(got) * rows, np.asarray(want) * rows, atol=1e-6
+    )
+    for g, w in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-5)
+
+
+def test_spmd_layout_gives_each_shard_its_own_dropout_stream(world):
+    """Kernel dropout hashes the LOCAL (batch, head) index: without a
+    per-shard seed every device would drop the same pattern."""
+    from fluxmpi_tpu.ops import flash_attention_fn
+    from fluxmpi_tpu.ops.flash_attention import spmd_attention_layout
+
+    rng = np.random.default_rng(1)
+    row = rng.normal(size=(1, 64, 2, 32)).astype(np.float32)
+    # Eight identical rows: identical outputs iff identical masks.
+    q, k, v = (jnp.asarray(np.repeat(row, 8, axis=0)) for _ in range(3))
+    attend = flash_attention_fn(causal=True, dropout_impl="kernel")
+
+    def run(q, k, v):
+        with spmd_attention_layout(world, world.axis_names[0]):
+            return attend(q, k, v, dropout_rate=0.5, deterministic=False,
+                          dropout_rng=jax.random.PRNGKey(0))
+
+    out = np.asarray(jax.jit(run)(q, k, v))
+    assert all(not np.array_equal(out[0], out[i]) for i in range(1, 8))

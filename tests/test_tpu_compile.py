@@ -1,0 +1,180 @@
+"""Compile the main path's kernels and steps, at real widths, for a TPU
+that is described and not attached (``v5e:2x2``). Nothing runs; what the
+chip's compiler would refuse — a misaligned tile, too much VMEM, a kernel
+inside a program XLA must partition — is refused here, at no chip time.
+Interpret mode, which every other test uses, reaches none of that.
+
+A compile that passes is not a chip run: ``chip_smoke.py`` is."""
+
+import os
+
+import numpy as np
+import optax
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, SingleDeviceSharding
+
+import chip_smoke
+from fluxmpi_tpu import ParallelConfig, config
+from fluxmpi_tpu.ops import flash_attention
+from fluxmpi_tpu.parallel import TrainState, make_train_step
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs under /tmp
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # no libtpu here, or its lock is held
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {exc!r}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one; keep it out.
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture()
+def as_on_tpu(monkeypatch):
+    """Code that asks ``jax.default_backend()`` still sees the CPU here;
+    steer the kernels' ``interpret=None`` to its on-chip branch."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _sds(shape, dtype, device):
+    return jax.ShapeDtypeStruct(
+        shape, dtype, sharding=SingleDeviceSharding(device)
+    )
+
+
+# (b, s, h, d) = (8, 1024, 12, 64): GPT-2 small's attention at the
+# smoke's batch. name -> (h_kv, flash_attention keyword arguments).
+_VARIANTS = {
+    "causal": (12, {"causal": True}),
+    "segments": (12, {"causal": True, "segments": True}),
+    "window": (12, {"causal": True, "window": 256}),
+    "gqa": (4, {"causal": True}),
+    "kernel_dropout": (12, {"causal": True, "dropout_rate": 0.1}),
+}
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("variant", sorted(_VARIANTS))
+def test_flash_kernels_compile_for_v5e(topo, variant, grad):
+    h_kv, kwargs = _VARIANTS[variant]
+    kwargs = dict(kwargs)
+    segments = kwargs.pop("segments", False)
+    dev = topo.devices[0]
+    b, s, h, d = 8, 1024, 12, 64
+
+    def attend(q, k, v, seg, seed):
+        return flash_attention(
+            q, k, v, interpret=False,
+            segment_ids=seg if segments else None,
+            dropout_seed=seed if "dropout_rate" in kwargs else None,
+            **kwargs,
+        )
+
+    def loss(q, k, v, seg, seed):
+        return jnp.sum(attend(q, k, v, seg, seed).astype(jnp.float32))
+
+    fn = jax.grad(loss, (0, 1, 2)) if grad else attend
+    text = jax.jit(fn).lower(
+        _sds((b, s, h, d), jnp.bfloat16, dev),
+        _sds((b, s, h_kv, d), jnp.bfloat16, dev),
+        _sds((b, s, h_kv, d), jnp.bfloat16, dev),
+        _sds((b, s), jnp.int32, dev),
+        _sds((), jnp.uint32, dev),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == (3 if grad else 1)
+
+
+@pytest.mark.parametrize(
+    "name, q_shape, kv_shape, grad",
+    [
+        # Paged decode: one query row against a 1,024-key cache, masked
+        # by a (q, kv) segment pair — the engine's decode attend.
+        ("decode_sq1", (8, 1, 12, 64), (8, 1024, 12, 64), False),
+        # A wider, longer model: head_dim 128 at sequence 2,048.
+        ("s2048_d128", (4, 2048, 16, 128), (4, 2048, 16, 128), True),
+    ],
+)
+def test_flash_shapes_compile_for_v5e(topo, name, q_shape, kv_shape, grad):
+    dev = topo.devices[0]
+    decode = q_shape[1] == 1
+
+    def attend(q, k, v, qseg, kseg):
+        return flash_attention(
+            q, k, v, interpret=False, causal=not decode,
+            segment_ids=(qseg, kseg) if decode else None,
+        )
+
+    def loss(*args):
+        return jnp.sum(attend(*args).astype(jnp.float32))
+
+    fn = jax.grad(loss, (0, 1, 2)) if grad else attend
+    text = jax.jit(fn).lower(
+        _sds(q_shape, jnp.bfloat16, dev),
+        _sds(kv_shape, jnp.bfloat16, dev),
+        _sds(kv_shape, jnp.bfloat16, dev),
+        _sds(q_shape[:2], jnp.int32, dev),
+        _sds(kv_shape[:2], jnp.int32, dev),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == (3 if grad else 1)
+
+
+def _lm_state(cfg, optimizer):
+    model = chip_smoke._lm(cfg)
+    params = jax.eval_shape(
+        lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32), train=False),
+        jax.random.PRNGKey(0),
+    )
+    return jax.eval_shape(lambda p: TrainState.create(p, optimizer), params)
+
+
+def _lm_batch(cfg, batch):
+    tokens = jax.ShapeDtypeStruct((batch, cfg["max_len"]), jnp.int32)
+    return tokens, tokens
+
+
+def test_gpt2_small_train_step_compiles_for_v5e(topo, as_on_tpu):
+    """The smoke's own step — GPT-2 small, bf16, flash attention, fused
+    CE head, AdamW, 8 x 1,024 tokens — through make_train_step."""
+    cfg = chip_smoke.GPT2_SMALL
+    flash_loss, _ = chip_smoke._lm_losses(cfg)
+    optimizer = optax.adamw(1e-3)
+    mesh = Mesh(np.asarray(topo.devices[:1]), (config.DP_AXIS_NAME,))
+    step = make_train_step(flash_loss, optimizer, mesh=mesh)
+    compiled = step.lower(
+        _lm_state(cfg, optimizer), _lm_batch(cfg, 8)
+    ).compile()
+    # Forward, dq and dkv for each of the 12 layers.
+    assert compiled.as_text().count("tpu_custom_call") == 36
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2**30
+
+
+def test_dp4_step_compiles_for_v5e(topo, as_on_tpu):
+    """Four chips, data parallel, two layers: the kernels sit inside a
+    program XLA partitions, which it cannot do to a Mosaic kernel —
+    make_train_step has them run per device, and the gradients meet in
+    an all-reduce."""
+    cfg = {**chip_smoke.GPT2_SMALL, "num_layers": 2}
+    flash_loss, _ = chip_smoke._lm_losses(cfg)
+    optimizer = optax.adamw(1e-3)
+    plan = ParallelConfig(dp=4).resolve(topo.devices)
+    step = make_train_step(flash_loss, optimizer, parallel=plan)
+    text = step.lower(
+        _lm_state(cfg, optimizer), _lm_batch(cfg, 8)
+    ).compile().as_text()
+    assert "all-reduce" in text
+    assert text.count("tpu_custom_call") == 6
