@@ -47,6 +47,7 @@ __all__ = [
     "flash_attention_fn",
     "padding_to_segment_ids",
     "spmd_attention_layout",
+    "attention_scope",
 ]
 
 _NEG_INF = -1e30
@@ -1314,6 +1315,29 @@ def spmd_attention_layout(mesh, batch_axes, head_axis=None):
         _SPMD_LAYOUT.reset(token)
 
 
+_ATTENTION_SCOPE: contextvars.ContextVar = contextvars.ContextVar(
+    "fluxmpi_tpu_attention_scope", default=None
+)
+
+
+@contextlib.contextmanager
+def attention_scope(name: str):
+    """Declare, while tracing a program, the ``jax.named_scope`` that
+    :func:`flash_attention_fn` puts around attention (mask to segment
+    ids, the kernels, the fidelity check): the serving engine's decode
+    and prefill programs say ``decode_attention`` / ``prefill_attention``
+    so a device trace tells the two apart. Compile-time metadata only.
+    The scope sits OUTSIDE ``jit(flash_attention)``, whose name is what
+    the chip's compiler gives the kernels' instructions
+    (``%flash_attention.N``): a scope (or a ``pallas_call(name=)``)
+    inside it would rename them."""
+    token = _ATTENTION_SCOPE.set(name)
+    try:
+        yield
+    finally:
+        _ATTENTION_SCOPE.reset(token)
+
+
 def _axis_names(axes) -> tuple:
     """A PartitionSpec entry (None, a name, or a tuple of names) as a
     tuple of mesh axis names."""
@@ -1409,6 +1433,13 @@ def flash_attention_fn(
         raise ValueError("dropout_impl must be 'dense' or 'kernel'")
 
     def fn(query, key, value, bias=None, mask=None, **kwargs):
+        scope = _ATTENTION_SCOPE.get()
+        if scope is None:
+            return attention(query, key, value, bias, mask, **kwargs)
+        with jax.named_scope(scope):
+            return attention(query, key, value, bias, mask, **kwargs)
+
+    def attention(query, key, value, bias, mask, **kwargs):
         if bias is not None:
             raise ValueError(
                 "flash_attention_fn cannot honor a dense attention bias "

@@ -221,8 +221,11 @@ def unembed_cross_entropy(
     lead = h.shape[:-1]
     h2 = h.reshape(-1, d)
     targets1 = targets.reshape(-1).astype(jnp.int32)
-    out = _fused_ce(h2, embedding, targets1, min(chunk, vocab),
-                    float(label_smoothing))
+    # The loss head's name in a device trace; its backward pass carries
+    # it as transpose(jvp(ce_head)).
+    with jax.named_scope("ce_head"):
+        out = _fused_ce(h2, embedding, targets1, min(chunk, vocab),
+                        float(label_smoothing))
     return out.reshape(lead)
 
 
@@ -411,8 +414,10 @@ def tp_unembed_cross_entropy(
             f"label_smoothing must be in [0, 1), got {label_smoothing}"
         )
     local_chunk = min(chunk, vocab // n)
-    out = _fused_ce_tp(
-        h2, embedding, targets1, local_chunk, tp, mesh,
-        tuple(batch_axes) if batch_axes else None, float(label_smoothing),
-    )
+    with jax.named_scope("ce_head"):
+        out = _fused_ce_tp(
+            h2, embedding, targets1, local_chunk, tp, mesh,
+            tuple(batch_axes) if batch_axes else None,
+            float(label_smoothing),
+        )
     return out.reshape(lead)

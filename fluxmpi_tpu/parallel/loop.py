@@ -103,6 +103,10 @@ def _epoch_len(batches: Any, scan_steps: int) -> int | None:
     return n
 
 
+# What next() returns once the per-step path's source has run dry.
+_SOURCE_DRY = object()
+
+
 def _stall_timed(it: Any, gp: Any) -> Iterable[Any]:
     """Wrap an epoch iterator so the host wait for each batch lands in
     the goodput ``data_stall`` bucket (enabled-tracker path only — the
@@ -1023,69 +1027,74 @@ def train_loop(
         nonlocal t_flush, halt_rule, stall_base
         if interval_updates == 0:
             return
-        if last_out is not None:
-            # Drain to the newest dispatched result so the interval's wall
-            # time covers completed work, not enqueued promises — the
-            # step_timer discipline at flush granularity. The drain is
-            # honest device compute: productive goodput.
-            if gp_on:
-                with gp.segment("step"):
-                    jax.block_until_ready(last_out)
-            else:
-                jax.block_until_ready(last_out)
-        now = time.perf_counter()
-        elapsed = now - t_flush
-        per_update = elapsed / interval_updates
-        notify_progress(interval_updates)
-        loss_v: float | None = None
-        grad_v: float | None = None
-        stats_host: Any = None
-        window_stats: dict[str, float] = {}
-        if record_metrics or det_on or exp_on or ms_on:
-            if fused_w:
-                # The window program's metric carry: a dict of f32
-                # scalars (plus the model-stats tree when the plane is
-                # on) — ONE tiny device→host transfer per flush.
-                vals = jax.device_get(last_out)
-                loss_v = float(np.asarray(vals["loss"]))
-                if "grad_norm" in vals:
-                    grad_v = float(np.asarray(vals["grad_norm"]))
-                if ms_on:
-                    stats_host = vals.get("model_stats")
-                if last_width > 0:
-                    window_stats["loss_window_mean"] = (
-                        float(np.asarray(vals["loss_sum"])) / last_width
-                    )
-                window_stats["loss_window_max"] = float(
-                    np.asarray(vals["loss_max"])
-                )
-            else:
-                if ms_on:
-                    # Aux is (loss, grad_norm, stats): pull the whole
-                    # tuple across in one transfer; a scan_steps step
-                    # stacks each leaf [K] — the flush describes the
-                    # NEWEST update, so take the last entry.
-                    vals = jax.device_get(last_out)
-                    loss_v = float(np.asarray(vals[0]).mean())
-                    grad_v = float(np.asarray(vals[1]).mean())
-                    stats_host = vals[2]
-                    if k > 1:
-                        from .train import _last_scan_entry
-
-                        stats_host = _last_scan_entry(stats_host)
+        # The blocking boundary, one span: the drain to the newest result
+        # and the device->host read of the interval's scalars.
+        with _tracing.span(
+            "loop.flush", update=updates, fused=bool(fused_w)
+        ):
+            if last_out is not None:
+                # Drain to the newest dispatched result so the interval's wall
+                # time covers completed work, not enqueued promises — the
+                # step_timer discipline at flush granularity. The drain is
+                # honest device compute: productive goodput.
+                if gp_on:
+                    with gp.segment("step"):
+                        jax.block_until_ready(last_out)
                 else:
-                    leaves = jax.tree_util.tree_leaves(last_out)
-                    loss_h = (
-                        np.asarray(jax.device_get(leaves[0]))
-                        if leaves else None
-                    )
-                    loss_v = (
-                        float(loss_h.mean()) if loss_h is not None else None
-                    )
-                    if len(leaves) > 1:
-                        grad_v = float(
-                            np.asarray(jax.device_get(leaves[1])).mean()
+                    jax.block_until_ready(last_out)
+            now = time.perf_counter()
+            elapsed = now - t_flush
+            per_update = elapsed / interval_updates
+            notify_progress(interval_updates)
+            loss_v: float | None = None
+            grad_v: float | None = None
+            stats_host: Any = None
+            window_stats: dict[str, float] = {}
+            if record_metrics or det_on or exp_on or ms_on:
+                if fused_w:
+                    # The window program's metric carry: a dict of f32
+                    # scalars (plus the model-stats tree when the plane is
+                    # on) — ONE tiny device→host transfer per flush.
+                    vals = jax.device_get(last_out)
+                    loss_v = float(np.asarray(vals["loss"]))
+                    if "grad_norm" in vals:
+                        grad_v = float(np.asarray(vals["grad_norm"]))
+                    if ms_on:
+                        stats_host = vals.get("model_stats")
+                    if last_width > 0:
+                        window_stats["loss_window_mean"] = (
+                            float(np.asarray(vals["loss_sum"])) / last_width
                         )
+                    window_stats["loss_window_max"] = float(
+                        np.asarray(vals["loss_max"])
+                    )
+                else:
+                    if ms_on:
+                        # Aux is (loss, grad_norm, stats): pull the whole
+                        # tuple across in one transfer; a scan_steps step
+                        # stacks each leaf [K] — the flush describes the
+                        # NEWEST update, so take the last entry.
+                        vals = jax.device_get(last_out)
+                        loss_v = float(np.asarray(vals[0]).mean())
+                        grad_v = float(np.asarray(vals[1]).mean())
+                        stats_host = vals[2]
+                        if k > 1:
+                            from .train import _last_scan_entry
+
+                            stats_host = _last_scan_entry(stats_host)
+                    else:
+                        leaves = jax.tree_util.tree_leaves(last_out)
+                        loss_h = (
+                            np.asarray(jax.device_get(leaves[0]))
+                            if leaves else None
+                        )
+                        loss_v = (
+                            float(loss_h.mean()) if loss_h is not None else None
+                        )
+                        if len(leaves) > 1:
+                            grad_v = float(
+                                np.asarray(jax.device_get(leaves[1])).mean()
+                            )
         if record_metrics:
             record: dict[str, Any] = {
                 "step_seconds": per_update,
@@ -1272,13 +1281,14 @@ def train_loop(
             # inside the compiled program. The host wait for the epoch
             # bring-up (permutation transfer) is the fused analogue of
             # the loader stall.
-            if gp_on:
-                clock = gp._clock
-                t0 = clock()
-                staged, perm, pos = batches.device_epoch()
-                gp.add("data_stall", clock() - t0)
-            else:
-                staged, perm, pos = batches.device_epoch()
+            with _tracing.span("loop.device_epoch", epoch=epochs_done):
+                if gp_on:
+                    clock = gp._clock
+                    t0 = clock()
+                    staged, perm, pos = batches.device_epoch()
+                    gp.add("data_stall", clock() - t0)
+                else:
+                    staged, perm, pos = batches.device_epoch()
             nb = per_epoch
             # The cache-key fingerprint is invariant within a pass (the
             # program returns same-aval state by construction; staged
@@ -1310,15 +1320,20 @@ def train_loop(
                 width = fused_w - pos % fused_w if pos % fused_w else fused_w
                 program = _window_program(width, state, staged, perm, avals)
                 start_idx = np.int32(pos * lbs_fused)
-                if gp_on:
-                    # The dispatch is the whole window's productive
-                    # compute; the flush inside _post_dispatch drains it
-                    # under its own step segment.
-                    with gp.segment("step"):
+                with _tracing.span(
+                    "loop.dispatch", update=updates, width=width
+                ):
+                    if gp_on:
+                        # The dispatch is the whole window's productive
+                        # compute; the flush inside _post_dispatch drains
+                        # it under its own step segment.
+                        with gp.segment("step"):
+                            state, out = program(
+                                state, staged, perm, start_idx
+                            )
+                        gp.note_updates(width)
+                    else:
                         state, out = program(state, staged, perm, start_idx)
-                    gp.note_updates(width)
-                else:
-                    state, out = program(state, staged, perm, start_idx)
                 first_dispatch = False
                 last_out = out
                 last_width = width
@@ -1353,7 +1368,15 @@ def train_loop(
             # Loader waits land in the data_stall bucket; the off path
             # iterates the source directly (no wrapper, no clock reads).
             source = _stall_timed(iter(source), gp)
-        for batch in source:
+        # An explicit next() (not a for loop) so the wait for the loader
+        # is a span of its own; with tracing off, span() is one call.
+        source = iter(source)
+        while True:
+            with _tracing.span("loop.fetch", update=updates):
+                batch = next(source, _SOURCE_DRY)
+            if batch is _SOURCE_DRY:
+                exhausted = True
+                break
             if gp_on:
                 if first_dispatch and gp._flops_per_update is None:
                     # FLOPs per update from XLA's cost model, BEFORE the
@@ -1372,16 +1395,24 @@ def train_loop(
                 # window-full block on the oldest result) are the
                 # productive step bucket.
                 with gp.segment("compile" if first_dispatch else "step"):
-                    state, out = hot(state, batch)
+                    with _tracing.span(
+                        "loop.dispatch", update=updates, width=k
+                    ):
+                        state, out = hot(state, batch)
                     window.append(out)
                     if len(window) > in_flight:
-                        jax.block_until_ready(window.popleft())
+                        with _tracing.span(
+                            "loop.backpressure", update=updates
+                        ):
+                            jax.block_until_ready(window.popleft())
                 gp.note_updates(k)
             else:
-                state, out = hot(state, batch)
+                with _tracing.span("loop.dispatch", update=updates, width=k):
+                    state, out = hot(state, batch)
                 window.append(out)
                 if len(window) > in_flight:
-                    jax.block_until_ready(window.popleft())
+                    with _tracing.span("loop.backpressure", update=updates):
+                        jax.block_until_ready(window.popleft())
             first_dispatch = False
             last_out = out
             dispatches += 1
@@ -1395,8 +1426,6 @@ def train_loop(
             _post_dispatch(interval_updates >= flush_every)
             if done:
                 break
-        else:
-            exhausted = True
         if exhausted or dispatched_this_epoch == per_epoch:
             # Iterator ran dry, or the steps budget landed exactly on the
             # last dispatch of a sized source — either way a full pass.

@@ -547,8 +547,11 @@ def make_train_step(
     grad_and_aux = jax.value_and_grad(loss_fn, has_aux=True)
 
     def _apply_update(ts: TrainState, grads, loss, new_mstate):
-        updates, opt_state = optimizer.update(grads, ts.opt_state, ts.params)
-        params = optax.apply_updates(ts.params, updates)
+        with jax.named_scope("optimizer_update"):
+            updates, opt_state = optimizer.update(
+                grads, ts.opt_state, ts.params
+            )
+            params = optax.apply_updates(ts.params, updates)
         return (
             TrainState(
                 step=ts.step + 1,
@@ -860,11 +863,14 @@ def make_window_program(
     def window(ts: TrainState, data, perm, start):
         def body(carry, i):
             st, m = carry
-            batch = _gather_batch(data, perm, start + i * lbs, lbs)
-            # Pin the gathered batch to the step's data-parallel layout
-            # so the partitioner sees exactly what the per-batch gather
-            # jit's out_shardings produced.
-            batch = jax.lax.with_sharding_constraint(batch, batch_sharding)
+            with jax.named_scope("batch_gather"):
+                batch = _gather_batch(data, perm, start + i * lbs, lbs)
+                # Pin the gathered batch to the step's data-parallel
+                # layout so the partitioner sees exactly what the
+                # per-batch gather jit's out_shardings produced.
+                batch = jax.lax.with_sharding_constraint(
+                    batch, batch_sharding
+                )
             out = single(st, batch)
             stats = None
             if carries_aux:

@@ -60,6 +60,7 @@ from typing import Any, Callable
 import numpy as np
 
 from ..errors import RequestRejectedError
+from ..telemetry import tracing as _tracing
 from ..telemetry.registry import MetricsRegistry, get_registry
 from . import observe as _observe_mod
 from .cache import BlockKVCache, TRASH_BLOCK, blocks_for_tokens
@@ -589,6 +590,12 @@ class InferenceEngine:
         self._decode_steps = 0
         self._tokens = 0
         self._slo_violations = 0
+        # Occupancy (see stats()): slots holding a request now, the sum of
+        # that over decode ticks, and requests admitted / evicted.
+        self._active = 0
+        self._slot_steps_active = 0
+        self._admissions = 0
+        self._evictions = 0
         # Registry-counter delta baselines (see _resolve_run).
         self._counted_steps = 0
         self._counted_tokens = 0
@@ -631,6 +638,7 @@ class InferenceEngine:
         import jax.numpy as jnp
 
         from ..models.generate import layer_index
+        from ..ops.flash_attention import attention_scope
 
         twin = self._twin
         tmpl = self._tmpl
@@ -677,23 +685,26 @@ class InferenceEngine:
 
         def step(params, k_pool, v_pool, tables, positions, tokens):
             # tables: [slots, max_blocks]; positions/tokens: [slots].
-            k_g = jnp.moveaxis(k_pool[:, tables], 1, 0).reshape(
-                nslots, -1, t_total, k_pool.shape[3], k_pool.shape[4]
-            )
-            v_g = jnp.moveaxis(v_pool[:, tables], 1, 0).reshape(
-                nslots, -1, t_total, v_pool.shape[3], v_pool.shape[4]
-            )
-            nxt, knew, vnew = jax.vmap(
-                one, in_axes=(None, 0, 0, 0, 0)
-            )(params["params"], tokens, positions, k_g, v_g)
-            blk = jnp.take_along_axis(
-                tables, (positions // bs)[:, None], axis=1
-            )[:, 0]
-            off = positions % bs
-            # Idle slots carry all-trash tables, so their writes land in
-            # block 0 — no masking, no shape change.
-            k_pool = k_pool.at[:, blk, off].set(jnp.moveaxis(knew, 0, 1))
-            v_pool = v_pool.at[:, blk, off].set(jnp.moveaxis(vnew, 0, 1))
+            with jax.named_scope("kv_gather"):
+                k_g = jnp.moveaxis(k_pool[:, tables], 1, 0).reshape(
+                    nslots, -1, t_total, k_pool.shape[3], k_pool.shape[4]
+                )
+                v_g = jnp.moveaxis(v_pool[:, tables], 1, 0).reshape(
+                    nslots, -1, t_total, v_pool.shape[3], v_pool.shape[4]
+                )
+            with attention_scope("decode_attention"):
+                nxt, knew, vnew = jax.vmap(
+                    one, in_axes=(None, 0, 0, 0, 0)
+                )(params["params"], tokens, positions, k_g, v_g)
+            with jax.named_scope("kv_write"):
+                blk = jnp.take_along_axis(
+                    tables, (positions // bs)[:, None], axis=1
+                )[:, 0]
+                off = positions % bs
+                # Idle slots carry all-trash tables, so their writes land
+                # in block 0 — no masking, no shape change.
+                k_pool = k_pool.at[:, blk, off].set(jnp.moveaxis(knew, 0, 1))
+                v_pool = v_pool.at[:, blk, off].set(jnp.moveaxis(vnew, 0, 1))
             return nxt, k_pool, v_pool
 
         return jax.jit(step, donate_argnums=(1, 2))
@@ -710,22 +721,25 @@ class InferenceEngine:
         import jax.numpy as jnp
 
         from ..models.generate import prefill_kv
+        from ..ops.flash_attention import attention_scope
 
         model = self.model
         bs = self.block_size
 
         def prefill(params, k_pool, v_pool, tokens, length, table):
             # tokens: [bucket]; length: true prompt length; table: [MB].
-            k, v, logits = prefill_kv(model, params, tokens[None])
-            k = k[:, 0]  # [layers, bucket, heads, head_dim]
-            v = v[:, 0]
-            pos = jnp.arange(tokens.shape[0])
-            blk = jnp.where(
-                pos < length, table[pos // bs], jnp.int32(TRASH_BLOCK)
-            )
-            off = pos % bs
-            k_pool = k_pool.at[:, blk, off].set(k.astype(k_pool.dtype))
-            v_pool = v_pool.at[:, blk, off].set(v.astype(v_pool.dtype))
+            with attention_scope("prefill_attention"):
+                k, v, logits = prefill_kv(model, params, tokens[None])
+            with jax.named_scope("kv_write"):
+                k = k[:, 0]  # [layers, bucket, heads, head_dim]
+                v = v[:, 0]
+                pos = jnp.arange(tokens.shape[0])
+                blk = jnp.where(
+                    pos < length, table[pos // bs], jnp.int32(TRASH_BLOCK)
+                )
+                off = pos % bs
+                k_pool = k_pool.at[:, blk, off].set(k.astype(k_pool.dtype))
+                v_pool = v_pool.at[:, blk, off].set(v.astype(v_pool.dtype))
             last = jax.lax.dynamic_index_in_dim(
                 logits[0], length - 1, axis=0, keepdims=False
             )
@@ -898,36 +912,48 @@ class InferenceEngine:
     def _admit(self, req: ServingRequest, slot_ix: int, total: int) -> None:
         import jax.numpy as jnp
 
-        req.admitted_t = self._clock()
-        req.status = ACTIVE
-        blocks = self.cache.alloc(total)
-        table = self.cache.table_row(blocks)
-        slot = _Slot(req, blocks, table)
         plen = int(req.prompt.shape[0])
         bucket = self._bucket(plen)
-        padded = np.zeros((bucket,), np.int32)
-        padded[:plen] = req.prompt
-        fn = self._prefill_step(bucket)
-        first, self.cache.k_pool, self.cache.v_pool = fn(
-            self.params, self.cache.k_pool, self.cache.v_pool,
-            jnp.asarray(padded), jnp.int32(plen), jnp.asarray(table),
-        )
-        slot.position = plen
-        slot.generated = 1
-        slot.last_token = int(first)
-        self._slots[slot_ix] = slot
-        req._deliver(slot.last_token)
-        self._tokens += 1
-        if self._record:
-            reg = self._reg
-            if req.queue_wait_s is not None:
-                reg.histogram("serving.queue_wait_seconds").observe(
-                    req.queue_wait_s
-                )
-        if slot.generated >= req.max_new_tokens or (
-            req.eos_token is not None and slot.last_token == int(req.eos_token)
+        # Every active slot stalls for the length of this span.
+        with _tracing.span(
+            "serve.admit", request_id=req.id, prompt_tokens=plen,
+            bucket=bucket, active=self._active,
         ):
-            self._evict(slot_ix)
+            req.admitted_t = self._clock()
+            req.status = ACTIVE
+            blocks = self.cache.alloc(total)
+            table = self.cache.table_row(blocks)
+            slot = _Slot(req, blocks, table)
+            padded = np.zeros((bucket,), np.int32)
+            padded[:plen] = req.prompt
+            fn = self._prefill_step(bucket)
+            # Dispatch plus the blocking read of the first token.
+            with _tracing.span(
+                "serve.prefill", request_id=req.id, bucket=bucket
+            ):
+                first, self.cache.k_pool, self.cache.v_pool = fn(
+                    self.params, self.cache.k_pool, self.cache.v_pool,
+                    jnp.asarray(padded), jnp.int32(plen), jnp.asarray(table),
+                )
+                slot.last_token = int(first)
+            slot.position = plen
+            slot.generated = 1
+            self._slots[slot_ix] = slot
+            self._active += 1
+            self._admissions += 1
+            req._deliver(slot.last_token)
+            self._tokens += 1
+            if self._record:
+                reg = self._reg
+                if req.queue_wait_s is not None:
+                    reg.histogram("serving.queue_wait_seconds").observe(
+                        req.queue_wait_s
+                    )
+            if slot.generated >= req.max_new_tokens or (
+                req.eos_token is not None
+                and slot.last_token == int(req.eos_token)
+            ):
+                self._evict(slot_ix)
 
     # -- decode --------------------------------------------------------
 
@@ -941,35 +967,50 @@ class InferenceEngine:
         if faults.ARMED:
             faults.check("serving.decode")
         mb = self.max_blocks_per_seq
-        tables = np.zeros((self.slots, mb), np.int32)
-        positions = np.zeros((self.slots,), np.int32)
-        tokens = np.zeros((self.slots,), np.int32)
-        for i, slot in enumerate(self._slots):
-            if slot is None:
-                continue
-            tables[i] = slot.table
-            positions[i] = slot.position
-            tokens[i] = slot.last_token
-        nxt, self.cache.k_pool, self.cache.v_pool = self._decode_step(
-            self.params, self.cache.k_pool, self.cache.v_pool,
-            jnp.asarray(tables), jnp.asarray(positions), jnp.asarray(tokens),
-        )
-        nxt = np.asarray(nxt)
+        step = self._decode_steps
+        active = self._active
+        with _tracing.span("serve.decode.prepare", active=active):
+            tables = np.zeros((self.slots, mb), np.int32)
+            positions = np.zeros((self.slots,), np.int32)
+            tokens = np.zeros((self.slots,), np.int32)
+            for i, slot in enumerate(self._slots):
+                if slot is None:
+                    continue
+                tables[i] = slot.table
+                positions[i] = slot.position
+                tokens[i] = slot.last_token
+            tables = jnp.asarray(tables)
+            positions = jnp.asarray(positions)
+            tokens = jnp.asarray(tokens)
+        with _tracing.span("serve.decode.dispatch", step=step):
+            nxt, self.cache.k_pool, self.cache.v_pool = self._decode_step(
+                self.params, self.cache.k_pool, self.cache.v_pool,
+                tables, positions, tokens,
+            )
+        with _tracing.span("serve.decode.fetch", step=step):
+            nxt = np.asarray(nxt)
         self._decode_steps += 1
-        for i, slot in enumerate(self._slots):
-            if slot is None:
-                continue
-            tok = int(nxt[i])
-            slot.position += 1
-            slot.generated += 1
-            slot.last_token = tok
-            slot.req._deliver(tok)
-            self._tokens += 1
-            if slot.generated >= slot.req.max_new_tokens or (
-                slot.req.eos_token is not None
-                and tok == int(slot.req.eos_token)
-            ):
-                self._evict(i)
+        self._slot_steps_active += active
+        evicted = self._evictions
+        # Callbacks and frees: the device idles unless a step is queued.
+        with _tracing.span(
+            "serve.decode.deliver", step=step, tokens=active
+        ) as delivery:
+            for i, slot in enumerate(self._slots):
+                if slot is None:
+                    continue
+                tok = int(nxt[i])
+                slot.position += 1
+                slot.generated += 1
+                slot.last_token = tok
+                slot.req._deliver(tok)
+                self._tokens += 1
+                if slot.generated >= slot.req.max_new_tokens or (
+                    slot.req.eos_token is not None
+                    and tok == int(slot.req.eos_token)
+                ):
+                    self._evict(i)
+            delivery.set_metadata(evicted=self._evictions - evicted)
 
     def _evict(self, slot_ix: int) -> None:
         """Finish a slot's request and return its blocks to the free
@@ -977,6 +1018,8 @@ class InferenceEngine:
         slot = self._slots[slot_ix]
         assert slot is not None
         self._slots[slot_ix] = None
+        self._active -= 1
+        self._evictions += 1
         self.cache.free(slot.blocks)
         req = slot.req
         req._finish(FINISHED)
@@ -1022,6 +1065,22 @@ class InferenceEngine:
     def active_count(self) -> int:
         return sum(1 for s in self._slots if s is not None)
 
+    def stats(self) -> dict[str, int]:
+        """Snapshot of the scheduler's counters since construction:
+        ``decode_steps`` (decode programs dispatched), ``tokens``
+        (delivered, first tokens included), ``slot_steps_active`` (slots
+        holding a request, summed over decode steps: over ``decode_steps
+        * slots`` it is the occupancy), ``admissions`` and ``evictions``
+        (requests prefilled into a slot / finished out of one). Plain
+        ints the loop keeps anyway; safe to read from another thread."""
+        return {
+            "decode_steps": self._decode_steps,
+            "tokens": self._tokens,
+            "slot_steps_active": self._slot_steps_active,
+            "admissions": self._admissions,
+            "evictions": self._evictions,
+        }
+
     @property
     def queue_depth(self) -> int:
         with self._lock:
@@ -1049,11 +1108,16 @@ class InferenceEngine:
 
         if preemption_requested() and not self._draining:
             self._begin_drain(preempted=True)
-        admitted = self._admit_phase()
-        ticked = False
-        if any(s is not None for s in self._slots):
-            self._decode_tick()
-            ticked = True
+        if not self._active and not self._queue:
+            return False  # idle poll: no span, no progress tick
+        with _tracing.span(
+            "serve.iteration", active=self._active, queued=len(self._queue)
+        ):
+            admitted = self._admit_phase()
+            ticked = False
+            if self._active:
+                self._decode_tick()
+                ticked = True
         if admitted or ticked:
             # Progress ONLY when work happened: an idle serve thread
             # bumping the process-global watchdog counter every poll
@@ -1230,6 +1294,7 @@ class InferenceEngine:
             for i, slot in enumerate(self._slots):
                 if slot is not None:
                     self._slots[i] = None
+                    self._active -= 1
                     self.cache.free(slot.blocks)
                     self._reject(
                         slot.req, reason, kv_blocks=len(slot.blocks)

@@ -384,6 +384,26 @@ HISTOGRAM_BUCKET_EDGES: dict[str, tuple[float, ...]] = {
 # have, so the validator rejects the wrong phase.
 PREEMPTION_EVENT = "train.preemption"
 
+# The hot-path spans ("X" events) train_loop and the serving engine
+# record through tracing.span, with the arguments each carries: names and
+# arguments are a contract (docs/observability.md, "Hot-path spans") that
+# the benchmark's per-layer metrics read, so validate_trace_event holds an
+# exported span to its arguments. Other span names pass freely.
+HOT_PATH_SPAN_ARGS: dict[str, tuple[str, ...]] = {
+    "loop.fetch": ("update",),
+    "loop.device_epoch": ("epoch",),
+    "loop.dispatch": ("update", "width"),
+    "loop.backpressure": ("update",),
+    "loop.flush": ("update", "fused"),
+    "serve.iteration": ("active", "queued"),
+    "serve.admit": ("request_id", "prompt_tokens", "bucket", "active"),
+    "serve.prefill": ("request_id", "bucket"),
+    "serve.decode.prepare": ("active",),
+    "serve.decode.dispatch": ("step",),
+    "serve.decode.fetch": ("step",),
+    "serve.decode.deliver": ("step", "tokens", "evicted"),
+}
+
 # Anomaly trace events (AnomalyDetector triggers): "anomaly.<rule>"
 # instants carrying the rule name and the update count — same
 # instant-only contract as the preemption event (an anomaly is a point
@@ -1243,6 +1263,17 @@ def validate_trace_event(ev: object, where: str = "traceEvents[]") -> list[str]:
     args = ev.get("args")
     if args is not None and not isinstance(args, dict):
         errors.append(f"{where}: 'args' must be an object")
+    required = HOT_PATH_SPAN_ARGS.get(ev.get("name")) if ph == "X" else None
+    if required is not None:
+        missing = [
+            k for k in required
+            if not isinstance(args, dict) or k not in args
+        ]
+        if missing:
+            errors.append(
+                f"{where}: span {ev.get('name')!r} lacks args {missing} "
+                f"(hot-path span contract: {list(required)})"
+            )
     if ev.get("name") == PREEMPTION_EVENT:
         if ph not in ("i", "I"):
             errors.append(

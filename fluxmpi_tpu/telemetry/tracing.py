@@ -25,6 +25,18 @@ export rebases them onto the wall clock through a (unix, perf) anchor
 pair taken at tracer creation, so per-host traces merge onto one
 cross-host timeline keyed by NTP-disciplined wall time.
 
+Two sinks, one call: while a JAX profiler session records host events
+(``jax.profiler.start_trace`` at ``host_tracer_level`` >= 1),
+:func:`span` also opens a ``jax.profiler.TraceAnnotation`` of the same
+name and arguments, so the span lies in the session's ``.xplane.pb``
+beside the device's operations, on their clock (an event's ``start_ns``
+counts from the ``profile_start_time`` stat of the ``Task Environment``
+plane, unix nanoseconds). Nothing switches it on: the guard is
+``TraceAnnotation.is_enabled()``. A capture that drops host events
+(``host_tracer_level`` 0) leaves the ring as the only channel, which is
+why :func:`fluxmpi_tpu.utils.profile_trace` enables the ring for the
+capture and exports it beside the xplane.
+
 The open-span stack is tracked per thread (plain list append/pop) so the
 watchdog can report *where inside the step* each thread was when a hang
 dump fires — the Python-level analogue of the thread stacks it also
@@ -41,6 +53,18 @@ from typing import Any, Iterator
 
 from .registry import process_index_or_zero as _process_index
 from .schema import TRACE_SCHEMA
+
+try:  # the profiler's host-event sink; without jax only the ring records
+    from jax.profiler import TraceAnnotation as _Annotation
+except ImportError:  # pragma: no cover - jax is a hard dependency of the package
+    _Annotation = None
+
+    def _session_records() -> bool:
+        return False
+else:
+    # False before start_trace and at host_tracer_level 0, true during a
+    # session that keeps host events: one C++ call, no Python frame.
+    _session_records = _Annotation.is_enabled
 
 __all__ = [
     "Tracer",
@@ -73,6 +97,9 @@ class _NoopSpan:
     def __exit__(self, *exc: Any) -> None:
         return None
 
+    def set_metadata(self, **args: Any) -> None:
+        return None
+
 
 _NOOP_SPAN = _NoopSpan()
 
@@ -81,21 +108,38 @@ class _Span:
     """One live span: records a Chrome-trace "X" (complete) event on exit
     and sits on its thread's open-span stack while active."""
 
-    __slots__ = ("_tracer", "name", "args", "_start_ns", "_stack")
+    __slots__ = ("_tracer", "name", "args", "_start_ns", "_stack",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict | None):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self._annotation = None
 
     def __enter__(self) -> "_Span":
+        if _session_records():
+            # The profiler's copy opens first and closes last, so the
+            # ring's interval lies inside the xplane's.
+            self._annotation = _Annotation(self.name, **(self.args or {}))
+            self._annotation.__enter__()
         self._stack = self._tracer._open_stack()
         self._start_ns = time.perf_counter_ns()
         self._stack.append(self)
         return self
 
+    def set_metadata(self, **args: Any) -> None:
+        """Add arguments known only inside the span (the spelling of
+        ``TraceAnnotation.set_metadata``, which a session-only span is)."""
+        self.args = {**self.args, **args} if self.args else args
+        if self._annotation is not None:
+            self._annotation.set_metadata(**args)
+
     def __exit__(self, *exc: Any) -> None:
         end_ns = time.perf_counter_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
         stack = self._stack
         if stack and stack[-1] is self:
             stack.pop()
@@ -141,11 +185,15 @@ class Tracer:
         return stack
 
     def span(self, name: str, **args: Any) -> Any:
-        """Context manager timing the enclosed block as one "X" event.
-        No-op (shared singleton, nothing recorded) while disabled."""
-        if not self.enabled:
-            return _NOOP_SPAN
-        return _Span(self, name, args or None)
+        """Context manager timing the enclosed block as one "X" event,
+        and as a ``TraceAnnotation`` while a profiler session records
+        host events. No-op (shared singleton, nothing recorded, no clock
+        read) while the ring is disabled and no session records."""
+        if self.enabled:
+            return _Span(self, name, args or None)
+        if _session_records():
+            return _Annotation(name, **args)
+        return _NOOP_SPAN
 
     def instant(
         self, name: str, *, track: int | None = None, **args: Any
@@ -304,8 +352,15 @@ def trace_enabled() -> bool:
 
 
 def span(name: str, **args: Any) -> Any:
-    """``with span("train.step"): ...`` on the default tracer."""
-    return _default.span(name, **args)
+    """``with span("train.step"): ...`` on the default tracer: the one
+    span call the program makes (same body as :meth:`Tracer.span`,
+    inlined to spare the disabled path a second call)."""
+    tracer = _default
+    if tracer.enabled:
+        return _Span(tracer, name, args or None)
+    if _session_records():
+        return _Annotation(name, **args)
+    return _NOOP_SPAN
 
 
 def instant(name: str, **args: Any) -> None:

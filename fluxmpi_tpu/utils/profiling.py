@@ -24,7 +24,6 @@ import os
 import sys
 import threading
 import time
-import warnings
 from typing import Any, Iterator
 
 import jax
@@ -55,9 +54,13 @@ def _per_process_dir(logdir: str) -> str:
     return logdir
 
 
+#: The ring's export of a capture, beside the profiler's own files.
+SPANS_FILE = "fluxmpi_spans.trace.json"
+
+
 @contextlib.contextmanager
 def profile_trace(
-    logdir: str, *, all_hosts: bool = False, host_only: bool | None = None
+    logdir: str, *, all_hosts: bool = False, profiler_options: Any = None
 ) -> Iterator[None]:
     """Capture a profiler trace of the enclosed block into ``logdir``.
 
@@ -69,37 +72,48 @@ def profile_trace(
     automatically, so one shared logdir (GCS bucket, NFS path) works —
     the writers no longer collide.
 
-    ``host_only`` is the deprecated spelling of this switch: it was
-    documented as "only the lead process traces" but implemented so
-    ``host_only=True`` made *every* process trace. The shim preserves
-    each caller's old *actual* behavior (``all_hosts = host_only``) —
-    ``host_only=False`` callers keep their correct lead-only traces,
-    ``host_only=True`` callers keep tracing everywhere — while the
-    deprecation warning points at the honest spelling.
+    The program's own spans (:func:`fluxmpi_tpu.telemetry.tracing.span`)
+    reach the capture through two channels. While the session records
+    host events they are ``TraceAnnotation`` events on the XPlane's host
+    plane, on the device trace's clock. A capture that drops host events
+    (``profiler_options.host_tracer_level = 0``, for a host-fed loop the
+    runtime's own TraceMe spans would slow) comes back with an empty
+    host plane, so the span ring is enabled for the capture's length and
+    its export written beside the XPlane as ``<dir>/fluxmpi_spans.trace.json``
+    (wall-clock microseconds; the XPlane's ``profile_start_time`` is the
+    same clock). A ring that was already recording is left recording.
 
-    View with TensorBoard's profile plugin or Perfetto. For the
-    always-on, in-process span timeline (no XPlane machinery), see
-    :mod:`fluxmpi_tpu.telemetry.tracing`.
+    View with TensorBoard's profile plugin or Perfetto.
     """
-    if host_only is not None:
-        warnings.warn(
-            "profile_trace(host_only=...) is deprecated: the flag's old "
-            "behavior contradicted its documentation (host_only=True "
-            "traced on EVERY process). Behavior is preserved; spell it "
-            "all_hosts=True to trace on every process, or omit the flag "
-            "to trace on the lead process only.",
-            DeprecationWarning,
-            stacklevel=3,
-        )
-        all_hosts = bool(host_only)
+    from ..telemetry import tracing
+
     if all_hosts:
-        with jax.profiler.trace(_per_process_dir(logdir)):
-            yield
+        target = _per_process_dir(logdir)
     elif jax.process_index() == 0:
-        with jax.profiler.trace(logdir):
-            yield
+        target = logdir
     else:  # pragma: no cover - multihost only
         yield
+        return
+    options = (
+        {} if profiler_options is None
+        else {"profiler_options": profiler_options}
+    )
+    tracer = tracing.get_tracer()
+    was_enabled = tracer.enabled
+    tracer.enabled = True
+    try:
+        with jax.profiler.trace(target, **options):
+            yield
+    finally:
+        tracer.enabled = was_enabled
+        try:
+            tracer.export(os.path.join(target, SPANS_FILE))
+        except OSError as exc:  # a logdir only the profiler can write (gs://)
+            print(
+                f"fluxmpi_tpu: span ring not exported beside the capture "
+                f"in {target!r}: {exc!r}",
+                file=sys.stderr,
+            )
 
 
 class AutoProfiler:

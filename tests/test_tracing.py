@@ -602,7 +602,7 @@ def test_merge_traces_produces_loadable_chrome_trace(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Satellites: step_timer sentinel cache, profile_trace flag repair
+# Satellites: step_timer sentinel cache, profile_trace
 # ---------------------------------------------------------------------------
 
 
@@ -623,14 +623,15 @@ def test_step_timer_sentinel_is_cached(world):
     assert holder["seconds"] > 0
 
 
-def test_profile_trace_lead_only_and_deprecated_flag(world, tmp_path, monkeypatch):
+def test_profile_trace_lead_only_and_all_hosts(world, tmp_path, monkeypatch):
     from fluxmpi_tpu.utils import profiling
 
     calls = []
 
     class _FakeTrace:
-        def __init__(self, logdir):
-            calls.append(logdir)
+        def __init__(self, logdir, **kwargs):
+            calls.append((logdir, kwargs))
+            os.makedirs(logdir, exist_ok=True)
 
         def __enter__(self):
             return self
@@ -644,21 +645,20 @@ def test_profile_trace_lead_only_and_deprecated_flag(world, tmp_path, monkeypatc
     # Default: lead process traces (single-process world: that's us).
     with profiling.profile_trace(str(tmp_path / "a")):
         pass
-    assert calls == [str(tmp_path / "a")]
-    # all_hosts=True also traces here.
-    with profiling.profile_trace(str(tmp_path / "b"), all_hosts=True):
+    assert calls == [(str(tmp_path / "a"), {})]
+    # all_hosts=True also traces here; profiler options pass through.
+    with profiling.profile_trace(
+        str(tmp_path / "b"), all_hosts=True, profiler_options="opts"
+    ):
         pass
-    assert len(calls) == 2
-    # The deprecated spelling keeps each caller's old actual behavior
-    # (host_only=True traced everywhere → all_hosts=True) and warns.
-    with pytest.warns(DeprecationWarning, match="host_only"):
+    assert calls[1] == (str(tmp_path / "b"), {"profiler_options": "opts"})
+    # The span ring's export lies beside each capture.
+    for sub in ("a", "b"):
+        assert (tmp_path / sub / profiling.SPANS_FILE).exists()
+    # The deprecated host_only= spelling is gone.
+    with pytest.raises(TypeError):
         with profiling.profile_trace(str(tmp_path / "c"), host_only=True):
             pass
-    assert len(calls) == 3
-    with pytest.warns(DeprecationWarning, match="host_only"):
-        with profiling.profile_trace(str(tmp_path / "d"), host_only=False):
-            pass
-    assert len(calls) == 4  # lead-only, and we are the lead
 
 
 def test_merge_traces_discovers_proc_subdirectories(tmp_path):
