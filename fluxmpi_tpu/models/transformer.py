@@ -75,11 +75,11 @@ class EncoderBlock(nn.Module):
             # ids; ``attention_causal`` folds the causal structure into
             # the kernel so upper-triangle tiles skip compute. Decode:
             # flax's cache-index mask is a trailing valid prefix —
-            # exactly representable by segment ids, which double as the
-            # padding/alias mask over block-table-gathered caches (the
-            # serving engine's paged pool; positions past the cache
-            # index, trash-block rows included, land in segment 0 and
-            # their fully-masked k-tiles are skipped). The decode mask
+            # exactly representable by segment ids (positions past the
+            # cache index land in segment 0 and their fully-masked
+            # k-tiles are skipped). That is generate()'s contiguous
+            # cache; the serving engine's paged pool has its own decode
+            # kernel (ops/paged_attention.py). The decode mask
             # is representable by construction, so the O(s·k) runtime
             # fidelity check is skipped there; training masks arrive
             # from callers and stay checked.
@@ -235,7 +235,15 @@ class TransformerLM(nn.Module):
         per call, ``pos_offset`` (traced int scalar) selects the position
         embedding, the attention layers read/extend their flax KV caches
         (``mutable=["cache"]``), and no causal mask is needed — the cache
-        index provides causality."""
+        index provides causality.
+
+        Without ``decode``, a ``pos_offset`` of shape ``[batch]`` places
+        row ``r``'s tokens at positions ``pos_offset[r] + [0, seq)``: the
+        position embedding is gathered per row. That is the serving
+        engine's paged decode entry (one token a slot, every slot at its
+        own position, the K/V state held by the engine's ``attention_fn``
+        instead of a flax cache). ``pos_offset=None`` is the plain
+        forward, unchanged."""
         embed = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype, name="embed")
         pos = self.param(
             "pos_embed",
@@ -252,7 +260,12 @@ class TransformerLM(nn.Module):
             x = embed(tokens) + pos_slice[None].astype(self.dtype)
             mask = None
         else:
-            x = embed(tokens) + pos[:seq][None, :, :].astype(self.dtype)
+            if pos_offset is None:
+                pos_rows = pos[:seq][None, :, :]
+            else:
+                at = jnp.asarray(pos_offset)[:, None] + jnp.arange(seq)
+                pos_rows = pos[at]
+            x = embed(tokens) + pos_rows.astype(self.dtype)
             # causal mask
             mask = nn.make_causal_mask(tokens)
         x = self.make_encoder()(x, train=train, mask=mask)

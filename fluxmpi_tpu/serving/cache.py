@@ -5,27 +5,36 @@ allocates one contiguous ``[batch, max_len]`` KV cache per call — every
 sequence pays for the longest possible one. A serving engine cannot: a
 mixed workload of 8-token and 500-token requests sharing per-request
 max-len rows wastes most of the pool. :class:`BlockKVCache` is the
-vLLM-style answer scaled to this repo: the flax decode cache's
-``[*, max_len, heads, head_dim]`` axis is cut into fixed-size **blocks**,
+vLLM-style answer scaled to this repo: a sequence's ``max_len`` cache
+positions are cut into fixed-size **blocks**,
 
-- the physical pool is ``[num_layers, num_blocks, block_size, heads,
+- the physical pool is ``[num_layers, num_blocks, block_size, heads *
   head_dim]`` per K and V (device-resident, donated through the decode
-  step so it updates in place);
+  and prefill steps so it updates in place). Heads are folded into the
+  minor dimension: one block of one layer is a contiguous
+  ``[block_size, heads * head_dim]`` tile that the decode kernel reads
+  without a relayout, and a 64-wide head does not pad a 128-lane minor
+  dimension to twice its bytes. :attr:`BlockKVCache.pool_shape` is the
+  one place that says so;
 - a **free-list allocator** hands blocks to sequences at admission and
   takes them back at eviction — a freed block is immediately reusable
   by the next request (the free-list round-trip the serving tests
   assert);
 - each sequence carries a **block table** (``[max_blocks_per_seq]``
   int32 row): logical position ``p`` of the sequence lives at pool slot
-  ``(table[p // block_size], p % block_size)``. The decode step gathers
-  a sequence's blocks into the contiguous layout the flax decode twin
-  expects and scatters the newly written position back (see
+  ``(table[p // block_size], p % block_size)``. The decode step writes
+  the new position's row there and attends **through the table**: the
+  kernel's K/V block index is ``table[j]``, so the pool is read in
+  place and no contiguous per-sequence copy exists
+  (:func:`fluxmpi_tpu.ops.paged_attention.paged_decode_attention`, see
   :mod:`fluxmpi_tpu.serving.engine`).
 
 **Block 0 is the trash block**: it is never allocated. Unused table
 entries point at it, masked prefill positions and idle batch slots
-write into it, and attention's cache-index mask zeroes anything read
-from it — so padding and inactive slots need no special-case shapes.
+write into it, and decode attention never reads it into a result: a
+sequence's length bounds the blocks its kernel visits and masks the
+last one's tail by position (an idle slot has length 0) — so padding
+and inactive slots need no special-case shapes.
 
 Admission is **token-budget based**: a request reserves its worst-case
 ``ceil((prompt + max_new_tokens) / block_size)`` blocks up front, so an
@@ -199,12 +208,14 @@ class BlockKVCache:
 
     @property
     def pool_shape(self) -> tuple[int, ...]:
+        """``[layers, blocks, block_size, heads * head_dim]`` — the one
+        statement of the pool's layout (the prefill's and the decode's
+        ``kv_write``, the decode kernel and :attr:`pool_bytes` follow)."""
         return (
             self.num_layers,
             self.num_blocks,
             self.block_size,
-            self.num_heads,
-            self.head_dim,
+            self.num_heads * self.head_dim,
         )
 
     @property

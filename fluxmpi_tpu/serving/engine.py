@@ -1,12 +1,13 @@
-"""Continuous-batching inference engine over the TransformerLM decode twin.
+"""Continuous-batching inference engine: TransformerLM over a paged K/V pool.
 
 The serving plane's core loop (ROADMAP open item 1 — the "millions of
 users" leg): an Orca-style **continuous-batching** scheduler where new
 requests join the in-flight decode batch *between* iterations, built
-from the pieces this repo already has — the decode twin of
-:mod:`fluxmpi_tpu.models.generate`, the batched prefill kernel
+from the pieces this repo already has — the model's own blocks, the
+batched prefill kernel
 (:func:`~fluxmpi_tpu.models.generate.prefill_kv`), the paged
-:class:`~fluxmpi_tpu.serving.cache.BlockKVCache`, the ``serving.*``
+:class:`~fluxmpi_tpu.serving.cache.BlockKVCache` and the decode kernel
+that reads it through the block tables, the ``serving.*``
 telemetry namespace, the watchdog's progress clock (``/healthz`` covers
 a stuck decode), and the fault plane (``serving.admit`` /
 ``serving.decode`` chaos sites, SIGTERM drain).
@@ -20,16 +21,20 @@ Phase split:
   rounded up to a block multiple) — a handful of shapes, warmed by
   :meth:`InferenceEngine.warmup`.
 - **decode** — ONE fixed-shape jitted step per engine iteration runs
-  every active batch slot one token forward: gather each slot's blocks
-  into the contiguous cache layout the flax decode twin expects, run
-  the twin per slot (vmapped, so every slot carries its *own* cache
-  index/position — heterogeneous sequence states in one dispatch),
-  scatter the newly written K/V position back into the pool, and
-  return the argmax tokens. Shapes depend only on the engine geometry
-  ``(slots, max_blocks_per_seq, block_size)`` — never on which
-  requests are active — so **requests join and leave the batch with
-  zero retrace** (the compile monitor asserts this in the tests and
-  the bench).
+  every active batch slot one token forward: the model runs ONCE over
+  ``[slots, 1]`` tokens, every slot at its own position (the position
+  embedding is a gather), through its own blocks; each layer's
+  attention writes the new token's K/V row into the donated pool at
+  ``(table[pos // block_size], pos % block_size)`` and then reads the
+  slot's keys and values **in place, block by block through the block
+  table** (:func:`fluxmpi_tpu.ops.paged_attention.paged_decode_attention`;
+  ``lengths = position + 1``, 0 for an idle slot, whose table is all
+  trash). No per-slot copy of a cache is built and no flax cache is
+  rebuilt; the argmax tokens come back. Shapes depend only on the
+  engine geometry ``(slots, max_blocks_per_seq, block_size)`` — never
+  on which requests are active — so **requests join and leave the
+  batch with zero retrace** (the compile monitor asserts this in the
+  tests and the bench).
 
 The decode loop is **host-driven** (``lax.scan``-free): one dispatch +
 one small device→host token transfer per iteration, with eviction,
@@ -398,6 +403,62 @@ class _Slot:
         self.generated = 0
 
 
+class _PagedDecodeAttention:
+    """The decode program's ``attention_fn``: the K/V state of one traced
+    decode step. flax's attention sublayer hands it the new token's
+    ``query`` / ``key`` / ``value`` (``[slots, 1, heads, head_dim]``, from
+    the model's own ``attn/{query,key,value}`` projections); each call —
+    one per layer, in layer order — writes the key and value rows into
+    the pools at ``(table[pos // block_size], pos % block_size)`` and then
+    attends through the block tables. Idle slots carry all-trash tables:
+    their rows land in the trash block and their length is 0. The step
+    reads the updated pools back from :attr:`k_pool` / :attr:`v_pool`."""
+
+    def __init__(self, k_pool, v_pool, tables, positions, block_size: int,
+                 kernel: bool):
+        import jax.numpy as jnp
+
+        self.k_pool, self.v_pool = k_pool, v_pool
+        self.tables = tables
+        self.block = jnp.take_along_axis(
+            tables, (positions // block_size)[:, None], axis=1
+        )[:, 0]
+        self.offset = positions % block_size
+        self.lengths = jnp.where(
+            tables[:, 0] != TRASH_BLOCK, positions + 1, 0
+        )
+        self.kernel = kernel
+        self.layer = 0
+
+    def __call__(self, query, key, value):
+        import jax
+
+        from ..ops.paged_attention import (
+            paged_decode_attention,
+            paged_decode_reference,
+        )
+
+        layer, self.layer = self.layer, self.layer + 1
+        slots = query.shape[0]
+        with jax.named_scope("kv_write"):
+            rows = (layer, self.block, self.offset)
+            self.k_pool = self.k_pool.at[rows].set(
+                key.reshape(slots, -1).astype(self.k_pool.dtype)
+            )
+            self.v_pool = self.v_pool.at[rows].set(
+                value.reshape(slots, -1).astype(self.v_pool.dtype)
+            )
+        attend = (
+            paged_decode_attention if self.kernel else paged_decode_reference
+        )
+        with jax.named_scope("decode_attention"):
+            out = attend(
+                query[:, 0], self.k_pool, self.v_pool, self.tables,
+                self.lengths, layer=layer,
+            )
+        return out[:, None]
+
+
 # ---------------------------------------------------------------------------
 # Engine
 # ---------------------------------------------------------------------------
@@ -408,8 +469,8 @@ class InferenceEngine:
 
     Args:
       model: a :class:`~fluxmpi_tpu.models.TransformerLM` (training
-        configuration — the decode twin is derived internally, exactly
-        like :func:`~fluxmpi_tpu.models.generate`).
+        configuration — the prefill and decode configurations are
+        derived internally).
       params: its variables (``{"params": ...}``).
       slots: static decode batch width (default: ``init(serving=)`` /
         ``FLUXMPI_TPU_SERVING_SLOTS`` / 8). The decode step's shapes
@@ -439,11 +500,12 @@ class InferenceEngine:
         model's kernel-plane switch for prefill and the paged decode
         step (default: ``init(serving=)`` /
         ``FLUXMPI_TPU_SERVING_ATTENTION`` / inherit the model's). With
-        ``"flash"`` the decode twin reads the block-table-gathered K/V
-        through the flash kernel's segment ids — positions past the
-        cache index (trash-block rows included) mask out and skip
-        compute — while the step stays one fixed-shape program (the
-        no-retrace join contract is unchanged).
+        ``"flash"`` the decode step reads the pool through the paged
+        Pallas kernel (blocks past a slot's length do no work, the last
+        block's tail and the trash block mask out by position); with
+        ``"naive"`` through its plain ``jax.numpy`` reference. Either
+        way the step stays one fixed-shape program (the no-retrace join
+        contract is unchanged).
 
     The engine registers itself as the module's active engine
     (:func:`get_engine`) so the live export plane's ``/status`` board
@@ -469,20 +531,11 @@ class InferenceEngine:
         check_memory: bool = True,
         attention: str | None = None,
     ):
-        import jax.numpy as jnp
-
-        from ..models.generate import _decode_twin, cache_template
-
         cfg = _config or ServingConfig()
         # attention="flash"|"naive"|"auto" overrides the model's own
         # kernel-plane switch for BOTH serving hot paths (bucketed
-        # prefill and the vmapped paged decode): the decode twin's flash
-        # kernel reads the block-table-gathered K/V through segment ids
-        # recovered from flax's cache-index mask, so trash-block/alias
-        # positions are masked (and their fully-masked tiles skipped)
-        # with no extra plumbing, and the step stays one fixed-shape
-        # program — mid-flight joins still retrace nothing. None (the
-        # default) inherits whatever the model was built with.
+        # prefill and the paged decode). None (the default) inherits
+        # whatever the model was built with.
         mode = attention if attention is not None else (
             cfg.attention if cfg.attention is not None
             else os.environ.get(_ENV_ATTENTION) or None
@@ -543,25 +596,15 @@ class InferenceEngine:
                 "expert capacity when serving such checkpoints",
                 stacklevel=2,
             )
-        self._twin = _decode_twin(model)
-        head_dim = int(model.d_model) // int(model.num_heads)
-        # The cache template fixes the decode-time dtype and tree shape
-        # (one slot, full table width) — the decode step rebuilds the
-        # flax cache from the pool through it every dispatch.
-        self._tmpl = cache_template(self._twin, 1, self.max_len)
-        dtype = None
-        for path, leaf in self._flat_tmpl():
-            if path[-1].key == "cached_key":
-                dtype = leaf.dtype
-                break
         self.cache = BlockKVCache(
             num_layers=int(model.num_layers),
             num_heads=int(model.num_heads),
-            head_dim=head_dim,
+            head_dim=int(model.d_model) // int(model.num_heads),
             num_blocks=nb,
             block_size=self.block_size,
             max_blocks_per_seq=self.max_blocks_per_seq,
-            dtype=dtype if dtype is not None else jnp.float32,
+            # The attention sublayer computes K and V in the model's dtype.
+            dtype=model.dtype,
         )
         if check_memory:
             fits, detail = self.cache.fits_device()
@@ -596,6 +639,10 @@ class InferenceEngine:
         self._slot_steps_active = 0
         self._admissions = 0
         self._evictions = 0
+        # Blocks the decode kernel read (live positions only) against the
+        # blocks the slots' tables span, both summed over decode ticks.
+        self._kv_blocks_live = 0
+        self._kv_blocks_tabled = 0
         # Registry-counter delta baselines (see _resolve_run).
         self._counted_steps = 0
         self._counted_tokens = 0
@@ -611,11 +658,6 @@ class InferenceEngine:
 
     # -- small helpers -------------------------------------------------
 
-    def _flat_tmpl(self):
-        import jax
-
-        return jax.tree_util.tree_flatten_with_path(self._tmpl)[0]
-
     @staticmethod
     def _compile_monitor():
         from ..telemetry.compileplane import get_compile_monitor
@@ -630,82 +672,37 @@ class InferenceEngine:
     # -- compiled steps ------------------------------------------------
 
     def _build_decode_step(self):
-        """ONE fixed-shape program advancing every slot a token: gather
-        each slot's pool blocks into the contiguous flax cache layout,
-        run the decode twin per slot (vmapped — per-slot cache index),
-        scatter the written position back, argmax the next tokens."""
+        """ONE fixed-shape program advancing every slot a token: the
+        model once over ``[slots, 1]`` tokens at per-slot positions,
+        attention through :class:`_PagedDecodeAttention` (each layer
+        writes its new K/V row into the donated pool, then reads the
+        slot's blocks in place), argmax the next tokens."""
         import jax
         import jax.numpy as jnp
 
-        from ..models.generate import layer_index
-        from ..ops.flash_attention import attention_scope
+        from ..models.transformer import _resolve_attention_mode
 
-        twin = self._twin
-        tmpl = self._tmpl
+        model = self.model
         bs = self.block_size
-        nslots = self.slots
-        t_total = self.max_len
-
-        def one(params_tree, tok, pos, k_sl, v_sl):
-            # k_sl/v_sl: [layers, t_total, heads, head_dim] — this
-            # slot's gathered cache; pos is ITS cache index.
-            def fill(path, leaf):
-                name = path[-1].key
-                if name == "cached_key":
-                    return k_sl[layer_index(path)][None]
-                if name == "cached_value":
-                    return v_sl[layer_index(path)][None]
-                if name == "cache_index":
-                    return pos.astype(leaf.dtype)
-                return jnp.zeros(leaf.shape, leaf.dtype)
-
-            cache = jax.tree_util.tree_map_with_path(fill, tmpl)
-            logits, mut = twin.apply(
-                {"params": params_tree, "cache": cache},
-                tok[None, None], train=False, pos_offset=pos,
-                mutable=["cache"],
-            )
-            knew, vnew = [], []
-            for path, leaf in jax.tree_util.tree_flatten_with_path(
-                mut["cache"]
-            )[0]:
-                name = path[-1].key
-                if name not in ("cached_key", "cached_value"):
-                    continue
-                written = jax.lax.dynamic_slice_in_dim(
-                    leaf[0], pos, 1, axis=0
-                )[0]  # [heads, head_dim]
-                (knew if name == "cached_key" else vnew).append(
-                    (layer_index(path), written)
-                )
-            knew = jnp.stack([w for _, w in sorted(knew, key=lambda t: t[0])])
-            vnew = jnp.stack([w for _, w in sorted(vnew, key=lambda t: t[0])])
-            nxt = jnp.argmax(logits[0, -1], axis=-1).astype(jnp.int32)
-            return nxt, knew, vnew
+        kernel = _resolve_attention_mode(model.attention) == "flash"
 
         def step(params, k_pool, v_pool, tables, positions, tokens):
             # tables: [slots, max_blocks]; positions/tokens: [slots].
-            with jax.named_scope("kv_gather"):
-                k_g = jnp.moveaxis(k_pool[:, tables], 1, 0).reshape(
-                    nslots, -1, t_total, k_pool.shape[3], k_pool.shape[4]
-                )
-                v_g = jnp.moveaxis(v_pool[:, tables], 1, 0).reshape(
-                    nslots, -1, t_total, v_pool.shape[3], v_pool.shape[4]
-                )
-            with attention_scope("decode_attention"):
-                nxt, knew, vnew = jax.vmap(
-                    one, in_axes=(None, 0, 0, 0, 0)
-                )(params["params"], tokens, positions, k_g, v_g)
-            with jax.named_scope("kv_write"):
-                blk = jnp.take_along_axis(
-                    tables, (positions // bs)[:, None], axis=1
-                )[:, 0]
-                off = positions % bs
-                # Idle slots carry all-trash tables, so their writes land
-                # in block 0 — no masking, no shape change.
-                k_pool = k_pool.at[:, blk, off].set(jnp.moveaxis(knew, 0, 1))
-                v_pool = v_pool.at[:, blk, off].set(jnp.moveaxis(vnew, 0, 1))
-            return nxt, k_pool, v_pool
+            attend = _PagedDecodeAttention(
+                k_pool, v_pool, tables, positions, bs, kernel
+            )
+            # The model's own blocks (make_ff included) around the paged
+            # attention; K/V state lives in the pool, not in a flax cache.
+            paged = model.clone(
+                decode=False, attention="naive", attention_fn=attend,
+                dropout=0.0,
+            )
+            logits = paged.apply(
+                {"params": params["params"]}, tokens[:, None], train=False,
+                pos_offset=positions,
+            )
+            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            return nxt, attend.k_pool, attend.v_pool
 
         return jax.jit(step, donate_argnums=(1, 2))
 
@@ -731,15 +728,20 @@ class InferenceEngine:
             with attention_scope("prefill_attention"):
                 k, v, logits = prefill_kv(model, params, tokens[None])
             with jax.named_scope("kv_write"):
-                k = k[:, 0]  # [layers, bucket, heads, head_dim]
-                v = v[:, 0]
+                # [layers, bucket, heads * head_dim]: the pool's row.
+                k = k[:, 0].reshape(k.shape[0], k.shape[2], -1)
+                v = v[:, 0].reshape(v.shape[0], v.shape[2], -1)
                 pos = jnp.arange(tokens.shape[0])
                 blk = jnp.where(
                     pos < length, table[pos // bs], jnp.int32(TRASH_BLOCK)
                 )
-                off = pos % bs
-                k_pool = k_pool.at[:, blk, off].set(k.astype(k_pool.dtype))
-                v_pool = v_pool.at[:, blk, off].set(v.astype(v_pool.dtype))
+                # One row per (layer, position), indexed on every leading
+                # dimension: a window over the layers makes XLA move the
+                # whole pool into a layers-minor layout and back.
+                layers = jnp.arange(k.shape[0])[:, None]
+                rows = (layers, blk[None], (pos % bs)[None])
+                k_pool = k_pool.at[rows].set(k.astype(k_pool.dtype))
+                v_pool = v_pool.at[rows].set(v.astype(v_pool.dtype))
             last = jax.lax.dynamic_index_in_dim(
                 logits[0], length - 1, axis=0, keepdims=False
             )
@@ -969,16 +971,23 @@ class InferenceEngine:
         mb = self.max_blocks_per_seq
         step = self._decode_steps
         active = self._active
-        with _tracing.span("serve.decode.prepare", active=active):
+        with _tracing.span("serve.decode.prepare", active=active) as prep:
             tables = np.zeros((self.slots, mb), np.int32)
             positions = np.zeros((self.slots,), np.int32)
             tokens = np.zeros((self.slots,), np.int32)
+            live = 0  # blocks the decode kernel reads this tick
             for i, slot in enumerate(self._slots):
                 if slot is None:
                     continue
                 tables[i] = slot.table
                 positions[i] = slot.position
                 tokens[i] = slot.last_token
+                live += blocks_for_tokens(slot.position + 1, self.block_size)
+            self._kv_blocks_live += live
+            self._kv_blocks_tabled += self.slots * mb
+            prep.set_metadata(
+                live_blocks_pct=100.0 * live / (self.slots * mb)
+            )
             tables = jnp.asarray(tables)
             positions = jnp.asarray(positions)
             tokens = jnp.asarray(tokens)
@@ -1071,7 +1080,12 @@ class InferenceEngine:
         (delivered, first tokens included), ``slot_steps_active`` (slots
         holding a request, summed over decode steps: over ``decode_steps
         * slots`` it is the occupancy), ``admissions`` and ``evictions``
-        (requests prefilled into a slot / finished out of one). Plain
+        (requests prefilled into a slot / finished out of one),
+        ``kv_blocks_live`` (pool blocks the decode attention read: ``ceil(
+        (position + 1) / block_size)`` of every active slot, summed over
+        decode steps) and ``kv_blocks_tabled`` (``slots *
+        max_blocks_per_seq`` a step: what the slots' tables span; the
+        ratio is the share of the reserved cache a tick touches). Plain
         ints the loop keeps anyway; safe to read from another thread."""
         return {
             "decode_steps": self._decode_steps,
@@ -1079,6 +1093,8 @@ class InferenceEngine:
             "slot_steps_active": self._slot_steps_active,
             "admissions": self._admissions,
             "evictions": self._evictions,
+            "kv_blocks_live": self._kv_blocks_live,
+            "kv_blocks_tabled": self._kv_blocks_tabled,
         }
 
     @property

@@ -398,7 +398,7 @@ HOT_PATH_SPAN_ARGS: dict[str, tuple[str, ...]] = {
     "serve.iteration": ("active", "queued"),
     "serve.admit": ("request_id", "prompt_tokens", "bucket", "active"),
     "serve.prefill": ("request_id", "bucket"),
-    "serve.decode.prepare": ("active",),
+    "serve.decode.prepare": ("active", "live_blocks_pct"),
     "serve.decode.dispatch": ("step",),
     "serve.decode.fetch": ("step",),
     "serve.decode.deliver": ("step", "tokens", "evicted"),

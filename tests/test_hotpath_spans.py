@@ -113,7 +113,7 @@ def _named(spans, name):
 
 
 def _int_args(span):
-    return {k: int(v) for k, v in span[3].items()}
+    return {k: int(float(v)) for k, v in span[3].items()}
 
 
 # ---------------------------------------------------------------------------
@@ -267,6 +267,19 @@ def test_engine_spans(tiny_lm, capture, request):
         stats["slot_steps_active"]
     )
     assert sum(_int_args(s)["evicted"] for s in ticks["deliver"]) == len(ids)
+    # live_blocks_pct: the blocks the decode kernel read this tick over
+    # the blocks the slots' tables span (2 slots x 8 blocks of 8 here);
+    # summed back over the ticks it is stats()'s pair of counters.
+    tabled = 2 * 8
+    assert stats["kv_blocks_tabled"] == tabled * stats["decode_steps"]
+    shares = [float(s[3]["live_blocks_pct"]) for s in ticks["prepare"]]
+    assert sum(shares) * tabled / 100.0 == pytest.approx(
+        stats["kv_blocks_live"]
+    )
+    # Prompts of 5 and answers of 5: positions 5..8, so one block a slot
+    # until position 8 opens the second.
+    assert max(shares) == pytest.approx(100.0 * 4 / tabled)
+    assert min(shares) == pytest.approx(100.0 * 1 / tabled)
     chain = _named(spans, "request.prefill")
     if request.node.callspec.params["capture"] == "ring":
         # The ring also holds the request chain (written when a request
@@ -286,7 +299,11 @@ def test_engine_stats_agree_with_delivery(tiny_lm):
     assert stats["slot_steps_active"] == stats["tokens"] - stats["admissions"]
     assert 0 < stats["slot_steps_active"] <= 2 * stats["decode_steps"]
     assert set(stats) == {"decode_steps", "tokens", "slot_steps_active",
-                          "admissions", "evictions"}
+                          "admissions", "evictions", "kv_blocks_live",
+                          "kv_blocks_tabled"}
+    # 3 requests x 4 decode steps at positions 5..8 of 8-token blocks:
+    # one live block each, two at position 8.
+    assert stats["kv_blocks_live"] == 3 * (1 + 1 + 1 + 2)
     assert all(type(v) is int for v in stats.values())
 
 
@@ -394,17 +411,17 @@ def test_span_contract_names_and_arguments(name):
 # ---------------------------------------------------------------------------
 
 
-def _engine_program_text(tiny_lm, which):
+def _engine_program_text(tiny_lm, which, slots=2, attention="flash"):
     lm, variables = tiny_lm
-    engine = InferenceEngine(lm, variables, slots=2, block_size=8,
-                             attention="flash")
+    engine = InferenceEngine(lm, variables, slots=slots, block_size=8,
+                             attention=attention)
     try:
         cache = engine.cache
         if which == "decode":
             lowered = engine._decode_step.lower(
                 variables, cache.k_pool, cache.v_pool,
-                jnp.zeros((2, engine.max_blocks_per_seq), jnp.int32),
-                jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
+                jnp.zeros((slots, engine.max_blocks_per_seq), jnp.int32),
+                jnp.zeros((slots,), jnp.int32), jnp.zeros((slots,), jnp.int32),
             )
         else:
             lowered = engine._prefill_step(8).lower(
@@ -440,7 +457,7 @@ def _train_program_text(which):
 
 
 @pytest.mark.parametrize("program,scope", [
-    ("decode", "kv_gather"), ("decode", "kv_write"),
+    ("decode", "kv_write"),
     ("decode", "decode_attention"), ("prefill", "kv_write"),
     ("prefill", "prefill_attention"), ("step", "ce_head"),
     ("step", "optimizer_update"), ("window", "batch_gather"),
@@ -457,6 +474,32 @@ def test_compiled_programs_carry_the_programs_scope_names(
     # (relative inside a scan's body, inside jvp(...) under a gradient).
     assert re.search(rf'[/("]{scope}[/)]', text), scope
     if scope.endswith("_attention"):
-        # Outside jit(flash_attention), whose name the chip's compiler
+        # Outside the kernel's own jit, whose name the chip's compiler
         # gives the kernels' instructions.
-        assert f"{scope}/jit(flash_attention)" in text
+        kernel = ("paged_decode_attention" if program == "decode"
+                  else "flash_attention")
+        assert f"{scope}/jit({kernel})" in text
+
+
+def test_decode_program_builds_no_per_slot_cache(world):
+    """The decode program reads the pool through the block tables: with
+    the kernel no value in the lowered program has a ``slots x ... x
+    max_len`` (or ``slots x max_blocks x block_size``) K/V shape, and the
+    scopes around what it does are still there. Sizes no other dimension
+    of the model shares: 3 slots, 5 blocks of 8 = 40 positions."""
+    lm = TransformerLM(vocab_size=32, max_len=40, num_layers=2, d_model=24,
+                       num_heads=2, d_ff=48)
+    variables = lm.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32), train=False
+    )
+    per_slot = re.compile(r"tensor<3x(\d+x)*40x|tensor<3x5x8x")
+    text = _engine_program_text((lm, variables), "decode", slots=3)
+    assert not per_slot.search(text), per_slot.search(text).group(0)
+    for scope in ("kv_write", "decode_attention"):
+        assert re.search(rf'[/("]{scope}[/)]', text), scope
+    assert "kv_gather" not in text
+    # The plain reference gathers one layer's tabled blocks: the pattern
+    # does see such a shape where there is one.
+    naive = _engine_program_text((lm, variables), "decode", slots=3,
+                                 attention="naive")
+    assert per_slot.search(naive)

@@ -1,0 +1,100 @@
+"""The paged decode attention kernel (interpret mode here) against its
+plain ``jax.numpy`` reference: lengths, table order, trash and garbage."""
+
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+from fluxmpi_tpu.ops.paged_attention import (
+    paged_decode_attention,
+    paged_decode_reference,
+)
+from fluxmpi_tpu.serving.cache import TRASH_BLOCK
+
+BLOCK = 8
+MAX_BLOCKS = 4
+HEADS = 3
+LAYERS = 2
+GARBAGE = 1e4
+
+# The slot under test: idle, one position, a block's edge, one past it,
+# mid-block, the whole table.
+LENGTHS = {"idle": 0, "one": 1, "edge": BLOCK, "past_edge": BLOCK + 1,
+           "mid_block": 2 * BLOCK + 3, "full_table": MAX_BLOCKS * BLOCK}
+
+
+def _case(length, head_dim, dtype, seed):
+    """Five slots over a pool full of garbage: the slot under test, an
+    idle slot (length 0, all-trash table) and three of other lengths;
+    blocks handed out in scrambled order; the tables' tails padded with
+    the trash block; garbage in the trash block, in every block no table
+    names and in the rows past each length."""
+    rng = np.random.default_rng(seed)
+    lengths = np.array([length, 0, 5, 2 * BLOCK, 3 * BLOCK + 1], np.int32)
+    width = HEADS * head_dim
+    num_blocks = 1 + len(lengths) * MAX_BLOCKS + 3
+    pools = np.full((2, LAYERS, num_blocks, BLOCK, width), GARBAGE,
+                    np.float32)
+    order = iter(rng.permutation(np.arange(1, num_blocks)))
+    tables = np.full((len(lengths), MAX_BLOCKS), TRASH_BLOCK, np.int32)
+    for slot, n in enumerate(lengths):
+        for j in range(-(-int(n) // BLOCK)):
+            block = tables[slot, j] = next(order)
+            rows = min(BLOCK, int(n) - j * BLOCK)
+            pools[:, :, block, :rows] = rng.normal(
+                size=(2, LAYERS, rows, width)
+            )
+    q = rng.normal(size=(len(lengths), HEADS, head_dim))
+    return (jnp.asarray(q, dtype), jnp.asarray(pools[0], dtype),
+            jnp.asarray(pools[1], dtype), jnp.asarray(tables),
+            jnp.asarray(lengths))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("length", sorted(LENGTHS), ids=sorted(LENGTHS))
+def test_paged_decode_attention_matches_reference(length, head_dim, dtype):
+    args = _case(LENGTHS[length], head_dim, dtype, seed=len(length))
+    layer = 1
+    got = paged_decode_attention(*args, layer=layer).astype(jnp.float32)
+    want = paged_decode_reference(*args, layer=layer).astype(jnp.float32)
+    assert got.shape == (5, HEADS, head_dim)
+    # No garbage got through: outputs are averages of unit normals.
+    assert float(jnp.max(jnp.abs(got))) < 10.0
+    # bf16: both round one float32 result to the output's 8 bits.
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    # Idle slots (length 0) output zeros; so does the slot under test
+    # when it is one.
+    lengths = np.asarray(args[4])
+    np.testing.assert_array_equal(
+        np.asarray(got)[lengths == 0], 0.0
+    )
+    # The other layer's rows are not read: an independent dense softmax
+    # over slot 0's own positions of THIS layer gives the same answer.
+    n = int(lengths[0])
+    if n:
+        q, k_pool, v_pool, tables = (np.asarray(a, np.float32)
+                                     for a in args[:4])
+        blocks = np.asarray(args[3])[0, : -(-n // BLOCK)]
+        keys = k_pool[layer, blocks].reshape(-1, HEADS, head_dim)[:n]
+        values = v_pool[layer, blocks].reshape(-1, HEADS, head_dim)[:n]
+        scores = np.einsum("hd,thd->ht", q[0], keys) / np.sqrt(head_dim)
+        probs = np.exp(scores - scores.max(axis=1, keepdims=True))
+        probs /= probs.sum(axis=1, keepdims=True)
+        np.testing.assert_allclose(
+            np.asarray(got)[0], np.einsum("ht,thd->hd", probs, values),
+            rtol=5 * tol, atol=5 * tol,
+        )
+
+
+def test_paged_decode_attention_rejects_mismatched_shapes():
+    q, k_pool, v_pool, tables, lengths = _case(3, 64, jnp.float32, seed=0)
+    with pytest.raises(ValueError, match="heads \\* head_dim"):
+        paged_decode_attention(q[:, :2], k_pool, v_pool, tables, lengths)
+    with pytest.raises(ValueError, match="tables must be"):
+        paged_decode_reference(q, k_pool, v_pool, tables[:2], lengths)
+    with pytest.raises(ValueError, match="layer 2 outside"):
+        paged_decode_attention(q, k_pool, v_pool, tables, lengths, layer=2)

@@ -250,16 +250,15 @@ def test_flash_decode_bit_identical_with_midflight_join(model, engine_factory):
 
 
 def test_flash_decode_masks_trash_block_garbage(model, engine_factory):
-    """The segment-ids mask doubles as the padding/alias mask over the
-    block-table-gathered cache: every gathered row past a request's
-    cache index — trash-block rows included — lands in segment 0 and
-    must not contaminate the output. Poison the reserved trash block
-    (block 0) with large finite garbage (stale K/V is what it really
-    holds after warmup); greedy streams must stay bit-identical to
-    ``generate()``, which never sees a paged pool at all. (The sharper
-    NaN variant that PROVES fully-masked tiles skip compute lives at
-    the adapter level: test_ops.py
-    test_flash_fn_decode_prefix_mask_skips_garbage_tiles.)"""
+    """The paged decode kernel reads the pool through the block tables:
+    a table's unused entries point at the trash block and a request's
+    last block holds stale rows past its length — neither may
+    contaminate the output. Poison the reserved trash block (block 0)
+    with large finite garbage (stale K/V is what it really holds after
+    warmup); greedy streams must stay bit-identical to ``generate()``,
+    which never sees a paged pool at all. (The kernel-level variant,
+    garbage in every block no table names and past every length:
+    test_paged_attention.py.)"""
     lm, variables = model
     eng = engine_factory(slots=2, attention="flash")
     eng.warmup(prompt_lengths=(4, 6))
@@ -280,6 +279,41 @@ def test_flash_decode_masks_trash_block_garbage(model, engine_factory):
             generate(lm, variables, jnp.asarray(req.prompt[None]), mnew)
         )[0][plen:]
         np.testing.assert_array_equal(toks, ref)
+
+
+@pytest.mark.parametrize("attention", ["naive", "flash"])
+def test_moe_lm_serves_through_the_same_decode_program(world, attention):
+    """The decode program runs the model's own blocks, so a subclass's
+    ``make_ff`` (the MoE block) serves unchanged: every slot's token is
+    its own routing group, as under ``generate()``'s one-token ticks.
+    With ample capacity the greedy streams equal ``generate()``'s."""
+    from fluxmpi_tpu.models.moe import MoETransformerLM
+
+    lm = MoETransformerLM(vocab_size=32, max_len=64, num_layers=2,
+                          d_model=32, num_heads=4, d_ff=64, num_experts=4,
+                          capacity_factor=4.0)
+    variables = lm.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32), train=False
+    )
+    with pytest.warns(UserWarning, match="batched_prefill_safe"):
+        eng = InferenceEngine(lm, variables, slots=3, block_size=8,
+                              attention=attention)
+    try:
+        rng = np.random.default_rng(0)
+        reqs = [eng.submit(_prompt(rng, plen), mnew)
+                for plen, mnew in ((5, 7), (9, 4), (3, 10), (12, 6))]
+        assert eng.run()["completed"] == len(reqs)
+        for req in reqs:
+            ref = np.asarray(generate(
+                lm, variables, jnp.asarray(req.prompt[None]),
+                req.max_new_tokens, prefill="batched",
+            ))[0][len(req.prompt):]
+            np.testing.assert_array_equal(
+                np.asarray(req.tokens, np.int32), ref
+            )
+    finally:
+        eng.close()
+        serving.shutdown()
 
 
 def test_engine_attention_option_validation(model):

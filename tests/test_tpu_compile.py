@@ -133,6 +133,84 @@ def test_flash_shapes_compile_for_v5e(topo, name, q_shape, kv_shape, grad):
     assert text.count("tpu_custom_call") == (3 if grad else 1)
 
 
+# name -> (slots, heads, head_dim, layers, pool blocks, block_size,
+# table width, dtype): the benchmark's serve cell (GPT-2 medium, 32 slots
+# of 8 blocks of 128), the smoke's engine (GPT-2 small at the engine's
+# default 16-token blocks), a 128-wide head, a float32 pool.
+_PAGED = {
+    "gpt2m_cell": (32, 16, 64, 24, 257, 128, 8, jnp.bfloat16),
+    "gpt2s_smoke": (8, 12, 64, 12, 513, 16, 64, jnp.bfloat16),
+    "d128": (8, 8, 128, 2, 33, 32, 4, jnp.bfloat16),
+    "f32": (8, 16, 64, 2, 33, 128, 4, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_PAGED))
+def test_paged_decode_kernel_compiles_for_v5e(topo, name):
+    from fluxmpi_tpu.ops.paged_attention import paged_decode_attention
+
+    slots, heads, d, layers, blocks, bs, mb, dtype = _PAGED[name]
+    dev = topo.devices[0]
+    pool = _sds((layers, blocks, bs, heads * d), dtype, dev)
+
+    def attend(q, k_pool, v_pool, tables, lengths):
+        return paged_decode_attention(
+            q, k_pool, v_pool, tables, lengths, layer=layers - 1,
+            interpret=False,
+        )
+
+    compiled = jax.jit(attend).lower(
+        _sds((slots, heads, d), dtype, dev), pool, pool,
+        _sds((slots, mb), jnp.int32, dev), _sds((slots,), jnp.int32, dev),
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    # The pools are read where they lie: nothing pool-sized is made.
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def test_gpt2_medium_serving_programs_compile_for_v5e(topo, as_on_tpu):
+    """The serve cell's decode program and a prefill bucket — GPT-2
+    medium, 32 slots x 1,024 positions in 128-token blocks, bf16 pools
+    of 1.6 GB each — hold one paged kernel a layer and no copy of a pool
+    or of a slot's reserved cache (the programs they replace held 15.3 GB
+    and 3.3 GB of temporaries)."""
+    from fluxmpi_tpu.serving import InferenceEngine
+
+    cfg = {**chip_smoke.GPT2_SMALL, "num_layers": 24, "d_model": 1024,
+           "num_heads": 16, "d_ff": 4096}
+    dev = topo.devices[0]
+    model = chip_smoke._lm(cfg)
+    params = jax.tree_util.tree_map(
+        lambda x: _sds(x.shape, x.dtype, dev),
+        jax.eval_shape(
+            lambda key: model.init(
+                key, jnp.zeros((1, 8), jnp.int32), train=False
+            ),
+            jax.random.PRNGKey(0),
+        ),
+    )
+    engine = InferenceEngine(model, params, attention="flash", slots=32,
+                             block_size=128, check_memory=False)
+    try:
+        pool = _sds(engine.cache.pool_shape, jnp.bfloat16, dev)
+        mb = engine.max_blocks_per_seq
+        decode = engine._decode_step.lower(
+            params, pool, pool, _sds((32, mb), jnp.int32, dev),
+            _sds((32,), jnp.int32, dev), _sds((32,), jnp.int32, dev),
+        ).compile()
+        prefill = engine._prefill_step(256).lower(
+            params, pool, pool, _sds((256,), jnp.int32, dev),
+            _sds((), jnp.int32, dev), _sds((mb,), jnp.int32, dev),
+        ).compile()
+    finally:
+        engine.close()
+    assert decode.as_text().count("tpu_custom_call") == cfg["num_layers"]
+    for program in (decode, prefill):
+        memory = program.memory_analysis()
+        assert memory.temp_size_in_bytes < 2**29
+        assert memory.alias_size_in_bytes >= 2 * 1.6e9  # pools in place
+
+
 def _lm_state(cfg, optimizer):
     model = chip_smoke._lm(cfg)
     params = jax.eval_shape(
