@@ -1,0 +1,47 @@
+"""The grouped expert matmuls' share of their (bandwidth) roofline in the
+decode ticks: the bytes of the routed experts' weights a tick HAS to read
+(the (layer, expert) cells that received a token, from
+``experts_touched_pct`` of the ``serve.decode.deliver`` spans, each
+expert's three matrices once) over the chip's HBM bandwidth, over the
+device time of the operations matching ``pattern`` that ran inside an
+execution of the decode program (``module``). The shared expert and the
+router are left out on both sides: today's traces cannot tell their
+operations from other fusions. Returns None where the trace holds no
+such span or operation (a program without expert layers)."""
+
+import json
+import re
+import statistics
+
+from harness import moebytes, spans as spans_mod
+
+
+def read(ctx, pattern, module):
+    trace, peaks = ctx.get("trace"), ctx["peaks"]
+    if trace is None or peaks is None:
+        return None
+    loaded = spans_mod.for_cell(ctx)
+    host = spans_mod.whole(loaded["host"], loaded["window_ns"])
+    touched = [float(s[4]["experts_touched_pct"])
+               for s in spans_mod.named(host, "serve.decode.deliver")
+               if "experts_touched_pct" in s[4]]
+    ticks = sorted((start, start + dur) for name, start, dur
+                   in trace["modules"] if re.search(module, name))
+    if not touched or not ticks:
+        return None
+    rx = re.compile(pattern)
+    took, at = 0.0, 0
+    for name, _, start, dur in sorted(trace["rows"], key=lambda r: r[2]):
+        while at < len(ticks) and ticks[at][1] <= start:
+            at += 1
+        if at < len(ticks) and ticks[at][0] <= start and rx.search(name):
+            took += dur / 1e9
+    if not took:
+        return None
+    cfg = ctx["cell"].config
+    per_tick = moebytes.touched_expert_bytes(cfg, statistics.fmean(touched))
+    ideal = len(ticks) * per_tick / peaks["hbm_bytes_per_s"]
+    print(json.dumps({"moe_stream_roofline": {
+        "ticks": len(ticks), "spans": len(touched), "bytes_per_tick": per_tick,
+        "ideal_s": ideal, "took_s": took}}), flush=True)
+    return 100.0 * ideal / took

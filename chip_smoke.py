@@ -431,13 +431,13 @@ def _engine_kernel_counts(engine) -> dict:
         return jnp.zeros(shape, jnp.int32)
 
     slots, mb = engine.slots, engine.max_blocks_per_seq
-    pools = (engine.params, engine.cache.k_pool, engine.cache.v_pool)
+    pools = (engine.params, engine.cache.k_pools, engine.cache.v_pools)
     programs = {"decode": engine._decode_step.lower(
-        *pools, zeros(slots, mb), zeros(slots), zeros(slots)
+        *pools, (zeros(slots, mb),), zeros(slots), zeros(slots)
     )}
     for bucket, fn in engine._prefill_steps.items():
         programs[f"prefill_{bucket}"] = fn.lower(
-            *pools, zeros(bucket), jnp.int32(1), zeros(mb)
+            *pools, zeros(bucket), jnp.int32(1), (zeros(mb),)
         )
     return {name: low.as_text().count("tpu_custom_call")
             for name, low in programs.items()}

@@ -7,6 +7,9 @@
 5. :class:`TransformerEncoder` — the wrapped-model adapter path.
 
 Beyond the five parity configs: ResNet-18/34/101, :class:`TransformerLM`,
+:class:`DecoderLM` (a decoder block read from a configuration: RMSNorm,
+gated MLP, rotary, grouped K/V heads, window and full layers, and
+:class:`ExpertMLP`, routed experts without dropped tokens),
 Switch-MoE variants, :class:`ViT` (patch-conv + the same encoder stack;
 composes with the flash/ring/Ulysses ``attention_fn`` hooks), and
 :class:`UNet` with the DDPM/DDIM helpers (generative vision — GroupNorm
@@ -32,6 +35,7 @@ from .resnet import (  # noqa: F401
 )
 from .deq import DEQ, fixed_point_solve  # noqa: F401
 from .transformer import TransformerEncoder, TransformerLM  # noqa: F401
+from .decoder import DecoderConfig, DecoderLM, ExpertMLP  # noqa: F401
 from .generate import beam_search, generate  # noqa: F401
 from .hf_gpt2 import lm_from_gpt2  # noqa: F401
 from .vit import ViT  # noqa: F401

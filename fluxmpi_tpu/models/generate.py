@@ -121,7 +121,7 @@ def _is_ln1(path) -> bool:
     return "ln1" in keys
 
 
-def prefill_kv(model, params, tokens: jnp.ndarray):
+def prefill_kv(model, params, tokens: jnp.ndarray, head_at=None):
     """K/V projections for every prompt position from ONE batched causal
     forward — the O(1)-forwards prefill kernel.
 
@@ -138,16 +138,28 @@ def prefill_kv(model, params, tokens: jnp.ndarray):
     ``[num_layers, batch, plen, num_heads, head_dim]`` in cache layer
     order (:func:`layer_index`), ``logits`` is the full-sequence
     ``[batch, plen, vocab]`` (position ``plen - 1`` is the
-    next-token distribution after the whole prompt).
+    next-token distribution after the whole prompt). With ``head_at``
+    (``[batch]`` positions) the head runs at that one position a row and
+    ``logits`` is ``[batch, vocab]``: a padded prompt's other rows of
+    logits are never built.
     """
     fwd = model.clone(decode=False, attention_fn=None, dropout=0.0)
     logits, state = fwd.apply(
         {"params": params["params"]},
         tokens.astype(jnp.int32),
         train=False,
+        hidden=head_at is not None,
         capture_intermediates=lambda mdl, _: mdl.name == "ln1",
         mutable=["intermediates"],
     )
+    if head_at is not None:
+        # The tied head as ``nn.Embed.attend`` computes it, on one row.
+        x, embedding = logits
+        x = jnp.take_along_axis(
+            x, jnp.asarray(head_at)[:, None, None], axis=1
+        )[:, 0]
+        logits = jnp.dot(x.astype(model.dtype),
+                         embedding.astype(model.dtype).T)
     flat_h = [
         (layer_index(path), leaf)
         for path, leaf in jax.tree_util.tree_flatten_with_path(

@@ -12,13 +12,22 @@ Contract (shared by the kernel and its plain reference,
 :func:`paged_decode_reference`):
 
 - ``q`` is ``[slots, heads, head_dim]``; the pools are ``[layers,
-  num_blocks, block_size, heads * head_dim]`` (a block is one contiguous
-  ``[block_size, heads * head_dim]`` tile: no relayout, and no 64-wide
-  minor dimension padded to 128 lanes); ``layer`` picks the pool's
-  leading index statically, inside the block index map.
-- ``lengths[slot]`` counts the slot's live positions ``0 .. length - 1``
+  num_blocks, block_size, kv_heads * head_dim]`` (a block is one
+  contiguous ``[block_size, kv_heads * head_dim]`` tile: no relayout, and
+  no 64-wide minor dimension padded to 128 lanes); ``layer`` picks the
+  pool's leading index statically, inside the block index map.
+  ``kv_heads`` divides ``heads``: each K/V head serves ``heads //
+  kv_heads`` consecutive query heads (grouped-query attention).
+- ``lengths[slot]`` counts the slot's positions ``0 .. length - 1``
   (the new token's row included: the caller writes it first). Position
   ``p`` lives at ``(tables[slot, p // block_size], p % block_size)``.
+- With ``window``, a slot attends positions ``length - window ..
+  length - 1`` only, and its table is a RING of ``tables.shape[1]``
+  blocks: position ``p`` lives at ``(tables[slot, (p // block_size) %
+  ring], p % block_size)``, so a sequence longer than the ring
+  overwrites what the window has left behind (the ring must hold
+  ``window + block_size`` positions). Only the blocks that meet the
+  window are visited; the first one's head is masked by position.
 - Blocks at or past ``ceil(length / block_size)`` do no work: their grid
   steps neither compute nor fetch (the index map holds the last live
   block, which the pipeline does not fetch twice). The tail of the last
@@ -31,11 +40,12 @@ Contract (shared by the kernel and its plain reference,
   has ``q``'s dtype.
 
 All heads of a slot share one grid step. The per-head products come out
-of two plain matmuls over the folded ``heads * head_dim`` lanes: the
-query row is laid out block-diagonally (``[heads, heads * head_dim]``,
-head ``h``'s query in its own lanes, zeros elsewhere), so ``q_bd @ k.T``
-is ``[heads, block_size]`` scores with no per-head slicing, and of ``p @
-v`` (``[heads, heads * head_dim]``) each head keeps its own lanes.
+of two plain matmuls over the folded ``kv_heads * head_dim`` lanes: the
+query rows are laid out block-diagonally (``[heads, kv_heads *
+head_dim]``, head ``h``'s query in its K/V head's lanes, zeros
+elsewhere), so ``q_bd @ k.T`` is ``[heads, block_size]`` scores with no
+per-head slicing, and of ``p @ v`` (``[heads, kv_heads * head_dim]``)
+each head keeps its K/V head's lanes.
 """
 
 from __future__ import annotations
@@ -56,18 +66,27 @@ def _paged_decode_kernel(
     tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
     m_scratch, l_scratch, acc_scratch,
     *, sm_scale: float, head_dim: int, block_size: int, num_j: int,
+    group: int, window: int | None,
 ):
     del tables_ref  # read by the index maps
     slot = pl.program_id(0)
     j = pl.program_id(1)
     length = lengths_ref[slot]
-    # heads (padded to whole sublane tiles), heads * head_dim
+    # heads (padded to whole sublane tiles), kv_heads * head_dim
     rows, width = acc_scratch.shape
+    # The first position of the block this step visits: block j of the
+    # table, or the j-th block that meets the window.
+    if window is None:
+        base = j * block_size
+    else:
+        base = (jnp.maximum(length - window, 0) // block_size + j) * block_size
 
     def own_lanes():
-        # own[h, c]: lane c of the folded minor dimension belongs to head
-        # h. Padding rows (h >= heads) own nothing.
+        # own[h, c]: lane c of the folded minor dimension belongs to the
+        # K/V head of query head h. Padding rows (h >= heads) own nothing.
         head = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
+        if group > 1:
+            head = head // group
         lane = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
         return (lane >= head * head_dim) & (lane < (head + 1) * head_dim)
 
@@ -77,21 +96,24 @@ def _paged_decode_kernel(
         l_scratch[...] = jnp.zeros_like(l_scratch)
         acc_scratch[...] = jnp.zeros_like(acc_scratch)
 
-    @pl.when(j * block_size < length)
+    @pl.when(base < length)
     def _compute():
         k = k_ref[...]  # [block_size, width]
+        q = q_ref[0].astype(jnp.float32)
+        if group > 1:
+            # [rows, head_dim] -> every K/V head's lanes hold the row.
+            q = jnp.concatenate([q] * (width // head_dim), axis=1)
         # The select runs on 32-bit tiles (the mask comes from int32
         # iotas); the rounding back to the pool's dtype is exact.
-        q_bd = jnp.where(
-            own_lanes(), q_ref[0].astype(jnp.float32), 0.0
-        ).astype(k.dtype)
+        q_bd = jnp.where(own_lanes(), q, 0.0).astype(k.dtype)
         s = jax.lax.dot_general(
             q_bd, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * sm_scale  # [rows, block_size]
-        live = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
-        ) < length
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        live = pos < length
+        if window is not None:
+            live &= pos >= length - window
         s = jnp.where(live, s, _NEG_INF)
 
         m_prev = m_scratch[...]  # [rows, 128], value replicated over lanes
@@ -118,21 +140,30 @@ def _paged_decode_kernel(
         l_final = l_scratch[...][:, :1]
         l_safe = jnp.where(l_final == 0.0, 1.0, l_final)
         out = jnp.where(own_lanes(), acc_scratch[...] / l_safe, 0.0)
-        o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+        if group > 1:
+            # Row h keeps its K/V head's head_dim lanes: [rows, head_dim].
+            o_ref[0] = sum(
+                out[:, c:c + head_dim] for c in range(0, width, head_dim)
+            ).astype(o_ref.dtype)
+        else:
+            o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
 
 
-def _check_shapes(q, k_pool, v_pool, tables, lengths, layer):
+def _check_shapes(q, k_pool, v_pool, tables, lengths, layer, window):
+    """Returns ``heads // kv_heads``."""
     slots, heads, head_dim = q.shape
     if k_pool.shape != v_pool.shape or k_pool.ndim != 4:
         raise ValueError(
             f"k_pool and v_pool must share one [layers, num_blocks, "
-            f"block_size, heads * head_dim] shape; got {k_pool.shape} and "
-            f"{v_pool.shape}"
+            f"block_size, kv_heads * head_dim] shape; got {k_pool.shape} "
+            f"and {v_pool.shape}"
         )
-    if k_pool.shape[3] != heads * head_dim:
+    kv_heads, rest = divmod(k_pool.shape[3], head_dim)
+    if rest or not kv_heads or heads % kv_heads:
         raise ValueError(
-            f"pool minor dimension {k_pool.shape[3]} is not heads * "
-            f"head_dim = {heads} * {head_dim}"
+            f"pool minor dimension {k_pool.shape[3]} is not kv_heads * "
+            f"head_dim for a kv_heads that divides {heads} heads of "
+            f"{head_dim}"
         )
     tables_ok = tables.ndim == 2 and tables.shape[0] == slots
     if not tables_ok or lengths.shape != (slots,):
@@ -144,9 +175,17 @@ def _check_shapes(q, k_pool, v_pool, tables, lengths, layer):
         raise ValueError(
             f"layer {layer} outside the pool's {k_pool.shape[0]} layers"
         )
+    if window is not None and not (
+        1 <= window <= (tables.shape[1] - 1) * k_pool.shape[2]
+    ):
+        raise ValueError(
+            f"a ring of {tables.shape[1]} blocks of {k_pool.shape[2]} "
+            f"cannot hold a window of {window} and one block more"
+        )
+    return heads // kv_heads
 
 
-@functools.partial(jax.jit, static_argnames=("layer", "interpret"))
+@functools.partial(jax.jit, static_argnames=("layer", "window", "interpret"))
 def paged_decode_attention(
     q: jnp.ndarray,
     k_pool: jnp.ndarray,
@@ -155,6 +194,7 @@ def paged_decode_attention(
     lengths: jnp.ndarray,
     *,
     layer: int = 0,
+    window: int | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """One query row per slot against its paged cache; see the module
@@ -163,7 +203,7 @@ def paged_decode_attention(
     backend and Pallas interpret mode elsewhere."""
     from jax.experimental.pallas import tpu as pltpu
 
-    _check_shapes(q, k_pool, v_pool, tables, lengths, layer)
+    group = _check_shapes(q, k_pool, v_pool, tables, lengths, layer, window)
     slots, heads, head_dim = q.shape
     _, _, block_size, width = k_pool.shape
     num_j = tables.shape[1]
@@ -174,20 +214,31 @@ def paged_decode_attention(
     def kv_index(slot, j, tables_ref, lengths_ref):
         # Past the live blocks the index stays on the last live one: an
         # unchanged block index is not fetched again.
-        last = jnp.maximum(
-            (lengths_ref[slot] + block_size - 1) // block_size - 1, 0
-        )
-        return layer, tables_ref[slot, jnp.minimum(j, last)], 0, 0
+        length = lengths_ref[slot]
+        last = jnp.maximum((length + block_size - 1) // block_size - 1, 0)
+        if window is None:
+            return layer, tables_ref[slot, jnp.minimum(j, last)], 0, 0
+        first = jnp.maximum(length - window, 0) // block_size
+        entry = jnp.minimum(first + j, last) % num_j
+        return layer, tables_ref[slot, entry], 0, 0
 
     def row_index(slot, j, tables_ref, lengths_ref):
         return slot, 0, 0
 
     kv_spec = pl.BlockSpec((None, None, block_size, width), kv_index)
-    row_spec = pl.BlockSpec((1, 1, width), row_index)
+    if group > 1:
+        # One query row a head, each head_dim wide, padded to whole
+        # sublane tiles.
+        row_spec = pl.BlockSpec((1, rows, head_dim), row_index)
+        q_rows = jnp.pad(q, ((0, 0), (0, rows - heads), (0, 0)))
+    else:
+        row_spec = pl.BlockSpec((1, 1, width), row_index)
+        q_rows = q.reshape(slots, 1, width)
     out = pl.pallas_call(
         functools.partial(
             _paged_decode_kernel, sm_scale=1.0 / (head_dim**0.5),
             head_dim=head_dim, block_size=block_size, num_j=num_j,
+            group=group, window=window,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -200,15 +251,17 @@ def paged_decode_attention(
                 pltpu.VMEM((rows, width), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((slots, 1, width), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_rows.shape, q.dtype),
         compiler_params=pallas_tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(
         tables.astype(jnp.int32), lengths.astype(jnp.int32),
-        q.reshape(slots, 1, width), k_pool, v_pool,
+        q_rows, k_pool, v_pool,
     )
+    if group > 1:
+        return out[:, :heads]
     return out.reshape(slots, heads, head_dim)
 
 
@@ -220,24 +273,37 @@ def paged_decode_reference(
     lengths: jnp.ndarray,
     *,
     layer: int = 0,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """The same contract in plain ``jax.numpy``: gather one layer's
     tabled blocks, dense scores, a position mask. The kernel's reference
     in the tests, and the engine's ``attention="naive"`` route."""
-    _check_shapes(q, k_pool, v_pool, tables, lengths, layer)
+    group = _check_shapes(q, k_pool, v_pool, tables, lengths, layer, window)
     slots, heads, head_dim = q.shape
-    k = k_pool[layer][tables].reshape(slots, -1, heads, head_dim)
-    v = v_pool[layer][tables].reshape(slots, -1, heads, head_dim)
+    block_size, ring = k_pool.shape[2], tables.shape[1]
+    k = k_pool[layer][tables].reshape(slots, -1, heads // group, head_dim)
+    v = v_pool[layer][tables].reshape(slots, -1, heads // group, head_dim)
+    qg = q.reshape(slots, heads // group, group, head_dim)
     s = jnp.einsum(
-        "shd,sthd->sht", q.astype(k.dtype), k,
+        "skgd,stkd->skgt", qg.astype(k.dtype), k,
         preferred_element_type=jnp.float32,
     ) / (head_dim**0.5)
-    live = (jnp.arange(k.shape[1])[None, :] < lengths[:, None])[:, None, :]
+    # The position each gathered row holds: entry * block_size + offset,
+    # or, in a ring, the newest position below length that maps there.
+    pos = jnp.arange(k.shape[1])[None, :]
+    if window is not None:
+        last = (jnp.maximum(lengths, 1)[:, None] - 1) // block_size
+        entry = pos // block_size
+        pos = (last - (last - entry) % ring) * block_size + pos % block_size
+    live = (pos >= 0) & (pos < lengths[:, None])
+    if window is not None:
+        live &= pos >= lengths[:, None] - window
+    live = live[:, None, None, :]
     s = jnp.where(live, s, _NEG_INF)
     p = jnp.where(live, jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
     l = jnp.sum(p, axis=-1, keepdims=True)
     out = jnp.einsum(
-        "sht,sthd->shd", p / jnp.where(l == 0.0, 1.0, l),
+        "skgt,stkd->skgd", p / jnp.where(l == 0.0, 1.0, l),
         v.astype(jnp.float32),
     )
-    return out.astype(q.dtype)
+    return out.reshape(slots, heads, head_dim).astype(q.dtype)

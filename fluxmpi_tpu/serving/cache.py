@@ -29,6 +29,16 @@ positions are cut into fixed-size **blocks**,
   (:func:`fluxmpi_tpu.ops.paged_attention.paged_decode_attention`, see
   :mod:`fluxmpi_tpu.serving.engine`).
 
+**Layers that attend a window keep a ring.** A model whose layers are
+not all alike (``layer_windows``: some attend their whole context, some
+a sliding window) gets one pool, one free list and one table a **kind**
+of layer (:attr:`BlockKVCache.kinds`): a full layer's table spans
+``max_len``, a window layer's is a ring of ``ceil((window + block_size) /
+block_size)`` blocks in which position ``p`` lives at entry ``(p //
+block_size) % ring``, so a long sequence costs a window layer no more
+than its ring. A model without window layers has the one kind, and
+everything below reads as it did.
+
 **Block 0 is the trash block**: it is never allocated. Unused table
 entries point at it, masked prefill positions and idle batch slots
 write into it, and decode attention never reads it into a result: a
@@ -48,7 +58,7 @@ would OOM the chip refuses at construction, not at the first admission.
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Sequence
 
 __all__ = ["BlockKVCache", "blocks_for_tokens"]
 
@@ -60,20 +70,68 @@ def blocks_for_tokens(tokens: int, block_size: int) -> int:
     return -(-int(tokens) // int(block_size))
 
 
+class _Kind:
+    """The layers that keep the same span of a sequence, their free list
+    and their pools: ``window`` None for layers that keep the whole
+    context, else the positions a window layer attends. ``entries`` is
+    the width of a sequence's table row for these layers, ``layer_ids``
+    the model's layers that are of this kind, in order."""
+
+    __slots__ = ("layer_ids", "window", "entries", "num_blocks", "free",
+                 "k_pool", "v_pool")
+
+    def __init__(self, layer_ids: tuple[int, ...], window: int | None,
+                 entries: int, num_blocks: int):
+        if num_blocks < 2:
+            raise ValueError(
+                f"num_blocks must be >= 2 (block 0 is the reserved trash "
+                f"block), got {num_blocks}"
+            )
+        self.layer_ids = layer_ids
+        self.window = window
+        self.entries = entries
+        self.num_blocks = num_blocks
+        # LIFO free list: the most recently freed block is handed out
+        # next — the round-trip the reuse test pins down.
+        self.free: list[int] = list(range(num_blocks - 1, 0, -1))
+        self.k_pool = None
+        self.v_pool = None
+
+    @property
+    def layers(self) -> int:
+        return len(self.layer_ids)
+
+
 class BlockKVCache:
     """Paged K/V pool + free-list allocator + per-sequence block tables.
 
     Args:
       num_layers, num_heads, head_dim: the model's cache geometry
-        (``head_dim = qkv_features // num_heads``).
+        (``num_heads`` K/V heads of ``head_dim`` a layer).
       num_blocks: total pool blocks INCLUDING the reserved trash block
         (capacity = ``(num_blocks - 1) * block_size`` tokens).
       block_size: cache positions per block.
       max_blocks_per_seq: width of a block-table row — the longest
         sequence the engine serves, in blocks.
       dtype: pool dtype (the model's cache dtype).
+      layer_windows: per layer, the positions it attends (a window
+        layer) or None (a layer that attends its whole context; the
+        default for every layer). Layers of the two **kinds** live in
+        pools of their own, each with its free list and its tables
+        (:attr:`kinds`, full layers first): a window layer keeps at most
+        ``window + block_size`` positions of a sequence, rounded up to
+        blocks, as a RING (position ``p`` at table entry ``(p //
+        block_size) % entries``), so its table row is that many entries
+        wide and a long sequence costs it no more than that. One window
+        size a model. The window kind's pool holds the rings of as many
+        sequences as ``num_blocks`` holds at ``max_blocks_per_seq``.
 
-    The pools are created lazily on first :attr:`k_pool` access (so the
+    :meth:`alloc`, :meth:`free`, :meth:`table_row` and :meth:`blocks_for`
+    take the ``kind`` they speak of (default 0: the only kind of a model
+    without window layers); :meth:`can_alloc` answers for all kinds, and
+    the block counts are sums over them.
+
+    The pools are created lazily on first :attr:`k_pools` access (so the
     allocator half is importable/testable without a device) and live as
     plain device arrays the engine threads through its jitted steps.
     """
@@ -88,12 +146,8 @@ class BlockKVCache:
         block_size: int,
         max_blocks_per_seq: int,
         dtype: Any = None,
+        layer_windows: Sequence[int | None] | None = None,
     ):
-        if num_blocks < 2:
-            raise ValueError(
-                f"num_blocks must be >= 2 (block 0 is the reserved trash "
-                f"block), got {num_blocks}"
-            )
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         if max_blocks_per_seq < 1:
@@ -107,33 +161,60 @@ class BlockKVCache:
         self.block_size = int(block_size)
         self.max_blocks_per_seq = int(max_blocks_per_seq)
         self._dtype = dtype
-        # LIFO free list: the most recently freed block is handed out
-        # next — the round-trip the reuse test pins down.
-        self._free: list[int] = list(range(self.num_blocks - 1, 0, -1))
+        windows = tuple(layer_windows or (None,) * self.num_layers)
+        if len(windows) != self.num_layers:
+            raise ValueError(
+                f"layer_windows names {len(windows)} layers, not "
+                f"{self.num_layers}"
+            )
+        sizes = sorted({w for w in windows if w is not None})
+        if len(sizes) > 1:
+            raise ValueError(f"one window size a model, got {sizes}")
+        self.kinds: list[_Kind] = []
+        full = tuple(i for i, w in enumerate(windows) if w is None)
+        if full:
+            self.kinds.append(_Kind(
+                full, None, self.max_blocks_per_seq, self.num_blocks
+            ))
+        if sizes:
+            entries = min(
+                self.max_blocks_per_seq,
+                blocks_for_tokens(sizes[0] + self.block_size, self.block_size),
+            )
+            sequences = (self.num_blocks - 1) // self.max_blocks_per_seq
+            self.kinds.append(_Kind(
+                tuple(i for i, w in enumerate(windows) if w is not None),
+                int(sizes[0]), entries, 1 + sequences * entries,
+            ))
+        # Per layer: (its kind, its index among that kind's layers).
+        where = {
+            layer: (at, index)
+            for at, kind in enumerate(self.kinds)
+            for index, layer in enumerate(kind.layer_ids)
+        }
+        self.layer_kind = [where[layer] for layer in range(self.num_layers)]
         # Forensics (PR 16): the pool-lifetime peak of used_blocks —
         # "how close did this run actually get to the wall".
         self._high_watermark = 0
-        self._k_pool = None
-        self._v_pool = None
 
     # -- allocator -----------------------------------------------------
 
     @property
     def free_blocks(self) -> int:
-        return len(self._free)
+        return sum(len(k.free) for k in self.kinds)
 
     @property
     def used_blocks(self) -> int:
-        return (self.num_blocks - 1) - len(self._free)
+        return sum(k.num_blocks - 1 - len(k.free) for k in self.kinds)
 
     @property
     def capacity_tokens(self) -> int:
-        """Total cache positions the allocatable pool holds."""
-        return (self.num_blocks - 1) * self.block_size
+        """Total cache positions the first kind's allocatable pool holds."""
+        return (self.kinds[0].num_blocks - 1) * self.block_size
 
     @property
     def free_tokens(self) -> int:
-        return len(self._free) * self.block_size
+        return len(self.kinds[0].free) * self.block_size
 
     @property
     def high_watermark_blocks(self) -> int:
@@ -144,125 +225,175 @@ class BlockKVCache:
     @property
     def fragmentation(self) -> float:
         """Free-list scatter in [0, 1]: ``1 - (longest contiguous free
-        run / free blocks)``; 0.0 when the free space is one run (or
-        empty). Block allocation is id-agnostic, so this never blocks
-        an admission — it measures how shuffled churn has left the
-        pool, the precursor signal for block-coalescing / prefix-cache
-        work that DOES care about contiguity."""
-        if not self._free:
-            return 0.0
-        ids = sorted(self._free)
-        longest = run = 1
-        for a, b in zip(ids, ids[1:]):
-            run = run + 1 if b == a + 1 else 1
-            if run > longest:
-                longest = run
-        return 1.0 - longest / len(ids)
+        run / free blocks)``, runs and blocks summed over the kinds; 0.0
+        when the free space is one run (or empty). Block allocation is
+        id-agnostic, so this never blocks an admission — it measures how
+        shuffled churn has left the pool, the precursor signal for
+        block-coalescing / prefix-cache work that DOES care about
+        contiguity."""
+        free = longest_runs = 0
+        for kind in self.kinds:
+            if not kind.free:
+                continue
+            ids = sorted(kind.free)
+            longest = run = 1
+            for a, b in zip(ids, ids[1:]):
+                run = run + 1 if b == a + 1 else 1
+                if run > longest:
+                    longest = run
+            free += len(ids)
+            longest_runs += longest
+        return 1.0 - longest_runs / free if free else 0.0
 
-    def blocks_for(self, tokens: int) -> int:
-        return blocks_for_tokens(tokens, self.block_size)
+    def blocks_for(self, tokens: int, kind: int = 0) -> int:
+        """Blocks a sequence of ``tokens`` positions holds in ``kind``:
+        all of them, or a window kind's ring at most."""
+        need = blocks_for_tokens(tokens, self.block_size)
+        k = self.kinds[kind]
+        return need if k.window is None else min(need, k.entries)
 
     def can_alloc(self, tokens: int) -> bool:
-        return self.blocks_for(tokens) <= len(self._free)
+        """Whether every kind has the blocks ``tokens`` positions need."""
+        return all(
+            self.blocks_for(tokens, i) <= len(kind.free)
+            for i, kind in enumerate(self.kinds)
+        )
 
-    def alloc(self, tokens: int) -> list[int]:
-        """Reserve the blocks for ``tokens`` cache positions; raises
-        ``RuntimeError`` when the pool cannot cover them (callers gate
-        on :meth:`can_alloc` — admission control, not this, is where
-        "no" is decided)."""
-        need = self.blocks_for(tokens)
-        if need > len(self._free):
+    def fits_pool(self, tokens: int) -> bool:
+        """Whether ``tokens`` positions could EVER be held: in every
+        kind, no more blocks than the whole pool has."""
+        return all(
+            self.blocks_for(tokens, i) <= kind.num_blocks - 1
+            for i, kind in enumerate(self.kinds)
+        )
+
+    def alloc(self, tokens: int, kind: int = 0) -> list[int]:
+        """Reserve ``kind``'s blocks for ``tokens`` cache positions;
+        raises ``RuntimeError`` when the pool cannot cover them (callers
+        gate on :meth:`can_alloc` — admission control, not this, is
+        where "no" is decided)."""
+        need = self.blocks_for(tokens, kind)
+        free = self.kinds[kind].free
+        if need > len(free):
             raise RuntimeError(
                 f"KV pool exhausted: need {need} blocks for {tokens} "
-                f"tokens, {len(self._free)} free"
+                f"tokens, {len(free)} free"
             )
-        if need > self.max_blocks_per_seq:
+        if need > self.kinds[kind].entries:
             raise ValueError(
                 f"{tokens} tokens need {need} blocks but block tables are "
-                f"{self.max_blocks_per_seq} wide"
+                f"{self.kinds[kind].entries} wide"
             )
-        blocks = [self._free.pop() for _ in range(need)]
+        blocks = [free.pop() for _ in range(need)]
         if self.used_blocks > self._high_watermark:
             self._high_watermark = self.used_blocks
         return blocks
 
-    def free(self, blocks: list[int]) -> None:
-        """Return a sequence's blocks to the pool (eviction)."""
+    def free(self, blocks: list[int], kind: int = 0) -> None:
+        """Return a sequence's blocks to ``kind``'s pool (eviction)."""
+        k = self.kinds[kind]
         for b in blocks:
-            if not 0 < b < self.num_blocks:
+            if not 0 < b < k.num_blocks:
                 raise ValueError(f"block id {b} outside the pool")
-            if b in self._free:
+            if b in k.free:
                 raise ValueError(f"double free of block {b}")
-        self._free.extend(blocks)
+        k.free.extend(blocks)
 
-    def table_row(self, blocks: list[int]):
-        """``[max_blocks_per_seq]`` int32 block-table row for a
-        sequence's blocks; unused entries point at the trash block."""
+    def table_row(self, blocks: list[int], kind: int = 0):
+        """``kind``'s int32 block-table row (``max_blocks_per_seq`` wide,
+        or a window kind's ring) for a sequence's blocks; unused entries
+        point at the trash block."""
         import numpy as np
 
-        row = np.full((self.max_blocks_per_seq,), TRASH_BLOCK, np.int32)
+        row = np.full((self.kinds[kind].entries,), TRASH_BLOCK, np.int32)
         row[: len(blocks)] = blocks
         return row
 
     # -- device pools --------------------------------------------------
 
     @property
+    def pool_shapes(self) -> list[tuple[int, ...]]:
+        """Per kind, ``[layers, blocks, block_size, heads * head_dim]`` —
+        the one statement of the pools' layout (the prefill's and the
+        decode's ``kv_write``, the decode kernel and :attr:`pool_bytes`
+        follow)."""
+        return [
+            (k.layers, k.num_blocks, self.block_size,
+             self.num_heads * self.head_dim)
+            for k in self.kinds
+        ]
+
+    @property
     def pool_shape(self) -> tuple[int, ...]:
-        """``[layers, blocks, block_size, heads * head_dim]`` — the one
-        statement of the pool's layout (the prefill's and the decode's
-        ``kv_write``, the decode kernel and :attr:`pool_bytes` follow)."""
-        return (
-            self.num_layers,
-            self.num_blocks,
-            self.block_size,
-            self.num_heads * self.head_dim,
-        )
+        """The first kind's pool shape."""
+        return self.pool_shapes[0]
 
     @property
     def pool_bytes(self) -> int:
-        """Byte footprint of BOTH pools (K and V)."""
+        """Byte footprint of ALL pools (K and V, every kind)."""
         import numpy as np
 
         import jax.numpy as jnp
 
         dtype = self._dtype if self._dtype is not None else jnp.float32
         itemsize = np.dtype(dtype).itemsize
-        n = 1
-        for d in self.pool_shape:
-            n *= d
-        return 2 * n * itemsize
+        return 2 * itemsize * sum(
+            int(np.prod(shape)) for shape in self.pool_shapes
+        )
 
     def _ensure_pools(self) -> None:
-        if self._k_pool is None:
+        if self.kinds[0].k_pool is None:
             import jax.numpy as jnp
 
             dtype = self._dtype if self._dtype is not None else jnp.float32
-            self._k_pool = jnp.zeros(self.pool_shape, dtype)
-            self._v_pool = jnp.zeros(self.pool_shape, dtype)
+            for kind, shape in zip(self.kinds, self.pool_shapes):
+                kind.k_pool = jnp.zeros(shape, dtype)
+                kind.v_pool = jnp.zeros(shape, dtype)
+
+    @property
+    def k_pools(self) -> tuple:
+        """The K pools, one a kind: what the engine's steps take (and
+        donate) and hand back."""
+        self._ensure_pools()
+        return tuple(k.k_pool for k in self.kinds)
+
+    @k_pools.setter
+    def k_pools(self, value) -> None:
+        for kind, pool in zip(self.kinds, value):
+            kind.k_pool = pool
+
+    @property
+    def v_pools(self) -> tuple:
+        self._ensure_pools()
+        return tuple(k.v_pool for k in self.kinds)
+
+    @v_pools.setter
+    def v_pools(self, value) -> None:
+        for kind, pool in zip(self.kinds, value):
+            kind.v_pool = pool
 
     @property
     def k_pool(self):
-        self._ensure_pools()
-        return self._k_pool
+        """The first kind's K pool."""
+        return self.k_pools[0]
 
     @k_pool.setter
     def k_pool(self, value) -> None:
-        self._k_pool = value
+        self.kinds[0].k_pool = value
 
     @property
     def v_pool(self):
-        self._ensure_pools()
-        return self._v_pool
+        return self.v_pools[0]
 
     @v_pool.setter
     def v_pool(self, value) -> None:
-        self._v_pool = value
+        self.kinds[0].v_pool = value
 
     def drop_pools(self) -> None:
         """Release the device arrays (engine shutdown — the pool must
         not outlive the engine into the next init cycle)."""
-        self._k_pool = None
-        self._v_pool = None
+        for kind in self.kinds:
+            kind.k_pool = kind.v_pool = None
 
     # -- memory-plane admission check ----------------------------------
 

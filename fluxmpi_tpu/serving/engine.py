@@ -386,21 +386,55 @@ class ServingRequest:
 
 
 class _Slot:
-    """One active batch slot: the request plus its device-side cursor."""
+    """One active batch slot: the request plus its device-side cursor.
+    ``blocks`` and ``tables`` hold one entry per kind of layer the cache
+    has (:attr:`BlockKVCache.kinds`)."""
 
-    __slots__ = ("req", "blocks", "table", "position", "last_token",
+    __slots__ = ("req", "blocks", "tables", "position", "last_token",
                  "generated")
 
-    def __init__(self, req: ServingRequest, blocks: list[int],
-                 table: np.ndarray):
+    def __init__(self, req: ServingRequest, blocks: list[list[int]],
+                 tables: list[np.ndarray]):
         self.req = req
         self.blocks = blocks
-        self.table = table
+        self.tables = tables
         # Cache positions filled so far == the position the NEXT fed
         # token occupies; after prefill this is the prompt length.
         self.position = 0
         self.last_token = 0
         self.generated = 0
+
+    @property
+    def num_blocks(self) -> int:
+        return sum(len(b) for b in self.blocks)
+
+
+def _cache_layers(model) -> tuple[tuple[int, int, int | None], ...]:
+    """Per layer ``(kv_heads, head_dim, window)``: what the model says it
+    keeps of a sequence (``cache_layers()``, the protocol of
+    :class:`~fluxmpi_tpu.models.DecoderLM`), else
+    :class:`~fluxmpi_tpu.models.TransformerLM`'s ``num_heads`` heads of
+    ``d_model // num_heads`` over the whole context."""
+    layers = getattr(model, "cache_layers", None)
+    if layers is not None:
+        return tuple(layers())
+    heads = int(model.num_heads)
+    return ((heads, int(model.d_model) // heads, None),) * int(
+        model.num_layers
+    )
+
+
+def _expert_counts(state) -> list:
+    """Every ``expert_tokens`` array a model's expert layers sowed into
+    ``intermediates`` (``[num_experts]`` int32 each), in tree order."""
+    import jax
+
+    return [
+        leaf for path, leaf in jax.tree_util.tree_flatten_with_path(
+            state.get("intermediates", {})
+        )[0]
+        if any(getattr(e, "key", None) == "expert_tokens" for e in path)
+    ]
 
 
 class _PagedDecodeAttention:
@@ -411,21 +445,33 @@ class _PagedDecodeAttention:
     one per layer, in layer order — writes the key and value rows into
     the pools at ``(table[pos // block_size], pos % block_size)`` and then
     attends through the block tables. Idle slots carry all-trash tables:
-    their rows land in the trash block and their length is 0. The step
-    reads the updated pools back from :attr:`k_pool` / :attr:`v_pool`."""
+    their rows land in the trash block and their length is 0. Layers of a
+    kind (:attr:`BlockKVCache.kinds`) share a pool and a table; a window
+    kind's table is a ring. The step reads the updated pools back from
+    :attr:`k_pools` / :attr:`v_pools`."""
 
-    def __init__(self, k_pool, v_pool, tables, positions, block_size: int,
-                 kernel: bool):
+    def __init__(self, cache: BlockKVCache, k_pools, v_pools, tables,
+                 positions, kernel: bool):
         import jax.numpy as jnp
 
-        self.k_pool, self.v_pool = k_pool, v_pool
+        # One pool, one table and one written block a kind of layer.
+        self.k_pools, self.v_pools = list(k_pools), list(v_pools)
         self.tables = tables
-        self.block = jnp.take_along_axis(
-            tables, (positions // block_size)[:, None], axis=1
-        )[:, 0]
-        self.offset = positions % block_size
+        self.windows = [kind.window for kind in cache.kinds]
+        self.layer_kind = cache.layer_kind
+        entry = positions // cache.block_size
+        self.blocks = [
+            jnp.take_along_axis(
+                table,
+                # A window kind's table is a ring.
+                (entry if window is None else entry % table.shape[1])[:, None],
+                axis=1,
+            )[:, 0]
+            for table, window in zip(tables, self.windows)
+        ]
+        self.offset = positions % cache.block_size
         self.lengths = jnp.where(
-            tables[:, 0] != TRASH_BLOCK, positions + 1, 0
+            tables[0][:, 0] != TRASH_BLOCK, positions + 1, 0
         )
         self.kernel = kernel
         self.layer = 0
@@ -438,25 +484,54 @@ class _PagedDecodeAttention:
             paged_decode_reference,
         )
 
-        layer, self.layer = self.layer, self.layer + 1
+        kind, layer = self.layer_kind[self.layer]
+        self.layer += 1
         slots = query.shape[0]
+        k_pool, v_pool = self.k_pools[kind], self.v_pools[kind]
         with jax.named_scope("kv_write"):
-            rows = (layer, self.block, self.offset)
-            self.k_pool = self.k_pool.at[rows].set(
-                key.reshape(slots, -1).astype(self.k_pool.dtype)
+            rows = (layer, self.blocks[kind], self.offset)
+            k_pool = self.k_pools[kind] = k_pool.at[rows].set(
+                key.reshape(slots, -1).astype(k_pool.dtype)
             )
-            self.v_pool = self.v_pool.at[rows].set(
-                value.reshape(slots, -1).astype(self.v_pool.dtype)
+            v_pool = self.v_pools[kind] = v_pool.at[rows].set(
+                value.reshape(slots, -1).astype(v_pool.dtype)
             )
         attend = (
             paged_decode_attention if self.kernel else paged_decode_reference
         )
         with jax.named_scope("decode_attention"):
             out = attend(
-                query[:, 0], self.k_pool, self.v_pool, self.tables,
-                self.lengths, layer=layer,
+                query[:, 0], k_pool, v_pool, self.tables[kind],
+                self.lengths, layer=layer, window=self.windows[kind],
             )
         return out[:, None]
+
+
+class _PrefillAttention:
+    """The prefill program's ``attention_fn`` for a model that speaks the
+    ``cache_layers()`` protocol: causal attention over the padded prompt
+    (within the layer's window; the flash kernels with ``kernel``), and
+    each layer's keys and values kept for the pool's ``kv_write``."""
+
+    def __init__(self, windows, kernel: bool):
+        self.windows = windows
+        self.kernel = kernel
+        self.keys: list = []
+        self.values: list = []
+
+    def __call__(self, query, key, value):
+        import jax
+
+        from ..models.decoder import causal_attention
+
+        window = self.windows[len(self.keys)]
+        self.keys.append(key)
+        self.values.append(value)
+        with jax.named_scope("prefill_attention"):
+            return causal_attention(
+                query, key, value, window=window,
+                mode="flash" if self.kernel else "naive",
+            )
 
 
 # ---------------------------------------------------------------------------
@@ -596,15 +671,26 @@ class InferenceEngine:
                 "expert capacity when serving such checkpoints",
                 stacklevel=2,
             )
+        # A model that says what its layers cache (``cache_layers()``)
+        # is served through its ``attention_fn`` / ``head_at`` /
+        # ``token_mask`` protocol; else it is TransformerLM-shaped.
+        self._protocol = hasattr(model, "cache_layers")
+        layers = _cache_layers(model)
+        if len({(heads, dim) for heads, dim, _ in layers}) != 1:
+            raise ValueError(
+                f"every layer must cache K/V heads of one shape; got "
+                f"{sorted({(h, d) for h, d, _ in layers})}"
+            )
         self.cache = BlockKVCache(
-            num_layers=int(model.num_layers),
-            num_heads=int(model.num_heads),
-            head_dim=int(model.d_model) // int(model.num_heads),
+            num_layers=len(layers),
+            num_heads=layers[0][0],
+            head_dim=layers[0][1],
             num_blocks=nb,
             block_size=self.block_size,
             max_blocks_per_seq=self.max_blocks_per_seq,
             # The attention sublayer computes K and V in the model's dtype.
             dtype=model.dtype,
+            layer_windows=[window for _, _, window in layers],
         )
         if check_memory:
             fits, detail = self.cache.fits_device()
@@ -643,6 +729,19 @@ class InferenceEngine:
         # blocks the slots' tables span, both summed over decode ticks.
         self._kv_blocks_live = 0
         self._kv_blocks_tabled = 0
+        # Layer-blocks the active slots hold (reserved at admission), by
+        # kind of layer, beside what one pool of one shape would hold for
+        # the same slots; summed over decode ticks.
+        self._kv_blocks_full = 0
+        self._kv_blocks_window = 0
+        self._kv_blocks_uniform = 0
+        # Routed experts (a model with expert layers): (token, expert)
+        # pairs, (layer, expert) cells with at least one, and cells in
+        # all, summed over decode ticks.
+        self._expert_tokens = 0
+        self._experts_touched = 0
+        self._expert_slots = 0
+        self._expert_layers = 0
         # Registry-counter delta baselines (see _resolve_run).
         self._counted_steps = 0
         self._counted_tokens = 0
@@ -683,26 +782,44 @@ class InferenceEngine:
         from ..models.transformer import _resolve_attention_mode
 
         model = self.model
-        bs = self.block_size
+        cache = self.cache
         kernel = _resolve_attention_mode(model.attention) == "flash"
+        protocol = self._protocol
 
-        def step(params, k_pool, v_pool, tables, positions, tokens):
-            # tables: [slots, max_blocks]; positions/tokens: [slots].
+        def step(params, k_pools, v_pools, tables, positions, tokens):
+            # k_pools / v_pools / tables: one entry a kind of layer
+            # (tables[kind]: [slots, entries]); positions/tokens: [slots].
             attend = _PagedDecodeAttention(
-                k_pool, v_pool, tables, positions, bs, kernel
+                cache, k_pools, v_pools, tables, positions, kernel
             )
             # The model's own blocks (make_ff included) around the paged
             # attention; K/V state lives in the pool, not in a flax cache.
-            paged = model.clone(
-                decode=False, attention="naive", attention_fn=attend,
-                dropout=0.0,
-            )
-            logits = paged.apply(
-                {"params": params["params"]}, tokens[:, None], train=False,
-                pos_offset=positions,
-            )
+            if protocol:
+                logits, state = model.clone(attention_fn=attend).apply(
+                    {"params": params["params"]}, tokens[:, None],
+                    pos_offset=positions,
+                    token_mask=(tables[0][:, :1] != TRASH_BLOCK),
+                    mutable=["intermediates"],
+                )
+            else:
+                paged = model.clone(
+                    decode=False, attention="naive", attention_fn=attend,
+                    dropout=0.0,
+                )
+                logits = paged.apply(
+                    {"params": params["params"]}, tokens[:, None],
+                    train=False, pos_offset=positions,
+                )
+                state = {}
             nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-            return nxt, attend.k_pool, attend.v_pool
+            counts = _expert_counts(state)
+            if counts:
+                # The tick's tokens, then every expert layer's count of
+                # the pairs each expert received: one fetch. (How many
+                # layers is known once the step is traced.)
+                self._expert_layers = len(counts)
+                nxt = jnp.concatenate([nxt, *counts])
+            return nxt, tuple(attend.k_pools), tuple(attend.v_pools)
 
         return jax.jit(step, donate_argnums=(1, 2))
 
@@ -718,35 +835,73 @@ class InferenceEngine:
         import jax.numpy as jnp
 
         from ..models.generate import prefill_kv
+        from ..models.transformer import _resolve_attention_mode
         from ..ops.flash_attention import attention_scope
 
         model = self.model
+        cache = self.cache
         bs = self.block_size
+        kernel = _resolve_attention_mode(model.attention) == "flash"
+        protocol = self._protocol
 
-        def prefill(params, k_pool, v_pool, tokens, length, table):
-            # tokens: [bucket]; length: true prompt length; table: [MB].
-            with attention_scope("prefill_attention"):
-                k, v, logits = prefill_kv(model, params, tokens[None])
+        def write(pool, rows, table, length, window):
+            """``rows`` ``[layers, bucket, heads * head_dim]`` into the
+            layers' pool through the sequence's ``table``; positions past
+            ``length`` (and, in a window kind's ring, before what the
+            window keeps) land in the trash block."""
+            pos = jnp.arange(rows.shape[1])
+            keep = pos < length
+            entry = pos // bs
+            if window is not None:
+                ring = table.shape[0]
+                keep &= entry > (length - 1) // bs - ring
+                entry = entry % ring
+            blk = jnp.where(keep, table[entry], jnp.int32(TRASH_BLOCK))
+            # One row per (layer, position), indexed on every leading
+            # dimension: a window over the layers makes XLA move the
+            # whole pool into a layers-minor layout and back.
+            layers = jnp.arange(rows.shape[0])[:, None]
+            return pool.at[(layers, blk[None], (pos % bs)[None])].set(
+                rows.astype(pool.dtype)
+            )
+
+        def prefill(params, k_pools, v_pools, tokens, length, tables):
+            # tokens: [bucket]; length: true prompt length; k_pools /
+            # v_pools / tables ([entries]): one entry a kind of layer.
+            # The head runs at the last real position only.
+            if protocol:
+                attend = _PrefillAttention(
+                    [cache.kinds[at].window for at, _ in cache.layer_kind],
+                    kernel,
+                )
+                last = model.clone(attention_fn=attend).apply(
+                    {"params": params["params"]}, tokens[None],
+                    head_at=(length - 1)[None],
+                    token_mask=(jnp.arange(tokens.shape[0]) < length)[None],
+                )[0]
+                k, v = jnp.stack(attend.keys), jnp.stack(attend.values)
+            else:
+                with attention_scope("prefill_attention"):
+                    k, v, logits = prefill_kv(
+                        model, params, tokens[None],
+                        head_at=(length - 1)[None],
+                    )
+                last = logits[0]
             with jax.named_scope("kv_write"):
                 # [layers, bucket, heads * head_dim]: the pool's row.
                 k = k[:, 0].reshape(k.shape[0], k.shape[2], -1)
                 v = v[:, 0].reshape(v.shape[0], v.shape[2], -1)
-                pos = jnp.arange(tokens.shape[0])
-                blk = jnp.where(
-                    pos < length, table[pos // bs], jnp.int32(TRASH_BLOCK)
-                )
-                # One row per (layer, position), indexed on every leading
-                # dimension: a window over the layers makes XLA move the
-                # whole pool into a layers-minor layout and back.
-                layers = jnp.arange(k.shape[0])[:, None]
-                rows = (layers, blk[None], (pos % bs)[None])
-                k_pool = k_pool.at[rows].set(k.astype(k_pool.dtype))
-                v_pool = v_pool.at[rows].set(v.astype(v_pool.dtype))
-            last = jax.lax.dynamic_index_in_dim(
-                logits[0], length - 1, axis=0, keepdims=False
-            )
+                k_pools, v_pools = list(k_pools), list(v_pools)
+                for i, kind in enumerate(cache.kinds):
+                    # Every layer of the only kind, or this kind's.
+                    mine = (slice(None) if len(cache.kinds) == 1
+                            else np.asarray(kind.layer_ids))
+                    k_pools[i] = write(k_pools[i], k[mine], tables[i],
+                                       length, kind.window)
+                    v_pools[i] = write(v_pools[i], v[mine], tables[i],
+                                       length, kind.window)
             first = jnp.argmax(last, axis=-1).astype(jnp.int32)
-            return first, k_pool, v_pool
+            return first, tuple(k_pools), tuple(v_pools)
 
         fn = jax.jit(prefill, donate_argnums=(1, 2))
         self._prefill_steps[bucket] = fn
@@ -774,17 +929,18 @@ class InferenceEngine:
 
         buckets = {self._bucket(max(1, int(p))) for p in prompt_lengths}
         buckets.add(self.block_size)
-        mb = self.max_blocks_per_seq
-        trash_table = jnp.zeros((mb,), jnp.int32)
+        cache = self.cache
+        entries = [kind.entries for kind in cache.kinds]
+        trash_tables = tuple(jnp.zeros((n,), jnp.int32) for n in entries)
         for bucket in sorted(buckets):
             fn = self._prefill_step(bucket)
-            _, self.cache.k_pool, self.cache.v_pool = fn(
-                self.params, self.cache.k_pool, self.cache.v_pool,
-                jnp.zeros((bucket,), jnp.int32), jnp.int32(1), trash_table,
+            _, cache.k_pools, cache.v_pools = fn(
+                self.params, cache.k_pools, cache.v_pools,
+                jnp.zeros((bucket,), jnp.int32), jnp.int32(1), trash_tables,
             )
-        nxt, self.cache.k_pool, self.cache.v_pool = self._decode_step(
-            self.params, self.cache.k_pool, self.cache.v_pool,
-            jnp.zeros((self.slots, mb), jnp.int32),
+        nxt, cache.k_pools, cache.v_pools = self._decode_step(
+            self.params, cache.k_pools, cache.v_pools,
+            tuple(jnp.zeros((self.slots, n), jnp.int32) for n in entries),
             jnp.zeros((self.slots,), jnp.int32),
             jnp.zeros((self.slots,), jnp.int32),
         )
@@ -839,7 +995,7 @@ class InferenceEngine:
                 f"eos_token {req.eos_token} outside the vocabulary "
                 f"[0, {self.model.vocab_size})"
             )
-        if self.cache.blocks_for(total) > self.cache.num_blocks - 1:
+        if not self.cache.fits_pool(total):
             raise ValueError(
                 f"request needs {self.cache.blocks_for(total)} blocks but "
                 f"the pool only holds {self.cache.num_blocks - 1}"
@@ -889,11 +1045,15 @@ class InferenceEngine:
         between any two iterations; static mode: only once every slot
         has drained), prefilling each admission. FIFO — a head request
         waiting on blocks holds the line (documented in
-        docs/serving.md)."""
+        docs/serving.md). While slots are decoding, ONE admission an
+        iteration: a prefill stalls every active slot, and a queue of
+        them taken at once would put the sum of their prefills into one
+        gap between tokens. An idle engine fills its slots at once."""
         if not self.continuous and any(s is not None for s in self._slots):
             return 0
+        limit = 1 if self._active else self.slots
         admitted = 0
-        while True:
+        while admitted < limit:
             free_ix = next(
                 (i for i, s in enumerate(self._slots) if s is None), None
             )
@@ -923,9 +1083,11 @@ class InferenceEngine:
         ):
             req.admitted_t = self._clock()
             req.status = ACTIVE
-            blocks = self.cache.alloc(total)
-            table = self.cache.table_row(blocks)
-            slot = _Slot(req, blocks, table)
+            kinds = range(len(self.cache.kinds))
+            blocks = [self.cache.alloc(total, kind) for kind in kinds]
+            tables = [self.cache.table_row(blocks[kind], kind)
+                      for kind in kinds]
+            slot = _Slot(req, blocks, tables)
             padded = np.zeros((bucket,), np.int32)
             padded[:plen] = req.prompt
             fn = self._prefill_step(bucket)
@@ -933,9 +1095,10 @@ class InferenceEngine:
             with _tracing.span(
                 "serve.prefill", request_id=req.id, bucket=bucket
             ):
-                first, self.cache.k_pool, self.cache.v_pool = fn(
-                    self.params, self.cache.k_pool, self.cache.v_pool,
-                    jnp.asarray(padded), jnp.int32(plen), jnp.asarray(table),
+                first, self.cache.k_pools, self.cache.v_pools = fn(
+                    self.params, self.cache.k_pools, self.cache.v_pools,
+                    jnp.asarray(padded), jnp.int32(plen),
+                    tuple(jnp.asarray(table) for table in tables),
                 )
                 slot.last_token = int(first)
             slot.position = plen
@@ -971,33 +1134,62 @@ class InferenceEngine:
         mb = self.max_blocks_per_seq
         step = self._decode_steps
         active = self._active
+        kinds = self.cache.kinds
         with _tracing.span("serve.decode.prepare", active=active) as prep:
-            tables = np.zeros((self.slots, mb), np.int32)
+            tables = [np.zeros((self.slots, kind.entries), np.int32)
+                      for kind in kinds]
             positions = np.zeros((self.slots,), np.int32)
             tokens = np.zeros((self.slots,), np.int32)
-            live = 0  # blocks the decode kernel reads this tick
+            live = 0  # layer-blocks the decode kernel reads this tick
+            # With window layers: layer-blocks the slots hold, by kind,
+            # and would hold in one pool of one shape.
+            windowed = len(kinds) > 1
+            held = [0] * len(kinds)
+            uniform = 0
             for i, slot in enumerate(self._slots):
                 if slot is None:
                     continue
-                tables[i] = slot.table
                 positions[i] = slot.position
                 tokens[i] = slot.last_token
-                live += blocks_for_tokens(slot.position + 1, self.block_size)
+                reach = slot.position + 1
+                for at, kind in enumerate(kinds):
+                    tables[at][i] = slot.tables[at]
+                    if windowed:
+                        held[at] += kind.layers * len(slot.blocks[at])
+                    if kind.window is not None and reach > kind.window:
+                        # The blocks that meet the window.
+                        blocks = (slot.position // self.block_size
+                                  - (reach - kind.window) // self.block_size
+                                  + 1)
+                    else:
+                        blocks = blocks_for_tokens(reach, self.block_size)
+                    live += kind.layers * blocks
+                if windowed:
+                    uniform += self.cache.num_layers * len(slot.blocks[0])
+            tabled = self.slots * sum(k.layers * k.entries for k in kinds)
             self._kv_blocks_live += live
-            self._kv_blocks_tabled += self.slots * mb
-            prep.set_metadata(
-                live_blocks_pct=100.0 * live / (self.slots * mb)
-            )
-            tables = jnp.asarray(tables)
+            self._kv_blocks_tabled += tabled
+            prep.set_metadata(live_blocks_pct=100.0 * live / tabled)
+            if windowed:
+                self._kv_blocks_full += held[0]
+                self._kv_blocks_window += held[1]
+                self._kv_blocks_uniform += uniform
+                if uniform:
+                    prep.set_metadata(
+                        window_blocks_pct=100.0 * sum(held) / uniform
+                    )
+            tables = tuple(jnp.asarray(table) for table in tables)
             positions = jnp.asarray(positions)
             tokens = jnp.asarray(tokens)
         with _tracing.span("serve.decode.dispatch", step=step):
-            nxt, self.cache.k_pool, self.cache.v_pool = self._decode_step(
-                self.params, self.cache.k_pool, self.cache.v_pool,
+            nxt, self.cache.k_pools, self.cache.v_pools = self._decode_step(
+                self.params, self.cache.k_pools, self.cache.v_pools,
                 tables, positions, tokens,
             )
         with _tracing.span("serve.decode.fetch", step=step):
             nxt = np.asarray(nxt)
+            # Past the slots' tokens: each expert layer's pairs an expert.
+            expert_tokens = nxt[self.slots:]
         self._decode_steps += 1
         self._slot_steps_active += active
         evicted = self._evictions
@@ -1020,6 +1212,26 @@ class InferenceEngine:
                 ):
                     self._evict(i)
             delivery.set_metadata(evicted=self._evictions - evicted)
+            if expert_tokens.size:
+                touched = int(np.count_nonzero(expert_tokens))
+                self._expert_tokens += int(expert_tokens.sum())
+                self._experts_touched += touched
+                self._expert_slots += expert_tokens.size
+                # The busiest expert's pairs over the mean, layer by layer.
+                by_layer = expert_tokens.reshape(
+                    -1, expert_tokens.size // self._expert_layers
+                )
+                delivery.set_metadata(
+                    experts_touched_pct=100.0 * touched / expert_tokens.size,
+                    expert_load_max_over_mean=float(np.mean(
+                        by_layer.max(axis=1)
+                        / np.maximum(by_layer.mean(axis=1), 1e-9)
+                    )),
+                )
+
+    def _free(self, slot: _Slot) -> None:
+        for kind, blocks in enumerate(slot.blocks):
+            self.cache.free(blocks, kind)
 
     def _evict(self, slot_ix: int) -> None:
         """Finish a slot's request and return its blocks to the free
@@ -1029,7 +1241,7 @@ class InferenceEngine:
         self._slots[slot_ix] = None
         self._active -= 1
         self._evictions += 1
-        self.cache.free(slot.blocks)
+        self._free(slot)
         req = slot.req
         req._finish(FINISHED)
         self._completed += 1
@@ -1064,7 +1276,7 @@ class InferenceEngine:
                 reg.counter("serving.slo_violations", kind=kind).inc()
         if self._observer is not None:
             self._observer.observe_terminal(
-                req, kv_blocks=len(slot.blocks),
+                req, kv_blocks=slot.num_blocks,
                 violations=tuple(violations),
             )
 
@@ -1085,8 +1297,18 @@ class InferenceEngine:
         (position + 1) / block_size)`` of every active slot, summed over
         decode steps) and ``kv_blocks_tabled`` (``slots *
         max_blocks_per_seq`` a step: what the slots' tables span; the
-        ratio is the share of the reserved cache a tick touches). Plain
-        ints the loop keeps anyway; safe to read from another thread."""
+        ratio is the share of the reserved cache a tick touches; both
+        count a block once a layer that has it, and a window layer only
+        the blocks that meet its window). A model with window layers
+        also counts the layer-blocks its slots HOLD, summed over decode
+        steps: ``kv_blocks_full`` and ``kv_blocks_window`` by kind of
+        layer, beside ``kv_blocks_uniform``, what one pool of one shape
+        would hold for the same slots. A model with expert layers counts
+        ``expert_tokens`` ((token, expert) pairs routed), ``experts_touched``
+        ((layer, expert) cells that received at least one) and
+        ``expert_slots`` (cells in all), summed over decode steps. The
+        keys a model has no use for stay 0. Plain ints the loop keeps
+        anyway; safe to read from another thread."""
         return {
             "decode_steps": self._decode_steps,
             "tokens": self._tokens,
@@ -1095,6 +1317,12 @@ class InferenceEngine:
             "evictions": self._evictions,
             "kv_blocks_live": self._kv_blocks_live,
             "kv_blocks_tabled": self._kv_blocks_tabled,
+            "kv_blocks_full": self._kv_blocks_full,
+            "kv_blocks_window": self._kv_blocks_window,
+            "kv_blocks_uniform": self._kv_blocks_uniform,
+            "expert_tokens": self._expert_tokens,
+            "experts_touched": self._experts_touched,
+            "expert_slots": self._expert_slots,
         }
 
     @property
@@ -1191,7 +1419,7 @@ class InferenceEngine:
                 if det is not None and det.enabled:
                     det.observe(slo_burn=rate, step=self._decode_steps)
         if self._exporter is not None:
-            total = self.cache.num_blocks - 1
+            total = self.cache.used_blocks + self.cache.free_blocks
             board: dict[str, Any] = dict(
                 phase=phase,
                 continuous=self.continuous,
@@ -1311,9 +1539,9 @@ class InferenceEngine:
                 if slot is not None:
                     self._slots[i] = None
                     self._active -= 1
-                    self.cache.free(slot.blocks)
+                    self._free(slot)
                     self._reject(
-                        slot.req, reason, kv_blocks=len(slot.blocks)
+                        slot.req, reason, kv_blocks=slot.num_blocks
                     )
 
     def start(self) -> "InferenceEngine":

@@ -482,13 +482,13 @@ class RequestObserver:
             census.append(
                 {
                     "request_id": int(slot.req.id),
-                    "blocks": len(slot.blocks),
+                    "blocks": slot.num_blocks,
                     "position": int(slot.position),
                     "generated": int(slot.generated),
                 }
             )
         census.sort(key=lambda e: (-e["blocks"], e["request_id"]))
-        total = cache.num_blocks - 1
+        total = cache.used_blocks + cache.free_blocks
         return {
             "blocks_total": total,
             "blocks_in_use": cache.used_blocks,

@@ -268,9 +268,10 @@ def test_engine_spans(tiny_lm, capture, request):
     )
     assert sum(_int_args(s)["evicted"] for s in ticks["deliver"]) == len(ids)
     # live_blocks_pct: the blocks the decode kernel read this tick over
-    # the blocks the slots' tables span (2 slots x 8 blocks of 8 here);
-    # summed back over the ticks it is stats()'s pair of counters.
-    tabled = 2 * 8
+    # the blocks the slots' tables span (2 slots x 8 blocks of 8 here),
+    # each counted once a layer (2); summed back over the ticks it is
+    # stats()'s pair of counters.
+    tabled = 2 * 2 * 8
     assert stats["kv_blocks_tabled"] == tabled * stats["decode_steps"]
     shares = [float(s[3]["live_blocks_pct"]) for s in ticks["prepare"]]
     assert sum(shares) * tabled / 100.0 == pytest.approx(
@@ -278,8 +279,8 @@ def test_engine_spans(tiny_lm, capture, request):
     )
     # Prompts of 5 and answers of 5: positions 5..8, so one block a slot
     # until position 8 opens the second.
-    assert max(shares) == pytest.approx(100.0 * 4 / tabled)
-    assert min(shares) == pytest.approx(100.0 * 1 / tabled)
+    assert max(shares) == pytest.approx(100.0 * 2 * 4 / tabled)
+    assert min(shares) == pytest.approx(100.0 * 2 * 1 / tabled)
     chain = _named(spans, "request.prefill")
     if request.node.callspec.params["capture"] == "ring":
         # The ring also holds the request chain (written when a request
@@ -300,10 +301,16 @@ def test_engine_stats_agree_with_delivery(tiny_lm):
     assert 0 < stats["slot_steps_active"] <= 2 * stats["decode_steps"]
     assert set(stats) == {"decode_steps", "tokens", "slot_steps_active",
                           "admissions", "evictions", "kv_blocks_live",
-                          "kv_blocks_tabled"}
+                          "kv_blocks_tabled", "kv_blocks_full",
+                          "kv_blocks_window", "kv_blocks_uniform",
+                          "expert_tokens", "experts_touched", "expert_slots"}
     # 3 requests x 4 decode steps at positions 5..8 of 8-token blocks:
-    # one live block each, two at position 8.
-    assert stats["kv_blocks_live"] == 3 * (1 + 1 + 1 + 2)
+    # one live block each, two at position 8, in each of the 2 layers.
+    assert stats["kv_blocks_live"] == 2 * 3 * (1 + 1 + 1 + 2)
+    # No window layer, no expert layer: those counters stay 0.
+    assert all(stats[k] == 0 for k in stats
+               if k.startswith("expert") or k in (
+                   "kv_blocks_full", "kv_blocks_window", "kv_blocks_uniform"))
     assert all(type(v) is int for v in stats.values())
 
 
@@ -419,15 +426,15 @@ def _engine_program_text(tiny_lm, which, slots=2, attention="flash"):
         cache = engine.cache
         if which == "decode":
             lowered = engine._decode_step.lower(
-                variables, cache.k_pool, cache.v_pool,
-                jnp.zeros((slots, engine.max_blocks_per_seq), jnp.int32),
+                variables, cache.k_pools, cache.v_pools,
+                (jnp.zeros((slots, engine.max_blocks_per_seq), jnp.int32),),
                 jnp.zeros((slots,), jnp.int32), jnp.zeros((slots,), jnp.int32),
             )
         else:
             lowered = engine._prefill_step(8).lower(
-                variables, cache.k_pool, cache.v_pool,
+                variables, cache.k_pools, cache.v_pools,
                 jnp.zeros((8,), jnp.int32), jnp.int32(1),
-                jnp.zeros((engine.max_blocks_per_seq,), jnp.int32),
+                (jnp.zeros((engine.max_blocks_per_seq,), jnp.int32),),
             )
         return lowered.as_text(debug_info=True)
     finally:

@@ -98,3 +98,88 @@ def test_paged_decode_attention_rejects_mismatched_shapes():
         paged_decode_reference(q, k_pool, v_pool, tables[:2], lengths)
     with pytest.raises(ValueError, match="layer 2 outside"):
         paged_decode_attention(q, k_pool, v_pool, tables, lengths, layer=2)
+
+
+# ---------------------------------------------------------------------------
+# Grouped heads and a window over a ring of blocks
+# ---------------------------------------------------------------------------
+
+WINDOW = 20
+RING = -(-(WINDOW + BLOCK) // BLOCK)  # 4 blocks hold window + one block
+Q_HEADS, KV_HEADS = 16, 2  # 8 query heads a K/V head
+
+# Below the window, at it, one past it, a block's edge past it, and far
+# enough past it that the ring has wrapped several times.
+RING_LENGTHS = {"idle": 0, "one": 1, "below": WINDOW - 3, "at": WINDOW,
+                "past": WINDOW + 1, "edge": 4 * BLOCK, "past_edge": 4 * BLOCK + 1,
+                "wrapped": 9 * BLOCK + 5}
+
+
+def _ring_case(length, head_dim, window, seed):
+    """Four slots whose sequences were written position by position into
+    their tables (a ring of RING blocks with ``window``, else a plain
+    table), over a pool of garbage. Returns the kernel's arguments and
+    each slot's own keys and values, position by position."""
+    rng = np.random.default_rng(seed)
+    lengths = np.array([length, 0, 7, 6 * BLOCK + 2], np.int32)
+    width = KV_HEADS * head_dim
+    entries = RING if window else -(-int(lengths.max()) // BLOCK)
+    num_blocks = 1 + len(lengths) * entries + 2
+    pools = np.full((2, LAYERS, num_blocks, BLOCK, width), GARBAGE, np.float32)
+    order = iter(rng.permutation(np.arange(1, num_blocks)))
+    tables = np.full((len(lengths), entries), TRASH_BLOCK, np.int32)
+    history = []
+    for slot, n in enumerate(lengths):
+        rows = rng.normal(size=(2, LAYERS, int(n), width))
+        history.append(rows)
+        for p in range(int(n)):
+            entry = (p // BLOCK) % entries
+            if tables[slot, entry] == TRASH_BLOCK:
+                tables[slot, entry] = next(order)
+            pools[:, :, tables[slot, entry], p % BLOCK] = rows[:, :, p]
+    q = rng.normal(size=(len(lengths), Q_HEADS, head_dim))
+    args = (jnp.asarray(q, jnp.float32), jnp.asarray(pools[0]),
+            jnp.asarray(pools[1]), jnp.asarray(tables), jnp.asarray(lengths))
+    return args, history
+
+
+@pytest.mark.parametrize("window", [WINDOW, None], ids=["window", "full"])
+@pytest.mark.parametrize("head_dim", [16, 128])
+@pytest.mark.parametrize("length", sorted(RING_LENGTHS), ids=sorted(RING_LENGTHS))
+def test_paged_decode_attention_grouped_heads_and_window(length, head_dim,
+                                                         window):
+    """8 query heads a K/V head, with and without a window: the kernel
+    (interpret mode), its reference, and a dense softmax over each slot's
+    own last ``window`` positions agree; what the ring has overwritten
+    and the garbage around it never reach the output."""
+    args, history = _ring_case(RING_LENGTHS[length], head_dim, window,
+                               seed=len(length))
+    layer = 1
+    got = np.asarray(paged_decode_attention(*args, layer=layer, window=window))
+    want = np.asarray(paged_decode_reference(*args, layer=layer, window=window))
+    assert got.shape == (4, Q_HEADS, head_dim)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    q = np.asarray(args[0])
+    for slot, n in enumerate(np.asarray(args[4])):
+        if n == 0:
+            np.testing.assert_array_equal(got[slot], 0.0)
+            continue
+        lo = max(0, n - window) if window else 0
+        keys, values = (history[slot][i][layer, lo:n].reshape(
+            -1, KV_HEADS, head_dim) for i in (0, 1))
+        group = Q_HEADS // KV_HEADS
+        keys, values = (np.repeat(a, group, axis=1) for a in (keys, values))
+        scores = np.einsum("hd,thd->ht", q[slot], keys) / np.sqrt(head_dim)
+        probs = np.exp(scores - scores.max(axis=1, keepdims=True))
+        probs /= probs.sum(axis=1, keepdims=True)
+        np.testing.assert_allclose(
+            got[slot], np.einsum("ht,thd->hd", probs, values),
+            rtol=1e-4, atol=1e-4,
+        )
+
+
+def test_paged_decode_attention_rejects_a_ring_too_short_for_its_window():
+    (q, k_pool, v_pool, tables, lengths), _ = _ring_case(5, 16, WINDOW, seed=0)
+    with pytest.raises(ValueError, match="cannot hold a window"):
+        paged_decode_attention(q, k_pool, v_pool, tables, lengths,
+                               window=RING * BLOCK)

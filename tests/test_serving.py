@@ -399,6 +399,35 @@ def test_capacity_queueing_and_block_reuse(model, engine_factory):
     assert eng.cache.free_blocks == 5
 
 
+@pytest.mark.parametrize("busy", [False, True])
+def test_one_admission_an_iteration_while_slots_decode(
+    model, engine_factory, busy
+):
+    """A prefill stalls every active slot, so a queue of waiting
+    requests is taken one an iteration while anything is decoding: a gap
+    between two tokens holds at most one admission. An idle engine
+    fills its slots at once."""
+    rng = np.random.default_rng(11)
+    eng = engine_factory(slots=4, max_queue=8)
+    first = eng.submit(_prompt(rng, 6), 12) if busy else None
+    if busy:
+        eng.step()
+        assert first.status == "active"
+    waiting = [eng.submit(_prompt(rng, 4 + i), 6) for i in range(3)]
+    before = len(first.tokens) if busy else 0
+    admitted = []
+    for _ in range(3):
+        eng.step()
+        admitted.append(sum(r.status != "queued" for r in waiting))
+    assert admitted == ([1, 2, 3] if busy else [3, 3, 3])
+    if busy:
+        # No gap between two of the active slot's tokens held more than
+        # one admission: it was delivered a token every iteration.
+        assert len(first.tokens) == before + 3
+    eng.run()
+    assert all(r.status == "finished" for r in waiting)
+
+
 def test_static_batching_gangs_admissions(model, engine_factory):
     """continuous=False is the A/B baseline: a new group is admitted
     only when every slot has drained, so a short request gangs behind a
@@ -804,7 +833,7 @@ def test_engine_close_fails_pending_and_drops_pools(model):
         if m["name"] == "serving.admission_rejects"
     }
     assert snap[("serving.admission_rejects", (("reason", "shutdown"),))] == 2
-    assert eng.cache._k_pool is None and eng.cache._v_pool is None
+    assert all(k.k_pool is None and k.v_pool is None for k in eng.cache.kinds)
     assert eng.cache.free_blocks == eng.cache.num_blocks - 1
     assert serving.get_engine() is None
 
@@ -1119,7 +1148,7 @@ def test_queue_full_load_shed_writes_debug_bundle_once(
     assert srv["blocks_total"] == eng.cache.num_blocks - 1
     assert srv["blocks_in_use"] > 0
     assert srv["census"][0]["request_id"] == held.id
-    assert srv["census"][0]["blocks"] == len(eng._slots[0].blocks)
+    assert srv["census"][0]["blocks"] == eng._slots[0].num_blocks
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     proc = subprocess.run(
         [sys.executable,

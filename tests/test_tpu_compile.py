@@ -195,12 +195,12 @@ def test_gpt2_medium_serving_programs_compile_for_v5e(topo, as_on_tpu):
         pool = _sds(engine.cache.pool_shape, jnp.bfloat16, dev)
         mb = engine.max_blocks_per_seq
         decode = engine._decode_step.lower(
-            params, pool, pool, _sds((32, mb), jnp.int32, dev),
+            params, (pool,), (pool,), (_sds((32, mb), jnp.int32, dev),),
             _sds((32,), jnp.int32, dev), _sds((32,), jnp.int32, dev),
         ).compile()
         prefill = engine._prefill_step(256).lower(
-            params, pool, pool, _sds((256,), jnp.int32, dev),
-            _sds((), jnp.int32, dev), _sds((mb,), jnp.int32, dev),
+            params, (pool,), (pool,), _sds((256,), jnp.int32, dev),
+            _sds((), jnp.int32, dev), (_sds((mb,), jnp.int32, dev),),
         ).compile()
     finally:
         engine.close()
@@ -209,6 +209,75 @@ def test_gpt2_medium_serving_programs_compile_for_v5e(topo, as_on_tpu):
         memory = program.memory_analysis()
         assert memory.temp_size_in_bytes < 2**29
         assert memory.alias_size_in_bytes >= 2 * 1.6e9  # pools in place
+
+
+def test_trinity_mini_serving_programs_compile_for_v5e(topo, as_on_tpu):
+    """The ``trinity-mini-serve`` cell's decode program and one prefill
+    bucket at the published widths (32 query over 4 K/V heads of 128, 128
+    experts top-8, vocabulary 200,192; 64 slots x 8,704 positions in
+    512-token blocks): one paged kernel a layer, grouped and windowed,
+    XLA's grouped matmul (a Mosaic kernel of its own) three times an
+    expert layer, the ring and the full pool updated in place, and
+    bfloat16 weights of 8.5 GB beside them within one chip."""
+    import importlib.util
+    import json
+
+    from fluxmpi_tpu.serving import InferenceEngine
+
+    configs = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "configs")
+
+    def load(name):
+        spec = importlib.util.spec_from_file_location(
+            name.replace(".", "_"), os.path.join(configs, name))
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        return module
+
+    with open(os.path.join(configs, "trinity-mini.json"),
+              encoding="utf-8") as f:
+        cfg = json.load(f)
+    prog, ref = load("trinity.program.py"), load("trinity.reference.py")
+    dev = topo.devices[0]
+    params = jax.tree_util.tree_map(
+        lambda x: _sds(x.shape, x.dtype, dev),
+        jax.eval_shape(
+            lambda key: prog.to_program(ref.make_weights(cfg, key), cfg)[0],
+            jax.random.PRNGKey(0),
+        ),
+    )
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert 8.4e9 < weights < 8.6e9
+    engine = InferenceEngine(
+        prog.build_model(cfg, "naive"), params, attention="flash", slots=64,
+        block_size=512, max_len=8704, check_memory=False,
+    )
+    try:
+        cache = engine.cache
+        assert cache.pool_shapes == [(1, 1089, 512, 512), (4, 321, 512, 512)]
+        pools = tuple(_sds(s, jnp.bfloat16, dev) for s in cache.pool_shapes)
+        decode = engine._decode_step.lower(
+            params, pools, pools,
+            tuple(_sds((64, k.entries), jnp.int32, dev) for k in cache.kinds),
+            _sds((64,), jnp.int32, dev), _sds((64,), jnp.int32, dev),
+        ).compile()
+        prefill = engine._prefill_step(2560).lower(
+            params, pools, pools, _sds((2560,), jnp.int32, dev),
+            _sds((), jnp.int32, dev),
+            tuple(_sds((k.entries,), jnp.int32, dev) for k in cache.kinds),
+        ).compile()
+    finally:
+        engine.close()
+    # 5 paged kernels; 4 expert layers x (3 grouped matmuls + their
+    # shared metadata kernel).
+    assert decode.as_text().count("tpu_custom_call") == 5 + 4 * 4
+    for program, temporaries in ((decode, 2**27), (prefill, 2**30)):
+        memory = program.memory_analysis()
+        assert memory.temp_size_in_bytes < temporaries
+        assert memory.alias_size_in_bytes >= cache.pool_bytes  # in place
+        assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                < 14e9)
 
 
 def _lm_state(cfg, optimizer):
