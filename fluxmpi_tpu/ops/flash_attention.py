@@ -21,9 +21,15 @@ and plain padding masks (valid → 1, pad → 0); the mask folds into the
 kernel's score step and fully-masked tiles skip their compute entirely
 (block-sparse), so a padded batch costs proportionally less, not more.
 
-Block sizes default to MXU/VPU-friendly shapes (128 lanes; f32 accumulation
-regardless of input dtype). On non-TPU backends the kernels run in Pallas
-interpret mode, which is how the CPU test suite exercises them.
+Operands go to the MXU in the dtype they arrive in (bfloat16 multiplies
+in one pass; float32 callers keep float32 products); the softmax
+statistics, the accumulators and the scale stay float32. A grid step's
+block is worked in sub-tiles under loops that run only from the window's
+far edge to the causal frontier, and only a sub-tile the mask cuts
+computes it (:func:`_walk`); block and sub-tile sizes are a pure function
+of the static shapes (:func:`_tile_rule`). On non-TPU backends the kernels
+run in Pallas interpret mode, which is how the CPU test suite exercises
+them.
 """
 
 from __future__ import annotations
@@ -57,7 +63,8 @@ _NEG_INF = -1e30
 # or the full array dim. 1-D per-row operands therefore travel
 # sublane-replicated ([.., 8, s], read as a [1, block] row — lse/dterm
 # everywhere, at 8× HBM) or lane-replicated ([.., s, 128], read as a
-# [block, 1] column — the per-batch segment q-ids in the fwd/dq kernels),
+# [block, 1] column — the segment ids of whichever side a kernel's tiles
+# put on sublanes: queries in dq, keys in the forward and dkv),
 # matching the orientation each kernel consumes them in; the dq kernel's
 # lse/dterm reads pay one in-register row→column transpose per tile
 # instead of a 128× lane-replicated buffer (ADVICE r3 #2).
@@ -76,46 +83,192 @@ def _as_row(x):
     return jnp.broadcast_to(x[:, None, :], (b, _SUBLANES, s))
 
 
-def _row_spec(block: int, order):
-    """BlockSpec for a sublane-replicated [b, 8, s] operand."""
-    return pl.BlockSpec((1, _SUBLANES, block), lambda g0, g1, g2: (g0, 0, order(g1, g2)))
+def _pos_mask(shape, offset, window: int | None = None,
+              causal: bool = True, transposed: bool = False):
+    """Positional mask of a tile of ``shape`` = [queries, keys] (or
+    [keys, queries] with ``transposed``, the dkv kernel's tiles) whose
+    first query sits ``offset`` positions after its first key: True =
+    attend. With ``causal``, requires ``q_pos >= k_pos``; with ``window``,
+    additionally requires ``q_pos - k_pos < window`` (sliding-window /
+    local attention, Mistral-style). ``causal=False`` with a window is the
+    band-only mode: only the upper displacement bound applies — the
+    ring-attention past-block primitive, where the causal floor is
+    satisfied globally by the block's ring offset (parallel/ring.py
+    windowed flash schedule). At least one of the two must be active.
 
-
-def _pos_mask(qi, kj, block_q: int, block_k: int, window: int | None = None,
-              causal: bool = True):
-    """Positional mask for the (qi, kj) tile: True = attend. With
-    ``causal``, requires ``q_pos >= k_pos``; with ``window``, additionally
-    requires ``q_pos - k_pos < window`` (sliding-window / local attention,
-    Mistral-style). ``causal=False`` with a window is the band-only mode:
-    only the upper displacement bound applies — the ring-attention
-    past-block primitive, where the causal floor is satisfied globally by
-    the block's ring offset (parallel/ring.py windowed flash schedule).
-    At least one of the two must be active."""
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-    k_pos = kj * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
-    mask = q_pos >= k_pos if causal else None
+    ``q_pos - k_pos`` is the in-tile difference of two iotas plus the
+    scalar ``offset``, so each bound costs one compare an element."""
+    q_dim = 1 if transposed else 0
+    diff = jax.lax.broadcasted_iota(
+        jnp.int32, shape, q_dim
+    ) - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
+    mask = diff >= -offset if causal else None
     if window is not None:
-        band = q_pos - k_pos < window
+        band = diff < window - offset
         mask = band if mask is None else mask & band
     return mask
 
 
-def _window_tile_live(qi, kj, block_q: int, block_k: int, window: int):
-    """Static tile-skip predicate for the sliding-window band: the tile has
-    an in-window pair iff its closest (first q row, last k col) pair is
-    within the window. Shared by all three kernels so forward and backward
-    masking cannot desynchronize."""
-    return qi * block_q - ((kj + 1) * block_k - 1) < window
+def _when(pred):
+    """``pl.when`` that also takes a predicate known while tracing (a grid
+    axis of one step is index 0 there)."""
+    if isinstance(pred, bool):
+        return lambda fn: fn() if pred else None
+    return pl.when(pred)
 
 
-def _seg_mask(qseg_col, kseg_row):
+def _div(a, b: int):
+    """``a // b`` of a grid index or an offset, never negative, so the
+    truncating ``lax.div`` is the floor. ``//`` on a traced integer is
+    ``div`` corrected by ``sign``s, each of which Pallas lowers by
+    tracing a helper: 3 ms an operation, for every index map and kernel
+    of every program that holds one, at every start (PERF.md §6, PR 29:
+    half of what lowering a kernel cost)."""
+    if isinstance(a, int) or b == 1:
+        return a // b
+    return jax.lax.div(a, jnp.asarray(b, a.dtype))
+
+
+def _rem(a, b: int):
+    """``a % b`` of a value that is never negative (:func:`_div`)."""
+    if isinstance(a, int):
+        return a % b
+    if b == 1:
+        return jnp.zeros_like(a)
+    return jax.lax.rem(a, jnp.asarray(b, a.dtype))
+
+
+def _sub_range(lo, hi, sub: int, n: int):
+    """Sub-tiles ``[first, last)`` of the ``n`` sub-tiles of ``sub``
+    positions a block holds that touch its positions ``[lo, hi)``, each
+    bound clamped into the block (``None``: the block's own end). Python
+    ints where the bounds are."""
+    def clamp(x):
+        if isinstance(x, int):
+            return min(max(x, 0), n * sub)
+        return jnp.clip(x, 0, n * sub)
+
+    first = 0 if lo is None else _div(clamp(lo), sub)
+    last = n if hi is None else _div(clamp(hi) + sub - 1, sub)
+    return first, last
+
+
+def _offsets(first, last, sub: int, n: int, visit, unroll: bool):
+    """``visit(offset)`` for the sub-tiles ``[first, last)`` of a block of
+    ``n``: a ``fori_loop`` whose bounds may come from ``program_id``.
+    With ``unroll`` (bounds known while tracing) the visits are traced
+    back to back; a block of one sub-tile is visited at the static
+    offset 0."""
+    if unroll:
+        for i in range(first, last):
+            visit(i * sub)
+    elif n == 1:
+        _when(last > first)(lambda: visit(0))
+    else:
+        def body(i, carry):
+            visit(pl.multiple_of(i * sub, sub))
+            return carry
+
+        jax.lax.fori_loop(first, last, body, 0)
+
+
+def _walk(tile, qi, kj, tiles, causal: bool, window: int | None,
+          always_masked: bool, k_outer: bool = False, unroll: bool = False):
+    """``tile(r0, c0, masked)`` for each ``(sub_q, sub_k)`` sub-tile of
+    the ``(block_q, block_k)`` block of grid step ``(qi, kj)`` that keeps
+    a pair, ``masked`` where the position mask drops one (or always:
+    segments, dropout). ``r0`` / ``c0`` are the sub-tile's offsets into
+    the block. Two nested loops, query sub-tiles outermost (key sub-tiles
+    with ``k_outer``, the dkv kernel); the inner one runs from the
+    window's far edge to the causal frontier, both computed from the
+    outer offset and ``program_id``, so a sub-tile wholly in the future of
+    its queries or wholly behind the window is never visited, and ``tile``
+    is traced at most twice (an unmasked interior, a masked edge)
+    however long the sequence. With ``unroll``, where one block is the
+    whole problem (both grid axes one step, every bound known while
+    tracing), the live sub-tiles are traced back to back instead, each
+    with the body it needs: the backward kernels, which only training
+    runs, one shape a model (0.59 / 0.87 ms a call against 0.67 / 0.88
+    under the loops at gpt2m-train's shape, PERF.md §6, PR 29). Shared by
+    all three kernels so forward and backward masking cannot
+    desynchronize.
+
+    Of a sub-tile whose first query and key sit at ``q`` and ``k``: it
+    keeps a pair iff ``k <= q + sub_q - 1`` (causal: its first key is not
+    in the future of its last query) and ``q - (k + sub_k - 1) < window``
+    (its closest pair is inside the window); the mask drops a pair iff
+    ``k + sub_k - 1 > q`` (the diagonal crosses it) or
+    ``q + sub_q - 1 - k >= window`` (the window's edge does)."""
+    block_q, block_k, sub_q, sub_k = tiles
+    q0, k0 = qi * block_q, kj * block_k
+    nq, nk = block_q // sub_q, block_k // sub_k
+    positional = causal or window is not None
+    unroll = unroll and isinstance(q0, int) and isinstance(k0, int)
+
+    def visit(r0, c0):
+        if always_masked or not positional:
+            tile(r0, c0, always_masked)
+            return
+        q, k = q0 + r0, k0 + c0
+        cuts = []
+        if causal:
+            cuts.append(k + sub_k - 1 > q)
+        if window is not None:
+            cuts.append(q + sub_q - 1 - k >= window)
+        if isinstance(cuts[0], bool):  # known while tracing
+            tile(r0, c0, any(cuts))
+            return
+        cut = functools.reduce(jnp.logical_or, cuts)
+        pl.when(cut)(functools.partial(tile, r0, c0, True))
+        pl.when(jnp.logical_not(cut))(functools.partial(tile, r0, c0, False))
+
+    if k_outer:
+        def keys(c0):
+            k = k0 + c0 - q0  # first key, from the block's first query
+            first, last = _sub_range(
+                k if causal else None,
+                None if window is None else k + sub_k - 1 + window,
+                sub_q, nq)
+            _offsets(first, last, sub_q, nq, lambda r0: visit(r0, c0), unroll)
+
+        _offsets(0, nk, sub_k, nk, keys, unroll)
+    else:
+        def queries(r0):
+            q = q0 + r0 - k0  # first query, from the block's first key
+            first, last = _sub_range(
+                None if window is None else q - window + 1,
+                q + sub_q if causal else None,
+                sub_k, nk)
+            _offsets(first, last, sub_k, nk, lambda c0: visit(r0, c0), unroll)
+
+        _offsets(0, nq, sub_q, nq, queries, unroll)
+
+
+def _operands(*tiles, rows: int = _LANES):
+    """The tiles as the MXU takes them: in the dtype the caller gave
+    (bfloat16 multiplies in one pass, float32 stays float32), promoted to
+    a common one only where the caller mixed them. Under 8 ``rows`` of
+    queries (a decode step's one) the products are matrix-vector ones,
+    which Pallas multiplies out on the VPU, in float32 only."""
+    dtype = jnp.float32 if rows < _SUBLANES else jnp.result_type(*tiles)
+    return tuple(t.astype(dtype) for t in tiles)
+
+
+def _split_scale(d: int):
+    """``(q_scale, s_scale)`` with ``q_scale * s_scale == 1 / sqrt(d)``:
+    a power of two (head_dim 16, 64, 256) multiplies Q exactly in any
+    float dtype, once an element of Q outside the kernels (XLA fuses it
+    into the head fold); any other scale stays on the float32 scores."""
+    scale = 1.0 / (d**0.5)
+    exact = float(np.frexp(scale)[0]) == 0.5
+    return (scale, 1.0) if exact else (1.0, scale)
+
+
+def _seg_mask(qseg, kseg):
     """Segment mask: attend iff same segment and key is not padding (id 0).
-    qseg_col: [bq, 1], kseg_row: [1, bk] int32 → bool [bq, bk]."""
-    return (qseg_col == kseg_row) & (kseg_row != 0)
+    ``qseg`` [bq, 1] and ``kseg`` [1, bk] int32 → bool [bq, bk]; a
+    [keys, queries] tile passes a [1, bq] row and a [bk, 1] column."""
+    return (qseg == kseg) & (kseg != 0)
 
 
 def _hash_mix(h, k):
@@ -157,49 +310,21 @@ def _dropout_keep(seed, bh, q_pos, k_pos, keep_prob):
     return bits < threshold
 
 
-def _tile_dropout(p, seed, bh, qi, kj, block_q, block_k, keep_prob,
-                  transposed=False):
-    """Apply the deterministic dropout mask to a probability tile.
-    ``transposed=True`` builds the [block_k, block_q] tile the dkv kernel
-    uses (same (q, k) hash inputs, swapped iota orientation)."""
-    if transposed:
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, block_q), 0
-        )
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, block_q), 1
-        )
-    else:
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-    keep = _dropout_keep(seed, bh, q_pos, k_pos, keep_prob)
-    return jnp.where(keep, p / keep_prob, 0.0)
+def _tile_keep(shape, seed, bh, q_start, k_start, keep_prob,
+               transposed=False):
+    """The deterministic dropout keep-mask of a tile of ``shape`` whose
+    first query and key sit at ``q_start`` / ``k_start``.
+    ``transposed=True`` is the [keys, queries] tile the dkv kernel uses
+    (same (q, k) hash inputs, swapped iota orientation)."""
+    q_dim = 1 if transposed else 0
+    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
+    return _dropout_keep(seed, bh, q_pos, k_pos, keep_prob)
 
 
-def _and_preds(preds):
-    out = preds[0]
-    for p in preds[1:]:
-        out = jnp.logical_and(out, p)
-    return out
-
-
-def _flash_kernel(
-    *refs,
-    sm_scale: float,
-    causal: bool,
-    window: int | None,
-    has_segments: bool,
-    block_q: int,
-    block_k: int,
-    num_k_blocks: int,
-    dropout_rate: float = 0.0,
-):
+def _unpack_refs(refs, has_segments: bool, dropout_rate: float):
+    """(q, k, v, qseg, kseg, seed, rest) of a kernel's positional refs."""
     refs = list(refs)
-    q_ref, k_ref, v_ref = refs[:3]
     pos = 3
     qseg_ref = kseg_ref = seed_ref = None
     if has_segments:
@@ -208,243 +333,265 @@ def _flash_kernel(
     if dropout_rate:
         seed_ref = refs[pos]
         pos += 1
-    (o_ref, lse_ref, m_scratch, l_scratch, acc_scratch) = refs[pos:]
+    return (*refs[:3], qseg_ref, kseg_ref, seed_ref, refs[pos:])
+
+
+def _flash_kernel(
+    *refs,
+    s_scale: float,
+    causal: bool,
+    window: int | None,
+    has_segments: bool,
+    tiles: tuple[int, int, int, int],
+    num_q_blocks: int,
+    num_k_blocks: int,
+    dropout_rate: float = 0.0,
+):
+    """Forward: one Q block against one K/V block a grid step, worked in
+    ``(sub_q, sub_k)`` sub-tiles (:func:`_walk`), the online softmax
+    carried in VMEM scratch across sub-tiles and grid steps. Tiles are
+    [keys, queries], queries on the lane axis as in the dkv kernel: the
+    row statistics ``m`` and ``l`` are lane-dense [1, sub_q] rows (a
+    [sub_q, 1] column costs a register for every 8 queries, in every
+    statistic's every operation), and the reductions run over sublanes.
+    V arrives transposed ([d, keys]) and the output leaves transposed
+    ([d, queries]); Q arrives carrying whatever part of the softmax scale
+    is not ``s_scale`` (:func:`_split_scale`)."""
+    (q_ref, k_ref, vt_ref, qseg_ref, kseg_ref, seed_ref,
+     (o_ref, lse_ref, m_scratch, l_scratch, acc_scratch)) = _unpack_refs(
+        refs, has_segments, dropout_rate)
+    block_q, block_k, sub_q, sub_k = tiles
 
     bh = pl.program_id(0)
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    # A grid axis of one step is known while tracing.
+    qi = 0 if num_q_blocks == 1 else pl.program_id(1)
+    kj = 0 if num_k_blocks == 1 else pl.program_id(2)
 
-    @pl.when(kj == 0)
+    @_when(kj == 0)
     def _init():
         m_scratch[...] = jnp.full_like(m_scratch, _NEG_INF)
         l_scratch[...] = jnp.zeros_like(l_scratch)
         acc_scratch[...] = jnp.zeros_like(acc_scratch)
 
-    def _tile_mask():
+    def _tile(r0, c0, masked):
+        rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
+        q_start = qi * block_q + r0
+        k_start = kj * block_k + c0
         mask = None
-        if causal or window is not None:
-            mask = _pos_mask(qi, kj, block_q, block_k, window, causal)
+        if masked and (causal or window is not None):
+            mask = _pos_mask((sub_k, sub_q), q_start - k_start, window,
+                             causal, transposed=True)
         if has_segments:
-            # qseg lane-replicated → [block_q, 1] column; kseg
-            # sublane-replicated → [1, block_k] row.
-            sm = _seg_mask(qseg_ref[0][:, :1], kseg_ref[0][:1, :])
+            # kseg lane-replicated → [sub_k, 1] column; qseg
+            # sublane-replicated → [1, sub_q] row.
+            sm = _seg_mask(qseg_ref[0, :1, rows], kseg_ref[0, cols, :1])
             mask = sm if mask is None else jnp.logical_and(mask, sm)
-        return mask
 
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)  # [block_q, d]
-        k = k_ref[0].astype(jnp.float32)  # [block_k, d]
-        v = v_ref[0].astype(jnp.float32)  # [block_k, d]
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale  # [block_q, block_k]
-
-        mask = _tile_mask()
-        if mask is not None:
-            s = jnp.where(mask, s, _NEG_INF)
-
-        m_prev = m_scratch[...]  # [block_q, 128] (value replicated over lanes)
-        l_prev = l_scratch[...]
-        m_cur = jnp.max(s, axis=1, keepdims=True)  # [block_q, 1]
-        m_cur = jnp.broadcast_to(m_cur, m_prev.shape)
-        m_new = jnp.maximum(m_prev, m_cur)
-
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, :1])  # [block_q, block_k]
-        if mask is not None:
-            p = jnp.where(mask, p, 0.0)
-        # Softmax normalization (l) accumulates UNdropped probabilities —
-        # dropout applies after normalization (flax semantics); only the
-        # value accumulation sees the dropped, 1/keep_prob-scaled tile.
-        l_new = l_prev * alpha + jnp.broadcast_to(
-            jnp.sum(p, axis=1, keepdims=True), l_prev.shape
-        )
-        if dropout_rate:
-            p = _tile_dropout(
-                p, seed_ref[0, 0], bh, qi, kj, block_q, block_k,
-                1.0 - dropout_rate,
+        def _compute():
+            q, k, vt = _operands(
+                q_ref[0, rows, :], k_ref[0, cols, :], vt_ref[0, :, cols],
+                rows=sub_q,
             )
+            s_t = jax.lax.dot_general(
+                k, q, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [sub_k, sub_q]
+            if s_scale != 1.0:
+                s_t = s_t * s_scale
+            if mask is not None:
+                s_t = jnp.where(mask, s_t, _NEG_INF)
 
-        acc_scratch[...] = acc_scratch[...] * alpha[:, :1] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scratch[...] = m_new
-        l_scratch[...] = l_new
+            m_prev = m_scratch[:1, rows]  # [1, sub_q]
+            l_prev = l_scratch[:1, rows]
+            m_new = jnp.maximum(m_prev, jnp.max(s_t, axis=0, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p_t = jnp.exp(s_t - m_new)
+            if mask is not None and (window is not None or has_segments):
+                # A query with no kept key so far has m_new = -1e30, and
+                # its masked scores would exponentiate to 1. Under the
+                # causal mask alone no such query exists (the first
+                # sub-tile visited holds key 0, which every query sees),
+                # so exp has already zeroed what s_t masked.
+                p_t = jnp.where(mask, p_t, 0.0)
+            # Softmax normalization (l) accumulates UNdropped probabilities
+            # — dropout applies after normalization (flax semantics); only
+            # the value accumulation sees the dropped, 1/keep_prob-scaled
+            # tile.
+            l_new = l_prev * alpha + jnp.sum(p_t, axis=0, keepdims=True)
+            if dropout_rate:
+                kp = 1.0 - dropout_rate
+                keep = _tile_keep(p_t.shape, seed_ref[0, 0], bh, q_start,
+                                  k_start, kp, transposed=True)
+                p_t = jnp.where(keep, p_t / kp, 0.0)
 
-    # Skip tiles with no attendable pair: statically-shaped predicates — the
-    # causal frontier (kj strictly in the future of every query) and, with
-    # segments, any-overlap of the tile's segment ids (block-sparse skip of
-    # fully-masked/fully-padded tiles).
-    preds = []
-    if causal:
-        preds.append(kj * block_k < (qi + 1) * block_q)
-    if window is not None:
-        preds.append(_window_tile_live(qi, kj, block_q, block_k, window))
-    if has_segments:
-        preds.append(
-            jnp.any(_seg_mask(qseg_ref[0][:, :1], kseg_ref[0][:1, :]))
-        )
-    if preds:
-        @pl.when(_and_preds(preds))
-        def _():
+            # p is narrowed to the operand dtype only as an operand of
+            # its own product; statistics and accumulators stay f32.
+            acc_scratch[:, rows] = acc_scratch[:, rows] * alpha + (
+                jax.lax.dot_general(
+                    vt, p_t.astype(vt.dtype), (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            )  # [d, sub_q]
+            m_scratch[:, rows] = jnp.broadcast_to(m_new, (_SUBLANES, sub_q))
+            l_scratch[:, rows] = jnp.broadcast_to(l_new, (_SUBLANES, sub_q))
+
+        if has_segments:
+            # Block-sparse skip of fully-masked / fully-padded sub-tiles.
+            pl.when(jnp.any(mask))(_compute)
+        else:
             _compute()
-    else:
-        _compute()
 
-    @pl.when(kj == num_k_blocks - 1)
+    _walk(_tile, qi, kj, tiles, causal, window,
+          has_segments or bool(dropout_rate))
+
+    @_when(kj == num_k_blocks - 1)
     def _finish():
-        l_final = l_scratch[...][:, :1]
+        l_final = l_scratch[:1, :]
         l_safe = jnp.where(l_final == 0.0, 1.0, l_final)
         o_ref[0] = (acc_scratch[...] / l_safe).astype(o_ref.dtype)
-        # Rows with no attendable keys get lse = m = -1e30 (≈ -inf), which
-        # merges as a zero-weight block in ring accumulation. Written
-        # sublane-replicated ([8, block_q]: one in-register transpose per
-        # q-block) — 8× HBM instead of the 128× a lane-replicated
-        # [block_q, 128] layout costs (ADVICE r3 #2).
-        lse_col = m_scratch[...][:, :1] + jnp.log(l_safe)  # [block_q, 1]
+        # Queries with no attendable key get lse = m = -1e30 (≈ -inf),
+        # which merges as a zero-weight block in ring accumulation.
+        # Sublane-replicated ([8, block_q]): 8× HBM instead of the 128× a
+        # lane-replicated [block_q, 128] layout costs (ADVICE r3 #2).
         lse_ref[0] = jnp.broadcast_to(
-            jnp.transpose(lse_col), (_SUBLANES, lse_col.shape[0])
+            m_scratch[:1, :] + jnp.log(l_safe), (_SUBLANES, block_q)
         )
 
 
 def _flash_bwd_dq_kernel(
     *refs,
     sm_scale: float,
+    s_scale: float,
     causal: bool,
     window: int | None,
     has_segments: bool,
-    block_q: int,
-    block_k: int,
+    tiles: tuple[int, int, int, int],
+    num_q_blocks: int,
     num_k_blocks: int,
     dropout_rate: float = 0.0,
 ):
-    """dQ pass: for each Q block, sweep K/V blocks (innermost grid dim),
+    """dQ pass: for each Q block, sweep K/V blocks (innermost grid dim) in
+    sub-tiles (the forward's :func:`_walk`, on [queries, keys] tiles),
     recompute probabilities from the saved lse, accumulate
-    ``dq += (p ∘ (dp - dterm)) @ K · scale`` in VMEM scratch."""
-    refs = list(refs)
-    q_ref, k_ref, v_ref = refs[:3]
-    pos = 3
-    qseg_ref = kseg_ref = seed_ref = None
-    if has_segments:
-        qseg_ref, kseg_ref = refs[pos:pos + 2]
-        pos += 2
-    if dropout_rate:
-        seed_ref = refs[pos]
-        pos += 1
-    (do_ref, lse_ref, dterm_ref, dq_ref, dq_scratch) = refs[pos:]
+    ``dq += (p ∘ (dp - dterm)) @ K`` in VMEM scratch; ``sm_scale``
+    multiplies the sum once, in ``_finish``."""
+    (q_ref, k_ref, v_ref, qseg_ref, kseg_ref, seed_ref,
+     (do_ref, lse_ref, dterm_ref, dq_ref, dq_scratch)) = _unpack_refs(
+        refs, has_segments, dropout_rate)
+    block_q, block_k, sub_q, sub_k = tiles
 
     bh = pl.program_id(0)
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    qi = 0 if num_q_blocks == 1 else pl.program_id(1)
+    kj = 0 if num_k_blocks == 1 else pl.program_id(2)
 
-    @pl.when(kj == 0)
+    @_when(kj == 0)
     def _init():
         dq_scratch[...] = jnp.zeros_like(dq_scratch)
 
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)  # [block_q, d]
-        k = k_ref[0].astype(jnp.float32)  # [block_k, d]
-        v = v_ref[0].astype(jnp.float32)  # [block_k, d]
-        do = do_ref[0].astype(jnp.float32)  # [block_q, d]
-        # lse/dterm arrive sublane-replicated ([8, block_q] rows — the 8×
-        # layout, ADVICE r3 #2); one in-register transpose per tile gives
-        # the [block_q, 1] column the score math broadcasts against.
-        lse = jnp.transpose(lse_ref[0][:1, :])  # [block_q, 1]
-        dterm = jnp.transpose(dterm_ref[0][:1, :])  # [block_q, 1] — delta - dlse
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale  # [block_q, block_k]
-        p = jnp.exp(s - lse)  # normalized probabilities
+    def _tile(r0, c0, masked):
+        rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
+        q_start = qi * block_q + r0
+        k_start = kj * block_k + c0
         mask = None
-        if causal or window is not None:
-            mask = _pos_mask(qi, kj, block_q, block_k, window, causal)
+        if masked and (causal or window is not None):
+            mask = _pos_mask((sub_q, sub_k), q_start - k_start, window, causal)
         if has_segments:
-            sm = _seg_mask(qseg_ref[0][:, :1], kseg_ref[0][:1, :])
+            sm = _seg_mask(qseg_ref[0, rows, :1], kseg_ref[0, :1, cols])
             mask = sm if mask is None else jnp.logical_and(mask, sm)
-        if mask is not None:
-            p = jnp.where(mask, p, 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [block_q, block_k]
-        if dropout_rate:
-            # ds = w ∘ (d∘dp/kp − delta): the dropout mask lands on dp; the
-            # delta term (rowsum dO∘O) already carries the dropped forward.
-            dp = _tile_dropout(
-                dp, seed_ref[0, 0], bh, qi, kj, block_q, block_k,
-                1.0 - dropout_rate,
+
+        def _compute():
+            q, k, v, do = _operands(
+                q_ref[0, rows, :], k_ref[0, cols, :], v_ref[0, cols, :],
+                do_ref[0, rows, :],
             )
-        ds = p * (dp - dterm) * sm_scale
-        dq_scratch[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+            # lse/dterm arrive sublane-replicated ([8, block_q] rows — the
+            # 8× layout, ADVICE r3 #2); one in-register transpose per
+            # sub-tile gives the [sub_q, 1] column the score math
+            # broadcasts against.
+            lse = jnp.transpose(lse_ref[0, :1, rows])  # [sub_q, 1]
+            dterm = jnp.transpose(dterm_ref[0, :1, rows])  # delta - dlse
 
-    preds = []
-    if causal:
-        preds.append(kj * block_k < (qi + 1) * block_q)
-    if window is not None:
-        preds.append(_window_tile_live(qi, kj, block_q, block_k, window))
-    if has_segments:
-        preds.append(
-            jnp.any(_seg_mask(qseg_ref[0][:, :1], kseg_ref[0][:1, :]))
-        )
-    if preds:
-        @pl.when(_and_preds(preds))
-        def _():
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [sub_q, sub_k]
+            if s_scale != 1.0:
+                s = s * s_scale
+            p = jnp.exp(s - lse)  # normalized probabilities
+            if mask is not None:
+                p = jnp.where(mask, p, 0.0)
+            dp = jax.lax.dot_general(
+                do, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [sub_q, sub_k]
+            if dropout_rate:
+                # ds = w ∘ (d∘dp/kp − delta): the dropout mask lands on
+                # dp; the delta term (rowsum dO∘O) already carries the
+                # dropped forward.
+                kp = 1.0 - dropout_rate
+                keep = _tile_keep(dp.shape, seed_ref[0, 0], bh, q_start,
+                                  k_start, kp)
+                dp = jnp.where(keep, dp / kp, 0.0)
+            ds = p * (dp - dterm)
+            dq_scratch[rows, :] += jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+
+        if has_segments:
+            pl.when(jnp.any(mask))(_compute)
+        else:
             _compute()
-    else:
-        _compute()
 
-    @pl.when(kj == num_k_blocks - 1)
+    _walk(_tile, qi, kj, tiles, causal, window,
+          has_segments or bool(dropout_rate), unroll=True)
+
+    @_when(kj == num_k_blocks - 1)
     def _finish():
-        dq_ref[0] = dq_scratch[...].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scratch[...] * sm_scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(
     *refs,
-    sm_scale: float,
+    s_scale: float,
     causal: bool,
     window: int | None,
     has_segments: bool,
-    block_q: int,
-    block_k: int,
+    tiles: tuple[int, int, int, int],
     num_q_blocks: int,
+    num_k_blocks: int,
     total_q_iters: int,
     dropout_rate: float = 0.0,
     h: int = 0,
     h_kv: int = 0,
 ):
     """dK/dV pass: for each K/V block, sweep Q blocks — and, under GQA, the
-    whole query-head group — in the innermost grid dim, accumulating
-    ``dv += pᵀ @ dO`` and ``dk += (p ∘ (dp - dterm))ᵀ @ Q · scale`` in f32
-    VMEM scratch (transposed forms computed directly to keep the
-    contraction on the MXU). One grid row per KV head: the group-summed
-    gradient is written once, full f32 accumulation, no q-head-granularity
-    HBM temporaries."""
-    refs = list(refs)
-    q_ref, k_ref, v_ref = refs[:3]
-    pos = 3
-    qseg_ref = kseg_ref = seed_ref = None
-    if has_segments:
-        qseg_ref, kseg_ref = refs[pos:pos + 2]
-        pos += 2
-    if dropout_rate:
-        seed_ref = refs[pos]
-        pos += 1
-    (do_ref, lse_ref, dterm_ref, dk_ref, dv_ref,
-     dk_scratch, dv_scratch) = refs[pos:]
+    whole query-head group — in the innermost grid dim, each (K, Q) block
+    pair in sub-tiles (:func:`_walk`, key sub-tiles outermost),
+    accumulating ``dv += pᵀ @ dO`` and
+    ``dk += (p ∘ (dp - dterm))ᵀ @ Q`` in f32 VMEM scratch (transposed forms
+    computed directly to keep the contraction on the MXU). One grid row
+    per KV head: the group-summed gradient is written once, full f32
+    accumulation, no q-head-granularity HBM temporaries. Q arrives
+    carrying whatever part of the softmax scale is not ``s_scale``, so dK
+    needs ``s_scale`` alone, in ``_finish``."""
+    (q_ref, k_ref, v_ref, qseg_ref, kseg_ref, seed_ref,
+     (do_ref, lse_ref, dterm_ref, dk_ref, dv_ref,
+      dk_scratch, dv_scratch)) = _unpack_refs(
+        refs, has_segments, dropout_rate)
+    block_q, block_k, sub_q, sub_k = tiles
 
     g0 = pl.program_id(0)  # b·h_kv + kv_head (kv-head-major grid row)
-    kj = pl.program_id(1)
+    kj = 0 if num_k_blocks == 1 else pl.program_id(1)
     it = pl.program_id(2)  # group-major: it = group_idx·num_q_blocks + qi
-    qi = it % num_q_blocks
+    qi = 0 if num_q_blocks == 1 else _rem(it, num_q_blocks)
     if dropout_rate:
         # The dropout hash is keyed by the folded QUERY row b·h + h_idx —
         # reconstruct it from the kv-head-major grid exactly as the q
         # BlockSpec index map does.
         group = h // h_kv
-        bh_q = (g0 // h_kv) * h + (g0 % h_kv) * group + it // num_q_blocks
+        bh_q = (_div(g0, h_kv) * h + _rem(g0, h_kv) * group
+                + _div(it, num_q_blocks))
     else:
         bh_q = g0
 
@@ -453,102 +600,82 @@ def _flash_bwd_dkv_kernel(
         dk_scratch[...] = jnp.zeros_like(dk_scratch)
         dv_scratch[...] = jnp.zeros_like(dv_scratch)
 
-    def _mask_t():
-        # Transposed tile mask [block_k, block_q]. Here kseg arrives
-        # lane-replicated (→ [block_k, 1] column) and qseg
-        # sublane-replicated (→ [1, block_q] row) — the transpose of the
-        # fwd/dq layouts.
+    def _tile(r0, c0, masked):
+        # [keys, queries] tiles: queries ride the lane axis.
+        rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
+        q_start = qi * block_q + r0
+        k_start = kj * block_k + c0
         mask = None
-        if causal or window is not None:
-            k_pos = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 0
-            )
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 1
-            )
-            mask = q_pos >= k_pos if causal else None
-            if window is not None:
-                band = q_pos - k_pos < window
-                mask = band if mask is None else mask & band
+        if masked and (causal or window is not None):
+            mask = _pos_mask((sub_k, sub_q), q_start - k_start, window,
+                             causal, transposed=True)
         if has_segments:
-            kseg = kseg_ref[0][:, :1]
-            qseg = qseg_ref[0][:1, :]
-            sm = (kseg == qseg) & (kseg != 0)
+            # Here kseg arrives lane-replicated (→ [sub_k, 1] column) and
+            # qseg sublane-replicated (→ [1, sub_q] row) — the transpose
+            # of the fwd/dq layouts.
+            sm = _seg_mask(qseg_ref[0, :1, rows], kseg_ref[0, cols, :1])
             mask = sm if mask is None else jnp.logical_and(mask, sm)
-        return mask
 
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)  # [block_q, d]
-        k = k_ref[0].astype(jnp.float32)  # [block_k, d]
-        v = v_ref[0].astype(jnp.float32)  # [block_k, d]
-        do = do_ref[0].astype(jnp.float32)  # [block_q, d]
-        lse = lse_ref[0][:1, :]  # [1, block_q] (sublane-replicated operand)
-        dterm = dterm_ref[0][:1, :]  # [1, block_q]
+        def _compute():
+            q, k, v, do = _operands(
+                q_ref[0, rows, :], k_ref[0, cols, :], v_ref[0, cols, :],
+                do_ref[0, rows, :],
+            )
+            lse = lse_ref[0, :1, rows]  # [1, sub_q] (sublane-replicated)
+            dterm = dterm_ref[0, :1, rows]  # [1, sub_q]
 
-        s_t = jax.lax.dot_general(
-            k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale  # [block_k, block_q]
-        p_t = jnp.exp(s_t - lse)
-        mask = _mask_t()
-        if mask is not None:
-            p_t = jnp.where(mask, p_t, 0.0)
-        if dropout_rate:
-            # One hash per tile, applied twice: dV sees the dropped,
-            # rescaled probabilities (the forward's value path); dK's ds
-            # keeps undropped w with the same mask landing on dp — the
-            # transposed twin of the dq kernel's math.
-            kp = 1.0 - dropout_rate
-            k_pos_t = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 0
+            s_t = jax.lax.dot_general(
+                k, q, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [sub_k, sub_q]
+            if s_scale != 1.0:
+                s_t = s_t * s_scale
+            p_t = jnp.exp(s_t - lse)
+            if mask is not None:
+                p_t = jnp.where(mask, p_t, 0.0)
+            if dropout_rate:
+                # One hash per tile, applied twice: dV sees the dropped,
+                # rescaled probabilities (the forward's value path); dK's
+                # ds keeps undropped w with the same mask landing on dp —
+                # the transposed twin of the dq kernel's math.
+                kp = 1.0 - dropout_rate
+                keep_t = _tile_keep(p_t.shape, seed_ref[0, 0], bh_q,
+                                    q_start, k_start, kp, transposed=True)
+                p_t_drop = jnp.where(keep_t, p_t / kp, 0.0)
+            else:
+                p_t_drop = p_t
+            dv_scratch[cols, :] += jax.lax.dot_general(
+                p_t_drop.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [sub_k, d]
+            dp_t = jax.lax.dot_general(
+                v, do, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [sub_k, sub_q]
+            if dropout_rate:
+                dp_t = jnp.where(keep_t, dp_t / kp, 0.0)
+            ds_t = p_t * (dp_t - dterm)
+            dk_scratch[cols, :] += jax.lax.dot_general(
+                ds_t.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-            q_pos_t = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 1
-            )
-            keep_t = _dropout_keep(
-                seed_ref[0, 0], bh_q, q_pos_t, k_pos_t, kp
-            )
-            p_t_drop = jnp.where(keep_t, p_t / kp, 0.0)
+
+        if has_segments:
+            pl.when(jnp.any(mask))(_compute)
         else:
-            p_t_drop = p_t
-        dv_scratch[...] += jax.lax.dot_general(
-            p_t_drop, do, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32
-        )  # [block_k, d]
-        dp_t = jax.lax.dot_general(
-            v, do, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [block_k, block_q]
-        if dropout_rate:
-            dp_t = jnp.where(keep_t, dp_t / kp, 0.0)
-        ds_t = p_t * (dp_t - dterm) * sm_scale
-        dk_scratch[...] += jax.lax.dot_general(
-            ds_t, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    preds = []
-    if causal:
-        # Skip q-blocks entirely in the past of this k-block (every score
-        # masked).
-        preds.append((qi + 1) * block_q > kj * block_k)
-    if window is not None:
-        # ...and q-blocks entirely beyond the window's future edge.
-        preds.append(_window_tile_live(qi, kj, block_q, block_k, window))
-    if has_segments:
-        preds.append(
-            jnp.any(
-                (kseg_ref[0][:, :1] == qseg_ref[0][:1, :])
-                & (kseg_ref[0][:, :1] != 0)
-            )
-        )
-    if preds:
-        @pl.when(_and_preds(preds))
-        def _():
             _compute()
-    else:
-        _compute()
+
+    # Query sub-tiles entirely in the past of a key sub-tile, or entirely
+    # beyond the window's future edge, are not visited.
+    _walk(_tile, qi, kj, tiles, causal, window,
+          has_segments or bool(dropout_rate), k_outer=True, unroll=True)
 
     @pl.when(it == total_q_iters - 1)
     def _finish():
-        dk_ref[0] = dk_scratch[...].astype(dk_ref.dtype)
+        dk = dk_scratch[...]
+        if s_scale != 1.0:
+            dk = dk * s_scale
+        dk_ref[0] = dk.astype(dk_ref.dtype)
         dv_ref[0] = dv_scratch[...].astype(dv_ref.dtype)
 
 
@@ -563,22 +690,10 @@ def _unfold_heads(x, b, h):
     return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
-def _seg_specs(h: int, qblock: int, kblock: int, q_order, k_order):
-    """BlockSpecs for segment-id operands: q lane-replicated
-    ([b, sq, 128] → column), kv sublane-replicated ([b, 8, sk] → row) in
-    the fwd/dq kernels; the dkv kernel passes them pre-swapped. The grid's
-    leading dim is folded batch·heads; segments are per-batch, so the index
-    map divides the head factor back out."""
-    return (
-        pl.BlockSpec(
-            (1, qblock, _LANES),
-            lambda g0, g1, g2: (g0 // h, q_order(g1, g2), 0),
-        ),
-        pl.BlockSpec(
-            (1, _SUBLANES, kblock),
-            lambda g0, g1, g2: (g0 // h, 0, k_order(g1, g2)),
-        ),
-    )
+def _fold_q(q):
+    """Q folded, carrying the exact part of the softmax scale."""
+    q_scale = _split_scale(q.shape[-1])[0]
+    return _fold_heads(q if q_scale == 1.0 else q * q_scale)
 
 
 def _kv_row(h: int, h_kv: int):
@@ -588,9 +703,45 @@ def _kv_row(h: int, h_kv: int):
     group = h // h_kv
 
     def row(bh):
-        return (bh // h) * h_kv + (bh % h) // group
+        if group == 1:
+            return bh
+        return _div(bh, h) * h_kv + _div(_rem(bh, h), group)
 
     return row
+
+
+def _live_k_block(qi, kj, block_q: int, block_k: int, num_k_blocks: int,
+                  causal: bool, window: int | None):
+    """K/V block a (qi, kj) grid step of the forward / dq kernels maps to:
+    ``kj`` where the block keeps a pair, the nearest such block of that
+    query row elsewhere, so the pipeline sees the index it already holds
+    and moves nothing for a step whose loops are empty."""
+    if causal:
+        kj = jnp.minimum(kj, _div((qi + 1) * block_q - 1, block_k))
+    if window is not None:
+        kj = jnp.maximum(
+            kj, _div(jnp.maximum(qi * block_q - window + 1, 0), block_k)
+        )
+    if causal or window is not None:
+        kj = jnp.clip(kj, 0, num_k_blocks - 1)
+    return kj
+
+
+def _live_q_block(qi, kj, block_q: int, block_k: int, num_q_blocks: int,
+                  causal: bool, window: int | None):
+    """The dkv kernel's twin of :func:`_live_k_block`: the Q / dO / lse
+    block of a (kj, qi) grid step, clamped into the key block's kept
+    range of query blocks."""
+    if causal:
+        qi = jnp.maximum(qi, _div(kj * block_k, block_q))
+    if window is not None:
+        qi = jnp.minimum(
+            qi, _div(jnp.maximum(window + (kj + 1) * block_k - 2, 0),
+                     block_q)
+        )
+    if causal or window is not None:
+        qi = jnp.clip(qi, 0, num_q_blocks - 1)
+    return qi
 
 
 def _seed_spec():
@@ -605,66 +756,80 @@ def _seed_operand(seed):
     )
 
 
-def _fwd_pallas(q, k, v, qseg, kseg, seed, causal, window, block_q, block_k,
-                interpret, dropout_rate):
+def _fwd_pallas(q, k, v, qseg, kseg, seed, causal, window, tiles, interpret,
+                dropout_rate):
     from jax.experimental.pallas import tpu as pltpu
 
+    block_q, block_k = tiles[:2]
     b, sq, h, d = q.shape
     sk = k.shape[1]
     h_kv = k.shape[2]
     kv_row = _kv_row(h, h_kv)
-    sm_scale = 1.0 / (d**0.5)
     num_k_blocks = sk // block_k
     has_segments = qseg is not None
 
-    qr, kr, vr = _fold_heads(q), _fold_heads(k), _fold_heads(v)
+    # V and the output travel transposed ([.., d, s]: _flash_kernel); the
+    # transposes are the head folds', with another permutation.
+    qr, kr = _fold_q(q), _fold_heads(k)
+    vtr = v.transpose(0, 2, 3, 1).reshape(b * h_kv, d, sk)
 
     kernel = functools.partial(
         _flash_kernel,
-        sm_scale=sm_scale,
+        s_scale=_split_scale(d)[1],
         causal=causal,
         window=window,
         has_segments=has_segments,
-        block_q=block_q,
-        block_k=block_k,
+        tiles=tiles,
+        num_q_blocks=sq // block_q,
         num_k_blocks=num_k_blocks,
         dropout_rate=dropout_rate,
     )
 
+    def live(qi, kj):
+        return _live_k_block(qi, kj, block_q, block_k, num_k_blocks, causal,
+                             window)
+
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bh, qi, kj: (kv_row(bh), kj, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bh, qi, kj: (kv_row(bh), kj, 0)),
+        pl.BlockSpec((1, block_k, d),
+                     lambda bh, qi, kj: (kv_row(bh), live(qi, kj), 0)),
+        pl.BlockSpec((1, d, block_k),
+                     lambda bh, qi, kj: (kv_row(bh), 0, live(qi, kj))),
     ]
-    operands = [qr, kr, vr]
+    operands = [qr, kr, vtr]
     if has_segments:
-        in_specs += list(
-            _seg_specs(h, block_q, block_k,
-                       lambda g1, g2: g1, lambda g1, g2: g2)
-        )
-        operands += [_as_col(qseg), _as_row(kseg)]
+        # qseg sublane-replicated row, kseg lane-replicated column.
+        # Segments are per batch row: the index map divides the head
+        # factor back out of the folded grid row.
+        in_specs += [
+            pl.BlockSpec((1, _SUBLANES, block_q),
+                         lambda bh, qi, kj: (_div(bh, h), 0, qi)),
+            pl.BlockSpec((1, block_k, _LANES),
+                         lambda bh, qi, kj: (_div(bh, h), kj, 0)),
+        ]
+        operands += [_as_row(qseg), _as_col(kseg)]
     if dropout_rate:
         in_specs.append(_seed_spec())
         operands.append(_seed_operand(seed))
 
-    out, lse = pl.pallas_call(
+    out_t, lse = pl.pallas_call(
         kernel,
         grid=(b * h, sq // block_q, num_k_blocks),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
+            pl.BlockSpec((1, d, block_q), lambda bh, qi, kj: (bh, 0, qi)),
             pl.BlockSpec(
                 (1, _SUBLANES, block_q), lambda bh, qi, kj: (bh, 0, qi)
             ),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, d, sq), q.dtype),
             jax.ShapeDtypeStruct((b * h, _SUBLANES, sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((_SUBLANES, block_q), jnp.float32),
+            pltpu.VMEM((_SUBLANES, block_q), jnp.float32),
+            pltpu.VMEM((d, block_q), jnp.float32),
         ],
         compiler_params=pallas_tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -672,84 +837,93 @@ def _fwd_pallas(q, k, v, qseg, kseg, seed, causal, window, block_q, block_k,
         interpret=interpret,
     )(*operands)
 
-    return _unfold_heads(out, b, h), lse[:, 0, :].reshape(b, h, sq)
+    out = out_t.reshape(b, h, d, sq).transpose(0, 3, 1, 2)
+    return out, lse[:, 0, :].reshape(b, h, sq)
 
 
-def _bwd_pallas(
-    q, k, v, qseg, kseg, seed, out, lse, do, dlse, causal, window, block_q,
-    block_k, interpret, dropout_rate
-):
+def _dq_pallas(qr, kr, vr, dor, lse_row, dterm_row, qseg, kseg, seed, *,
+               h, h_kv, causal, window, tiles, interpret, dropout_rate):
+    """dQ over folded operands (``[b·h, s, d]``; lse and dterm
+    sublane-replicated rows)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    h_kv = k.shape[2]
+    block_q, block_k = tiles[:2]
+    bh, sq, d = qr.shape
+    sk = kr.shape[1]
     kv_row = _kv_row(h, h_kv)
-    sm_scale = 1.0 / (d**0.5)
-    num_q_blocks = sq // block_q
     num_k_blocks = sk // block_k
     has_segments = qseg is not None
+    q_scale, s_scale = _split_scale(d)
 
-    qr, kr, vr = _fold_heads(q), _fold_heads(k), _fold_heads(v)
-    dor = _fold_heads(do.astype(jnp.float32))
-    or_ = _fold_heads(out.astype(jnp.float32))
-    lse_r = lse.reshape(b * h, sq)
-    # delta_r = rowsum(dO ∘ O): the softmax-normalization term of the output
-    # cotangent; the lse cotangent enters the same dS slot with opposite
-    # sign, so one fused [bh, sq] operand serves both paths.
-    delta = jnp.sum(dor * or_, axis=-1)
-    dterm = delta - dlse.reshape(b * h, sq).astype(jnp.float32)
-
-    # Both backward kernels consume the sublane-replicated [bh, 8, s] row
-    # layout (the dq kernel transposes in-register) — the lane-replicated
-    # [bh, s, 128] f32 temporaries this used to materialize were 16× bigger
-    # (ADVICE r3 #2: multiple transient GB at 32k sequence length).
-    lse_row, dterm_row = _as_row(lse_r), _as_row(dterm)
-
-    dq_in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bh, qi, kj: (kv_row(bh), kj, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bh, qi, kj: (kv_row(bh), kj, 0)),
-    ]
-    dq_operands = [qr, kr, vr]
-    if has_segments:
-        dq_in_specs += list(
-            _seg_specs(h, block_q, block_k,
-                       lambda g1, g2: g1, lambda g1, g2: g2)
+    def kv_index(bh, qi, kj):
+        return (
+            kv_row(bh),
+            _live_k_block(qi, kj, block_q, block_k, num_k_blocks, causal,
+                          window),
+            0,
         )
-        dq_operands += [_as_col(qseg), _as_row(kseg)]
-    if dropout_rate:
-        dq_in_specs.append(_seed_spec())
-        dq_operands.append(_seed_operand(seed))
-    dq_in_specs += [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
-        _row_spec(block_q, lambda g1, g2: g1),
-        _row_spec(block_q, lambda g1, g2: g1),
-    ]
-    dq_operands += [dor, lse_row, dterm_row]
 
-    dq = pl.pallas_call(
+    in_specs = [
+        pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
+        pl.BlockSpec((1, block_k, d), kv_index),
+        pl.BlockSpec((1, block_k, d), kv_index),
+    ]
+    operands = [qr, kr, vr]
+    if has_segments:
+        # [queries, keys] tiles: qseg lane-replicated column, kseg
+        # sublane-replicated row, per batch row as in the forward.
+        in_specs += [
+            pl.BlockSpec((1, block_q, _LANES),
+                         lambda bh, qi, kj: (_div(bh, h), qi, 0)),
+            pl.BlockSpec((1, _SUBLANES, block_k),
+                         lambda bh, qi, kj: (_div(bh, h), 0, kj)),
+        ]
+        operands += [_as_col(qseg), _as_row(kseg)]
+    if dropout_rate:
+        in_specs.append(_seed_spec())
+        operands.append(_seed_operand(seed))
+    in_specs += [
+        pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
+        pl.BlockSpec((1, _SUBLANES, block_q), lambda bh, qi, kj: (bh, 0, qi)),
+        pl.BlockSpec((1, _SUBLANES, block_q), lambda bh, qi, kj: (bh, 0, qi)),
+    ]
+    operands += [dor, lse_row, dterm_row]
+
+    return pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel,
-            sm_scale=sm_scale,
+            sm_scale=q_scale * s_scale,
+            s_scale=s_scale,
             causal=causal,
             window=window,
             has_segments=has_segments,
-            block_q=block_q,
-            block_k=block_k,
+            tiles=tiles,
+            num_q_blocks=sq // block_q,
             num_k_blocks=num_k_blocks,
             dropout_rate=dropout_rate,
         ),
-        grid=(b * h, num_q_blocks, num_k_blocks),
-        in_specs=dq_in_specs,
+        grid=(bh, sq // block_q, num_k_blocks),
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((bh, sq, d), qr.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=pallas_tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(*dq_operands)
+    )(*operands)
+
+
+def _dkv_pallas(qr, kr, vr, dor, lse_row, dterm_row, qseg, kseg, seed, *,
+                h, h_kv, causal, window, tiles, interpret, dropout_rate):
+    """dK/dV over folded operands, one grid row a KV head."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    block_q, block_k = tiles[:2]
+    sq, d = qr.shape[1:]
+    bh_kv, sk, _ = kr.shape
+    num_q_blocks = sq // block_q
+    has_segments = qseg is not None
 
     # GQA-aware grid: one row per KV head; the innermost "arbitrary" dim
     # sweeps the q-head group × q-blocks (group-major), so the whole
@@ -760,70 +934,74 @@ def _bwd_pallas(
 
     def q_row(g0, g2):
         # folded q row for kv-head row g0 at inner iteration g2
-        return (g0 // h_kv) * h + (g0 % h_kv) * group + g2 // num_q_blocks
+        return (_div(g0, h_kv) * h + _rem(g0, h_kv) * group
+                + _div(g2, num_q_blocks))
 
-    def q_blk(g2):
-        return g2 % num_q_blocks
+    def q_blk(g1, g2):
+        return _live_q_block(_rem(g2, num_q_blocks), g1, block_q, block_k,
+                             num_q_blocks, causal, window)
 
-    dkv_in_specs = [
-        pl.BlockSpec((1, block_q, d),
-                     lambda g0, g1, g2: (q_row(g0, g2), q_blk(g2), 0)),
+    def q_index(g0, g1, g2):
+        return (q_row(g0, g2), q_blk(g1, g2), 0)
+
+    def q_row_index(g0, g1, g2):
+        return (q_row(g0, g2), 0, q_blk(g1, g2))
+
+    in_specs = [
+        pl.BlockSpec((1, block_q, d), q_index),
         pl.BlockSpec((1, block_k, d), lambda g0, g1, g2: (g0, g1, 0)),
         pl.BlockSpec((1, block_k, d), lambda g0, g1, g2: (g0, g1, 0)),
     ]
-    dkv_operands = [qr, kr, vr]
+    operands = [qr, kr, vr]
     if has_segments:
         # Transposed layouts for the transposed kernel: qseg
         # sublane-replicated row, kseg lane-replicated column. Batch
         # decodes from the kv-head-major grid row.
-        dkv_in_specs += [
+        in_specs += [
             pl.BlockSpec(
                 (1, _SUBLANES, block_q),
-                lambda g0, g1, g2: (g0 // h_kv, 0, q_blk(g2)),
+                lambda g0, g1, g2: (_div(g0, h_kv), 0, q_blk(g1, g2)),
             ),
             pl.BlockSpec(
                 (1, block_k, _LANES),
-                lambda g0, g1, g2: (g0 // h_kv, g1, 0),
+                lambda g0, g1, g2: (_div(g0, h_kv), g1, 0),
             ),
         ]
-        dkv_operands += [_as_row(qseg), _as_col(kseg)]
+        operands += [_as_row(qseg), _as_col(kseg)]
     if dropout_rate:
-        dkv_in_specs.append(_seed_spec())
-        dkv_operands.append(_seed_operand(seed))
-    dkv_in_specs += [
-        pl.BlockSpec((1, block_q, d),
-                     lambda g0, g1, g2: (q_row(g0, g2), q_blk(g2), 0)),
-        pl.BlockSpec((1, _SUBLANES, block_q),
-                     lambda g0, g1, g2: (q_row(g0, g2), 0, q_blk(g2))),
-        pl.BlockSpec((1, _SUBLANES, block_q),
-                     lambda g0, g1, g2: (q_row(g0, g2), 0, q_blk(g2))),
+        in_specs.append(_seed_spec())
+        operands.append(_seed_operand(seed))
+    in_specs += [
+        pl.BlockSpec((1, block_q, d), q_index),
+        pl.BlockSpec((1, _SUBLANES, block_q), q_row_index),
+        pl.BlockSpec((1, _SUBLANES, block_q), q_row_index),
     ]
-    dkv_operands += [dor, lse_row, dterm_row]
+    operands += [dor, lse_row, dterm_row]
 
-    dk, dv = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel,
-            sm_scale=sm_scale,
+            s_scale=_split_scale(d)[1],
             causal=causal,
             window=window,
             has_segments=has_segments,
-            block_q=block_q,
-            block_k=block_k,
+            tiles=tiles,
             num_q_blocks=num_q_blocks,
+            num_k_blocks=sk // block_k,
             total_q_iters=total_q_iters,
             dropout_rate=dropout_rate,
             h=h,
             h_kv=h_kv,
         ),
-        grid=(b * h_kv, num_k_blocks, total_q_iters),
-        in_specs=dkv_in_specs,
+        grid=(bh_kv, sk // block_k, total_q_iters),
+        in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda g0, g1, g2: (g0, g1, 0)),
             pl.BlockSpec((1, block_k, d), lambda g0, g1, g2: (g0, g1, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h_kv, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h_kv, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((bh_kv, sk, d), kr.dtype),
+            jax.ShapeDtypeStruct((bh_kv, sk, d), vr.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -833,8 +1011,37 @@ def _bwd_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(*dkv_operands)
+    )(*operands)
 
+
+def _bwd_pallas(
+    q, k, v, qseg, kseg, seed, out, lse, do, dlse, causal, window, tiles,
+    interpret, dropout_rate
+):
+    b, sq, h, d = q.shape
+    h_kv = k.shape[2]
+
+    # delta_r = rowsum(dO ∘ O): the softmax-normalization term of the output
+    # cotangent, in float32; the lse cotangent enters the same dS slot with
+    # opposite sign, so one fused [bh, sq] operand serves both paths. dO
+    # itself goes to the kernels in the dtype it arrives in.
+    delta = jnp.sum(
+        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
+    ).transpose(0, 2, 1)  # [b, h, sq]
+    dterm = (delta - dlse.astype(jnp.float32)).reshape(b * h, sq)
+
+    # Both backward kernels consume the sublane-replicated [bh, 8, s] row
+    # layout (the dq kernel transposes in-register) — the lane-replicated
+    # [bh, s, 128] f32 temporaries this used to materialize were 16× bigger
+    # (ADVICE r3 #2: multiple transient GB at 32k sequence length).
+    folded = (
+        _fold_q(q), _fold_heads(k), _fold_heads(v), _fold_heads(do),
+        _as_row(lse.reshape(b * h, sq)), _as_row(dterm), qseg, kseg, seed,
+    )
+    static = dict(h=h, h_kv=h_kv, causal=causal, window=window,
+                  interpret=interpret, dropout_rate=dropout_rate)
+    dq = _dq_pallas(*folded, tiles=tiles[1], **static)
+    dk, dv = _dkv_pallas(*folded, tiles=tiles[2], **static)
     return (
         _unfold_heads(dq, b, h),
         _unfold_heads(dk, b, h_kv),
@@ -842,18 +1049,19 @@ def _bwd_pallas(
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
-def _flash(q, k, v, qseg, kseg, seed, causal, window, block_q, block_k,
-           interpret, dropout_rate):
-    out, lse = _fwd_pallas(q, k, v, qseg, kseg, seed, causal, window,
-                           block_q, block_k, interpret, dropout_rate)
-    return out, lse
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def _flash(q, k, v, qseg, kseg, seed, causal, window, tiles, interpret,
+           dropout_rate):
+    """``tiles``: each kernel's (block_q, block_k, sub_q, sub_k), in the
+    order forward, dq, dkv."""
+    return _fwd_pallas(q, k, v, qseg, kseg, seed, causal, window, tiles[0],
+                       interpret, dropout_rate)
 
 
-def _flash_fwd(q, k, v, qseg, kseg, seed, causal, window, block_q, block_k,
-               interpret, dropout_rate):
+def _flash_fwd(q, k, v, qseg, kseg, seed, causal, window, tiles, interpret,
+               dropout_rate):
     out, lse = _fwd_pallas(q, k, v, qseg, kseg, seed, causal, window,
-                           block_q, block_k, interpret, dropout_rate)
+                           tiles[0], interpret, dropout_rate)
     return (out, lse), (q, k, v, qseg, kseg, seed, out, lse)
 
 
@@ -865,13 +1073,13 @@ def _seg_ct(seg):
     return np.zeros(seg.shape, jax.dtypes.float0)
 
 
-def _flash_bwd(causal, window, block_q, block_k, interpret, dropout_rate,
-               res, cotangents):
+def _flash_bwd(causal, window, tiles, interpret, dropout_rate, res,
+               cotangents):
     q, k, v, qseg, kseg, seed, out, lse = res
     do, dlse = cotangents
     dq, dk, dv = _bwd_pallas(
         q, k, v, qseg, kseg, seed, out, lse, do, dlse, causal, window,
-        block_q, block_k, interpret, dropout_rate
+        tiles, interpret, dropout_rate
     )
     return dq, dk, dv, _seg_ct(qseg), _seg_ct(kseg), _seg_ct(seed)
 
@@ -915,25 +1123,79 @@ def _normalize_segments(segment_ids, b, sq, sk):
     return qseg, kseg
 
 
-# Auto-picked block caps. Measured on TPU v5e (seq 4096, b=4, h=8, d=64,
-# causal fwd+bwd): (128,128) → 484K tok/s, (512,512) → 2333K, (512,1024) →
-# 2505K, (1024,1024) → 596K (VMEM spill). Bigger K blocks amortize the
-# per-tile online-softmax bookkeeping; Q caps at 512 to keep the dq/dkv
-# scratch accumulators comfortably in VMEM at head_dim 128.
-_BLOCK_Q_CAP = 512
-_BLOCK_K_CAP = 1024
-
-
-def _auto_block(s: int, cap: int) -> int:
+def _auto_block(s: int, cap: int, align: int = 8) -> int:
     """Largest TPU-legal block for a length-``s`` axis: the full axis when
-    it fits under ``cap``, else the biggest divisor ≤ cap that keeps the
-    sublane constraint (multiple of 8), else the full axis."""
+    it fits under ``cap``, else the biggest ``cap / 2**n`` that divides it
+    and is a multiple of ``align`` (8 sublanes; 128 lanes where the axis
+    is some operand's last dimension, as a query block is the last
+    dimension of its log-sum-exp row), else the full axis."""
     if s <= cap:
         return s
     b = cap
-    while b > 8 and s % b:
+    while b > align and s % b:
         b //= 2
-    return b if b >= 8 and s % b == 0 else s
+    return b if b >= align and s % b == 0 else s
+
+
+# The tile rule's one table, for 16-bit operands at head_dim <= 128: the
+# sub-tile a kernel works at a time, and how many sub-tiles' worth of
+# queries and keys a grid step holds. Every other call keeps _WHOLE_CAPS,
+# one sub-tile a block (the rule of before PR 29: float32 sub-tiles of 512
+# do not fit beside their blocks).
+# Source: scripts/flash_sweep.py on a TPU v5e, bfloat16, causal (PERF.md
+# §6, PR 29, sweeps s1-s3), ms a call, the caps of before -> the table's:
+# gpt2m-train's (8, 1,024, 16 heads of 64): forward 0.74 -> 0.51, dq
+# 0.87 -> 0.59, dkv 1.10 -> 0.87; trinity-mini-serve's prefill (32 over 4
+# heads of 128, window 2,048 and none), 512 ... 8,192 tokens: 0.125 -> 0.097
+# ... 4.00 -> 3.14 and 0.127 -> 0.092 ... 5.56 -> 5.46, every bucket faster.
+# What the sweeps say: sub-tiles under 512 x 512 lose more to their fixed
+# cost than the frontier returns (256 x 256: 0.79 / 0.98 / 1.16; 128 x
+# 128: 1.7 / 2.6 / 1.8); the forward wants a head's whole K/V resident
+# (fetched once a K/V head, the loop runs only from the window's edge to
+# the diagonal: 8,192 tokens 3.14 against 3.69 in 1,024-blocks) and does
+# not care how many queries a step holds (512 ... 2,048: equal), so it
+# holds one sub-tile's; the backward kernels want 1,024 x 1,024 (512 x 512
+# blocks: 0.71 / 0.95).
+_SUB = 512
+_TILES = {  # kernel -> (query sub-tiles, key sub-tiles) a block, at most
+    "fwd": (1, None),  # None: what _KV_BLOCK_BYTES admits
+    "dq": (2, 2),
+    "dkv": (2, 2),
+}
+# A forward step holds K and V^T of a head twice over (double-buffered):
+# 4 x 2 MiB of the 16 MiB a kernel may use.
+_KV_BLOCK_BYTES = 2 * 1024 * 1024
+# Up to here a length no _SUB divides is one whole tile in the forward
+# (gpt2m-serve's 640 and 768: 0.134 -> 0.090, 0.126 -> 0.094).
+_WHOLE_MAX = 1024
+_WHOLE_CAPS = (512, 1024)
+
+
+def _largest_block(s: int, sub: int, most: int) -> int:
+    """The largest multiple of ``sub`` that divides ``s``, of at most
+    ``most`` sub-tiles (at least one)."""
+    n = s // sub
+    return sub * max(m for m in range(1, max(most, 1) + 1) if n % m == 0)
+
+
+def _tile_rule(kernel: str, sq: int, sk: int, d: int, dtype):
+    """``(block_q, block_k, sub_q, sub_k)`` of ``kernel`` ("fwd", "dq",
+    "dkv"): a pure function of the static shapes, nothing timed or probed.
+    ``_TILES``' row where ``_SUB`` divides both lengths, else the largest
+    blocks under ``_WHOLE_CAPS`` that divide them, each worked whole."""
+    itemsize = jnp.dtype(dtype).itemsize
+    if itemsize <= 2 and d <= 128:
+        if sq % _SUB == 0 and sk % _SUB == 0:
+            most_q, most_k = _TILES[kernel]
+            if most_k is None:
+                most_k = _KV_BLOCK_BYTES // (_SUB * d * itemsize)
+            return (_largest_block(sq, _SUB, most_q),
+                    _largest_block(sk, _SUB, most_k), _SUB, _SUB)
+        if kernel == "fwd" and max(sq, sk) <= _WHOLE_MAX:
+            return sq, sk, sq, sk
+    block_q = _auto_block(sq, _WHOLE_CAPS[0], _LANES)
+    block_k = _auto_block(sk, _WHOLE_CAPS[1], _LANES)
+    return block_q, block_k, block_q, block_k
 
 
 def _check_dropout(dropout_rate, dropout_seed):
@@ -976,6 +1238,10 @@ def _check_window(window, causal, allow_band: bool = False):
 
 
 def _prepare(q, k, v, block_q, block_k, interpret):
+    """Validate the head layout and settle each kernel's tiles: the
+    caller's ``block_q`` / ``block_k`` for all three, each worked whole,
+    where given; else :func:`_tile_rule`'s. Returns ``(tiles, interpret)``,
+    ``tiles`` in the order forward, dq, dkv."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     h_kv = k.shape[2]
@@ -988,20 +1254,23 @@ def _prepare(q, k, v, block_q, block_k, interpret):
             f"query head count {h} must be a multiple of the kv head "
             f"count {h_kv} (grouped-query attention)"
         )
-    if block_q is None:
-        block_q = _auto_block(sq, _BLOCK_Q_CAP)
-    if block_k is None:
-        block_k = _auto_block(sk, _BLOCK_K_CAP)
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    if sq % block_q or sk % block_k:
-        raise ValueError(
-            f"sequence lengths ({sq}, {sk}) must be divisible by block sizes "
-            f"({block_q}, {block_k})"
-        )
+    dtype = jnp.result_type(q, k, v)
+    tiles = []
+    for kernel in ("fwd", "dq", "dkv"):
+        bq, bk, sub_q, sub_k = _tile_rule(kernel, sq, sk, d, dtype)
+        if block_q is not None:
+            bq = sub_q = min(block_q, sq)
+        if block_k is not None:
+            bk = sub_k = min(block_k, sk)
+        if sq % bq or sk % bk:
+            raise ValueError(
+                f"sequence lengths ({sq}, {sk}) must be divisible by block "
+                f"sizes ({bq}, {bk})"
+            )
+        tiles.append((bq, bk, sub_q, sub_k))
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return block_q, block_k, interpret
+    return tuple(tiles), interpret
 
 
 @functools.partial(
@@ -1058,12 +1327,12 @@ def flash_attention(
     """
     window = _check_window(window, causal)
     dropout_rate, seed = _check_dropout(dropout_rate, dropout_seed)
-    block_q, block_k, interpret = _prepare(q, k, v, block_q, block_k, interpret)
+    tiles, interpret = _prepare(q, k, v, block_q, block_k, interpret)
     qseg, kseg = _normalize_segments(
         segment_ids, q.shape[0], q.shape[1], k.shape[1]
     )
-    out, _ = _flash(q, k, v, qseg, kseg, seed, causal, window, block_q,
-                    block_k, interpret, dropout_rate)
+    out, _ = _flash(q, k, v, qseg, kseg, seed, causal, window, tiles,
+                    interpret, dropout_rate)
     return out
 
 
@@ -1103,12 +1372,12 @@ def flash_attention_with_lse(
     """
     window = _check_window(window, causal, allow_band=True)
     dropout_rate, seed = _check_dropout(dropout_rate, dropout_seed)
-    block_q, block_k, interpret = _prepare(q, k, v, block_q, block_k, interpret)
+    tiles, interpret = _prepare(q, k, v, block_q, block_k, interpret)
     qseg, kseg = _normalize_segments(
         segment_ids, q.shape[0], q.shape[1], k.shape[1]
     )
-    return _flash(q, k, v, qseg, kseg, seed, causal, window, block_q,
-                  block_k, interpret, dropout_rate)
+    return _flash(q, k, v, qseg, kseg, seed, causal, window, tiles,
+                  interpret, dropout_rate)
 
 
 def _segments_from_attention_mask(mask, b, sq, sk, causal):

@@ -1,0 +1,284 @@
+"""The flash kernels' sub-tile walk and tile rule (interpret mode, CPU):
+operands in the caller's dtype, the loops between the window's far edge
+and the causal frontier, the mask on cut sub-tiles only, against a dense
+reference; the rule's tiles for every prefill bucket the benchmark's
+serve cells compile; the kernel's traced size, which must not grow with
+the sequence (every start of a program pays it: PERF.md §6, PR 29)."""
+
+import importlib
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+# ``fluxmpi_tpu.ops.flash_attention`` the attribute is the function.
+fa = importlib.import_module("fluxmpi_tpu.ops.flash_attention")
+
+
+def _dense(q, k, v, *, causal, window, qseg, kseg, dropout, seed):
+    """Plain attention in float32 with the kernels' conventions: id-0 keys
+    are padding, rows with no key give zeros and ``lse = -1e30``, dropout
+    drops what the kernels' position hash drops."""
+    b, sq, h, d = q.shape
+    sk, group = k.shape[1], h // k.shape[2]
+    qf = q.astype(jnp.float32)
+    kf = jnp.repeat(k.astype(jnp.float32), group, axis=2)
+    vf = jnp.repeat(v.astype(jnp.float32), group, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", qf, kf) / np.sqrt(d)
+    diff = jnp.arange(sq)[:, None] - jnp.arange(sk)[None, :]
+    keep = jnp.ones((sq, sk), bool)
+    if causal:
+        keep &= diff >= 0
+    if window is not None:
+        keep &= diff < window
+    keep = jnp.broadcast_to(keep[None, None], s.shape)
+    if qseg is not None:
+        keep &= ((qseg[:, None, :, None] == kseg[:, None, None, :])
+                 & (kseg[:, None, None, :] != 0))
+    s = jnp.where(keep, s, -1e30)
+    lse = jax.scipy.special.logsumexp(s, axis=-1)
+    p = jnp.where(keep, jnp.exp(s - lse[..., None]), 0.0)
+    lse = jnp.where(jnp.any(keep, axis=-1), lse, -1e30)
+    if dropout:
+        kp = 1.0 - dropout
+        q_pos = jnp.broadcast_to(jnp.arange(sq)[:, None], (sq, sk))
+        k_pos = jnp.broadcast_to(jnp.arange(sk)[None, :], (sq, sk))
+        kept = jax.vmap(
+            lambda bh: fa._dropout_keep(jnp.uint32(seed), bh, q_pos, k_pos, kp)
+        )(jnp.arange(b * h, dtype=jnp.uint32)).reshape(b, h, sq, sk)
+        p = jnp.where(kept, p / kp, 0.0)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, vf), lse
+
+
+def _segments(s):
+    """Three documents and a padded tail, none on a sub-tile's edge."""
+    ids = np.zeros((1, s), np.int32)
+    ids[0, : s // 3 + 5] = 1
+    ids[0, s // 3 + 5: s // 2 + 9] = 2
+    ids[0, s // 2 + 9: s - 11] = 3
+    return jnp.asarray(ids)
+
+
+# name -> (length, heads, kv heads, keyword arguments, tiles or None).
+# ``tiles`` = (block_q, block_k, sub_q, sub_k) for all three kernels,
+# through the private entry; None goes through the public one and takes
+# the rule's. Lengths the frontier cuts unevenly; windows narrower and
+# wider than a sub-tile.
+_CASES = {
+    "causal_768": (768, 2, 2, dict(causal=True), (768, 768, 384, 256)),
+    "window_narrow_768": (768, 2, 1, dict(causal=True, window=96),
+                          (768, 768, 384, 256)),
+    "window_wide_1536": (1536, 2, 1, dict(causal=True, window=300),
+                         (512, 512, 256, 256)),
+    "grouped_1536": (1536, 4, 2, dict(causal=True), (1536, 512, 512, 256)),
+    "segments_768": (768, 2, 2, dict(causal=True, segments=True),
+                     (768, 768, 384, 256)),
+    "segments_window_768": (768, 2, 1, dict(causal=True, window=200,
+                                            segments=True),
+                            (384, 768, 384, 256)),
+    "dropout_768": (768, 2, 2, dict(causal=True, dropout=0.25),
+                    (768, 768, 384, 256)),
+    "band_768": (768, 2, 2, dict(causal=False, window=40),
+                 (256, 768, 256, 256)),
+    "full_768": (768, 2, 1, dict(causal=False), (768, 768, 384, 384)),
+    "rule_2560_window": (2560, 1, 1, dict(causal=True, window=1000), None),
+    "rule_1024": (1024, 2, 1, dict(causal=True), None),
+    "rule_2048_window": (2048, 1, 1, dict(causal=True, window=700), None),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(_CASES))
+def test_sub_tiled_kernels_match_dense(case, dtype):
+    s, h, h_kv, kwargs, tiles = _CASES[case]
+    kwargs = dict(kwargs)
+    causal = kwargs.pop("causal")
+    window = kwargs.pop("window", None)
+    dropout = kwargs.pop("dropout", 0.0)
+    seg = _segments(s) if kwargs.pop("segments", False) else None
+    d, seed = 32, 77
+    keys = jax.random.split(jax.random.PRNGKey(s + h), 4)
+    q = jax.random.normal(keys[0], (1, s, h, d), jnp.float32).astype(dtype)
+    k = jax.random.normal(keys[1], (1, s, h_kv, d), jnp.float32).astype(dtype)
+    v = jax.random.normal(keys[2], (1, s, h_kv, d), jnp.float32).astype(dtype)
+    w = jax.random.normal(keys[3], (1, s, h, d), jnp.float32)
+
+    def flash(q, k, v):
+        if tiles is None:
+            return fa.flash_attention_with_lse(
+                q, k, v, causal=causal, window=window, segment_ids=seg,
+                dropout_rate=dropout,
+                dropout_seed=seed if dropout else None)
+        return fa._flash(
+            q, k, v, seg, seg, jnp.uint32(seed) if dropout else None,
+            causal, window, (tiles,) * 3, True, dropout)
+
+    def dense(q, k, v):
+        return _dense(q, k, v, causal=causal, window=window, qseg=seg,
+                      kseg=seg, dropout=dropout, seed=seed)
+
+    def loss(fn):
+        def f(q, k, v):
+            out, lse = fn(q, k, v)
+            live = lse > -1e20
+            return (jnp.sum(out.astype(jnp.float32) * w)
+                    + 0.1 * jnp.sum(jnp.where(live, lse, 0.0))), (out, lse)
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    (_, (out, lse)), grads = loss(flash)(q, k, v)
+    (_, (out_d, lse_d)), grads_d = loss(dense)(q, k, v)
+    assert out.dtype == dtype and lse.dtype == jnp.float32
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(out_d), atol=tol)
+    # The log-sum-exp is float32 statistics over float32 products: tight
+    # in both dtypes; rows with no key read the -1e30 convention exactly.
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(lse_d), atol=tol, rtol=1e-6)
+    for name, g, g_d in zip("qkv", grads, grads_d):
+        assert g.dtype == dtype
+        scale = float(jnp.max(jnp.abs(g_d))) + 1e-6
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32) / scale,
+            np.asarray(g_d, np.float32) / scale, atol=tol,
+            err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("tiles", [(256, 256, 256, 256), (512, 512, 256, 256),
+                                   (256, 512, 128, 256)])
+def test_rows_with_no_key_keep_the_lse_convention(tiles):
+    """Ring attention merges blocks by the returned log-sum-exp: a row
+    with no attendable key reads -1e30 and a zero output, whatever the
+    sub-tiles: padding rows (id 0), and a band no pair of the block is
+    in."""
+    s, h, d = 512, 2, 32
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    q, k, v = (jax.random.normal(x, (1, s, h, d), jnp.float32) for x in keys)
+    seg = jnp.asarray(np.r_[np.ones(300), np.zeros(212)][None], jnp.int32)
+    out, lse = fa._flash(q, k, v, seg, seg, None, True, None, (tiles,) * 3,
+                         True, 0.0)
+    assert np.all(np.asarray(lse)[:, :, 300:] == np.float32(-1e30))
+    assert np.all(np.asarray(out)[:, 300:] == 0.0)
+    assert np.all(np.asarray(lse)[:, :, :300] > -1e20)
+    # Band-only, window <= -s: every key is too far behind.
+    out, lse = fa._flash(q, k, v, None, None, None, False, -s, (tiles,) * 3,
+                         True, 0.0)
+    assert np.all(np.asarray(lse) == np.float32(-1e30))
+    assert np.all(np.asarray(out) == 0.0)
+
+
+def _before(s: int) -> tuple[int, int]:
+    """The pair the caps of before PR 29 (512, 1,024) gave a length."""
+    return fa._auto_block(s, 512), fa._auto_block(s, 1024)
+
+
+# Every prefill bucket the benchmark's serve cells compile: gpt2m-serve's
+# six (head_dim 64), trinity-mini-serve's 512 ... 8,192 (head_dim 128).
+_BUCKETS = [(64, s) for s in (128, 256, 384, 512, 640, 768)] + [
+    (128, s) for s in range(512, 8193, 512)]
+
+
+@pytest.mark.parametrize("d, s", _BUCKETS)
+def test_tile_rule_is_legal_at_every_serve_bucket(d, s):
+    for kernel in ("fwd", "dq", "dkv"):
+        bq, bk, sub_q, sub_k = fa._tile_rule(kernel, s, s, d, jnp.bfloat16)
+        assert s % bq == 0 and s % bk == 0
+        assert bq % sub_q == 0 and bk % sub_k == 0
+        # A sub-tile smaller than its block starts on a lane boundary.
+        assert sub_q == bq or sub_q % 128 == 0
+        assert sub_k == bk or sub_k % 128 == 0
+        # Float32 operands keep the blocks of before, each worked whole.
+        assert fa._tile_rule(kernel, s, s, d, jnp.float32) == (
+            *_before(s), *_before(s))
+    fwd = fa._tile_rule("fwd", s, s, d, jnp.bfloat16)
+    if s % 512 == 0:
+        # One sub-tile of queries against the head's whole K/V, which at
+        # head_dim 128 and 8,192 tokens is what a step may hold.
+        assert fwd == (512, s, 512, 512)
+    else:
+        # gpt2m-serve's 128, 256, 384 (the pair of before) and 640, 768
+        # (the pair of before cut them in 128- and 256-row blocks).
+        assert fwd == (s, s, s, s)
+    # The backward kernels, which no serve cell runs: 1,024-blocks in
+    # 512-sub-tiles where they divide the length, else the pair of before.
+    for kernel in ("dq", "dkv"):
+        tiles = fa._tile_rule(kernel, s, s, d, jnp.bfloat16)
+        if s % 1024 == 0:
+            assert tiles == (1024, 1024, 512, 512)
+        else:
+            assert tiles == (*_before(s), *_before(s))
+
+
+def test_tile_rule_bounds_the_resident_keys():
+    """The forward holds K and V^T of a head twice over: 2 MiB a block."""
+    assert fa._tile_rule("fwd", 512, 16384, 128, jnp.bfloat16) == (
+        512, 8192, 512, 512)
+    assert fa._tile_rule("fwd", 1024, 32768, 64, jnp.bfloat16) == (
+        512, 16384, 512, 512)
+    # 9 sub-tiles of keys: the largest divisor under the bound is all 9.
+    assert fa._tile_rule("fwd", 512, 4608, 128, jnp.bfloat16)[1] == 4608
+    # 17 sub-tiles at head_dim 128: a prime over the bound falls to one.
+    assert fa._tile_rule("fwd", 512, 8704, 128, jnp.bfloat16)[1] == 512
+
+
+def test_tile_rule_keeps_odd_lengths_compilable():
+    """A length no multiple of 128 divides is one whole block (a 64-wide
+    query block's log-sum-exp row is refused by the chip's compiler)."""
+    for s in (576, 704, 96):
+        for kernel in ("fwd", "dq", "dkv"):
+            assert fa._tile_rule(kernel, s, s, 64, jnp.bfloat16)[:2] == (s, s)
+    # Past 1,024 a length 512 does not divide keeps the pair of before.
+    assert fa._tile_rule("fwd", 1280, 1280, 64, jnp.bfloat16) == (
+        256, 256, 256, 256)
+
+
+def test_callers_blocks_still_win():
+    q = jax.ShapeDtypeStruct((1, 2048, 2, 64), jnp.bfloat16)
+    tiles, _ = fa._prepare(q, q, q, 256, None, True)
+    assert [t[0] for t in tiles] == [256] * 3
+    assert [t[2] for t in tiles] == [256] * 3  # worked whole
+    assert tiles[0][1] == 2048 and tiles[1][1] == 1024
+    tiles, _ = fa._prepare(q, q, q, None, 128, True)
+    assert [t[1::2] for t in tiles] == [(128, 128)] * 3
+    with pytest.raises(ValueError, match="divisible"):
+        fa._prepare(q, q, q, 384, None, True)
+
+
+def _kernel_equations(jaxpr) -> int:
+    """Equations of a jaxpr and of every jaxpr inside it."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (tuple, list))
+                        else (value,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    n += _kernel_equations(inner)
+    return n
+
+
+def _forward_equations(s, window, dtype=jnp.bfloat16):
+    q = jax.ShapeDtypeStruct((1, s, 32, 128), dtype)
+    kv = jax.ShapeDtypeStruct((1, s, 4, 128), dtype)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, window=window, interpret=True))(q, kv, kv)
+    return _kernel_equations(jaxpr.jaxpr)
+
+
+@pytest.mark.parametrize("window", [None, 2048])
+def test_forward_kernel_does_not_grow_with_the_sequence(window):
+    """The guard for set-up: a start traces and lowers every kernel
+    before its compile-cache key exists, so what a kernel traces to is
+    paid at every start of a program that holds it. The forward's
+    sub-tiles run under ``fori_loop``s with a masked and an unmasked body:
+    8,192 tokens trace to exactly what 1,024 do."""
+    at_1024 = _forward_equations(1024, window)
+    for s in (1536, 2560, 8192):
+        assert _forward_equations(s, window) == at_1024
+    # ... and to less than twice the one-body kernel of float32 operands,
+    # whose blocks are worked whole.
+    assert at_1024 < 2 * _forward_equations(1024, window, jnp.float32)

@@ -133,6 +133,28 @@ def test_flash_shapes_compile_for_v5e(topo, name, q_shape, kv_shape, grad):
     assert text.count("tpu_custom_call") == (3 if grad else 1)
 
 
+# trinity-mini-serve's prefill (32 query over 4 K/V heads of 128, window
+# and full layers) at the tile rule's tiles: 512 queries a step against
+# the head's whole K/V in 512-sub-tiles under a loop (2 ... 16 of them;
+# 8,192 tokens are the 2 MiB a K/V block may hold), and 576, which no
+# multiple of 128 divides and the rule of before could not compile.
+@pytest.mark.parametrize("window", [None, 2048], ids=["full", "window"])
+@pytest.mark.parametrize("s", [576, 1024, 2560, 8192])
+def test_flash_prefill_tiles_compile_for_v5e(topo, s, window):
+    dev = topo.devices[0]
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, interpret=False, causal=True,
+                               window=window)
+
+    text = jax.jit(attend).lower(
+        _sds((1, s, 32, 128), jnp.bfloat16, dev),
+        _sds((1, s, 4, 128), jnp.bfloat16, dev),
+        _sds((1, s, 4, 128), jnp.bfloat16, dev),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
 # name -> (slots, heads, head_dim, layers, pool blocks, block_size,
 # table width, dtype): the benchmark's serve cell (GPT-2 medium, 32 slots
 # of 8 blocks of 128), the smoke's engine (GPT-2 small at the engine's
