@@ -39,12 +39,6 @@ FAULTS_RELPATH = os.path.join("fluxmpi_tpu", "faults.py")
 CONFIG_RELPATH = os.path.join("fluxmpi_tpu", "config.py")
 ENV_DOC_RELPATH = os.path.join("docs", "observability.md")
 
-# Files outside the default scan set that legitimately read FLUXMPI_TPU_*
-# env vars; the undocumented-env-var rule's reverse check (documented but
-# read nowhere) scans these too, so a bench-only knob doesn't look dead
-# when only `fluxmpi_tpu/ scripts/` are linted.
-EXTRA_ENV_ROOTS = ("bench.py",)
-
 
 def load_schema_module(repo_root: str) -> Any:
     """Load ``fluxmpi_tpu/telemetry/schema.py`` by file path — no package
@@ -216,7 +210,6 @@ class ProjectContext:
         anomaly_event_prefix: str = "anomaly.",
         known_fault_sites: frozenset[str] = frozenset(),
         documented_env_vars: dict[str, int] | None = None,
-        extra_env_vars: Iterable[str] = (),
         tests_corpus: str = "",
         env_doc_path: str = "docs/observability.md",
         faults_path: str = "fluxmpi_tpu/faults.py",
@@ -228,8 +221,6 @@ class ProjectContext:
         self.anomaly_event_prefix = anomaly_event_prefix
         self.known_fault_sites = known_fault_sites
         self.documented_env_vars = documented_env_vars or {}
-        # Env vars read by files outside the scan set (bench.py).
-        self.extra_env_vars = frozenset(extra_env_vars)
         self.tests_corpus = tests_corpus
         self.env_doc_path = env_doc_path
         self.faults_path = faults_path
@@ -238,15 +229,6 @@ class ProjectContext:
     @classmethod
     def load(cls, repo_root: str) -> "ProjectContext":
         schema = load_schema_module(repo_root)
-        extra: set[str] = set()
-        for rel in EXTRA_ENV_ROOTS:
-            try:
-                with open(
-                    os.path.join(repo_root, rel), encoding="utf-8"
-                ) as f:
-                    extra.update(env_vars_in_source(f.read()))
-            except OSError:
-                continue
         return cls(
             known_metric_names=frozenset(schema.KNOWN_METRIC_NAMES),
             closed_namespaces=tuple(schema._CLOSED_NAMESPACES),
@@ -254,7 +236,6 @@ class ProjectContext:
             anomaly_event_prefix=schema.ANOMALY_EVENT_PREFIX,
             known_fault_sites=known_fault_sites(repo_root),
             documented_env_vars=documented_env_vars(repo_root),
-            extra_env_vars=extra,
             tests_corpus=tests_corpus(repo_root),
             axis_name_literals=axis_name_literals(repo_root),
         )

@@ -956,10 +956,10 @@ class HandBuiltMesh(Rule):
 class UndocumentedEnvVar(Rule):
     """Every ``FLUXMPI_TPU_*`` variable the code reads must have a row
     in the docs/observability.md reference table, and every table row
-    must correspond to a variable some code actually reads (scan set
-    plus ``bench.py``) — the table was created precisely because these
-    knobs kept drifting across five doc pages, and a one-sided check
-    would let it rot back."""
+    must correspond to a variable some scanned code actually reads —
+    the table was created precisely because these knobs kept drifting
+    across five doc pages, and a one-sided check would let it rot
+    back."""
 
     id = "undocumented-env-var"
     severity = "error"
@@ -996,9 +996,8 @@ class UndocumentedEnvVar(Rule):
         # module is among the scanned files.
         if not any(m.path == ctx.faults_path for m in modules):
             return
-        all_used = set(used) | set(ctx.extra_env_vars)
         for var in sorted(documented):
-            if var not in all_used:
+            if var not in used:
                 yield Finding(
                     self.id,
                     self.severity,
@@ -1006,9 +1005,8 @@ class UndocumentedEnvVar(Rule):
                     documented[var],
                     0,
                     f"env var {var} is documented in the reference table "
-                    f"but read by no scanned code (fluxmpi_tpu/, scripts/, "
-                    f"bench.py) — delete the stale row or restore the "
-                    f"knob",
+                    f"but read by no scanned code (fluxmpi_tpu/, scripts/) "
+                    f"— delete the stale row or restore the knob",
                     f"unread:{var}",
                 )
 

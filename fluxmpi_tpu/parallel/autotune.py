@@ -2,8 +2,7 @@
 
 PR 15 made N-D layouts declarative (one :class:`ParallelConfig` → one
 mesh + strict partition rules) but a human still picked ``dp × fsdp ×
-tp`` per model and pod shape — and the per-axis bench legs prove the
-choice is workload-dependent (fsdp ~free, tp −28% at toy scale on CPU),
+tp`` per model and pod shape, and the choice is workload-dependent,
 not guessable. This module closes ROADMAP open item 3: a four-stage
 search that needs no human in the loop and no framework coupling beyond
 the one ``init`` kwarg.

@@ -44,8 +44,8 @@ and tf.data's pipelined input processing (Murray et al., VLDB 2021).
   attributes its wall clock into the
   :mod:`~fluxmpi_tpu.telemetry.goodput` buckets (productive step,
   first-dispatch compile, data stall, checkpoint save/restore, resume,
-  preemption drain) and records live MFU from the same FLOPs helpers
-  ``bench.py`` uses; when an
+  preemption drain) and records live MFU from XLA's operation count of
+  the step (:mod:`~fluxmpi_tpu.utils.flops`); when an
   :class:`~fluxmpi_tpu.telemetry.AnomalyDetector` is installed
   (``init(anomaly=True)`` / ``FLUXMPI_TPU_ANOMALY=1``) each flush's
   loss/grad-norm/step-time is checked and a ``halt``-policy trigger
@@ -950,8 +950,8 @@ def train_loop(
     # Per-run window-cache ledger: how many window programs this run
     # reused vs compiled, and the seconds the compiles cost. Surfaces in
     # the summary (``window_cache`` / ``window_compile_seconds``) so
-    # bench legs and autotune trials can PROVE a run was a pure cache
-    # hit instead of inferring it from wall clock.
+    # autotune trials can PROVE a run was a pure cache hit instead of
+    # inferring it from wall clock.
     window_compile = {"seconds": 0.0, "hits": 0, "misses": 0}
 
     def _window_program(
@@ -1380,10 +1380,9 @@ def train_loop(
             if gp_on:
                 if first_dispatch and gp._flops_per_update is None:
                     # FLOPs per update from XLA's cost model, BEFORE the
-                    # donating dispatch consumes the state buffers — the
-                    # same accounting bench.py reports, so live MFU and
-                    # bench MFU share one implementation. The lowering
-                    # this pays is compile work: attributed as such.
+                    # donating dispatch consumes the state buffers. The
+                    # lowering this pays is compile work: attributed as
+                    # such.
                     from ..utils.flops import cost_analysis_flops
 
                     with gp.segment("compile"):
@@ -1522,8 +1521,8 @@ def train_loop(
         "anomaly": halt_rule,
         # Host dispatches of the compiled hot/window program — the
         # number the fused path exists to shrink (1 per window vs 1 per
-        # batch); dispatches/updates is the bench's directly-asserted
-        # dispatch cost.
+        # batch); dispatches/updates is the benchmark's
+        # ``dispatches_per_update``.
         "dispatches": dispatches,
         "fused_window": fused_w or None,
     }

@@ -171,8 +171,8 @@ def _instrument_step(
     Timing follows the :func:`~fluxmpi_tpu.utils.step_timer` discipline:
     the clock stops only after blocking on the step's outputs, so async
     dispatch cannot under-report. Everything else is a handful of host
-    float/dict ops — cheap enough to leave on (<2% on the mlp bench with
-    a no-op sink; emission cost is the sink's business, at flush time).
+    float/dict ops — cheap enough to leave on (emission cost is the
+    sink's business, at flush time).
 
     The step is also a trace span (``train.step`` on the
     :mod:`~fluxmpi_tpu.telemetry.tracing` timeline when tracing is

@@ -34,7 +34,7 @@ Phase split:
   engine geometry ``(slots, max_blocks_per_seq, block_size)`` — never
   on which requests are active — so **requests join and leave the
   batch with zero retrace** (the compile monitor asserts this in the
-  tests and the bench).
+  tests and the benchmark's ``compiles_in_window.serve``).
 
 The decode loop is **host-driven** (``lax.scan``-free): one dispatch +
 one small device→host token transfer per iteration, with eviction,
@@ -558,10 +558,6 @@ class InferenceEngine:
         :meth:`submit` load-sheds with a rejection (default env / 64).
       max_len: per-sequence cap on ``prompt + max_new_tokens`` (default
         ``model.max_len`` rounded down to a block multiple).
-      continuous: True (default) = requests join the decode batch
-        between any two iterations; False = static batching (a new
-        group is admitted only when every slot has drained — the A/B
-        baseline ``bench.py --child serving`` measures against).
       slo_ttft_s / slo_token_s: optional latency objectives; completions
         breaching them bump ``serving.slo_violations{kind=...}``.
       registry: metrics registry (default: the process-global one,
@@ -597,7 +593,6 @@ class InferenceEngine:
         num_blocks: int | None = None,
         max_queue: int | None = None,
         max_len: int | None = None,
-        continuous: bool = True,
         slo_ttft_s: float | None = None,
         slo_token_s: float | None = None,
         registry: MetricsRegistry | None = None,
@@ -655,7 +650,6 @@ class InferenceEngine:
         self.max_blocks_per_seq = self.max_len // self.block_size
         default_blocks = 1 + self.slots * self.max_blocks_per_seq
         nb = _resolve(num_blocks, cfg.num_blocks, _ENV_BLOCKS, default_blocks)
-        self.continuous = bool(continuous)
         self.slo_ttft_s = slo_ttft_s
         self.slo_token_s = slo_token_s
         self.flush_every = max(1, int(flush_every))
@@ -1041,16 +1035,13 @@ class InferenceEngine:
         return self._registry if self._registry is not None else get_registry()
 
     def _admit_phase(self) -> int:
-        """Move queued requests into free batch slots (continuous mode:
-        between any two iterations; static mode: only once every slot
-        has drained), prefilling each admission. FIFO — a head request
+        """Move queued requests into free batch slots between any two
+        iterations, prefilling each admission. FIFO — a head request
         waiting on blocks holds the line (documented in
         docs/serving.md). While slots are decoding, ONE admission an
         iteration: a prefill stalls every active slot, and a queue of
         them taken at once would put the sum of their prefills into one
         gap between tokens. An idle engine fills its slots at once."""
-        if not self.continuous and any(s is not None for s in self._slots):
-            return 0
         limit = 1 if self._active else self.slots
         admitted = 0
         while admitted < limit:
@@ -1422,7 +1413,6 @@ class InferenceEngine:
             total = self.cache.used_blocks + self.cache.free_blocks
             board: dict[str, Any] = dict(
                 phase=phase,
-                continuous=self.continuous,
                 slots=self.slots,
                 active=self.active_count,
                 queued=self.queue_depth,

@@ -11,7 +11,7 @@ timing is ad-hoc wall-clock deltas in example scripts). Four planes:
   (:class:`JSONLSink` / :class:`MemorySink` / :class:`ConsoleSink`);
 - built-in instrumentation recording into the *default* registry:
   eager collectives (``comm.*``), the data loader (``data.*``), the
-  train-step ``metrics=`` hook (``train.*``), and ``bench.py``;
+  train-step ``metrics=`` hook (``train.*``);
 - :class:`TrainingMonitor` — periodic device-memory snapshots,
   cross-host step-time aggregation (straggler flag), and a per-host
   heartbeat.
@@ -38,7 +38,7 @@ progress, and is the run still sane:
 - :mod:`~fluxmpi_tpu.telemetry.goodput` — :class:`GoodputTracker`
   attributes wall time into goodput/badput buckets (productive step,
   compile, data stall, checkpoint I/O, resume, preemption drain) and
-  computes **live MFU** from the same FLOPs helpers ``bench.py`` uses
+  computes **live MFU** from XLA's operation count of the step
   (:mod:`fluxmpi_tpu.utils.flops`); per-run breakdowns via
   ``scripts/goodput_report.py``;
 - :mod:`~fluxmpi_tpu.telemetry.anomaly` — :class:`AnomalyDetector`
@@ -91,7 +91,6 @@ from .registry import (  # noqa: F401
 from .schema import (  # noqa: F401
     SCHEMA,
     TRACE_SCHEMA,
-    validate_bench_record,
     validate_flight_dump,
     validate_metric,
     validate_record,
@@ -177,7 +176,6 @@ __all__ = [
     "TRACE_SCHEMA",
     "validate_record",
     "validate_metric",
-    "validate_bench_record",
     "validate_trace_export",
     "validate_flight_dump",
     "validate_watchdog_dump",
@@ -265,14 +263,7 @@ def configure(spec: Any = None) -> MetricsRegistry:
             isinstance(s, JSONLSink) and s.path == spec for s in reg.sinks
         ):
             return reg
-        # A sink pointed at the bench result bank shares the file with
-        # bench.py's merge-by-rename writer — join the shared-JSONL
-        # locking protocol; private streams keep the fast path.
-        bench_jsonl = os.environ.get("FLUXMPI_TPU_BENCH_JSONL")
-        shared = bench_jsonl is not None and os.path.abspath(
-            spec
-        ) == os.path.abspath(bench_jsonl)
-        sink = JSONLSink(spec, shared=shared)
+        sink = JSONLSink(spec)
     else:
         raise ValueError(
             f"telemetry spec must be a path, 'console', a Sink, or a "

@@ -5,8 +5,7 @@ wall-clock spent making training progress (Google's ML-goodput
 methodology; the per-run efficiency tracking in MegaScale-style LLM
 training reports). Every robustness feature in this repo *adds*
 non-productive wall time — checkpoint saves, preemption drains, elastic
-resumes — and until this plane existed nothing accounted for it:
-MFU/FLOPs lived only offline in ``bench.py``.
+resumes — and this plane is what accounts for it.
 
 :class:`GoodputTracker` attributes wall-clock into named buckets via a
 ``with tracker.segment("checkpoint_save"): ...`` context API:
@@ -27,15 +26,16 @@ bucket               attributed to
                      overhead between segments — never measured directly
 ==================== =====================================================
 
-Goodput fraction = ``step / wall``. **Live MFU** comes from the same
-helpers ``bench.py`` uses (:mod:`fluxmpi_tpu.utils.flops` — one
-implementation for the offline and production numbers): the tracker is
-told FLOPs per optimizer update once (``set_flops_per_update``, from
-XLA's cost model) and counts updates; ``report()`` derives
+Goodput fraction = ``step / wall``. **Live MFU** comes from
+:mod:`fluxmpi_tpu.utils.flops`: the tracker is told FLOPs per optimizer
+update once (``set_flops_per_update``, from XLA's cost model) and counts
+updates; ``report()`` derives
 
 - ``mfu`` — over TOTAL wall (the production number badput drags down);
-- ``mfu_productive`` — over productive ``step`` seconds only, the
-  apples-to-apples twin of the bench's synthetic-loop MFU.
+- ``mfu_productive`` — over productive ``step`` seconds only.
+
+Neither is the benchmark's ``mfu_pct`` (``PERF.md`` §3), which counts
+the operations a model requires from its shapes; see the flops module.
 
 Cost discipline (the PR 4 zero-cost-when-off contract): while
 ``enabled`` is False — the default — :meth:`segment` returns a shared
@@ -320,8 +320,8 @@ class GoodputTracker:
         (measured + the computed ``host_idle`` remainder — the buckets
         sum to the wall by construction), ``goodput_fraction``
         (productive ``step`` seconds / wall), ``updates``, ``mfu``
-        (over wall) and ``mfu_productive`` (over step seconds; the
-        bench-comparable number) — None when FLOPs or peak are unknown."""
+        (over wall) and ``mfu_productive`` (over step seconds) — None
+        when FLOPs or peak are unknown."""
         wall = self.wall_seconds()
         buckets = dict(self._buckets)
         measured = sum(buckets.values())

@@ -2,16 +2,15 @@
 
 The measurement substrate under everything in this package: eager
 collectives (:mod:`fluxmpi_tpu.comm`), the train-step ``metrics=`` hook
-(:func:`fluxmpi_tpu.parallel.make_train_step`), the data loader, the
-bench harness, and :class:`~fluxmpi_tpu.telemetry.monitor.TrainingMonitor`
-all record through one of these.
+(:func:`fluxmpi_tpu.parallel.make_train_step`), the data loader and
+:class:`~fluxmpi_tpu.telemetry.monitor.TrainingMonitor` all record
+through one of these.
 
 Design constraints (why not a prometheus client):
 
 - the hot-path cost of an update must be a couple of dict/float ops —
   instrumentation that costs more than ~1% of an eager collective or a
-  train-step dispatch would get turned off and lie by omission (the
-  round-2 bench timing bug was exactly an undisciplined measurement);
+  train-step dispatch would get turned off and lie by omission;
 - no background threads, no sockets: records leave the process only at
   explicit :meth:`MetricsRegistry.flush`, one JSONL line per flush, so a
   training loop's metrics stream is replayable and diffable;
@@ -291,7 +290,7 @@ class MetricsRegistry:
     def flush(self, **extra: Any) -> dict[str, Any]:
         """Build one schema-v1 record from the current snapshot and write
         it to every sink (one JSONL line per flush). Extra keyword fields
-        are merged into the record top-level (e.g. ``bench=result``).
+        are merged into the record top-level.
         Counters/histograms are cumulative — flushing does not reset."""
         record: dict[str, Any] = {
             "schema": SCHEMA,

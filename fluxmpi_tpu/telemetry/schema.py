@@ -1,9 +1,9 @@
 """Telemetry record schemas and validators.
 
-The single source of truth for what a telemetry JSONL line and a bench
-output record look like. `scripts/check_metrics_schema.py` loads this
-module by file path (no package import, no jax) so schema drift in
-either producer is caught at PR time without booting a backend —
+The single source of truth for what a telemetry JSONL line and the
+other cross-run records look like. `scripts/check_metrics_schema.py`
+loads this module by file path (no package import, no jax) so schema
+drift in a producer is caught at PR time without booting a backend —
 deliberately stdlib-only: importing it must never pull in jax.
 
 Telemetry flush record (one JSON object per line in a JSONL stream):
@@ -13,7 +13,7 @@ Telemetry flush record (one JSON object per line in a JSONL stream):
       "time_unix": 1753812345.123,       # host wall clock at flush
       "process": 0,                       # controller process index
       "metrics": [ <metric>, ... ],
-      ...optional extra keys (e.g. "bench" for bench emissions)
+      ...optional extra keys (``Registry.flush(**extra)``)
     }
 
 Metric objects share ``name`` (dotted, e.g. "comm.bytes"), ``type``
@@ -23,11 +23,6 @@ Metric objects share ``name`` (dotted, e.g. "comm.bytes"), ``type``
     gauge:     {"value": <number>}            # last set value
     histogram: {"count": <int>, "sum": <number>,
                 "min"/"max"/"mean"/"last": <number>}   # when count > 0
-
-Bench record (``bench.py`` stdout JSON line / BENCH_*.json "tail"):
-required keys ``metric`` (str), ``value`` (number), ``unit`` (str),
-``vs_baseline`` (number); known optional keys are type-checked, unknown
-keys are allowed (forward compatibility).
 
 Trace-plane records (schema ``fluxmpi_tpu.trace/v1``) share one top-level
 shape — ``schema``, ``kind``, ``time_unix``, ``process`` — and dispatch
@@ -96,8 +91,7 @@ STRAGGLER_CAUSES = ("desync", "data_stall", "comm_wait", "compute")
 # Layout-autotuner records (parallel/autotune.py): the banked winner +
 # full candidate table one ``autotune()`` run produces — written as the
 # ``FLUXMPI_TPU_AUTOTUNE_BANK`` file, as the ``<ckpt>.autotune.json``
-# sidecar next to the checkpoint manifest, and embedded in bench
-# records under the ``autotune`` key. A later run with the same (model
+# sidecar next to the checkpoint manifest. A later run with the same (model
 # fingerprint, topology) trusts this record INSTEAD of re-running
 # trials, so ``scripts/check_metrics_schema.py`` validates it like any
 # other cross-run contract.
@@ -410,78 +404,8 @@ HOT_PATH_SPAN_ARGS: dict[str, tuple[str, ...]] = {
 # in time, not a span), enforced by validate_trace_event.
 ANOMALY_EVENT_PREFIX = "anomaly."
 
-# Known optional bench keys -> required type(s). Unknown keys pass (new
-# fields must not break old validators); known keys with the wrong type
-# fail (that is the drift being guarded against).
-_BENCH_OPTIONAL: dict[str, tuple[type, ...]] = {
-    "platform": (str,),
-    "device_kind": (str,),
-    "n_chips": (int,),
-    "mfu": (int, float),
-    "flops_source": (str,),
-    "scan_steps": (int,),
-    "probe": (dict,),
-    "scaling": (dict,),
-    "attention": (dict,),
-    "transformer_lm": (dict,),
-    "deq": (dict,),
-    # Steady-state breakdown keys (PR 4): the null-step dispatch floor,
-    # the assembly-only loader sub-rate, and the smoke-mode marker.
-    "dispatch": (dict,),
-    "assembly_samples_per_sec": (int, float),
-    "loader_fed_path": (str,),
-    "smoke": (int,),
-    # Which bench config a record (especially a bench_failed one, which
-    # has no device_kind/n_chips) belongs to — part of the JSONL merge
-    # key, so failures from different configs bank as distinct lines.
-    "config": (str,),
-    # An MFU the harness computed but refused to report (>1.0: a broken
-    # clock or FLOPs estimate). Recorded instead of stderr-only printed
-    # so trajectory tooling can see the discard happened.
-    "mfu_discarded": (bool,),
-    # Fused-window A/B (PR 11): per-leg throughput + dispatches-per-
-    # update for the pipelined vs fuse="window" train_loop paths, so the
-    # one-dispatch-per-window claim is asserted in the record rather
-    # than inferred.
-    "fused_window": (dict,),
-    # Serving A/B (PR 13): static-batch vs continuous-batch legs on the
-    # mixed-length workload, the speedup, and the steady-state retrace
-    # count across mid-flight joins (must be 0 — the zero-retrace
-    # claim, asserted by tests/test_bench.py's smoke).
-    "serving": (dict,),
-    # ParallelConfig plane (parallel/plan.py): the train_loop child's
-    # resolved plan — axes, rule hit counts, the loop's own
-    # dispatches-per-update under the plan-derived sharding — and the
-    # per-axis composition legs (dp vs dp×fsdp vs dp×tp) on the CPU
-    # virtual mesh.
-    "parallel": (dict,),
-    "parallel_axes": (dict,),
-    # Layout autotuner (parallel/autotune.py): the full
-    # fluxmpi_tpu.autotune/v1 record of the bench's auto-layout leg —
-    # candidate table with static scores and trial throughputs, winner,
-    # bank identity. Validated as an embedded autotune record by
-    # validate_bench_record when it carries the schema tag.
-    "autotune": (dict,),
-    # Kernel-plane A/B (ISSUE 19): attention="flash" vs "naive" through
-    # the model switch on BOTH hot paths — training fwd+bwd (per-leg
-    # throughput + compiled HBM footprint from memory_analysis) and
-    # paged serving decode (per-leg tokens/sec + steady-state retrace
-    # count, which must be 0 per the no-retrace join contract).
-    "attention_ab": (dict,),
-}
-
-
 def _is_number(x: object) -> bool:
     return isinstance(x, (int, float)) and not isinstance(x, bool)
-
-
-def _bench_type_ok(v: object, types: tuple[type, ...]) -> bool:
-    """Type check for _BENCH_OPTIONAL values. bool is a subclass of int,
-    so it is accepted ONLY where (bool,) is the declared type and
-    rejected everywhere a number is expected."""
-    if isinstance(v, bool):
-        return bool in types
-    return isinstance(v, types)
 
 
 def validate_metric(m: object, where: str = "metric") -> list[str]:
@@ -590,35 +514,6 @@ def validate_record(rec: object) -> list[str]:
     else:
         for i, m in enumerate(metrics):
             errors.extend(validate_metric(m, where=f"metrics[{i}]"))
-    return errors
-
-
-def validate_bench_record(rec: object) -> list[str]:
-    """Validate a bench.py output record (the headline JSON line)."""
-    if not isinstance(rec, dict):
-        return [f"bench record is not an object: {type(rec).__name__}"]
-    errors: list[str] = []
-    if not isinstance(rec.get("metric"), str) or not rec.get("metric"):
-        errors.append("missing/invalid 'metric' (str)")
-    if not _is_number(rec.get("value")):
-        errors.append("missing numeric 'value'")
-    if not isinstance(rec.get("unit"), str):
-        errors.append("missing/invalid 'unit' (str)")
-    if not _is_number(rec.get("vs_baseline")):
-        errors.append("missing numeric 'vs_baseline'")
-    for key, types in _BENCH_OPTIONAL.items():
-        if key in rec and not _bench_type_ok(rec[key], types):
-            errors.append(
-                f"{key!r} must be {'/'.join(t.__name__ for t in types)}, "
-                f"got {type(rec[key]).__name__}"
-            )
-    if "mfu" in rec and _is_number(rec["mfu"]) and not 0 <= rec["mfu"] <= 1:
-        errors.append(f"'mfu' out of range [0, 1]: {rec['mfu']!r}")
-    at = rec.get("autotune")
-    if isinstance(at, dict) and at.get("schema") == AUTOTUNE_SCHEMA:
-        errors.extend(
-            f"autotune: {e}" for e in validate_autotune_record(at)
-        )
     return errors
 
 
@@ -888,9 +783,9 @@ def validate_fleet_snapshot(rec: object) -> list[str]:
 def validate_autotune_record(rec: object) -> list[str]:
     """Validate one layout-autotuner record (schema
     "fluxmpi_tpu.autotune/v1", produced by
-    ``parallel/autotune.autotune`` — the bank file, the checkpoint
-    sidecar, and the bench's embedded ``autotune`` block all carry the
-    same shape); returns a list of error strings (empty == valid).
+    ``parallel/autotune.autotune`` — the bank file and the checkpoint
+    sidecar carry the same shape); returns a list of error strings
+    (empty == valid).
 
     The internal consistency rules ARE the bank contract: a ``pruned``
     candidate (reason in AUTOTUNE_PRUNE_REASONS) must carry no trial, an

@@ -1,18 +1,17 @@
 """Pluggable flush targets for :class:`~fluxmpi_tpu.telemetry.MetricsRegistry`.
 
 A sink receives the full flush record (schema.py shape) and owns its
-transport. Three are provided: a JSONL file writer (the bench-compatible
-one-line-per-flush stream), an in-memory list for tests, and a rank-0
-console reporter. ``NullSink`` exists so overhead can be measured with
-emission wired up but going nowhere.
+transport. Three are provided: a JSONL file writer (one line per
+flush), an in-memory list for tests, and a rank-0 console reporter.
+``NullSink`` exists so overhead can be measured with emission wired up
+but going nowhere.
 """
 
 from __future__ import annotations
 
-import contextlib
 import json
 import sys
-from typing import Any, IO, Iterator
+from typing import Any, IO
 
 __all__ = [
     "Sink",
@@ -20,28 +19,7 @@ __all__ = [
     "MemorySink",
     "ConsoleSink",
     "NullSink",
-    "jsonl_lock",
 ]
-
-
-@contextlib.contextmanager
-def jsonl_lock(path: str) -> Iterator[None]:
-    """Exclusive advisory lock on ``<path>.lock`` — the serialization
-    protocol every writer of a shared JSONL must join: per-line sink
-    appends here, and the bench result banker's read-merge-replace
-    (``bench.py``), so a merge never drops a line another writer lands
-    mid-merge. Non-POSIX platforms degrade to best-effort unlocked."""
-    with open(path + ".lock", "a", encoding="utf-8") as lock:
-        try:
-            import fcntl
-        except ImportError:  # pragma: no cover - non-POSIX
-            yield
-            return
-        fcntl.flock(lock.fileno(), fcntl.LOCK_EX)
-        try:
-            yield
-        finally:
-            fcntl.flock(lock.fileno(), fcntl.LOCK_UN)
 
 
 class Sink:
@@ -84,28 +62,14 @@ class JSONLSink(Sink):
     should write to its own path in multi-host runs (pass e.g.
     ``f"metrics.{jax.process_index()}.jsonl"``); lines carry ``process``
     so merged streams stay attributable.
-
-    ``shared=True`` opts into the shared-JSONL protocol for a path that a
-    merge-by-rename writer also owns (the ``FLUXMPI_TPU_BENCH_JSONL``
-    result bank, ``bench.py``): each line takes :func:`jsonl_lock` and
-    reopens the file, so a concurrent merge never drops the line and the
-    inode swap never strands the sink appending to an unlinked file. A
-    sink on its own private stream (the default) keeps the cheap
-    persistent handle and creates no ``.lock`` sidecar.
     """
 
-    def __init__(self, path: str, *, shared: bool = False):
+    def __init__(self, path: str):
         self.path = path
-        self.shared = shared
         self._file: IO[str] | None = None
 
     def write(self, record: dict[str, Any]) -> None:
         line = json.dumps(record) + "\n"
-        if self.shared:
-            with jsonl_lock(self.path):
-                with open(self.path, "a", encoding="utf-8") as f:
-                    f.write(line)
-            return
         if self._file is None:
             self._file = open(self.path, "a", encoding="utf-8")
         self._file.write(line)

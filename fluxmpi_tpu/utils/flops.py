@@ -1,16 +1,24 @@
-"""Shared FLOPs / MFU accounting for the bench harness and the live loop.
+"""The live-MFU plane's operation count, and the autotuner's static cost.
 
-Promoted out of ``bench.py`` (which had the only MFU implementation in
-the repo, usable solely offline) so the run-health plane
-(:mod:`fluxmpi_tpu.telemetry.goodput`) computes **live** MFU with the
-exact same peak table, cost-model fallback, and formula the bench
-reports — one implementation, two consumers, no drift between the
-offline number and the production one.
+The run-health plane (:mod:`fluxmpi_tpu.telemetry.goodput`, fed by
+``train_loop``) reports an operator's **live** ``goodput.mfu``: the
+FLOPs XLA's ``cost_analysis`` gives for the step's executable
+(:func:`cost_analysis_flops`, :func:`executable_flops`), times updates
+per second, over the chip's peak (:func:`chip_peak_flops`, :func:`mfu`).
+The layout autotuner scores candidates on :func:`executable_cost` plus
+:func:`pallas_kernel_cost`.
+
+This is explicitly NOT the benchmark's ``mfu_pct`` (``PERF.md`` §3,
+``benchmarks/harness/opsbytes.py``), which counts the operations the
+model REQUIRES from its shapes. ``cost_analysis`` counts what the
+program EXECUTES: recomputation (remat, a flash backward's second pass
+over the scores) reads as useful work, and a Pallas custom call reads
+as zero. The live number is for watching one run against itself; a
+claim about speed quotes the benchmark's.
 
 Deliberately import-light: nothing here imports jax at module scope
 (``cost_analysis_flops`` only touches the compiled-step objects handed
-to it), so ``bench.py``'s parent driver — which must never boot a
-backend — can delegate to these helpers lazily from its children.
+to it).
 """
 
 from __future__ import annotations
@@ -22,7 +30,6 @@ __all__ = [
     "cost_analysis_flops",
     "executable_cost",
     "executable_flops",
-    "jaxpr_dot_flops",
     "mfu",
     "pallas_kernel_cost",
     "PEAK_FLOPS",
@@ -223,8 +230,7 @@ def mfu(
     tracker's hook for tests and unlisted chips). The RAW value is
     returned even when it exceeds 1.0 — an impossible number means a
     broken clock or FLOPs estimate, and the *caller* decides whether to
-    discard it (``bench.py`` does, recording ``mfu_discarded``) or to
-    surface it."""
+    discard it or to surface it."""
     if not flops_per_step:
         return None
     if peak is None:

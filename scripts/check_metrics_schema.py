@@ -1,7 +1,7 @@
 #!/usr/bin/env python
-"""Validate bench JSON, telemetry JSONL, and trace-plane files against
-the documented schemas (fluxmpi_tpu/telemetry/schema.py — the single
-source of truth).
+"""Validate telemetry JSONL, trace-plane files, manifests and the other
+schema-tagged records against the documented schemas
+(fluxmpi_tpu/telemetry/schema.py — the single source of truth).
 
 Usage:
     python scripts/check_metrics_schema.py [FILE ...]
@@ -20,9 +20,8 @@ Usage:
   ``"schema": "fluxmpi_tpu.resize/v1"`` (the live-resize badput bank,
   ``init(resize=...)`` / ``FLUXMPI_TPU_RESIZE``), which validate as
   resize records (a number for every ``RESIZE_PHASES`` phase, totals
-  that sum; transient handoff half-records pass untouched) — and a
-  line carrying a ``bench`` key must also embed a valid bench record. Metric names in the
-  framework-owned ``fault.`` / ``checkpoint.`` / ``goodput.`` /
+  that sum; transient handoff half-records pass untouched). Metric
+  names in the framework-owned ``fault.`` / ``checkpoint.`` / ``goodput.`` /
   ``anomaly.`` / ``compile.`` / ``memory.`` namespaces must come from
   ``schema.KNOWN_METRIC_NAMES``
   (``fault.injected``, ``checkpoint.retries``, the run-health plane's
@@ -55,13 +54,9 @@ Usage:
   completed live-resize record saved whole validates like a bank line;
   a pending handoff stamp (``.fluxmpi_resize.json``, ``"handoff":
   true``) passes untouched.
-- other ``*.json`` files: a bench record — either bench.py's raw output
-  (``{"metric": ...}``) or a driver BENCH_*.json wrapper whose ``tail``
-  holds the JSON line bench.py printed.
-
-With no arguments, validates every ``BENCH_*.json`` in the repo root —
-the PR-time drift check (wired into tests/test_telemetry.py; the
-trace-plane paths are exercised by tests/test_tracing.py).
+- any other ``*.json`` file carries no schema tag this script knows and
+  is an error: a record that cannot be told apart cannot be held to a
+  schema.
 
 The schema module is loaded by file path, NOT via ``import fluxmpi_tpu``:
 this script must stay runnable in a second without booting jax or any
@@ -70,7 +65,6 @@ backend.
 
 from __future__ import annotations
 
-import glob
 import importlib.util
 import json
 import os
@@ -91,25 +85,6 @@ def _load_schema():
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.load_schema_module(_REPO)
-
-
-def _bench_record_from(data: dict) -> dict | None:
-    """Extract the bench record from either bench.py's raw output or a
-    driver BENCH_*.json wrapper (record rides as the last JSON line of
-    the captured ``tail``). Returns None when the wrapper holds no record
-    (e.g. a round where bench.py never ran)."""
-    if "metric" in data:
-        return data
-    tail = data.get("tail")
-    if isinstance(tail, str):
-        for line in reversed(tail.strip().splitlines()):
-            try:
-                rec = json.loads(line)
-            except json.JSONDecodeError:
-                continue
-            if isinstance(rec, dict) and "metric" in rec:
-                return rec
-    return None
 
 
 def check_file(path: str, schema) -> list[str]:
@@ -169,9 +144,6 @@ def check_file(path: str, schema) -> list[str]:
                 continue
             for e in schema.validate_record(rec):
                 errors.append(f"{path}:{i}: {e}")
-            if isinstance(rec, dict) and "bench" in rec:
-                for e in schema.validate_bench_record(rec["bench"]):
-                    errors.append(f"{path}:{i}: bench: {e}")
         return errors
     try:
         data = json.loads(content)
@@ -203,19 +175,12 @@ def check_file(path: str, schema) -> list[str]:
         if data.get("handoff"):
             return errors
         return [f"{path}: {e}" for e in schema.validate_resize_record(data)]
-    rec = _bench_record_from(data) if isinstance(data, dict) else None
-    if rec is None:
-        # A wrapper with no bench line is a bench that never ran — not a
-        # schema violation; drift in records that DO exist is the target.
-        return errors
-    for e in schema.validate_bench_record(rec):
-        errors.append(f"{path}: {e}")
-    return errors
+    tag = data.get("schema") if isinstance(data, dict) else None
+    return [f"{path}: no known 'schema' tag (got {tag!r})"]
 
 
-def main(argv: list[str]) -> int:
+def main(paths: list[str]) -> int:
     schema = _load_schema()
-    paths = argv or sorted(glob.glob(os.path.join(_REPO, "BENCH_*.json")))
     if not paths:
         print("check_metrics_schema: nothing to validate", file=sys.stderr)
         return 0

@@ -19,6 +19,8 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
+import contextlib  # noqa: E402
+
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
@@ -33,6 +35,34 @@ def world():
 
     mesh = fm.init(verbose=True)
     yield mesh
+
+
+@contextlib.contextmanager
+def _own_runtime():
+    """Let a test init()/shutdown() its own runtime and planes, as a
+    script on the chip does, and hand the session fixture's world back
+    untouched."""
+    from fluxmpi_tpu import runtime
+    from fluxmpi_tpu.telemetry import compileplane
+
+    saved = (runtime._state.initialized, runtime._state.mesh,
+             runtime._state.plan)
+    runtime._state.initialized = False
+    runtime._state.mesh = None
+    runtime._state.plan = None
+    try:
+        yield
+    finally:
+        runtime.shutdown()
+        compileplane.set_compile_monitor(None)
+        (runtime._state.initialized, runtime._state.mesh,
+         runtime._state.plan) = saved
+
+
+@pytest.fixture()
+def own_runtime():
+    """``with own_runtime(): fm.init(...)``: see :func:`_own_runtime`."""
+    return _own_runtime
 
 
 @pytest.fixture()

@@ -4,7 +4,6 @@ must pass at tiny sizes on the 8-device CPU mesh (kernels interpreted),
 so that a chip call is never spent on a wrong path, argument or
 assertion. The script itself has no small-size or CPU switch."""
 
-import contextlib
 import json
 import os
 import subprocess
@@ -20,27 +19,6 @@ TINY_LM = {"vocab_size": 256, "max_len": 32, "num_layers": 2,
 # The smallest ResNet of the zoo, at 32x32: a ResNet-50 compile on
 # XLA:CPU takes minutes.
 TINY_RESNET = {"model": "ResNet18", "image": 32, "classes": 10, "batch": 16}
-
-
-@contextlib.contextmanager
-def _own_runtime():
-    """Let a phase init()/shutdown() its own runtime and planes, and
-    hand the session fixture's world back untouched."""
-    from fluxmpi_tpu import runtime
-    from fluxmpi_tpu.telemetry import compileplane
-
-    saved = (runtime._state.initialized, runtime._state.mesh,
-             runtime._state.plan)
-    runtime._state.initialized = False
-    runtime._state.mesh = None
-    runtime._state.plan = None
-    try:
-        yield
-    finally:
-        runtime.shutdown()
-        compileplane.set_compile_monitor(None)
-        (runtime._state.initialized, runtime._state.mesh,
-         runtime._state.plan) = saved
 
 
 def test_script_refuses_the_cpu():
@@ -75,8 +53,8 @@ def test_failed_phase_ends_nonzero(monkeypatch, capsys):
     assert "phase device failed: AssertionError: deliberate" in last["reason"]
 
 
-def test_phase_device(world):
-    with _own_runtime():
+def test_phase_device(world, own_runtime):
+    with own_runtime():
         rec = chip_smoke.phase_device(jax.devices()[:1])
     assert rec["platform"] == "cpu" and rec["device_count"] == 8
     assert rec["mesh"] == {"dp": 1}
@@ -89,8 +67,8 @@ def test_phase_kernels_interpreted(world):
     assert rec["cases"] == 1 and len(rec["asserted"]) == 1
 
 
-def test_phase_train_lm(world):
-    with _own_runtime():
+def test_phase_train_lm(world, own_runtime):
+    with own_runtime():
         rec = chip_smoke.phase_train_lm(TINY_LM, seed=0, compiled=False)
     assert rec["updates"] == 8
     assert rec["fused_window"] == 2 and rec["device_gather"] is True
@@ -99,8 +77,8 @@ def test_phase_train_lm(world):
     assert any("zero compiles" in a for a in rec["asserted"])
 
 
-def test_phase_serve_lm(world):
-    with _own_runtime():
+def test_phase_serve_lm(world, own_runtime):
+    with own_runtime():
         rec = chip_smoke.phase_serve_lm(
             TINY_LM, seed=0, prompt_lengths=(5, 20, 5, 20),
             new_tokens=8, late=2, head_start=3, compiled=False,
@@ -110,15 +88,15 @@ def test_phase_serve_lm(world):
     assert rec["exact_vs_generate"] == 4 and rec["near_tie_requests"] == 0
 
 
-def test_phase_train_resnet(world):
-    with _own_runtime():
+def test_phase_train_resnet(world, own_runtime):
+    with own_runtime():
         rec = chip_smoke.phase_train_resnet(TINY_RESNET, seed=0)
     assert rec["updates"] == 8 and rec["fused_window"] == 2
     assert any("batch_stats" in a for a in rec["asserted"])
 
 
-def test_phase_multichip(world):
-    with _own_runtime():
+def test_phase_multichip(world, own_runtime):
+    with own_runtime():
         rec = chip_smoke.phase_multichip(
             TINY_LM, devices=jax.devices()[:4], seed=0, compiled=False
         )

@@ -587,63 +587,6 @@ def test_check_metrics_schema_script_accepts_fault_metrics(world, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Bench result banking (satellite: merge keyed by config, not clobber)
-# ---------------------------------------------------------------------------
-
-
-def test_bench_jsonl_merges_by_config(world, tmp_path, monkeypatch):
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "_cms2", os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "scripts", "check_metrics_schema.py",
-        ),
-    )
-    cms = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(cms)
-    schema = cms._load_schema()
-
-    import bench
-
-    path = tmp_path / "bench.jsonl"
-    monkeypatch.setenv("FLUXMPI_TPU_BENCH_JSONL", str(path))
-
-    def result(metric, value, **extra):
-        rec = {"metric": metric, "value": value, "unit": "samples/s",
-               "vs_baseline": 1.0, "platform": "cpu", "device_kind": "cpu"}
-        rec.update(extra)
-        return rec
-
-    bench._emit_telemetry(result("mlp_samples_per_sec_per_chip", 100.0))
-    bench._emit_telemetry(result("resnet_samples_per_sec_per_chip", 50.0))
-    # Re-running the first config REPLACES its line (interrupted-sweep
-    # accumulation), it does not append a duplicate.
-    bench._emit_telemetry(result("mlp_samples_per_sec_per_chip", 120.0))
-    lines = [json.loads(ln) for ln in path.read_text().splitlines() if ln]
-    assert len(lines) == 2
-    by_metric = {rec["bench"]["metric"]: rec["bench"]["value"] for rec in lines}
-    assert by_metric == {
-        "mlp_samples_per_sec_per_chip": 120.0,
-        "resnet_samples_per_sec_per_chip": 50.0,
-    }
-    # A different config (n_chips) of the same metric banks separately.
-    bench._emit_telemetry(result("mlp_samples_per_sec_per_chip", 80.0, n_chips=8))
-    assert len(path.read_text().splitlines()) == 3
-    # Non-bench telemetry lines in the same file survive the merge.
-    with open(path, "a") as f:
-        reg = MetricsRegistry()
-        reg.counter("train.steps").inc(3)
-        f.write(json.dumps(reg.flush()) + "\n")
-    bench._emit_telemetry(result("mlp_samples_per_sec_per_chip", 130.0))
-    lines = [json.loads(ln) for ln in path.read_text().splitlines() if ln]
-    assert len(lines) == 4
-    assert sum(1 for rec in lines if "bench" not in rec) == 1
-    # The merged stream still validates against the documented schemas.
-    assert cms.check_file(str(path), schema) == []
-
-
-# ---------------------------------------------------------------------------
 # delay= entries: stall injection (the liveness-chaos producer)
 # ---------------------------------------------------------------------------
 

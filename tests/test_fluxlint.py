@@ -551,18 +551,6 @@ def test_env_rule_quiet_when_table_matches_and_skips_docstrings():
     assert r.findings == []
 
 
-def test_env_rule_extra_roots_cover_bench_only_vars():
-    ctx = _ctx(
-        documented_env_vars={"FLUXMPI_TPU_DOCUMENTED": 10,
-                             "FLUXMPI_TPU_BENCH_ONLY": 11},
-        extra_env_vars={"FLUXMPI_TPU_BENCH_ONLY"},
-        faults_path="pkg/e.py",
-    )
-    src = 'import os\nv = os.environ.get("FLUXMPI_TPU_DOCUMENTED")\n'
-    r = lint_source(src, "pkg/e.py", ctx, rules=[UndocumentedEnvVar()])
-    assert r.findings == []
-
-
 # ---------------------------------------------------------------------------
 # Suppressions and baseline
 # ---------------------------------------------------------------------------

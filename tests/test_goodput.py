@@ -60,25 +60,47 @@ def plane_off():
 
 
 # ---------------------------------------------------------------------------
-# Shared FLOPs/MFU helpers (promoted out of bench.py)
+# FLOPs/MFU helpers of the live-MFU plane (utils.flops)
 # ---------------------------------------------------------------------------
 
 
-def test_flops_helpers_match_bench_delegates():
-    import bench
+@pytest.mark.parametrize(
+    "device_kind, peak",
+    [
+        ("TPU v5 lite", 197e12),
+        ("TPU v5e", 197e12),
+        ("TPU v4", 275e12),
+        ("TPU v6 lite", 918e12),
+        # Substring lookup: "TPU v5p" must hit the v5p row, not the
+        # "v5 lite"/v5e one.
+        ("TPU v5p", 459e12),
+        ("cpu", None),
+    ],
+)
+def test_chip_peak_flops_lookup(device_kind, peak):
+    assert flops_util.chip_peak_flops(device_kind) == peak
 
-    # One implementation: the bench module delegates to utils.flops.
-    assert bench._chip_peak_flops("TPU v5 lite") == flops_util.chip_peak_flops(
-        "TPU v5 lite"
-    )
-    assert bench._mfu(1e12, 98.5, 1, "TPU v5 lite") == flops_util.mfu(
-        1e12, 98.5, 1, "TPU v5 lite"
-    )
+
+@pytest.mark.parametrize(
+    "flops, rate, n_dev, device_kind, expected",
+    [
+        # 1e12 FLOPs/step at 98.5 steps/s on one v5e (197e12 peak) = 50%.
+        (1e12, 98.5, 1, "TPU v5 lite", 0.5),
+        # Per-chip normalization.
+        (2e12, 98.5, 2, "TPU v5 lite", 0.5),
+        # Unknown chip or missing FLOPs -> None.
+        (1e12, 10.0, 1, "cpu", None),
+        (None, 10.0, 1, "TPU v5 lite", None),
+        (0.0, 10.0, 1, "TPU v5 lite", None),
+    ],
+)
+def test_mfu_math(flops, rate, n_dev, device_kind, expected):
+    assert flops_util.mfu(flops, rate, n_dev, device_kind) == expected
 
 
 def test_mfu_raw_returns_impossible_values_for_caller_decision():
-    # The shared helper reports the raw number; discarding is the
-    # caller's policy (bench records mfu_discarded, see test_bench).
+    # The helper reports the raw number; discarding is the caller's
+    # policy.
     raw = flops_util.mfu(1e12, 1000.0, 1, "TPU v5 lite")
     assert raw is not None and raw > 1.0
     assert flops_util.mfu(None, 10.0, 1, "TPU v5 lite") is None
@@ -86,19 +108,6 @@ def test_mfu_raw_returns_impossible_values_for_caller_decision():
     # peak= override bypasses the device-kind table (live-tracker hook).
     assert flops_util.mfu(1e12, 98.5, 1, peak=197e12) == 0.5
     assert flops_util.mfu(1e12, 98.5, 1, None) is None
-
-
-def test_bench_record_carries_mfu_discarded_flag():
-    rec = {
-        "metric": "m",
-        "value": 1.0,
-        "unit": "x",
-        "vs_baseline": 1.0,
-        "mfu_discarded": True,
-    }
-    assert tschema.validate_bench_record(rec) == []
-    rec["mfu_discarded"] = "yes"  # wrong type: drift fails the check
-    assert any("mfu_discarded" in e for e in tschema.validate_bench_record(rec))
 
 
 # ---------------------------------------------------------------------------
@@ -169,8 +178,7 @@ def test_tracker_disabled_reads_no_clock():
 
 
 def test_tracker_mfu_uses_shared_helper():
-    # Live MFU == bench.py's for the same FLOPs/rate — both go through
-    # utils.flops.mfu, so the numbers are identical by construction.
+    # Live MFU is utils.flops.mfu of the tracker's FLOPs and rate.
     clock = _fake_clock(0.0, 0.0, 2.0, 10.0)
     t = GoodputTracker(clock=clock, peak_flops_per_chip=197e12, n_chips=8)
     t.start_run()
@@ -537,8 +545,7 @@ def test_train_loop_goodput_accounting(world, plane_off):
         reg.gauge("goodput.bucket_seconds", bucket="step").value
         == pytest.approx(rep["buckets"]["step"], rel=1e-3)
     )
-    # FLOPs came from the shared cost-model helper -> live MFU inputs
-    # are the ones bench.py would use for this step function.
+    # FLOPs came from the cost-model helper (utils.flops).
     assert rep["flops_per_update"] is None or rep["flops_per_update"] > 0
 
 
@@ -565,11 +572,10 @@ def test_train_loop_resets_tracker_window_per_run(world, plane_off):
     assert s2["goodput"]["wall_seconds"] < s1["goodput"]["wall_seconds"] + 60
 
 
-def test_train_loop_live_mfu_matches_bench_formula(world, plane_off):
-    # Acceptance: live MFU == bench.py's for the same step function.
-    # Both sides read FLOPs from utils.flops.cost_analysis_flops and
-    # feed utils.flops.mfu; with the same measured rate the numbers are
-    # identical. (CPU has no peak-FLOPs entry, so the tracker gets the
+def test_train_loop_live_mfu_matches_flops_formula(world, plane_off):
+    # Acceptance: the loop's live MFU is utils.flops.mfu of the FLOPs
+    # utils.flops.cost_analysis_flops read and the rate the tracker
+    # measured. (CPU has no peak-FLOPs entry, so the tracker gets the
     # v5e peak injected — the formula, not the table, is under test.)
     tracker = GoodputTracker(peak_flops_per_chip=197e12, n_chips=8)
     goodput.set_goodput_tracker(tracker)
@@ -584,13 +590,13 @@ def test_train_loop_live_mfu_matches_bench_formula(world, plane_off):
     if rep["flops_per_update"] is None:
         pytest.skip("XLA cost analysis unavailable on this backend")
     step_s = rep["buckets"]["step"]
-    bench_style = flops_util.mfu(
+    by_formula = flops_util.mfu(
         rep["flops_per_update"],
         rep["updates"] / step_s,
         8,
         "TPU v5 lite",  # same 197e12 peak the tracker was given
     )
-    assert rep["mfu_productive"] == bench_style
+    assert rep["mfu_productive"] == by_formula
 
 
 def test_train_loop_nan_halts_cleanly_with_bundle(world, tmp_path, plane_off):

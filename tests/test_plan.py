@@ -293,10 +293,9 @@ def test_gpt2_composed_plan_matches_dp_only(world):
 
 
 def test_train_loop_fused_window_under_plan(world):
-    """The scaling legs' contract in-tree: train_loop(fuse="window")
-    drives a plan-sharded step at one dispatch per window — the
-    dispatches-per-update assertion the bench makes, held under the
-    plan-derived (dp×fsdp) sharding."""
+    """train_loop(fuse="window") drives a plan-sharded step at one
+    dispatch per window — the benchmark's ``dispatches_per_update``,
+    held under the plan-derived (dp×fsdp) sharding."""
     import fluxmpi_tpu as fm
     from fluxmpi_tpu import ParallelConfig
     from fluxmpi_tpu.data import ArrayDataset, DistributedDataLoader

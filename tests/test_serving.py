@@ -428,27 +428,6 @@ def test_one_admission_an_iteration_while_slots_decode(
     assert all(r.status == "finished" for r in waiting)
 
 
-def test_static_batching_gangs_admissions(model, engine_factory):
-    """continuous=False is the A/B baseline: a new group is admitted
-    only when every slot has drained, so a short request gangs behind a
-    long one and total decode steps grow — the loss continuous batching
-    exists to recover."""
-    rng = np.random.default_rng(5)
-    workload = [(6, 16), (4, 2), (5, 2), (4, 2)]
-
-    def run_mode(continuous):
-        eng = engine_factory(slots=2, continuous=continuous)
-        for plen, mnew in workload:
-            eng.submit(_prompt(rng, plen), mnew)
-        return eng.run()
-
-    static = run_mode(False)
-    cont = run_mode(True)
-    assert static["completed"] == cont["completed"] == 4
-    assert static["tokens"] == cont["tokens"]
-    assert cont["decode_steps"] < static["decode_steps"]
-
-
 # ---------------------------------------------------------------------------
 # Streaming + latency accounting
 # ---------------------------------------------------------------------------

@@ -1,6 +1,6 @@
 """Telemetry subsystem tests: registry semantics, sink round-trips, comm
 instrumentation over the 8-device CPU mesh, the train-step metrics hook,
-the TrainingMonitor, and the JSONL/bench schema checker.
+the TrainingMonitor, and the JSONL schema checker.
 
 The acceptance loop at the bottom is the PR's contract: a CPU-only
 training loop with the metrics hook enabled must produce a JSONL stream
@@ -31,7 +31,6 @@ from fluxmpi_tpu.telemetry import (
     TrainingMonitor,
     configure,
     get_registry,
-    validate_bench_record,
     validate_record,
 )
 from fluxmpi_tpu.telemetry import schema
@@ -176,7 +175,7 @@ def test_jsonl_sink_round_trip(tmp_path):
 
 
 def test_jsonl_sink_private_stream_keeps_fast_path(tmp_path):
-    # Default (non-shared) sink: persistent handle, no .lock sidecar.
+    # The sink's one mode: persistent handle, no .lock sidecar.
     path = str(tmp_path / "private.jsonl")
     sink = JSONLSink(path)
     sink.write({"a": 1})
@@ -184,37 +183,6 @@ def test_jsonl_sink_private_stream_keeps_fast_path(tmp_path):
     assert not os.path.exists(path + ".lock")
     sink.close()
     assert [json.loads(l)["a"] for l in open(path)] == [1, 2]
-
-
-def test_jsonl_sink_shared_survives_merge_by_rename(tmp_path):
-    # shared=True reopens per line: a merge-by-rename writer swapping the
-    # inode between writes must not strand the sink on the unlinked file.
-    path = str(tmp_path / "bank.jsonl")
-    sink = JSONLSink(path, shared=True)
-    sink.write({"a": 1})
-    os.rename(path, path + ".merged")  # simulate bench's replace
-    sink.write({"a": 2})
-    assert [json.loads(l)["a"] for l in open(path)] == [2]
-    assert os.path.exists(path + ".lock")
-    sink.close()
-
-
-def test_configure_marks_bench_bank_path_shared(tmp_path, monkeypatch):
-    bank = str(tmp_path / "bank.jsonl")
-    other = str(tmp_path / "other.jsonl")
-    monkeypatch.setenv("FLUXMPI_TPU_BENCH_JSONL", bank)
-    try:
-        configure(bank)
-        configure(other)
-        by_path = {
-            s.path: s for s in get_registry().sinks if isinstance(s, JSONLSink)
-        }
-        assert by_path[bank].shared is True
-        assert by_path[other].shared is False
-    finally:
-        for s in list(get_registry().sinks):
-            if isinstance(s, JSONLSink) and s.path in (bank, other):
-                get_registry().remove_sink(s)
 
 
 def test_memory_and_null_sinks_and_close():
@@ -537,7 +505,7 @@ def test_transform_with_rng_without_transform_rejected(world):
 
 
 # ---------------------------------------------------------------------------
-# Schema checker script + bench schema
+# Schema checker script
 # ---------------------------------------------------------------------------
 
 
@@ -550,9 +518,14 @@ def _run_checker(*args):
     )
 
 
-def test_checker_passes_repo_bench_files():
-    proc = _run_checker()  # no args → BENCH_*.json in the repo root
-    assert proc.returncode == 0, proc.stderr + proc.stdout
+def test_checker_rejects_json_without_a_known_schema_tag(tmp_path):
+    # A .json the checker cannot tell apart is held to no schema: an
+    # error, not a silent pass.
+    untagged = tmp_path / "untagged.json"
+    untagged.write_text(json.dumps({"metric": "m", "value": 1.0}))
+    proc = _run_checker(str(untagged))
+    assert proc.returncode == 1
+    assert "schema" in proc.stderr
 
 
 def test_checker_validates_jsonl(tmp_path):
@@ -569,56 +542,6 @@ def test_checker_validates_jsonl(tmp_path):
     proc = _run_checker(str(bad))
     assert proc.returncode == 1
     assert "schema" in proc.stderr and "not JSON" in proc.stderr
-
-
-def test_bench_record_schema():
-    ok = {
-        "metric": "mlp_quickstart_samples_per_sec_per_chip",
-        "value": 84080.6,
-        "unit": "samples/sec/chip",
-        "vs_baseline": 1.0,
-        "platform": "cpu",
-        "device_kind": "cpu",
-        "n_chips": 1,
-        "mfu": 0.5,
-        "probe": {"attempts": []},
-        "future_key": object(),  # unknown keys must pass
-    }
-    assert validate_bench_record(ok) == []
-    assert validate_bench_record({"value": "x"})  # missing/mistyped keys
-    assert any(
-        "mfu" in e for e in validate_bench_record({**ok, "mfu": 6.33})
-    )
-    assert any(
-        "n_chips" in e for e in validate_bench_record({**ok, "n_chips": "8"})
-    )
-
-
-def test_bench_emit_telemetry_writes_valid_line(tmp_path, monkeypatch):
-    import bench
-
-    path = str(tmp_path / "bench.jsonl")
-    monkeypatch.setenv("FLUXMPI_TPU_BENCH_JSONL", path)
-    result = {
-        "metric": "mlp_quickstart_samples_per_sec_per_chip",
-        "value": 100.0,
-        "unit": "samples/sec/chip",
-        "vs_baseline": 1.0,
-        "platform": "cpu",
-        "device_kind": "cpu",
-        "n_chips": 1,
-        "scaling": {"scaling_efficiency": 0.9},
-    }
-    bench._emit_telemetry(result)
-    lines = open(path, encoding="utf-8").read().splitlines()
-    assert len(lines) == 1
-    rec = json.loads(lines[0])
-    assert validate_record(rec) == []
-    assert validate_bench_record(rec["bench"]) == []
-    names = {m["name"]: m for m in rec["metrics"]}
-    assert names["bench." + result["metric"]]["value"] == 100.0
-    assert names["bench.scaling_efficiency"]["value"] == 0.9
-    assert _run_checker(path).returncode == 0
 
 
 # ---------------------------------------------------------------------------

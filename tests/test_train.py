@@ -386,6 +386,10 @@ def test_policy_casts_params_entering_loss(world):
     data = shard_batch(batch)
     for _ in range(40):
         st, loss = step(st, data)
+        # Drain every update: forty 8-device programs queued back to back
+        # can starve XLA:CPU's in-process all-reduce rendezvous of a
+        # thread (7 of 8 arrive), and it aborts the process after 40 s.
+        loss.block_until_ready()
     assert seen and all(d == jnp.bfloat16 for d in seen)  # compute dtype
     leaves = jax.tree_util.tree_leaves(st.params)
     assert all(x.dtype == jnp.float32 for x in leaves)  # f32 masters
