@@ -237,7 +237,8 @@ class ExpertMLP(nn.Module):
     ``route_scale``. The layer HOLDS the contiguous range
     ``expert_range`` of the experts (default: all): the (token, expert)
     pairs are sorted by expert, the held experts' pairs first, and one
-    grouped matmul (``jax.lax.ragged_dot``) a projection computes them;
+    grouped matmul (:func:`~fluxmpi_tpu.ops.grouped_matmul.grouped_matmul`:
+    ``jax.lax.ragged_dot``'s meaning) a projection computes them;
     pairs routed to experts held elsewhere add nothing here (on one chip
     the layer runs without its exchange). The shared expert, which every
     token passes, is added where ``include_shared``.
@@ -305,11 +306,14 @@ class ExpertMLP(nn.Module):
         with jax.named_scope("moe_experts"):
             rows = u.astype(self.dtype)[order // k]
 
+            # Imported here: ``fluxmpi_tpu.ops`` brings Pallas with it, which
+            # a model without expert layers may never need.
+            from ..ops.grouped_matmul import grouped_matmul
+
             def grouped(x, w):
                 # Rows past the held experts' pairs come out zero.
-                return jax.lax.ragged_dot(
-                    x.astype(self.dtype), w.astype(self.dtype), sizes,
-                    preferred_element_type=jnp.float32,
+                return grouped_matmul(
+                    x.astype(self.dtype), w.astype(self.dtype), sizes
                 )
 
             h = jax.nn.silu(grouped(rows, w1)) * grouped(rows, w3)
@@ -394,6 +398,22 @@ class DecoderLM(nn.Module):
             (c.num_key_value_heads, c.head_dim,
              c.sliding_window if kind == SLIDING else None)
             for kind in c.layer_types
+        )
+
+    def expert_row_tile(self, tokens: int) -> int | None:
+        """The rows of one row tile of the expert layers' grouped matmul
+        in a call over ``tokens`` tokens, None where it is XLA's
+        ``ragged_dot`` (or the model has no expert layer): what
+        :func:`~fluxmpi_tpu.ops.grouped_matmul.weight_visits` counts
+        a tick's weight visits by."""
+        from ..ops.grouped_matmul import row_tile
+
+        c = self.config
+        if c.num_dense_layers >= c.num_layers:
+            return None
+        return row_tile(
+            tokens * c.num_experts_per_tok, c.hidden_size,
+            c.moe_intermediate_size, self.dtype,
         )
 
     @nn.compact

@@ -736,6 +736,7 @@ class InferenceEngine:
         self._experts_touched = 0
         self._expert_slots = 0
         self._expert_layers = 0
+        self._expert_weight_visits = 0
         # Registry-counter delta baselines (see _resolve_run).
         self._counted_steps = 0
         self._counted_tokens = 0
@@ -1219,6 +1220,18 @@ class InferenceEngine:
                         / np.maximum(by_layer.mean(axis=1), 1e-9)
                     )),
                 )
+                # Where the grouped matmul is the kernel: the weight
+                # blocks a projection fetched over the experts touched.
+                tile = getattr(self.model, "expert_row_tile", None)
+                tile = tile(self.slots) if tile is not None else None
+                if tile is not None and touched:
+                    from ..ops.grouped_matmul import weight_visits
+
+                    visits = weight_visits(by_layer, tile)
+                    self._expert_weight_visits += visits
+                    delivery.set_metadata(
+                        expert_weight_visits_per_touched=visits / touched
+                    )
 
     def _free(self, slot: _Slot) -> None:
         for kind, blocks in enumerate(slot.blocks):
@@ -1297,9 +1310,12 @@ class InferenceEngine:
         would hold for the same slots. A model with expert layers counts
         ``expert_tokens`` ((token, expert) pairs routed), ``experts_touched``
         ((layer, expert) cells that received at least one) and
-        ``expert_slots`` (cells in all), summed over decode steps. The
-        keys a model has no use for stay 0. Plain ints the loop keeps
-        anyway; safe to read from another thread."""
+        ``expert_slots`` (cells in all), summed over decode steps, and,
+        where the grouped matmul is the Pallas kernel,
+        ``expert_weight_visits`` ((row tile, expert) visits a projection
+        made: ``experts_touched`` when each touched expert's weights
+        streamed once). The keys a model has no use for stay 0. Plain
+        ints the loop keeps anyway; safe to read from another thread."""
         return {
             "decode_steps": self._decode_steps,
             "tokens": self._tokens,
@@ -1314,6 +1330,7 @@ class InferenceEngine:
             "expert_tokens": self._expert_tokens,
             "experts_touched": self._experts_touched,
             "expert_slots": self._expert_slots,
+            "expert_weight_visits": self._expert_weight_visits,
         }
 
     @property

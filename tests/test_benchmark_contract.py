@@ -18,6 +18,7 @@ import glob
 import inspect
 import json
 import os
+import subprocess
 import sys
 
 import jax
@@ -226,7 +227,7 @@ _ENGINE_COUNTERS = (
     "decode_steps", "tokens", "slot_steps_active", "admissions", "evictions",
     "kv_blocks_live", "kv_blocks_tabled", "kv_blocks_full",
     "kv_blocks_window", "kv_blocks_uniform", "expert_tokens",
-    "experts_touched", "expert_slots",
+    "experts_touched", "expert_slots", "expert_weight_visits",
 )
 
 
@@ -273,3 +274,41 @@ def test_tiny_serve_cell_engine_surface(world, own_runtime):
     assert stats["decode_steps"] == steps > 0
     assert stats["tokens"] == 4 and stats["admissions"] == 1
     assert slots == spec["engine"]["slots"]
+
+
+# ---------------------------------------------------------------------------
+# A traced run of the serve rehearsal cells: the span readers read
+# ---------------------------------------------------------------------------
+
+# What a traced run's last line carries (under ``cpu_rehearsal.``): the
+# span readers both serve cells share, and the expert layer's. On a CPU
+# the grouped matmul is XLA's ``ragged_dot``, which makes no weight visits
+# to count: ``expert_weight_visits_per_touched`` is then absent, and its
+# reader must say so without raising.
+_TRACED = {
+    "tiny-lm-serve": ("decode_host_ms", "decode_active_slots",
+                      "kv_blocks_read_pct"),
+    "tiny-trinity-serve": ("decode_host_ms", "decode_active_slots",
+                           "kv_blocks_read_pct", "experts_touched_pct"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_TRACED))
+def test_traced_rehearsal_run_exits_0_and_reads_its_spans(name, tmp_path):
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env.update(JAX_PLATFORMS="cpu", HOME=str(tmp_path), TMPDIR=str(tmp_path))
+    done = subprocess.run(
+        [sys.executable, os.path.join(_BENCH, "run.py"), "--workload", name,
+         "--seed", "5", "--seconds", "3", "--trace", "1"],
+        cwd=_REPO, env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert done.returncode == 0, done.stderr[-4000:]
+    result = json.loads(done.stdout.strip().splitlines()[-1])
+    assert result["correct"] is True, result["compared"]
+    assert result["failed"] == 0 and result["attempted"] > 0
+    assert result["device"]["platform"] == "cpu"
+    metrics = result["metrics"]
+    for metric in _TRACED[name]:
+        assert f"cpu_rehearsal.{metric}" in metrics, (metric, sorted(metrics))
+    visits = metrics.get("cpu_rehearsal.expert_weight_visits_per_touched")
+    assert visits is None or visits["value"] >= 1.0

@@ -1,0 +1,349 @@
+"""The routed experts' grouped matmul (``ops/grouped_matmul.py``): the
+Pallas kernel in interpret mode at small aligned shapes against
+``jax.lax.ragged_dot`` in float32, the walk it prefetches, the count of
+its weight visits, and the rule that chooses between the kernel and
+``ragged_dot``. What the chip's compiler says of the kernel at the real
+widths is ``tests/test_tpu_compile.py``'s."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from fluxmpi_tpu.ops import grouped_matmul as gm
+
+# (rows, k, n), tiles (tile_rows, sub_rows, tile_n), group sizes.
+_CASES = {
+    "uniform": ((64, 128, 256), (32, 16, 128), [16, 16, 16, 16]),
+    "first_group_empty": ((64, 128, 256), (32, 16, 128), [0, 40, 8, 16]),
+    "last_group_empty": ((64, 128, 256), (32, 16, 128), [30, 20, 14, 0]),
+    "runs_of_empty_groups": ((64, 128, 128), (32, 16, 128),
+                             [0, 0, 24, 0, 0, 0, 40, 0]),
+    "one_group_holds_every_row": ((64, 128, 256), (32, 16, 256),
+                                  [0, 0, 64, 0]),
+    "tail_comes_out_zero": ((64, 128, 256), (32, 16, 128), [3, 5, 0, 7]),
+    "no_row_at_all": ((64, 128, 256), (32, 16, 128), [0, 0, 0, 0]),
+    "group_straddles_row_tiles": ((96, 128, 128), (32, 16, 128),
+                                  [20, 50, 26]),
+    "group_straddles_sub_tiles": ((64, 128, 128), (64, 16, 128),
+                                  [7, 20, 9, 28]),
+    "three_rows_a_group": ((64, 256, 128), (64, 16, 128),
+                           [3, 2, 4, 3, 0, 3, 5, 1, 3, 3, 4, 2, 3, 3, 2, 3]),
+    "one_row_tile": ((32, 128, 384), (32, 32, 128), [10, 0, 22]),
+}
+
+
+def _operands(shape, sizes, seed=0):
+    rows, k, n = shape
+    kx, kw = jax.random.split(jax.random.PRNGKey(seed))
+    x = jax.random.normal(kx, (rows, k), jnp.bfloat16)
+    w = jax.random.normal(kw, (len(sizes), k, n), jnp.bfloat16)
+    return x, w, jnp.asarray(sizes, jnp.int32)
+
+
+def _reference(x, w, sizes):
+    out = jax.lax.ragged_dot(
+        x.astype(jnp.float32), w.astype(jnp.float32), sizes,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    live = jnp.arange(x.shape[0])[:, None] < jnp.sum(sizes)
+    return jnp.where(live, out, 0.0)
+
+
+@pytest.mark.parametrize("name", sorted(_CASES))
+def test_kernel_matches_ragged_dot(name):
+    shape, tiles, sizes = _CASES[name]
+    x, w, sizes = _operands(shape, sizes)
+    got = gm._gmm(x, w, sizes, tiles=tiles, interpret=True)
+    assert got.dtype == jnp.float32 and got.shape == (shape[0], shape[2])
+    np.testing.assert_allclose(
+        got, _reference(x, w, sizes), rtol=1e-5, atol=1e-4
+    )
+    total = int(np.sum(np.asarray(sizes)))
+    assert not np.any(np.asarray(got[total:]))
+
+
+@pytest.mark.parametrize("name", sorted(_CASES))
+def test_walk_visits_each_touched_group_once_a_row_tile(name):
+    shape, tiles, sizes = _CASES[name]
+    rows, tile_rows, groups = shape[0], tiles[0], len(sizes)
+    tile, weight, lo, hi, fresh = (
+        np.asarray(a) for a in gm._visits(
+            jnp.asarray(sizes, jnp.int32), rows, tile_rows)
+    )
+    assert len(tile) == rows // tile_rows + groups
+    ends = np.cumsum(sizes)
+    # (row tile, group, the tile's rows [lo, hi) that are the group's).
+    want = [(t, g,
+             max(ends[g] - sizes[g] - t * tile_rows, 0),
+             min(ends[g] - t * tile_rows, tile_rows))
+            for g in range(groups) if sizes[g]
+            for t in range((ends[g] - sizes[g]) // tile_rows,
+                           (ends[g] - 1) // tile_rows + 1)]
+    real = hi > lo
+    assert list(zip(tile[real], weight[real], lo[real], hi[real])) == want
+    assert gm.weight_visits(sizes, tile_rows) == len(want)
+    # Every row tile is visited (so it is zeroed, once), in order; the
+    # tail and the steps past the walk multiply nothing and hold the last
+    # real visit's weight block.
+    assert sorted(set(tile.tolist())) == list(range(rows // tile_rows))
+    assert np.all(np.diff(tile) >= 0)
+    assert fresh.tolist() == [1, *(np.diff(tile) != 0).astype(int)]
+    assert np.all(lo[~real] == 0) and np.all(hi[~real] == 0)
+    if want:
+        assert np.all(weight[~real] == want[-1][1])
+        # Real visits come first.
+        assert real[: len(want)].all() and not real[len(want):].any()
+
+
+def test_weight_visits_counts_calls_over_leading_dimensions():
+    by_layer = np.array([[3, 0, 5, 0], [0, 0, 0, 8], [2, 2, 2, 2]])
+    assert gm.weight_visits(by_layer, 8) == 2 + 1 + 4
+    # A group across a tile boundary is fetched once a tile.
+    assert gm.weight_visits(by_layer, 4) == 3 + 2 + 4
+    assert gm.weight_visits(np.zeros((2, 4), np.int32), 8) == 0
+
+
+@pytest.mark.parametrize("rows", [64, 72, 200])
+def test_jitted_kernel_pads_rows_the_row_tile_does_not_divide(
+        rows, monkeypatch):
+    monkeypatch.setattr(gm, "_SUB_ROWS", 16)
+    monkeypatch.setattr(gm, "_TILE_ROWS", 64)
+    sizes = [rows // 4, 0, rows // 2, 3]
+    x, w, sizes = _operands((rows, 128, 128), sizes, seed=rows)
+    fn = gm._jitted.__wrapped__(True)
+    got = fn(x, w, sizes)
+    assert got.shape == (rows, 128)
+    np.testing.assert_allclose(
+        got, _reference(x, w, sizes), rtol=1e-5, atol=1e-4
+    )
+
+
+def test_kernel_under_an_outer_jit_lowers_once_a_shape():
+    shape, tiles, sizes = _CASES["uniform"]
+    x, w, sizes = _operands(shape, sizes)
+    fn = gm._jitted(True)
+    assert fn is gm._jitted(True)
+
+    @jax.jit
+    def three(x, w, sizes):
+        return fn(x, w, sizes) + fn(x, w, sizes) + fn(x, w, sizes)
+
+    text = three.lower(x, w, sizes).as_text()
+    assert text.count('func.func private @"ragged-dot-gmm"(') == 1
+    assert text.count('call @"ragged-dot-gmm"(') == 3
+    assert text.count("func.func private @_visits(") == 1
+    np.testing.assert_allclose(
+        three(x, w, sizes), 3 * _reference(x, w, sizes), rtol=1e-5,
+        atol=3e-4,
+    )
+
+
+# ---------------------------------------------------------------------------
+# The rule: the kernel or ``ragged_dot``, from backend, dtype and shapes
+# ---------------------------------------------------------------------------
+
+_FALLBACKS = {
+    "float32_operands": ((64, 128, 128), jnp.float32),
+    "narrow_k": ((64, 64, 128), jnp.bfloat16),
+    "narrow_n": ((64, 128, 96), jnp.bfloat16),
+    "aligned_bfloat16_on_a_cpu": ((64, 128, 128), jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("name,as_tpu", [
+    (name, as_tpu) for name in sorted(_FALLBACKS) for as_tpu in (False, True)
+    # On a TPU these shapes are the kernel's.
+    if not (as_tpu and name == "aligned_bfloat16_on_a_cpu")
+])
+def test_fallback_is_ragged_dot_bit_for_bit(name, as_tpu, monkeypatch):
+    shape, dtype = _FALLBACKS[name]
+    if as_tpu:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    x, w, sizes = _operands(shape, [10, 0, 30, 16])
+    x, w = x.astype(dtype), w.astype(dtype)
+    assert gm.row_tile(shape[0], shape[1], shape[2], dtype) is None
+    got = gm.grouped_matmul(x, w, sizes)
+    want = jax.lax.ragged_dot(
+        x, w, sizes, preferred_element_type=jnp.float32
+    )
+    assert got.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_rule_takes_the_kernel_on_a_tpu_for_aligned_bfloat16(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # Every slot's pairs in one row tile: a touched expert is read once.
+    assert gm.row_tile(512, 2048, 1024, jnp.bfloat16) == 512
+    assert gm.row_tile(512, 1024, 2048, jnp.bfloat16) == 512
+    assert gm.row_tile(512, 1024, 2048, jnp.bfloat16, jnp.float32) is None
+
+
+@pytest.mark.parametrize("shape,want", [
+    ((512, 2048, 1024), (512, 1024)),
+    ((512, 1024, 2048), (512, 2048)),
+    ((4096, 2048, 1024), (512, 1024)),
+    ((384, 256, 128), (128, 128)),
+    ((256, 8192, 1024), (256, 256)),
+    ((256, 32768, 128), None),
+])
+def test_tile_rule_reads_shapes_only(shape, want):
+    rows, k, n = shape
+    tiles = gm._tile_rule(rows, k, n, 2)
+    if want is None:
+        assert tiles is None
+        return
+    tile_rows, sub_rows, tile_n = tiles
+    assert (tile_rows, tile_n) == want
+    assert rows % tile_rows == 0 and tile_rows % sub_rows == 0
+    assert n % tile_n == 0 and tile_n % 128 == 0
+    assert k * tile_n * 2 <= gm._WEIGHT_BLOCK_BYTES
+
+
+def test_expert_mlp_on_a_cpu_is_ragged_dot_unchanged():
+    """The layer's call site went from ``jax.lax.ragged_dot`` to
+    ``grouped_matmul``: on a CPU the same values bit for bit."""
+    from fluxmpi_tpu.models.decoder import ExpertMLP
+
+    layer = ExpertMLP(num_experts=8, top_k=2, width=32, shared_width=32,
+                      dtype=jnp.float32)
+    u = jax.random.normal(jax.random.PRNGKey(1), (2, 12, 16))
+    params = layer.init(jax.random.PRNGKey(2), u)
+    mask = jnp.arange(12)[None] < jnp.array([[12], [7]])
+    out, state = layer.apply(params, u, mask, mutable=["intermediates"])
+    counts = state["intermediates"]["expert_tokens"][0]
+    assert int(counts.sum()) == (12 + 7) * 2
+
+    p = params["params"]
+    experts, weights = layer.route(u.reshape(-1, 16), p["router"], p["bias"])
+    dense = jnp.zeros((24, 16))
+    for slot in range(2):
+        e = experts[:, slot]
+        h = jax.nn.silu(jnp.einsum("td,tdf->tf", u.reshape(-1, 16),
+                                   p["w1"][e]))
+        h = h * jnp.einsum("td,tdf->tf", u.reshape(-1, 16), p["w3"][e])
+        dense = dense + weights[:, slot, None] * jnp.einsum(
+            "tf,tfd->td", h, p["w2"][e])
+    shared = p["shared"]
+    g = jax.nn.silu(u.reshape(-1, 16) @ shared["w1"]) * (
+        u.reshape(-1, 16) @ shared["w3"])
+    dense = dense * mask.reshape(-1, 1) + g @ shared["w2"]
+    np.testing.assert_allclose(
+        out.reshape(-1, 16), dense, rtol=2e-4, atol=2e-5
+    )
+
+
+# ---------------------------------------------------------------------------
+# The engine's count of weight visits: only where a model has expert
+# layers AND their grouped matmul is the kernel; traced or not
+# ---------------------------------------------------------------------------
+
+
+def _tiny_decoder():
+    from fluxmpi_tpu.models import DecoderConfig, DecoderLM
+
+    config = DecoderConfig.from_hf({
+        "model_type": "afmoe", "vocab_size": 64, "hidden_size": 32,
+        "intermediate_size": 64, "moe_intermediate_size": 16,
+        "num_hidden_layers": 3, "num_dense_layers": 1,
+        "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 8,
+        "num_experts": 8, "num_experts_per_tok": 2, "num_shared_experts": 1,
+        "layer_types": ["sliding_attention", "sliding_attention",
+                        "full_attention"],
+        "sliding_window": 16, "max_position_embeddings": 64,
+        "rms_norm_eps": 1e-5, "rope_theta": 10000.0,
+        "route_norm": True, "route_scale": 1.0, "mup_enabled": False,
+    })
+    model = DecoderLM(config)
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))
+    return model, variables
+
+
+def _serve(model, variables, traced, **engine):
+    from fluxmpi_tpu.serving import InferenceEngine
+    from fluxmpi_tpu.telemetry import tracing
+
+    if traced:
+        tracing.configure(True)
+    try:
+        eng = InferenceEngine(model, variables, slots=2, check_memory=False,
+                              **engine)
+        try:
+            rng = np.random.default_rng(0)
+            reqs = [eng.submit(rng.integers(0, 32, 6).astype(np.int32), 5)
+                    for _ in range(3)]
+            eng.run()
+            assert [r.status for r in reqs] == ["finished"] * 3
+            stats = eng.stats()
+        finally:
+            eng.close()
+        delivered = [
+            args for _, name, _, _, _, args
+            in list(tracing.get_tracer()._events)
+            if name == "serve.decode.deliver"
+        ]
+    finally:
+        if traced:
+            tracing.configure(False)
+            tracing.reset()
+    return stats, delivered
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["untraced", "traced"])
+@pytest.mark.parametrize("tile", [None, 2, 4], ids=["ragged_dot", "tile2",
+                                                   "tile4"])
+def test_engine_counts_weight_visits_where_the_kernel_runs(
+        tile, traced, monkeypatch):
+    from fluxmpi_tpu.models import DecoderLM
+
+    model, variables = _tiny_decoder()
+    assert model.expert_row_tile(2) is None  # a CPU: ragged_dot
+    monkeypatch.setattr(DecoderLM, "expert_row_tile",
+                        lambda self, tokens: tile)
+    stats, delivered = _serve(model, variables, traced, block_size=8,
+                              max_len=32)
+    assert stats["experts_touched"] > 0
+    assert len(delivered) == (stats["decode_steps"] if traced else 0)
+    ratios = [a["expert_weight_visits_per_touched"] for a in delivered
+              if "expert_weight_visits_per_touched" in a]
+    if tile is None:
+        assert stats["expert_weight_visits"] == 0 and not ratios
+        return
+    # 2 slots x top-2 = 4 rows a call: one row tile of 4 holds them all.
+    visits, touched = stats["expert_weight_visits"], stats["experts_touched"]
+    assert visits == touched if tile == 4 else touched <= visits <= 2 * touched
+    if traced:
+        assert len(ratios) == len(delivered)
+        assert all(1.0 <= r <= 2.0 for r in ratios)
+        assert all("experts_touched_pct" in a for a in delivered)
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["untraced", "traced"])
+def test_model_without_expert_layers_never_reaches_the_expert_branch(traced):
+    from fluxmpi_tpu.models import TransformerLM
+
+    lm = TransformerLM(vocab_size=32, max_len=32, num_layers=1, d_model=16,
+                       num_heads=2, d_ff=32)
+    variables = lm.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
+                        train=False)
+    stats, delivered = _serve(lm, variables, traced, block_size=8)
+    assert stats["expert_weight_visits"] == 0 and stats["expert_slots"] == 0
+    assert len(delivered) == (stats["decode_steps"] if traced else 0)
+    for args in delivered:
+        assert not [k for k in args if k.startswith("expert")]
+
+
+def test_decoder_lm_row_tile_follows_the_rule(monkeypatch):
+    import dataclasses
+
+    model, _ = _tiny_decoder()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert model.expert_row_tile(64) is None  # float32, narrow
+    wide = dataclasses.replace(
+        model.config, hidden_size=256, moe_intermediate_size=128)
+    bf16 = type(model)(wide, dtype=jnp.bfloat16)
+    assert bf16.expert_row_tile(64) == 128  # 128 rows: one tile
+    assert bf16.expert_row_tile(256) == 512
+    dense = dataclasses.replace(wide, num_dense_layers=wide.num_layers)
+    assert type(model)(dense, dtype=jnp.bfloat16).expert_row_tile(64) is None

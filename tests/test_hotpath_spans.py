@@ -303,7 +303,8 @@ def test_engine_stats_agree_with_delivery(tiny_lm):
                           "admissions", "evictions", "kv_blocks_live",
                           "kv_blocks_tabled", "kv_blocks_full",
                           "kv_blocks_window", "kv_blocks_uniform",
-                          "expert_tokens", "experts_touched", "expert_slots"}
+                          "expert_tokens", "experts_touched", "expert_slots",
+                          "expert_weight_visits"}
     # 3 requests x 4 decode steps at positions 5..8 of 8-token blocks:
     # one live block each, two at position 8, in each of the 2 layers.
     assert stats["kv_blocks_live"] == 2 * 3 * (1 + 1 + 1 + 2)

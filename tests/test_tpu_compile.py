@@ -7,6 +7,7 @@ Interpret mode, which every other test uses, reaches none of that.
 A compile that passes is not a chip run: ``chip_smoke.py`` is."""
 
 import os
+import re
 
 import numpy as np
 import optax
@@ -190,6 +191,32 @@ def test_paged_decode_kernel_compiles_for_v5e(topo, name):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
+# trinity-mini-serve's expert layer: 128 experts of [2048, 1024] up and
+# [1024, 2048] down; 64 slots x top-8 rows in the decode tick, a prompt
+# bucket's tokens x 8 in a prefill (the shortest, one past the window,
+# the longest).
+@pytest.mark.parametrize("side", ["up", "down"])
+@pytest.mark.parametrize("tokens", [64, 512, 2560, 8704])
+def test_grouped_matmul_kernel_compiles_for_v5e(topo, as_on_tpu, tokens,
+                                                side):
+    from fluxmpi_tpu.ops.grouped_matmul import grouped_matmul, row_tile
+
+    dev = topo.devices[0]
+    rows = tokens * 8
+    k, n = (2048, 1024) if side == "up" else (1024, 2048)
+    assert row_tile(rows, k, n, jnp.bfloat16) == 512
+    compiled = jax.jit(grouped_matmul).lower(
+        _sds((rows, k), jnp.bfloat16, dev),
+        _sds((128, k, n), jnp.bfloat16, dev), _sds((128,), jnp.int32, dev),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(re.findall(r"%ragged-dot-gmm[.\d]* = ", text)) == 1
+    # The weights are read where they lie; no pass over the output
+    # zeroes the rows past the last group.
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**21
+
+
 def test_gpt2_medium_serving_programs_compile_for_v5e(topo, as_on_tpu):
     """The serve cell's decode program and a prefill bucket — GPT-2
     medium, 32 slots x 1,024 positions in 128-token blocks, bf16 pools
@@ -238,7 +265,7 @@ def test_trinity_mini_serving_programs_compile_for_v5e(topo, as_on_tpu):
     bucket at the published widths (32 query over 4 K/V heads of 128, 128
     experts top-8, vocabulary 200,192; 64 slots x 8,704 positions in
     512-token blocks): one paged kernel a layer, grouped and windowed,
-    XLA's grouped matmul (a Mosaic kernel of its own) three times an
+    the grouped matmul's kernel (``ops/grouped_matmul.py``) three times an
     expert layer, the ring and the full pool updated in place, and
     bfloat16 weights of 8.5 GB beside them within one chip."""
     import importlib.util
@@ -291,9 +318,13 @@ def test_trinity_mini_serving_programs_compile_for_v5e(topo, as_on_tpu):
         ).compile()
     finally:
         engine.close()
-    # 5 paged kernels; 4 expert layers x (3 grouped matmuls + their
-    # shared metadata kernel).
-    assert decode.as_text().count("tpu_custom_call") == 5 + 4 * 4
+    # 5 paged kernels; 4 expert layers x 3 grouped matmuls, each named
+    # as the benchmark's readers find XLA's own (``^ragged-dot``).
+    text = decode.as_text()
+    assert text.count("tpu_custom_call") == 5 + 4 * 3
+    assert len(re.findall(r"%ragged-dot-gmm[.\d]* = ", text)) == 4 * 3
+    assert len(re.findall(
+        r"%ragged-dot-gmm[.\d]* = ", prefill.as_text())) == 4 * 3
     for program, temporaries in ((decode, 2**27), (prefill, 2**30)):
         memory = program.memory_analysis()
         assert memory.temp_size_in_bytes < temporaries
