@@ -7,8 +7,9 @@
 5. :class:`TransformerEncoder` — the wrapped-model adapter path.
 
 Beyond the five parity configs: ResNet-18/34/101, :class:`TransformerLM`,
-:class:`DecoderLM` (a decoder block read from a configuration: RMSNorm,
-gated MLP, rotary, grouped K/V heads, window and full layers, and
+:class:`DecoderLM` (a decoder block read from a configuration: RMSNorm
+sandwich or pre-norm, gated MLP, rotary, grouped K/V heads, window, full
+and latent-attention layers, and
 :class:`ExpertMLP`, routed experts without dropped tokens),
 Switch-MoE variants, :class:`ViT` (patch-conv + the same encoder stack;
 composes with the flash/ring/Ulysses ``attention_fn`` hooks), and

@@ -6,18 +6,32 @@ not that block. :class:`DecoderLM` reads its block from a
 :class:`DecoderConfig` (the keys of a Hugging Face ``config.json``, see
 :meth:`DecoderConfig.from_hf`):
 
-- RMSNorm, four a layer in the sandwich placement:
-  ``h = x + N_post_attn(Attn(N_in(x)))``, ``y = h + N_post_ff(FF(N_pre_ff(h)))``;
-- attention with ``num_heads`` query heads over ``num_kv_heads`` K/V
-  heads of ``head_dim`` (independent of ``hidden_size``), RMSNorm over
-  ``head_dim`` on ``q`` and ``k``, a sigmoid output gate, and a kind per
-  layer (``layer_types``): ``"sliding_attention"`` (rotary positions, the
-  mask ``0 <= i - j < sliding_window``) or ``"full_attention"`` (the
-  causal mask, no rotary);
+- RMSNorm, four a layer in the sandwich placement
+  (``norm_placement="sandwich"``: ``h = x + N_post_attn(Attn(N_in(x)))``,
+  ``y = h + N_post_ff(FF(N_pre_ff(h)))``) or two in the pre-norm one
+  (``"pre"``: ``h = x + Attn(N_in(x))``, ``y = h + FF(N_pre_ff(h))``);
+- one of THREE kinds of attention a layer (``layer_types``). Two keep K
+  and V: ``num_heads`` query heads over ``num_kv_heads`` K/V heads of
+  ``head_dim`` (independent of ``hidden_size``), RMSNorm over ``head_dim``
+  on ``q`` and ``k``, a sigmoid output gate (``output_gate``), as
+  ``"sliding_attention"`` (rotary positions, the mask ``0 <= i - j <
+  sliding_window``) or ``"full_attention"`` (the causal mask, no rotary).
+  The third, ``"latent_attention"`` (multi-head latent attention, MLA),
+  keeps ONE row a token: a latent ``c`` of ``kv_lora_rank`` (RMSNormed)
+  and one rotary key ``k_r`` of ``qk_rope_head_dim`` shared by all heads;
+  each head's key is ``[Wkvb_K c; k_r]`` and its value ``Wkvb_V c``
+  (:class:`LatentAttention`: the un-absorbed form over a call's own
+  tokens, the absorbed form against a cache of rows), rotary angles
+  scaled as ``rope_scaling`` says (``deepseek_yarn``);
 - a gated (SwiGLU) MLP in the first ``num_dense_layers`` layers and an
   :class:`ExpertMLP` in the rest;
 - an untied head, and the embedding scaled by ``sqrt(hidden_size)``
   where ``mup_enabled``.
+
+``num_experts`` counts the experts a layer HOLDS; where the router is
+wider (``num_routed_experts``: this chip's share of an expert-parallel
+deployment) the layer routes over all of them and computes its own
+experts' part of the result (:class:`ExpertMLP`, ``expert_range``).
 
 Compute runs in ``dtype`` (bfloat16 in the served configuration) with
 float32 accumulation; the norms, the router, the rotary angles and the
@@ -28,8 +42,12 @@ The serving engine's seam is ``attention_fn`` (as in
 :class:`TransformerLM`): where set, every layer hands it ``(query, key,
 value)`` (``[batch, seq, heads | kv_heads, head_dim]``, after the head
 norms and the rotary) in layer order and takes ``[batch, seq, heads,
-head_dim]`` back; :meth:`DecoderLM.cache_layers` says what each layer
-keeps in a cache. ``pos_offset`` (``[batch]``) places each row at its own
+head_dim]`` back; a latent layer adds ``row=`` (what its cache keeps of
+each token) and, where the function attends a cache
+(``attention_fn.from_cache``), calls ``attention_fn.latent(q_abs, q_rope,
+row)`` with the absorbed queries instead;
+:meth:`DecoderLM.cache_layers` says what each layer keeps in a cache.
+``pos_offset`` (``[batch]``) places each row at its own
 position and ``head_at`` (``[batch]``) takes the head at one position a
 row: a prefill never builds ``[prompt, vocab]`` logits. ``token_mask``
 (``[batch, seq]``) names the real tokens: padding and idle slots are
@@ -39,17 +57,21 @@ routed to no expert.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .transformer import _resolve_attention_mode
 
-__all__ = ["DecoderConfig", "DecoderLM", "ExpertMLP", "causal_attention"]
+__all__ = ["DecoderConfig", "DecoderLM", "ExpertMLP", "LatentAttention",
+           "causal_attention"]
 
 SLIDING, FULL = "sliding_attention", "full_attention"
+LATENT = "latent_attention"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,13 +98,51 @@ class DecoderConfig:
     route_norm: bool = True
     route_scale: float = 1.0
     mup_enabled: bool = False
+    # The block around the attention: four norms or two, the gate or none.
+    norm_placement: str = "sandwich"
+    output_gate: bool = True
+    # The router's width where it is not the experts held (0: the same).
+    num_routed_experts: int = 0
+    # Latent attention: what a token leaves in the cache (kv_lora_rank +
+    # qk_rope_head_dim) and the heads rebuilt from it.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # ``rope_scaling`` of the config.json as sorted (key, value) pairs
+    # (hashable); None: plain rotary angles.
+    rope_scaling: tuple | None = None
 
     def __post_init__(self):
-        unknown = set(self.layer_types) - {SLIDING, FULL}
+        unknown = set(self.layer_types) - {SLIDING, FULL, LATENT}
         if unknown:
             raise ValueError(f"unknown layer types {sorted(unknown)}")
         if SLIDING in self.layer_types and not self.sliding_window:
             raise ValueError("sliding_attention layers need sliding_window")
+        if LATENT in self.layer_types and not (
+            self.kv_lora_rank and self.qk_nope_head_dim
+            and self.qk_rope_head_dim and self.v_head_dim
+        ):
+            raise ValueError(
+                "latent_attention layers need kv_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim"
+            )
+        if self.norm_placement not in ("sandwich", "pre"):
+            raise ValueError(
+                f"unknown norm_placement {self.norm_placement!r}"
+            )
+        scaling = dict(self.rope_scaling or ())
+        if scaling and scaling.get("type") != "deepseek_yarn":
+            raise ValueError(
+                f"unknown rope_scaling type {scaling.get('type')!r}"
+            )
+        if self.num_routed_experts and (
+            self.num_routed_experts < self.num_experts
+        ):
+            raise ValueError(
+                f"a router over {self.num_routed_experts} experts cannot "
+                f"hold {self.num_experts}"
+            )
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError(
                 f"{self.num_attention_heads} query heads are not a multiple "
@@ -95,13 +155,35 @@ class DecoderConfig:
     def num_layers(self) -> int:
         return len(self.layer_types)
 
+    @property
+    def latent_row(self) -> int:
+        """What a token leaves in a latent layer's cache."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
     @classmethod
     def from_hf(cls, cfg: dict) -> "DecoderConfig":
-        """From the keys of a ``config.json`` (``model_type: "afmoe"``);
-        keys this class does not know are left alone."""
+        """From the keys of a ``config.json``, ``model_type`` ``"afmoe"``
+        or ``"sarvam_mla"`` (whose names for the same things are mapped:
+        ``first_k_dense_replace``, ``routed_scaling_factor``; every layer
+        latent attention in a pre-norm block without the output gate;
+        ``head_dim`` there is the cache's row, not a head's width); keys
+        this class does not know are left alone."""
         names = {f.name for f in dataclasses.fields(cls)}
-        known = {k: v for k, v in cfg.items() if k in names}
-        known["layer_types"] = tuple(cfg["layer_types"])
+        known = {k: v for k, v in cfg.items() if k in names and v is not None}
+        if cfg.get("model_type") == "sarvam_mla":
+            heads = cfg["num_attention_heads"]
+            known.update(
+                layer_types=(LATENT,) * cfg["num_hidden_layers"],
+                num_dense_layers=cfg["first_k_dense_replace"],
+                route_scale=cfg["routed_scaling_factor"],
+                num_key_value_heads=heads,
+                head_dim=cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"],
+                norm_placement="pre", output_gate=False,
+            )
+        else:
+            known["layer_types"] = tuple(cfg["layer_types"])
+        if known.get("rope_scaling") is not None:
+            known["rope_scaling"] = tuple(sorted(cfg["rope_scaling"].items()))
         return cls(**known)
 
 
@@ -143,7 +225,8 @@ def _rotary(x, positions, theta: float):
 def causal_attention(q, k, v, *, window: int | None, mode: str):
     """Causal attention of ``q`` ``[batch, seq, heads, head_dim]`` over
     ``k`` / ``v`` with fewer (grouped) heads, within ``window`` keys where
-    given. ``mode`` ``"flash"``: the Pallas kernels (K/V never repeated to
+    given; ``v`` may have a width of its own (the result's). ``mode``
+    ``"flash"``: the Pallas kernels (K/V never repeated to
     the query heads); ``"naive"``: dense scores, float32 softmax."""
     if mode == "flash":
         from ..ops.flash_attention import flash_attention
@@ -163,7 +246,81 @@ def causal_attention(q, k, v, *, window: int | None, mode: str):
     p = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgqt,btkd->bqkgd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, s, h, d).astype(q.dtype)
+    return out.reshape(b, s, h, v.shape[-1]).astype(q.dtype)
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_frequencies(dim: int, theta: float, scaling: dict) -> np.ndarray:
+    """The ``dim // 2`` rotary frequencies: ``theta ** (-2i / dim)``, and
+    under ``deepseek_yarn`` (a non-empty ``scaling``) between that and
+    that over ``factor``, a linear ramp over the pairs from the one whose
+    wavelength makes ``beta_fast`` turns in
+    ``original_max_position_embeddings`` positions to ``beta_slow``
+    turns. float64, computed once while tracing."""
+    plain = theta ** -(np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if not scaling:
+        return plain
+
+    def pair_of(turns):
+        return dim * math.log(
+            scaling["original_max_position_embeddings"]
+            / (turns * 2 * math.pi)
+        ) / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(scaling["beta_fast"])), 0)
+    high = min(math.ceil(pair_of(scaling["beta_slow"])), dim - 1)
+    ramp = np.clip(
+        (np.arange(dim // 2) - low) / max(high - low, 0.001), 0.0, 1.0
+    )
+    return plain / scaling["factor"] * ramp + plain * (1.0 - ramp)
+
+
+def latent_scales(c: "DecoderConfig"):
+    """``(frequencies, cos / sin multiplier, softmax scale)`` of the
+    latent layers: plain rotary angles and ``q_head_dim ** -0.5`` without
+    ``rope_scaling``; with it the ``deepseek_yarn`` frequencies, ``mscale /
+    mscale_all_dim`` on cos and sin, and the scale times the square of
+    ``0.1 * mscale_all_dim * ln(factor) + 1``."""
+    dim = c.qk_rope_head_dim
+    scale = (c.qk_nope_head_dim + dim) ** -0.5
+    scaling = dict(c.rope_scaling or ())
+    trig = 1.0
+    if scaling:
+        factor = scaling["factor"]
+        all_dim = scaling.get("mscale_all_dim", 0)
+        if all_dim:
+            scale *= _yarn_mscale(factor, all_dim) ** 2
+        trig = _yarn_mscale(factor, scaling.get("mscale", 1)) / _yarn_mscale(
+            factor, all_dim)
+    return yarn_frequencies(dim, c.rope_theta, scaling), trig, scale
+
+
+def _rotary_pairs(x, positions, freq, trig_scale: float):
+    """Rotary positions on ``x`` ``[batch, seq, heads, dim]`` whose
+    CONSECUTIVE lanes pair up (``(x0, x1), (x2, x3), ...``: the
+    interleaved layout of the latent models' weights), pair ``i`` rotated
+    by ``position * freq[i]``; the lanes stay where they were. float32.
+    A pair's partner comes by a signed permutation matrix on the lanes
+    (exact in any dtype: one product of a value with 1 or -1 a lane):
+    halves of ``dim // 2`` lanes, or a lane rotation's slices, would each
+    be padded to a whole 128-lane tile (at 16,384 tokens and 64 heads of
+    64 rotary dims, 0.5 GB apiece)."""
+    dim = x.shape[-1]
+    angle = positions.astype(jnp.float32)[..., None, None] * jnp.repeat(
+        jnp.asarray(freq, jnp.float32), 2)
+    cos, sin = jnp.cos(angle) * trig_scale, jnp.sin(angle) * trig_scale
+    swap = np.zeros((dim, dim), np.float32)
+    first = np.arange(0, dim, 2)
+    swap[first + 1, first] = -1.0  # partner[2i] = -x[2i + 1]
+    swap[first, first + 1] = 1.0  # partner[2i + 1] = x[2i]
+    partner = jnp.einsum(
+        "...d,de->...e", x, jnp.asarray(swap, x.dtype),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=x.dtype,
+    )
+    return x.astype(jnp.float32) * cos + partner.astype(jnp.float32) * sin
 
 
 class Attention(nn.Module):
@@ -183,7 +340,8 @@ class Attention(nn.Module):
         wq = self.param("wq", init, (d, heads * hd))
         wk = self.param("wk", init, (d, kvh * hd))
         wv = self.param("wv", init, (d, kvh * hd))
-        wg = self.param("wg", init, (d, heads * hd))
+        wg = (self.param("wg", init, (d, heads * hd))
+              if c.output_gate else None)
         wo = self.param("wo", init, (heads * hd, d))
         q_scale = self.param("q_norm", nn.initializers.ones, (hd,))
         k_scale = self.param("k_norm", nn.initializers.ones, (hd,))
@@ -206,9 +364,118 @@ class Attention(nn.Module):
                 q, k, v, window=window,
                 mode=_resolve_attention_mode(self.attention),
             )
-        gate = jax.nn.sigmoid(_dot(u, wg, self.dtype))
-        out = out.reshape(b, s, heads * hd).astype(jnp.float32) * gate
+        out = out.reshape(b, s, heads * hd)
+        if wg is not None:
+            out = out.astype(jnp.float32) * jax.nn.sigmoid(
+                _dot(u, wg, self.dtype))
         return _dot(out, wo, self.dtype).astype(self.dtype)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention. Of ``u`` at position ``t``: ``q = u
+    Wq`` as heads of ``[q_nope; q_rope]``; ``[c; k_r] = u Wkva``, ``c``
+    RMSNormed, ``q_rope`` and ``k_r`` (ONE rotary key for all heads)
+    rotated to ``t``. ``row = [c; k_r]`` is all a cache keeps of a token.
+
+    Un-absorbed (a call over its own tokens: the plain forward, a
+    prefill): ``[k_nope_h; v_h] = c Wkvb``, ``k_h = [k_nope_h; k_r]``,
+    causal softmax attention of scale ``s`` (:func:`latent_scales`),
+    ``out = [o_1 .. o_H] Wo``. Absorbed (against a cache of rows,
+    ``attention_fn.from_cache``): ``q~_h = Wkvb[K, h]^T q_nope_h``, scores
+    ``s * (q~_h . c + q_rope_h . k_r)``, ``o~_h = sum p c``, ``o_h = Wkvb[V,
+    h] o~_h``: the same numbers, each cached row read once as key and as
+    value. Both forms rebuild from the row AS STORED (``dtype``)."""
+
+    config: DecoderConfig
+    dtype: Any
+    attention: str = "naive"
+    attention_fn: Callable | None = None
+
+    @nn.compact
+    def __call__(self, u, positions):
+        c = self.config
+        heads, rank = c.num_attention_heads, c.kv_lora_rank
+        nope, rope, vd = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
+        init = nn.initializers.normal(0.02)
+        b, s, d = u.shape
+        wq = self.param("wq", init, (d, heads * (nope + rope)))
+        wkva = self.param("wkva", init, (d, rank + rope))
+        kv_scale = self.param("kv_norm", nn.initializers.ones, (rank,))
+        wkvb = self.param("wkvb", init, (rank, heads * (nope + vd)))
+        wo = self.param("wo", init, (heads * vd, d))
+        freq, trig, scale = latent_scales(c)
+        # The attention divides by sqrt(q_head_dim) itself; what the
+        # rotary scaling adds to the scale rides on the queries, applied
+        # before their one rounding to ``dtype`` (a float32 q of a
+        # 16,384-token prompt is 1 GB).
+        extra = scale * (nope + rope) ** 0.5
+        q = (_dot(u, wq, self.dtype) * extra).astype(self.dtype).reshape(
+            b, s, heads, nope + rope)
+        kva = _dot(u, wkva, self.dtype)
+        latent = _rms_norm(kva[..., :rank], kv_scale, c.rms_norm_eps)
+        with jax.named_scope("rope"):
+            q_rope = _rotary_pairs(q[..., nope:], positions, freq, trig)
+            k_rope = _rotary_pairs(
+                kva[..., None, rank:], positions, freq, trig)[..., 0, :]
+        q_nope = q[..., :nope]
+        row = jnp.concatenate([latent, k_rope], axis=-1).astype(self.dtype)
+        w = wkvb.astype(self.dtype).reshape(rank, heads, nope + vd)
+        fn = self.attention_fn
+        if fn is not None and getattr(fn, "from_cache", False):
+            rest = (nope + rope) ** -0.5  # the scale's other part
+            with jax.named_scope("latent_absorb"):
+                q_abs = jnp.einsum(
+                    "bshn,chn->bshc", q_nope, w[..., :nope],
+                    preferred_element_type=jnp.float32,
+                )
+            ctx = fn.latent((q_abs * rest).astype(self.dtype),
+                            (q_rope * rest).astype(self.dtype), row)
+            with jax.named_scope("latent_expand"):
+                out = jnp.einsum(
+                    "bshc,chv->bshv", ctx.astype(self.dtype), w[..., nope:],
+                    preferred_element_type=jnp.float32,
+                )
+        else:
+            def expand(part):
+                return jnp.einsum(
+                    "bsc,chn->bshn", row[..., :rank], part,
+                    preferred_element_type=jnp.float32,
+                ).astype(self.dtype)
+
+            k = jnp.concatenate([
+                expand(w[..., :nope]),
+                jnp.broadcast_to(row[:, :, None, rank:], (b, s, heads, rope)),
+            ], axis=-1)
+            v = expand(w[..., nope:])
+            qf = jnp.concatenate(
+                [q_nope, q_rope.astype(self.dtype)], axis=-1)
+            if fn is not None:
+                out = fn(qf, k, v, row=row)
+            else:
+                out = causal_attention(
+                    qf, k, v, window=None,
+                    mode=_resolve_attention_mode(self.attention),
+                )
+        out = out.reshape(b, s, heads * vd)
+        return _dot(out, wo, self.dtype).astype(self.dtype)
+
+
+# The float32 result a feed-forward may hold for all its tokens at once:
+# over every cell served before the latent model (the widest: 8,704
+# tokens x 8 pairs x 2,048 = 570 MB), under a 16,384-token prompt's
+# 16,384-wide hidden layer (1.07 GB) and pairs (2.1 GB), which do not fit
+# beside that model's weights and cache.
+_SLAB_BYTES = 768 * 2**20
+
+
+def _slabs(tokens: int, width: int) -> int:
+    """In how many equal slabs ``tokens`` pass a per-token layer whose
+    float32 intermediate is ``width`` a token: the fewest that keep it
+    under ``_SLAB_BYTES`` and divide ``tokens`` (1: all at once)."""
+    n = -(-tokens * width * 4 // _SLAB_BYTES)
+    while tokens % n:
+        n += 1
+    return n
 
 
 class GatedMLP(nn.Module):
@@ -224,8 +491,16 @@ class GatedMLP(nn.Module):
         w1 = self.param("w1", init, (d, self.width))
         w3 = self.param("w3", init, (d, self.width))
         w2 = self.param("w2", init, (self.width, d))
-        h = jax.nn.silu(_dot(u, w1, self.dtype)) * _dot(u, w3, self.dtype)
-        return _dot(h, w2, self.dtype).astype(self.dtype)
+
+        def mlp(u):
+            h = jax.nn.silu(_dot(u, w1, self.dtype)) * _dot(u, w3, self.dtype)
+            return _dot(h, w2, self.dtype).astype(self.dtype)
+
+        tokens = math.prod(u.shape[:-1])
+        slabs = _slabs(tokens, self.width)
+        if slabs == 1:
+            return mlp(u)
+        return jax.lax.map(mlp, u.reshape(slabs, -1, d)).reshape(u.shape)
 
 
 class ExpertMLP(nn.Module):
@@ -243,10 +518,14 @@ class ExpertMLP(nn.Module):
     the layer runs without its exchange). The shared expert, which every
     token passes, is added where ``include_shared``.
 
-    Returns the layer's output and sows ``expert_tokens`` (``[num_experts]``
-    int32: the pairs each expert received) into ``intermediates``.
+    Returns the layer's output and sows ``expert_tokens`` (``[held]``
+    int32: the pairs each HELD expert received; what went to experts held
+    elsewhere is not this layer's work) into ``intermediates``.
     Tokens that ``token_mask`` leaves out (padding, idle slots) are
     routed nowhere: they count for no expert and reach no grouped matmul.
+    A call over so many tokens that the float32 result of the (token,
+    expert) pairs would pass ``_SLAB_BYTES`` takes them in equal slabs
+    (:func:`_slabs`).
     """
 
     num_experts: int
@@ -279,7 +558,7 @@ class ExpertMLP(nn.Module):
         init = nn.initializers.normal(0.02)
         shape, d = u.shape, u.shape[-1]
         u = u.reshape(-1, d)
-        tokens, n, k = u.shape[0], self.num_experts, self.top_k
+        n, k = self.num_experts, self.top_k
         lo, hi = self.expert_range or (0, n)
         held = hi - lo
         router = self.param("router", init, (d, n))
@@ -287,38 +566,57 @@ class ExpertMLP(nn.Module):
         w1 = self.param("w1", init, (held, d, self.width))
         w3 = self.param("w3", init, (held, d, self.width))
         w2 = self.param("w2", init, (held, self.width, d))
-        with jax.named_scope("moe_route"):
-            experts, weights = self.route(u, router, bias)
-            flat = experts.reshape(-1)
-            rank = (flat - lo) % n
-            if token_mask is not None:
-                keep = jnp.repeat(token_mask.reshape(-1), k)
-                flat = jnp.where(keep, flat, n)  # counted nowhere
-                rank = jnp.where(keep, rank, n)  # and sorted last
-            counts = jnp.zeros((n,), jnp.int32).at[flat].add(1, mode="drop")
-            self.sow("intermediates", "expert_tokens", counts)
-            # Held experts first, in order; the pairs of the others after.
-            order = jnp.argsort(rank, stable=True)
-            back = jnp.zeros_like(order).at[order].set(
-                jnp.arange(order.shape[0], dtype=order.dtype)
-            )
-            sizes = counts[lo:hi]
-        with jax.named_scope("moe_experts"):
-            rows = u.astype(self.dtype)[order // k]
 
-            # Imported here: ``fluxmpi_tpu.ops`` brings Pallas with it, which
-            # a model without expert layers may never need.
-            from ..ops.grouped_matmul import grouped_matmul
-
-            def grouped(x, w):
-                # Rows past the held experts' pairs come out zero.
-                return grouped_matmul(
-                    x.astype(self.dtype), w.astype(self.dtype), sizes
+        def routed(u, token_mask):
+            """The held experts' part for the tokens ``u`` ``[tokens,
+            d]``, and the pairs each held expert received."""
+            tokens = u.shape[0]
+            with jax.named_scope("moe_route"):
+                experts, weights = self.route(u, router, bias)
+                flat = experts.reshape(-1)
+                rank = (flat - lo) % n
+                if token_mask is not None:
+                    keep = jnp.repeat(token_mask.reshape(-1), k)
+                    flat = jnp.where(keep, flat, n)  # counted nowhere
+                    rank = jnp.where(keep, rank, n)  # and sorted last
+                counts = jnp.zeros((n,), jnp.int32).at[flat].add(
+                    1, mode="drop")
+                # Held experts first, in order; the others' pairs after.
+                order = jnp.argsort(rank, stable=True)
+                back = jnp.zeros_like(order).at[order].set(
+                    jnp.arange(order.shape[0], dtype=order.dtype)
                 )
+                sizes = counts[lo:hi]
+            with jax.named_scope("moe_experts"):
+                rows = u.astype(self.dtype)[order // k]
 
-            h = jax.nn.silu(grouped(rows, w1)) * grouped(rows, w3)
-            y = grouped(h, w2)[back].reshape(tokens, k, d)
-            out = jnp.sum(y * weights[..., None], axis=1)
+                # Imported here: ``fluxmpi_tpu.ops`` brings Pallas with it,
+                # which a model without expert layers may never need.
+                from ..ops.grouped_matmul import grouped_matmul
+
+                def grouped(x, w):
+                    # Rows past the held experts' pairs come out zero.
+                    return grouped_matmul(
+                        x.astype(self.dtype), w.astype(self.dtype), sizes
+                    )
+
+                h = jax.nn.silu(grouped(rows, w1)) * grouped(rows, w3)
+                y = grouped(h, w2)[back].reshape(tokens, k, d)
+                return jnp.sum(y * weights[..., None], axis=1), sizes
+
+        slabs = _slabs(u.shape[0], k * d)
+        if slabs == 1:
+            out, sizes = routed(u, token_mask)
+        else:
+            # A long prompt's tokens in equal slabs: routing is per token.
+            mask = (jnp.ones(u.shape[:1], bool) if token_mask is None
+                    else token_mask.reshape(-1))
+            out, sizes = jax.lax.map(
+                lambda slab: routed(*slab),
+                (u.reshape(slabs, -1, d), mask.reshape(slabs, -1)),
+            )
+            out, sizes = out.reshape(-1, d), jnp.sum(sizes, axis=0)
+        self.sow("intermediates", "expert_tokens", sizes)
         if self.shared_width and self.include_shared:
             with jax.named_scope("moe_shared"):
                 out = out + GatedMLP(
@@ -342,24 +640,36 @@ class DecoderLayer(nn.Module):
         def norm(name):
             return RMSNorm(c.rms_norm_eps, self.dtype, name=name)
 
-        attn = Attention(
-            c, c.layer_types[self.index], self.dtype, self.attention,
-            self.attention_fn, name="attn",
-        )
-        h = x + norm("norm_post_attn")(attn(norm("norm_in")(x), positions))
+        sandwich = c.norm_placement == "sandwich"
+        kind = c.layer_types[self.index]
+        if kind == LATENT:
+            attn = LatentAttention(
+                c, self.dtype, self.attention, self.attention_fn, name="attn"
+            )
+        else:
+            attn = Attention(
+                c, kind, self.dtype, self.attention, self.attention_fn,
+                name="attn",
+            )
+        a = attn(norm("norm_in")(x), positions)
+        h = x + (norm("norm_post_attn")(a) if sandwich else a)
         u = norm("norm_pre_ff")(h)
         if self.index < c.num_dense_layers:
             y = GatedMLP(c.intermediate_size, self.dtype, name="mlp")(u)
         else:
+            # A router wider than the experts held: this chip's share.
+            routed = c.num_routed_experts or c.num_experts
+            held = self.expert_range or (
+                (0, c.num_experts) if routed > c.num_experts else None)
             ff = ExpertMLP(
-                num_experts=c.num_experts, top_k=c.num_experts_per_tok,
+                num_experts=routed, top_k=c.num_experts_per_tok,
                 width=c.moe_intermediate_size,
                 shared_width=c.num_shared_experts * c.moe_intermediate_size,
                 route_norm=c.route_norm, route_scale=c.route_scale,
-                expert_range=self.expert_range, dtype=self.dtype, name="moe",
+                expert_range=held, dtype=self.dtype, name="moe",
             )
             y = ff(u, token_mask)
-        return h + norm("norm_post_ff")(y)
+        return h + (norm("norm_post_ff")(y) if sandwich else y)
 
 
 class DecoderLM(nn.Module):
@@ -389,12 +699,14 @@ class DecoderLM(nn.Module):
     def num_layers(self) -> int:
         return self.config.num_layers
 
-    def cache_layers(self) -> tuple[tuple[int, int, int | None], ...]:
+    def cache_layers(self) -> tuple[tuple[int | None, int, int | None], ...]:
         """What each layer keeps of a sequence: ``(kv_heads, head_dim,
         window)``, ``window`` None where a layer attends its whole
-        context."""
+        context; a latent layer ``(None, row, None)``: no K/V heads, ONE
+        row of ``kv_lora_rank + qk_rope_head_dim`` a token and no V."""
         c = self.config
         return tuple(
+            (None, c.latent_row, None) if kind == LATENT else
             (c.num_key_value_heads, c.head_dim,
              c.sliding_window if kind == SLIDING else None)
             for kind in c.layer_types

@@ -764,14 +764,17 @@ def _fwd_pallas(q, k, v, qseg, kseg, seed, causal, window, tiles, interpret,
     b, sq, h, d = q.shape
     sk = k.shape[1]
     h_kv = k.shape[2]
+    # The values' width may differ from the keys' (latent attention: keys
+    # of 192, values of 128); the kernel takes its shapes from its blocks.
+    dv = v.shape[3]
     kv_row = _kv_row(h, h_kv)
     num_k_blocks = sk // block_k
     has_segments = qseg is not None
 
-    # V and the output travel transposed ([.., d, s]: _flash_kernel); the
+    # V and the output travel transposed ([.., dv, s]: _flash_kernel); the
     # transposes are the head folds', with another permutation.
     qr, kr = _fold_q(q), _fold_heads(k)
-    vtr = v.transpose(0, 2, 3, 1).reshape(b * h_kv, d, sk)
+    vtr = v.transpose(0, 2, 3, 1).reshape(b * h_kv, dv, sk)
 
     kernel = functools.partial(
         _flash_kernel,
@@ -793,7 +796,7 @@ def _fwd_pallas(q, k, v, qseg, kseg, seed, causal, window, tiles, interpret,
         pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
         pl.BlockSpec((1, block_k, d),
                      lambda bh, qi, kj: (kv_row(bh), live(qi, kj), 0)),
-        pl.BlockSpec((1, d, block_k),
+        pl.BlockSpec((1, dv, block_k),
                      lambda bh, qi, kj: (kv_row(bh), 0, live(qi, kj))),
     ]
     operands = [qr, kr, vtr]
@@ -817,19 +820,19 @@ def _fwd_pallas(q, k, v, qseg, kseg, seed, causal, window, tiles, interpret,
         grid=(b * h, sq // block_q, num_k_blocks),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, d, block_q), lambda bh, qi, kj: (bh, 0, qi)),
+            pl.BlockSpec((1, dv, block_q), lambda bh, qi, kj: (bh, 0, qi)),
             pl.BlockSpec(
                 (1, _SUBLANES, block_q), lambda bh, qi, kj: (bh, 0, qi)
             ),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, d, sq), q.dtype),
+            jax.ShapeDtypeStruct((b * h, dv, sq), q.dtype),
             jax.ShapeDtypeStruct((b * h, _SUBLANES, sq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((_SUBLANES, block_q), jnp.float32),
             pltpu.VMEM((_SUBLANES, block_q), jnp.float32),
-            pltpu.VMEM((d, block_q), jnp.float32),
+            pltpu.VMEM((dv, block_q), jnp.float32),
         ],
         compiler_params=pallas_tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -837,7 +840,7 @@ def _fwd_pallas(q, k, v, qseg, kseg, seed, causal, window, tiles, interpret,
         interpret=interpret,
     )(*operands)
 
-    out = out_t.reshape(b, h, d, sq).transpose(0, 3, 1, 2)
+    out = out_t.reshape(b, h, dv, sq).transpose(0, 3, 1, 2)
     return out, lse[:, 0, :].reshape(b, h, sq)
 
 
@@ -1077,6 +1080,12 @@ def _flash_bwd(causal, window, tiles, interpret, dropout_rate, res,
                cotangents):
     q, k, v, qseg, kseg, seed, out, lse = res
     do, dlse = cotangents
+    if v.shape[-1] != q.shape[-1]:
+        raise NotImplementedError(
+            f"the backward kernels take one head_dim; values of "
+            f"{v.shape[-1]} beside keys of {q.shape[-1]} are served, not "
+            f"trained"
+        )
     dq, dk, dv = _bwd_pallas(
         q, k, v, qseg, kseg, seed, out, lse, do, dlse, causal, window,
         tiles, interpret, dropout_rate
@@ -1245,6 +1254,11 @@ def _prepare(q, k, v, block_q, block_k, interpret):
     b, sq, h, d = q.shape
     sk = k.shape[1]
     h_kv = k.shape[2]
+    if k.shape[3] != d:
+        raise ValueError(
+            f"q and k head_dim differ: {d} vs {k.shape[3]} (v may have a "
+            f"width of its own)"
+        )
     if v.shape[2] != h_kv:
         raise ValueError(
             f"k and v head counts differ: {h_kv} vs {v.shape[2]}"
@@ -1299,7 +1313,8 @@ def flash_attention(
     Tiles stream through VMEM with online-softmax accumulation; the
     ``[seq, seq]`` score matrix never exists in HBM. Sequence length must
     divide the block sizes (pad upstream). f32 accumulation, output in the
-    input dtype. Fully differentiable (Pallas backward kernels).
+    input dtype. Fully differentiable (Pallas backward kernels). ``v`` may
+    have a head width of its own (the output's; forward only).
 
     ``segment_ids``: optional int32 ``[batch, seq]`` array (or a
     ``(q_seg, kv_seg)`` pair for cross-attention) — position pairs attend

@@ -46,6 +46,25 @@ head_dim]``, head ``h``'s query in its K/V head's lanes, zeros
 elsewhere), so ``q_bd @ k.T`` is ``[heads, block_size]`` scores with no
 per-head slicing, and of ``p @ v`` (``[heads, kv_heads * head_dim]``)
 each head keeps its K/V head's lanes.
+
+**A cache of latent rows** (:func:`paged_latent_decode_attention`, plain
+reference :func:`paged_latent_decode_reference`): a latent-attention
+layer keeps ONE row a token, ``[c; k_r]`` (a compressed latent of
+``rank`` lanes and one rotary key shared by all heads), in ONE pool
+``[layers, num_blocks, block_size, width]``, ``width >= rank + rope``
+(the cache pads a row to whole 128-lane tiles with zeros: the chip's
+compiler COPIES a pool whose rows are not, 7.1 GB of temporaries at 1,089
+blocks of 1,024 rows of 576; the queries are padded with zeros to match,
+so the lanes past ``rank + rope`` never count). Its decode step brings
+the ABSORBED queries: ``q_abs`` ``[slots, heads, rank]`` (each head's
+query carried through its key projection, so it meets ``c`` directly) and
+``q_rope`` ``[slots, heads, rope]``, both already carrying the softmax
+scale. The score of head ``h`` against position ``p`` is ``q_abs[h] .
+c[p] + q_rope[h] . k_r[p]`` and the result ``sum_p softmax(score)[p]
+c[p]`` (``[slots, heads, rank]``: the caller carries it through the value
+projection): every live block is fetched ONCE for all heads and used as
+key (all its lanes) and as value (the first ``rank``). Tables, lengths,
+the trash block, idle slots and the tail mask are as above; no window.
 """
 
 from __future__ import annotations
@@ -59,7 +78,14 @@ from jax.experimental import pallas as pl
 from ..parallel._compat import pallas_tpu_compiler_params
 from .flash_attention import _LANES, _NEG_INF, _SUBLANES
 
-__all__ = ["paged_decode_attention", "paged_decode_reference"]
+__all__ = ["paged_decode_attention", "paged_decode_reference",
+           "paged_latent_decode_attention", "paged_latent_decode_reference"]
+
+# The chip's compiler names a Mosaic call's instruction by the last
+# component of its path: the latent kernel's jitted wrapper and its
+# ``pallas_call`` both carry this name, and the benchmark's readers find
+# the kernel by it.
+_LATENT_KERNEL_NAME = "paged_latent_decode"
 
 
 def _paged_decode_kernel(
@@ -307,3 +333,205 @@ def paged_decode_reference(
         v.astype(jnp.float32),
     )
     return out.reshape(slots, heads, head_dim).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# A cache of latent rows
+# ---------------------------------------------------------------------------
+
+
+def _paged_latent_kernel(
+    tables_ref, lengths_ref, qa_ref, qr_ref, kv_ref, o_ref,
+    m_scratch, l_scratch, acc_scratch,
+    *, rank: int, block_size: int, num_j: int,
+):
+    del tables_ref  # read by the index map
+    slot = pl.program_id(0)
+    j = pl.program_id(1)
+    length = lengths_ref[slot]
+    base = j * block_size
+
+    @pl.when(j == 0)
+    def _init():
+        m_scratch[...] = jnp.full_like(m_scratch, _NEG_INF)
+        l_scratch[...] = jnp.zeros_like(l_scratch)
+        acc_scratch[...] = jnp.zeros_like(acc_scratch)
+
+    @pl.when(base < length)
+    def _compute():
+        c = kv_ref[:, :rank]  # [block_size, rank]: key and value
+        k_rope = kv_ref[:, rank:]
+        contract_last = (((1,), (1,)), ((), ()))
+        s = jax.lax.dot_general(
+            qa_ref[0], c, contract_last, preferred_element_type=jnp.float32,
+        ) + jax.lax.dot_general(
+            qr_ref[0], k_rope, contract_last,
+            preferred_element_type=jnp.float32,
+        )  # [rows, block_size]
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        live = pos < length
+        s = jnp.where(live, s, _NEG_INF)
+
+        m_prev = m_scratch[...]  # [rows, 128], value replicated over lanes
+        l_prev = l_scratch[...]
+        m_cur = jnp.broadcast_to(
+            jnp.max(s, axis=1, keepdims=True), m_prev.shape
+        )
+        m_new = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(live, jnp.exp(s - m_new[:, :1]), 0.0)
+        l_scratch[...] = l_prev * alpha + jnp.broadcast_to(
+            jnp.sum(p, axis=1, keepdims=True), l_prev.shape
+        )
+        # p is narrowed to the pool's dtype only as an operand of its own
+        # product; statistics and the accumulator stay float32.
+        pv = jax.lax.dot_general(
+            p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [rows, rank]
+        acc_scratch[...] = acc_scratch[...] * alpha[:, :1] + pv
+        m_scratch[...] = m_new
+
+    @pl.when(j == num_j - 1)
+    def _finish():
+        l_final = l_scratch[...][:, :1]
+        l_safe = jnp.where(l_final == 0.0, 1.0, l_final)
+        o_ref[0] = (acc_scratch[...] / l_safe).astype(o_ref.dtype)
+
+
+def _check_latent_shapes(q_abs, q_rope, pool, tables, lengths, layer):
+    slots, heads, rank = q_abs.shape
+    if q_rope.shape[:2] != (slots, heads) or pool.ndim != 4 or (
+        pool.shape[3] < rank + q_rope.shape[2]
+    ):
+        raise ValueError(
+            f"q_abs [slots, heads, rank], q_rope [slots, heads, rope] and a "
+            f"pool [layers, num_blocks, block_size, >= rank + rope]; got "
+            f"{q_abs.shape}, {q_rope.shape} and {pool.shape}"
+        )
+    tables_ok = tables.ndim == 2 and tables.shape[0] == slots
+    if not tables_ok or lengths.shape != (slots,):
+        raise ValueError(
+            f"tables must be [slots, max_blocks] and lengths [slots] for "
+            f"{slots} slots; got {tables.shape} and {lengths.shape}"
+        )
+    if not 0 <= layer < pool.shape[0]:
+        raise ValueError(
+            f"layer {layer} outside the pool's {pool.shape[0]} layers"
+        )
+
+
+@functools.lru_cache(maxsize=None)
+def _latent_jitted(layer: int, interpret: bool):
+    from jax.experimental.pallas import tpu as pltpu
+
+    def paged_latent_decode(q_abs, q_rope, pool, tables, lengths):
+        slots, heads, rank = q_abs.shape
+        block_size, width = pool.shape[2:]
+        rope = width - rank  # the pool's lanes past the latent
+        num_j = tables.shape[1]
+        rows = -(-heads // _SUBLANES) * _SUBLANES
+        pad = (0, 0), (0, rows - heads)
+
+        def kv_index(slot, j, tables_ref, lengths_ref):
+            # Past the live blocks the index stays on the last live one:
+            # an unchanged block index is not fetched again.
+            last = jnp.maximum(
+                (lengths_ref[slot] + block_size - 1) // block_size - 1, 0
+            )
+            return layer, tables_ref[slot, jnp.minimum(j, last)], 0, 0
+
+        def row_index(slot, j, tables_ref, lengths_ref):
+            return slot, 0, 0
+
+        out = pl.pallas_call(
+            functools.partial(
+                _paged_latent_kernel, rank=rank, block_size=block_size,
+                num_j=num_j,
+            ),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(slots, num_j),
+                in_specs=[
+                    pl.BlockSpec((1, rows, rank), row_index),
+                    pl.BlockSpec((1, rows, rope), row_index),
+                    pl.BlockSpec((None, None, block_size, width), kv_index),
+                ],
+                out_specs=pl.BlockSpec((1, rows, rank), row_index),
+                scratch_shapes=[
+                    pltpu.VMEM((rows, _LANES), jnp.float32),
+                    pltpu.VMEM((rows, _LANES), jnp.float32),
+                    pltpu.VMEM((rows, rank), jnp.float32),
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((slots, rows, rank), q_abs.dtype),
+            compiler_params=pallas_tpu_compiler_params(
+                dimension_semantics=("parallel", "arbitrary"),
+            ),
+            interpret=interpret,
+            name=_LATENT_KERNEL_NAME,
+        )(
+            tables.astype(jnp.int32), lengths.astype(jnp.int32),
+            jnp.pad(q_abs, (*pad, (0, 0))).astype(pool.dtype),
+            jnp.pad(
+                q_rope, (*pad, (0, rope - q_rope.shape[2]))
+            ).astype(pool.dtype),
+            pool,
+        )
+        return out[:, :heads]
+
+    return jax.jit(paged_latent_decode)
+
+
+def paged_latent_decode_attention(
+    q_abs: jnp.ndarray,
+    q_rope: jnp.ndarray,
+    pool: jnp.ndarray,
+    tables: jnp.ndarray,
+    lengths: jnp.ndarray,
+    *,
+    layer: int = 0,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """The absorbed queries of one token per slot against its paged cache
+    of latent rows; see the module docstring for the contract. Returns
+    ``[slots, heads, rank]`` in ``q_abs``'s dtype. ``interpret=None`` runs
+    the compiled kernel on a TPU backend and Pallas interpret mode
+    elsewhere. Tiles come from the shapes alone: one block of the pool a
+    grid step."""
+    _check_latent_shapes(q_abs, q_rope, pool, tables, lengths, layer)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _latent_jitted(int(layer), bool(interpret))(
+        q_abs, q_rope, pool, tables, lengths
+    )
+
+
+def paged_latent_decode_reference(
+    q_abs: jnp.ndarray,
+    q_rope: jnp.ndarray,
+    pool: jnp.ndarray,
+    tables: jnp.ndarray,
+    lengths: jnp.ndarray,
+    *,
+    layer: int = 0,
+) -> jnp.ndarray:
+    """The same contract in plain ``jax.numpy``: gather one layer's
+    tabled blocks, dense scores, a position mask. The kernel's reference
+    in the tests, and the engine's ``attention="naive"`` route."""
+    _check_latent_shapes(q_abs, q_rope, pool, tables, lengths, layer)
+    slots, _, rank = q_abs.shape
+    width = rank + q_rope.shape[2]
+    rows = pool[layer][tables].reshape(slots, -1, pool.shape[3])[..., :width]
+    q = jnp.concatenate([q_abs, q_rope], axis=-1).astype(rows.dtype)
+    s = jnp.einsum("shc,stc->sht", q, rows,
+                   preferred_element_type=jnp.float32)
+    live = (jnp.arange(rows.shape[1])[None, :] < lengths[:, None])[:, None, :]
+    s = jnp.where(live, s, _NEG_INF)
+    p = jnp.where(live, jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    out = jnp.einsum(
+        "sht,stc->shc", p / jnp.where(l == 0.0, 1.0, l),
+        rows[..., :rank].astype(jnp.float32),
+    )
+    return out.astype(q_abs.dtype)

@@ -29,15 +29,28 @@ positions are cut into fixed-size **blocks**,
   (:func:`fluxmpi_tpu.ops.paged_attention.paged_decode_attention`, see
   :mod:`fluxmpi_tpu.serving.engine`).
 
+**Three kinds of layer** (:attr:`BlockKVCache.kinds`: full, window,
+latent), each with its pool(s), its free list and its tables.
+
 **Layers that attend a window keep a ring.** A model whose layers are
 not all alike (``layer_windows``: some attend their whole context, some
 a sliding window) gets one pool, one free list and one table a **kind**
-of layer (:attr:`BlockKVCache.kinds`): a full layer's table spans
+of layer: a full layer's table spans
 ``max_len``, a window layer's is a ring of ``ceil((window + block_size) /
 block_size)`` blocks in which position ``p`` lives at entry ``(p //
 block_size) % ring``, so a long sequence costs a window layer no more
 than its ring. A model without window layers has the one kind, and
 everything below reads as it did.
+
+**Layers that keep a latent keep one row and no V.** A latent-attention
+layer (``layer_latent``) caches ONE row a token (a compressed latent and
+a shared rotary key, ``num_heads * head_dim`` wide as the caller counts
+it) that its decode kernel reads once as key and as value
+(:func:`fluxmpi_tpu.ops.paged_attention.paged_latent_decode_attention`).
+Such layers are a third kind, with a free list, a table spanning
+``max_len`` and ONE pool (:attr:`BlockKVCache.k_pools`; its entry of
+:attr:`BlockKVCache.v_pools` is None), counted at one row a token,
+padded to whole 128-lane tiles, in :attr:`BlockKVCache.pool_bytes`.
 
 **Block 0 is the trash block**: it is never allocated. Unused table
 entries point at it, masked prefill positions and idle batch slots
@@ -63,6 +76,7 @@ from typing import Any, Sequence
 __all__ = ["BlockKVCache", "blocks_for_tokens"]
 
 TRASH_BLOCK = 0
+_LANES = 128
 
 
 def blocks_for_tokens(tokens: int, block_size: int) -> int:
@@ -75,13 +89,14 @@ class _Kind:
     and their pools: ``window`` None for layers that keep the whole
     context, else the positions a window layer attends. ``entries`` is
     the width of a sequence's table row for these layers, ``layer_ids``
-    the model's layers that are of this kind, in order."""
+    the model's layers that are of this kind, in order. ``latent``: the
+    layers keep one row a token in ``k_pool`` and no ``v_pool``."""
 
     __slots__ = ("layer_ids", "window", "entries", "num_blocks", "free",
-                 "k_pool", "v_pool")
+                 "k_pool", "v_pool", "latent")
 
     def __init__(self, layer_ids: tuple[int, ...], window: int | None,
-                 entries: int, num_blocks: int):
+                 entries: int, num_blocks: int, latent: bool = False):
         if num_blocks < 2:
             raise ValueError(
                 f"num_blocks must be >= 2 (block 0 is the reserved trash "
@@ -91,6 +106,7 @@ class _Kind:
         self.window = window
         self.entries = entries
         self.num_blocks = num_blocks
+        self.latent = latent
         # LIFO free list: the most recently freed block is handed out
         # next — the round-trip the reuse test pins down.
         self.free: list[int] = list(range(num_blocks - 1, 0, -1))
@@ -125,6 +141,11 @@ class BlockKVCache:
         wide and a long sequence costs it no more than that. One window
         size a model. The window kind's pool holds the rings of as many
         sequences as ``num_blocks`` holds at ``max_blocks_per_seq``.
+      layer_latent: per layer, whether it keeps ONE row of ``num_heads *
+        head_dim`` a token (a latent read as key and as value) in place
+        of K and V (default: no layer). Such layers attend their whole
+        context and are a kind of their own, after the other two, with
+        one pool.
 
     :meth:`alloc`, :meth:`free`, :meth:`table_row` and :meth:`blocks_for`
     take the ``kind`` they speak of (default 0: the only kind of a model
@@ -147,6 +168,7 @@ class BlockKVCache:
         max_blocks_per_seq: int,
         dtype: Any = None,
         layer_windows: Sequence[int | None] | None = None,
+        layer_latent: Sequence[bool] | None = None,
     ):
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
@@ -167,11 +189,21 @@ class BlockKVCache:
                 f"layer_windows names {len(windows)} layers, not "
                 f"{self.num_layers}"
             )
+        latent = tuple(bool(x) for x in
+                       layer_latent or (False,) * self.num_layers)
+        if len(latent) != self.num_layers:
+            raise ValueError(
+                f"layer_latent names {len(latent)} layers, not "
+                f"{self.num_layers}"
+            )
+        if any(w is not None and x for w, x in zip(windows, latent)):
+            raise ValueError("a latent layer attends its whole context")
         sizes = sorted({w for w in windows if w is not None})
         if len(sizes) > 1:
             raise ValueError(f"one window size a model, got {sizes}")
         self.kinds: list[_Kind] = []
-        full = tuple(i for i, w in enumerate(windows) if w is None)
+        full = tuple(i for i, w in enumerate(windows)
+                     if w is None and not latent[i])
         if full:
             self.kinds.append(_Kind(
                 full, None, self.max_blocks_per_seq, self.num_blocks
@@ -185,6 +217,11 @@ class BlockKVCache:
             self.kinds.append(_Kind(
                 tuple(i for i, w in enumerate(windows) if w is not None),
                 int(sizes[0]), entries, 1 + sequences * entries,
+            ))
+        if any(latent):
+            self.kinds.append(_Kind(
+                tuple(i for i, x in enumerate(latent) if x), None,
+                self.max_blocks_per_seq, self.num_blocks, latent=True,
             ))
         # Per layer: (its kind, its index among that kind's layers).
         where = {
@@ -313,13 +350,16 @@ class BlockKVCache:
 
     @property
     def pool_shapes(self) -> list[tuple[int, ...]]:
-        """Per kind, ``[layers, blocks, block_size, heads * head_dim]`` —
-        the one statement of the pools' layout (the prefill's and the
-        decode's ``kv_write``, the decode kernel and :attr:`pool_bytes`
-        follow)."""
+        """Per kind, ``[layers, blocks, block_size, heads * head_dim]``
+        (a latent kind: its row, padded with zeros to whole 128-lane tiles,
+        which is what the row costs on the chip whoever pads it: the
+        decode kernel reads the pool in place only so) — the one
+        statement of the pools' layout (the prefill's and the decode's
+        ``kv_write``, the decode kernel and :attr:`pool_bytes` follow)."""
+        width = self.num_heads * self.head_dim
         return [
             (k.layers, k.num_blocks, self.block_size,
-             self.num_heads * self.head_dim)
+             -(-width // _LANES) * _LANES if k.latent else width)
             for k in self.kinds
         ]
 
@@ -330,15 +370,17 @@ class BlockKVCache:
 
     @property
     def pool_bytes(self) -> int:
-        """Byte footprint of ALL pools (K and V, every kind)."""
+        """Byte footprint of ALL pools (K and V of every kind; a latent
+        kind's one pool)."""
         import numpy as np
 
         import jax.numpy as jnp
 
         dtype = self._dtype if self._dtype is not None else jnp.float32
         itemsize = np.dtype(dtype).itemsize
-        return 2 * itemsize * sum(
-            int(np.prod(shape)) for shape in self.pool_shapes
+        return itemsize * sum(
+            (1 if kind.latent else 2) * int(np.prod(shape))
+            for kind, shape in zip(self.kinds, self.pool_shapes)
         )
 
     def _ensure_pools(self) -> None:
@@ -348,12 +390,12 @@ class BlockKVCache:
             dtype = self._dtype if self._dtype is not None else jnp.float32
             for kind, shape in zip(self.kinds, self.pool_shapes):
                 kind.k_pool = jnp.zeros(shape, dtype)
-                kind.v_pool = jnp.zeros(shape, dtype)
+                kind.v_pool = None if kind.latent else jnp.zeros(shape, dtype)
 
     @property
     def k_pools(self) -> tuple:
-        """The K pools, one a kind: what the engine's steps take (and
-        donate) and hand back."""
+        """The K pools (a latent kind's rows), one a kind: what the
+        engine's steps take (and donate) and hand back."""
         self._ensure_pools()
         return tuple(k.k_pool for k in self.kinds)
 
@@ -364,6 +406,8 @@ class BlockKVCache:
 
     @property
     def v_pools(self) -> tuple:
+        """The V pools, one a kind; None for a latent kind, which has
+        none (an empty leaf wherever the tuple travels)."""
         self._ensure_pools()
         return tuple(k.v_pool for k in self.kinds)
 

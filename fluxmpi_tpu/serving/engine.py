@@ -36,6 +36,17 @@ Phase split:
   batch with zero retrace** (the compile monitor asserts this in the
   tests and the benchmark's ``compiles_in_window.serve``).
 
+A model whose layers keep a **latent** (``cache_layers()`` says ``(None,
+row, None)``: multi-head latent attention) is served by the same two
+programs over the cache's latent kind, one pool of one row a token: the
+prefill runs the layer's un-absorbed form over the prompt (keys and
+values rebuilt from the rows, causal flash attention) and writes only the
+rows; the decode step writes the new token's row and hands the layer's
+ABSORBED queries to
+:func:`fluxmpi_tpu.ops.paged_attention.paged_latent_decode_attention`,
+which reads each live block once as key and as value. The layer type
+decides; no option does.
+
 The decode loop is **host-driven** (``lax.scan``-free): one dispatch +
 one small device→host token transfer per iteration, with eviction,
 admission, streaming delivery, and preemption polling between
@@ -409,10 +420,11 @@ class _Slot:
         return sum(len(b) for b in self.blocks)
 
 
-def _cache_layers(model) -> tuple[tuple[int, int, int | None], ...]:
+def _cache_layers(model) -> tuple[tuple[int | None, int, int | None], ...]:
     """Per layer ``(kv_heads, head_dim, window)``: what the model says it
     keeps of a sequence (``cache_layers()``, the protocol of
-    :class:`~fluxmpi_tpu.models.DecoderLM`), else
+    :class:`~fluxmpi_tpu.models.DecoderLM`; ``kv_heads`` None: a latent
+    layer, ONE row of ``head_dim`` a token and no V), else
     :class:`~fluxmpi_tpu.models.TransformerLM`'s ``num_heads`` heads of
     ``d_model // num_heads`` over the whole context."""
     layers = getattr(model, "cache_layers", None)
@@ -447,8 +459,14 @@ class _PagedDecodeAttention:
     attends through the block tables. Idle slots carry all-trash tables:
     their rows land in the trash block and their length is 0. Layers of a
     kind (:attr:`BlockKVCache.kinds`) share a pool and a table; a window
-    kind's table is a ring. The step reads the updated pools back from
-    :attr:`k_pools` / :attr:`v_pools`."""
+    kind's table is a ring. A latent layer calls :meth:`latent` instead:
+    its one row a token goes into its kind's one pool, and the absorbed
+    queries attend the rows through the same tables. The step reads the
+    updated pools back from :attr:`k_pools` / :attr:`v_pools`."""
+
+    # What a layer asks before it chooses its form: this function attends
+    # a cache, not the call's own tokens.
+    from_cache = True
 
     def __init__(self, cache: BlockKVCache, k_pools, v_pools, tables,
                  positions, kernel: bool):
@@ -506,12 +524,48 @@ class _PagedDecodeAttention:
             )
         return out[:, None]
 
+    def latent(self, q_abs, q_rope, row):
+        """A latent layer's call: ``row`` ``[slots, 1, width]`` into the
+        pool (padded with zeros to the pool's lanes), then the absorbed
+        queries (``[slots, 1, heads, rank | rope]``) against the slot's
+        rows; ``[slots, 1, heads, rank]`` back."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops.paged_attention import (
+            paged_latent_decode_attention,
+            paged_latent_decode_reference,
+        )
+
+        kind, layer = self.layer_kind[self.layer]
+        self.layer += 1
+        pool = self.k_pools[kind]
+        with jax.named_scope("kv_write"):
+            row = jnp.pad(
+                row[:, 0], ((0, 0), (0, pool.shape[3] - row.shape[2]))
+            )
+            pool = self.k_pools[kind] = pool.at[
+                (layer, self.blocks[kind], self.offset)
+            ].set(row.astype(pool.dtype))
+        attend = (
+            paged_latent_decode_attention if self.kernel
+            else paged_latent_decode_reference
+        )
+        with jax.named_scope("decode_attention"):
+            out = attend(
+                q_abs[:, 0], q_rope[:, 0], pool, self.tables[kind],
+                self.lengths, layer=layer,
+            )
+        return out[:, None]
+
 
 class _PrefillAttention:
     """The prefill program's ``attention_fn`` for a model that speaks the
     ``cache_layers()`` protocol: causal attention over the padded prompt
     (within the layer's window; the flash kernels with ``kernel``), and
-    each layer's keys and values kept for the pool's ``kv_write``."""
+    each layer's keys and values kept for the pool's ``kv_write``; of a
+    latent layer, which rebuilt ``key`` and ``value`` from its ``row``,
+    the row alone."""
 
     def __init__(self, windows, kernel: bool):
         self.windows = windows
@@ -519,14 +573,18 @@ class _PrefillAttention:
         self.keys: list = []
         self.values: list = []
 
-    def __call__(self, query, key, value):
+    def __call__(self, query, key, value, row=None):
         import jax
 
         from ..models.decoder import causal_attention
 
         window = self.windows[len(self.keys)]
-        self.keys.append(key)
-        self.values.append(value)
+        if row is None:
+            self.keys.append(key)
+            self.values.append(value)
+        else:
+            self.keys.append(row[:, :, None])  # one "head" of the row
+            self.values.append(None)
         with jax.named_scope("prefill_attention"):
             return causal_attention(
                 query, key, value, window=window,
@@ -671,13 +729,14 @@ class InferenceEngine:
         self._protocol = hasattr(model, "cache_layers")
         layers = _cache_layers(model)
         if len({(heads, dim) for heads, dim, _ in layers}) != 1:
+            shapes = sorted({(h, d) for h, d, _ in layers}, key=str)
             raise ValueError(
-                f"every layer must cache K/V heads of one shape; got "
-                f"{sorted({(h, d) for h, d, _ in layers})}"
+                f"every layer must cache K/V heads (or a latent row) of "
+                f"one shape; got {shapes}"
             )
         self.cache = BlockKVCache(
             num_layers=len(layers),
-            num_heads=layers[0][0],
+            num_heads=layers[0][0] or 1,
             head_dim=layers[0][1],
             num_blocks=nb,
             block_size=self.block_size,
@@ -685,6 +744,7 @@ class InferenceEngine:
             # The attention sublayer computes K and V in the model's dtype.
             dtype=model.dtype,
             layer_windows=[window for _, _, window in layers],
+            layer_latent=[heads is None for heads, _, _ in layers],
         )
         if check_memory:
             fits, detail = self.cache.fits_device()
@@ -723,6 +783,9 @@ class InferenceEngine:
         # blocks the slots' tables span, both summed over decode ticks.
         self._kv_blocks_live = 0
         self._kv_blocks_tabled = 0
+        # Positions the decode attention read: every active slot's length,
+        # summed over decode ticks.
+        self._context_tokens = 0
         # Layer-blocks the active slots hold (reserved at admission), by
         # kind of layer, beside what one pool of one shape would hold for
         # the same slots; summed over decode ticks.
@@ -735,7 +798,12 @@ class InferenceEngine:
         self._expert_tokens = 0
         self._experts_touched = 0
         self._expert_slots = 0
-        self._expert_layers = 0
+        # How many expert layers sowed counts: known once the decode step
+        # is traced, which writes it HERE and not on the engine (a step
+        # that closed over the engine kept it, and with it the weights,
+        # alive past ``del`` until a garbage collection: 5.3 GB that the
+        # benchmark's float32 reference then lacked, PERF.md, PR 35).
+        self._expert_layers = [0]
         self._expert_weight_visits = 0
         # Registry-counter delta baselines (see _resolve_run).
         self._counted_steps = 0
@@ -780,6 +848,7 @@ class InferenceEngine:
         cache = self.cache
         kernel = _resolve_attention_mode(model.attention) == "flash"
         protocol = self._protocol
+        expert_layers = self._expert_layers
 
         def step(params, k_pools, v_pools, tables, positions, tokens):
             # k_pools / v_pools / tables: one entry a kind of layer
@@ -812,7 +881,7 @@ class InferenceEngine:
                 # The tick's tokens, then every expert layer's count of
                 # the pairs each expert received: one fetch. (How many
                 # layers is known once the step is traced.)
-                self._expert_layers = len(counts)
+                expert_layers[0] = len(counts)
                 nxt = jnp.concatenate([nxt, *counts])
             return nxt, tuple(attend.k_pools), tuple(attend.v_pools)
 
@@ -852,6 +921,11 @@ class InferenceEngine:
                 keep &= entry > (length - 1) // bs - ring
                 entry = entry % ring
             blk = jnp.where(keep, table[entry], jnp.int32(TRASH_BLOCK))
+            if rows.shape[2] != pool.shape[3]:
+                # A latent row, padded to the pool's whole lane tiles.
+                rows = jnp.pad(
+                    rows, ((0, 0), (0, 0), (0, pool.shape[3] - rows.shape[2]))
+                )
             # One row per (layer, position), indexed on every leading
             # dimension: a window over the layers makes XLA move the
             # whole pool into a layers-minor layout and back.
@@ -874,7 +948,11 @@ class InferenceEngine:
                     head_at=(length - 1)[None],
                     token_mask=(jnp.arange(tokens.shape[0]) < length)[None],
                 )[0]
-                k, v = jnp.stack(attend.keys), jnp.stack(attend.values)
+                # A latent layer keeps rows and no values (every layer is
+                # of one shape: __init__).
+                k = jnp.stack(attend.keys)
+                v = (None if attend.values[0] is None
+                     else jnp.stack(attend.values))
             else:
                 with attention_scope("prefill_attention"):
                     k, v, logits = prefill_kv(
@@ -885,7 +963,8 @@ class InferenceEngine:
             with jax.named_scope("kv_write"):
                 # [layers, bucket, heads * head_dim]: the pool's row.
                 k = k[:, 0].reshape(k.shape[0], k.shape[2], -1)
-                v = v[:, 0].reshape(v.shape[0], v.shape[2], -1)
+                if v is not None:
+                    v = v[:, 0].reshape(v.shape[0], v.shape[2], -1)
                 k_pools, v_pools = list(k_pools), list(v_pools)
                 for i, kind in enumerate(cache.kinds):
                     # Every layer of the only kind, or this kind's.
@@ -893,8 +972,9 @@ class InferenceEngine:
                             else np.asarray(kind.layer_ids))
                     k_pools[i] = write(k_pools[i], k[mine], tables[i],
                                        length, kind.window)
-                    v_pools[i] = write(v_pools[i], v[mine], tables[i],
-                                       length, kind.window)
+                    if v is not None:
+                        v_pools[i] = write(v_pools[i], v[mine], tables[i],
+                                           length, kind.window)
             first = jnp.argmax(last, axis=-1).astype(jnp.int32)
             return first, tuple(k_pools), tuple(v_pools)
 
@@ -1133,6 +1213,7 @@ class InferenceEngine:
             positions = np.zeros((self.slots,), np.int32)
             tokens = np.zeros((self.slots,), np.int32)
             live = 0  # layer-blocks the decode kernel reads this tick
+            context = 0  # positions it reads: the live slots' lengths
             # With window layers: layer-blocks the slots hold, by kind,
             # and would hold in one pool of one shape.
             windowed = len(kinds) > 1
@@ -1144,6 +1225,7 @@ class InferenceEngine:
                 positions[i] = slot.position
                 tokens[i] = slot.last_token
                 reach = slot.position + 1
+                context += reach
                 for at, kind in enumerate(kinds):
                     tables[at][i] = slot.tables[at]
                     if windowed:
@@ -1161,7 +1243,9 @@ class InferenceEngine:
             tabled = self.slots * sum(k.layers * k.entries for k in kinds)
             self._kv_blocks_live += live
             self._kv_blocks_tabled += tabled
-            prep.set_metadata(live_blocks_pct=100.0 * live / tabled)
+            self._context_tokens += context
+            prep.set_metadata(live_blocks_pct=100.0 * live / tabled,
+                              context_tokens=context)
             if windowed:
                 self._kv_blocks_full += held[0]
                 self._kv_blocks_window += held[1]
@@ -1211,7 +1295,7 @@ class InferenceEngine:
                 self._expert_slots += expert_tokens.size
                 # The busiest expert's pairs over the mean, layer by layer.
                 by_layer = expert_tokens.reshape(
-                    -1, expert_tokens.size // self._expert_layers
+                    -1, expert_tokens.size // self._expert_layers[0]
                 )
                 delivery.set_metadata(
                     experts_touched_pct=100.0 * touched / expert_tokens.size,
@@ -1303,14 +1387,18 @@ class InferenceEngine:
         max_blocks_per_seq`` a step: what the slots' tables span; the
         ratio is the share of the reserved cache a tick touches; both
         count a block once a layer that has it, and a window layer only
-        the blocks that meet its window). A model with window layers
+        the blocks that meet its window); ``context_tokens`` (the
+        positions the decode attention read: every active slot's length,
+        summed over decode steps). A model with window layers
         also counts the layer-blocks its slots HOLD, summed over decode
         steps: ``kv_blocks_full`` and ``kv_blocks_window`` by kind of
         layer, beside ``kv_blocks_uniform``, what one pool of one shape
         would hold for the same slots. A model with expert layers counts
-        ``expert_tokens`` ((token, expert) pairs routed), ``experts_touched``
-        ((layer, expert) cells that received at least one) and
-        ``expert_slots`` (cells in all), summed over decode steps, and,
+        ``expert_tokens`` ((token, expert) pairs routed to an expert the
+        model HOLDS), ``experts_touched`` ((layer, held expert) cells that
+        received at least one) and ``expert_slots`` (such cells in all),
+        summed over decode steps (experts a wider router scores and other
+        chips hold are in none of the three), and,
         where the grouped matmul is the Pallas kernel,
         ``expert_weight_visits`` ((row tile, expert) visits a projection
         made: ``experts_touched`` when each touched expert's weights
@@ -1324,6 +1412,7 @@ class InferenceEngine:
             "evictions": self._evictions,
             "kv_blocks_live": self._kv_blocks_live,
             "kv_blocks_tabled": self._kv_blocks_tabled,
+            "context_tokens": self._context_tokens,
             "kv_blocks_full": self._kv_blocks_full,
             "kv_blocks_window": self._kv_blocks_window,
             "kv_blocks_uniform": self._kv_blocks_uniform,

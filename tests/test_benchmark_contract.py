@@ -225,7 +225,7 @@ def test_tiny_train_cell_summary_and_record_keys(world, own_runtime):
 # driver reads.
 _ENGINE_COUNTERS = (
     "decode_steps", "tokens", "slot_steps_active", "admissions", "evictions",
-    "kv_blocks_live", "kv_blocks_tabled", "kv_blocks_full",
+    "kv_blocks_live", "kv_blocks_tabled", "context_tokens", "kv_blocks_full",
     "kv_blocks_window", "kv_blocks_uniform", "expert_tokens",
     "experts_touched", "expert_slots", "expert_weight_visits",
 )
@@ -290,7 +290,30 @@ _TRACED = {
                       "kv_blocks_read_pct"),
     "tiny-trinity-serve": ("decode_host_ms", "decode_active_slots",
                            "kv_blocks_read_pct", "experts_touched_pct"),
+    # Latent attention: the new span argument, the held experts' counters.
+    "tiny-sarvam-serve": ("decode_host_ms", "decode_active_slots",
+                          "kv_blocks_read_pct", "experts_touched_pct",
+                          "expert_load_max_over_mean",
+                          "decode_context_tokens"),
 }
+
+
+def test_untraced_sarvam_rehearsal_reports_its_end_to_end_metrics(tmp_path):
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env.update(JAX_PLATFORMS="cpu", HOME=str(tmp_path), TMPDIR=str(tmp_path))
+    done = subprocess.run(
+        [sys.executable, os.path.join(_BENCH, "run.py"), "--workload",
+         "tiny-sarvam-serve", "--seed", "2147483777", "--seconds", "3",
+         "--trace", "0"],
+        cwd=_REPO, env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert done.returncode == 0, done.stderr[-4000:]
+    result = json.loads(done.stdout.strip().splitlines()[-1])
+    assert result["correct"] is True, result["compared"]
+    assert result["failed"] == 0 and result["attempted"] > 0
+    assert set(result["metrics"]) == {
+        "cpu_rehearsal.serve_tokens_per_s", "cpu_rehearsal.itl_p95_ms",
+        "cpu_rehearsal.setup_s"}
 
 
 @pytest.mark.parametrize("name", sorted(_TRACED))

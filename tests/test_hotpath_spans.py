@@ -301,7 +301,8 @@ def test_engine_stats_agree_with_delivery(tiny_lm):
     assert 0 < stats["slot_steps_active"] <= 2 * stats["decode_steps"]
     assert set(stats) == {"decode_steps", "tokens", "slot_steps_active",
                           "admissions", "evictions", "kv_blocks_live",
-                          "kv_blocks_tabled", "kv_blocks_full",
+                          "kv_blocks_tabled", "context_tokens",
+                          "kv_blocks_full",
                           "kv_blocks_window", "kv_blocks_uniform",
                           "expert_tokens", "experts_touched", "expert_slots",
                           "expert_weight_visits"}

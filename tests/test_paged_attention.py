@@ -183,3 +183,118 @@ def test_paged_decode_attention_rejects_a_ring_too_short_for_its_window():
     with pytest.raises(ValueError, match="cannot hold a window"):
         paged_decode_attention(q, k_pool, v_pool, tables, lengths,
                                window=RING * BLOCK)
+
+
+# ---------------------------------------------------------------------------
+# A cache of latent rows: one pool, each row key and value
+# ---------------------------------------------------------------------------
+
+RANK, ROPE = 32, 16
+
+
+def _latent_case(length, width, dtype, seed):
+    """As :func:`_case`, over ONE pool of latent rows ``[c; k_r]`` padded
+    with zeros to ``width`` lanes (what the cache writes), garbage
+    everywhere no table and no length reaches."""
+    from fluxmpi_tpu.ops.paged_attention import (  # noqa: F401
+        paged_latent_decode_attention,
+    )
+
+    rng = np.random.default_rng(seed)
+    lengths = np.array([length, 0, 5, 2 * BLOCK, 3 * BLOCK + 1], np.int32)
+    num_blocks = 1 + len(lengths) * MAX_BLOCKS + 3
+    pool = np.full((LAYERS, num_blocks, BLOCK, width), GARBAGE, np.float32)
+    pool[..., RANK + ROPE:] = 0.0
+    order = iter(rng.permutation(np.arange(1, num_blocks)))
+    tables = np.full((len(lengths), MAX_BLOCKS), TRASH_BLOCK, np.int32)
+    for slot, n in enumerate(lengths):
+        for j in range(-(-int(n) // BLOCK)):
+            block = tables[slot, j] = next(order)
+            rows = min(BLOCK, int(n) - j * BLOCK)
+            pool[:, block, :rows, :RANK + ROPE] = rng.normal(
+                size=(LAYERS, rows, RANK + ROPE)
+            )
+    q_abs = 0.3 * rng.normal(size=(len(lengths), HEADS, RANK))
+    q_rope = 0.3 * rng.normal(size=(len(lengths), HEADS, ROPE))
+    return (jnp.asarray(q_abs, dtype), jnp.asarray(q_rope, dtype),
+            jnp.asarray(pool, dtype), jnp.asarray(tables),
+            jnp.asarray(lengths))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("width", [RANK + ROPE, 128],
+                         ids=["exact", "lane_padded"])
+@pytest.mark.parametrize("length", sorted(LENGTHS), ids=sorted(LENGTHS))
+def test_paged_latent_decode_matches_reference(length, width, dtype):
+    from fluxmpi_tpu.ops.paged_attention import (
+        paged_latent_decode_attention,
+        paged_latent_decode_reference,
+    )
+
+    args = _latent_case(LENGTHS[length], width, dtype, seed=len(length))
+    layer = 1
+    got = paged_latent_decode_attention(*args, layer=layer).astype(jnp.float32)
+    want = paged_latent_decode_reference(*args, layer=layer).astype(
+        jnp.float32)
+    assert got.shape == (5, HEADS, RANK)
+    # No garbage got through: outputs are averages of unit normals.
+    assert float(jnp.max(jnp.abs(got))) < 10.0
+    # bf16: the kernel narrows p to the pool's dtype for its product with
+    # the values (8 bits), the reference keeps float32 there.
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    lengths = np.asarray(args[4])
+    np.testing.assert_array_equal(np.asarray(got)[lengths == 0], 0.0)
+    # An independent dense softmax over slot 0's own rows of THIS layer:
+    # the row is the key (all of it) and the value (its first RANK lanes).
+    n = int(lengths[0])
+    if n:
+        q_abs, q_rope, pool = (np.asarray(a, np.float32) for a in args[:3])
+        tables = np.asarray(args[3])
+        rows = np.concatenate(
+            [pool[layer, tables[0, j]] for j in range(MAX_BLOCKS)]
+        )[:n, :RANK + ROPE]
+        q = np.concatenate([q_abs[0], q_rope[0]], axis=-1)  # [heads, 48]
+        s = q @ rows.T
+        p = np.exp(s - s.max(axis=-1, keepdims=True))
+        dense = (p / p.sum(axis=-1, keepdims=True)) @ rows[:, :RANK]
+        np.testing.assert_allclose(np.asarray(got)[0], dense, rtol=tol,
+                                   atol=tol)
+
+
+def test_paged_latent_decode_one_block_tables_and_many_heads():
+    """A table of ONE block (the grid's second axis has one step) and a
+    head count that is not a multiple of 8 sublanes."""
+    from fluxmpi_tpu.ops.paged_attention import (
+        paged_latent_decode_attention,
+        paged_latent_decode_reference,
+    )
+
+    rng = np.random.default_rng(3)
+    pool = jnp.asarray(rng.normal(size=(1, 4, BLOCK, RANK + ROPE)),
+                       jnp.float32)
+    q_abs = jnp.asarray(0.3 * rng.normal(size=(3, 11, RANK)), jnp.float32)
+    q_rope = jnp.asarray(0.3 * rng.normal(size=(3, 11, ROPE)), jnp.float32)
+    tables = jnp.asarray([[2], [TRASH_BLOCK], [3]], jnp.int32)
+    lengths = jnp.asarray([BLOCK, 0, 3], jnp.int32)
+    got = paged_latent_decode_attention(q_abs, q_rope, pool, tables, lengths)
+    want = paged_latent_decode_reference(q_abs, q_rope, pool, tables, lengths)
+    assert got.shape == (3, 11, RANK)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+def test_paged_latent_decode_rejects_mismatched_shapes():
+    from fluxmpi_tpu.ops.paged_attention import paged_latent_decode_attention
+
+    q_abs, q_rope, pool, tables, lengths = _latent_case(
+        5, RANK + ROPE, jnp.float32, seed=0)
+    with pytest.raises(ValueError, match="rank \\+ rope"):
+        paged_latent_decode_attention(q_abs, q_rope, pool[..., :RANK],
+                                      tables, lengths)
+    with pytest.raises(ValueError, match="tables must be"):
+        paged_latent_decode_attention(q_abs, q_rope, pool, tables[:3],
+                                      lengths)
+    with pytest.raises(ValueError, match="outside the pool"):
+        paged_latent_decode_attention(q_abs, q_rope, pool, tables, lengths,
+                                      layer=LAYERS)

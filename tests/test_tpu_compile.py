@@ -333,6 +333,134 @@ def test_trinity_mini_serving_programs_compile_for_v5e(topo, as_on_tpu):
                 < 14e9)
 
 
+def _load_config_module(name):
+    import importlib.util
+
+    configs = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "configs")
+    spec = importlib.util.spec_from_file_location(
+        name.replace(".", "_"), os.path.join(configs, name))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module, configs
+
+
+# sarvam-105b-serve's kernels alone: the absorbed decode against the
+# cell's pool of latent rows (576 padded to 640 lanes), and the
+# un-absorbed prefill's flash forward with keys of 192 and values of 128
+# (one block, some, the longest prompt).
+def test_paged_latent_decode_kernel_compiles_for_v5e(topo):
+    from fluxmpi_tpu.ops.paged_attention import paged_latent_decode_attention
+
+    dev = topo.devices[0]
+    pool = _sds((5, 817, 1024, 640), jnp.bfloat16, dev)
+
+    def attend(q_abs, q_rope, pool, tables, lengths):
+        return paged_latent_decode_attention(
+            q_abs, q_rope, pool, tables, lengths, layer=4, interpret=False)
+
+    compiled = jax.jit(attend).lower(
+        _sds((48, 64, 512), jnp.bfloat16, dev),
+        _sds((48, 64, 64), jnp.bfloat16, dev), pool,
+        _sds((48, 17), jnp.int32, dev), _sds((48,), jnp.int32, dev),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    # One findable name, the jitted wrapper's and the kernel's.
+    assert len(re.findall(r"%paged_latent_decode[.\d]* = ", text)) == 1
+    # The pool is read where it lies: nothing pool-sized is made (a pool
+    # of 576-lane rows is copied whole: 7.1 GB at 1,089 blocks).
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize("s", [1024, 5120, 16384])
+def test_flash_latent_prefill_compiles_for_v5e(topo, s):
+    dev = topo.devices[0]
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, interpret=False, causal=True)
+
+    compiled = jax.jit(attend).lower(
+        _sds((1, s, 64, 192), jnp.bfloat16, dev),
+        _sds((1, s, 64, 192), jnp.bfloat16, dev),
+        _sds((1, s, 64, 128), jnp.bfloat16, dev),
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.output_shardings is not None
+
+
+def test_sarvam_105b_serving_programs_compile_for_v5e(topo, as_on_tpu):
+    """The ``sarvam-105b-serve`` cell's decode program and its LONGEST
+    prefill at the published widths (64 heads, latent 512 + rotary 64,
+    16 of 128 experts of [4096, 2048] held, 32,768 rows of the
+    vocabulary; 48 slots x 17,408 positions in 1,024-blocks): one latent
+    kernel a layer, the grouped matmul's kernel three times an expert
+    layer (its column-split tiles: a [4096, 2048] matrix is four weight
+    blocks), the one pool of rows updated in place, and everything under
+    14.5 GB beside 5.31 GB of bfloat16 weights. (64 slots: 16.1 GB.)"""
+    import json
+
+    from fluxmpi_tpu.serving import InferenceEngine
+
+    prog, configs = _load_config_module("sarvam.program.py")
+    ref, _ = _load_config_module("sarvam.reference.py")
+    with open(os.path.join(configs, "sarvam-105b.json"),
+              encoding="utf-8") as f:
+        cfg = json.load(f)
+    with open(os.path.join(os.path.dirname(configs), "workloads",
+                           "sarvam-105b-serve.json"), encoding="utf-8") as f:
+        geometry = json.load(f)["engine"]
+    dev = topo.devices[0]
+    params = jax.tree_util.tree_map(
+        lambda x: _sds(x.shape, x.dtype, dev),
+        jax.eval_shape(
+            lambda key: prog.to_program(ref.make_weights(cfg, key), cfg)[0],
+            jax.random.PRNGKey(0),
+        ),
+    )
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert 5.3e9 < weights < 5.33e9
+    slots, bucket = geometry["slots"], 16384
+    engine = InferenceEngine(
+        prog.build_model(cfg, "naive"), params, attention="flash",
+        slots=slots, block_size=geometry["block_size"],
+        max_len=geometry["max_len"], check_memory=False,
+    )
+    try:
+        cache = engine.cache
+        assert cache.pool_shapes == [(5, 1 + slots * 17, 1024, 640)]
+        assert cache.pool_bytes == 5 * (1 + slots * 17) * 1024 * 640 * 2
+        pools = tuple(_sds(s, jnp.bfloat16, dev) for s in cache.pool_shapes)
+        decode = engine._decode_step.lower(
+            params, pools, (None,),
+            tuple(_sds((slots, k.entries), jnp.int32, dev)
+                  for k in cache.kinds),
+            _sds((slots,), jnp.int32, dev), _sds((slots,), jnp.int32, dev),
+        ).compile()
+        prefill = engine._prefill_step(bucket).lower(
+            params, pools, (None,), _sds((bucket,), jnp.int32, dev),
+            _sds((), jnp.int32, dev),
+            tuple(_sds((k.entries,), jnp.int32, dev) for k in cache.kinds),
+        ).compile()
+    finally:
+        engine.close()
+    text = decode.as_text()
+    assert text.count("tpu_custom_call") == 5 + 4 * 3
+    assert len(re.findall(r"%paged_latent_decode[.\d]* = ", text)) == 5
+    assert len(re.findall(r"%ragged-dot-gmm[.\d]* = ", text)) == 4 * 3
+    # The prefill: one flash forward a layer, no latent decode kernel.
+    text = prefill.as_text()
+    assert text.count("tpu_custom_call") == 5 + 4 * 3
+    assert not re.findall(r"%paged_latent_decode[.\d]* = ", text)
+    for program, temporaries in ((decode, 2**28), (prefill, 4 * 2**30)):
+        memory = program.memory_analysis()
+        assert memory.temp_size_in_bytes < temporaries
+        assert memory.alias_size_in_bytes >= cache.pool_bytes  # in place
+        assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                < 14.5e9)
+
+
 def _lm_state(cfg, optimizer):
     model = chip_smoke._lm(cfg)
     params = jax.eval_shape(
