@@ -433,7 +433,8 @@ def _engine_kernel_counts(engine) -> dict:
     slots, mb = engine.slots, engine.max_blocks_per_seq
     pools = (engine.params, engine.cache.k_pools, engine.cache.v_pools)
     programs = {"decode": engine._decode_step.lower(
-        *pools, (zeros(slots, mb),), zeros(slots), zeros(slots)
+        *pools, (zeros(slots, mb),), zeros(slots), zeros(slots),
+        zeros(slots), jnp.zeros((slots,), bool),
     )}
     for bucket, fn in engine._prefill_steps.items():
         programs[f"prefill_{bucket}"] = fn.lower(

@@ -228,6 +228,7 @@ _ENGINE_COUNTERS = (
     "kv_blocks_live", "kv_blocks_tabled", "context_tokens", "kv_blocks_full",
     "kv_blocks_window", "kv_blocks_uniform", "expert_tokens",
     "experts_touched", "expert_slots", "expert_weight_visits",
+    "decode_steps_overlapped", "tokens_discarded",
 )
 
 
@@ -287,14 +288,15 @@ def test_tiny_serve_cell_engine_surface(world, own_runtime):
 # reader must say so without raising.
 _TRACED = {
     "tiny-lm-serve": ("decode_host_ms", "decode_active_slots",
-                      "kv_blocks_read_pct"),
+                      "kv_blocks_read_pct", "decode_ticks_in_flight"),
     "tiny-trinity-serve": ("decode_host_ms", "decode_active_slots",
-                           "kv_blocks_read_pct", "experts_touched_pct"),
+                           "kv_blocks_read_pct", "experts_touched_pct",
+                           "decode_ticks_in_flight"),
     # Latent attention: the new span argument, the held experts' counters.
     "tiny-sarvam-serve": ("decode_host_ms", "decode_active_slots",
                           "kv_blocks_read_pct", "experts_touched_pct",
                           "expert_load_max_over_mean",
-                          "decode_context_tokens"),
+                          "decode_context_tokens", "decode_ticks_in_flight"),
 }
 
 

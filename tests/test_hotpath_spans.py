@@ -261,8 +261,23 @@ def test_engine_spans(tiny_lm, capture, request):
         assert prep[2] <= disp[1] and disp[2] <= fetch[1] <= deliv[1]
         assert _int_args(disp)["step"] == _int_args(fetch)["step"]
         assert _int_args(deliv)["tokens"] == _int_args(prep)["active"]
-        assert any(_inside(prep, it) and _inside(deliv, it)
+        # One tick in flight: a tick is prepared and dispatched in one
+        # iteration, fetched and delivered in the next.
+        assert any(_inside(prep, it) and _inside(disp, it)
                    for it in iterations)
+        assert any(_inside(fetch, it) and _inside(deliv, it)
+                   and not _inside(disp, it) for it in iterations)
+    overlapped = 0
+    for k, (disp, fetch) in enumerate(zip(ticks["dispatch"], ticks["fetch"])):
+        behind = int(_int_args(disp)["in_flight"])
+        overlapped += behind
+        if behind:
+            # Dispatched BEFORE the tick in flight was fetched.
+            assert disp[2] <= ticks["fetch"][k - 1][1]
+        elif k:
+            assert ticks["deliver"][k - 1][2] <= ticks["prepare"][k][1]
+    assert overlapped == stats["decode_steps_overlapped"] > 0
+    assert stats["tokens_discarded"] == 0
     assert sum(_int_args(s)["active"] for s in ticks["prepare"]) == (
         stats["slot_steps_active"]
     )
@@ -305,7 +320,11 @@ def test_engine_stats_agree_with_delivery(tiny_lm):
                           "kv_blocks_full",
                           "kv_blocks_window", "kv_blocks_uniform",
                           "expert_tokens", "experts_touched", "expert_slots",
-                          "expert_weight_visits"}
+                          "expert_weight_visits", "decode_steps_overlapped",
+                          "tokens_discarded"}
+    # Every tick but the two started from an empty engine (the third
+    # request waits for a slot) went out behind the one in flight.
+    assert stats["decode_steps_overlapped"] == stats["decode_steps"] - 2
     # 3 requests x 4 decode steps at positions 5..8 of 8-token blocks:
     # one live block each, two at position 8, in each of the 2 layers.
     assert stats["kv_blocks_live"] == 2 * 3 * (1 + 1 + 1 + 2)
@@ -431,6 +450,7 @@ def _engine_program_text(tiny_lm, which, slots=2, attention="flash"):
                 variables, cache.k_pools, cache.v_pools,
                 (jnp.zeros((slots, engine.max_blocks_per_seq), jnp.int32),),
                 jnp.zeros((slots,), jnp.int32), jnp.zeros((slots,), jnp.int32),
+                jnp.zeros((slots,), jnp.int32), jnp.zeros((slots,), bool),
             )
         else:
             lowered = engine._prefill_step(8).lower(

@@ -435,9 +435,8 @@ def test_decode_tick_counts_held_experts_only_and_says_its_context():
     try:
         cache = eng.cache
         out, k_pools, v_pools = eng._decode_step(
-            variables, cache.k_pools, cache.v_pools,
-            tuple(jnp.zeros((2, k.entries), jnp.int32) for k in cache.kinds),
-            jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
+            variables, cache.k_pools, cache.v_pools, *eng._idle_tick(),
+            eng._last_output(), jnp.zeros((2,), bool),
         )
         cache.k_pools, cache.v_pools = k_pools, v_pools
         # 2 tokens, then 2 expert layers x 4 held experts (not the

@@ -281,6 +281,252 @@ def test_flash_decode_masks_trash_block_garbage(model, engine_factory):
         np.testing.assert_array_equal(toks, ref)
 
 
+# ---------------------------------------------------------------------------
+# One decode tick in flight: tick N+1 is dispatched from the tokens on the
+# device, tick N fetched and delivered while it runs
+# ---------------------------------------------------------------------------
+
+
+def _reference(lm, variables, req, eos=None):
+    ref = np.asarray(generate(
+        lm, variables, jnp.asarray(req.prompt[None]), req.max_new_tokens,
+        eos_token=eos,
+    ))[0][len(req.prompt):]
+    if eos is not None and (ref == eos).any():
+        ref = ref[: int(np.argmax(ref == eos)) + 1]  # the engine stops AT eos
+    return ref
+
+
+def _span_args(tracer, name):
+    return [e["args"] for e in tracer.export()["traceEvents"]
+            if e.get("name") == name]
+
+
+def test_one_tick_in_flight_staggered_joins_restart_and_count_eviction(
+    model, engine_factory
+):
+    """Served tokens equal ``generate()``'s, token for token, through
+    admissions staggered mid-flight, evictions by count while others go
+    on, and a restart from an empty engine; the decode program compiles
+    once though nothing warmed it; the dispatch span says how often a
+    tick was in flight behind it."""
+    lm, variables = model
+    mon = compileplane.CompileMonitor()
+    compileplane.set_compile_monitor(mon)
+    tracer = tracing.Tracer(enabled=True)
+    previous = tracing.set_tracer(tracer)
+    try:
+        eng = engine_factory(slots=3)
+        rng = np.random.default_rng(5)
+        reqs = [eng.submit(_prompt(rng, 9), 14)]
+        for plen, mnew in ((5, 3), (12, 9), (4, 2), (7, 6)):
+            for _ in range(2):
+                eng.step()
+            assert eng._in_flight is not None  # hand-stepped: one tick lags
+            reqs.append(eng.submit(_prompt(rng, plen), mnew))
+        summary = eng.run()
+        assert summary["completed"] == 5 and eng._in_flight is None
+        mon.observe_flush()
+        # Idle -> busy: the first tick after an empty engine feeds the
+        # host's tokens, and nothing recompiles.
+        assert eng.active_count == 0 and not eng.step()
+        reqs += [eng.submit(_prompt(rng, 6), 8), eng.submit(_prompt(rng, 3), 5)]
+        eng.run()
+        assert mon.observe_flush()["events"] == 0 and mon.retraces == []
+        assert eng._decode_step._cache_size() == 1
+    finally:
+        tracing.set_tracer(previous)
+        compileplane.set_compile_monitor(None)
+    for req in reqs:
+        assert req.status == "finished"
+        np.testing.assert_array_equal(
+            np.asarray(req.tokens, np.int32), _reference(lm, variables, req)
+        )
+    assert eng.cache.free_blocks == eng.cache.num_blocks - 1
+    stats = eng.stats()
+    assert stats["tokens_discarded"] == 0
+    assert stats["tokens"] == sum(len(r.tokens) for r in reqs)
+    dispatches = _span_args(tracer, "serve.decode.dispatch")
+    assert len(dispatches) == stats["decode_steps"]
+    assert sum(d["in_flight"] for d in dispatches) == (
+        stats["decode_steps_overlapped"]
+    )
+    # Both starts from an empty engine had nothing in flight; most
+    # ticks went out behind one.
+    assert [d["in_flight"] for d in dispatches].count(0) == 2
+    assert dispatches[0]["in_flight"] == 0
+    # Every tick was fetched once, in order, each named by its own step.
+    assert [f["step"] for f in _span_args(tracer, "serve.decode.fetch")] == [
+        d["step"] for d in dispatches
+    ]
+
+
+def test_warmup_with_a_tick_in_flight_feeds_it_back_unharmed(
+    model, engine_factory
+):
+    """A hand-stepped engine may warm a new bucket up mid-flight: the
+    warm-up's own decode dispatch must not stand in for the output the
+    next tick takes its tokens from."""
+    lm, variables = model
+    eng = engine_factory(slots=2)
+    rng = np.random.default_rng(29)
+    req = eng.submit(_prompt(rng, 5), 10)
+    for _ in range(3):
+        eng.step()
+    assert eng._in_flight is not None
+    eng.warmup(prompt_lengths=(20,))
+    eng.run()
+    np.testing.assert_array_equal(
+        np.asarray(req.tokens, np.int32), _reference(lm, variables, req)
+    )
+    assert eng._decode_step._cache_size() == 1
+
+
+def _eos_case(lm, variables, rng, max_new):
+    """A prompt whose greedy continuation first shows some token at an
+    index in ``[2, max_new - 2]``: an ``eos_token`` that ends the request
+    with a tick in flight behind the one that made it."""
+    for _ in range(64):
+        prompt = _prompt(rng, 6)
+        ref = np.asarray(
+            generate(lm, variables, jnp.asarray(prompt[None]), max_new)
+        )[0][6:]
+        for at in range(2, max_new - 1):
+            if ref[at] not in ref[:at]:
+                return prompt, int(ref[at]), at
+    raise AssertionError("no prompt of this seed changes its token")
+
+
+def test_eos_hit_midflight_discards_one_token_and_frees_blocks_once(
+    model, engine_factory
+):
+    """What the host cannot know ahead: a slot whose request has an
+    ``eos_token`` rides the next tick speculatively. When the tick in
+    flight ends it, nothing is delivered past the end, exactly one token
+    is discarded, its blocks come back once and serve the next request."""
+    lm, variables = model
+    rng = np.random.default_rng(21)
+    prompt, eos, at = _eos_case(lm, variables, rng, 12)
+    # One usable reservation beside the bystander's: the follower below
+    # can only be served from the blocks the eos eviction returned.
+    eng = engine_factory(slots=2, num_blocks=1 + 3 + 3, max_queue=4)
+    seen = []
+    ended = eng.submit(prompt, 12, eos_token=eos, on_token=seen.append)
+    bystander = eng.submit(_prompt(rng, 5), 16)
+    follower = eng.submit(_prompt(rng, 9), 10)  # waits for blocks
+    while ended.status != "finished":
+        eng.step()
+    # The tick dispatched before the end was seen is still in flight and
+    # carries the evicted slot; its blocks are already free.
+    assert eng._in_flight is not None
+    assert any(slot.req is ended for _, slot in eng._in_flight.riders)
+    assert len(ended.tokens) == at + 1 and ended.tokens[-1] == eos
+    eng.run()
+    assert seen == ended.tokens and len(seen) == at + 1
+    assert eng.stats()["tokens_discarded"] == 1
+    np.testing.assert_array_equal(
+        np.asarray(ended.tokens, np.int32),
+        _reference(lm, variables, ended, eos),
+    )
+    for req in (bystander, follower):
+        assert req.status == "finished"
+        np.testing.assert_array_equal(
+            np.asarray(req.tokens, np.int32), _reference(lm, variables, req)
+        )
+    # A double free raises in the allocator; every block is back.
+    assert eng.cache.free_blocks == eng.cache.num_blocks - 1
+    assert eng.stats()["evictions"] == 3
+
+
+@pytest.mark.parametrize("end", ["run", "drain", "stop"])
+def test_ends_leave_no_tick_in_flight_and_no_request_unfinished(
+    model, engine_factory, end
+):
+    lm, variables = model
+    eng = engine_factory(slots=2, max_queue=4)
+    rng = np.random.default_rng(13)
+    reqs = [eng.submit(_prompt(rng, 5), 40), eng.submit(_prompt(rng, 8), 30)]
+    if end == "stop":
+        eng.start()
+        stream = reqs[0].stream(timeout=60.0)
+        for _ in range(4):
+            next(stream)
+        assert eng.stop()
+        assert eng._in_flight is None
+        # Parked: what was delivered is a prefix, and the next driver
+        # serves the rest.
+        held = [len(r.tokens) for r in reqs]
+        assert all(r.tokens == list(_reference(lm, variables, r)[:n])
+                   for r, n in zip(reqs, held))
+    else:
+        for _ in range(3):
+            eng.step()
+        assert eng._in_flight is not None
+    if end == "drain":
+        shed = eng.submit(_prompt(rng, 4), 4)  # queued: no slot is free
+        eng.drain()
+        assert shed.status == "rejected" and shed.reject_reason == "draining"
+    eng.run()
+    assert eng._in_flight is None and eng.active_count == 0
+    for req in reqs:
+        assert req.status == "finished"
+        np.testing.assert_array_equal(
+            np.asarray(req.tokens, np.int32), _reference(lm, variables, req)
+        )
+    # A hand-stepped engine's stop() delivers the lagging tick too.
+    late = eng.submit(_prompt(rng, 4), 6) if end != "drain" else None
+    if late is not None:
+        for _ in range(3):
+            eng.step()
+        delivered = len(late.tokens)
+        assert eng._in_flight is not None and eng.stop()
+        assert eng._in_flight is None and len(late.tokens) == delivered + 1
+        eng.run()
+        assert late.status == "finished" and len(late.tokens) == 6
+
+
+@pytest.mark.parametrize("driver", ["thread", "inline"])
+def test_fault_with_a_tick_in_flight_fails_each_request_once(
+    model, engine_factory, driver
+):
+    """The third dispatch hits the ``serving.decode`` site with the
+    second tick in flight. On the serve thread both ticks' requests fail
+    once (``reason="error"``) and their blocks come back once; driven
+    inline the exception reaches the caller with the tick still in
+    flight, and the next run serves on without losing or repeating a
+    token."""
+    lm, variables = model
+    get_registry().reset()
+    eng = engine_factory(slots=2)
+    eng.warmup(prompt_lengths=(4, 7))
+    rng = np.random.default_rng(17)
+    if driver == "thread":
+        with faults.scope("serving.decode@step=3"):
+            eng.start()
+            reqs = [eng.submit(_prompt(rng, 4), 20),
+                    eng.submit(_prompt(rng, 7), 20)]
+            assert all(r.wait(timeout=60.0) for r in reqs)
+        eng.stop()
+        assert isinstance(eng.serve_error, FaultInjectedError)
+        assert [r.reject_reason for r in reqs] == ["error", "error"]
+        assert eng._rejected == 2 and eng._in_flight is None
+        assert eng.active_count == 0
+        assert eng.cache.free_blocks == eng.cache.num_blocks - 1
+        return
+    reqs = [eng.submit(_prompt(rng, 4), 20), eng.submit(_prompt(rng, 7), 20)]
+    with faults.scope("serving.decode@step=3"):
+        with pytest.raises(FaultInjectedError, match="serving.decode"):
+            eng.run()
+    assert eng._in_flight is not None and eng._decode_steps == 2
+    eng.run()
+    for req in reqs:
+        assert req.status == "finished"
+        np.testing.assert_array_equal(
+            np.asarray(req.tokens, np.int32), _reference(lm, variables, req)
+        )
+    assert eng._rejected == 0 and eng._in_flight is None
+
+
 @pytest.mark.parametrize("attention", ["naive", "flash"])
 def test_moe_lm_serves_through_the_same_decode_program(world, attention):
     """The decode program runs the model's own blocks, so a subclass's
@@ -411,8 +657,10 @@ def test_one_admission_an_iteration_while_slots_decode(
     eng = engine_factory(slots=4, max_queue=8)
     first = eng.submit(_prompt(rng, 6), 12) if busy else None
     if busy:
-        eng.step()
+        eng.step()  # admitted: the first token came from the prefill
         assert first.status == "active"
+        eng.step()  # its first decode tick is in flight
+        assert len(first.tokens) == 1 and eng._in_flight is not None
     waiting = [eng.submit(_prompt(rng, 4 + i), 6) for i in range(3)]
     before = len(first.tokens) if busy else 0
     admitted = []
@@ -422,7 +670,8 @@ def test_one_admission_an_iteration_while_slots_decode(
     assert admitted == ([1, 2, 3] if busy else [3, 3, 3])
     if busy:
         # No gap between two of the active slot's tokens held more than
-        # one admission: it was delivered a token every iteration.
+        # one admission: every iteration delivered it the token of the
+        # tick dispatched the iteration before.
         assert len(first.tokens) == before + 3
     eng.run()
     assert all(r.status == "finished" for r in waiting)

@@ -246,6 +246,7 @@ def test_gpt2_medium_serving_programs_compile_for_v5e(topo, as_on_tpu):
         decode = engine._decode_step.lower(
             params, (pool,), (pool,), (_sds((32, mb), jnp.int32, dev),),
             _sds((32,), jnp.int32, dev), _sds((32,), jnp.int32, dev),
+            _sds((32,), jnp.int32, dev), _sds((32,), jnp.bool_, dev),
         ).compile()
         prefill = engine._prefill_step(256).lower(
             params, (pool,), (pool,), _sds((256,), jnp.int32, dev),
@@ -254,6 +255,9 @@ def test_gpt2_medium_serving_programs_compile_for_v5e(topo, as_on_tpu):
     finally:
         engine.close()
     assert decode.as_text().count("tpu_custom_call") == cfg["num_layers"]
+    # Each weight is prefetched whole (the compiler's default cuts it in
+    # four): a tick launches 1,352 operations where it launched 2,198.
+    assert "slice-start" not in decode.as_text()
     for program in (decode, prefill):
         memory = program.memory_analysis()
         assert memory.temp_size_in_bytes < 2**29
@@ -310,6 +314,9 @@ def test_trinity_mini_serving_programs_compile_for_v5e(topo, as_on_tpu):
             params, pools, pools,
             tuple(_sds((64, k.entries), jnp.int32, dev) for k in cache.kinds),
             _sds((64,), jnp.int32, dev), _sds((64,), jnp.int32, dev),
+            # prev: 64 tokens, then 4 expert layers' counts of 128.
+            _sds((64 + 4 * 128,), jnp.int32, dev),
+            _sds((64,), jnp.bool_, dev),
         ).compile()
         prefill = engine._prefill_step(2560).lower(
             params, pools, pools, _sds((2560,), jnp.int32, dev),
@@ -322,6 +329,7 @@ def test_trinity_mini_serving_programs_compile_for_v5e(topo, as_on_tpu):
     # as the benchmark's readers find XLA's own (``^ragged-dot``).
     text = decode.as_text()
     assert text.count("tpu_custom_call") == 5 + 4 * 3
+    assert "slice-start" not in text  # operands prefetched whole
     assert len(re.findall(r"%ragged-dot-gmm[.\d]* = ", text)) == 4 * 3
     assert len(re.findall(
         r"%ragged-dot-gmm[.\d]* = ", prefill.as_text())) == 4 * 3
@@ -437,6 +445,9 @@ def test_sarvam_105b_serving_programs_compile_for_v5e(topo, as_on_tpu):
             tuple(_sds((slots, k.entries), jnp.int32, dev)
                   for k in cache.kinds),
             _sds((slots,), jnp.int32, dev), _sds((slots,), jnp.int32, dev),
+            # prev: the tokens, then 4 expert layers' counts of 16 held.
+            _sds((slots + 4 * 16,), jnp.int32, dev),
+            _sds((slots,), jnp.bool_, dev),
         ).compile()
         prefill = engine._prefill_step(bucket).lower(
             params, pools, (None,), _sds((bucket,), jnp.int32, dev),
@@ -447,6 +458,7 @@ def test_sarvam_105b_serving_programs_compile_for_v5e(topo, as_on_tpu):
         engine.close()
     text = decode.as_text()
     assert text.count("tpu_custom_call") == 5 + 4 * 3
+    assert "slice-start" not in text  # operands prefetched whole
     assert len(re.findall(r"%paged_latent_decode[.\d]* = ", text)) == 5
     assert len(re.findall(r"%ragged-dot-gmm[.\d]* = ", text)) == 4 * 3
     # The prefill: one flash forward a layer, no latent decode kernel.

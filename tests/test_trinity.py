@@ -298,9 +298,8 @@ def test_engine_tick_returns_expert_counts_with_the_tokens_in_one_array():
     try:
         cache = eng.cache
         out, _, _ = eng._decode_step(
-            variables, cache.k_pools, cache.v_pools,
-            tuple(jnp.zeros((2, k.entries), jnp.int32) for k in cache.kinds),
-            jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
+            variables, cache.k_pools, cache.v_pools, *eng._idle_tick(),
+            eng._last_output(), jnp.zeros((2,), bool),
         )
         # 2 tokens, then 3 expert layers x 16 experts; idle slots (all
         # trash tables) are routed nowhere.
