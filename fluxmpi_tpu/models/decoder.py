@@ -10,7 +10,7 @@ not that block. :class:`DecoderLM` reads its block from a
   (``norm_placement="sandwich"``: ``h = x + N_post_attn(Attn(N_in(x)))``,
   ``y = h + N_post_ff(FF(N_pre_ff(h)))``) or two in the pre-norm one
   (``"pre"``: ``h = x + Attn(N_in(x))``, ``y = h + FF(N_pre_ff(h))``);
-- one of THREE kinds of attention a layer (``layer_types``). Two keep K
+- one of FOUR kinds of mixer a layer (``layer_types``). Two keep K
   and V: ``num_heads`` query heads over ``num_kv_heads`` K/V heads of
   ``head_dim`` (independent of ``hidden_size``), RMSNorm over ``head_dim``
   on ``q`` and ``k``, a sigmoid output gate (``output_gate``), as
@@ -22,11 +22,26 @@ not that block. :class:`DecoderLM` reads its block from a
   each head's key is ``[Wkvb_K c; k_r]`` and its value ``Wkvb_V c``
   (:class:`LatentAttention`: the un-absorbed form over a call's own
   tokens, the absorbed form against a cache of rows), rotary angles
-  scaled as ``rope_scaling`` says (``deepseek_yarn``);
+  scaled as ``rope_scaling`` says (``deepseek_yarn``). The fourth,
+  ``"mamba"`` (:class:`MambaMixer`: Mamba-2), keeps nothing a token: a
+  recurrent STATE a sequence (``mamba_n_heads x mamba_d_head x
+  mamba_d_state``) and the last ``mamba_d_conv - 1`` inputs of its
+  causal convolution; over a call's own tokens the recurrence runs as a
+  chunked scan, against a cache one step a token. A full layer may run
+  without the head norms (``qk_norm``) and at a configured softmax scale
+  (``attention_multiplier``);
 - a gated (SwiGLU) MLP in the first ``num_dense_layers`` layers and an
-  :class:`ExpertMLP` in the rest;
-- an untied head, and the embedding scaled by ``sqrt(hidden_size)``
-  where ``mup_enabled``.
+  :class:`ExpertMLP` in the rest (scores ``sigmoid(u Wr)`` normalised
+  over the chosen, or ``score_func="softmax"``: the largest logits,
+  weighed by a softmax over the chosen), with a shared MLP of
+  ``num_shared_experts x moe_intermediate_size`` or of a width of its own
+  (``shared_intermediate_size``);
+- a head of its own or the embedding's transpose
+  (``tie_word_embeddings``); the embedding scaled by
+  ``sqrt(hidden_size)`` where ``mup_enabled`` and by
+  ``embedding_multiplier``, each sublayer's result by
+  ``residual_multiplier`` as it joins the stream, the logits divided by
+  ``logits_scaling``.
 
 ``num_experts`` counts the experts a layer HOLDS; where the router is
 wider (``num_routed_experts``: this chip's share of an expert-parallel
@@ -45,13 +60,15 @@ norms and the rotary) in layer order and takes ``[batch, seq, heads,
 head_dim]`` back; a latent layer adds ``row=`` (what its cache keeps of
 each token) and, where the function attends a cache
 (``attention_fn.from_cache``), calls ``attention_fn.latent(q_abs, q_rope,
-row)`` with the absorbed queries instead;
+row)`` with the absorbed queries instead; a Mamba layer hands a prefill's
+function ``keep_state(tail, state)`` and asks a cache's for
+``conv_tail()`` and ``state_update(tail, x, step, decay, B, C)``;
 :meth:`DecoderLM.cache_layers` says what each layer keeps in a cache.
 ``pos_offset`` (``[batch]``) places each row at its own
 position and ``head_at`` (``[batch]``) takes the head at one position a
 row: a prefill never builds ``[prompt, vocab]`` logits. ``token_mask``
 (``[batch, seq]``) names the real tokens: padding and idle slots are
-routed to no expert.
+routed to no expert and move no Mamba layer's state (their step is 0).
 """
 
 from __future__ import annotations
@@ -68,10 +85,11 @@ import numpy as np
 from .transformer import _resolve_attention_mode
 
 __all__ = ["DecoderConfig", "DecoderLM", "ExpertMLP", "LatentAttention",
-           "causal_attention"]
+           "MambaMixer", "causal_attention"]
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 LATENT = "latent_attention"
+MAMBA = "mamba"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,9 +130,32 @@ class DecoderConfig:
     # ``rope_scaling`` of the config.json as sorted (key, value) pairs
     # (hashable); None: plain rotary angles.
     rope_scaling: tuple | None = None
+    # RMSNorm over ``head_dim`` on q and k (full and window layers), and
+    # the softmax scale where it is not ``head_dim ** -0.5``.
+    qk_norm: bool = True
+    attention_multiplier: float | None = None
+    # muP-style multipliers: on the embedding, on each sublayer's result
+    # as it joins the residual stream, and the logits' divisor.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # The head is the embedding's transpose.
+    tie_word_embeddings: bool = False
+    # The shared MLP's width (0: num_shared_experts * moe_intermediate_size).
+    shared_intermediate_size: int = 0
+    # Mamba-2 layers: heads of ``mamba_d_head``, a state of ``mamba_d_state``
+    # a head dimension, B and C shared by all heads (one group), a causal
+    # depthwise convolution over ``mamba_d_conv`` positions, the prefill's
+    # scan in chunks of ``mamba_chunk_size``.
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
 
     def __post_init__(self):
-        unknown = set(self.layer_types) - {SLIDING, FULL, LATENT}
+        unknown = set(self.layer_types) - {SLIDING, FULL, LATENT, MAMBA}
         if unknown:
             raise ValueError(f"unknown layer types {sorted(unknown)}")
         if SLIDING in self.layer_types and not self.sliding_window:
@@ -148,8 +189,20 @@ class DecoderConfig:
                 f"{self.num_attention_heads} query heads are not a multiple "
                 f"of {self.num_key_value_heads} K/V heads"
             )
-        if self.score_func != "sigmoid":
+        if self.score_func not in ("sigmoid", "softmax"):
             raise ValueError(f"unknown score_func {self.score_func!r}")
+        if MAMBA in self.layer_types:
+            if not (self.mamba_n_heads and self.mamba_d_head
+                    and self.mamba_d_state):
+                raise ValueError(
+                    "mamba layers need mamba_n_heads, mamba_d_head and "
+                    "mamba_d_state"
+                )
+            if self.mamba_n_groups != 1:
+                raise ValueError(
+                    f"one group of B and C for all heads, not "
+                    f"{self.mamba_n_groups}"
+                )
 
     @property
     def num_layers(self) -> int:
@@ -160,14 +213,37 @@ class DecoderConfig:
         """What a token leaves in a latent layer's cache."""
         return self.kv_lora_rank + self.qk_rope_head_dim
 
+    @property
+    def mamba_inner(self) -> int:
+        """A Mamba layer's inner width: its heads side by side."""
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """What the convolution runs over: ``[x; B; C]``."""
+        return self.mamba_inner + 2 * self.mamba_d_state
+
+    @property
+    def shared_width(self) -> int:
+        """The width of the MLP every token passes beside the experts."""
+        return (self.shared_intermediate_size
+                or self.num_shared_experts * self.moe_intermediate_size)
+
     @classmethod
     def from_hf(cls, cfg: dict) -> "DecoderConfig":
-        """From the keys of a ``config.json``, ``model_type`` ``"afmoe"``
-        or ``"sarvam_mla"`` (whose names for the same things are mapped:
+        """From the keys of a ``config.json``, ``model_type`` ``"afmoe"``,
+        ``"sarvam_mla"`` (whose names for the same things are mapped:
         ``first_k_dense_replace``, ``routed_scaling_factor``; every layer
         latent attention in a pre-norm block without the output gate;
-        ``head_dim`` there is the cache's row, not a head's width); keys
-        this class does not know are left alone."""
+        ``head_dim`` there is the cache's row, not a head's width) or
+        ``"granitemoehybrid"`` (``layer_types`` of ``"mamba"`` and
+        ``"attention"``, read as full attention without positions, head
+        norms or gate at the scale ``attention_multiplier``;
+        ``num_local_experts`` experts of ``intermediate_size`` chosen by
+        the largest router logits and weighed by a softmax over the
+        chosen; a shared MLP of ``shared_intermediate_size``; pre-norm;
+        the three multipliers; a tied head); keys this class does not
+        know are left alone."""
         names = {f.name for f in dataclasses.fields(cls)}
         known = {k: v for k, v in cfg.items() if k in names and v is not None}
         if cfg.get("model_type") == "sarvam_mla":
@@ -179,6 +255,16 @@ class DecoderConfig:
                 num_key_value_heads=heads,
                 head_dim=cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"],
                 norm_placement="pre", output_gate=False,
+            )
+        elif cfg.get("model_type") == "granitemoehybrid":
+            known.update(
+                layer_types=tuple(MAMBA if kind == MAMBA else FULL
+                                  for kind in cfg["layer_types"]),
+                head_dim=cfg["hidden_size"] // cfg["num_attention_heads"],
+                num_experts=cfg["num_local_experts"],
+                moe_intermediate_size=cfg["intermediate_size"],
+                score_func="softmax", norm_placement="pre",
+                output_gate=False, qk_norm=False,
             )
         else:
             known["layer_types"] = tuple(cfg["layer_types"])
@@ -343,13 +429,19 @@ class Attention(nn.Module):
         wg = (self.param("wg", init, (d, heads * hd))
               if c.output_gate else None)
         wo = self.param("wo", init, (heads * hd, d))
-        q_scale = self.param("q_norm", nn.initializers.ones, (hd,))
-        k_scale = self.param("k_norm", nn.initializers.ones, (hd,))
         q = _dot(u, wq, self.dtype).reshape(b, s, heads, hd)
         k = _dot(u, wk, self.dtype).reshape(b, s, kvh, hd)
         v = _dot(u, wv, self.dtype).reshape(b, s, kvh, hd).astype(self.dtype)
-        q = _rms_norm(q, q_scale, c.rms_norm_eps)
-        k = _rms_norm(k, k_scale, c.rms_norm_eps)
+        if c.qk_norm:
+            q_scale = self.param("q_norm", nn.initializers.ones, (hd,))
+            k_scale = self.param("k_norm", nn.initializers.ones, (hd,))
+            q = _rms_norm(q, q_scale, c.rms_norm_eps)
+            k = _rms_norm(k, k_scale, c.rms_norm_eps)
+        if c.attention_multiplier is not None:
+            # The attention divides by sqrt(head_dim) itself; the rest of
+            # a configured scale rides on the queries, applied before
+            # their one rounding to ``dtype``.
+            q = q * (c.attention_multiplier * hd ** 0.5)
         window = None
         if self.layer_type == SLIDING:
             window = c.sliding_window
@@ -460,6 +552,135 @@ class LatentAttention(nn.Module):
         return _dot(out, wo, self.dtype).astype(self.dtype)
 
 
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """``log A`` with ``A`` uniform in [1, 16], as Mamba-2 publishes it."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _conv_init(key, shape, dtype=jnp.float32):
+    """``[channels, taps]`` uniform in +-1 / sqrt(taps): what Mamba-2's
+    depthwise convolution is left at."""
+    bound = shape[-1] ** -0.5
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """The inverse softplus of a step drawn log-uniform in [0.001, 0.1]
+    (Mamba-2's): with a zero projection the step IS that draw."""
+    step = jnp.exp(jax.random.uniform(
+        key, shape, dtype, math.log(0.001), math.log(0.1)))
+    return step + jnp.log(-jnp.expm1(-step))
+
+
+class MambaMixer(nn.Module):
+    """A Mamba-2 mixer. Of ``u`` at position ``t``: ``[z; xBC; dt] = u
+    W_in`` (``inner``; ``inner + 2 d_state``; ``heads``); ``xBC_t <-
+    silu(b_c + sum_j w_c[:, j] xBC_{t - d_conv + 1 + j})`` (depthwise,
+    causal, zeros before the start), split ``[x (heads x head_dim); B; C
+    (d_state each, one group for all heads)]``; ``D_t = softplus(dt_t +
+    dt_bias)``, ``a_t = exp(D_t A)``, ``A = -exp(a_log)`` a head; the
+    state a head ``H_t = a_t H_{t-1} + D_t x_t B_t^T``, ``y_t = H_t C_t +
+    d_skip x_t``; ``g = y silu(z)``, RMSNormed over all of ``inner``;
+    ``g W_out``.
+
+    Over a call's own tokens (the plain forward, a prefill) the state
+    starts at zero and the chunked scan
+    (:func:`~fluxmpi_tpu.ops.ssm.ssd_chunk_scan`) computes the
+    recurrence; positions that ``token_mask`` leaves out get ``D_t = 0``,
+    so the state after a padded prompt is the state after its last real
+    token, and where ``attention_fn`` is set it is handed what a cache
+    keeps of the sequence: ``attention_fn.keep_state(tail, state)``, the
+    last ``d_conv - 1`` real PRE-convolution ``xBC`` columns (zeros
+    before the start) and the final state. Against a cache
+    (``attention_fn.from_cache``, one token a row): ``tail =
+    attention_fn.conv_tail()``, one convolution step over ``[tail; xBC]``,
+    and ``attention_fn.state_update(new tail, x, D, a, B, C)`` moves the
+    row's state in its pool and returns ``H_t C_t``. ``xBC`` is rounded
+    to ``dtype`` where it leaves the projection: a cached tail holds what
+    the prefill's convolution read."""
+
+    config: DecoderConfig
+    dtype: Any
+    attention_fn: Callable | None = None
+
+    @nn.compact
+    def __call__(self, u, token_mask=None):
+        c = self.config
+        heads, hd, n, taps = (c.mamba_n_heads, c.mamba_d_head,
+                              c.mamba_d_state, c.mamba_d_conv)
+        inner, conv_dim = c.mamba_inner, c.mamba_conv_dim
+        init = nn.initializers.normal(0.02)
+        f32 = jnp.float32
+        b, s, d = u.shape
+        w_in = self.param("w_in", init, (d, inner + conv_dim + heads))
+        conv_w = self.param("conv_w", _conv_init, (conv_dim, taps))
+        conv_b = self.param("conv_b", nn.initializers.zeros, (conv_dim,))
+        dt_bias = self.param("dt_bias", _dt_bias_init, (heads,))
+        a_log = self.param("a_log", _a_log_init, (heads,))
+        d_skip = self.param("d_skip", nn.initializers.ones, (heads,))
+        norm = self.param("norm", nn.initializers.ones, (inner,))
+        w_out = self.param("w_out", init, (inner, d))
+        # One row a token from here on (``[batch * seq, width]``): a
+        # decode tick's ``[slots, 1, width]`` arrays would each be tiled
+        # one row a tile.
+        with jax.named_scope("ssm_in_proj"):
+            proj = _dot(u.reshape(b * s, d), w_in, self.dtype)
+            z = proj[:, :inner]
+            xbc = proj[:, inner:inner + conv_dim].astype(self.dtype)
+            step = jax.nn.softplus(
+                proj[:, inner + conv_dim:] + dt_bias.astype(f32))
+            if token_mask is not None:
+                step = jnp.where(token_mask.reshape(-1, 1), step, 0.0)
+        a_rate = -jnp.exp(a_log.astype(f32))
+        fn = self.attention_fn
+        cached = fn is not None and getattr(fn, "from_cache", False)
+        with jax.named_scope("ssm_conv"):
+            if cached:  # seq is 1: the tail, then the token
+                before = fn.conv_tail().astype(self.dtype)
+            else:
+                before = jnp.zeros((b, taps - 1, conv_dim), self.dtype)
+            padded = jnp.concatenate(
+                [before, xbc.reshape(b, s, conv_dim)], axis=1)
+            conv = conv_b.astype(f32) + sum(
+                padded[:, j:j + s].astype(f32) * conv_w[:, j].astype(f32)
+                for j in range(taps)
+            )
+            conv = jax.nn.silu(conv).reshape(b * s, conv_dim)
+            x = conv[:, :inner]
+            state_in, state_out = conv[:, inner:inner + n], conv[:, inner + n:]
+        if cached:
+            with jax.named_scope("ssm_update"):
+                y = fn.state_update(
+                    padded[:, 1:], x.reshape(b, heads, hd), step,
+                    jnp.exp(step * a_rate), state_in, state_out,
+                )
+        else:
+            from ..ops.ssm import ssd_chunk_scan
+
+            with jax.named_scope("ssm_scan"):
+                y, state = ssd_chunk_scan(
+                    x.reshape(b, s, heads, hd).astype(self.dtype),
+                    step.reshape(b, s, heads), a_rate,
+                    state_in.reshape(b, s, n), state_out.reshape(b, s, n),
+                    chunk=c.mamba_chunk_size,
+                )
+            if fn is not None:
+                # The last ``taps - 1`` real columns: ``padded`` holds
+                # position p at p + taps - 1, zeros before the start.
+                length = (jnp.full((b,), s) if token_mask is None
+                          else jnp.sum(token_mask, axis=1))
+                at = length[:, None] + jnp.arange(taps - 1)[None]
+                fn.keep_state(
+                    jnp.take_along_axis(padded, at[..., None], axis=1), state)
+        with jax.named_scope("ssm_gate_norm"):
+            y = y.reshape(b * s, inner) + jnp.repeat(
+                d_skip.astype(f32), hd) * x
+            out = _rms_norm(y * jax.nn.silu(z), norm, c.rms_norm_eps)
+        with jax.named_scope("ssm_out_proj"):
+            return _dot(out, w_out, self.dtype).astype(self.dtype).reshape(
+                b, s, d)
+
+
 # The float32 result a feed-forward may hold for all its tokens at once:
 # over every cell served before the latent model (the widest: 8,704
 # tokens x 8 pairs x 2,048 = 570 MB), under a 16,384-token prompt's
@@ -509,7 +730,9 @@ class ExpertMLP(nn.Module):
     Every token scores all ``num_experts`` (``sigmoid(u Wr)``, float32),
     takes the ``top_k`` largest of score + bias, and weighs the chosen by
     their scores, normalised to sum 1 (``route_norm``), times
-    ``route_scale``. The layer HOLDS the contiguous range
+    ``route_scale``; with ``score_func="softmax"`` the scores are the
+    logits ``u Wr`` themselves and the weights a softmax over the
+    ``top_k`` chosen. The layer HOLDS the contiguous range
     ``expert_range`` of the experts (default: all): the (token, expert)
     pairs are sorted by expert, the held experts' pairs first, and one
     grouped matmul (:func:`~fluxmpi_tpu.ops.grouped_matmul.grouped_matmul`:
@@ -537,16 +760,22 @@ class ExpertMLP(nn.Module):
     expert_range: tuple[int, int] | None = None
     include_shared: bool = True
     dtype: Any = jnp.float32
+    score_func: str = "sigmoid"
 
     def route(self, u, router, bias):
         """``(experts [tokens, top_k], weights [tokens, top_k])``."""
-        scores = jax.nn.sigmoid(jnp.dot(
+        scores = jnp.dot(
             u.astype(jnp.float32), router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST,
-        ))
+        )
+        if self.score_func == "sigmoid":
+            scores = jax.nn.sigmoid(scores)
         _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32),
                                    self.top_k)
         weights = jnp.take_along_axis(scores, experts, axis=-1)
+        if self.score_func == "softmax":
+            # The largest logits, weighed by a softmax over the chosen.
+            return experts, jax.nn.softmax(weights, axis=-1) * self.route_scale
         if self.route_norm:
             weights = weights / (
                 jnp.sum(weights, axis=-1, keepdims=True) + 1e-20
@@ -640,19 +869,28 @@ class DecoderLayer(nn.Module):
         def norm(name):
             return RMSNorm(c.rms_norm_eps, self.dtype, name=name)
 
+        def join(stream, result):
+            """The sublayer's result onto the residual stream."""
+            if c.residual_multiplier == 1.0:
+                return stream + result
+            return (stream.astype(jnp.float32) + c.residual_multiplier
+                    * result.astype(jnp.float32)).astype(self.dtype)
+
         sandwich = c.norm_placement == "sandwich"
         kind = c.layer_types[self.index]
-        if kind == LATENT:
-            attn = LatentAttention(
+        if kind == MAMBA:
+            a = MambaMixer(c, self.dtype, self.attention_fn, name="mamba")(
+                norm("norm_in")(x), token_mask)
+        elif kind == LATENT:
+            a = LatentAttention(
                 c, self.dtype, self.attention, self.attention_fn, name="attn"
-            )
+            )(norm("norm_in")(x), positions)
         else:
-            attn = Attention(
+            a = Attention(
                 c, kind, self.dtype, self.attention, self.attention_fn,
                 name="attn",
-            )
-        a = attn(norm("norm_in")(x), positions)
-        h = x + (norm("norm_post_attn")(a) if sandwich else a)
+            )(norm("norm_in")(x), positions)
+        h = join(x, norm("norm_post_attn")(a) if sandwich else a)
         u = norm("norm_pre_ff")(h)
         if self.index < c.num_dense_layers:
             y = GatedMLP(c.intermediate_size, self.dtype, name="mlp")(u)
@@ -664,18 +902,20 @@ class DecoderLayer(nn.Module):
             ff = ExpertMLP(
                 num_experts=routed, top_k=c.num_experts_per_tok,
                 width=c.moe_intermediate_size,
-                shared_width=c.num_shared_experts * c.moe_intermediate_size,
+                shared_width=c.shared_width,
                 route_norm=c.route_norm, route_scale=c.route_scale,
-                expert_range=held, dtype=self.dtype, name="moe",
+                expert_range=held, dtype=self.dtype,
+                score_func=c.score_func, name="moe",
             )
             y = ff(u, token_mask)
-        return h + (norm("norm_post_ff")(y) if sandwich else y)
+        return join(h, norm("norm_post_ff")(y) if sandwich else y)
 
 
 class DecoderLM(nn.Module):
     """Embedding, ``config.num_layers`` :class:`DecoderLayer`, RMSNorm,
-    untied head. ``__call__`` returns float32 logits ``[batch, seq,
-    vocab]``, or ``[batch, vocab]`` with ``head_at``."""
+    a head of its own or the embedding's transpose
+    (``tie_word_embeddings``). ``__call__`` returns float32 logits
+    ``[batch, seq, vocab]``, or ``[batch, vocab]`` with ``head_at``."""
 
     # No capacity, no dropped token: a batched prefill computes for each
     # position what a one-token tick computes.
@@ -699,13 +939,19 @@ class DecoderLM(nn.Module):
     def num_layers(self) -> int:
         return self.config.num_layers
 
-    def cache_layers(self) -> tuple[tuple[int | None, int, int | None], ...]:
+    def cache_layers(self) -> tuple[tuple, ...]:
         """What each layer keeps of a sequence: ``(kv_heads, head_dim,
         window)``, ``window`` None where a layer attends its whole
         context; a latent layer ``(None, row, None)``: no K/V heads, ONE
-        row of ``kv_lora_rank + qk_rope_head_dim`` a token and no V."""
+        row of ``kv_lora_rank + qk_rope_head_dim`` a token and no V; a
+        Mamba layer ``("state", (heads, head_dim, d_state), (d_conv - 1,
+        conv_dim))``: nothing a token, ONE state and one tail of
+        pre-convolution columns a SEQUENCE, whatever its length."""
         c = self.config
+        state = ("state", (c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state),
+                 (c.mamba_d_conv - 1, c.mamba_conv_dim))
         return tuple(
+            state if kind == MAMBA else
             (None, c.latent_row, None) if kind == LATENT else
             (c.num_key_value_heads, c.head_dim,
              c.sliding_window if kind == SLIDING else None)
@@ -742,6 +988,8 @@ class DecoderLM(nn.Module):
         x = embed[tokens].astype(self.dtype)
         if c.mup_enabled:
             x = x * jnp.asarray(c.hidden_size ** 0.5, self.dtype)
+        if c.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(c.embedding_multiplier, self.dtype)
         for i in range(c.num_layers):
             x = DecoderLayer(
                 c, i, self.dtype, self.attention, self.attention_fn,
@@ -752,5 +1000,12 @@ class DecoderLM(nn.Module):
                 x, jnp.asarray(head_at)[:, None, None], axis=1
             )[:, 0]
         x = RMSNorm(c.rms_norm_eps, self.dtype, name="norm_out")(x)
-        head = self.param("head", init, (c.hidden_size, c.vocab_size))
-        return _dot(x, head, self.dtype)
+        if c.tie_word_embeddings:
+            logits = jnp.einsum(
+                "...d,vd->...v", x.astype(self.dtype),
+                embed.astype(self.dtype), preferred_element_type=jnp.float32,
+            )
+        else:
+            head = self.param("head", init, (c.hidden_size, c.vocab_size))
+            logits = _dot(x, head, self.dtype)
+        return logits if c.logits_scaling == 1.0 else logits / c.logits_scaling

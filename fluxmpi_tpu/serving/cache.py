@@ -29,8 +29,8 @@ positions are cut into fixed-size **blocks**,
   (:func:`fluxmpi_tpu.ops.paged_attention.paged_decode_attention`, see
   :mod:`fluxmpi_tpu.serving.engine`).
 
-**Three kinds of layer** (:attr:`BlockKVCache.kinds`: full, window,
-latent), each with its pool(s), its free list and its tables.
+**Four kinds of layer** (:attr:`BlockKVCache.kinds`: full, window,
+latent, state), each with its pool(s), its free list and its tables.
 
 **Layers that attend a window keep a ring.** A model whose layers are
 not all alike (``layer_windows``: some attend their whole context, some
@@ -51,6 +51,28 @@ Such layers are a third kind, with a free list, a table spanning
 ``max_len`` and ONE pool (:attr:`BlockKVCache.k_pools`; its entry of
 :attr:`BlockKVCache.v_pools` is None), counted at one row a token,
 padded to whole 128-lane tiles, in :attr:`BlockKVCache.pool_bytes`.
+
+**Layers that keep a state keep one entry a sequence.** A state-space
+(Mamba-2) layer (``layer_state``) caches nothing a token: a sequence's
+whole past is ONE recurrent state (``heads x head_dim x d_state``,
+float32: the recurrence compounds its rounding over a whole answer) and
+ONE tail of the last ``d_conv - 1`` pre-convolution columns (the pool's
+dtype), whatever its length. Such layers are a fourth kind whose "blocks"
+are ENTRIES: :meth:`BlockKVCache.blocks_for` is 1 for any number of
+tokens, a table row is one entry wide, the pool holds one entry a sequence
+``num_blocks`` holds at ``max_blocks_per_seq`` (the engine's slots) plus
+the trash entry, its :attr:`BlockKVCache.k_pools` entry is the state pool
+``[layers, entries, d_state, heads * head_dim]`` (a state transposed, its
+heads side by side: what the update kernel reads in place) and its
+:attr:`BlockKVCache.v_pools` entry the tail pool ``[layers, entries,
+(d_conv - 1) * conv_dim]`` (a tail's columns end to end in one row).
+Admission is then bounded by STATES where a token-keeping kind bounds it
+by tokens: a Mamba-2 layer of 128 heads of 64 over a state of 128 holds
+4.19 MB a sequence at any length, where a layer of 8 K/V heads of 128
+holds 4 KB a token. The decode tick moves a live
+slot's state where it lies
+(:func:`fluxmpi_tpu.ops.ssm.ssm_state_update`); a prefill overwrites an
+admitted sequence's entry whole.
 
 **Block 0 is the trash block**: it is never allocated. Unused table
 entries point at it, masked prefill positions and idle batch slots
@@ -90,13 +112,17 @@ class _Kind:
     context, else the positions a window layer attends. ``entries`` is
     the width of a sequence's table row for these layers, ``layer_ids``
     the model's layers that are of this kind, in order. ``latent``: the
-    layers keep one row a token in ``k_pool`` and no ``v_pool``."""
+    layers keep one row a token in ``k_pool`` and no ``v_pool``.
+    ``state``: the layers keep ONE entry a sequence, ``((heads, head_dim,
+    d_state), tail shape)``: the recurrent state in ``k_pool`` (float32),
+    the convolution's tail in ``v_pool``; ``num_blocks`` counts entries."""
 
     __slots__ = ("layer_ids", "window", "entries", "num_blocks", "free",
-                 "k_pool", "v_pool", "latent")
+                 "k_pool", "v_pool", "latent", "state")
 
     def __init__(self, layer_ids: tuple[int, ...], window: int | None,
-                 entries: int, num_blocks: int, latent: bool = False):
+                 entries: int, num_blocks: int, latent: bool = False,
+                 state: tuple | None = None):
         if num_blocks < 2:
             raise ValueError(
                 f"num_blocks must be >= 2 (block 0 is the reserved trash "
@@ -107,6 +133,7 @@ class _Kind:
         self.entries = entries
         self.num_blocks = num_blocks
         self.latent = latent
+        self.state = state
         # LIFO free list: the most recently freed block is handed out
         # next — the round-trip the reuse test pins down.
         self.free: list[int] = list(range(num_blocks - 1, 0, -1))
@@ -146,6 +173,12 @@ class BlockKVCache:
         of K and V (default: no layer). Such layers attend their whole
         context and are a kind of their own, after the other two, with
         one pool.
+      layer_state: per layer, None or ``((heads, head_dim, d_state), tail
+        shape)`` of a layer that keeps ONE recurrent state (float32) and
+        one convolution tail (``dtype``) a SEQUENCE and nothing a token
+        (default: no layer). One shape a model. Such layers are the last
+        kind: a "block" of theirs is a pool entry, one a sequence.
+        ``num_heads`` / ``head_dim`` then speak of the other layers.
 
     :meth:`alloc`, :meth:`free`, :meth:`table_row` and :meth:`blocks_for`
     take the ``kind`` they speak of (default 0: the only kind of a model
@@ -169,6 +202,7 @@ class BlockKVCache:
         dtype: Any = None,
         layer_windows: Sequence[int | None] | None = None,
         layer_latent: Sequence[bool] | None = None,
+        layer_state: Sequence[tuple | None] | None = None,
     ):
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
@@ -198,12 +232,24 @@ class BlockKVCache:
             )
         if any(w is not None and x for w, x in zip(windows, latent)):
             raise ValueError("a latent layer attends its whole context")
+        states = tuple(layer_state or (None,) * self.num_layers)
+        if len(states) != self.num_layers:
+            raise ValueError(
+                f"layer_state names {len(states)} layers, not "
+                f"{self.num_layers}"
+            )
+        if any(st is not None and (w is not None or x)
+               for st, w, x in zip(states, windows, latent)):
+            raise ValueError("a state layer keeps no token's rows")
+        shapes = sorted({st for st in states if st is not None})
+        if len(shapes) > 1:
+            raise ValueError(f"one state shape a model, got {shapes}")
         sizes = sorted({w for w in windows if w is not None})
         if len(sizes) > 1:
             raise ValueError(f"one window size a model, got {sizes}")
         self.kinds: list[_Kind] = []
         full = tuple(i for i, w in enumerate(windows)
-                     if w is None and not latent[i])
+                     if w is None and not latent[i] and states[i] is None)
         if full:
             self.kinds.append(_Kind(
                 full, None, self.max_blocks_per_seq, self.num_blocks
@@ -222,6 +268,14 @@ class BlockKVCache:
             self.kinds.append(_Kind(
                 tuple(i for i, x in enumerate(latent) if x), None,
                 self.max_blocks_per_seq, self.num_blocks, latent=True,
+            ))
+        if shapes:
+            # One entry a sequence the token-keeping pools hold at full
+            # length (the engine's slots), and the trash entry.
+            sequences = (self.num_blocks - 1) // self.max_blocks_per_seq
+            self.kinds.append(_Kind(
+                tuple(i for i, st in enumerate(states) if st is not None),
+                None, 1, 1 + sequences, state=shapes[0],
             ))
         # Per layer: (its kind, its index among that kind's layers).
         where = {
@@ -284,9 +338,12 @@ class BlockKVCache:
 
     def blocks_for(self, tokens: int, kind: int = 0) -> int:
         """Blocks a sequence of ``tokens`` positions holds in ``kind``:
-        all of them, or a window kind's ring at most."""
+        all of them, a window kind's ring at most, or a state kind's one
+        entry whatever the length."""
         need = blocks_for_tokens(tokens, self.block_size)
         k = self.kinds[kind]
+        if k.state is not None:
+            return 1
         return need if k.window is None else min(need, k.entries)
 
     def can_alloc(self, tokens: int) -> bool:
@@ -353,15 +410,50 @@ class BlockKVCache:
         """Per kind, ``[layers, blocks, block_size, heads * head_dim]``
         (a latent kind: its row, padded with zeros to whole 128-lane tiles,
         which is what the row costs on the chip whoever pads it: the
-        decode kernel reads the pool in place only so) — the one
+        decode kernel reads the pool in place only so; a state kind: its
+        STATE pool ``[layers, entries, d_state, heads * head_dim]``, the
+        tail pool beside it ``[layers, entries, (d_conv - 1) * conv_dim]``)
+        — the one
         statement of the pools' layout (the prefill's and the decode's
         ``kv_write``, the decode kernel and :attr:`pool_bytes` follow)."""
         width = self.num_heads * self.head_dim
         return [
+            (k.layers, k.num_blocks, k.state[0][2],
+             k.state[0][0] * k.state[0][1]) if k.state is not None else
             (k.layers, k.num_blocks, self.block_size,
              -(-width // _LANES) * _LANES if k.latent else width)
             for k in self.kinds
         ]
+
+    @property
+    def state_kind(self) -> int | None:
+        """Which of :attr:`kinds` keeps states (None: no such layer)."""
+        return next((at for at, kind in enumerate(self.kinds)
+                     if kind.state is not None), None)
+
+    @property
+    def tail_width(self) -> int:
+        """A sequence's convolution tail of one state layer, its ``d_conv
+        - 1`` columns end to end in ONE row of the tail pool (rows of
+        three columns are gathered and scattered through a copy of the
+        whole pool a layer; 0 without state layers)."""
+        import math
+
+        at = self.state_kind
+        return 0 if at is None else math.prod(self.kinds[at].state[1])
+
+    @property
+    def state_entry_bytes(self) -> int:
+        """What ONE sequence's entry holds over all the state layers:
+        the float32 states and the tails (0 without state layers)."""
+        import math
+
+        at = self.state_kind
+        if at is None:
+            return 0
+        kind = self.kinds[at]
+        return kind.layers * (
+            4 * math.prod(kind.state[0]) + self._itemsize() * self.tail_width)
 
     @property
     def pool_shape(self) -> tuple[int, ...]:
@@ -371,17 +463,23 @@ class BlockKVCache:
     @property
     def pool_bytes(self) -> int:
         """Byte footprint of ALL pools (K and V of every kind; a latent
-        kind's one pool)."""
+        kind's one pool; a state kind's float32 states and its tails)."""
+        import numpy as np
+
+        itemsize = self._itemsize()
+        return sum(
+            kind.num_blocks * self.state_entry_bytes if kind.state is not None
+            else itemsize * (1 if kind.latent else 2) * int(np.prod(shape))
+            for kind, shape in zip(self.kinds, self.pool_shapes)
+        )
+
+    def _itemsize(self) -> int:
         import numpy as np
 
         import jax.numpy as jnp
 
-        dtype = self._dtype if self._dtype is not None else jnp.float32
-        itemsize = np.dtype(dtype).itemsize
-        return itemsize * sum(
-            (1 if kind.latent else 2) * int(np.prod(shape))
-            for kind, shape in zip(self.kinds, self.pool_shapes)
-        )
+        return np.dtype(
+            self._dtype if self._dtype is not None else jnp.float32).itemsize
 
     def _ensure_pools(self) -> None:
         if self.kinds[0].k_pool is None:
@@ -389,13 +487,18 @@ class BlockKVCache:
 
             dtype = self._dtype if self._dtype is not None else jnp.float32
             for kind, shape in zip(self.kinds, self.pool_shapes):
+                if kind.state is not None:
+                    kind.k_pool = jnp.zeros(shape, jnp.float32)
+                    kind.v_pool = jnp.zeros(
+                        (*shape[:2], self.tail_width), dtype)
+                    continue
                 kind.k_pool = jnp.zeros(shape, dtype)
                 kind.v_pool = None if kind.latent else jnp.zeros(shape, dtype)
 
     @property
     def k_pools(self) -> tuple:
-        """The K pools (a latent kind's rows), one a kind: what the
-        engine's steps take (and donate) and hand back."""
+        """The K pools (a latent kind's rows, a state kind's states), one
+        a kind: what the engine's steps take (and donate) and hand back."""
         self._ensure_pools()
         return tuple(k.k_pool for k in self.kinds)
 
@@ -406,8 +509,9 @@ class BlockKVCache:
 
     @property
     def v_pools(self) -> tuple:
-        """The V pools, one a kind; None for a latent kind, which has
-        none (an empty leaf wherever the tuple travels)."""
+        """The V pools, one a kind (a state kind's convolution tails);
+        None for a latent kind, which has none (an empty leaf wherever the
+        tuple travels)."""
         self._ensure_pools()
         return tuple(k.v_pool for k in self.kinds)
 

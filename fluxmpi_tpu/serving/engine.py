@@ -47,6 +47,19 @@ ABSORBED queries to
 which reads each live block once as key and as value. The layer type
 decides; no option does.
 
+A model whose layers keep a **state** (``cache_layers()`` says
+``("state", state shape, tail shape)``: Mamba-2) is served by the same two
+programs over the cache's state kind, one pool entry a SEQUENCE: the
+prefill runs the recurrence over the padded prompt as a chunked scan
+(positions past the prompt's length take a step of 0, so the state after
+the bucket is the state after the prompt) and overwrites the request's
+entry whole with that state and the last ``d_conv - 1`` real inputs of
+the convolution; the decode step takes one convolution step from the tail
+and moves the LIVE slots' states where they lie
+(:func:`fluxmpi_tpu.ops.ssm.ssm_state_update`: idle slots' entries are
+neither read nor written). Admission takes one entry a request whatever
+its length.
+
 The decode loop is **host-driven** (``lax.scan``-free) with **one tick
 in flight**: an iteration dispatches tick N+1 from what the host knows
 before tick N's tokens arrive (positions, tables, which slots N finishes
@@ -445,11 +458,13 @@ class _Tick:
         self.riders = riders
 
 
-def _cache_layers(model) -> tuple[tuple[int | None, int, int | None], ...]:
+def _cache_layers(model) -> tuple[tuple, ...]:
     """Per layer ``(kv_heads, head_dim, window)``: what the model says it
     keeps of a sequence (``cache_layers()``, the protocol of
     :class:`~fluxmpi_tpu.models.DecoderLM`; ``kv_heads`` None: a latent
-    layer, ONE row of ``head_dim`` a token and no V), else
+    layer, ONE row of ``head_dim`` a token and no V; ``("state", state
+    shape, tail shape)``: a layer that keeps one recurrent state and one
+    convolution tail a SEQUENCE), else
     :class:`~fluxmpi_tpu.models.TransformerLM`'s ``num_heads`` heads of
     ``d_model // num_heads`` over the whole context."""
     layers = getattr(model, "cache_layers", None)
@@ -486,8 +501,11 @@ class _PagedDecodeAttention:
     kind (:attr:`BlockKVCache.kinds`) share a pool and a table; a window
     kind's table is a ring. A latent layer calls :meth:`latent` instead:
     its one row a token goes into its kind's one pool, and the absorbed
-    queries attend the rows through the same tables. The step reads the
-    updated pools back from :attr:`k_pools` / :attr:`v_pools`."""
+    queries attend the rows through the same tables. A state layer
+    (Mamba-2) calls :meth:`conv_tail` and then :meth:`state_update`: its
+    kind's pools hold one state and one convolution tail a sequence, and
+    the update moves the live slots' states where they lie. The step
+    reads the updated pools back from :attr:`k_pools` / :attr:`v_pools`."""
 
     # What a layer asks before it chooses its form: this function attends
     # a cache, not the call's own tokens.
@@ -518,6 +536,48 @@ class _PagedDecodeAttention:
         )
         self.kernel = kernel
         self.layer = 0
+        # A state kind's table is one entry wide: each slot's pool entry
+        # (the trash entry: an idle slot), and the live ones compacted
+        # once for every layer's update.
+        self.entries = self.live = None
+        at = cache.state_kind
+        if at is not None:
+            from ..ops.ssm import live_entries
+
+            self.entries = tables[at][:, 0]
+            self.live = live_entries(self.entries)
+            self.tail_shape = cache.kinds[at].state[1]
+
+    def conv_tail(self):
+        """A state layer's first call: its slots' convolution tails
+        ``[slots, d_conv - 1, conv_dim]`` as the pool holds them."""
+        kind, layer = self.layer_kind[self.layer]
+        rows = self.v_pools[kind][layer, self.entries]
+        return rows.reshape(rows.shape[0], *self.tail_shape)
+
+    def state_update(self, tail, x, step, decay, b, c):
+        """A state layer's second call: the new ``tail`` into its pool,
+        and the slots' states moved one token where they lie (``x``
+        ``[slots, heads, head_dim]``, ``step`` and ``decay`` ``[slots,
+        heads]``, ``b`` and ``c`` ``[slots, d_state]``); ``H_t C_t``
+        ``[slots, heads, head_dim]`` back, zero for idle slots."""
+        import jax
+
+        from ..ops.ssm import ssm_state_update
+
+        kind, layer = self.layer_kind[self.layer]
+        self.layer += 1
+        with jax.named_scope("state_write"):
+            tails = self.v_pools[kind]
+            self.v_pools[kind] = tails.at[layer, self.entries].set(
+                tail.reshape(tail.shape[0], -1).astype(tails.dtype))
+        # The update chooses its own form from the backend and the
+        # pool's shape: the kernel on a TPU, its plain twin elsewhere.
+        out, self.k_pools[kind] = ssm_state_update(
+            self.k_pools[kind], self.entries, x, step, decay, b, c,
+            layer=layer, live=self.live,
+        )
+        return out
 
     def __call__(self, query, key, value):
         import jax
@@ -590,13 +650,25 @@ class _PrefillAttention:
     (within the layer's window; the flash kernels with ``kernel``), and
     each layer's keys and values kept for the pool's ``kv_write``; of a
     latent layer, which rebuilt ``key`` and ``value`` from its ``row``,
-    the row alone."""
+    the row alone; of a state layer (:meth:`keep_state`) the convolution
+    tail and the state after the prompt's last real token."""
 
     def __init__(self, windows, kernel: bool):
         self.windows = windows
         self.kernel = kernel
+        # One entry a layer, in layer order; a state layer's are None.
         self.keys: list = []
         self.values: list = []
+        # Of the state layers, in their order.
+        self.tails: list = []
+        self.states: list = []
+
+    def keep_state(self, tail, state):
+        """A state layer's call: what the cache keeps of the sequence."""
+        self.keys.append(None)
+        self.values.append(None)
+        self.tails.append(tail)
+        self.states.append(state)
 
     def __call__(self, query, key, value, row=None):
         import jax
@@ -753,23 +825,33 @@ class InferenceEngine:
         # ``token_mask`` protocol; else it is TransformerLM-shaped.
         self._protocol = hasattr(model, "cache_layers")
         layers = _cache_layers(model)
-        if len({(heads, dim) for heads, dim, _ in layers}) != 1:
-            shapes = sorted({(h, d) for h, d, _ in layers}, key=str)
+        # Layers that keep a state a sequence, and those that keep rows a
+        # token (K/V heads or a latent row, of one shape a model).
+        states = [layer[1:] if layer[0] == "state" else None
+                  for layer in layers]
+        rows = [layer for layer, state in zip(layers, states)
+                if state is None]
+        if len({(heads, dim) for heads, dim, _ in rows}) > 1:
+            shapes = sorted({(h, d) for h, d, _ in rows}, key=str)
             raise ValueError(
                 f"every layer must cache K/V heads (or a latent row) of "
                 f"one shape; got {shapes}"
             )
+        heads, dim, _ = rows[0] if rows else (1, 1, None)
         self.cache = BlockKVCache(
             num_layers=len(layers),
-            num_heads=layers[0][0] or 1,
-            head_dim=layers[0][1],
+            num_heads=heads or 1,
+            head_dim=dim,
             num_blocks=nb,
             block_size=self.block_size,
             max_blocks_per_seq=self.max_blocks_per_seq,
             # The attention sublayer computes K and V in the model's dtype.
             dtype=model.dtype,
-            layer_windows=[window for _, _, window in layers],
-            layer_latent=[heads is None for heads, _, _ in layers],
+            layer_windows=[None if state else layer[2]
+                           for layer, state in zip(layers, states)],
+            layer_latent=[state is None and layer[0] is None
+                          for layer, state in zip(layers, states)],
+            layer_state=states,
         )
         if check_memory:
             fits, detail = self.cache.fits_device()
@@ -817,6 +899,11 @@ class InferenceEngine:
         self._kv_blocks_full = 0
         self._kv_blocks_window = 0
         self._kv_blocks_uniform = 0
+        # A model with state layers: the states the decode ticks moved
+        # (the live slots') and the entries the state pool holds, both
+        # summed over decode ticks.
+        self._states_live = 0
+        self._states_held = 0
         # Routed experts (a model with expert layers): (token, expert)
         # pairs, (layer, expert) cells with at least one, and cells in
         # all, summed over decode ticks.
@@ -994,12 +1081,18 @@ class InferenceEngine:
                     head_at=(length - 1)[None],
                     token_mask=(jnp.arange(tokens.shape[0]) < length)[None],
                 )[0]
-                # A latent layer keeps rows and no values (every layer is
+                # The layers that keep rows a token, in layer order. A
+                # latent layer keeps rows and no values (such layers are
                 # of one shape: __init__).
-                k = jnp.stack(attend.keys)
-                v = (None if attend.values[0] is None
-                     else jnp.stack(attend.values))
+                stacked = [i for i, key in enumerate(attend.keys)
+                           if key is not None]
+                k = v = None
+                if stacked:
+                    k = jnp.stack([attend.keys[i] for i in stacked])
+                    if attend.values[stacked[0]] is not None:
+                        v = jnp.stack([attend.values[i] for i in stacked])
             else:
+                stacked = list(range(cache.num_layers))
                 with attention_scope("prefill_attention"):
                     k, v, logits = prefill_kv(
                         model, params, tokens[None],
@@ -1008,19 +1101,41 @@ class InferenceEngine:
                 last = logits[0]
             with jax.named_scope("kv_write"):
                 # [layers, bucket, heads * head_dim]: the pool's row.
-                k = k[:, 0].reshape(k.shape[0], k.shape[2], -1)
+                if k is not None:
+                    k = k[:, 0].reshape(k.shape[0], k.shape[2], -1)
                 if v is not None:
                     v = v[:, 0].reshape(v.shape[0], v.shape[2], -1)
                 k_pools, v_pools = list(k_pools), list(v_pools)
                 for i, kind in enumerate(cache.kinds):
-                    # Every layer of the only kind, or this kind's.
-                    mine = (slice(None) if len(cache.kinds) == 1
-                            else np.asarray(kind.layer_ids))
+                    if kind.state is not None:
+                        continue
+                    # Every stacked layer (the only such kind), or this
+                    # kind's among them.
+                    mine = (slice(None)
+                            if len(kind.layer_ids) == len(stacked)
+                            else np.asarray([stacked.index(layer)
+                                             for layer in kind.layer_ids]))
                     k_pools[i] = write(k_pools[i], k[mine], tables[i],
                                        length, kind.window)
                     if v is not None:
                         v_pools[i] = write(v_pools[i], v[mine], tables[i],
                                            length, kind.window)
+            at = cache.state_kind
+            if at is not None:
+                # The sequence's one entry, every state layer's, whole:
+                # nothing of the entry's last holder is left.
+                from ..ops.ssm import to_pool_layout
+
+                with jax.named_scope("state_write"):
+                    entry = tables[at][0]
+                    states = [to_pool_layout(s) for s in attend.states]
+                    tails = [t.reshape(1, -1) for t in attend.tails]
+                    for pool, kept in ((k_pools, states), (v_pools, tails)):
+                        rows = jnp.stack(kept)  # [layers, 1, ...]
+                        pool[at] = jax.lax.dynamic_update_slice(
+                            pool[at], rows.astype(pool[at].dtype),
+                            (0, entry) + (0,) * (rows.ndim - 2),
+                        )
             first = jnp.argmax(last, axis=-1).astype(jnp.int32)
             return first, tuple(k_pools), tuple(v_pools)
 
@@ -1227,7 +1342,7 @@ class InferenceEngine:
         with _tracing.span(
             "serve.admit", request_id=req.id, prompt_tokens=plen,
             bucket=bucket, active=self._active,
-        ):
+        ) as admit:
             req.admitted_t = self._clock()
             req.status = ACTIVE
             kinds = range(len(self.cache.kinds))
@@ -1238,9 +1353,15 @@ class InferenceEngine:
             padded = np.zeros((bucket,), np.int32)
             padded[:plen] = req.prompt
             fn = self._prefill_step(bucket)
+            # A model with state layers: the one pool entry the request's
+            # state lives in until its eviction.
+            state = self.cache.state_kind
+            entry = ({} if state is None
+                     else {"state_entry": blocks[state][0]})
+            admit.set_metadata(**entry)
             # Dispatch plus the blocking read of the first token.
             with _tracing.span(
-                "serve.prefill", request_id=req.id, bucket=bucket
+                "serve.prefill", request_id=req.id, bucket=bucket, **entry
             ):
                 first, self.cache.k_pools, self.cache.v_pools = fn(
                     self.params, self.cache.k_pools, self.cache.v_pools,
@@ -1305,7 +1426,7 @@ class InferenceEngine:
             context = 0  # positions it reads: the live slots' lengths
             # With window layers: layer-blocks the slots hold, by kind,
             # and would hold in one pool of one shape.
-            windowed = len(kinds) > 1
+            windowed = any(kind.window is not None for kind in kinds)
             held = [0] * len(kinds)
             uniform = 0
             for i, slot in riders:
@@ -1317,6 +1438,8 @@ class InferenceEngine:
                 context += reach
                 for at, kind in enumerate(kinds):
                     tables[at][i] = slot.tables[at]
+                    if kind.state is not None:
+                        continue  # one entry, no block: counted below
                     if windowed:
                         held[at] += kind.layers * len(slot.blocks[at])
                     if kind.window is not None and reach > kind.window:
@@ -1328,16 +1451,29 @@ class InferenceEngine:
                         blocks = blocks_for_tokens(reach, self.block_size)
                     live += kind.layers * blocks
                 if windowed:
-                    uniform += self.cache.num_layers * len(slot.blocks[0])
-            tabled = self.slots * sum(k.layers * k.entries for k in kinds)
+                    uniform += (self.cache.num_layers
+                                * max(map(len, slot.blocks)))
+            tabled = self.slots * sum(
+                k.layers * k.entries for k in kinds if k.state is None)
             self._kv_blocks_live += live
             self._kv_blocks_tabled += tabled
             self._context_tokens += context
-            prep.set_metadata(live_blocks_pct=100.0 * live / tabled,
-                              context_tokens=context)
+            said = {"context_tokens": context}
+            if tabled:
+                said["live_blocks_pct"] = 100.0 * live / tabled
+            if self.cache.state_kind is not None:
+                # The states this tick's update reads and writes, of
+                # those the pool holds.
+                states = kinds[self.cache.state_kind].num_blocks - 1
+                self._states_live += active
+                self._states_held += states
+                said["live_states_pct"] = 100.0 * active / states
+            prep.set_metadata(**said)
             if windowed:
-                self._kv_blocks_full += held[0]
-                self._kv_blocks_window += held[1]
+                ring = sum(n for n, kind in zip(held, kinds)
+                           if kind.window is not None)
+                self._kv_blocks_full += sum(held) - ring
+                self._kv_blocks_window += ring
                 self._kv_blocks_uniform += uniform
                 if uniform:
                     prep.set_metadata(
@@ -1523,7 +1659,13 @@ class InferenceEngine:
         ``decode_steps``: how often the device went from tick to tick
         with no host turn between); ``tokens_discarded``: tokens a slot
         rode a tick for after its ``eos_token`` had come in the tick
-        before (computed, never delivered). The keys a model has no use
+        before (computed, never delivered). A model with state layers
+        counts ``state_entries_used`` (the states a decode step's update
+        read and wrote: the live slots') and ``state_entries`` (what the
+        state pool holds: the slots), summed over decode steps, and
+        ``state_bytes``, what those updates moved (each live sequence's
+        states and convolution tails of every state layer, read and
+        written). The keys a model has no use
         for stay 0. Plain
         ints the loop keeps anyway; safe to read from another thread."""
         return {
@@ -1544,6 +1686,10 @@ class InferenceEngine:
             "expert_weight_visits": self._expert_weight_visits,
             "decode_steps_overlapped": self._steps_overlapped,
             "tokens_discarded": self._tokens_discarded,
+            "state_entries": self._states_held,
+            "state_entries_used": self._states_live,
+            "state_bytes": 2 * self._states_live
+            * self.cache.state_entry_bytes,
         }
 
     @property

@@ -228,7 +228,8 @@ _ENGINE_COUNTERS = (
     "kv_blocks_live", "kv_blocks_tabled", "context_tokens", "kv_blocks_full",
     "kv_blocks_window", "kv_blocks_uniform", "expert_tokens",
     "experts_touched", "expert_slots", "expert_weight_visits",
-    "decode_steps_overlapped", "tokens_discarded",
+    "decode_steps_overlapped", "tokens_discarded", "state_entries",
+    "state_entries_used", "state_bytes",
 )
 
 
@@ -282,7 +283,8 @@ def test_tiny_serve_cell_engine_surface(world, own_runtime):
 # ---------------------------------------------------------------------------
 
 # What a traced run's last line carries (under ``cpu_rehearsal.``): the
-# span readers both serve cells share, and the expert layer's. On a CPU
+# span readers the serve cells share, the expert layer's and the state
+# kind's. On a CPU
 # the grouped matmul is XLA's ``ragged_dot``, which makes no weight visits
 # to count: ``expert_weight_visits_per_touched`` is then absent, and its
 # reader must say so without raising.
@@ -297,16 +299,21 @@ _TRACED = {
                           "kv_blocks_read_pct", "experts_touched_pct",
                           "expert_load_max_over_mean",
                           "decode_context_tokens", "decode_ticks_in_flight"),
+    # Mamba-2 layers: the state kind's span argument beside the K/V's.
+    "tiny-granite-serve": ("decode_host_ms", "decode_active_slots",
+                           "kv_blocks_read_pct", "experts_touched_pct",
+                           "decode_context_tokens", "decode_ticks_in_flight",
+                           "ssm_states_read_pct"),
 }
 
 
-def test_untraced_sarvam_rehearsal_reports_its_end_to_end_metrics(tmp_path):
+@pytest.mark.parametrize("name", ["tiny-sarvam-serve", "tiny-granite-serve"])
+def test_untraced_rehearsal_reports_its_end_to_end_metrics(name, tmp_path):
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env.update(JAX_PLATFORMS="cpu", HOME=str(tmp_path), TMPDIR=str(tmp_path))
     done = subprocess.run(
         [sys.executable, os.path.join(_BENCH, "run.py"), "--workload",
-         "tiny-sarvam-serve", "--seed", "2147483777", "--seconds", "3",
-         "--trace", "0"],
+         name, "--seed", "2147483777", "--seconds", "3", "--trace", "0"],
         cwd=_REPO, env=env, capture_output=True, text=True, timeout=300,
     )
     assert done.returncode == 0, done.stderr[-4000:]

@@ -321,7 +321,8 @@ def test_engine_stats_agree_with_delivery(tiny_lm):
                           "kv_blocks_window", "kv_blocks_uniform",
                           "expert_tokens", "experts_touched", "expert_slots",
                           "expert_weight_visits", "decode_steps_overlapped",
-                          "tokens_discarded"}
+                          "tokens_discarded", "state_entries",
+                          "state_entries_used", "state_bytes"}
     # Every tick but the two started from an empty engine (the third
     # request waits for a slot) went out behind the one in flight.
     assert stats["decode_steps_overlapped"] == stats["decode_steps"] - 2

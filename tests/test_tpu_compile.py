@@ -473,6 +473,124 @@ def test_sarvam_105b_serving_programs_compile_for_v5e(topo, as_on_tpu):
                 < 14.5e9)
 
 
+def test_ssm_state_update_kernel_compiles_for_v5e(topo):
+    """The decode tick's state update at the published widths (128 heads
+    of 64 over a state of 128; 9 layers x 129 entries of float32 = 4.87
+    GB): one kernel, the pool aliased in and out, no copy of it."""
+    from fluxmpi_tpu.ops.ssm import ssm_state_update
+
+    dev = topo.devices[0]
+    slots, layers, heads, head_dim, d_state = 128, 9, 128, 64, 128
+
+    def update(pool, entries, x, step, decay, b, c):
+        return ssm_state_update(pool, entries, x, step, decay, b, c,
+                                layer=3, interpret=False)
+
+    compiled = jax.jit(update, donate_argnums=(0,)).lower(
+        _sds((layers, slots + 1, d_state, heads * head_dim), jnp.float32, dev),
+        _sds((slots,), jnp.int32, dev),
+        _sds((slots, heads, head_dim), jnp.float32, dev),
+        _sds((slots, heads), jnp.float32, dev),
+        _sds((slots, heads), jnp.float32, dev),
+        _sds((slots, d_state), jnp.float32, dev),
+        _sds((slots, d_state), jnp.float32, dev),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(re.findall(r"%ssm_state_update[.\d]* = ", text)) == 1
+    memory = compiled.memory_analysis()
+    pool_bytes = layers * (slots + 1) * heads * head_dim * d_state * 4
+    assert memory.alias_size_in_bytes >= pool_bytes  # in place
+    assert memory.temp_size_in_bytes < 2**24
+
+
+def test_granite_4_0_h_small_serving_programs_compile_for_v5e(topo, as_on_tpu):
+    """The ``granite-4.0-h-small-serve`` cell's decode program and its
+    LONGEST prefill at the published widths (9 Mamba-2 layers of 128 heads
+    of 64 over a state of 128 around one attention layer of 32 over 8
+    heads of 128, 18 of 72 experts of [4096, 768] held, 25,088 rows of
+    the tied vocabulary; 128 slots x 2,560 positions in 256-blocks): one
+    state-update kernel a Mamba layer and one paged decode kernel, the
+    grouped matmul's kernel three times a layer, the state pool, the
+    tails and the K/V updated in place, and everything under 14.5 GB
+    beside 5.91 GB of bfloat16 weights. (160 slots: 13.74 GB of
+    arguments + 1.28 GB of the prefill's temporaries = 15.0 GB.)"""
+    import json
+
+    from fluxmpi_tpu.serving import InferenceEngine
+
+    prog, configs = _load_config_module("granite.program.py")
+    ref, _ = _load_config_module("granite.reference.py")
+    with open(os.path.join(configs, "granite-4.0-h-small.json"),
+              encoding="utf-8") as f:
+        cfg = json.load(f)
+    with open(os.path.join(os.path.dirname(configs), "workloads",
+                           "granite-4.0-h-small-serve.json"),
+              encoding="utf-8") as f:
+        geometry = json.load(f)["engine"]
+    dev = topo.devices[0]
+    params = jax.tree_util.tree_map(
+        lambda x: _sds(x.shape, x.dtype, dev),
+        jax.eval_shape(
+            lambda key: prog.to_program(ref.make_weights(cfg, key), cfg)[0],
+            jax.random.PRNGKey(0),
+        ),
+    )
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert 5.90e9 < weights < 5.92e9
+    slots, bucket = geometry["slots"], 2048
+    engine = InferenceEngine(
+        prog.build_model(cfg, "naive"), params, attention="flash",
+        slots=slots, block_size=geometry["block_size"],
+        max_len=geometry["max_len"], check_memory=False,
+    )
+    try:
+        cache = engine.cache
+        assert cache.pool_shapes == [(1, 1 + slots * 10, 256, 1024),
+                                     (9, 1 + slots, 128, 8192)]
+        state, tail = 128 * 64 * 128 * 4, 3 * 8448 * 2
+        assert cache.pool_bytes == (
+            2 * (1 + slots * 10) * 256 * 1024 * 2
+            + (1 + slots) * 9 * (state + tail))
+        k_pools = (_sds(cache.pool_shapes[0], jnp.bfloat16, dev),
+                   _sds(cache.pool_shapes[1], jnp.float32, dev))
+        v_pools = (_sds(cache.pool_shapes[0], jnp.bfloat16, dev),
+                   _sds((9, 1 + slots, 3 * 8448), jnp.bfloat16, dev))
+        decode = engine._decode_step.lower(
+            params, k_pools, v_pools,
+            tuple(_sds((slots, k.entries), jnp.int32, dev)
+                  for k in cache.kinds),
+            _sds((slots,), jnp.int32, dev), _sds((slots,), jnp.int32, dev),
+            # prev: the tokens, then 10 expert layers' counts of 18 held.
+            _sds((slots + 10 * 18,), jnp.int32, dev),
+            _sds((slots,), jnp.bool_, dev),
+        ).compile()
+        prefill = engine._prefill_step(bucket).lower(
+            params, k_pools, v_pools, _sds((bucket,), jnp.int32, dev),
+            _sds((), jnp.int32, dev),
+            tuple(_sds((k.entries,), jnp.int32, dev) for k in cache.kinds),
+        ).compile()
+    finally:
+        engine.close()
+    text = decode.as_text()
+    assert text.count("tpu_custom_call") == 9 + 1 + 10 * 3
+    assert "slice-start" not in text  # operands prefetched whole
+    assert len(re.findall(r"%ssm_state_update[.\d]* = ", text)) == 9
+    assert len(re.findall(r"%ragged-dot-gmm[.\d]* = ", text)) == 10 * 3
+    # The prefill: one flash forward (the attention layer), the chunked
+    # scan in plain XLA, no state-update kernel.
+    text = prefill.as_text()
+    assert text.count("tpu_custom_call") == 1 + 10 * 3
+    assert not re.findall(r"%ssm_state_update[.\d]* = ", text)
+    for program, temporaries in ((decode, 2**28), (prefill, 3 * 2**29)):
+        memory = program.memory_analysis()
+        assert memory.temp_size_in_bytes < temporaries
+        assert memory.alias_size_in_bytes >= cache.pool_bytes  # in place
+        assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                < 14.5e9)
+
+
 def _lm_state(cfg, optimizer):
     model = chip_smoke._lm(cfg)
     params = jax.eval_shape(

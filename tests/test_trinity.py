@@ -124,7 +124,7 @@ def test_decoder_config_refuses_what_it_cannot_build():
         DecoderConfig(layer_types=(FULL,), **{**base,
                                               "num_key_value_heads": 3})
     with pytest.raises(ValueError, match="score_func"):
-        DecoderConfig(layer_types=(FULL,), score_func="softmax", **base)
+        DecoderConfig(layer_types=(FULL,), score_func="tanh", **base)
 
 
 # ---------------------------------------------------------------------------
