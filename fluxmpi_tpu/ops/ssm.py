@@ -14,15 +14,20 @@ engine's pool: the recurrence compounds its rounding over a whole answer.
 pool ``[layers, entries, d_state, heads * head_dim]``
 (:mod:`fluxmpi_tpu.serving.cache`: the state kind; entry 0 is the trash
 entry idle slots point at; :func:`to_pool_layout` is the one statement of
-a state's layout there). ``entries[slot]`` names each batch slot's
+a state's layout there), and beside it, entry for entry, the tail pool
+``[layers, entries, tiles, 128]``: what the layer's convolution still
+needs of the sequence, its last ``d_conv - 1`` pre-convolution columns
+(:func:`tail_to_pool_layout`). ``entries[slot]`` names each batch slot's
 entry. On a TPU a Pallas kernel (``name="ssm_state_update"``) walks the
 LIVE slots only (:func:`live_entries`: their pool entries and rows,
 compacted, scalar prefetched; the grid's steps past the last live slot
-hold its block and do nothing): each live state is read once, moved, read
-out against ``C`` and written back where it lay, the pool aliased in and
-out, so an idle slot's state is neither read nor written. Left to XLA the
-update streams every entry. Anywhere else than a TPU the reference's
-arithmetic runs (a gather, the update, a scatter that drops idle slots).
+hold its blocks and do nothing): each live state is read once, moved, read
+out against ``C`` and written back where it lay, and the slot's new tail
+is copied over its old one, both pools aliased in and out, so an idle
+slot's state and tail are neither read nor written. Left to XLA the
+update streams every entry and a scatter of tails pays 1.3 us a row,
+idle or live. Anywhere else than a TPU the reference's arithmetic runs (a
+gather, the update, two scatters that drop idle slots).
 
 A state lies in the pool TRANSPOSED, ``H^T`` of all heads side by side:
 ``[d_state, heads * head_dim]``, ``d_state`` on the sublanes and a head's
@@ -33,7 +38,11 @@ and ``C`` have to become columns: once a state, not once a head. (The
 first kernel kept ``[heads, head_dim, d_state]`` and paid a lane
 broadcast and a lane reduction a head: 23 us a state where its two
 transfers take 10.) Tiles come from the shapes alone: one sequence's
-state of one layer a grid step, walked 128 lanes at a time.
+state of one layer a grid step, walked 128 lanes at a time. A tail lies
+in its pool as whole 128-lane tiles, its columns end to end: the block
+the kernel writes is the layout the pool is held in, and nothing between
+the pool and the kernel changes a tiling (a pool of ``[d_conv - 1,
+conv_dim]`` rows was copied whole a layer a tick).
 
 **The prefill** (:func:`ssd_chunk_scan`): the same recurrence over a
 whole prompt in chunks of ``chunk`` tokens, as matmuls. With ``l_t`` the
@@ -51,13 +60,14 @@ prefill's operations.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 
 __all__ = ["from_pool_layout", "live_entries", "ssd_chunk_scan",
            "ssm_state_update", "ssm_state_update_reference",
-           "to_pool_layout"]
+           "tail_from_pool_layout", "tail_to_pool_layout", "to_pool_layout"]
 
 TRASH_ENTRY = 0
 # The chip's compiler names a Mosaic call's instruction by the last
@@ -85,6 +95,26 @@ def from_pool_layout(state, heads: int):
         *lead, heads, inner // heads, d_state)
 
 
+def tail_to_pool_layout(tail):
+    """``[..., d_conv - 1, conv_dim]`` (a sequence's last pre-convolution
+    columns, oldest first) as the tail pool holds it: ``[..., tiles,
+    128]``, the columns end to end, padded with zeros to whole 128-lane
+    tiles."""
+    *lead, taps, width = tail.shape
+    flat = jnp.pad(tail.reshape(*lead, taps * width),
+                   [(0, 0)] * len(lead) + [(0, -(taps * width) % _LANES)])
+    return flat.reshape(*lead, -1, _LANES)
+
+
+def tail_from_pool_layout(tail, shape):
+    """The inverse of :func:`tail_to_pool_layout`; ``shape`` is
+    ``(d_conv - 1, conv_dim)``."""
+    *lead, tiles, lanes = tail.shape
+    taps, width = shape
+    return tail.reshape(*lead, tiles * lanes)[..., :taps * width].reshape(
+        *lead, taps, width)
+
+
 def live_entries(entries):
     """``(ids, rows, count)`` of the slots whose pool entry is not the
     trash entry, compacted in slot order: ``ids[i]`` the i-th live slot's
@@ -101,11 +131,14 @@ def live_entries(entries):
     return entries[rows], rows, count[None]
 
 
-def _check_update_shapes(pool, entries, x, dt, a, b, c, layer):
-    if pool.ndim != 4 or x.ndim != 3:
+def _check_update_shapes(pool, tail_pool, entries, tail, x, dt, a, b, c,
+                         layer):
+    if pool.ndim != 4 or tail_pool.ndim != 4 or x.ndim != 3:
         raise ValueError(
-            f"a state pool is [layers, entries, d_state, heads * head_dim] "
-            f"and x [slots, heads, head_dim]; got {pool.shape} and {x.shape}"
+            f"a state pool is [layers, entries, d_state, heads * head_dim], "
+            f"its tail pool [layers, entries, tiles, {_LANES}] and x "
+            f"[slots, heads, head_dim]; got {pool.shape}, {tail_pool.shape} "
+            f"and {x.shape}"
         )
     _, _, d_state, inner = pool.shape
     slots, heads = entries.shape[0], x.shape[1]
@@ -117,6 +150,13 @@ def _check_update_shapes(pool, entries, x, dt, a, b, c, layer):
         raise ValueError(
             f"for {slots} slots over a pool {pool.shape}: expected {want}, "
             f"got {got}"
+        )
+    held = (*pool.shape[:2], -(-math.prod(tail.shape[1:]) // _LANES), _LANES)
+    if tail.ndim != 3 or tail.shape[0] != slots or tail_pool.shape != held:
+        raise ValueError(
+            f"for {slots} slots over a pool {pool.shape}: expected tails "
+            f"[{slots}, d_conv - 1, conv_dim] and their pool {held}, got "
+            f"{tail.shape} and {tail_pool.shape}"
         )
     if not 0 <= layer < pool.shape[0]:
         raise ValueError(
@@ -133,15 +173,16 @@ def _rows(x, dt, a):
     return jnp.repeat(a.astype(f32), head_dim, axis=1), stepped
 
 
-def ssm_state_update_reference(pool, entries, x, dt, a, b, c, *,
-                               layer: int = 0):
+def ssm_state_update_reference(pool, tail_pool, entries, tail, x, dt, a, b,
+                               c, *, layer: int = 0):
     """The contract in plain ``jax.numpy``: gather the slots' states of
     one layer, ``H <- a H + (dt x) B^T``, ``y = H C`` (float32, from the
     state before it is rounded to the pool's dtype), scatter the live
-    slots' back. Returns ``(y [slots, heads, head_dim] float32, pool)``;
-    an idle slot's ``y`` is zero and no entry but the live slots' is
-    written."""
-    _check_update_shapes(pool, entries, x, dt, a, b, c, layer)
+    slots' states and their new tails back. Returns ``(y [slots, heads,
+    head_dim] float32, pool, tail_pool)``; an idle slot's ``y`` is zero
+    and no entry but the live slots' is written, in either pool."""
+    _check_update_shapes(pool, tail_pool, entries, tail, x, dt, a, b, c,
+                         layer)
     f32 = jnp.float32
     live = entries != TRASH_ENTRY
     slots, heads, head_dim = x.shape
@@ -150,16 +191,18 @@ def ssm_state_update_reference(pool, entries, x, dt, a, b, c, *,
     moved = (state * decay[:, None, :]
              + b.astype(f32)[:, :, None] * stepped[:, None, :])
     y = jnp.sum(moved * c.astype(f32)[:, :, None], axis=1)
-    # Idle slots are sent past the pool and dropped: the trash entry, and
+    # Idle slots are sent past the pools and dropped: the trash entry, and
     # every entry no live slot names, stays bit for bit what it was.
     where = jnp.where(live, entries, pool.shape[1])
     pool = pool.at[layer, where].set(moved.astype(pool.dtype), mode="drop")
+    tail_pool = tail_pool.at[layer, where].set(
+        tail_to_pool_layout(tail).astype(tail_pool.dtype), mode="drop")
     return jnp.where(live[:, None, None],
-                     y.reshape(slots, heads, head_dim), 0.0), pool
+                     y.reshape(slots, heads, head_dim), 0.0), pool, tail_pool
 
 
-def _update_kernel(ids_ref, rows_ref, count_ref, s_ref, a_ref, x_ref,
-                   b_ref, c_ref, o_ref, y_ref):
+def _update_kernel(ids_ref, rows_ref, count_ref, s_ref, t_ref, a_ref, x_ref,
+                   b_ref, c_ref, n_ref, o_ref, w_ref, y_ref):
     del ids_ref, rows_ref  # read by the index maps
     from jax.experimental import pallas as pl
 
@@ -183,11 +226,13 @@ def _update_kernel(ids_ref, rows_ref, count_ref, s_ref, a_ref, x_ref,
             o_ref[:, lanes] = moved.astype(o_ref.dtype)
             y_ref[tile:tile + 1, :] = jnp.sum(moved * c, axis=0,
                                               keepdims=True)
+        w_ref[...] = n_ref[...]  # the slot's new tail over its old one
 
     @pl.when((step == 0) & (count == 0))
     def _nothing_live():
         # The blocks held are the trash entry's: written back as read.
         o_ref[...] = s_ref[...]
+        w_ref[...] = t_ref[...]
 
 
 @functools.lru_cache(maxsize=None)
@@ -197,19 +242,31 @@ def _jitted(layer: int, interpret: bool):
 
     from ..parallel._compat import pallas_tpu_compiler_params
 
-    def ssm_state_update(pool, ids, rows, count, x, dt, a, b, c):
+    def ssm_state_update(pool, tail_pool, ids, rows, count, tail, x, dt, a,
+                         b, c):
         slots, heads, head_dim = x.shape
         _, _, d_state, inner = pool.shape
         f32 = jnp.float32
         tiles = inner // _LANES
 
-        def state_index(i, ids_ref, rows_ref, count_ref):
+        def entry_index(i, ids_ref, rows_ref, count_ref):
             return layer, ids_ref[i], 0, 0
 
         def row_index(i, ids_ref, rows_ref, count_ref):
             return rows_ref[i], 0, 0
 
-        state_block = pl.BlockSpec((None, None, d_state, inner), state_index)
+        def trash_index(i, ids_ref, rows_ref, count_ref):
+            return layer, TRASH_ENTRY, 0, 0
+
+        state_block = pl.BlockSpec((None, None, d_state, inner), entry_index)
+        # One sequence's tail of one layer, whole, as the pool holds it.
+        # No old tail is read but the trash entry's, once a call (the
+        # same block at every step): what is written back as read where
+        # nothing is live.
+        held = (None, None, *tail_pool.shape[2:])
+        old_tail = pl.BlockSpec(held, trash_index)
+        tail_block = pl.BlockSpec(held, entry_index)
+        new_tail = pl.BlockSpec(held[1:], row_index)
         # A slot's row of ``inner`` numbers as ``[inner / 128, 128]``:
         # whole tiles (a ``[1, inner]`` block is padded to eight rows on
         # its way: 9% of the state's own bytes), lane tile ``j`` of the
@@ -217,20 +274,23 @@ def _jitted(layer: int, interpret: bool):
         tiled = pl.BlockSpec((None, tiles, _LANES), row_index)
         narrow = pl.BlockSpec((None, 1, d_state), row_index)
         decay, stepped = _rows(x, dt, a)
-        pool, y = pl.pallas_call(
+        pool, tail_pool, y = pl.pallas_call(
             _update_kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,
                 grid=(slots,),
-                in_specs=[state_block, tiled, tiled, narrow, narrow],
-                out_specs=[state_block, tiled],
+                in_specs=[state_block, old_tail, tiled, tiled, narrow,
+                          narrow, new_tail],
+                out_specs=[state_block, tail_block, tiled],
             ),
             out_shape=[
                 jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+                jax.ShapeDtypeStruct(tail_pool.shape, tail_pool.dtype),
                 jax.ShapeDtypeStruct((slots, tiles, _LANES), f32),
             ],
-            # Operand 3 (after the three prefetched scalars) is the pool.
-            input_output_aliases={3: 0},
+            # Operands 3 and 4 (after the three prefetched scalars) are
+            # the two pools.
+            input_output_aliases={3: 0, 4: 1},
             compiler_params=pallas_tpu_compiler_params(
                 dimension_semantics=("arbitrary",),
                 vmem_limit_bytes=_VMEM_LIMIT_BYTES,
@@ -238,45 +298,54 @@ def _jitted(layer: int, interpret: bool):
             interpret=interpret,
             name=_KERNEL_NAME,
         )(
-            ids, rows, count, pool,
+            ids, rows, count, pool, tail_pool,
             decay.reshape(slots, tiles, _LANES),
             stepped.reshape(slots, tiles, _LANES),
             b.astype(f32)[:, None, :], c.astype(f32)[:, None, :],
+            tail_to_pool_layout(tail).astype(tail_pool.dtype),
         )
         # A slot the walk never reached left its row of ``y`` unwritten.
         reached = jnp.zeros((slots,), bool).at[rows].set(count[0] > 0)
         return jnp.where(reached[:, None, None],
-                         y.reshape(slots, heads, head_dim), 0.0), pool
+                         y.reshape(slots, heads, head_dim), 0.0
+                         ), pool, tail_pool
 
     return jax.jit(ssm_state_update)
 
 
-def ssm_state_update(pool, entries, x, dt, a, b, c, *, layer: int = 0,
-                     live=None, interpret: bool | None = None):
-    """One token a slot: ``H <- a H + (dt x) B^T`` and ``y = H C`` for the
-    LIVE slots' states of one layer of ``pool`` ``[layers, entries,
-    d_state, heads * head_dim]``, in place; see the module docstring.
+def ssm_state_update(pool, tail_pool, entries, tail, x, dt, a, b, c, *,
+                     layer: int = 0, live=None,
+                     interpret: bool | None = None):
+    """One token a slot, for the LIVE slots of one layer, in place: the
+    state ``H <- a H + (dt x) B^T`` in ``pool`` ``[layers, entries,
+    d_state, heads * head_dim]`` with ``y = H C`` read out, and the new
+    convolution tail over the old one in ``tail_pool`` ``[layers, entries,
+    tiles, 128]`` (:func:`tail_to_pool_layout`); see the module docstring.
     ``entries`` ``[slots]`` int32 names each slot's pool entry (0, the
-    trash entry: an idle slot); ``x`` ``[slots, heads, head_dim]``, ``dt``
-    and ``a`` ``[slots, heads]``, ``b`` and ``c`` ``[slots, d_state]``.
-    ``live``: :func:`live_entries` of ``entries`` where the caller has it
-    (one call a tick for all layers). Returns ``(y [slots, heads,
-    head_dim] float32, pool)``, ``y`` zero for idle slots. In place where
-    the caller's program donates the pool (the engine's decode step).
-    ``interpret=None``: the compiled kernel on a TPU backend (states of
-    whole 128-lane tiles), the reference's arithmetic elsewhere;
-    ``True``: the kernel in Pallas interpret mode (the tests)."""
-    _check_update_shapes(pool, entries, x, dt, a, b, c, layer)
+    trash entry: an idle slot); ``tail`` ``[slots, d_conv - 1,
+    conv_dim]`` (rounded to the tail pool's dtype), ``x`` ``[slots,
+    heads, head_dim]``, ``dt`` and ``a`` ``[slots, heads]``, ``b`` and
+    ``c`` ``[slots, d_state]``. ``live``: :func:`live_entries` of
+    ``entries`` where the caller has it (one call a tick for all layers).
+    Returns ``(y [slots, heads, head_dim] float32, pool, tail_pool)``,
+    ``y`` zero for idle slots, whose entry (the trash entry) is written in
+    neither pool. In place where the caller's program donates the pools
+    (the engine's decode step). ``interpret=None``: the compiled kernel on
+    a TPU backend (states of whole 128-lane tiles), the reference's
+    arithmetic elsewhere; ``True``: the kernel in Pallas interpret mode
+    (the tests)."""
+    _check_update_shapes(pool, tail_pool, entries, tail, x, dt, a, b, c,
+                         layer)
     if interpret is None:
         # The kernel walks whole 128-lane tiles of whole sublane tiles.
         if (jax.default_backend() != "tpu" or pool.shape[3] % _LANES
                 or pool.shape[2] % 8):
             return ssm_state_update_reference(
-                pool, entries, x, dt, a, b, c, layer=layer)
+                pool, tail_pool, entries, tail, x, dt, a, b, c, layer=layer)
         interpret = False
     ids, rows, count = live_entries(entries) if live is None else live
     return _jitted(int(layer), bool(interpret))(
-        pool, ids, rows, count, x, dt, a, b, c)
+        pool, tail_pool, ids, rows, count, tail, x, dt, a, b, c)
 
 
 def ssd_chunk_scan(x, dt, a_rate, b, c, *, chunk: int, initial_state=None):

@@ -65,12 +65,15 @@ the trash entry, its :attr:`BlockKVCache.k_pools` entry is the state pool
 ``[layers, entries, d_state, heads * head_dim]`` (a state transposed, its
 heads side by side: what the update kernel reads in place) and its
 :attr:`BlockKVCache.v_pools` entry the tail pool ``[layers, entries,
-(d_conv - 1) * conv_dim]`` (a tail's columns end to end in one row).
+tiles, 128]`` (a tail's ``d_conv - 1`` columns end to end, padded to whole
+128-lane tiles: the block the same kernel writes a live slot's new tail
+into, :func:`fluxmpi_tpu.ops.ssm.tail_to_pool_layout`).
 Admission is then bounded by STATES where a token-keeping kind bounds it
 by tokens: a Mamba-2 layer of 128 heads of 64 over a state of 128 holds
 4.19 MB a sequence at any length, where a layer of 8 K/V heads of 128
 holds 4 KB a token. The decode tick moves a live
-slot's state where it lies
+slot's state where it lies and writes its new tail over the old, in one
+walk over the live slots
 (:func:`fluxmpi_tpu.ops.ssm.ssm_state_update`); a prefill overwrites an
 admitted sequence's entry whole.
 
@@ -412,10 +415,10 @@ class BlockKVCache:
         which is what the row costs on the chip whoever pads it: the
         decode kernel reads the pool in place only so; a state kind: its
         STATE pool ``[layers, entries, d_state, heads * head_dim]``, the
-        tail pool beside it ``[layers, entries, (d_conv - 1) * conv_dim]``)
-        — the one
+        tail pool beside it ``[layers, entries,`` :attr:`tail_tiles` ``,
+        128]``) — the one
         statement of the pools' layout (the prefill's and the decode's
-        ``kv_write``, the decode kernel and :attr:`pool_bytes` follow)."""
+        ``kv_write``, the decode kernels and :attr:`pool_bytes` follow)."""
         width = self.num_heads * self.head_dim
         return [
             (k.layers, k.num_blocks, k.state[0][2],
@@ -432,20 +435,25 @@ class BlockKVCache:
                      if kind.state is not None), None)
 
     @property
-    def tail_width(self) -> int:
-        """A sequence's convolution tail of one state layer, its ``d_conv
-        - 1`` columns end to end in ONE row of the tail pool (rows of
-        three columns are gathered and scattered through a copy of the
-        whole pool a layer; 0 without state layers)."""
+    def tail_tiles(self) -> int:
+        """The 128-lane tiles a sequence's convolution tail of one state
+        layer fills in the tail pool: its ``d_conv - 1`` columns end to
+        end, padded with zeros to whole tiles (the update kernel's block
+        is one entry's ``[tiles, 128]``, so the pool is held as the kernel
+        writes it; rows of three columns were gathered and scattered
+        through a copy of the whole pool a layer; 0 without state
+        layers)."""
         import math
 
         at = self.state_kind
-        return 0 if at is None else math.prod(self.kinds[at].state[1])
+        return 0 if at is None else -(
+            -math.prod(self.kinds[at].state[1]) // _LANES)
 
     @property
     def state_entry_bytes(self) -> int:
         """What ONE sequence's entry holds over all the state layers:
-        the float32 states and the tails (0 without state layers)."""
+        the float32 states and the tails as the pools hold them (0
+        without state layers)."""
         import math
 
         at = self.state_kind
@@ -453,7 +461,8 @@ class BlockKVCache:
             return 0
         kind = self.kinds[at]
         return kind.layers * (
-            4 * math.prod(kind.state[0]) + self._itemsize() * self.tail_width)
+            4 * math.prod(kind.state[0])
+            + self._itemsize() * self.tail_tiles * _LANES)
 
     @property
     def pool_shape(self) -> tuple[int, ...]:
@@ -490,7 +499,7 @@ class BlockKVCache:
                 if kind.state is not None:
                     kind.k_pool = jnp.zeros(shape, jnp.float32)
                     kind.v_pool = jnp.zeros(
-                        (*shape[:2], self.tail_width), dtype)
+                        (*shape[:2], self.tail_tiles, _LANES), dtype)
                     continue
                 kind.k_pool = jnp.zeros(shape, dtype)
                 kind.v_pool = None if kind.latent else jnp.zeros(shape, dtype)

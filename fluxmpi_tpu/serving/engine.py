@@ -55,10 +55,11 @@ prefill runs the recurrence over the padded prompt as a chunked scan
 the bucket is the state after the prompt) and overwrites the request's
 entry whole with that state and the last ``d_conv - 1`` real inputs of
 the convolution; the decode step takes one convolution step from the tail
-and moves the LIVE slots' states where they lie
+and, in ONE walk over the LIVE slots, moves their states where they lie
+and writes their new tails over the old
 (:func:`fluxmpi_tpu.ops.ssm.ssm_state_update`: idle slots' entries are
-neither read nor written). Admission takes one entry a request whatever
-its length.
+neither read nor written, state or tail). Admission takes one entry a
+request whatever its length.
 
 The decode loop is **host-driven** (``lax.scan``-free) with **one tick
 in flight**: an iteration dispatches tick N+1 from what the host knows
@@ -550,32 +551,30 @@ class _PagedDecodeAttention:
 
     def conv_tail(self):
         """A state layer's first call: its slots' convolution tails
-        ``[slots, d_conv - 1, conv_dim]`` as the pool holds them."""
+        ``[slots, d_conv - 1, conv_dim]`` out of the pool (an idle
+        slot's: the trash entry's, whatever it holds)."""
+        from ..ops.ssm import tail_from_pool_layout
+
         kind, layer = self.layer_kind[self.layer]
-        rows = self.v_pools[kind][layer, self.entries]
-        return rows.reshape(rows.shape[0], *self.tail_shape)
+        return tail_from_pool_layout(
+            self.v_pools[kind][layer, self.entries], self.tail_shape)
 
     def state_update(self, tail, x, step, decay, b, c):
-        """A state layer's second call: the new ``tail`` into its pool,
-        and the slots' states moved one token where they lie (``x``
+        """A state layer's second call: the LIVE slots' states moved one
+        token where they lie and their new ``tail`` ``[slots, d_conv - 1,
+        conv_dim]`` written over the old, in one walk over them (``x``
         ``[slots, heads, head_dim]``, ``step`` and ``decay`` ``[slots,
         heads]``, ``b`` and ``c`` ``[slots, d_state]``); ``H_t C_t``
         ``[slots, heads, head_dim]`` back, zero for idle slots."""
-        import jax
-
         from ..ops.ssm import ssm_state_update
 
         kind, layer = self.layer_kind[self.layer]
         self.layer += 1
-        with jax.named_scope("state_write"):
-            tails = self.v_pools[kind]
-            self.v_pools[kind] = tails.at[layer, self.entries].set(
-                tail.reshape(tail.shape[0], -1).astype(tails.dtype))
         # The update chooses its own form from the backend and the
         # pool's shape: the kernel on a TPU, its plain twin elsewhere.
-        out, self.k_pools[kind] = ssm_state_update(
-            self.k_pools[kind], self.entries, x, step, decay, b, c,
-            layer=layer, live=self.live,
+        out, self.k_pools[kind], self.v_pools[kind] = ssm_state_update(
+            self.k_pools[kind], self.v_pools[kind], self.entries, tail, x,
+            step, decay, b, c, layer=layer, live=self.live,
         )
         return out
 
@@ -1124,12 +1123,12 @@ class InferenceEngine:
             if at is not None:
                 # The sequence's one entry, every state layer's, whole:
                 # nothing of the entry's last holder is left.
-                from ..ops.ssm import to_pool_layout
+                from ..ops.ssm import tail_to_pool_layout, to_pool_layout
 
                 with jax.named_scope("state_write"):
                     entry = tables[at][0]
                     states = [to_pool_layout(s) for s in attend.states]
-                    tails = [t.reshape(1, -1) for t in attend.tails]
+                    tails = [tail_to_pool_layout(t) for t in attend.tails]
                     for pool, kept in ((k_pools, states), (v_pools, tails)):
                         rows = jnp.stack(kept)  # [layers, 1, ...]
                         pool[at] = jax.lax.dynamic_update_slice(

@@ -1,6 +1,7 @@
 """The state-space kernels alone, on the chip: the decode tick's in-place
-state update (``ops/ssm.ssm_state_update``, ms a call and GB/s of state
-moved at several counts of live slots) and the prefill's chunked scan
+update of the live slots' states and convolution tails
+(``ops/ssm.ssm_state_update``, ms a call and GB/s of state moved at
+several counts of live slots) and the prefill's chunked scan
 (``ops/ssm.ssd_chunk_scan``, ms a call and TFLOP/s at the prompt
 buckets), at granite-4.0-h-small's widths. JSON rows.
 
@@ -44,45 +45,51 @@ def main(argv=None) -> int:
 
     if args.tiny:
         slots, layers, heads, head_dim, d_state, chunk = 4, 2, 8, 16, 128, 8
-        lives, buckets, reps = (0, 2, 4), (16, 24), 1
+        lives, buckets, reps, conv_dim = (0, 2, 4), (16, 24), 1, 160
     else:
         slots, layers, heads, head_dim = 128, 9, 128, 64
-        d_state, chunk = 128, 256
+        d_state, chunk, conv_dim = 128, 256, 8448
         lives, buckets, reps = (0, 1, 16, 32, 64, 96, 128), (
             256, 512, 1024, 2048), args.reps
     rows = []
     keys = jax.random.split(jax.random.PRNGKey(0), 8)
     inner = heads * head_dim
-    # The pool is an ARGUMENT, donated and handed back: closed over it
-    # would be a constant of the program.
-    pool = jnp.zeros((layers, slots + 1, d_state, inner), jnp.float32)
+    # The pools are ARGUMENTS, donated and handed back: closed over they
+    # would be constants of the program.
+    tail = jax.random.normal(keys[4], (slots, 3, conv_dim)).astype(
+        jnp.bfloat16)
+    pools = (
+        jnp.zeros((layers, slots + 1, d_state, inner), jnp.float32),
+        jnp.zeros((layers, slots + 1,
+                   *ssm.tail_to_pool_layout(tail).shape[1:]), jnp.bfloat16),
+    )
     x = jax.random.normal(keys[0], (slots, heads, head_dim))
     step = jax.nn.softplus(jax.random.normal(keys[1], (slots, heads)) - 3.0)
     decay = jnp.exp(-step * 4.0)
     b = jax.random.normal(keys[2], (slots, d_state))
     c = jax.random.normal(keys[3], (slots, d_state))
 
-    def update(pool, entries, x, step, decay, b, c):
+    def update(pools, entries, tail, x, step, decay, b, c):
         live = ssm.live_entries(entries)
         y = 0.0
         for layer in range(layers):  # one tick: every layer's update
-            out, pool = ssm.ssm_state_update(
-                pool, entries, x, step, decay, b, c, layer=layer, live=live,
-                interpret=True if args.tiny else None)
+            out, *pools = ssm.ssm_state_update(
+                *pools, entries, tail, x, step, decay, b, c, layer=layer,
+                live=live, interpret=True if args.tiny else None)
             y = y + out
-        return y, pool
+        return y, tuple(pools)
 
     tick = jax.jit(update, donate_argnums=(0,))
     state_bytes = heads * head_dim * d_state * 4
     for live in lives:
         entries = jnp.where(jnp.arange(slots) < live,
                             jnp.arange(1, slots + 1), 0).astype(jnp.int32)
-        _, pool = tick(pool, entries, x, step, decay, b, c)
-        jax.block_until_ready(pool)
+        _, pools = tick(pools, entries, tail, x, step, decay, b, c)
+        jax.block_until_ready(pools)
         start = time.perf_counter()
         for _ in range(reps):
-            _, pool = tick(pool, entries, x, step, decay, b, c)
-        jax.block_until_ready(pool)
+            _, pools = tick(pools, entries, tail, x, step, decay, b, c)
+        jax.block_until_ready(pools)
         took = (time.perf_counter() - start) / reps
         rows.append({
             "kernel": "ssm_state_update", "live": live, "slots": slots,
@@ -91,7 +98,7 @@ def main(argv=None) -> int:
             "gb_per_s": live * layers * 2 * state_bytes / took / 1e9,
         })
         print(json.dumps(rows[-1]), flush=True)
-    del pool
+    del pools
     a_rate = -jnp.exp(jnp.linspace(0.0, 2.7, heads))
     scan = jax.jit(lambda x, dt, b, c: ssm.ssd_chunk_scan(
         x, dt, a_rate, b, c, chunk=chunk))

@@ -18,7 +18,8 @@ import jax.numpy as jnp
 
 from fluxmpi_tpu.models import DecoderConfig, ExpertMLP
 from fluxmpi_tpu.models.decoder import MambaMixer
-from fluxmpi_tpu.ops.ssm import from_pool_layout, ssm_state_update_reference
+from fluxmpi_tpu.ops.ssm import (from_pool_layout, ssm_state_update_reference,
+                                 tail_from_pool_layout, tail_to_pool_layout)
 from fluxmpi_tpu.serving import InferenceEngine
 from fluxmpi_tpu.serving.cache import BlockKVCache
 from fluxmpi_tpu.serving.engine import _PagedDecodeAttention
@@ -180,16 +181,21 @@ class _DenseState:
                         config.mamba_d_state)
         self.heads = heads
         self.pool = jnp.zeros((1, 2, n, heads * hd), jnp.float32)
-        self.tail = jnp.zeros((1, config.mamba_d_conv - 1,
-                               config.mamba_conv_dim), jnp.float32)
+        self.tail_shape = (config.mamba_d_conv - 1, config.mamba_conv_dim)
+        self.tails = jnp.zeros(
+            (1, 2, *tail_to_pool_layout(jnp.zeros(self.tail_shape)).shape))
+
+    @property
+    def tail(self):
+        return tail_from_pool_layout(self.tails[0, 1:], self.tail_shape)
 
     def conv_tail(self):
         return self.tail
 
     def state_update(self, tail, x, step, decay, b, c):
-        self.tail = tail
-        y, self.pool = ssm_state_update_reference(
-            self.pool, jnp.ones((1,), jnp.int32), x, step, decay, b, c)
+        y, self.pool, self.tails = ssm_state_update_reference(
+            self.pool, self.tails, jnp.ones((1,), jnp.int32), tail, x, step,
+            decay, b, c)
         return y
 
 
@@ -501,6 +507,110 @@ def test_engine_serves_what_the_reference_puts_first(attention):
         eng.close()
 
 
+def _ref_columns(weights, tokens, cfg):
+    """The reference's full forward (the recurrence one token at a time)
+    once more, keeping what a cache's tails hold the last three of: every
+    Mamba layer's pre-convolution columns. ``(logits [seq, vocab],
+    columns [state layers, seq, conv_dim])``."""
+    *_, inner, conv_dim = ref._sizes(cfg)
+
+    def forward(weights, tokens):
+        x = cfg["embedding_multiplier"] * weights["embed"][tokens].astype(
+            jnp.float32)
+        columns = []
+        for kind, w in zip(cfg["layer_types"], weights["layers"]):
+            if kind == "mamba":
+                u = ref._rms_norm(x, w["norm_in"], cfg["rms_norm_eps"])
+                columns.append(ref._mm("td,dn->tn", u, w["w_in"], "f32")[
+                    :, inner:inner + conv_dim])
+            h, u = ref.mix(x, w, cfg, kind, "f32")
+            x = h + cfg["residual_multiplier"] * ref.feed_forward(
+                u, w, cfg, "f32")
+        return ref.head(x, weights, cfg), jnp.stack(columns)
+
+    return jax.jit(forward)(weights, tokens)
+
+
+# (prompt, answer, the iteration it is submitted at): three slots, so the
+# later ones wait for an eviction and join into the slot, the blocks and
+# the state entry a finished request has just left, while others decode.
+ARRIVALS = ((5, 24, 0), (33, 9, 0), (2, 40, 0), (17, 30, 3), (BLOCK, 6, 10),
+            (1, 22, 12), (40, 12, 30), (3, 28, 44))
+
+
+def test_64_ticks_of_joins_and_evictions_keep_every_live_tail():
+    """The engine driven by hand: after EVERY iteration each live slot's
+    entry of the tail pool holds the last three pre-convolution columns of
+    exactly the tokens its sequence has fed, in every state layer (a tail
+    written to another slot's entry, or one left by the entry's last
+    holder, is not that), the trash entry is never written, and the
+    served tokens are the ones the reference's plain forward puts first."""
+    cfg = _cfg()
+    model, variables, weights = _model_and_weights(cfg)
+    eng = InferenceEngine(model, variables, attention="naive", slots=3,
+                          block_size=BLOCK, max_len=128, check_memory=False)
+    try:
+        at = eng.cache.state_kind
+        shape = eng.cache.kinds[at].state[1]
+        assert shape == (3, 160) and eng.cache.tail_tiles == 4
+        assert eng.cache.v_pools[at].shape == (3, 4, 4, 128)
+        # Noise where zeros were: what a prefill does not overwrite whole,
+        # or a tick writes where no live slot points, shows.
+        eng.cache.v_pools = tuple(
+            jnp.full_like(pool, 1e3) if i == at else pool
+            for i, pool in enumerate(eng.cache.v_pools))
+        rng = np.random.default_rng(5)
+        prompts = [rng.integers(0, 512, plen).astype(np.int32)
+                   for plen, _, _ in ARRIVALS]
+        requests, seen, iteration = {}, [], 0
+        while iteration < 200:
+            for i, (_, new, due) in enumerate(ARRIVALS):
+                if due == iteration:
+                    requests[i] = eng.submit(prompts[i], new)
+            worked = eng.step()
+            iteration += 1
+            tails = np.asarray(eng.cache.v_pools[at])
+            np.testing.assert_array_equal(tails[:, 0], 1e3)  # the trash entry
+            for slot in eng._slots:
+                if slot is not None:
+                    index = next(i for i, r in requests.items()
+                                 if r is slot.req)
+                    seen.append((index, slot.position,
+                                 tails[:, slot.blocks[at][0]]))
+            if not worked and len(requests) == len(ARRIVALS):
+                break
+        stats = eng.stats()
+        assert stats["decode_steps"] >= 64
+        assert stats["admissions"] == stats["evictions"] == len(ARRIVALS)
+        assert len({index for index, _, _ in seen}) == len(ARRIVALS)
+        columns = {}
+        for i, req in requests.items():
+            plen, new, _ = ARRIVALS[i]
+            assert req.status == "finished" and len(req.tokens) == new
+            whole = jnp.asarray(np.concatenate([req.prompt, req.tokens]))
+            logits, columns[i] = _ref_columns(weights, whole, cfg)
+            logits = logits[plen - 1:-1]
+            served = jnp.take_along_axis(
+                logits, jnp.asarray(req.tokens)[:, None], axis=-1)[:, 0]
+            assert float(jnp.max(jnp.max(logits, axis=-1) - served)
+                         ) < LOGIT_TOLERANCE, ARRIVALS[i]
+        for index, position, held in seen:
+            # Positions ``position - 3 .. position - 1``, zeros before
+            # the sequence's start.
+            want = np.pad(np.asarray(columns[index]),
+                          ((0, 0), (3, 0), (0, 0)))[:, position:position + 3]
+            got = tail_from_pool_layout(held, shape)
+            # float32 on both sides, as the mixer's own tests hold a tail.
+            np.testing.assert_allclose(got, want, rtol=0, atol=1e-6,
+                                       err_msg=f"{ARRIVALS[index]} at "
+                                       f"position {position}")
+            # The pool's padding lanes (480 of 512): written as zeros.
+            np.testing.assert_array_equal(
+                np.asarray(held).reshape(3, -1)[:, 480:], 0.0)
+    finally:
+        eng.close()
+
+
 def test_spans_say_the_live_states_and_the_entry_taken():
     from fluxmpi_tpu.telemetry import tracing
 
@@ -621,7 +731,8 @@ def test_state_kind_counts_float32_states_and_tails_by_their_bytes():
     k_pools, v_pools = cache.k_pools, cache.v_pools
     assert k_pools[1].shape == (9, 5, 128, 8192)
     assert k_pools[1].dtype == jnp.float32  # whatever the model's dtype
-    assert v_pools[1].shape == (9, 5, 3 * 8448)
+    # A tail's three columns of 8,448 end to end: 198 whole 128-lane tiles.
+    assert v_pools[1].shape == (9, 5, 198, 128) and cache.tail_tiles == 198
     assert v_pools[1].dtype == k_pools[0].dtype == jnp.bfloat16
     assert sum(x.size * x.dtype.itemsize
                for x in k_pools + v_pools) == cache.pool_bytes

@@ -14,23 +14,42 @@ from fluxmpi_tpu.ops import ssm
 
 LAYERS, ENTRIES, HEADS, HEAD_DIM, D_STATE = 2, 6, 8, 16, 128
 SLOTS = 4
+# A tail of three columns of 160 = 480 numbers: three whole 128-lane tiles
+# and 96 lanes of a fourth, padded with zeros in the pool.
+TAIL = (3, HEADS * HEAD_DIM + 2 * 16)
+TILES = 4
 
 
 def _operands(seed=0, dtype=jnp.float32):
-    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    """``(state pool, tail pool), (tail, x, step, decay, b, c)``: pools
+    full of noise, so that an entry written by mistake shows."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 8)
     pool = jax.random.normal(
         keys[0], (LAYERS, ENTRIES, D_STATE, HEADS * HEAD_DIM)).astype(dtype)
+    tail_pool = jax.random.normal(
+        keys[5], (LAYERS, ENTRIES, TILES, 128)).astype(jnp.bfloat16)
+    tail = jax.random.normal(keys[6], (SLOTS, *TAIL))
     x = jax.random.normal(keys[1], (SLOTS, HEADS, HEAD_DIM))
     step = jax.nn.softplus(jax.random.normal(keys[2], (SLOTS, HEADS)) - 2.0)
     decay = jnp.exp(-step * jnp.linspace(1.0, 16.0, HEADS))
     b = jax.random.normal(keys[3], (SLOTS, D_STATE))
     c = jax.random.normal(keys[4], (SLOTS, D_STATE))
-    return pool, (x, step, decay, b, c)
+    return (pool, tail_pool), (tail, x, step, decay, b, c)
+
+
+def _update(kernel, pools, entries, operands, layer=0):
+    """The kernel in interpret mode, or its plain reference."""
+    if kernel:
+        return ssm.ssm_state_update(*pools, entries, *operands, layer=layer,
+                                    interpret=True)
+    return ssm.ssm_state_update_reference(*pools, entries, *operands,
+                                          layer=layer)
 
 
 def _by_hand(pool, entry, slot, operands, layer):
     """One slot's update in numpy float64: ``(y, new state)``."""
-    x, step, decay, b, c = (np.asarray(v, np.float64)[slot] for v in operands)
+    x, step, decay, b, c = (np.asarray(v, np.float64)[slot]
+                            for v in operands[1:])
     state = np.asarray(ssm.from_pool_layout(pool[layer, entry], HEADS),
                        np.float64)
     moved = (decay[:, None, None] * state
@@ -38,31 +57,37 @@ def _by_hand(pool, entry, slot, operands, layer):
     return moved @ c, np.asarray(ssm.to_pool_layout(jnp.asarray(moved)))
 
 
-# Idle slots point at the trash entry (0): none live, one, some, all.
-@pytest.mark.parametrize("entries", [
-    (0, 0, 0, 0), (0, 0, 3, 0), (5, 0, 0, 2), (4, 2, 3, 1),
-], ids=["none_live", "one_live", "two_live", "all_live"])
+# Idle slots point at the trash entry (0): none live, one, some, all, and
+# live slots whose entries run against the slot order.
+LIVE = {"none_live": (0, 0, 0, 0), "one_live": (0, 0, 3, 0),
+        "two_live": (5, 0, 0, 2), "all_live": (4, 2, 3, 1),
+        "against_slot_order": (5, 4, 0, 1)}
+
+
+@pytest.mark.parametrize("entries", LIVE.values(), ids=LIVE.keys())
 @pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "reference"])
 def test_state_update_moves_live_states_and_leaves_the_rest(entries, kernel):
     layer = 1
-    pool, operands = _operands()
+    (pool, tail_pool), operands = _operands()
     entries = jnp.asarray(entries, jnp.int32)
-    if kernel:
-        y, out = ssm.ssm_state_update(pool, entries, *operands, layer=layer,
-                                      interpret=True)
-    else:
-        y, out = ssm.ssm_state_update_reference(pool, entries, *operands,
-                                                layer=layer)
+    y, out, tails = _update(kernel, (pool, tail_pool), entries, operands,
+                            layer)
     assert y.shape == (SLOTS, HEADS, HEAD_DIM) and y.dtype == jnp.float32
     assert out.shape == pool.shape and out.dtype == pool.dtype
+    assert tails.shape == tail_pool.shape and tails.dtype == tail_pool.dtype
     live = {int(e): slot for slot, e in enumerate(entries) if int(e)}
-    # Bit for bit: the other layer, the trash entry, every entry no live
-    # slot names (an idle slot's state is neither read nor written).
+    # Bit for bit, states and tails: the other layer, the trash entry,
+    # every entry no live slot names (an idle slot's entry is neither
+    # read nor written).
     np.testing.assert_array_equal(out[0], pool[0])
+    np.testing.assert_array_equal(tails[0], tail_pool[0])
     for entry in range(ENTRIES):
         if entry not in live:
             np.testing.assert_array_equal(out[layer, entry],
                                           pool[layer, entry])
+            np.testing.assert_array_equal(tails[layer, entry],
+                                          tail_pool[layer, entry])
+    want_tails = ssm.tail_to_pool_layout(operands[0]).astype(jnp.bfloat16)
     for slot, entry in enumerate(entries):
         if not int(entry):
             np.testing.assert_array_equal(y[slot], 0.0)
@@ -72,22 +97,42 @@ def test_state_update_moves_live_states_and_leaves_the_rest(entries, kernel):
         np.testing.assert_allclose(y[slot], want_y, rtol=0, atol=2e-4)
         np.testing.assert_allclose(out[layer, entry], want_state, rtol=0,
                                    atol=1e-5)
+        # The slot's new tail, rounded once to the pool's dtype, whole.
+        np.testing.assert_array_equal(tails[layer, entry], want_tails[slot])
+
+
+@pytest.mark.parametrize("entries", LIVE.values(), ids=LIVE.keys())
+def test_kernel_and_reference_write_the_same_pools(entries):
+    """One contract: what the chip runs and what runs anywhere else leave
+    the same tail pool bit for bit, and the same states to the last place
+    of a float32 sum."""
+    pools, operands = _operands(seed=4)
+    entries = jnp.asarray(entries, jnp.int32)
+    y_k, out_k, tails_k = _update(True, pools, entries, operands)
+    y_r, out_r, tails_r = _update(False, pools, entries, operands)
+    np.testing.assert_array_equal(tails_k, tails_r)
+    np.testing.assert_allclose(out_k, out_r, rtol=0, atol=1e-5)
+    np.testing.assert_allclose(y_k, y_r, rtol=0, atol=2e-4)
+    # Where nothing moved, nothing was rounded either.
+    idle = np.ones((ENTRIES,), bool)
+    idle[[e for e in entries.tolist() if e]] = False
+    np.testing.assert_array_equal(out_k[:, idle], out_r[:, idle])
 
 
 def test_kernel_equals_reference_on_a_bfloat16_pool():
     """The pool's dtype is the state's: ``y`` comes from the moved state
     before it is rounded, the pool holds it rounded, on both paths."""
-    pool, operands = _operands(seed=1, dtype=jnp.bfloat16)
+    pools, operands = _operands(seed=1, dtype=jnp.bfloat16)
     entries = jnp.asarray((2, 0, 5, 1), jnp.int32)
-    y_k, out_k = ssm.ssm_state_update(pool, entries, *operands,
-                                      interpret=True)
-    y_r, out_r = ssm.ssm_state_update_reference(pool, entries, *operands)
+    y_k, out_k, tails_k = _update(True, pools, entries, operands)
+    y_r, out_r, tails_r = _update(False, pools, entries, operands)
     assert out_k.dtype == out_r.dtype == jnp.bfloat16
     np.testing.assert_allclose(y_k, y_r, rtol=0, atol=2e-4)
     # One rounding to bfloat16 of the same float32 sums (a fused
     # multiply-add on one path may tip a tie: one unit in the last place).
     np.testing.assert_allclose(np.asarray(out_k, np.float32),
                                np.asarray(out_r, np.float32), rtol=2 ** -7)
+    np.testing.assert_array_equal(tails_k, tails_r)
 
 
 def test_live_entries_compacts_in_slot_order_and_holds_the_last():
@@ -102,23 +147,30 @@ def test_live_entries_compacts_in_slot_order_and_holds_the_last():
 
 
 def test_without_a_tpu_the_public_call_is_the_reference():
-    pool, operands = _operands(seed=2)
+    pools, operands = _operands(seed=2)
     entries = jnp.asarray((1, 0, 2, 0), jnp.int32)
-    y, out = ssm.ssm_state_update(pool, entries, *operands)
-    y_r, out_r = ssm.ssm_state_update_reference(pool, entries, *operands)
-    np.testing.assert_array_equal(y, y_r)
-    np.testing.assert_array_equal(out, out_r)
+    got = ssm.ssm_state_update(*pools, entries, *operands)
+    want = ssm.ssm_state_update_reference(*pools, entries, *operands)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
 
 
 def test_state_update_refuses_shapes_that_do_not_fit():
-    pool, (x, step, decay, b, c) = _operands()
+    (pool, tail_pool), (tail, x, step, decay, b, c) = _operands()
     entries = jnp.zeros((SLOTS,), jnp.int32)
+    rest = (x, step, decay, b, c)
     with pytest.raises(ValueError, match="expected"):
-        ssm.ssm_state_update(pool, entries, x[:, :4], step, decay, b, c)
+        ssm.ssm_state_update(pool, tail_pool, entries, tail, x[:, :4], step,
+                             decay, b, c)
     with pytest.raises(ValueError, match="layer 2 outside"):
-        ssm.ssm_state_update(pool, entries, x, step, decay, b, c, layer=2)
+        ssm.ssm_state_update(pool, tail_pool, entries, tail, *rest, layer=2)
     with pytest.raises(ValueError, match="a state pool is"):
-        ssm.ssm_state_update(pool[0], entries, x, step, decay, b, c)
+        ssm.ssm_state_update(pool[0], tail_pool, entries, tail, *rest)
+    # A tail pool of another width, of other entries, tails of other slots.
+    for pool_, tail_ in ((tail_pool[:, :, :3], tail), (tail_pool[:, :5], tail),
+                         (tail_pool, tail[:3])):
+        with pytest.raises(ValueError, match="expected tails"):
+            ssm.ssm_state_update(pool, pool_, entries, tail_, *rest)
 
 
 def test_pool_layout_is_the_states_transposed_side_by_side():
@@ -127,6 +179,24 @@ def test_pool_layout_is_the_states_transposed_side_by_side():
     assert held.shape == (2, 5, 12)
     assert float(held[1, 4, 2 * 4 + 3]) == float(state[1, 2, 3, 4])
     np.testing.assert_array_equal(ssm.from_pool_layout(held, 3), state)
+
+
+# The cell's tail (25,344 = 198 whole tiles), the rehearsal's (480: 96
+# lanes into a fourth tile), one a lane wider than a tile, one narrower.
+@pytest.mark.parametrize("shape, tiles", [((3, 8448), 198), ((3, 160), 4),
+                                          ((1, 129), 2), ((1, 5), 1)])
+def test_tail_layout_is_the_columns_end_to_end_in_whole_tiles(shape, tiles):
+    taps, width = shape
+    tail = jnp.arange(2 * taps * width, dtype=jnp.float32).reshape(
+        2, taps, width) + 1.0
+    held = ssm.tail_to_pool_layout(tail)
+    assert held.shape == (2, tiles, 128)
+    flat = np.asarray(held).reshape(2, -1)
+    np.testing.assert_array_equal(flat[:, :taps * width],
+                                  np.asarray(tail).reshape(2, -1))
+    np.testing.assert_array_equal(flat[:, taps * width:], 0.0)
+    np.testing.assert_array_equal(ssm.tail_from_pool_layout(held, shape),
+                                  tail)
 
 
 def _recurrence(x, step, a_rate, b, c, initial=None):

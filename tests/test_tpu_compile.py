@@ -473,22 +473,39 @@ def test_sarvam_105b_serving_programs_compile_for_v5e(topo, as_on_tpu):
                 < 14.5e9)
 
 
+def _pool_sized(text, *shapes):
+    """The instructions of a compiled program's text, by opcode, whose
+    RESULT is a whole pool of one of ``shapes``: ``{opcode: count}``."""
+    found = {}
+    for shape in shapes:
+        dims = ",".join(str(n) for n in shape)
+        for opcode in re.findall(
+                r"= \w+\[%s\]\S* ([\w\-]+)\(" % re.escape(dims), text):
+            found[opcode] = found.get(opcode, 0) + 1
+    return found
+
+
 def test_ssm_state_update_kernel_compiles_for_v5e(topo):
     """The decode tick's state update at the published widths (128 heads
     of 64 over a state of 128; 9 layers x 129 entries of float32 = 4.87
-    GB): one kernel, the pool aliased in and out, no copy of it."""
+    GB, and beside them the tails, 198 lane tiles of bfloat16 an entry):
+    one kernel, both pools aliased in and out, no copy of either and no
+    scatter."""
     from fluxmpi_tpu.ops.ssm import ssm_state_update
 
     dev = topo.devices[0]
     slots, layers, heads, head_dim, d_state = 128, 9, 128, 64, 128
+    pool = (layers, slots + 1, d_state, heads * head_dim)
+    tails = (layers, slots + 1, 198, 128)
 
-    def update(pool, entries, x, step, decay, b, c):
-        return ssm_state_update(pool, entries, x, step, decay, b, c,
-                                layer=3, interpret=False)
+    def update(pool, tail_pool, entries, tail, x, step, decay, b, c):
+        return ssm_state_update(pool, tail_pool, entries, tail, x, step,
+                                decay, b, c, layer=3, interpret=False)
 
-    compiled = jax.jit(update, donate_argnums=(0,)).lower(
-        _sds((layers, slots + 1, d_state, heads * head_dim), jnp.float32, dev),
+    compiled = jax.jit(update, donate_argnums=(0, 1)).lower(
+        _sds(pool, jnp.float32, dev), _sds(tails, jnp.bfloat16, dev),
         _sds((slots,), jnp.int32, dev),
+        _sds((slots, 3, 8448), jnp.bfloat16, dev),
         _sds((slots, heads, head_dim), jnp.float32, dev),
         _sds((slots, heads), jnp.float32, dev),
         _sds((slots, heads), jnp.float32, dev),
@@ -498,8 +515,13 @@ def test_ssm_state_update_kernel_compiles_for_v5e(topo):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1
     assert len(re.findall(r"%ssm_state_update[.\d]* = ", text)) == 1
+    # Whole pools come out of the kernel and nothing else: no scatter
+    # into one, no copy of one.
+    assert set(_pool_sized(text, pool, tails)) <= {
+        "custom-call", "parameter", "get-tuple-element"}
     memory = compiled.memory_analysis()
-    pool_bytes = layers * (slots + 1) * heads * head_dim * d_state * 4
+    pool_bytes = layers * (slots + 1) * (
+        heads * head_dim * d_state * 4 + 198 * 128 * 2)
     assert memory.alias_size_in_bytes >= pool_bytes  # in place
     assert memory.temp_size_in_bytes < 2**24
 
@@ -512,9 +534,11 @@ def test_granite_4_0_h_small_serving_programs_compile_for_v5e(topo, as_on_tpu):
     the tied vocabulary; 128 slots x 2,560 positions in 256-blocks): one
     state-update kernel a Mamba layer and one paged decode kernel, the
     grouped matmul's kernel three times a layer, the state pool, the
-    tails and the K/V updated in place, and everything under 14.5 GB
-    beside 5.91 GB of bfloat16 weights. (160 slots: 13.74 GB of
-    arguments + 1.28 GB of the prefill's temporaries = 15.0 GB.)"""
+    tails and the K/V updated in place (the tails by the state-update
+    kernel: the tick holds no scatter into their pool and no copy of a
+    whole pool), and everything under 14.5 GB beside 5.91 GB of bfloat16
+    weights. (160 slots: 13.74 GB of arguments + 1.28 GB of the
+    prefill's temporaries = 15.0 GB.)"""
     import json
 
     from fluxmpi_tpu.serving import InferenceEngine
@@ -555,8 +579,10 @@ def test_granite_4_0_h_small_serving_programs_compile_for_v5e(topo, as_on_tpu):
             + (1 + slots) * 9 * (state + tail))
         k_pools = (_sds(cache.pool_shapes[0], jnp.bfloat16, dev),
                    _sds(cache.pool_shapes[1], jnp.float32, dev))
+        tails = (9, 1 + slots, cache.tail_tiles, 128)
+        assert cache.tail_tiles * 128 == 3 * 8448  # whole tiles, no padding
         v_pools = (_sds(cache.pool_shapes[0], jnp.bfloat16, dev),
-                   _sds((9, 1 + slots, 3 * 8448), jnp.bfloat16, dev))
+                   _sds(tails, jnp.bfloat16, dev))
         decode = engine._decode_step.lower(
             params, k_pools, v_pools,
             tuple(_sds((slots, k.entries), jnp.int32, dev)
@@ -578,6 +604,12 @@ def test_granite_4_0_h_small_serving_programs_compile_for_v5e(topo, as_on_tpu):
     assert "slice-start" not in text  # operands prefetched whole
     assert len(re.findall(r"%ssm_state_update[.\d]* = ", text)) == 9
     assert len(re.findall(r"%ragged-dot-gmm[.\d]* = ", text)) == 10 * 3
+    # A whole state or tail pool is the result of the nine kernels and of
+    # nothing else (no scatter of tails, no copy between tilings), and
+    # no pool of any kind is copied.
+    assert set(_pool_sized(text, cache.pool_shapes[1], tails)) <= {
+        "custom-call", "parameter", "get-tuple-element"}
+    assert "copy" not in _pool_sized(text, *cache.pool_shapes, tails)
     # The prefill: one flash forward (the attention layer), the chunked
     # scan in plain XLA, no state-update kernel.
     text = prefill.as_text()
