@@ -423,7 +423,7 @@ class _Slot:
     has (:attr:`BlockKVCache.kinds`)."""
 
     __slots__ = ("req", "blocks", "tables", "position", "last_token",
-                 "generated", "dispatched")
+                 "generated", "dispatched", "gap_t", "gap_admits")
 
     def __init__(self, req: ServingRequest, blocks: list[list[int]],
                  tables: list[np.ndarray]):
@@ -440,6 +440,11 @@ class _Slot:
         # Tokens asked of the device: ``generated`` plus the tick in
         # flight, if the slot rides it.
         self.dispatched = 0
+        # The gap ledger: when the request's newest token was delivered,
+        # and the engine's count of admissions then (another by the next
+        # delivery: the gap between the two held a prefill).
+        self.gap_t = 0.0
+        self.gap_admits = 0
 
     @property
     def num_blocks(self) -> int:
@@ -924,6 +929,19 @@ class InferenceEngine:
         self._prev = None
         self._steps_overlapped = 0
         self._tokens_discarded = 0
+        # The gap ledger (see stats()): gaps between two deliveries to one
+        # request, those that held another request's admission, the
+        # seconds of both, and the request admitted last.
+        self._gaps = 0
+        self._gaps_stalled = 0
+        self._gap_seconds = 0.0
+        self._gap_stalled_seconds = 0.0
+        self._last_admitted = 0
+        # What a decode tick uploads: every kind's table, positions and
+        # tokens (int32) and ``use_prev`` (bool), of fixed shapes.
+        self._upload_bytes = self.slots * (
+            4 * sum(kind.entries for kind in self.cache.kinds) + 4 + 4 + 1
+        )
         # Registry-counter delta baselines (see _resolve_run).
         self._counted_steps = 0
         self._counted_tokens = 0
@@ -1375,6 +1393,11 @@ class InferenceEngine:
             self._admissions += 1
             req._deliver(slot.last_token)
             self._tokens += 1
+            # The first token opens the request's first gap; its own
+            # admission is not in it.
+            slot.gap_t = req.first_token_t
+            slot.gap_admits = self._admissions
+            self._last_admitted = req.id
             if self._record:
                 reg = self._reg
                 if req.queue_wait_s is not None:
@@ -1478,10 +1501,13 @@ class InferenceEngine:
                     prep.set_metadata(
                         window_blocks_pct=100.0 * sum(held) / uniform
                     )
-            tables = tuple(jnp.asarray(table) for table in tables)
-            positions = jnp.asarray(positions)
-            tokens = jnp.asarray(tokens)
-            use_prev = jnp.asarray(use_prev)
+            with _tracing.span(
+                "serve.decode.upload", bytes=self._upload_bytes
+            ):
+                tables = tuple(jnp.asarray(table) for table in tables)
+                positions = jnp.asarray(positions)
+                tokens = jnp.asarray(tokens)
+                use_prev = jnp.asarray(use_prev)
             prev = self._last_output()
         in_flight = int(self._in_flight is not None)
         with _tracing.span(
@@ -1521,7 +1547,24 @@ class InferenceEngine:
         with _tracing.span(
             "serve.decode.deliver", step=tick.step, tokens=len(riders)
         ) as delivery:
+            # Arguments only the span reads are computed only for a span
+            # that records; the counters of stats() always.
+            recording = delivery is not _tracing.NOOP_SPAN
+            # The riders share this delivery time: one clock read a tick.
+            now = self._clock()
+            admits = self._admissions
+            gap_seconds = stalled_seconds = 0.0
+            stalled = 0
             for i, slot in riders:
+                gap = now - slot.gap_t
+                slot.gap_t = now
+                gap_seconds += gap
+                if slot.gap_admits != admits:
+                    # Another request was admitted since this one's last
+                    # token: the gap held that prefill.
+                    slot.gap_admits = admits
+                    stalled += 1
+                    stalled_seconds += gap
                 tok = int(nxt[i])
                 slot.generated += 1
                 slot.last_token = tok
@@ -1532,23 +1575,40 @@ class InferenceEngine:
                     and tok == int(slot.req.eos_token)
                 ):
                     self._evict(i)
-            delivery.set_metadata(evicted=self._evictions - evicted)
+            self._gaps += len(riders)
+            self._gaps_stalled += stalled
+            self._gap_seconds += gap_seconds
+            self._gap_stalled_seconds += stalled_seconds
+            if recording:
+                said = {"evicted": self._evictions - evicted,
+                        # The riders' mean gap: the time since the tick
+                        # before for those that rode it, since its first
+                        # token for one admitted after it.
+                        "gap_ms": (1e3 * gap_seconds / len(riders)
+                                   if riders else 0.0),
+                        "stalled": stalled}
+                if stalled:
+                    said["stalled_by"] = self._last_admitted
+                delivery.set_metadata(**said)
             if expert_tokens.size:
                 touched = int(np.count_nonzero(expert_tokens))
                 self._expert_tokens += int(expert_tokens.sum())
                 self._experts_touched += touched
                 self._expert_slots += expert_tokens.size
-                # The busiest expert's pairs over the mean, layer by layer.
                 by_layer = expert_tokens.reshape(
                     -1, expert_tokens.size // self._expert_layers[0]
                 )
-                delivery.set_metadata(
-                    experts_touched_pct=100.0 * touched / expert_tokens.size,
-                    expert_load_max_over_mean=float(np.mean(
-                        by_layer.max(axis=1)
-                        / np.maximum(by_layer.mean(axis=1), 1e-9)
-                    )),
-                )
+                if recording:
+                    delivery.set_metadata(
+                        experts_touched_pct=(
+                            100.0 * touched / expert_tokens.size),
+                        # The busiest expert's pairs over the mean, layer
+                        # by layer.
+                        expert_load_max_over_mean=float(np.mean(
+                            by_layer.max(axis=1)
+                            / np.maximum(by_layer.mean(axis=1), 1e-9)
+                        )),
+                    )
                 # Where the grouped matmul is the kernel: the weight
                 # blocks a projection fetched over the experts touched.
                 tile = getattr(self.model, "expert_row_tile", None)
@@ -1625,7 +1685,7 @@ class InferenceEngine:
     def active_count(self) -> int:
         return sum(1 for s in self._slots if s is not None)
 
-    def stats(self) -> dict[str, int]:
+    def stats(self) -> dict[str, int | float]:
         """Snapshot of the scheduler's counters since construction:
         ``decode_steps`` (decode programs dispatched), ``tokens``
         (delivered, first tokens included), ``slot_steps_active`` (slots
@@ -1664,9 +1724,19 @@ class InferenceEngine:
         state pool holds: the slots), summed over decode steps, and
         ``state_bytes``, what those updates moved (each live sequence's
         states and convolution tails of every state layer, read and
-        written). The keys a model has no use
+        written). The gap ledger, kept where tokens are delivered: a gap
+        is the time between two consecutive deliveries to one request
+        (the first token, out of the admission, opens the first), and it
+        is STALLED when another request's admission began and ended
+        inside it, so every slot waited out that prefill. ``gaps`` (over
+        finished requests: ``tokens - admissions``), ``gaps_stalled``,
+        and the seconds of both on the engine's clock, ``gap_seconds``
+        and ``gap_stalled_seconds`` (floats): ``gaps_stalled / gaps`` is
+        the share of gaps a prefill hit, ``gap_stalled_seconds /
+        gaps_stalled`` the mean stalled gap, and the two differences the
+        clean gaps. The keys a model has no use
         for stay 0. Plain
-        ints the loop keeps anyway; safe to read from another thread."""
+        numbers the loop keeps anyway; safe to read from another thread."""
         return {
             "decode_steps": self._decode_steps,
             "tokens": self._tokens,
@@ -1689,6 +1759,10 @@ class InferenceEngine:
             "state_entries_used": self._states_live,
             "state_bytes": 2 * self._states_live
             * self.cache.state_entry_bytes,
+            "gaps": self._gaps,
+            "gaps_stalled": self._gaps_stalled,
+            "gap_seconds": self._gap_seconds,
+            "gap_stalled_seconds": self._gap_stalled_seconds,
         }
 
     @property
@@ -1949,7 +2023,11 @@ class InferenceEngine:
                     self._fail_pending("error", include_active=True)
                     return
                 if not worked and self.active_count == 0:
-                    self._wake.wait(timeout=0.05)
+                    # The engine is EMPTY: asleep until a submit() wakes
+                    # it, 50 ms at most a span.
+                    with _tracing.span("serve.idle") as idle:
+                        woken = self._wake.wait(timeout=0.05)
+                        idle.set_metadata(woken=int(woken))
                     self._wake.clear()
 
         self._thread = threading.Thread(

@@ -390,12 +390,15 @@ HOT_PATH_SPAN_ARGS: dict[str, tuple[str, ...]] = {
     "loop.backpressure": ("update",),
     "loop.flush": ("update", "fused"),
     "serve.iteration": ("active", "queued"),
+    "serve.idle": ("woken",),
     "serve.admit": ("request_id", "prompt_tokens", "bucket", "active"),
     "serve.prefill": ("request_id", "bucket"),
     "serve.decode.prepare": ("active", "live_blocks_pct"),
+    "serve.decode.upload": ("bytes",),
     "serve.decode.dispatch": ("step",),
     "serve.decode.fetch": ("step",),
-    "serve.decode.deliver": ("step", "tokens", "evicted"),
+    "serve.decode.deliver": ("step", "tokens", "evicted", "gap_ms",
+                             "stalled"),
 }
 
 # Anomaly trace events (AnomalyDetector triggers): "anomaly.<rule>"

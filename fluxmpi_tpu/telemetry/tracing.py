@@ -71,6 +71,7 @@ __all__ = [
     "get_tracer",
     "set_tracer",
     "span",
+    "NOOP_SPAN",
     "instant",
     "add_complete_event",
     "name_track",
@@ -101,7 +102,10 @@ class _NoopSpan:
         return None
 
 
-_NOOP_SPAN = _NoopSpan()
+# What span() returns while nothing records. A call site whose span
+# arguments cost something to compute compares against it and skips them
+# (``sp is not NOOP_SPAN``: the span records).
+NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
@@ -193,7 +197,7 @@ class Tracer:
             return _Span(self, name, args or None)
         if _session_records():
             return _Annotation(name, **args)
-        return _NOOP_SPAN
+        return NOOP_SPAN
 
     def instant(
         self, name: str, *, track: int | None = None, **args: Any
@@ -360,7 +364,7 @@ def span(name: str, **args: Any) -> Any:
         return _Span(tracer, name, args or None)
     if _session_records():
         return _Annotation(name, **args)
-    return _NOOP_SPAN
+    return NOOP_SPAN
 
 
 def instant(name: str, **args: Any) -> None:

@@ -229,7 +229,8 @@ _ENGINE_COUNTERS = (
     "kv_blocks_window", "kv_blocks_uniform", "expert_tokens",
     "experts_touched", "expert_slots", "expert_weight_visits",
     "decode_steps_overlapped", "tokens_discarded", "state_entries",
-    "state_entries_used", "state_bytes",
+    "state_entries_used", "state_bytes", "gaps", "gaps_stalled",
+    "gap_seconds", "gap_stalled_seconds",
 )
 
 
@@ -275,6 +276,9 @@ def test_tiny_serve_cell_engine_surface(world, own_runtime):
     assert set(_ENGINE_COUNTERS) <= set(stats)
     assert stats["decode_steps"] == steps > 0
     assert stats["tokens"] == 4 and stats["admissions"] == 1
+    # One request alone: three gaps, none of them behind a prefill.
+    assert stats["gaps"] == 3 and stats["gaps_stalled"] == 0
+    assert stats["gap_seconds"] > 0.0 == stats["gap_stalled_seconds"]
     assert slots == spec["engine"]["slots"]
 
 
@@ -325,6 +329,15 @@ def test_untraced_rehearsal_reports_its_end_to_end_metrics(name, tmp_path):
         "cpu_rehearsal.setup_s"}
 
 
+# The gap ledger's and the upload's metrics (PR 39) read the spans of
+# every serve cell; the three idle shares read the device's plane, which
+# a CPU run has not: absent, and their reader must not raise.
+_GAP_METRICS = ("stalled_gap_pct", "stalled_gap_p50_ms", "clean_gap_p95_ms",
+                "decode_upload_ms")
+_IDLE_METRICS = ("idle_engine_empty_pct", "idle_admit_pct",
+                 "idle_tick_host_pct")
+
+
 @pytest.mark.parametrize("name", sorted(_TRACED))
 def test_traced_rehearsal_run_exits_0_and_reads_its_spans(name, tmp_path):
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
@@ -340,7 +353,14 @@ def test_traced_rehearsal_run_exits_0_and_reads_its_spans(name, tmp_path):
     assert result["failed"] == 0 and result["attempted"] > 0
     assert result["device"]["platform"] == "cpu"
     metrics = result["metrics"]
-    for metric in _TRACED[name]:
+    for metric in _TRACED[name] + _GAP_METRICS:
         assert f"cpu_rehearsal.{metric}" in metrics, (metric, sorted(metrics))
+    for metric in _GAP_METRICS:
+        value = metrics[f"cpu_rehearsal.{metric}"]["value"]
+        assert isinstance(value, float) and value >= 0.0, (metric, value)
+    assert 0.0 <= metrics["cpu_rehearsal.stalled_gap_pct"]["value"] <= 100.0
+    assert metrics["cpu_rehearsal.clean_gap_p95_ms"]["value"] > 0.0
+    for metric in _IDLE_METRICS:
+        assert f"cpu_rehearsal.{metric}" not in metrics
     visits = metrics.get("cpu_rehearsal.expert_weight_visits_per_touched")
     assert visits is None or visits["value"] >= 1.0

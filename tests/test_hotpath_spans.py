@@ -261,12 +261,41 @@ def test_engine_spans(tiny_lm, capture, request):
         assert prep[2] <= disp[1] and disp[2] <= fetch[1] <= deliv[1]
         assert _int_args(disp)["step"] == _int_args(fetch)["step"]
         assert _int_args(deliv)["tokens"] == _int_args(prep)["active"]
+        assert float(deliv[3]["gap_ms"]) > 0.0
         # One tick in flight: a tick is prepared and dispatched in one
         # iteration, fetched and delivered in the next.
         assert any(_inside(prep, it) and _inside(disp, it)
                    for it in iterations)
         assert any(_inside(fetch, it) and _inside(deliv, it)
                    and not _inside(disp, it) for it in iterations)
+    # The tables' transfer is a child of the prepare, one a tick, and
+    # says what it moved: a table of 8 entries, a position and a token
+    # (int32) and ``use_prev`` (bool) for each of the 2 slots.
+    uploads = _named(spans, "serve.decode.upload")
+    assert len(uploads) == len(ticks["prepare"])
+    for upload, prep in zip(uploads, ticks["prepare"]):
+        assert _inside(upload, prep)
+        assert _int_args(upload)["bytes"] == 2 * (4 * 8 + 4 + 4 + 1)
+    # The gap ledger on the deliveries: every rider's gap is counted
+    # once, a stalled one names the admission that caused it, and the
+    # ticks sum back to stats()'s counters.
+    assert sum(_int_args(s)["tokens"] for s in ticks["deliver"]) == (
+        stats["gaps"]) == stats["tokens"] - stats["admissions"]
+    assert sum(_int_args(s)["stalled"] for s in ticks["deliver"]) == (
+        stats["gaps_stalled"])
+    assert sum(float(s[3]["gap_ms"]) * _int_args(s)["tokens"]
+               for s in ticks["deliver"]) / 1e3 == pytest.approx(
+        stats["gap_seconds"])
+    for deliv in ticks["deliver"]:
+        assert ("stalled_by" in deliv[3]) == (_int_args(deliv)["stalled"] > 0)
+    # The idle engine admits its first two requests at once: the first
+    # one's first gap holds the second's admission, and no other does.
+    stalled = [s for s in ticks["deliver"] if _int_args(s)["stalled"]]
+    assert [(_int_args(s)["stalled"], _int_args(s)["stalled_by"])
+            for s in stalled] == [(1, ids[1])]
+    assert 0.0 < stats["gap_stalled_seconds"] < stats["gap_seconds"]
+    # run() never waits: no idle span.
+    assert not _named(spans, "serve.idle")
     overlapped = 0
     for k, (disp, fetch) in enumerate(zip(ticks["dispatch"], ticks["fetch"])):
         behind = int(_int_args(disp)["in_flight"])
@@ -322,7 +351,9 @@ def test_engine_stats_agree_with_delivery(tiny_lm):
                           "expert_tokens", "experts_touched", "expert_slots",
                           "expert_weight_visits", "decode_steps_overlapped",
                           "tokens_discarded", "state_entries",
-                          "state_entries_used", "state_bytes"}
+                          "state_entries_used", "state_bytes", "gaps",
+                          "gaps_stalled", "gap_seconds",
+                          "gap_stalled_seconds"}
     # Every tick but the two started from an empty engine (the third
     # request waits for a slot) went out behind the one in flight.
     assert stats["decode_steps_overlapped"] == stats["decode_steps"] - 2
@@ -333,7 +364,103 @@ def test_engine_stats_agree_with_delivery(tiny_lm):
     assert all(stats[k] == 0 for k in stats
                if k.startswith("expert") or k in (
                    "kv_blocks_full", "kv_blocks_window", "kv_blocks_uniform"))
-    assert all(type(v) is int for v in stats.values())
+    # Plain numbers: ints, and the two sums of seconds.
+    seconds = {"gap_seconds", "gap_stalled_seconds"}
+    assert all(type(v) is (float if k in seconds else int)
+               for k, v in stats.items())
+    assert stats["gaps"] == stats["tokens"] - stats["admissions"]
+
+
+@pytest.mark.parametrize("capture", ["ring", "xplane"], indirect=True)
+def test_idle_engine_span(tiny_lm, capture):
+    """The serve thread asleep on its wake event is a ``serve.idle``
+    span, 50 ms at most; the ``submit()`` that wakes it ends it."""
+    import time
+
+    lm, variables = tiny_lm
+    recording, box = capture
+    engine = InferenceEngine(lm, variables, slots=2, block_size=8)
+    try:
+        engine.warmup(prompt_lengths=(5,))
+        with recording():
+            engine.start()
+            time.sleep(0.12)  # two waits run out
+            req = engine.submit(np.arange(1, 6, dtype=np.int32), 3)
+            assert req.wait(timeout=120.0)
+            assert engine.stop()
+    finally:
+        engine.close()
+    assert engine.serve_error is None
+    idles = _named(box["spans"], "serve.idle")
+    woken = [_int_args(s)["woken"] for s in idles]
+    assert len(idles) >= 3 and set(woken) == {0, 1}
+    # A wait that ran out lasted its 50 ms.
+    for span, hit in zip(idles, woken):
+        ms = (span[2] - span[1]) / 1e6
+        assert ms < 1000.0 and (hit or ms >= 49.0)
+    # Idle spans and iterations take turns on the thread.
+    iterations = _named(box["spans"], "serve.iteration")
+    assert iterations and not any(
+        _inside(idle, it) for idle in idles for it in iterations)
+    # The first iteration is the one the submit woke.
+    first = min(iterations, key=lambda s: s[1])
+    before = max((s for s in idles if s[2] <= first[1]), key=lambda s: s[2])
+    assert _int_args(before)["woken"] == 1
+
+
+def test_gap_ledger_counts_the_riders_behind_each_admission(tiny_lm):
+    """Requests admitted while others decode: every slot that rode the
+    tick in flight waits out the prefill, so ``gaps_stalled`` grows by
+    the riders live at each admission; on an injected clock the seconds
+    are exact."""
+    lm, variables = tiny_lm
+    ticks = iter(range(10**6))
+    tracer = Tracer(enabled=True)
+    prev = tracing.set_tracer(tracer)
+    engine = InferenceEngine(lm, variables, slots=4, block_size=8,
+                             clock=lambda: float(next(ticks)))
+    try:
+        engine.warmup(prompt_lengths=(5,))
+        prompt = np.arange(1, 6, dtype=np.int32)
+        first = engine.submit(prompt, 12)
+        engine.step()  # admitted into an empty engine: nobody stalls
+        assert engine.stats()["gaps"] == 0
+        live_at_admission = []
+        joined = []
+        for _ in range(3):
+            joined.append(engine.submit(prompt, 12))
+            live_at_admission.append(engine.active_count)
+            engine.step()  # dispatch a tick, then admit behind it
+            engine.step()  # that tick lands AFTER the prefill: stalled
+            engine.step()  # a clean tick
+        assert live_at_admission == [1, 2, 3]
+        mid = engine.stats()
+        assert mid["gaps_stalled"] == sum(live_at_admission)
+        engine.run()
+        stats = engine.stats()
+    finally:
+        engine.close()
+        tracing.set_tracer(prev)
+    requests = [first] + joined
+    assert all(r.status == "finished" for r in requests)
+    assert stats["tokens"] == 4 * 12 and stats["admissions"] == 4
+    assert stats["gaps"] == stats["tokens"] - len(requests)
+    # No admission after the last one joined: nothing more stalls.
+    assert stats["gaps_stalled"] == sum(live_at_admission) == 6
+    assert 0.0 < stats["gap_stalled_seconds"] <= stats["gap_seconds"]
+    # Every gap of a request lies between its first token and its end.
+    assert stats["gap_seconds"] <= sum(
+        r.finished_t - r.first_token_t for r in requests)
+    # The deliveries say the same, tick by tick, and name the culprit.
+    delivers = [e for e in tracer.export()["traceEvents"]
+                if e["name"] == "serve.decode.deliver"]
+    assert [validate_trace_event(e) for e in delivers] == [[]] * len(delivers)
+    stalled = [(e["args"]["stalled"], e["args"]["stalled_by"])
+               for e in delivers if e["args"]["stalled"]]
+    assert stalled == [(n, r.id) for n, r in zip(live_at_admission, joined)]
+    assert sum(e["args"]["tokens"] for e in delivers) == stats["gaps"]
+    assert sum(e["args"]["gap_ms"] * e["args"]["tokens"]
+               for e in delivers) / 1e3 == pytest.approx(stats["gap_seconds"])
 
 
 # ---------------------------------------------------------------------------
