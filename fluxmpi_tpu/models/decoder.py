@@ -736,10 +736,27 @@ class ExpertMLP(nn.Module):
     ``expert_range`` of the experts (default: all): the (token, expert)
     pairs are sorted by expert, the held experts' pairs first, and one
     grouped matmul (:func:`~fluxmpi_tpu.ops.grouped_matmul.grouped_matmul`:
-    ``jax.lax.ragged_dot``'s meaning) a projection computes them;
+    ``jax.lax.ragged_dot``'s meaning, its rows past the groups
+    UNSPECIFIED) a projection computes them;
     pairs routed to experts held elsewhere add nothing here (on one chip
     the layer runs without its exchange). The shared expert, which every
     token passes, is added where ``include_shared``.
+
+    What the layer does with the grouped matmuls' results is bounded by
+    the pairs its held experts received (``sum(sizes)``), never by a
+    capacity: where it holds fewer experts than its router is wide, the
+    kernel's walk ends at the last held pair's row tile and
+    :func:`~fluxmpi_tpu.ops.grouped_matmul.combine` adds the live rows
+    into their tokens' rows, reading the live row tiles only; if every
+    pair went to held experts every tile is worked. The row gather and
+    the gate product stay single passes over all the sorted rows (what
+    they leave past the live rows is never read): row tile by row tile
+    under a ``lax.cond`` they were slower on the chip than the passes
+    they bounded (PERF.md §6, PR 40). With every expert held the bound
+    is the static row count: the pairs are gathered back by token as
+    before, and the pairs of tokens ``token_mask`` leaves out, which lie
+    past the groups, are masked. Either way the layer is
+    reverse-differentiable where its grouped matmul is.
 
     Returns the layer's output and sows ``expert_tokens`` (``[held]``
     int32: the pairs each HELD expert received; what went to experts held
@@ -812,25 +829,35 @@ class ExpertMLP(nn.Module):
                     1, mode="drop")
                 # Held experts first, in order; the others' pairs after.
                 order = jnp.argsort(rank, stable=True)
-                back = jnp.zeros_like(order).at[order].set(
-                    jnp.arange(order.shape[0], dtype=order.dtype)
-                )
                 sizes = counts[lo:hi]
             with jax.named_scope("moe_experts"):
-                rows = u.astype(self.dtype)[order // k]
-
                 # Imported here: ``fluxmpi_tpu.ops`` brings Pallas with it,
                 # which a model without expert layers may never need.
-                from ..ops.grouped_matmul import grouped_matmul
+                from ..ops.grouped_matmul import combine, grouped_matmul
 
                 def grouped(x, w):
-                    # Rows past the held experts' pairs come out zero.
+                    # Rows past the held experts' pairs: unspecified.
                     return grouped_matmul(
                         x.astype(self.dtype), w.astype(self.dtype), sizes
                     )
 
-                h = jax.nn.silu(grouped(rows, w1)) * grouped(rows, w3)
-                y = grouped(h, w2)[back].reshape(tokens, k, d)
+                rows = u.astype(self.dtype)[order // k]
+                gate, up = grouped(rows, w1), grouped(rows, w3)
+                y = grouped(jax.nn.silu(gate) * up, w2)
+                if held < n:
+                    # The held pairs' rows added into their tokens' rows:
+                    # the rows past them (3 of 4, 7 of 8) are not read.
+                    return combine(
+                        y, order // k, weights.reshape(-1)[order],
+                        jnp.sum(sizes), tokens), sizes
+                back = jnp.zeros_like(order).at[order].set(
+                    jnp.arange(order.shape[0], dtype=order.dtype)
+                )
+                y = y[back]
+                if token_mask is not None:
+                    # A masked token's pairs lie past the groups.
+                    y = jnp.where((back < jnp.sum(sizes))[:, None], y, 0.0)
+                y = y.reshape(tokens, k, d)
                 return jnp.sum(y * weights[..., None], axis=1), sizes
 
         slabs = _slabs(u.shape[0], k * d)
@@ -852,6 +879,14 @@ class ExpertMLP(nn.Module):
                     self.shared_width, self.dtype, name="shared"
                 )(u).astype(jnp.float32)
         return out.astype(self.dtype).reshape(shape)
+
+
+def _held_experts(config, expert_range):
+    """``(router width, the range of it a layer holds or None for all)``:
+    a router wider than the experts held is this chip's share."""
+    routed = config.num_routed_experts or config.num_experts
+    return routed, expert_range or (
+        (0, config.num_experts) if routed > config.num_experts else None)
 
 
 class DecoderLayer(nn.Module):
@@ -895,10 +930,7 @@ class DecoderLayer(nn.Module):
         if self.index < c.num_dense_layers:
             y = GatedMLP(c.intermediate_size, self.dtype, name="mlp")(u)
         else:
-            # A router wider than the experts held: this chip's share.
-            routed = c.num_routed_experts or c.num_experts
-            held = self.expert_range or (
-                (0, c.num_experts) if routed > c.num_experts else None)
+            routed, held = _held_experts(c, self.expert_range)
             ff = ExpertMLP(
                 num_experts=routed, top_k=c.num_experts_per_tok,
                 width=c.moe_intermediate_size,
@@ -973,6 +1005,24 @@ class DecoderLM(nn.Module):
             tokens * c.num_experts_per_tok, c.hidden_size,
             c.moe_intermediate_size, self.dtype,
         )
+
+    def expert_row_tiles(self, tokens: int, held_pairs) -> tuple[int, int]:
+        """``(worked, spanned)``: of the row tiles the (token, expert)
+        pairs of a call over ``tokens`` tokens span, summed over the
+        expert layers, how many the layers work when their held experts
+        received ``held_pairs`` (``[expert layers]``) of them: every tile
+        where every expert is held, else the tiles up to each layer's
+        last held pair (:class:`ExpertMLP`)."""
+        from ..ops.grouped_matmul import live_row_tile
+
+        c = self.config
+        rows = tokens * c.num_experts_per_tok
+        tile = live_row_tile(rows)
+        spanned = -(-rows // tile) * len(held_pairs)
+        routed, held = _held_experts(c, self.expert_range)
+        if held is None or held[1] - held[0] == routed:
+            return spanned, spanned
+        return int(np.sum(-(-np.asarray(held_pairs) // tile))), spanned
 
     @nn.compact
     def __call__(self, tokens, *, train: bool = False, pos_offset=None,

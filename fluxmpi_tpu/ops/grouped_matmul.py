@@ -5,8 +5,16 @@ streamed once.
 ``[rows, k]`` with its rows sorted by group, ``w`` is ``[groups, k, n]``,
 ``group_sizes`` ``[groups]`` int32: the first ``group_sizes[0]`` rows are
 multiplied by ``w[0]``, the next ``group_sizes[1]`` by ``w[1]``, and so
-on; rows past ``sum(group_sizes)`` come out zero. Operands go to the MXU
-as given, accumulation and the ``[rows, n]`` result are float32.
+on. **Rows past ``sum(group_sizes)`` are UNSPECIFIED**, in the result and
+as operands: the kernel never fetches, zeroes or writes a row tile that
+holds none of the groups' rows (such a tile of the result is whatever the
+buffer held, NaN bit patterns among it), ``ragged_dot`` leaves there what
+its backend leaves, and neither reads ``x`` there. A caller reads the
+first ``sum(group_sizes)`` rows and nothing else
+(:class:`~fluxmpi_tpu.models.decoder.ExpertMLP` sums them back by token
+with :func:`combine`, which reads the live row tiles only).
+Operands go to the MXU as given, accumulation and the ``[rows, n]`` result
+are float32.
 
 An expert layer at serving sizes is a weight-streaming problem: 512
 (token, expert) rows over 128 experts of ``[2048, 1024]`` are three rows
@@ -18,9 +26,19 @@ an expert that received no row is never read, and one whose rows lie
 inside one row tile is read exactly once (one that straddles a tile
 boundary once a tile: :func:`weight_visits` counts them). Inside a visit
 only the ``sub_rows``-row sub-tiles that hold rows of the group are
-multiplied; a row mask keeps the neighbours' rows. The rows past the last
-group are one more group that multiplies nothing, so their tiles are
-visited and come out zero without a pass over the output.
+multiplied; a row mask keeps the neighbours' rows. The walk ends with the
+last group: a layer that holds a share of its router's experts sorts the
+pairs of the experts held elsewhere last, and their tiles cost nothing.
+
+:func:`combine` is the way back: the down projection's rows, still sorted
+by group, scaled and added into their tokens' rows. Only the first
+``live`` rows are read, so a layer whose held experts received a quarter
+of the pairs moves a quarter of the float32 result. Its kernel keeps a
+``[tokens, tile_n]`` column block of the sum in VMEM, streams the live
+row tiles past it once a column block (the steps past them stay on the
+last live tile and add nothing) and adds each row where its token says
+(scalar prefetched); anywhere but a TPU it is a masked ``segment_sum``,
+whose reverse rule the kernel is given too.
 
 What runs is chosen from what can be observed, never by an option: on a
 TPU backend, with bfloat16 operands and ``k`` and ``n`` multiples of 128
@@ -38,7 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 
 
-__all__ = ["grouped_matmul", "row_tile", "weight_visits"]
+__all__ = ["combine", "grouped_matmul", "live_row_tile", "row_tile",
+           "weight_visits"]
 
 # One weight block ``[k, tile_n]`` (double-buffered by the pipeline): all
 # of an expert's ``[2048, 1024]`` or ``[1024, 2048]``, one contiguous read.
@@ -58,6 +77,10 @@ _LANES = 128
 # benchmark's readers find the routed experts' matmul by the name XLA's
 # own has, ``ragged-dot``.
 _KERNEL_NAME = "ragged-dot-gmm"
+# The combine's kernel, and the float32 ``[tokens, tile_n]`` block of the
+# result it keeps resident (double-buffered by the pipeline).
+_COMBINE_NAME = "expert-combine"
+_COMBINE_BLOCK_BYTES = 4 * 2**20
 
 
 def _tile_rule(rows: int, k: int, n: int, itemsize: int):
@@ -70,12 +93,19 @@ def _tile_rule(rows: int, k: int, n: int, itemsize: int):
         tile_n //= 2
     if k * tile_n * itemsize > _WEIGHT_BLOCK_BYTES:
         return None
-    tile_rows = next(t for t in (_TILE_ROWS, 256, _SUB_ROWS) if rows % t == 0)
-    return tile_rows, _SUB_ROWS, tile_n
+    return live_row_tile(rows), _SUB_ROWS, tile_n
 
 
 def _padded(rows: int) -> int:
     return -(-rows // _SUB_ROWS) * _SUB_ROWS
+
+
+def live_row_tile(rows: int) -> int:
+    """The rows of one row tile of a call over ``rows`` rows (padded to
+    whole sub-tiles), from ``rows`` alone and on any backend: the
+    granularity at which the kernels' walks stop past the groups."""
+    padded = _padded(rows)
+    return next(t for t in (_TILE_ROWS, 256, _SUB_ROWS) if padded % t == 0)
 
 
 def row_tile(rows: int, k: int, n: int, x_dtype, w_dtype=None) -> int | None:
@@ -106,18 +136,19 @@ def weight_visits(group_sizes, tile_rows: int) -> int:
 @functools.partial(jax.jit, static_argnames=("rows", "tile_rows"))
 def _visits(group_sizes, rows: int, tile_rows: int):
     """The kernel's walk, one entry a grid step (``rows // tile_rows +
-    groups`` of them): the step's row tile, the weight block it holds,
-    the rows ``[lo, hi)`` of the tile that belong to its group, and
-    whether it is the tile's first visit (the output block is zeroed
-    there). The rows past the last group are one more group, the tail,
-    which multiplies nothing (``lo == hi == 0``); steps past the last
-    visit stay on the last tile as the tail, and both hold the last real
-    visit's weight block, so neither fetches one. Jitted, and in ``lax``
-    rather than ``jax.numpy``: a program's projections share one walk,
-    and a warm start pays for tracing it (PERF.md §6, PR 33)."""
+    groups`` of them, the most visits any sizes make): the step's row
+    tile, the weight block it holds, the rows ``[lo, hi)`` of the tile
+    that belong to its group, and whether it is the tile's first visit
+    (the output block is zeroed there). The walk ends with the last
+    group's last row: the steps past it stay on the last visited tile
+    and hold the last real visit's weight block with ``lo == hi == 0``
+    and ``fresh == 0``, so they fetch nothing, multiply nothing and zero
+    nothing, and a row tile past the groups is never visited. Jitted, and
+    in ``lax`` rather than ``jax.numpy``: a program's projections share
+    one walk, and a warm start pays for tracing it (PERF.md §6, PR 33)."""
     lax = jax.lax
     groups = group_sizes.shape[0]
-    tiles = rows // tile_rows
+    steps = rows // tile_rows + groups
     i32 = jnp.int32
 
     def const(value, like):
@@ -126,60 +157,58 @@ def _visits(group_sizes, rows: int, tile_rows: int):
     sizes = lax.convert_element_type(group_sizes, i32)
     ends = lax.cumsum(sizes)
     starts = lax.sub(ends, sizes)
-    total = lax.slice(ends, (groups - 1,), (groups,))
-    # With the tail: [groups + 1].
-    all_starts = lax.concatenate([starts, total], 0)
-    all_sizes = lax.concatenate([sizes, lax.sub(const(rows, total), total)], 0)
-    all_ends = lax.add(all_starts, all_sizes)
-    first = lax.div(all_starts, const(tile_rows, all_starts))
-    last = lax.div(lax.sub(all_ends, const(1, all_ends)),
-                   const(tile_rows, all_ends))
-    held = lax.gt(all_sizes, const(0, all_sizes))
+    first = lax.div(starts, const(tile_rows, starts))
+    last = lax.div(lax.sub(ends, const(1, ends)), const(tile_rows, ends))
+    held = lax.gt(sizes, const(0, sizes))
     count = lax.select(held, lax.add(lax.sub(last, first), const(1, first)),
                        const(0, first))
     visit_ends = lax.cumsum(count)
-    step = lax.iota(i32, tiles + groups)
+    step = lax.iota(i32, steps)
     # The group whose visits hold the step: how many groups' visits end
-    # at or before it (the tail's id, ``groups``, past the walk).
-    before = lax.le(
-        lax.broadcast_in_dim(visit_ends, (tiles + groups, groups + 1), (1,)),
-        lax.broadcast_in_dim(step, (tiles + groups, groups + 1), (0,)),
+    # at or before it; past the walk, every group's.
+    before = lax.reduce_sum(
+        lax.convert_element_type(
+            lax.le(lax.broadcast_in_dim(visit_ends, (steps, groups), (1,)),
+                   lax.broadcast_in_dim(step, (steps, groups), (0,))),
+            i32,
+        ),
+        (1,),
     )
-    group = lax.min(
-        lax.reduce_sum(lax.convert_element_type(before, i32), (1,)),
-        const(groups, step),
+    walking = lax.lt(before, const(groups, before))
+    group = lax.min(before, const(groups - 1, before))
+    ids = lax.iota(i32, groups)
+    last_real = lax.reduce_max(lax.select(held, ids, const(0, ids)), (0,))
+    # Past the walk: the last real visit's group, on its last tile.
+    group = lax.select(
+        walking, group, lax.broadcast_in_dim(last_real, group.shape, ())
     )
 
     def of_group(values):
         return values.at[group].get(mode="promise_in_bounds")
 
-    tile = lax.min(
+    tile = lax.select(
+        walking,
         lax.add(of_group(lax.add(lax.sub(first, visit_ends), count)), step),
-        const(tiles - 1, step),
+        lax.max(of_group(last), const(0, step)),
     )
     base = lax.mul(tile, const(tile_rows, tile))
-    zero = const(0, total)
-    real_starts = lax.concatenate([starts, zero], 0)
-    real_ends = lax.concatenate([ends, zero], 0)
 
     def within(rows_of_group):
-        return lax.clamp(const(0, base), lax.sub(rows_of_group, base),
-                         const(tile_rows, base))
+        return lax.select(
+            walking,
+            lax.clamp(const(0, base), lax.sub(rows_of_group, base),
+                      const(tile_rows, base)),
+            const(0, base),
+        )
 
-    lo, hi = within(of_group(real_starts)), within(of_group(real_ends))
+    lo, hi = within(of_group(starts)), within(of_group(ends))
     previous = lax.concatenate(
-        [const(-1, zero), lax.slice(tile, (0,), (tiles + groups - 1,))], 0
+        [lax.full((1,), -1, i32), lax.slice(tile, (0,), (steps - 1,))], 0
     )
-    fresh = lax.convert_element_type(lax.ne(tile, previous), i32)
-    ids = lax.iota(i32, groups)
-    last_real = lax.reduce_max(
-        lax.select(lax.gt(sizes, const(0, sizes)), ids, const(0, ids)), (0,)
+    fresh = lax.convert_element_type(
+        lax.bitwise_and(walking, lax.ne(tile, previous)), i32
     )
-    weight = lax.select(
-        lax.lt(group, const(groups, group)), group,
-        lax.broadcast_in_dim(last_real, group.shape, ()),
-    )
-    return tile, weight, lo, hi, fresh
+    return tile, group, lo, hi, fresh
 
 
 def _gmm_kernel(tile_ref, weight_ref, lo_ref, hi_ref, fresh_ref,
@@ -257,6 +286,158 @@ def _gmm(x, w, group_sizes, *, tiles, interpret: bool = False):
     )(*_visits(group_sizes, rows, tile_rows), x, w)
 
 
+def _combine_kernel(live_ref, token_ref, y_ref, scale_ref, o_ref, scaled_ref,
+                    *, tile_rows: int):
+    """One (column block, row tile) step of :func:`combine`: the tile's
+    live rows, scaled, each added into its token's row of ``o_ref``."""
+    from jax.experimental import pallas as pl
+
+    lax = jax.lax
+    step = pl.program_id(1)
+
+    @pl.when(step == 0)
+    def _first_tile_of_the_columns():
+        o_ref[...] = lax.full_like(o_ref[...], 0)
+
+    base = step * tile_rows
+    count = lax.clamp(jnp.int32(0), live_ref[0] - base, jnp.int32(tile_rows))
+
+    @pl.when(count > 0)
+    def _a_tile_with_live_rows():
+        scaled_ref[...] = y_ref[...] * scale_ref[...]
+
+        def row(r, carry):
+            at = pl.ds(token_ref[base + r], 1)
+            o_ref[at, :] = o_ref[at, :] + scaled_ref[pl.ds(r, 1), :]
+            return carry
+
+        lax.fori_loop(0, count, row, None)
+
+
+def _combine(y, token, scale, live, *, tokens: int, tiles,
+             interpret: bool = False):
+    """The kernel: a grid of (column blocks, row tiles), the column
+    block of the result resident while the LIVE row tiles stream past it
+    (a step past them stays on the last live tile and adds nothing)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ..parallel._compat import pallas_tpu_compiler_params
+
+    tile_rows, tile_n = tiles
+    rows, n = y.shape
+
+    def tile_of(step, live):
+        last = jax.lax.max((live[0] - 1) // tile_rows, 0)
+        return jax.lax.min(step, last)
+
+    return pl.pallas_call(
+        functools.partial(_combine_kernel, tile_rows=tile_rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n // tile_n, rows // tile_rows),
+            in_specs=[
+                pl.BlockSpec((tile_rows, tile_n),
+                             lambda j, s, live, token: (tile_of(s, live), j)),
+                pl.BlockSpec((tile_rows, 1),
+                             lambda j, s, live, token: (tile_of(s, live), 0)),
+            ],
+            out_specs=pl.BlockSpec((tokens, tile_n),
+                                   lambda j, s, live, token: (0, j)),
+            scratch_shapes=[pltpu.VMEM((tile_rows, tile_n), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((tokens, n), jnp.float32),
+        compiler_params=pallas_tpu_compiler_params(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES,
+        ),
+        interpret=interpret,
+        name=_COMBINE_NAME,
+    )(jnp.reshape(live, (1,)).astype(jnp.int32), token.astype(jnp.int32),
+      y, scale.astype(jnp.float32)[:, None])
+
+
+def _combine_tiles(rows: int, n: int, tokens: int):
+    """``(tile_rows, tile_n)`` of the combine's kernel from the static
+    shapes (``rows`` and ``tokens`` as the caller pads them: whole
+    sub-tiles, whole sublanes), or None where it does not apply: the
+    widest column block whose ``[tokens, tile_n]`` float32 part of the
+    result fits ``_COMBINE_BLOCK_BYTES``."""
+    if n % _LANES:
+        return None
+    tile_n = n
+    while tokens * tile_n * 4 > _COMBINE_BLOCK_BYTES and tile_n % 256 == 0:
+        tile_n //= 2
+    if tokens * tile_n * 4 > _COMBINE_BLOCK_BYTES:
+        return None
+    return live_row_tile(rows), tile_n
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_combine(interpret: bool, tokens: int):
+    """One jitted function a token count, as :func:`_jitted`: a program
+    lowers the kernel once a distinct shape. Its reverse rule is the
+    masked ``segment_sum``'s, in plain XLA (a live row's cotangent is
+    its token's, scaled), so the layer differentiates where the kernel
+    runs as where it does not."""
+
+    @jax.custom_vjp
+    def combine_live_rows(y, token, scale, live):
+        # Whole sub-tiles of rows, whole sublanes of tokens.
+        pad = _padded(y.shape[0]) - y.shape[0]
+        if pad:
+            y = jnp.pad(y, ((0, pad), (0, 0)))
+            token, scale = jnp.pad(token, (0, pad)), jnp.pad(scale, (0, pad))
+        whole = -(-tokens // 8) * 8
+        tiles = _combine_tiles(y.shape[0], y.shape[1], whole)
+        return _combine(y, token, scale, live, tokens=whole, tiles=tiles,
+                        interpret=interpret)[:tokens]
+
+    def forward(y, token, scale, live):
+        return combine_live_rows(y, token, scale, live), (y, token, scale,
+                                                          live)
+
+    def backward(kept, cotangent):
+        y, token, scale, live = kept
+        rows = (jnp.arange(y.shape[0]) < live)[:, None]
+        by_row = jnp.where(rows, cotangent[token], 0.0)
+        return (by_row * scale[:, None], None,
+                jnp.sum(jnp.where(rows, y, 0.0) * by_row, axis=1), None)
+
+    combine_live_rows.defvjp(forward, backward)
+
+    def expert_combine(y, token, scale, live):
+        return combine_live_rows(y, token, scale, live)
+
+    expert_combine.__name__ = expert_combine.__qualname__ = _COMBINE_NAME
+    return jax.jit(expert_combine)
+
+
+def combine(y, token, scale, live, tokens: int):
+    """The routed experts' results summed back by token: ``out[t]`` is
+    the sum of ``scale[r] * y[r]`` over the sorted rows ``r < live`` with
+    ``token[r] == t``, float32 ``[tokens, n]``. ``y`` is a
+    :func:`grouped_matmul` result ``[rows, n]`` (float32; its rows past
+    ``live`` unspecified and never read), ``token`` ``[rows]`` int32,
+    ``scale`` ``[rows]`` float32, ``live`` a scalar. On a TPU backend
+    with ``n`` a multiple of 128 it is a Pallas kernel that reads the
+    live row tiles only, each once a column block, and adds a row into
+    its token's row of the resident block; anywhere else a masked
+    ``segment_sum``."""
+    tiles = _combine_tiles(_padded(y.shape[0]), y.shape[1],
+                           -(-tokens // 8) * 8)
+    if jax.default_backend() != "tpu" or y.dtype != jnp.float32 or (
+            tiles is None):
+        # Masked before it is scaled: a row past ``live`` may hold NaN,
+        # which must reach neither the sum nor ``scale``'s gradient.
+        rows = jnp.arange(y.shape[0]) < live
+        return jax.ops.segment_sum(
+            jnp.where(rows[:, None], y, 0.0) * scale[:, None], token,
+            num_segments=tokens,
+        )
+    return _jitted_combine(False, tokens)(y, token, scale, live)
+
+
 @functools.lru_cache(maxsize=None)
 def _jitted(interpret: bool):
     """One jitted function: a program lowers the kernel once a distinct
@@ -276,8 +457,9 @@ def _jitted(interpret: bool):
 
 
 def grouped_matmul(x, w, group_sizes):
-    """``jax.lax.ragged_dot(x, w, group_sizes)`` with a float32 result;
-    see the module docstring."""
+    """``jax.lax.ragged_dot(x, w, group_sizes)`` with a float32 result
+    whose rows past ``sum(group_sizes)`` are unspecified; see the module
+    docstring."""
     if row_tile(x.shape[0], w.shape[1], w.shape[2], x.dtype, w.dtype) is None:
         return jax.lax.ragged_dot(
             x, w, group_sizes, preferred_element_type=jnp.float32

@@ -921,6 +921,9 @@ class InferenceEngine:
         # benchmark's float32 reference then lacked, PERF.md, PR 35).
         self._expert_layers = [0]
         self._expert_weight_visits = 0
+        # Row tiles the expert layers worked, and those their pairs span.
+        self._expert_row_tiles_worked = 0
+        self._expert_row_tiles = 0
         # The decode tick in flight (dispatched, its tokens not fetched),
         # the newest decode output on the device (the next step's
         # ``prev``), how many ticks were dispatched behind one in flight,
@@ -1621,6 +1624,17 @@ class InferenceEngine:
                     delivery.set_metadata(
                         expert_weight_visits_per_touched=visits / touched
                     )
+                # Row tiles of the sorted pairs the layers worked (a layer
+                # that holds a share of its experts stops at the tile of
+                # its last held pair) over those every pair spans.
+                tiles = getattr(self.model, "expert_row_tiles", None)
+                if tiles is not None:
+                    worked, spanned = tiles(self.slots, by_layer.sum(axis=1))
+                    self._expert_row_tiles_worked += worked
+                    self._expert_row_tiles += spanned
+                    delivery.set_metadata(
+                        expert_row_tiles_worked_pct=100.0 * worked / spanned
+                    )
 
     def _flush(self) -> None:
         """Fetch and deliver the tick in flight, dispatching no other."""
@@ -1713,7 +1727,13 @@ class InferenceEngine:
         where the grouped matmul is the Pallas kernel,
         ``expert_weight_visits`` ((row tile, expert) visits a projection
         made: ``experts_touched`` when each touched expert's weights
-        streamed once). ``decode_steps_overlapped``: the decode programs
+        streamed once); ``expert_row_tiles_worked`` over
+        ``expert_row_tiles``: the row tiles of the sorted (token, expert)
+        pairs the expert layers multiplied and combined, over the
+        row tiles all the tick's pairs span (equal where every expert is
+        held; a layer that holds a share of its router's experts stops
+        at its last held pair's tile; both 0 without expert layers).
+        ``decode_steps_overlapped``: the decode programs
         dispatched while the one before was still unfetched (over
         ``decode_steps``: how often the device went from tick to tick
         with no host turn between); ``tokens_discarded``: tokens a slot
@@ -1753,6 +1773,8 @@ class InferenceEngine:
             "experts_touched": self._experts_touched,
             "expert_slots": self._expert_slots,
             "expert_weight_visits": self._expert_weight_visits,
+            "expert_row_tiles_worked": self._expert_row_tiles_worked,
+            "expert_row_tiles": self._expert_row_tiles,
             "decode_steps_overlapped": self._steps_overlapped,
             "tokens_discarded": self._tokens_discarded,
             "state_entries": self._states_held,

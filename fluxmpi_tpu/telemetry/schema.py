@@ -401,6 +401,16 @@ HOT_PATH_SPAN_ARGS: dict[str, tuple[str, ...]] = {
                              "stalled"),
 }
 
+# Arguments a hot-path span carries only where they have a meaning (a
+# stalled gap, a model with expert layers, the grouped matmul's kernel):
+# documented beside the required ones, held by no validator.
+HOT_PATH_SPAN_OPTIONAL_ARGS: dict[str, tuple[str, ...]] = {
+    "serve.decode.deliver": ("stalled_by", "experts_touched_pct",
+                             "expert_load_max_over_mean",
+                             "expert_weight_visits_per_touched",
+                             "expert_row_tiles_worked_pct"),
+}
+
 # Anomaly trace events (AnomalyDetector triggers): "anomaly.<rule>"
 # instants carrying the rule name and the update count — same
 # instant-only contract as the preemption event (an anomaly is a point

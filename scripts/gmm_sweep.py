@@ -14,10 +14,10 @@ host clock around the loop, best of three), ``lower_s`` (what
 ``jit(call).lower()`` takes in Python, paid at every start of a program
 that holds the call), ``gbps`` (the touched experts' bytes over ``ms``),
 ``visits`` and ``touched`` (``weight_visits``), ``max_err`` against
-``ragged_dot`` on the same operands over the live rows and ``tail_max``,
-the largest magnitude in the rows past the last group (the kernel's are
-zero; XLA's op leaves what it finds there). ``--tiles`` is ``tile_rows,sub_rows,
-tile_n;...``; without it the module's own rule. A tile the chip's
+``ragged_dot`` on the same operands over the live rows (the rows past
+the last group are unspecified: the kernel leaves a row tile past the
+groups unwritten, XLA's op what it finds there). ``--tiles`` is
+``tile_rows,sub_rows,tile_n;...``; without it the module's own rule. A tile the chip's
 compiler refuses is reported as ``error``. The rule's constants in
 ``ops/grouped_matmul.py`` are read off such sweeps.
 
@@ -148,8 +148,6 @@ def main(argv=None) -> int:
                             gbps=touched * k * n * 2 / ms / 1e9,
                             max_err=float(jnp.max(jnp.abs(
                                 jnp.where(live_mask, got - want, 0.0)))),
-                            tail_max=float(jnp.max(jnp.abs(
-                                jnp.where(live_mask, 0.0, got)))),
                         )
                         if name == "kernel":
                             row["visits"] = G.weight_visits(counts, t[0])

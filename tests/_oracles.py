@@ -1,5 +1,6 @@
-"""Shared dense-attention oracles for the test suite (single source — the
-segment-mask semantics must not drift between test files)."""
+"""Shared oracles for the test suite (single source — the segment-mask
+semantics, and what a grouped matmul may leave past its groups, must not
+drift between test files)."""
 
 import numpy as np
 
@@ -25,3 +26,17 @@ def dense_seg_attention(q, k, v, qseg, kseg, causal=False, window=None):
     s = jnp.where(mask[:, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+def poisoned_past_the_groups(grouped):
+    """``grouped`` (a grouped matmul ``(x, w, sizes)``) with every row of
+    its result past ``sum(sizes)`` NaN: what its contract lets a backend
+    leave there (``fluxmpi_tpu.ops.grouped_matmul``), so a caller that
+    reads such a row shows."""
+
+    def poisoned(x, w, sizes):
+        out = grouped(x, w, sizes)
+        live = jnp.arange(out.shape[0])[:, None] < jnp.sum(sizes)
+        return jnp.where(live, out, jnp.nan)
+
+    return poisoned

@@ -228,7 +228,7 @@ _ENGINE_COUNTERS = (
     "kv_blocks_live", "kv_blocks_tabled", "context_tokens", "kv_blocks_full",
     "kv_blocks_window", "kv_blocks_uniform", "expert_tokens",
     "experts_touched", "expert_slots", "expert_weight_visits",
-    "decode_steps_overlapped", "tokens_discarded", "state_entries",
+    "expert_row_tiles_worked", "expert_row_tiles", "decode_steps_overlapped", "tokens_discarded", "state_entries",
     "state_entries_used", "state_bytes", "gaps", "gaps_stalled",
     "gap_seconds", "gap_stalled_seconds",
 )
@@ -297,15 +297,18 @@ _TRACED = {
                       "kv_blocks_read_pct", "decode_ticks_in_flight"),
     "tiny-trinity-serve": ("decode_host_ms", "decode_active_slots",
                            "kv_blocks_read_pct", "experts_touched_pct",
+                           "expert_row_tiles_worked_pct",
                            "decode_ticks_in_flight"),
     # Latent attention: the new span argument, the held experts' counters.
     "tiny-sarvam-serve": ("decode_host_ms", "decode_active_slots",
                           "kv_blocks_read_pct", "experts_touched_pct",
                           "expert_load_max_over_mean",
+                          "expert_row_tiles_worked_pct",
                           "decode_context_tokens", "decode_ticks_in_flight"),
     # Mamba-2 layers: the state kind's span argument beside the K/V's.
     "tiny-granite-serve": ("decode_host_ms", "decode_active_slots",
                            "kv_blocks_read_pct", "experts_touched_pct",
+                           "expert_row_tiles_worked_pct",
                            "decode_context_tokens", "decode_ticks_in_flight",
                            "ssm_states_read_pct"),
 }
@@ -364,3 +367,12 @@ def test_traced_rehearsal_run_exits_0_and_reads_its_spans(name, tmp_path):
         assert f"cpu_rehearsal.{metric}" not in metrics
     visits = metrics.get("cpu_rehearsal.expert_weight_visits_per_touched")
     assert visits is None or visits["value"] >= 1.0
+    # Every expert held: every row tile worked; a model without expert
+    # layers has no such span argument to read.
+    worked = metrics.get("cpu_rehearsal.expert_row_tiles_worked_pct")
+    if name == "tiny-lm-serve":
+        assert worked is None
+    elif name == "tiny-trinity-serve":
+        assert worked["value"] == 100.0
+    else:
+        assert 0.0 <= worked["value"] <= 100.0

@@ -16,6 +16,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from _oracles import poisoned_past_the_groups
 from fluxmpi_tpu.models import DecoderConfig, ExpertMLP
 from fluxmpi_tpu.models.decoder import MambaMixer
 from fluxmpi_tpu.ops.ssm import (from_pool_layout, ssm_state_update_reference,
@@ -367,6 +368,47 @@ def test_four_shares_and_the_shared_mlp_once_add_up_to_the_uncut_layer():
     first = {k: (v[:4] if k in ("ew1", "ew3", "ew2") else v)
              for k, v in w.items()}
     np.testing.assert_allclose(parts[0], ref.feed_forward(u, first, cut),
+                               rtol=0, atol=2e-6)
+
+
+@pytest.mark.parametrize("steer", ["as_routed", "all_to_one_share"])
+def test_shares_read_no_row_past_their_own_pairs(steer, monkeypatch):
+    """The same cut with the rows past each share's pairs poisoned: the
+    parts still sum to the uncut layer. ``all_to_one_share``: the
+    router's columns for experts 8-11 are raised, so that share receives
+    every pair it can (4 of a token's top-k)."""
+    from fluxmpi_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(
+        gm, "grouped_matmul", poisoned_past_the_groups(gm.grouped_matmul))
+    whole = _cfg(num_local_experts=16)
+    w = ref.layer_weights(whole, jax.random.PRNGKey(5), 1, mixer=False)
+    u = jax.random.normal(jax.random.PRNGKey(6), (48, whole["hidden_size"]))
+    bias = jnp.zeros((16,))
+    if steer == "all_to_one_share":
+        bias = jnp.where((jnp.arange(16) >= 8) & (jnp.arange(16) < 12),
+                         50.0, 0.0)
+    pairs = u.shape[0] * whole["num_experts_per_tok"]
+    total, received = 0.0, []
+    for lo in range(0, 16, 4):
+        layer = _expert_layer(whole, (lo, lo + 4), include_shared=lo == 0)
+        params = dict(_layer_params(w, lo, lo + 4), bias=bias)
+        part, state = layer.apply(
+            {"params": params}, u, mutable=["intermediates"])
+        assert bool(jnp.all(jnp.isfinite(part)))
+        total = total + part
+        received.append(
+            int(state["intermediates"]["expert_tokens"][0].sum()))
+    assert sum(received) == pairs
+    if steer == "all_to_one_share":
+        assert received[2] == u.shape[0] * min(
+            4, whole["num_experts_per_tok"])
+        # The reference routes without a bias: against the uncut layer.
+        uncut = _expert_layer(whole, None).apply(
+            {"params": dict(_layer_params(w, 0, 16), bias=bias)}, u)
+        np.testing.assert_allclose(total, uncut, rtol=0, atol=2e-6)
+        return
+    np.testing.assert_allclose(total, ref.feed_forward(u, w, whole),
                                rtol=0, atol=2e-6)
 
 

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _oracles import poisoned_past_the_groups
 from fluxmpi_tpu.ops import grouped_matmul as gm
 
 # (rows, k, n), tiles (tile_rows, sub_rows, tile_n), group sizes.
@@ -21,7 +22,7 @@ _CASES = {
                              [0, 0, 24, 0, 0, 0, 40, 0]),
     "one_group_holds_every_row": ((64, 128, 256), (32, 16, 256),
                                   [0, 0, 64, 0]),
-    "tail_comes_out_zero": ((64, 128, 256), (32, 16, 128), [3, 5, 0, 7]),
+    "tail_tile_never_visited": ((64, 128, 256), (32, 16, 128), [3, 5, 0, 7]),
     "no_row_at_all": ((64, 128, 256), (32, 16, 128), [0, 0, 0, 0]),
     "group_straddles_row_tiles": ((96, 128, 128), (32, 16, 128),
                                   [20, 50, 26]),
@@ -46,7 +47,12 @@ def _reference(x, w, sizes):
         x.astype(jnp.float32), w.astype(jnp.float32), sizes,
         precision=jax.lax.Precision.HIGHEST,
     )
-    live = jnp.arange(x.shape[0])[:, None] < jnp.sum(sizes)
+    return _live(out, sizes)
+
+
+def _live(out, sizes):
+    """What a caller may read: the groups' rows (the rest made zero)."""
+    live = jnp.arange(out.shape[0])[:, None] < jnp.sum(sizes)
     return jnp.where(live, out, 0.0)
 
 
@@ -57,10 +63,14 @@ def test_kernel_matches_ragged_dot(name):
     got = gm._gmm(x, w, sizes, tiles=tiles, interpret=True)
     assert got.dtype == jnp.float32 and got.shape == (shape[0], shape[2])
     np.testing.assert_allclose(
-        got, _reference(x, w, sizes), rtol=1e-5, atol=1e-4
+        _live(got, sizes), _reference(x, w, sizes), rtol=1e-5, atol=1e-4
     )
-    total = int(np.sum(np.asarray(sizes)))
-    assert not np.any(np.asarray(got[total:]))
+    # A row tile that holds a row of the groups is zeroed past them; one
+    # that holds none was never written (the interpreter leaves NaN).
+    total, tile_rows = int(np.sum(np.asarray(sizes))), tiles[0]
+    worked = -(-total // tile_rows) * tile_rows
+    assert not np.any(np.asarray(got[total:worked]))
+    assert np.all(np.isnan(np.asarray(got[max(worked, tile_rows):])))
 
 
 @pytest.mark.parametrize("name", sorted(_CASES))
@@ -83,17 +93,27 @@ def test_walk_visits_each_touched_group_once_a_row_tile(name):
     real = hi > lo
     assert list(zip(tile[real], weight[real], lo[real], hi[real])) == want
     assert gm.weight_visits(sizes, tile_rows) == len(want)
-    # Every row tile is visited (so it is zeroed, once), in order; the
-    # tail and the steps past the walk multiply nothing and hold the last
-    # real visit's weight block.
-    assert sorted(set(tile.tolist())) == list(range(rows // tile_rows))
+    # Real visits come first, and fetch as many weight blocks as
+    # ``weight_visits`` counts: the block changes at a real visit only.
+    assert real[: len(want)].all() and not real[len(want):].any()
+    fetched = 1 + np.count_nonzero(np.diff(weight)) if want else 0
+    assert fetched <= len(want)
+    assert len({(t, g) for t, g, _, _ in want}) == len(want)
+    # The row tiles that hold a row of the groups are visited in order
+    # and zeroed once, at their first visit; no tile past them is: the
+    # steps past the walk stay on the last visited tile with the last
+    # real visit's weight block, and multiply and zero nothing.
+    worked = -(-int(ends[-1]) // tile_rows)
+    assert sorted(set(tile.tolist())) == list(range(max(worked, 1)))
     assert np.all(np.diff(tile) >= 0)
-    assert fresh.tolist() == [1, *(np.diff(tile) != 0).astype(int)]
+    walked = len(want)
+    assert fresh[:walked].tolist() == (
+        [1, *(np.diff(tile[:walked]) != 0).astype(int)] if want else [])
+    assert not fresh[walked:].any()
     assert np.all(lo[~real] == 0) and np.all(hi[~real] == 0)
     if want:
         assert np.all(weight[~real] == want[-1][1])
-        # Real visits come first.
-        assert real[: len(want)].all() and not real[len(want):].any()
+        assert np.all(tile[~real] == want[-1][0])
 
 
 def test_weight_visits_counts_calls_over_leading_dimensions():
@@ -115,7 +135,7 @@ def test_jitted_kernel_pads_rows_the_row_tile_does_not_divide(
     got = fn(x, w, sizes)
     assert got.shape == (rows, 128)
     np.testing.assert_allclose(
-        got, _reference(x, w, sizes), rtol=1e-5, atol=1e-4
+        _live(got, sizes), _reference(x, w, sizes), rtol=1e-5, atol=1e-4
     )
 
 
@@ -134,8 +154,8 @@ def test_kernel_under_an_outer_jit_lowers_once_a_shape():
     assert text.count('call @"ragged-dot-gmm"(') == 3
     assert text.count("func.func private @_visits(") == 1
     np.testing.assert_allclose(
-        three(x, w, sizes), 3 * _reference(x, w, sizes), rtol=1e-5,
-        atol=3e-4,
+        _live(three(x, w, sizes), sizes), 3 * _reference(x, w, sizes),
+        rtol=1e-5, atol=3e-4,
     )
 
 
@@ -347,3 +367,269 @@ def test_decoder_lm_row_tile_follows_the_rule(monkeypatch):
     assert bf16.expert_row_tile(256) == 512
     dense = dataclasses.replace(wide, num_dense_layers=wide.num_layers)
     assert type(model)(dense, dtype=jnp.bfloat16).expert_row_tile(64) is None
+
+
+# ---------------------------------------------------------------------------
+# A layer that holds a share of its router's experts works on its own
+# pairs only: rows past them are unspecified and never read
+# ---------------------------------------------------------------------------
+
+_SHARE = dict(num_experts=8, top_k=2, width=128, include_shared=False)
+
+
+def _share_layers(dtype, monkeypatch, kernel):
+    """The uncut layer's parameters, a function that applies the share
+    ``(lo, hi)`` of them, and tokens whose router is steered: the first
+    half choose among experts 0-3 only, the rest among all eight."""
+    from fluxmpi_tpu.models.decoder import ExpertMLP
+
+    monkeypatch.setattr(gm, "_SUB_ROWS", 16)
+    monkeypatch.setattr(gm, "_TILE_ROWS", 64)
+    if kernel:
+        fn = gm._jitted.__wrapped__(True)
+        monkeypatch.setattr(
+            gm, "combine", lambda y, token, scale, live, tokens: gm._combine(
+                y, token, scale, live, tokens=tokens, interpret=True,
+                tiles=gm._combine_tiles(y.shape[0], y.shape[1], tokens)))
+    else:
+        def fn(x, w, sizes):
+            return jax.lax.ragged_dot(
+                x, w, sizes, preferred_element_type=jnp.float32)
+    monkeypatch.setattr(gm, "grouped_matmul", poisoned_past_the_groups(fn))
+    whole = ExpertMLP(dtype=dtype, **_SHARE)
+    u = jax.random.normal(jax.random.PRNGKey(3), (1, 128, 128), dtype)
+    params = whole.init(jax.random.PRNGKey(4), u)["params"]
+
+    def share(lo, hi, u, token_mask=None, params=params):
+        layer = ExpertMLP(dtype=dtype, expert_range=(lo, hi), **_SHARE)
+        cut = {k: (v[lo:hi] if k in ("w1", "w2", "w3") else v)
+               for k, v in params.items()}
+        out, state = layer.apply({"params": cut}, u, token_mask,
+                                 mutable=["intermediates"])
+        return out, state["intermediates"]["expert_tokens"][0]
+
+    return whole, params, share, u
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "masked"])
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["ragged_dot_float32", "kernel_bfloat16"])
+def test_shares_add_up_though_rows_past_the_groups_are_poison(
+        kernel, masked, monkeypatch):
+    dtype = jnp.bfloat16 if kernel else jnp.float32
+    whole, params, share, u = _share_layers(dtype, monkeypatch, kernel)
+    # Every token to experts 0-3: the share (0, 4) receives EVERY pair
+    # (all its row tiles are worked), the share (4, 8) none.
+    steered = dict(params, bias=jnp.where(jnp.arange(8) < 4, 100.0, 0.0))
+    mask = (jnp.arange(128) % 3 != 0)[None] if masked else None
+    tokens = int(mask.sum()) if masked else 128
+    uncut = whole.apply({"params": steered}, u, mask)
+    assert bool(jnp.all(jnp.isfinite(uncut.astype(jnp.float32))))
+    every, got_every = share(0, 4, u, mask, steered)
+    none, got_none = share(4, 8, u, mask, steered)
+    assert int(got_every.sum()) == tokens * 2 and int(got_none.sum()) == 0
+    assert not np.any(np.asarray(none, np.float32))
+    tol = dict(rtol=2e-2, atol=2e-3) if kernel else dict(rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(every, np.float32), np.asarray(uncut, np.float32), **tol)
+    # Unsteered: three unequal shares, each a part of the pairs.
+    uncut = whole.apply({"params": params}, u, mask)
+    parts = [share(lo, hi, u, mask) for lo, hi in ((0, 1), (1, 5), (5, 8))]
+    assert sum(int(got.sum()) for _, got in parts) == tokens * 2
+    assert all(0 < int(got.sum()) < tokens * 2 for _, got in parts)
+    total = sum(np.asarray(out, np.float32) for out, _ in parts)
+    assert np.all(np.isfinite(total))
+    np.testing.assert_allclose(total, np.asarray(uncut, np.float32), **tol)
+
+
+def test_grad_through_a_share_matches_dense_over_the_held_experts(
+        monkeypatch):
+    whole, params, share, u = _share_layers(jnp.float32, monkeypatch, False)
+    lo, hi = 2, 5
+    target = jax.random.normal(jax.random.PRNGKey(5), u.shape)
+
+    def dense(params, u):
+        flat = u.reshape(-1, u.shape[-1])
+        experts, weights = whole.route(flat, params["router"], params["bias"])
+        out = jnp.zeros_like(flat)
+        for e in range(lo, hi):
+            h = jax.nn.silu(flat @ params["w1"][e]) * (flat @ params["w3"][e])
+            weight = jnp.sum(jnp.where(experts == e, weights, 0.0), axis=-1)
+            out = out + weight[:, None] * (h @ params["w2"][e])
+        return jnp.sum(out.reshape(u.shape) * target)
+
+    def routed(params, u):
+        return jnp.sum(share(lo, hi, u, params=params)[0] * target)
+
+    want = jax.grad(dense, argnums=(0, 1))(params, u)
+    got = jax.grad(routed, argnums=(0, 1))(params, u)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert np.all(np.isfinite(np.asarray(a)))
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-6)
+    # Experts the share does not hold get no gradient from it.
+    for name in ("w1", "w2", "w3"):
+        assert not np.any(np.asarray(got[0][name][:lo]))
+        assert not np.any(np.asarray(got[0][name][hi:]))
+
+
+def _static_routed(layer, params, u):
+    """``ExpertMLP`` with every expert held and no mask as it stood before
+    a share's work was bounded (PR 39: every pass over all the rows at
+    once, no loop, no branch, no mask), for the program text."""
+    n, k, d = layer.num_experts, layer.top_k, u.shape[-1]
+    u = u.reshape(-1, d)
+    tokens = u.shape[0]
+    experts, weights = layer.route(u, params["router"], params["bias"])
+    flat = experts.reshape(-1)
+    rank = (flat - 0) % n
+    counts = jnp.zeros((n,), jnp.int32).at[flat].add(1, mode="drop")
+    order = jnp.argsort(rank, stable=True)
+    sizes = counts[0:n]
+    rows = u.astype(layer.dtype)[order // k]
+
+    def grouped(x, w):
+        return gm.grouped_matmul(
+            x.astype(layer.dtype), w.astype(layer.dtype), sizes)
+
+    gate, up = grouped(rows, params["w1"]), grouped(rows, params["w3"])
+    y = grouped(jax.nn.silu(gate) * up, params["w2"])
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    y = y[back].reshape(tokens, k, d)
+    return jnp.sum(y * weights[..., None], axis=1).astype(layer.dtype)
+
+
+@pytest.mark.parametrize("held", [None, (0, 8)], ids=["default", "range"])
+def test_every_expert_held_lowers_to_the_static_program(held):
+    from fluxmpi_tpu.models.decoder import ExpertMLP
+
+    layer = ExpertMLP(dtype=jnp.float32, expert_range=held, **_SHARE)
+    u = jax.random.normal(jax.random.PRNGKey(3), (64, 128))
+    params = layer.init(jax.random.PRNGKey(4), u)["params"]
+    got = jax.jit(
+        lambda p, u: layer.apply({"params": p}, u)).lower(params, u).as_text()
+    want = jax.jit(
+        lambda p, u: _static_routed(layer, p, u)).lower(params, u).as_text()
+    assert "stablehlo.while" not in got and "stablehlo.case" not in got
+    assert got == want
+    # A share of the experts differs in its combine only (on a CPU a
+    # ``segment_sum``: a scatter-add where the gather by token stood).
+    cut = ExpertMLP(dtype=jnp.float32, expert_range=(0, 4), **_SHARE)
+    params = cut.init(jax.random.PRNGKey(4), u)["params"]
+    text = jax.jit(
+        lambda p, u: cut.apply({"params": p}, u)).lower(params, u).as_text()
+    assert "stablehlo.while" not in text and "stablehlo.case" not in text
+    assert text.count("stablehlo.scatter") == got.count(
+        "stablehlo.scatter")  # the counts, the combine; no ``back``
+
+
+# ---------------------------------------------------------------------------
+# The combine: the live rows added into their tokens' rows
+# ---------------------------------------------------------------------------
+
+
+def _combine_operands(rows, n, tokens, live, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    y = jax.random.normal(keys[0], (rows, n), jnp.float32)
+    token = jax.random.randint(keys[1], (rows,), 0, tokens)
+    scale = jax.random.uniform(keys[2], (rows,))
+    # Rows past the live ones hold what the contract allows: NaN.
+    y = jnp.where((jnp.arange(rows) < live)[:, None], y, jnp.nan)
+    return y, token, scale
+
+
+def _combine_by_hand(y, token, scale, live, tokens):
+    out = np.zeros((tokens, y.shape[1]), np.float64)
+    for r in range(live):
+        out[int(token[r])] += float(scale[r]) * np.asarray(y[r], np.float64)
+    return out
+
+
+@pytest.mark.parametrize("live", [0, 1, 63, 64, 65, 200, 256])
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
+def test_combine_adds_live_rows_only(kernel, live):
+    rows, n, tokens = 256, 256, 40
+    y, token, scale = _combine_operands(rows, n, tokens, live, seed=live)
+    if kernel:
+        got = gm._combine(y, token, scale, jnp.int32(live), tokens=tokens,
+                          tiles=(64, 128), interpret=True)
+    else:
+        got = gm.combine(y, token, scale, jnp.int32(live), tokens)
+    assert got.shape == (tokens, n) and got.dtype == jnp.float32
+    np.testing.assert_allclose(
+        got, _combine_by_hand(y, token, scale, live, tokens),
+        rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("shape,want", [
+    ((1280, 4096, 128), (256, 4096)),   # a tick: 128 slots x top-10
+    ((20480, 4096, 2048), (512, 512)),  # a 2,048-token prompt
+    ((32768, 4096, 4096), (512, 256)),  # a slab of 4,096 tokens x top-8
+    ((384, 4096, 48), (128, 4096)),
+    ((128, 96, 16), None),              # narrow columns
+    ((128, 128, 16384), None),          # no column block fits
+])
+def test_combine_tiles_read_shapes_only(shape, want):
+    rows, n, tokens = shape
+    assert gm._combine_tiles(rows, n, tokens) == want
+    if want is not None:
+        assert tokens * want[1] * 4 <= gm._COMBINE_BLOCK_BYTES
+        assert n % want[1] == 0 and rows % want[0] == 0
+
+
+def test_combine_on_a_cpu_is_the_masked_segment_sum(monkeypatch):
+    y, token, scale = _combine_operands(128, 128, 16, 50)
+    want = jax.ops.segment_sum(
+        jnp.where((jnp.arange(128) < 50)[:, None], y, 0.0) * scale[:, None],
+        token, num_segments=16)
+    np.testing.assert_array_equal(
+        np.asarray(gm.combine(y, token, scale, jnp.int32(50), 16)),
+        np.asarray(want))
+    # Float32 rows of whole lanes on a TPU: the kernel's.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    called = []
+    monkeypatch.setattr(gm, "_jitted_combine",
+                        lambda interpret, tokens: called.append(tokens) or (
+                            lambda *a: "kernel"))
+    assert gm.combine(y, token, scale, jnp.int32(50), 16) == "kernel"
+    assert called == [16]
+
+
+@pytest.mark.parametrize("rows,tokens", [(256, 40), (200, 40), (72, 13)])
+def test_jitted_combine_pads_rows_and_tokens_to_whole_tiles(
+        rows, tokens, monkeypatch):
+    monkeypatch.setattr(gm, "_SUB_ROWS", 16)
+    monkeypatch.setattr(gm, "_TILE_ROWS", 64)
+    live = rows - 9
+    y, token, scale = _combine_operands(rows, 128, tokens, live, seed=rows)
+    got = gm._jitted_combine.__wrapped__(True, tokens)(
+        y, token, scale, jnp.int32(live))
+    assert got.shape == (tokens, 128)
+    np.testing.assert_allclose(
+        got, _combine_by_hand(y, token, scale, live, tokens),
+        rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("live", [0, 70, 200])
+def test_combine_kernel_differentiates_as_the_masked_segment_sum(
+        live, monkeypatch):
+    monkeypatch.setattr(gm, "_SUB_ROWS", 16)
+    monkeypatch.setattr(gm, "_TILE_ROWS", 64)
+    rows, tokens = 200, 40
+    y, token, scale = _combine_operands(rows, 128, tokens, live, seed=live)
+    target = jax.random.normal(jax.random.PRNGKey(9), (tokens, 128))
+    kernel = gm._jitted_combine.__wrapped__(True, tokens)
+
+    def loss(fn):
+        return lambda y, scale: jnp.sum(
+            fn(y, token, scale, jnp.int32(live)) * target)
+
+    got = jax.grad(loss(kernel), argnums=(0, 1))(y, scale)
+    want = jax.grad(
+        loss(lambda *a: gm.combine(*a, tokens)), argnums=(0, 1))(y, scale)
+    for a, b in zip(got, want):
+        # Rows past the live ones (NaN operands) get an exact zero.
+        assert np.all(np.isfinite(np.asarray(a)))
+        assert not np.any(np.asarray(a[live:]))
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)

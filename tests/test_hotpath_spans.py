@@ -349,7 +349,8 @@ def test_engine_stats_agree_with_delivery(tiny_lm):
                           "kv_blocks_full",
                           "kv_blocks_window", "kv_blocks_uniform",
                           "expert_tokens", "experts_touched", "expert_slots",
-                          "expert_weight_visits", "decode_steps_overlapped",
+                          "expert_weight_visits", "expert_row_tiles_worked",
+                          "expert_row_tiles", "decode_steps_overlapped",
                           "tokens_discarded", "state_entries",
                           "state_entries_used", "state_bytes", "gaps",
                           "gaps_stalled", "gap_seconds",

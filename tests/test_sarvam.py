@@ -16,6 +16,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from _oracles import poisoned_past_the_groups
 from fluxmpi_tpu.models import DecoderConfig, ExpertMLP
 from fluxmpi_tpu.models import decoder as decoder_mod
 from fluxmpi_tpu.models.decoder import LatentAttention
@@ -330,6 +331,85 @@ def test_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
              for k, v in w.items()}
     np.testing.assert_allclose(parts[0], ref.expert_layer(u, first, cut),
                                rtol=0, atol=2e-5)
+
+
+@pytest.mark.parametrize("steer", ["uneven", "all_to_one_share"])
+def test_shares_read_no_row_past_their_own_pairs(steer, monkeypatch):
+    """The same cut with the rows past each share's pairs poisoned: the
+    parts still sum to the uncut layer. ``all_to_one_share``: every pair
+    goes to experts 4-7, so two shares receive every pair between them
+    and six receive nothing."""
+    from fluxmpi_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(
+        gm, "grouped_matmul", poisoned_past_the_groups(gm.grouped_matmul))
+    whole = _cfg(num_experts=16)
+    w = ref.layer_weights(whole, jax.random.PRNGKey(5), 1)
+    if steer == "uneven":
+        w["bias"] = w["bias"].at[2].set(4.0).at[5].set(-4.0)
+    else:
+        w["bias"] = jnp.where((jnp.arange(16) >= 4) & (jnp.arange(16) < 8),
+                              50.0, 0.0)
+    u = jax.random.normal(jax.random.PRNGKey(6), (48, whole["hidden_size"]))
+    pairs = u.shape[0] * whole["num_experts_per_tok"]
+    total, received = 0.0, []
+    for lo in range(0, 16, 2):
+        layer = _expert_layer(whole, (lo, lo + 2), include_shared=lo == 0)
+        part, state = layer.apply(
+            {"params": _layer_params(w, lo, lo + 2)}, u,
+            mutable=["intermediates"])
+        assert bool(jnp.all(jnp.isfinite(part)))
+        total = total + part
+        received.append(
+            int(state["intermediates"]["expert_tokens"][0].sum()))
+    assert sum(received) == pairs
+    if steer == "all_to_one_share":
+        assert received[2] + received[3] == pairs
+    np.testing.assert_allclose(total, ref.expert_layer(u, w, whole),
+                               rtol=0, atol=2e-5)
+
+
+def test_tick_says_the_row_tiles_its_expert_layers_worked(monkeypatch):
+    from fluxmpi_tpu.ops import grouped_matmul as gm
+    from fluxmpi_tpu.telemetry import schema, tracing
+
+    # 2 slots x top-4 = 8 rows a tick: four row tiles of 2.
+    monkeypatch.setattr(gm, "_SUB_ROWS", 2)
+    monkeypatch.setattr(gm, "_TILE_ROWS", 2)
+    cfg = _cfg()
+    model, variables, _ = _model_and_weights(cfg)
+    assert model.expert_row_tiles(2, [0, 0]) == (0, 8)
+    assert model.expert_row_tiles(2, [1, 8]) == (1 + 4, 8)
+    assert model.expert_row_tiles(2, [3, 4]) == (2 + 2, 8)
+    eng = InferenceEngine(model, variables, slots=2, block_size=BLOCK,
+                          max_len=64, check_memory=False)
+    try:
+        tracer = tracing.Tracer(enabled=True)
+        previous = tracing.set_tracer(tracer)
+        try:
+            eng.submit(np.arange(11, dtype=np.int32), 4)
+            eng.submit(np.arange(20, dtype=np.int32), 4)
+            eng.run()
+        finally:
+            tracing.set_tracer(previous)
+        stats = eng.stats()
+        spans = [e["args"] for e in tracer.export()["traceEvents"]
+                 if e.get("name") == "serve.decode.deliver"]
+    finally:
+        eng.close()
+    # Two expert layers, four tiles each, every tick; 4 of 16 experts
+    # held: a tick's 8 pairs leave most tiles to the other shares.
+    assert stats["expert_row_tiles"] == 8 * stats["decode_steps"]
+    assert 0 < stats["expert_row_tiles_worked"] < stats["expert_row_tiles"]
+    known = (set(schema.HOT_PATH_SPAN_ARGS["serve.decode.deliver"])
+             | set(schema.HOT_PATH_SPAN_OPTIONAL_ARGS["serve.decode.deliver"]))
+    assert all(set(a) <= known for a in spans)
+    said = [a["expert_row_tiles_worked_pct"] for a in spans]
+    assert len(said) == stats["decode_steps"]
+    assert sum(said) / 100 * 8 == pytest.approx(
+        stats["expert_row_tiles_worked"])
+    assert stats["expert_row_tiles_worked"] <= -(
+        -stats["expert_tokens"] // 2) + 2 * stats["decode_steps"]
 
 
 def test_feed_forwards_take_long_prompts_in_equal_slabs(monkeypatch):

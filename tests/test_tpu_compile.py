@@ -217,6 +217,34 @@ def test_grouped_matmul_kernel_compiles_for_v5e(topo, as_on_tpu, tokens,
     assert compiled.memory_analysis().temp_size_in_bytes < 2**21
 
 
+# The combine of a layer that holds a share of its experts: a decode
+# tick's rows (granite's 128 slots x top-10, sarvam's 48 x top-8), the
+# longest prefill bucket and slab (2,048 tokens x 10; 4,096 x 8).
+@pytest.mark.parametrize("tokens,top_k,tiles", [
+    (128, 10, (256, 4096)), (48, 8, (128, 4096)),
+    (2048, 10, (512, 512)), (4096, 8, (512, 256)),
+])
+def test_expert_combine_kernel_compiles_for_v5e(topo, as_on_tpu, tokens,
+                                                top_k, tiles):
+    from fluxmpi_tpu.ops import grouped_matmul as gm
+
+    dev = topo.devices[0]
+    rows, n = tokens * top_k, 4096
+    assert gm._combine_tiles(rows, n, tokens) == tiles
+    compiled = jax.jit(
+        lambda y, token, scale, live: gm.combine(y, token, scale, live,
+                                                 tokens)
+    ).lower(
+        _sds((rows, n), jnp.float32, dev), _sds((rows,), jnp.int32, dev),
+        _sds((rows,), jnp.float32, dev), _sds((), jnp.int32, dev),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(re.findall(r"%expert-combine[.\d]* = ", text)) == 1
+    # No pass over the rows outside the kernel: nothing gathered whole.
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**21
+
+
 def test_gpt2_medium_serving_programs_compile_for_v5e(topo, as_on_tpu):
     """The serve cell's decode program and a prefill bucket — GPT-2
     medium, 32 slots x 1,024 positions in 128-token blocks, bf16 pools
@@ -457,13 +485,15 @@ def test_sarvam_105b_serving_programs_compile_for_v5e(topo, as_on_tpu):
     finally:
         engine.close()
     text = decode.as_text()
-    assert text.count("tpu_custom_call") == 5 + 4 * 3
+    assert text.count("tpu_custom_call") == 5 + 4 * 4
     assert "slice-start" not in text  # operands prefetched whole
     assert len(re.findall(r"%paged_latent_decode[.\d]* = ", text)) == 5
     assert len(re.findall(r"%ragged-dot-gmm[.\d]* = ", text)) == 4 * 3
+    # 16 of the router's 128 experts held: the combine reads live tiles.
+    assert len(re.findall(r"%expert-combine[.\d]* = ", text)) == 4
     # The prefill: one flash forward a layer, no latent decode kernel.
     text = prefill.as_text()
-    assert text.count("tpu_custom_call") == 5 + 4 * 3
+    assert text.count("tpu_custom_call") == 5 + 4 * 4
     assert not re.findall(r"%paged_latent_decode[.\d]* = ", text)
     for program, temporaries in ((decode, 2**28), (prefill, 4 * 2**30)):
         memory = program.memory_analysis()
@@ -600,10 +630,11 @@ def test_granite_4_0_h_small_serving_programs_compile_for_v5e(topo, as_on_tpu):
     finally:
         engine.close()
     text = decode.as_text()
-    assert text.count("tpu_custom_call") == 9 + 1 + 10 * 3
+    assert text.count("tpu_custom_call") == 9 + 1 + 10 * 4
     assert "slice-start" not in text  # operands prefetched whole
     assert len(re.findall(r"%ssm_state_update[.\d]* = ", text)) == 9
     assert len(re.findall(r"%ragged-dot-gmm[.\d]* = ", text)) == 10 * 3
+    assert len(re.findall(r"%expert-combine[.\d]* = ", text)) == 10
     # A whole state or tail pool is the result of the nine kernels and of
     # nothing else (no scatter of tails, no copy between tilings), and
     # no pool of any kind is copied.
@@ -613,7 +644,7 @@ def test_granite_4_0_h_small_serving_programs_compile_for_v5e(topo, as_on_tpu):
     # The prefill: one flash forward (the attention layer), the chunked
     # scan in plain XLA, no state-update kernel.
     text = prefill.as_text()
-    assert text.count("tpu_custom_call") == 1 + 10 * 3
+    assert text.count("tpu_custom_call") == 1 + 10 * 4
     assert not re.findall(r"%ssm_state_update[.\d]* = ", text)
     for program, temporaries in ((decode, 2**28), (prefill, 3 * 2**29)):
         memory = program.memory_analysis()
