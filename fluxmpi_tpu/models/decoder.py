@@ -35,7 +35,13 @@ not that block. :class:`DecoderLM` reads its block from a
   over the chosen, or ``score_func="softmax"``: the largest logits,
   weighed by a softmax over the chosen), with a shared MLP of
   ``num_shared_experts x moe_intermediate_size`` or of a width of its own
-  (``shared_intermediate_size``);
+  (``shared_intermediate_size``); with ``mlp_activation="relu2"`` every
+  MLP, routed or shared, is UN-GATED: ``relu(u W_up)^2 W_down``, two
+  matrices;
+- a layer is that pair, mixer then feed-forward (``block="pair"``), or
+  ONE sublayer (``block="single"``: ``y = x + Sub(N_in(x))``), which
+  ``layer_types`` names: a mixer alone, or ``"experts"``, the routed
+  feed-forward alone, which keeps nothing of a sequence;
 - a head of its own or the embedding's transpose
   (``tie_word_embeddings``); the embedding scaled by
   ``sqrt(hidden_size)`` where ``mup_enabled`` and by
@@ -90,6 +96,9 @@ __all__ = ["DecoderConfig", "DecoderLM", "ExpertMLP", "LatentAttention",
 SLIDING, FULL = "sliding_attention", "full_attention"
 LATENT = "latent_attention"
 MAMBA = "mamba"
+# Not a mixer: a layer of a ``block="single"`` model that is its routed
+# experts alone.
+EXPERTS = "experts"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,10 +152,17 @@ class DecoderConfig:
     tie_word_embeddings: bool = False
     # The shared MLP's width (0: num_shared_experts * moe_intermediate_size).
     shared_intermediate_size: int = 0
+    # What a layer is: a mixer then a feed-forward ("pair"), or the one
+    # sublayer its entry of ``layer_types`` names ("single").
+    block: str = "pair"
+    # The MLPs, routed and shared: "swiglu" (gate, up, down) or "relu2"
+    # (un-gated: up, down).
+    mlp_activation: str = "swiglu"
     # Mamba-2 layers: heads of ``mamba_d_head``, a state of ``mamba_d_state``
-    # a head dimension, B and C shared by all heads (one group), a causal
-    # depthwise convolution over ``mamba_d_conv`` positions, the prefill's
-    # scan in chunks of ``mamba_chunk_size``.
+    # a head dimension, B and C shared by the consecutive heads of each of
+    # ``mamba_n_groups`` groups, a causal depthwise convolution over
+    # ``mamba_d_conv`` positions, the prefill's scan in chunks of
+    # ``mamba_chunk_size``.
     mamba_n_heads: int = 0
     mamba_d_head: int = 0
     mamba_d_state: int = 0
@@ -155,7 +171,13 @@ class DecoderConfig:
     mamba_chunk_size: int = 256
 
     def __post_init__(self):
-        unknown = set(self.layer_types) - {SLIDING, FULL, LATENT, MAMBA}
+        if self.block not in ("pair", "single"):
+            raise ValueError(f"unknown block {self.block!r}")
+        if self.mlp_activation not in ("swiglu", "relu2"):
+            raise ValueError(
+                f"unknown mlp_activation {self.mlp_activation!r}")
+        unknown = set(self.layer_types) - {SLIDING, FULL, LATENT, MAMBA} - (
+            {EXPERTS} if self.block == "single" else set())
         if unknown:
             raise ValueError(f"unknown layer types {sorted(unknown)}")
         if SLIDING in self.layer_types and not self.sliding_window:
@@ -198,10 +220,11 @@ class DecoderConfig:
                     "mamba layers need mamba_n_heads, mamba_d_head and "
                     "mamba_d_state"
                 )
-            if self.mamba_n_groups != 1:
+            if self.mamba_n_groups < 1 or (
+                    self.mamba_n_heads % self.mamba_n_groups):
                 raise ValueError(
-                    f"one group of B and C for all heads, not "
-                    f"{self.mamba_n_groups}"
+                    f"{self.mamba_n_heads} heads are not whole groups of B "
+                    f"and C for {self.mamba_n_groups}"
                 )
 
     @property
@@ -220,8 +243,18 @@ class DecoderConfig:
 
     @property
     def mamba_conv_dim(self) -> int:
-        """What the convolution runs over: ``[x; B; C]``."""
-        return self.mamba_inner + 2 * self.mamba_d_state
+        """What the convolution runs over: ``[x; B; C]``, B and C a
+        group."""
+        return self.mamba_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def expert_layer_ids(self) -> tuple[int, ...]:
+        """The layers that have routed experts: past the leading dense
+        ones, or the ``"experts"`` layers of a ``block="single"`` model."""
+        if self.block == "single":
+            return tuple(i for i, kind in enumerate(self.layer_types)
+                         if kind == EXPERTS)
+        return tuple(range(self.num_dense_layers, self.num_layers))
 
     @property
     def shared_width(self) -> int:
@@ -232,7 +265,17 @@ class DecoderConfig:
     @classmethod
     def from_hf(cls, cfg: dict) -> "DecoderConfig":
         """From the keys of a ``config.json``, ``model_type`` ``"afmoe"``,
-        ``"sarvam_mla"`` (whose names for the same things are mapped:
+        ``"nemotron_h"`` (``hybrid_override_pattern``: every layer ONE
+        sublayer, ``M`` Mamba-2, ``E`` routed experts, ``*`` attention,
+        read as full attention without positions, head norms or gate;
+        ``mlp_hidden_act`` ``relu2``: un-gated experts of
+        ``moe_intermediate_size`` and a shared one of
+        ``moe_shared_expert_intermediate_size``; the router
+        ``n_routed_experts`` wide, sigmoid scores normalised over the
+        chosen times ``routed_scaling_factor``; ``mamba_num_heads``,
+        ``mamba_head_dim``, ``ssm_state_size``, ``n_groups``,
+        ``conv_kernel``, ``chunk_size``; pre-norm), ``"sarvam_mla"`` (whose
+        names for the same things are mapped:
         ``first_k_dense_replace``, ``routed_scaling_factor``; every layer
         latent attention in a pre-norm block without the output gate;
         ``head_dim`` there is the cache's row, not a head's width) or
@@ -265,6 +308,38 @@ class DecoderConfig:
                 moe_intermediate_size=cfg["intermediate_size"],
                 score_func="softmax", norm_placement="pre",
                 output_gate=False, qk_norm=False,
+            )
+        elif cfg.get("model_type") == "nemotron_h":
+            kinds = {"M": MAMBA, "E": EXPERTS, "*": FULL}
+            pattern = cfg["hybrid_override_pattern"]
+            if set(pattern) - set(kinds) or (
+                    len(pattern) != cfg["num_hidden_layers"]):
+                raise ValueError(
+                    f"hybrid_override_pattern {pattern!r}: "
+                    f"{cfg['num_hidden_layers']} layers of M, E or *"
+                )
+            if cfg["mlp_hidden_act"] != "relu2" or cfg["n_group"] != 1:
+                raise ValueError(
+                    "nemotron_h as served: relu2 MLPs, one group of experts"
+                )
+            routed = cfg["n_routed_experts"]
+            known.update(
+                layer_types=tuple(kinds[kind] for kind in pattern),
+                block="single", mlp_activation="relu2",
+                num_routed_experts=routed,
+                num_experts=cfg.get("num_experts", routed),
+                shared_intermediate_size=cfg["n_shared_experts"]
+                * cfg["moe_shared_expert_intermediate_size"],
+                route_scale=cfg["routed_scaling_factor"],
+                route_norm=cfg["norm_topk_prob"],
+                rms_norm_eps=cfg["layer_norm_epsilon"],
+                mamba_n_heads=cfg["mamba_num_heads"],
+                mamba_d_head=cfg["mamba_head_dim"],
+                mamba_d_state=cfg["ssm_state_size"],
+                mamba_n_groups=cfg["n_groups"],
+                mamba_d_conv=cfg["conv_kernel"],
+                mamba_chunk_size=cfg["chunk_size"],
+                norm_placement="pre", output_gate=False, qk_norm=False,
             )
         else:
             known["layer_types"] = tuple(cfg["layer_types"])
@@ -574,14 +649,15 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
 
 class MambaMixer(nn.Module):
     """A Mamba-2 mixer. Of ``u`` at position ``t``: ``[z; xBC; dt] = u
-    W_in`` (``inner``; ``inner + 2 d_state``; ``heads``); ``xBC_t <-
+    W_in`` (``inner``; ``inner + 2 G d_state``; ``heads``); ``xBC_t <-
     silu(b_c + sum_j w_c[:, j] xBC_{t - d_conv + 1 + j})`` (depthwise,
     causal, zeros before the start), split ``[x (heads x head_dim); B; C
-    (d_state each, one group for all heads)]``; ``D_t = softplus(dt_t +
-    dt_bias)``, ``a_t = exp(D_t A)``, ``A = -exp(a_log)`` a head; the
-    state a head ``H_t = a_t H_{t-1} + D_t x_t B_t^T``, ``y_t = H_t C_t +
-    d_skip x_t``; ``g = y silu(z)``, RMSNormed over all of ``inner``;
-    ``g W_out``.
+    (G x d_state each: one pair a group of heads / G consecutive heads, G
+    = mamba_n_groups)]``; ``D_t = softplus(dt_t + dt_bias)``, ``a_t =
+    exp(D_t A)``, ``A = -exp(a_log)`` a head; the state a head ``H_t =
+    a_t H_{t-1} + D_t x_t B_t^T``, ``y_t = H_t C_t + d_skip x_t`` with its
+    group's B and C; ``g = y silu(z)``, RMSNormed over each group's
+    ``inner / G`` channels (all of ``inner`` for one group); ``g W_out``.
 
     Over a call's own tokens (the plain forward, a prefill) the state
     starts at zero and the chunked scan
@@ -608,6 +684,7 @@ class MambaMixer(nn.Module):
         c = self.config
         heads, hd, n, taps = (c.mamba_n_heads, c.mamba_d_head,
                               c.mamba_d_state, c.mamba_d_conv)
+        groups = c.mamba_n_groups
         inner, conv_dim = c.mamba_inner, c.mamba_conv_dim
         init = nn.initializers.normal(0.02)
         f32 = jnp.float32
@@ -647,7 +724,12 @@ class MambaMixer(nn.Module):
             )
             conv = jax.nn.silu(conv).reshape(b * s, conv_dim)
             x = conv[:, :inner]
-            state_in, state_out = conv[:, inner:inner + n], conv[:, inner + n:]
+            # B and C: ``[tokens, d_state]``, or ``[tokens, groups,
+            # d_state]`` with more groups than one.
+            state_in, state_out = (
+                v if groups == 1 else v.reshape(b * s, groups, n)
+                for v in (conv[:, inner:inner + groups * n],
+                          conv[:, inner + groups * n:]))
         if cached:
             with jax.named_scope("ssm_update"):
                 y = fn.state_update(
@@ -661,7 +743,8 @@ class MambaMixer(nn.Module):
                 y, state = ssd_chunk_scan(
                     x.reshape(b, s, heads, hd).astype(self.dtype),
                     step.reshape(b, s, heads), a_rate,
-                    state_in.reshape(b, s, n), state_out.reshape(b, s, n),
+                    state_in.reshape(b, s, *state_in.shape[1:]),
+                    state_out.reshape(b, s, *state_out.shape[1:]),
                     chunk=c.mamba_chunk_size,
                 )
             if fn is not None:
@@ -675,7 +758,14 @@ class MambaMixer(nn.Module):
         with jax.named_scope("ssm_gate_norm"):
             y = y.reshape(b * s, inner) + jnp.repeat(
                 d_skip.astype(f32), hd) * x
-            out = _rms_norm(y * jax.nn.silu(z), norm, c.rms_norm_eps)
+            gated = y * jax.nn.silu(z)
+            if groups == 1:
+                out = _rms_norm(gated, norm, c.rms_norm_eps)
+            else:  # each group's channels normed by themselves
+                out = _rms_norm(
+                    gated.reshape(b * s, groups, inner // groups),
+                    norm.reshape(groups, inner // groups), c.rms_norm_eps,
+                ).reshape(b * s, inner)
         with jax.named_scope("ssm_out_proj"):
             return _dot(out, w_out, self.dtype).astype(self.dtype).reshape(
                 b, s, d)
@@ -724,6 +814,30 @@ class GatedMLP(nn.Module):
         return jax.lax.map(mlp, u.reshape(slabs, -1, d)).reshape(u.shape)
 
 
+class ReluSquaredMLP(nn.Module):
+    """``relu(u W_up)^2 W_down``: un-gated, two matrices."""
+
+    width: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, u):
+        init = nn.initializers.normal(0.02)
+        d = u.shape[-1]
+        w_up = self.param("w_up", init, (d, self.width))
+        w_down = self.param("w_down", init, (self.width, d))
+
+        def mlp(u):
+            h = jnp.square(jax.nn.relu(_dot(u, w_up, self.dtype)))
+            return _dot(h, w_down, self.dtype).astype(self.dtype)
+
+        tokens = math.prod(u.shape[:-1])
+        slabs = _slabs(tokens, self.width)
+        if slabs == 1:
+            return mlp(u)
+        return jax.lax.map(mlp, u.reshape(slabs, -1, d)).reshape(u.shape)
+
+
 class ExpertMLP(nn.Module):
     """Routed experts without a capacity: no token is dropped.
 
@@ -740,7 +854,14 @@ class ExpertMLP(nn.Module):
     UNSPECIFIED) a projection computes them;
     pairs routed to experts held elsewhere add nothing here (on one chip
     the layer runs without its exchange). The shared expert, which every
-    token passes, is added where ``include_shared``.
+    token passes, is added where ``include_shared``. An expert is SwiGLU
+    (``w1``, ``w3``, ``w2``: three grouped matmuls) or, with
+    ``activation="relu2"``, un-gated: ``relu(u W_up)^2 W_down``, two
+    grouped matmuls and no gate product, both matrices held ``[experts,
+    width, hidden]`` (the up projection as its checkpoint holds it,
+    ``[out, in]``): the hidden size lies on the lanes of both, and a
+    width that is no whole number of 128-lane tiles (1,856) costs no
+    padding and no copy before the kernel reads them.
 
     What the layer does with the grouped matmuls' results is bounded by
     the pairs its held experts received (``sum(sizes)``), never by a
@@ -778,6 +899,7 @@ class ExpertMLP(nn.Module):
     include_shared: bool = True
     dtype: Any = jnp.float32
     score_func: str = "sigmoid"
+    activation: str = "swiglu"
 
     def route(self, u, router, bias):
         """``(experts [tokens, top_k], weights [tokens, top_k])``."""
@@ -809,9 +931,14 @@ class ExpertMLP(nn.Module):
         held = hi - lo
         router = self.param("router", init, (d, n))
         bias = self.param("bias", nn.initializers.zeros, (n,))
-        w1 = self.param("w1", init, (held, d, self.width))
-        w3 = self.param("w3", init, (held, d, self.width))
-        w2 = self.param("w2", init, (held, self.width, d))
+        gated = self.activation == "swiglu"
+        if gated:
+            w1 = self.param("w1", init, (held, d, self.width))
+            w3 = self.param("w3", init, (held, d, self.width))
+            w2 = self.param("w2", init, (held, self.width, d))
+        else:
+            w_up = self.param("w_up", init, (held, self.width, d))
+            w_down = self.param("w_down", init, (held, self.width, d))
 
         def routed(u, token_mask):
             """The held experts' part for the tokens ``u`` ``[tokens,
@@ -835,15 +962,22 @@ class ExpertMLP(nn.Module):
                 # which a model without expert layers may never need.
                 from ..ops.grouped_matmul import combine, grouped_matmul
 
-                def grouped(x, w):
+                def grouped(x, w, **layout):
                     # Rows past the held experts' pairs: unspecified.
                     return grouped_matmul(
-                        x.astype(self.dtype), w.astype(self.dtype), sizes
+                        x.astype(self.dtype), w.astype(self.dtype), sizes,
+                        **layout,
                     )
 
                 rows = u.astype(self.dtype)[order // k]
-                gate, up = grouped(rows, w1), grouped(rows, w3)
-                y = grouped(jax.nn.silu(gate) * up, w2)
+                if gated:
+                    gate, up = grouped(rows, w1), grouped(rows, w3)
+                    y = grouped(jax.nn.silu(gate) * up, w2)
+                else:
+                    with jax.named_scope("relu2_up"):
+                        up = grouped(rows, w_up, transposed=True)
+                    with jax.named_scope("relu2_down"):
+                        y = grouped(jnp.square(jax.nn.relu(up)), w_down)
                 if held < n:
                     # The held pairs' rows added into their tokens' rows:
                     # the rows past them (3 of 4, 7 of 8) are not read.
@@ -875,7 +1009,8 @@ class ExpertMLP(nn.Module):
         self.sow("intermediates", "expert_tokens", sizes)
         if self.shared_width and self.include_shared:
             with jax.named_scope("moe_shared"):
-                out = out + GatedMLP(
+                shared = GatedMLP if gated else ReluSquaredMLP
+                out = out + shared(
                     self.shared_width, self.dtype, name="shared"
                 )(u).astype(jnp.float32)
         return out.astype(self.dtype).reshape(shape)
@@ -913,33 +1048,44 @@ class DecoderLayer(nn.Module):
 
         sandwich = c.norm_placement == "sandwich"
         kind = c.layer_types[self.index]
-        if kind == MAMBA:
-            a = MambaMixer(c, self.dtype, self.attention_fn, name="mamba")(
-                norm("norm_in")(x), token_mask)
-        elif kind == LATENT:
-            a = LatentAttention(
-                c, self.dtype, self.attention, self.attention_fn, name="attn"
-            )(norm("norm_in")(x), positions)
-        else:
-            a = Attention(
+
+        def mixer(u):
+            if kind == MAMBA:
+                return MambaMixer(
+                    c, self.dtype, self.attention_fn, name="mamba"
+                )(u, token_mask)
+            if kind == LATENT:
+                return LatentAttention(
+                    c, self.dtype, self.attention, self.attention_fn,
+                    name="attn",
+                )(u, positions)
+            return Attention(
                 c, kind, self.dtype, self.attention, self.attention_fn,
                 name="attn",
-            )(norm("norm_in")(x), positions)
-        h = join(x, norm("norm_post_attn")(a) if sandwich else a)
-        u = norm("norm_pre_ff")(h)
-        if self.index < c.num_dense_layers:
-            y = GatedMLP(c.intermediate_size, self.dtype, name="mlp")(u)
-        else:
+            )(u, positions)
+
+        def feed_forward(u):
+            if self.index not in c.expert_layer_ids:
+                dense = (GatedMLP if c.mlp_activation == "swiglu"
+                         else ReluSquaredMLP)
+                return dense(c.intermediate_size, self.dtype, name="mlp")(u)
             routed, held = _held_experts(c, self.expert_range)
-            ff = ExpertMLP(
+            return ExpertMLP(
                 num_experts=routed, top_k=c.num_experts_per_tok,
                 width=c.moe_intermediate_size,
                 shared_width=c.shared_width,
                 route_norm=c.route_norm, route_scale=c.route_scale,
                 expert_range=held, dtype=self.dtype,
-                score_func=c.score_func, name="moe",
-            )
-            y = ff(u, token_mask)
+                score_func=c.score_func, activation=c.mlp_activation,
+                name="moe",
+            )(u, token_mask)
+
+        if c.block == "single":  # one sublayer: x + Sub(N_in(x))
+            sub = feed_forward if kind == EXPERTS else mixer
+            return join(x, sub(norm("norm_in")(x)))
+        a = mixer(norm("norm_in")(x))
+        h = join(x, norm("norm_post_attn")(a) if sandwich else a)
+        y = feed_forward(norm("norm_pre_ff")(h))
         return join(h, norm("norm_post_ff")(y) if sandwich else y)
 
 
@@ -978,11 +1124,14 @@ class DecoderLM(nn.Module):
         row of ``kv_lora_rank + qk_rope_head_dim`` a token and no V; a
         Mamba layer ``("state", (heads, head_dim, d_state), (d_conv - 1,
         conv_dim))``: nothing a token, ONE state and one tail of
-        pre-convolution columns a SEQUENCE, whatever its length."""
+        pre-convolution columns a SEQUENCE, whatever its length; a layer
+        that is its experts alone None: it keeps NOTHING of a sequence
+        (and never calls ``attention_fn``)."""
         c = self.config
         state = ("state", (c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state),
                  (c.mamba_d_conv - 1, c.mamba_conv_dim))
         return tuple(
+            None if kind == EXPERTS else
             state if kind == MAMBA else
             (None, c.latent_row, None) if kind == LATENT else
             (c.num_key_value_heads, c.head_dim,
@@ -999,11 +1148,12 @@ class DecoderLM(nn.Module):
         from ..ops.grouped_matmul import row_tile
 
         c = self.config
-        if c.num_dense_layers >= c.num_layers:
+        if not c.expert_layer_ids:
             return None
         return row_tile(
             tokens * c.num_experts_per_tok, c.hidden_size,
             c.moe_intermediate_size, self.dtype,
+            transposed=c.mlp_activation == "relu2",
         )
 
     def expert_row_tiles(self, tokens: int, held_pairs) -> tuple[int, int]:

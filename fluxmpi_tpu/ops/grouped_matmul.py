@@ -40,11 +40,22 @@ last live tile and add nothing) and adds each row where its token says
 (scalar prefetched); anywhere but a TPU it is a masked ``segment_sum``,
 whose reverse rule the kernel is given too.
 
+The weights may be held TRANSPOSED (``transposed=True``: ``w`` is
+``[groups, n, k]`` and a group's rows meet ``w[g].T``). That is how a
+layer holds a projection whose ``n`` is no whole number of 128-lane tiles
+(1,856 columns): the chip's compiler lays such an array out with its
+OTHER dimension on the lanes and copies all of it before a Mosaic kernel
+may read it, so the layer keeps ``k`` (whole tiles) on the lanes itself
+and the kernel contracts both operands' last dimension. A weight block is
+whole 128-lane tiles of the columns (:func:`_column_block`); the last
+block may end past the width (Pallas neither reads nor writes past it).
+
 What runs is chosen from what can be observed, never by an option: on a
-TPU backend, with bfloat16 operands and ``k`` and ``n`` multiples of 128
-whose weight block fits, the kernel, its tiles by :func:`_tile_rule` from
-the shapes alone; anywhere else (a CPU, float32, narrow widths)
-``jax.lax.ragged_dot`` exactly as before.
+TPU backend, with bfloat16 operands and weights whose minor dimension is
+whole 128-lane tiles (the other whole 64s) and whose block fits, the
+kernel, its tiles by :func:`_tile_rule` from the shapes alone; anywhere
+else (a CPU, float32, narrow widths) ``jax.lax.ragged_dot`` exactly as
+before (of the transpose, where the weights are held so).
 """
 
 from __future__ import annotations
@@ -72,6 +83,7 @@ _TILE_ROWS = 512
 _SUB_ROWS = 128
 _VMEM_LIMIT_BYTES = 64 * 2**20
 _LANES = 128
+_HALF_LANES = 64
 # The chip's compiler names a Mosaic call's instruction by the last
 # component of its path (the jitted function, the ``pallas_call``); the
 # benchmark's readers find the routed experts' matmul by the name XLA's
@@ -83,15 +95,30 @@ _COMBINE_NAME = "expert-combine"
 _COMBINE_BLOCK_BYTES = 4 * 2**20
 
 
+def _column_block(n: int, widest: int) -> int | None:
+    """The columns of one column block of a width ``n`` where a block may
+    hold ``widest`` columns: all of ``n`` if they fit, else the fewest
+    blocks of whole 128-lane tiles, as equal as whole tiles let them be
+    (4,096 under 1,024: four of 1,024; 2,688 = 21 tiles under 1,129:
+    three of 896; 1,856 = 14.5 tiles under 780: 640, 640 and a last block
+    of 576, whose columns past the width are neither read nor written).
+    None where not one tile fits."""
+    if n <= widest:
+        return n
+    most = widest // _LANES
+    if most < 1:
+        return None
+    tiles = -(-n // _LANES)
+    return -(-tiles // -(-tiles // most)) * _LANES
+
+
 def _tile_rule(rows: int, k: int, n: int, itemsize: int):
     """``(tile_rows, sub_rows, tile_n)`` from the static shapes, nothing
     timed or probed; None where no weight block of at least 128 columns
     fits (the caller keeps ``ragged_dot``). ``rows`` is a multiple of
     ``_SUB_ROWS`` (the caller pads)."""
-    tile_n = n
-    while k * tile_n * itemsize > _WEIGHT_BLOCK_BYTES and tile_n % 256 == 0:
-        tile_n //= 2
-    if k * tile_n * itemsize > _WEIGHT_BLOCK_BYTES:
+    tile_n = _column_block(n, _WEIGHT_BLOCK_BYTES // (k * itemsize))
+    if tile_n is None:
         return None
     return live_row_tile(rows), _SUB_ROWS, tile_n
 
@@ -108,15 +135,19 @@ def live_row_tile(rows: int) -> int:
     return next(t for t in (_TILE_ROWS, 256, _SUB_ROWS) if padded % t == 0)
 
 
-def row_tile(rows: int, k: int, n: int, x_dtype, w_dtype=None) -> int | None:
+def row_tile(rows: int, k: int, n: int, x_dtype, w_dtype=None, *,
+             transposed: bool = False) -> int | None:
     """The rows of one row tile of the kernel :func:`grouped_matmul` runs
     for these shapes and dtypes on this backend, or None where it runs
-    ``ragged_dot`` (what :func:`weight_visits` counts visits by)."""
+    ``ragged_dot`` (what :func:`weight_visits` counts visits by). The
+    weights' MINOR dimension (``n``, or ``k`` of ``transposed`` ones) is
+    whole 128-lane tiles, the other whole 64s and a tile at least."""
     w_dtype = x_dtype if w_dtype is None else w_dtype
+    minor, other = (k, n) if transposed else (n, k)
     if (
         jax.default_backend() != "tpu"
         or x_dtype != jnp.bfloat16 or w_dtype != jnp.bfloat16
-        or k % _LANES or n % _LANES
+        or minor % _LANES or other % _HALF_LANES or other < _LANES
     ):
         return None
     tiles = _tile_rule(_padded(rows), k, n, 2)
@@ -212,7 +243,7 @@ def _visits(group_sizes, rows: int, tile_rows: int):
 
 
 def _gmm_kernel(tile_ref, weight_ref, lo_ref, hi_ref, fresh_ref,
-                x_ref, w_ref, o_ref, *, sub_rows: int):
+                x_ref, w_ref, o_ref, *, sub_rows: int, transposed: bool):
     del tile_ref, weight_ref  # read by the index maps
     from jax.experimental import pallas as pl
 
@@ -227,7 +258,8 @@ def _gmm_kernel(tile_ref, weight_ref, lo_ref, hi_ref, fresh_ref,
     def sub_tile(s, carry):
         at = pl.ds(pl.multiple_of(s * sub_rows, sub_rows), sub_rows)
         product = lax.dot_general(
-            x_ref[at, :], w_ref[...], (((1,), (0,)), ((), ())),
+            x_ref[at, :], w_ref[...],
+            (((1,), (1 if transposed else 0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         row = s * sub_rows + lax.broadcasted_iota(
@@ -245,8 +277,11 @@ def _gmm_kernel(tile_ref, weight_ref, lo_ref, hi_ref, fresh_ref,
     )
 
 
-def _gmm(x, w, group_sizes, *, tiles, interpret: bool = False):
-    """The kernel over ``x`` whose rows ``tiles[0]`` divides."""
+def _gmm(x, w, group_sizes, *, tiles, transposed: bool = False,
+         interpret: bool = False):
+    """The kernel over ``x`` whose rows ``tiles[0]`` divides; ``w``
+    ``[groups, k, n]``, or ``[groups, n, k]`` where ``transposed``. A
+    last column block past the width is partial."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -254,25 +289,28 @@ def _gmm(x, w, group_sizes, *, tiles, interpret: bool = False):
 
     tile_rows, sub_rows, tile_n = tiles
     rows, k = x.shape
-    groups, _, n = w.shape
+    groups = w.shape[0]
+    n = w.shape[1] if transposed else w.shape[2]
 
     def x_index(j, step, tile, weight, lo, hi, fresh):
         return tile[step], 0
 
     def w_index(j, step, tile, weight, lo, hi, fresh):
-        return weight[step], 0, j
+        return (weight[step], j, 0) if transposed else (weight[step], 0, j)
 
     def o_index(j, step, tile, weight, lo, hi, fresh):
         return tile[step], j
 
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, sub_rows=sub_rows),
+        functools.partial(_gmm_kernel, sub_rows=sub_rows,
+                          transposed=transposed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
-            grid=(n // tile_n, rows // tile_rows + groups),
+            grid=(-(-n // tile_n), rows // tile_rows + groups),
             in_specs=[
                 pl.BlockSpec((tile_rows, k), x_index),
-                pl.BlockSpec((None, k, tile_n), w_index),
+                pl.BlockSpec((None, tile_n, k) if transposed
+                             else (None, k, tile_n), w_index),
             ],
             out_specs=pl.BlockSpec((tile_rows, tile_n), o_index),
         ),
@@ -335,7 +373,7 @@ def _combine(y, token, scale, live, *, tokens: int, tiles,
         functools.partial(_combine_kernel, tile_rows=tile_rows),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(n // tile_n, rows // tile_rows),
+            grid=(-(-n // tile_n), rows // tile_rows),
             in_specs=[
                 pl.BlockSpec((tile_rows, tile_n),
                              lambda j, s, live, token: (tile_of(s, live), j)),
@@ -365,10 +403,8 @@ def _combine_tiles(rows: int, n: int, tokens: int):
     result fits ``_COMBINE_BLOCK_BYTES``."""
     if n % _LANES:
         return None
-    tile_n = n
-    while tokens * tile_n * 4 > _COMBINE_BLOCK_BYTES and tile_n % 256 == 0:
-        tile_n //= 2
-    if tokens * tile_n * 4 > _COMBINE_BLOCK_BYTES:
+    tile_n = _column_block(n, _COMBINE_BLOCK_BYTES // (tokens * 4))
+    if tile_n is None:
         return None
     return live_row_tile(rows), tile_n
 
@@ -439,29 +475,39 @@ def combine(y, token, scale, live, tokens: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted(interpret: bool):
-    """One jitted function: a program lowers the kernel once a distinct
-    ``(rows, k, n)`` and calls it from every layer."""
+def _jitted(interpret: bool, transposed: bool = False):
+    """One jitted function a layout of the weights: a program lowers the
+    kernel once a distinct ``(rows, k, n)`` and calls it from every
+    layer."""
 
     def ragged_dot_gmm(x, w, group_sizes):
         rows = x.shape[0]
         padded = _padded(rows)
-        tiles = _tile_rule(padded, w.shape[1], w.shape[2], w.dtype.itemsize)
+        k, n = w.shape[1:][::-1] if transposed else w.shape[1:]
+        tiles = _tile_rule(padded, k, n, w.dtype.itemsize)
+
+        def gmm(x):
+            return _gmm(x, w, group_sizes, tiles=tiles,
+                        transposed=transposed, interpret=interpret)
+
         if padded == rows:
-            return _gmm(x, w, group_sizes, tiles=tiles, interpret=interpret)
-        x = jnp.pad(x, ((0, padded - rows), (0, 0)))
-        return _gmm(x, w, group_sizes, tiles=tiles, interpret=interpret)[:rows]
+            return gmm(x)
+        return gmm(jnp.pad(x, ((0, padded - rows), (0, 0))))[:rows]
 
     ragged_dot_gmm.__name__ = ragged_dot_gmm.__qualname__ = _KERNEL_NAME
     return jax.jit(ragged_dot_gmm)
 
 
-def grouped_matmul(x, w, group_sizes):
+def grouped_matmul(x, w, group_sizes, *, transposed: bool = False):
     """``jax.lax.ragged_dot(x, w, group_sizes)`` with a float32 result
     whose rows past ``sum(group_sizes)`` are unspecified; see the module
-    docstring."""
-    if row_tile(x.shape[0], w.shape[1], w.shape[2], x.dtype, w.dtype) is None:
+    docstring. ``transposed``: ``w`` is held ``[groups, n, k]`` and each
+    group's rows are multiplied by ``w[g].T``."""
+    k, n = w.shape[1:][::-1] if transposed else w.shape[1:]
+    if row_tile(x.shape[0], k, n, x.dtype, w.dtype,
+                transposed=transposed) is None:
         return jax.lax.ragged_dot(
-            x, w, group_sizes, preferred_element_type=jnp.float32
+            x, jnp.swapaxes(w, 1, 2) if transposed else w, group_sizes,
+            preferred_element_type=jnp.float32,
         )
-    return _jitted(False)(x, w, group_sizes)
+    return _jitted(False, transposed)(x, w, group_sizes)

@@ -4,7 +4,9 @@ prefill's chunked scan.
 A Mamba-2 head keeps, a sequence, a state ``H`` ``[head_dim, d_state]``
 that one token moves by ``H_t = a_t H_{t-1} + (D_t x_t) B_t^T`` and reads
 as ``y_t = H_t C_t`` (``x_t`` ``[head_dim]``; ``B_t``, ``C_t`` ``[d_state]``,
-ONE group shared by all heads; ``D_t > 0`` the step, ``a_t = exp(D_t A)``
+one pair a GROUP of consecutive heads: ``groups`` of them, head ``h``
+reading group ``h // (heads / groups)``, one group for all heads where the
+arrays carry no group axis; ``D_t > 0`` the step, ``a_t = exp(D_t A)``
 the decay, both a head). Whatever the context's length the state is
 ``heads x head_dim x d_state`` numbers a layer, float32 in the serving
 engine's pool: the recurrence compounds its rounding over a whole answer.
@@ -34,7 +36,10 @@ A state lies in the pool TRANSPOSED, ``H^T`` of all heads side by side:
 ``head_dim`` on consecutive lanes. So a token's ``D_t x_t`` and ``a_t``
 (repeated over a head's lanes) are ROWS that broadcast over the sublanes
 for free, ``y`` is a row again (a sum over the sublanes), and only ``B``
-and ``C`` have to become columns: once a state, not once a head. (The
+and ``C`` have to become columns: once a state and group, not once a
+head; a group's heads are consecutive lanes, whole 128-lane tiles of the
+walk (eight groups over 4,096 lanes: four tiles each), so a tile picks
+its group's columns and nothing is broadcast a head. (The
 first kernel kept ``[heads, head_dim, d_state]`` and paid a lane
 broadcast and a lane reduction a head: 23 us a state where its two
 transfers take 10.) Tiles come from the shapes alone: one sequence's
@@ -142,11 +147,14 @@ def _check_update_shapes(pool, tail_pool, entries, tail, x, dt, a, b, c,
         )
     _, _, d_state, inner = pool.shape
     slots, heads = entries.shape[0], x.shape[1]
+    groups = b.shape[1] if b.ndim == 3 else 1
+    grouped = (slots, groups, d_state) if b.ndim == 3 else (slots, d_state)
     want = {"x": (slots, heads, inner // heads), "dt": (slots, heads),
-            "a": (slots, heads), "b": (slots, d_state), "c": (slots, d_state)}
+            "a": (slots, heads), "b": grouped, "c": grouped}
     got = {"x": x.shape, "dt": dt.shape, "a": a.shape, "b": b.shape,
            "c": c.shape}
-    if entries.ndim != 1 or got != want or inner % heads:
+    if (entries.ndim != 1 or got != want or inner % heads
+            or heads % groups):
         raise ValueError(
             f"for {slots} slots over a pool {pool.shape}: expected {want}, "
             f"got {got}"
@@ -188,9 +196,16 @@ def ssm_state_update_reference(pool, tail_pool, entries, tail, x, dt, a, b,
     slots, heads, head_dim = x.shape
     decay, stepped = _rows(x, dt, a)
     state = pool[layer, entries].astype(f32)  # [slots, d_state, inner]
-    moved = (state * decay[:, None, :]
-             + b.astype(f32)[:, :, None] * stepped[:, None, :])
-    y = jnp.sum(moved * c.astype(f32)[:, :, None], axis=1)
+
+    def columns(v):
+        """``[slots, (groups,) d_state]`` as ``[slots, d_state, inner]``:
+        each lane its group's column."""
+        v = v.astype(f32).reshape(slots, -1, v.shape[-1])
+        return jnp.repeat(jnp.swapaxes(v, 1, 2), state.shape[2] // v.shape[1],
+                          axis=2)
+
+    moved = state * decay[:, None, :] + columns(b) * stepped[:, None, :]
+    y = jnp.sum(moved * columns(c), axis=1)
     # Idle slots are sent past the pools and dropped: the trash entry, and
     # every entry no live slot names, stays bit for bit what it was.
     where = jnp.where(live, entries, pool.shape[1])
@@ -213,19 +228,24 @@ def _update_kernel(ids_ref, rows_ref, count_ref, s_ref, t_ref, a_ref, x_ref,
     @pl.when(step < count)
     def _move():
         # B and C as columns (their entry n on sublane n of every lane):
-        # a row broadcast over the sublanes, transposed, once a state.
-        def column(row_ref):
-            return jnp.broadcast_to(row_ref[...], (_LANES, d_state)).T
+        # a row broadcast over the sublanes, transposed, once a state
+        # and group.
+        def column(row_ref, group):
+            return jnp.broadcast_to(row_ref[group:group + 1, :],
+                                    (_LANES, d_state)).T
 
-        b, c = column(b_ref), column(c_ref)
-        for tile in range(a_ref.shape[0]):  # 128 lanes of the state each
-            lanes = slice(tile * _LANES, (tile + 1) * _LANES)
-            moved = (s_ref[:, lanes].astype(jnp.float32)
-                     * a_ref[tile:tile + 1, :]
-                     + b * x_ref[tile:tile + 1, :])  # [d_state, 128 lanes]
-            o_ref[:, lanes] = moved.astype(o_ref.dtype)
-            y_ref[tile:tile + 1, :] = jnp.sum(moved * c, axis=0,
-                                              keepdims=True)
+        groups = b_ref.shape[0]
+        tiles = a_ref.shape[0] // groups  # 128 lanes of the state each
+        for group in range(groups):
+            b, c = column(b_ref, group), column(c_ref, group)
+            for tile in range(group * tiles, (group + 1) * tiles):
+                lanes = slice(tile * _LANES, (tile + 1) * _LANES)
+                moved = (s_ref[:, lanes].astype(jnp.float32)
+                         * a_ref[tile:tile + 1, :]
+                         + b * x_ref[tile:tile + 1, :])  # [d_state, 128]
+                o_ref[:, lanes] = moved.astype(o_ref.dtype)
+                y_ref[tile:tile + 1, :] = jnp.sum(moved * c, axis=0,
+                                                  keepdims=True)
         w_ref[...] = n_ref[...]  # the slot's new tail over its old one
 
     @pl.when((step == 0) & (count == 0))
@@ -272,7 +292,8 @@ def _jitted(layer: int, interpret: bool):
         # its way: 9% of the state's own bytes), lane tile ``j`` of the
         # state meeting sublane ``j`` of the row.
         tiled = pl.BlockSpec((None, tiles, _LANES), row_index)
-        narrow = pl.BlockSpec((None, 1, d_state), row_index)
+        b, c = (v.astype(f32).reshape(slots, -1, d_state) for v in (b, c))
+        narrow = pl.BlockSpec((None, b.shape[1], d_state), row_index)
         decay, stepped = _rows(x, dt, a)
         pool, tail_pool, y = pl.pallas_call(
             _update_kernel,
@@ -301,8 +322,7 @@ def _jitted(layer: int, interpret: bool):
             ids, rows, count, pool, tail_pool,
             decay.reshape(slots, tiles, _LANES),
             stepped.reshape(slots, tiles, _LANES),
-            b.astype(f32)[:, None, :], c.astype(f32)[:, None, :],
-            tail_to_pool_layout(tail).astype(tail_pool.dtype),
+            b, c, tail_to_pool_layout(tail).astype(tail_pool.dtype),
         )
         # A slot the walk never reached left its row of ``y`` unwritten.
         reached = jnp.zeros((slots,), bool).at[rows].set(count[0] > 0)
@@ -325,7 +345,8 @@ def ssm_state_update(pool, tail_pool, entries, tail, x, dt, a, b, c, *,
     trash entry: an idle slot); ``tail`` ``[slots, d_conv - 1,
     conv_dim]`` (rounded to the tail pool's dtype), ``x`` ``[slots,
     heads, head_dim]``, ``dt`` and ``a`` ``[slots, heads]``, ``b`` and
-    ``c`` ``[slots, d_state]``. ``live``: :func:`live_entries` of
+    ``c`` ``[slots, groups, d_state]`` (or ``[slots, d_state]``: one
+    group). ``live``: :func:`live_entries` of
     ``entries`` where the caller has it (one call a tick for all layers).
     Returns ``(y [slots, heads, head_dim] float32, pool, tail_pool)``,
     ``y`` zero for idle slots, whose entry (the trash entry) is written in
@@ -337,9 +358,11 @@ def ssm_state_update(pool, tail_pool, entries, tail, x, dt, a, b, c, *,
     _check_update_shapes(pool, tail_pool, entries, tail, x, dt, a, b, c,
                          layer)
     if interpret is None:
-        # The kernel walks whole 128-lane tiles of whole sublane tiles.
-        if (jax.default_backend() != "tpu" or pool.shape[3] % _LANES
-                or pool.shape[2] % 8):
+        # The kernel walks whole 128-lane tiles of whole sublane tiles,
+        # a group of heads whole tiles too.
+        groups = b.shape[1] if b.ndim == 3 else 1
+        if (jax.default_backend() != "tpu"
+                or pool.shape[3] % (groups * _LANES) or pool.shape[2] % 8):
             return ssm_state_update_reference(
                 pool, tail_pool, entries, tail, x, dt, a, b, c, layer=layer)
         interpret = False
@@ -353,11 +376,16 @@ def ssd_chunk_scan(x, dt, a_rate, b, c, *, chunk: int, initial_state=None):
     module docstring. ``x`` ``[batch, seq, heads, head_dim]`` (its dtype
     is the matmuls' operand dtype), ``dt`` ``[batch, seq, heads]`` float32
     (the step, 0 at positions that are padding), ``a_rate`` ``[heads]``
-    (``A``, negative), ``b`` and ``c`` ``[batch, seq, d_state]``.
+    (``A``, negative), ``b`` and ``c`` ``[batch, seq, d_state]``: one
+    group for all heads, or ``[batch, seq, groups, d_state]``: the groups
+    are independent recurrences over their own heads, so they are folded
+    into the batch and one group is the case that folds nothing.
     ``initial_state`` ``[batch, heads, head_dim, d_state]`` (default
     zeros). Returns ``(y [batch, seq, heads, head_dim] float32, the state
     after the last position, float32)``."""
     f32 = jnp.float32
+    if b.ndim == 4:
+        return _scan_by_group(x, dt, a_rate, b, c, chunk, initial_state)
     batch, seq, heads, head_dim = x.shape
     d_state = b.shape[-1]
     dtype = x.dtype
@@ -413,3 +441,31 @@ def ssd_chunk_scan(x, dt, a_rate, b, c, *, chunk: int, initial_state=None):
     )
     y = jnp.moveaxis(y, 0, 1).reshape(batch, seq + pad, heads, head_dim)
     return y[:, :seq], state
+
+
+def _scan_by_group(x, dt, a_rate, b, c, chunk, initial_state):
+    """:func:`ssd_chunk_scan` with a group axis on ``b`` and ``c``: each
+    group's heads as a batch row of their own (``a_rate`` a row too)."""
+    batch, seq, heads, head_dim = x.shape
+    groups, d_state = b.shape[2:]
+    if heads % groups:
+        raise ValueError(f"{heads} heads are not whole groups of {groups}")
+    per_group = heads // groups
+
+    def fold(v):
+        """``[batch, seq, groups, ...]`` as ``[batch * groups, seq, ...]``."""
+        return jnp.moveaxis(v, 2, 1).reshape(batch * groups, seq, *v.shape[3:])
+
+    if initial_state is not None:
+        initial_state = initial_state.reshape(
+            batch * groups, per_group, head_dim, d_state)
+    y, state = ssd_chunk_scan(
+        fold(x.reshape(batch, seq, groups, per_group, head_dim)),
+        fold(dt.reshape(batch, seq, groups, per_group)),
+        jnp.tile(a_rate.reshape(1, groups, 1, per_group),
+                 (batch, 1, 1, 1)).reshape(batch * groups, 1, per_group),
+        fold(b), fold(c), chunk=chunk, initial_state=initial_state,
+    )
+    y = jnp.moveaxis(y.reshape(batch, groups, seq, per_group, head_dim), 1, 2)
+    return (y.reshape(batch, seq, heads, head_dim),
+            state.reshape(batch, heads, head_dim, d_state))

@@ -61,6 +61,14 @@ and writes their new tails over the old
 neither read nor written, state or tail). Admission takes one entry a
 request whatever its length.
 
+A layer may keep **nothing** (``cache_layers()`` says None: a layer that
+is its routed experts alone, in a model whose layers are one sublayer
+each). It has no pool, no table and no call of ``attention_fn``; the
+cache's layers are the keeping ones in their order, and every count of
+blocks, states or context speaks of those. The ``expert_*`` counters and
+span arguments speak of the layers that HAVE experts (the ones that sow
+``expert_tokens``), wherever they stand in the model.
+
 The decode loop is **host-driven** (``lax.scan``-free) with **one tick
 in flight**: an iteration dispatches tick N+1 from what the host knows
 before tick N's tokens arrive (positions, tables, which slots N finishes
@@ -472,10 +480,13 @@ def _cache_layers(model) -> tuple[tuple, ...]:
     shape, tail shape)``: a layer that keeps one recurrent state and one
     convolution tail a SEQUENCE), else
     :class:`~fluxmpi_tpu.models.TransformerLM`'s ``num_heads`` heads of
-    ``d_model // num_heads`` over the whole context."""
+    ``d_model // num_heads`` over the whole context. A layer the model
+    says None of keeps NOTHING (a feed-forward alone): it never calls
+    ``attention_fn``, so it is left out here and the cache's layers are
+    the keeping ones, numbered in the order their calls come."""
     layers = getattr(model, "cache_layers", None)
     if layers is not None:
-        return tuple(layers())
+        return tuple(layer for layer in layers() if layer is not None)
     heads = int(model.num_heads)
     return ((heads, int(model.d_model) // heads, None),) * int(
         model.num_layers

@@ -311,10 +311,20 @@ _TRACED = {
                            "expert_row_tiles_worked_pct",
                            "decode_context_tokens", "decode_ticks_in_flight",
                            "ssm_states_read_pct"),
+    # Layers of one sublayer: the expert layers' arguments (counted over
+    # the layers that have experts) and the state kind's, which
+    # ``ssm_update_roofline`` reads, beside the K/V's.
+    "tiny-nemotron-serve": ("decode_host_ms", "decode_active_slots",
+                            "kv_blocks_read_pct", "experts_touched_pct",
+                            "expert_load_max_over_mean",
+                            "expert_row_tiles_worked_pct",
+                            "decode_context_tokens", "decode_ticks_in_flight",
+                            "ssm_states_read_pct"),
 }
 
 
-@pytest.mark.parametrize("name", ["tiny-sarvam-serve", "tiny-granite-serve"])
+@pytest.mark.parametrize("name", ["tiny-sarvam-serve", "tiny-granite-serve",
+                                  "tiny-nemotron-serve"])
 def test_untraced_rehearsal_reports_its_end_to_end_metrics(name, tmp_path):
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env.update(JAX_PLATFORMS="cpu", HOME=str(tmp_path), TMPDIR=str(tmp_path))
@@ -365,6 +375,14 @@ def test_traced_rehearsal_run_exits_0_and_reads_its_spans(name, tmp_path):
     assert metrics["cpu_rehearsal.clean_gap_p95_ms"]["value"] > 0.0
     for metric in _IDLE_METRICS:
         assert f"cpu_rehearsal.{metric}" not in metrics
+    if name == "tiny-nemotron-serve":
+        # The cell it stands for reports the un-gated experts' roofline:
+        # its reader ran and, on a CPU's trace, found no kernel to read.
+        reported = {m["name"] for m in manifest.Cell(name).per_layer()}
+        assert {"relu2_expert_stream_roofline", "ssm_update_roofline",
+                "moe_device_pct"} <= reported
+        assert "moe_weight_stream_roofline" not in reported
+        assert "cpu_rehearsal.relu2_expert_stream_roofline" not in metrics
     visits = metrics.get("cpu_rehearsal.expert_weight_visits_per_touched")
     assert visits is None or visits["value"] >= 1.0
     # Every expert held: every row tile worked; a model without expert

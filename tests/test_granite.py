@@ -16,14 +16,14 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from _oracles import poisoned_past_the_groups
+from _oracles import (DenseState as _DenseState, Kept as _Kept,
+                      poisoned_past_the_groups,
+                      served_logits as _served_logits)
 from fluxmpi_tpu.models import DecoderConfig, ExpertMLP
 from fluxmpi_tpu.models.decoder import MambaMixer
-from fluxmpi_tpu.ops.ssm import (from_pool_layout, ssm_state_update_reference,
-                                 tail_from_pool_layout, tail_to_pool_layout)
+from fluxmpi_tpu.ops.ssm import from_pool_layout, tail_from_pool_layout
 from fluxmpi_tpu.serving import InferenceEngine
 from fluxmpi_tpu.serving.cache import BlockKVCache
-from fluxmpi_tpu.serving.engine import _PagedDecodeAttention
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CONFIGS = os.path.join(ROOT, "benchmarks", "configs")
@@ -113,9 +113,9 @@ def test_config_refuses_what_the_layer_cannot_compute():
                 intermediate_size=16)
     with pytest.raises(ValueError, match="mamba layers need"):
         DecoderConfig(**base)
-    with pytest.raises(ValueError, match="one group of B and C"):
+    with pytest.raises(ValueError, match="not whole groups of B and C"):
         DecoderConfig(**base, mamba_n_heads=2, mamba_d_head=16,
-                      mamba_d_state=8, mamba_n_groups=2)
+                      mamba_d_state=8, mamba_n_groups=3)
     with pytest.raises(ValueError, match="unknown score_func"):
         DecoderConfig(**{**base, "layer_types": ("full_attention",)},
                       score_func="tanh")
@@ -168,43 +168,6 @@ def _mixer(cfg, seed=11):
     params = {k: jnp.asarray(v, jnp.float32) for k, v in w.items()}
     config = DecoderConfig.from_hf(cfg)
     return config, {"params": params}, w
-
-
-class _DenseState:
-    """An ``attention_fn`` that keeps a cache: ONE sequence's state and
-    tail, moved a token a call (what the engine's pools hold an entry
-    of)."""
-
-    from_cache = True
-
-    def __init__(self, config):
-        heads, hd, n = (config.mamba_n_heads, config.mamba_d_head,
-                        config.mamba_d_state)
-        self.heads = heads
-        self.pool = jnp.zeros((1, 2, n, heads * hd), jnp.float32)
-        self.tail_shape = (config.mamba_d_conv - 1, config.mamba_conv_dim)
-        self.tails = jnp.zeros(
-            (1, 2, *tail_to_pool_layout(jnp.zeros(self.tail_shape)).shape))
-
-    @property
-    def tail(self):
-        return tail_from_pool_layout(self.tails[0, 1:], self.tail_shape)
-
-    def conv_tail(self):
-        return self.tail
-
-    def state_update(self, tail, x, step, decay, b, c):
-        y, self.pool, self.tails = ssm_state_update_reference(
-            self.pool, self.tails, jnp.ones((1,), jnp.int32), tail, x, step,
-            decay, b, c)
-        return y
-
-
-class _Kept:
-    """An ``attention_fn`` of a prefill: what the layer hands a cache."""
-
-    def keep_state(self, tail, state):
-        self.tail, self.state = tail, state
 
 
 # Inside the first chunk, on an edge, one past it, chunks and a tail.
@@ -415,50 +378,6 @@ def test_shares_read_no_row_past_their_own_pairs(steer, monkeypatch):
 # ---------------------------------------------------------------------------
 # (e) the engine: prefill, then decode through the state pool and the K/V
 # ---------------------------------------------------------------------------
-
-
-def _served_logits(eng, variables, prompt, ticks):
-    """The logits the engine's own programs give: the prefill program
-    over ``prompt`` (into slot 1's blocks and state entry), then
-    ``ticks`` decode ticks over the engine's pools through
-    :class:`_PagedDecodeAttention` as the decode step builds it, each fed
-    the token the last put first. ``(tokens, logits [ticks, vocab])``."""
-    cache, model = eng.cache, eng.model
-    total = len(prompt) + ticks + 1
-    kinds = range(len(cache.kinds))
-    tables = [cache.table_row(cache.alloc(total, kind), kind)
-              for kind in kinds]
-    bucket = eng._bucket(len(prompt))
-    padded = np.zeros((bucket,), np.int32)
-    padded[:len(prompt)] = prompt
-    first, k_pools, v_pools = eng._prefill_step(bucket)(
-        variables, cache.k_pools, cache.v_pools, jnp.asarray(padded),
-        jnp.int32(len(prompt)), tuple(jnp.asarray(t) for t in tables))
-    # Slot 0 idles beside it.
-    slot_tables = tuple(
-        jnp.stack([jnp.zeros_like(jnp.asarray(t)), jnp.asarray(t)])
-        for t in tables)
-
-    @jax.jit
-    def tick(k_pools, v_pools, position, token):
-        positions = jnp.stack([jnp.int32(0), position])
-        attend = _PagedDecodeAttention(
-            cache, k_pools, v_pools, slot_tables, positions, kernel=False)
-        logits = model.clone(attention_fn=attend).apply(
-            variables, jnp.stack([jnp.int32(0), token])[:, None],
-            pos_offset=positions, token_mask=jnp.asarray([[False], [True]]),
-            mutable=["intermediates"],
-        )[0]
-        return logits[1, 0], tuple(attend.k_pools), tuple(attend.v_pools)
-
-    tokens, rows = [int(first)], []
-    for t in range(ticks):
-        row, k_pools, v_pools = tick(
-            k_pools, v_pools, jnp.int32(len(prompt) + t),
-            jnp.int32(tokens[-1]))
-        rows.append(row)
-        tokens.append(int(jnp.argmax(row)))
-    return tokens, jnp.stack(rows)
 
 
 @pytest.mark.parametrize("state_dtype", ["float32", "bfloat16"])

@@ -31,21 +31,39 @@ _CASES = {
     "three_rows_a_group": ((64, 256, 128), (64, 16, 128),
                            [3, 2, 4, 3, 0, 3, 5, 1, 3, 3, 4, 2, 3, 3, 2, 3]),
     "one_row_tile": ((32, 128, 384), (32, 32, 128), [10, 0, 22]),
+    # Widths that are no whole number of column blocks or lane tiles (the
+    # un-gated experts of 1,856 = 14.5 tiles between a hidden size of
+    # 2,688 = 21): a last column block past the width, a contraction of
+    # one and a half tiles, and weights held TRANSPOSED ([groups, n, k]),
+    # their narrow width on the sublanes.
+    "last_column_block_partial": ((64, 128, 384), (32, 16, 256),
+                                  [20, 0, 30, 14]),
+    "contraction_of_one_and_a_half_tiles": ((64, 192, 384), (32, 16, 128),
+                                            [0, 40, 8, 16]),
+    "transposed_weights": ((64, 384, 256), (32, 16, 128), [16, 0, 30, 18],
+                           True),
+    "transposed_one_and_a_half_tiles_wide": ((64, 384, 192), (32, 16, 128),
+                                             [3, 50, 0, 11], True),
+    "transposed_three_blocks_of_uneven_width": ((96, 256, 320), (32, 16, 128),
+                                                [20, 50, 26], True),
 }
 
 
-def _operands(shape, sizes, seed=0):
+def _operands(shape, sizes, seed=0, transposed=False):
     rows, k, n = shape
     kx, kw = jax.random.split(jax.random.PRNGKey(seed))
     x = jax.random.normal(kx, (rows, k), jnp.bfloat16)
-    w = jax.random.normal(kw, (len(sizes), k, n), jnp.bfloat16)
+    w = jax.random.normal(
+        kw, (len(sizes), n, k) if transposed else (len(sizes), k, n),
+        jnp.bfloat16)
     return x, w, jnp.asarray(sizes, jnp.int32)
 
 
-def _reference(x, w, sizes):
+def _reference(x, w, sizes, transposed=False):
     out = jax.lax.ragged_dot(
-        x.astype(jnp.float32), w.astype(jnp.float32), sizes,
-        precision=jax.lax.Precision.HIGHEST,
+        x.astype(jnp.float32),
+        (jnp.swapaxes(w, 1, 2) if transposed else w).astype(jnp.float32),
+        sizes, precision=jax.lax.Precision.HIGHEST,
     )
     return _live(out, sizes)
 
@@ -58,12 +76,15 @@ def _live(out, sizes):
 
 @pytest.mark.parametrize("name", sorted(_CASES))
 def test_kernel_matches_ragged_dot(name):
-    shape, tiles, sizes = _CASES[name]
-    x, w, sizes = _operands(shape, sizes)
-    got = gm._gmm(x, w, sizes, tiles=tiles, interpret=True)
+    shape, tiles, sizes, *transposed = _CASES[name]
+    transposed = bool(transposed)
+    x, w, sizes = _operands(shape, sizes, transposed=transposed)
+    got = gm._gmm(x, w, sizes, tiles=tiles, transposed=transposed,
+                  interpret=True)
     assert got.dtype == jnp.float32 and got.shape == (shape[0], shape[2])
     np.testing.assert_allclose(
-        _live(got, sizes), _reference(x, w, sizes), rtol=1e-5, atol=1e-4
+        _live(got, sizes), _reference(x, w, sizes, transposed),
+        rtol=1e-5, atol=1e-4
     )
     # A row tile that holds a row of the groups is zeroed past them; one
     # that holds none was never written (the interpreter leaves NaN).
@@ -75,7 +96,7 @@ def test_kernel_matches_ragged_dot(name):
 
 @pytest.mark.parametrize("name", sorted(_CASES))
 def test_walk_visits_each_touched_group_once_a_row_tile(name):
-    shape, tiles, sizes = _CASES[name]
+    shape, tiles, sizes, *_ = _CASES[name]
     rows, tile_rows, groups = shape[0], tiles[0], len(sizes)
     tile, weight, lo, hi, fresh = (
         np.asarray(a) for a in gm._visits(
@@ -140,7 +161,7 @@ def test_jitted_kernel_pads_rows_the_row_tile_does_not_divide(
 
 
 def test_kernel_under_an_outer_jit_lowers_once_a_shape():
-    shape, tiles, sizes = _CASES["uniform"]
+    shape, tiles, sizes, *_ = _CASES["uniform"]
     x, w, sizes = _operands(shape, sizes)
     fn = gm._jitted(True)
     assert fn is gm._jitted(True)
@@ -191,8 +212,24 @@ def test_fallback_is_ragged_dot_bit_for_bit(name, as_tpu, monkeypatch):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("sizes", [[10, 0, 30, 16], [0, 0, 0, 0]])
+def test_transposed_weights_on_a_cpu_are_ragged_dot_of_their_transpose(sizes):
+    x, w, sizes = _operands((64, 128, 96), sizes, transposed=True)
+    assert w.shape == (4, 96, 128)
+    got = gm.grouped_matmul(x, w, sizes, transposed=True)
+    want = jax.lax.ragged_dot(x, jnp.swapaxes(w, 1, 2), sizes,
+                              preferred_element_type=jnp.float32)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_rule_takes_the_kernel_on_a_tpu_for_aligned_bfloat16(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # The weights' lanes are whole tiles: 1,856 columns are not, 1,856
+    # rows of transposed weights (or of a contraction) are.
+    assert gm.row_tile(1152, 2688, 1856, jnp.bfloat16) is None
+    assert gm.row_tile(1152, 2688, 1856, jnp.bfloat16, transposed=True) == 128
+    assert gm.row_tile(1152, 1856, 2688, jnp.bfloat16) == 128
+    assert gm.row_tile(1152, 1856, 2688, jnp.bfloat16, transposed=True) is None
     # Every slot's pairs in one row tile: a touched expert is read once.
     assert gm.row_tile(512, 2048, 1024, jnp.bfloat16) == 512
     assert gm.row_tile(512, 1024, 2048, jnp.bfloat16) == 512
@@ -206,6 +243,16 @@ def test_rule_takes_the_kernel_on_a_tpu_for_aligned_bfloat16(monkeypatch):
     ((384, 256, 128), (128, 128)),
     ((256, 8192, 1024), (256, 256)),
     ((256, 32768, 128), None),
+    # The blocks of the cells the benchmark has: sarvam's up and down,
+    # granite's.
+    ((384, 4096, 2048), (128, 512)),
+    ((384, 2048, 4096), (128, 1024)),
+    ((1280, 4096, 768), (256, 384)),
+    ((1280, 768, 4096), (256, 2048)),
+    # Nemotron's: 1,856 columns in blocks of 640 (the last 576 wide),
+    # 2,688 = 21 tiles in three of 896.
+    ((1152, 2688, 1856), (128, 640)),
+    ((1152, 1856, 2688), (128, 896)),
 ])
 def test_tile_rule_reads_shapes_only(shape, want):
     rows, k, n = shape
@@ -216,7 +263,9 @@ def test_tile_rule_reads_shapes_only(shape, want):
     tile_rows, sub_rows, tile_n = tiles
     assert (tile_rows, tile_n) == want
     assert rows % tile_rows == 0 and tile_rows % sub_rows == 0
-    assert n % tile_n == 0 and tile_n % 128 == 0
+    # Whole lane tiles; only a last block may reach past the width.
+    assert tile_n % 128 == 0 and (-(-n // tile_n) - 1) * tile_n < n
+    assert n % tile_n == 0 or n % 128
     assert k * tile_n * 2 <= gm._WEIGHT_BLOCK_BYTES
 
 
@@ -546,14 +595,17 @@ def _combine_by_hand(y, token, scale, live, tokens):
     return out
 
 
+# Columns in two blocks of 128, and 384 in blocks of 256: the last block
+# half past the width.
+@pytest.mark.parametrize("n,tile_n", [(256, 128), (384, 256)])
 @pytest.mark.parametrize("live", [0, 1, 63, 64, 65, 200, 256])
 @pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
-def test_combine_adds_live_rows_only(kernel, live):
-    rows, n, tokens = 256, 256, 40
+def test_combine_adds_live_rows_only(kernel, live, n, tile_n):
+    rows, tokens = 256, 40
     y, token, scale = _combine_operands(rows, n, tokens, live, seed=live)
     if kernel:
         got = gm._combine(y, token, scale, jnp.int32(live), tokens=tokens,
-                          tiles=(64, 128), interpret=True)
+                          tiles=(64, tile_n), interpret=True)
     else:
         got = gm.combine(y, token, scale, jnp.int32(live), tokens)
     assert got.shape == (tokens, n) and got.dtype == jnp.float32
@@ -569,13 +621,16 @@ def test_combine_adds_live_rows_only(kernel, live):
     ((384, 4096, 48), (128, 4096)),
     ((128, 96, 16), None),              # narrow columns
     ((128, 128, 16384), None),          # no column block fits
+    ((1152, 2688, 192), (128, 2688)),   # a tick: 192 slots x top-6
+    ((12288, 2688, 2048), (512, 512)),  # 21 lane tiles: five blocks and one
 ])
 def test_combine_tiles_read_shapes_only(shape, want):
     rows, n, tokens = shape
     assert gm._combine_tiles(rows, n, tokens) == want
     if want is not None:
         assert tokens * want[1] * 4 <= gm._COMBINE_BLOCK_BYTES
-        assert n % want[1] == 0 and rows % want[0] == 0
+        assert want[1] % 128 == 0 and rows % want[0] == 0
+        assert (-(-n // want[1]) - 1) * want[1] < n
 
 
 def test_combine_on_a_cpu_is_the_masked_segment_sum(monkeypatch):

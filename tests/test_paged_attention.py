@@ -115,7 +115,7 @@ RING_LENGTHS = {"idle": 0, "one": 1, "below": WINDOW - 3, "at": WINDOW,
                 "wrapped": 9 * BLOCK + 5}
 
 
-def _ring_case(length, head_dim, window, seed):
+def _ring_case(length, head_dim, window, seed, q_heads=Q_HEADS):
     """Four slots whose sequences were written position by position into
     their tables (a ring of RING blocks with ``window``, else a plain
     table), over a pool of garbage. Returns the kernel's arguments and
@@ -137,27 +137,30 @@ def _ring_case(length, head_dim, window, seed):
             if tables[slot, entry] == TRASH_BLOCK:
                 tables[slot, entry] = next(order)
             pools[:, :, tables[slot, entry], p % BLOCK] = rows[:, :, p]
-    q = rng.normal(size=(len(lengths), Q_HEADS, head_dim))
+    q = rng.normal(size=(len(lengths), q_heads, head_dim))
     args = (jnp.asarray(q, jnp.float32), jnp.asarray(pools[0]),
             jnp.asarray(pools[1]), jnp.asarray(tables), jnp.asarray(lengths))
     return args, history
 
 
+# 8 query heads a K/V head, and 16 (32 over 2: a pool 256 lanes wide at
+# head_dim 128).
+@pytest.mark.parametrize("q_heads", [Q_HEADS, 32], ids=["8_to_1", "16_to_1"])
 @pytest.mark.parametrize("window", [WINDOW, None], ids=["window", "full"])
 @pytest.mark.parametrize("head_dim", [16, 128])
 @pytest.mark.parametrize("length", sorted(RING_LENGTHS), ids=sorted(RING_LENGTHS))
 def test_paged_decode_attention_grouped_heads_and_window(length, head_dim,
-                                                         window):
-    """8 query heads a K/V head, with and without a window: the kernel
+                                                         window, q_heads):
+    """Grouped query heads, with and without a window: the kernel
     (interpret mode), its reference, and a dense softmax over each slot's
     own last ``window`` positions agree; what the ring has overwritten
     and the garbage around it never reach the output."""
     args, history = _ring_case(RING_LENGTHS[length], head_dim, window,
-                               seed=len(length))
+                               seed=len(length), q_heads=q_heads)
     layer = 1
     got = np.asarray(paged_decode_attention(*args, layer=layer, window=window))
     want = np.asarray(paged_decode_reference(*args, layer=layer, window=window))
-    assert got.shape == (4, Q_HEADS, head_dim)
+    assert got.shape == (4, q_heads, head_dim)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     q = np.asarray(args[0])
     for slot, n in enumerate(np.asarray(args[4])):
@@ -167,7 +170,7 @@ def test_paged_decode_attention_grouped_heads_and_window(length, head_dim,
         lo = max(0, n - window) if window else 0
         keys, values = (history[slot][i][layer, lo:n].reshape(
             -1, KV_HEADS, head_dim) for i in (0, 1))
-        group = Q_HEADS // KV_HEADS
+        group = q_heads // KV_HEADS
         keys, values = (np.repeat(a, group, axis=1) for a in (keys, values))
         scores = np.einsum("hd,thd->ht", q[slot], keys) / np.sqrt(head_dim)
         probs = np.exp(scores - scores.max(axis=1, keepdims=True))

@@ -20,20 +20,25 @@ TAIL = (3, HEADS * HEAD_DIM + 2 * 16)
 TILES = 4
 
 
-def _operands(seed=0, dtype=jnp.float32):
+def _operands(seed=0, dtype=jnp.float32, groups=None):
     """``(state pool, tail pool), (tail, x, step, decay, b, c)``: pools
-    full of noise, so that an entry written by mistake shows."""
+    full of noise, so that an entry written by mistake shows. With
+    ``groups``, B and C carry a group axis and a head is 64 wide: 512
+    lanes, a group 256 or 128 of them (whole tiles of the kernel's
+    walk)."""
+    head_dim = HEAD_DIM if groups is None else 64
+    grouped = (SLOTS, D_STATE) if groups is None else (SLOTS, groups, D_STATE)
     keys = jax.random.split(jax.random.PRNGKey(seed), 8)
     pool = jax.random.normal(
-        keys[0], (LAYERS, ENTRIES, D_STATE, HEADS * HEAD_DIM)).astype(dtype)
+        keys[0], (LAYERS, ENTRIES, D_STATE, HEADS * head_dim)).astype(dtype)
     tail_pool = jax.random.normal(
         keys[5], (LAYERS, ENTRIES, TILES, 128)).astype(jnp.bfloat16)
     tail = jax.random.normal(keys[6], (SLOTS, *TAIL))
-    x = jax.random.normal(keys[1], (SLOTS, HEADS, HEAD_DIM))
+    x = jax.random.normal(keys[1], (SLOTS, HEADS, head_dim))
     step = jax.nn.softplus(jax.random.normal(keys[2], (SLOTS, HEADS)) - 2.0)
     decay = jnp.exp(-step * jnp.linspace(1.0, 16.0, HEADS))
-    b = jax.random.normal(keys[3], (SLOTS, D_STATE))
-    c = jax.random.normal(keys[4], (SLOTS, D_STATE))
+    b = jax.random.normal(keys[3], grouped)
+    c = jax.random.normal(keys[4], grouped)
     return (pool, tail_pool), (tail, x, step, decay, b, c)
 
 
@@ -52,9 +57,13 @@ def _by_hand(pool, entry, slot, operands, layer):
                             for v in operands[1:])
     state = np.asarray(ssm.from_pool_layout(pool[layer, entry], HEADS),
                        np.float64)
+    # Head h reads its group's B and C: group h // (heads / groups).
+    b, c = (np.repeat(v.reshape(-1, D_STATE), HEADS // v.reshape(
+        -1, D_STATE).shape[0], axis=0) for v in (b, c))
     moved = (decay[:, None, None] * state
-             + (step[:, None] * x)[:, :, None] * b[None, None, :])
-    return moved @ c, np.asarray(ssm.to_pool_layout(jnp.asarray(moved)))
+             + (step[:, None] * x)[:, :, None] * b[:, None, :])
+    return (np.einsum("hpn,hn->hp", moved, c),
+            np.asarray(ssm.to_pool_layout(jnp.asarray(moved))))
 
 
 # Idle slots point at the trash entry (0): none live, one, some, all, and
@@ -64,15 +73,20 @@ LIVE = {"none_live": (0, 0, 0, 0), "one_live": (0, 0, 3, 0),
         "against_slot_order": (5, 4, 0, 1)}
 
 
+# One group for all heads (no group axis), two groups of four heads, four
+# of two.
+@pytest.mark.parametrize("groups", [None, 2, 4],
+                         ids=["one_group", "two_groups", "four_groups"])
 @pytest.mark.parametrize("entries", LIVE.values(), ids=LIVE.keys())
 @pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "reference"])
-def test_state_update_moves_live_states_and_leaves_the_rest(entries, kernel):
+def test_state_update_moves_live_states_and_leaves_the_rest(entries, kernel,
+                                                            groups):
     layer = 1
-    (pool, tail_pool), operands = _operands()
+    (pool, tail_pool), operands = _operands(groups=groups)
     entries = jnp.asarray(entries, jnp.int32)
     y, out, tails = _update(kernel, (pool, tail_pool), entries, operands,
                             layer)
-    assert y.shape == (SLOTS, HEADS, HEAD_DIM) and y.dtype == jnp.float32
+    assert y.shape == operands[1].shape and y.dtype == jnp.float32
     assert out.shape == pool.shape and out.dtype == pool.dtype
     assert tails.shape == tail_pool.shape and tails.dtype == tail_pool.dtype
     live = {int(e): slot for slot, e in enumerate(entries) if int(e)}
@@ -101,12 +115,13 @@ def test_state_update_moves_live_states_and_leaves_the_rest(entries, kernel):
         np.testing.assert_array_equal(tails[layer, entry], want_tails[slot])
 
 
+@pytest.mark.parametrize("groups", [None, 4], ids=["one_group", "four_groups"])
 @pytest.mark.parametrize("entries", LIVE.values(), ids=LIVE.keys())
-def test_kernel_and_reference_write_the_same_pools(entries):
+def test_kernel_and_reference_write_the_same_pools(entries, groups):
     """One contract: what the chip runs and what runs anywhere else leave
     the same tail pool bit for bit, and the same states to the last place
     of a float32 sum."""
-    pools, operands = _operands(seed=4)
+    pools, operands = _operands(seed=4, groups=groups)
     entries = jnp.asarray(entries, jnp.int32)
     y_k, out_k, tails_k = _update(True, pools, entries, operands)
     y_r, out_r, tails_r = _update(False, pools, entries, operands)
@@ -203,13 +218,17 @@ def _recurrence(x, step, a_rate, b, c, initial=None):
     """``H_t = a_t H_{t-1} + D_t x_t B_t^T``, ``y_t = H_t C_t``, a token
     at a time."""
     batch, _, heads, head_dim = x.shape
+    if b.ndim == 4:  # a group axis: head h reads group h // (heads / groups)
+        b, c = (jnp.repeat(v, heads // v.shape[2], axis=2) for v in (b, c))
+    else:
+        b, c = (jnp.repeat(v[:, :, None], heads, axis=2) for v in (b, c))
 
     def token(state, at):
         x_t, step_t, b_t, c_t = at
         state = (state * jnp.exp(step_t * a_rate)[:, :, None, None]
                  + (step_t[..., None] * x_t)[..., None]
-                 * b_t[:, None, None, :])
-        return state, jnp.einsum("bhpn,bn->bhp", state, c_t)
+                 * b_t[:, :, None, :])
+        return state, jnp.einsum("bhpn,bhn->bhp", state, c_t)
 
     if initial is None:
         initial = jnp.zeros((batch, heads, head_dim, b.shape[-1]))
@@ -218,23 +237,28 @@ def _recurrence(x, step, a_rate, b, c, initial=None):
     return jnp.swapaxes(y, 0, 1), state
 
 
-def _sequence(seq, seed=0, batch=2, heads=4, head_dim=8, d_state=16):
+def _sequence(seq, seed=0, batch=2, heads=4, head_dim=8, d_state=16,
+              groups=None):
     keys = jax.random.split(jax.random.PRNGKey(seed), 5)
     x = jax.random.normal(keys[0], (batch, seq, heads, head_dim))
     step = jax.nn.softplus(jax.random.normal(keys[1], (batch, seq, heads)) - 2)
     a_rate = -jnp.exp(jnp.linspace(0.0, 2.7, heads))
-    b = jax.random.normal(keys[2], (batch, seq, d_state))
-    c = jax.random.normal(keys[3], (batch, seq, d_state))
+    grouped = (batch, seq, d_state) if groups is None else (
+        batch, seq, groups, d_state)
+    b = jax.random.normal(keys[2], grouped)
+    c = jax.random.normal(keys[3], grouped)
     initial = jax.random.normal(keys[4], (batch, heads, head_dim, d_state))
     return x, step, a_rate, b, c, initial
 
 
 # Inside the first chunk, on its edge, one past it, several
 # chunks and a ragged tail.
+@pytest.mark.parametrize("groups", [None, 1, 2, 4],
+                         ids=["no_group_axis", "one", "two", "a_head_each"])
 @pytest.mark.parametrize("seq", [1, 8, 9, 37])
 @pytest.mark.parametrize("start", ["zero", "given"])
-def test_chunked_scan_is_the_recurrence(seq, start):
-    x, step, a_rate, b, c, initial = _sequence(seq, seed=seq)
+def test_chunked_scan_is_the_recurrence(seq, start, groups):
+    x, step, a_rate, b, c, initial = _sequence(seq, seed=seq, groups=groups)
     initial = initial if start == "given" else None
     y, state = ssm.ssd_chunk_scan(x, step, a_rate, b, c, chunk=8,
                                   initial_state=initial)

@@ -654,6 +654,191 @@ def test_granite_4_0_h_small_serving_programs_compile_for_v5e(topo, as_on_tpu):
                 < 14.5e9)
 
 
+# nemotron-3-nano-serve's expert layer: 64 held experts of [1856, 2688]
+# both ways (the up projection transposed: 1,856 is 14.5 lane tiles); 192
+# slots x top-6 rows in the decode tick, the shortest and the longest
+# prompt bucket x 6 in a prefill.
+@pytest.mark.parametrize("tokens", [192, 256, 2048])
+def test_relu2_expert_kernels_compile_for_v5e(topo, as_on_tpu, tokens):
+    from fluxmpi_tpu.ops import grouped_matmul as gm
+
+    dev = topo.devices[0]
+    rows, d, width, held = tokens * 6, 2688, 1856, 64
+    tile = gm.live_row_tile(rows)
+    assert gm._tile_rule(rows, d, width, 2) == (tile, 128, 640)
+    assert gm._tile_rule(rows, width, d, 2) == (tile, 128, 896)
+    assert gm.row_tile(rows, d, width, jnp.bfloat16, transposed=True) == tile
+    assert gm.row_tile(rows, d, width, jnp.bfloat16) is None  # 1,856 lanes
+
+    def experts(u, w_up, w_down, sizes, token, scale, live):
+        up = gm.grouped_matmul(u, w_up, sizes, transposed=True)
+        y = gm.grouped_matmul(
+            jnp.square(jax.nn.relu(up)).astype(jnp.bfloat16), w_down, sizes)
+        return gm.combine(y, token, scale, live, tokens)
+
+    weights = _sds((held, width, d), jnp.bfloat16, dev)
+    compiled = jax.jit(experts).lower(
+        _sds((rows, d), jnp.bfloat16, dev), weights, weights,
+        _sds((held,), jnp.int32, dev), _sds((rows,), jnp.int32, dev),
+        _sds((rows,), jnp.float32, dev), _sds((), jnp.int32, dev),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert len(re.findall(r"%ragged-dot-gmm[.\d]* = ", text)) == 2
+    assert len(re.findall(r"%expert-combine[.\d]* = ", text)) == 1
+    # Both matrices are read where they lie (the hidden size on their
+    # lanes): no copy of 638 MB of weights into a padded layout.
+    assert "copy" not in _pool_sized(text, (held, width, d))
+    assert compiled.memory_analysis().temp_size_in_bytes < tokens * 2**17
+
+
+def test_ssm_state_update_kernel_with_groups_compiles_for_v5e(topo):
+    """The state update at nemotron-3-nano's widths: 64 heads of 64 over
+    a state of 128, EIGHT groups of B and C (a group 512 lanes, four
+    tiles of the walk), 4 layers x 193 entries, tails of 144 lane
+    tiles."""
+    from fluxmpi_tpu.ops.ssm import ssm_state_update
+
+    dev = topo.devices[0]
+    slots, layers, heads, head_dim, d_state, groups = 192, 4, 64, 64, 128, 8
+    pool = (layers, slots + 1, d_state, heads * head_dim)
+    tails = (layers, slots + 1, 144, 128)
+
+    def update(pool, tail_pool, entries, tail, x, step, decay, b, c):
+        return ssm_state_update(pool, tail_pool, entries, tail, x, step,
+                                decay, b, c, layer=2, interpret=False)
+
+    compiled = jax.jit(update, donate_argnums=(0, 1)).lower(
+        _sds(pool, jnp.float32, dev), _sds(tails, jnp.bfloat16, dev),
+        _sds((slots,), jnp.int32, dev),
+        _sds((slots, 3, 6144), jnp.bfloat16, dev),
+        _sds((slots, heads, head_dim), jnp.float32, dev),
+        _sds((slots, heads), jnp.float32, dev),
+        _sds((slots, heads), jnp.float32, dev),
+        _sds((slots, groups, d_state), jnp.float32, dev),
+        _sds((slots, groups, d_state), jnp.float32, dev),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(re.findall(r"%ssm_state_update[.\d]* = ", text)) == 1
+    # The state pool is moved where it lies. (The tails, 28 MB here, this
+    # free-standing program prefetches whole into fast memory and writes
+    # back, one ``copy-start`` each way: the compiler's placement, no
+    # re-tiling; the cell's decode program is held to none below.)
+    assert set(_pool_sized(text, pool)) <= {
+        "custom-call", "parameter", "get-tuple-element"}
+    assert "copy" not in _pool_sized(text, pool, tails)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= layers * (slots + 1) * (
+        heads * head_dim * d_state * 4 + 144 * 128 * 2)  # in place
+    assert memory.temp_size_in_bytes < 2**24
+
+
+def test_nemotron_3_nano_serving_programs_compile_for_v5e(topo, as_on_tpu):
+    """The ``nemotron-3-nano-serve`` cell's decode program and its LONGEST
+    prefill at the published widths (layers of one sublayer: 4 Mamba-2 of
+    64 heads of 64 over a state of 128 in 8 groups, 4 expert layers
+    holding 64 of 128 un-gated experts of [1856, 2688], one attention
+    layer of 32 over 2 heads of 128; 65,536 rows of the vocabulary; the
+    cell's slots x 6,144 positions in 256-blocks): one state-update
+    kernel a Mamba layer, one paged decode kernel, the grouped matmul's
+    kernel TWICE an expert layer and its combine once, every pool
+    updated in place, no copy of a pool or of an expert matrix, and
+    everything under 14.5 GB beside 6.33 GB of bfloat16 weights."""
+    import json
+
+    from fluxmpi_tpu.serving import InferenceEngine
+
+    prog, configs = _load_config_module("nemotron.program.py")
+    ref, _ = _load_config_module("nemotron.reference.py")
+    with open(os.path.join(configs, "nemotron-3-nano-30b-a3b.json"),
+              encoding="utf-8") as f:
+        cfg = json.load(f)
+    with open(os.path.join(os.path.dirname(configs), "workloads",
+                           "nemotron-3-nano-serve.json"),
+              encoding="utf-8") as f:
+        geometry = json.load(f)["engine"]
+    dev = topo.devices[0]
+    params = jax.tree_util.tree_map(
+        lambda x: _sds(x.shape, x.dtype, dev),
+        jax.eval_shape(
+            lambda key: prog.to_program(ref.make_weights(cfg, key), cfg)[0],
+            jax.random.PRNGKey(0),
+        ),
+    )
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert 6.32e9 < weights < 6.34e9
+    slots, bucket = geometry["slots"], 2048
+    engine = InferenceEngine(
+        prog.build_model(cfg, "naive"), params, attention="flash",
+        slots=slots, block_size=geometry["block_size"],
+        max_len=geometry["max_len"], check_memory=False,
+    )
+    try:
+        cache = engine.cache
+        # Nine layers, five of them keep something: the expert layers
+        # have no pool, no table and no entry.
+        assert cache.num_layers == 5
+        assert cache.pool_shapes == [(1, 1 + slots * 24, 256, 256),
+                                     (4, 1 + slots, 128, 4096)]
+        state, tail = 64 * 64 * 128 * 4, 3 * 6144 * 2
+        assert cache.pool_bytes == (
+            2 * (1 + slots * 24) * 256 * 256 * 2
+            + (1 + slots) * 4 * (state + tail))
+        k_pools = (_sds(cache.pool_shapes[0], jnp.bfloat16, dev),
+                   _sds(cache.pool_shapes[1], jnp.float32, dev))
+        tails = (4, 1 + slots, cache.tail_tiles, 128)
+        assert cache.tail_tiles * 128 == 3 * 6144  # whole tiles, no padding
+        v_pools = (_sds(cache.pool_shapes[0], jnp.bfloat16, dev),
+                   _sds(tails, jnp.bfloat16, dev))
+        decode = engine._decode_step.lower(
+            params, k_pools, v_pools,
+            tuple(_sds((slots, k.entries), jnp.int32, dev)
+                  for k in cache.kinds),
+            _sds((slots,), jnp.int32, dev), _sds((slots,), jnp.int32, dev),
+            # prev: the tokens, then 4 expert layers' counts of 64 held.
+            _sds((slots + 4 * 64,), jnp.int32, dev),
+            _sds((slots,), jnp.bool_, dev),
+        ).compile()
+        prefill = engine._prefill_step(bucket).lower(
+            params, k_pools, v_pools, _sds((bucket,), jnp.int32, dev),
+            _sds((), jnp.int32, dev),
+            tuple(_sds((k.entries,), jnp.int32, dev) for k in cache.kinds),
+        ).compile()
+    finally:
+        engine.close()
+    text = decode.as_text()
+    assert text.count("tpu_custom_call") == 4 + 1 + 4 * 3
+    assert "slice-start" not in text  # operands prefetched whole
+    assert len(re.findall(r"%ssm_state_update[.\d]* = ", text)) == 4
+    assert len(re.findall(r"%ragged-dot-gmm[.\d]* = ", text)) == 4 * 2
+    assert len(re.findall(r"%expert-combine[.\d]* = ", text)) == 4
+    # The state pool is the result of the four kernels and of nothing
+    # else. The tails (28 MB) the compiler prefetches whole into fast
+    # memory around each kernel (``copy-start`` / ``copy-done``, the same
+    # tiling both ways; granite's 58 MB it leaves where they lie):
+    # PERF.md §7.
+    assert set(_pool_sized(text, cache.pool_shapes[1])) <= {
+        "custom-call", "parameter", "get-tuple-element"}
+    assert set(_pool_sized(text, tails)) <= {
+        "custom-call", "parameter", "get-tuple-element", "copy-done"}
+    for program in (decode, prefill):
+        assert "copy" not in _pool_sized(
+            program.as_text(), *cache.pool_shapes, tails, (64, 1856, 2688))
+    # The prefill: one flash forward (the attention layer), the chunked
+    # scan in plain XLA, no state-update kernel.
+    text = prefill.as_text()
+    assert text.count("tpu_custom_call") == 1 + 4 * 3
+    assert not re.findall(r"%ssm_state_update[.\d]* = ", text)
+    for program, temporaries in ((decode, 2**28), (prefill, 3 * 2**29)):
+        memory = program.memory_analysis()
+        assert memory.temp_size_in_bytes < temporaries
+        assert memory.alias_size_in_bytes >= cache.pool_bytes  # in place
+        assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                < 14.5e9)
+
+
 def _lm_state(cfg, optimizer):
     model = chip_smoke._lm(cfg)
     params = jax.eval_shape(
