@@ -266,8 +266,14 @@ class TransformerLM(nn.Module):
                 at = jnp.asarray(pos_offset)[:, None] + jnp.arange(seq)
                 pos_rows = pos[at]
             x = embed(tokens) + pos_rows.astype(self.dtype)
-            # causal mask
-            mask = nn.make_causal_mask(tokens)
+            # The causal mask, which the flash path folds into its kernels
+            # (``attention_causal``): handed a mask that says nothing
+            # else, ``flash_attention_fn`` would recover one segment id a
+            # token from it and the kernels would compare ids on every
+            # tile (PERF.md §6, PR 42: 2.22 ms a layer where causality
+            # alone takes 1.97).
+            flash = _resolve_attention_mode(self.attention) == "flash"
+            mask = None if flash else nn.make_causal_mask(tokens)
         x = self.make_encoder()(x, train=train, mask=mask)
         if hidden:
             if targets is not None:

@@ -26,10 +26,11 @@ in one pass; float32 callers keep float32 products); the softmax
 statistics, the accumulators and the scale stay float32. A grid step's
 block is worked in sub-tiles under loops that run only from the window's
 far edge to the causal frontier, and only a sub-tile the mask cuts
-computes it (:func:`_walk`); block and sub-tile sizes are a pure function
-of the static shapes (:func:`_tile_rule`). On non-TPU backends the kernels
-run in Pallas interpret mode, which is how the CPU test suite exercises
-them.
+computes it (:func:`_walk`), under the causal mask alone as a staircase
+of strips that stop at the diagonal (:func:`_strips`); block and sub-tile
+sizes are a pure function of the static shapes (:func:`_tile_rule`). On
+non-TPU backends the kernels run in Pallas interpret mode, which is how
+the CPU test suite exercises them.
 """
 
 from __future__ import annotations
@@ -107,6 +108,67 @@ def _pos_mask(shape, offset, window: int | None = None,
         band = diff < window - offset
         mask = band if mask is None else mask & band
     return mask
+
+
+def _cut(mask, x, fill, axis: int, last: bool = True):
+    """``x`` where ``mask`` keeps it, ``fill`` elsewhere. A mask shorter
+    than ``x`` along ``axis`` covers ``x``'s last entries along it (its
+    first without ``last``) and the rest of ``x`` passes as it is: the
+    staircase's one cut square beside the squares under it
+    (:func:`_strips`); both pieces are whole 128-lane tiles."""
+    n, full = mask.shape[axis], x.shape[axis]
+    if n == full:
+        return jnp.where(mask, x, fill)
+    at = full - n if last else n
+    parts = [jax.lax.slice_in_dim(x, 0, at, axis=axis),
+             jax.lax.slice_in_dim(x, at, full, axis=axis)]
+    parts[last] = jnp.where(mask, parts[last], fill)
+    return jnp.concatenate(parts, axis=axis)
+
+
+def _part(x, piece: slice, axis: int):
+    """``x[piece]`` along ``axis`` (whole lane tiles); ``x`` itself where
+    the piece is all of it."""
+    start, stop, _ = piece.indices(x.shape[axis])
+    if (start, stop) == (0, x.shape[axis]):
+        return x
+    return jax.lax.slice_in_dim(x, start, stop, axis=axis)
+
+
+def _span(start, piece: slice, size: int):
+    """``piece`` of the ``size`` positions from ``start`` (a Python int,
+    or a traced multiple of ``size``), to index a ref with: a statistic
+    one query a lane is read a piece at a time, since a lane slice of the
+    loaded row is no layout the chip's compiler broadcasts from."""
+    lo, hi, _ = piece.indices(size)
+    if lo:
+        start = (start + lo if isinstance(start, int)
+                 else pl.multiple_of(start + lo, _LANES))
+    return pl.ds(start, hi - lo)
+
+
+def _join(parts, axis: int):
+    """The pieces' results side by side along ``axis``."""
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis)
+
+
+def _pieces(sub: int, strips: int, key_strips: bool = False):
+    """``(queries, keys)`` slices of a sub-tile of ``sub`` x ``sub``, the
+    pieces a kernel's body works one beside the other: the whole tile, or
+    the staircase of a diagonal sub-tile in ``strips`` strips
+    (:func:`_strips`): query strip ``i`` with the keys at or under its
+    frontier, or (``key_strips``, the dkv kernel, whose accumulators'
+    rows are keys) key strip ``j`` with the queries at or past its first
+    key. The square the diagonal crosses is a piece's LAST keys, or its
+    FIRST queries."""
+    if not strips:
+        return [(slice(None), slice(None))]
+    w = sub // strips
+    if key_strips:
+        return [(slice(j * w, sub), slice(j * w, (j + 1) * w))
+                for j in range(strips)]
+    return [(slice(i * w, (i + 1) * w), slice(0, (i + 1) * w))
+            for i in range(strips)]
 
 
 def _when(pred):
@@ -198,7 +260,11 @@ def _walk(tile, qi, kj, tiles, causal: bool, window: int | None,
     in the future of its last query) and ``q - (k + sub_k - 1) < window``
     (its closest pair is inside the window); the mask drops a pair iff
     ``k + sub_k - 1 > q`` (the diagonal crosses it) or
-    ``q + sub_q - 1 - k >= window`` (the window's edge does)."""
+    ``q + sub_q - 1 - k >= window`` (the window's edge does). Under the
+    causal mask alone and square sub-tiles, ``q`` and ``k`` both
+    multiples of the sub-tile, the one cut sub-tile a query sub-tile
+    visits is the DIAGONAL one, ``q == k``: what :func:`_strips` lets
+    ``tile`` work as a staircase."""
     block_q, block_k, sub_q, sub_k = tiles
     q0, k0 = qi * block_q, kj * block_k
     nq, nk = block_q // sub_q, block_k // sub_k
@@ -373,7 +439,79 @@ def _flash_kernel(
         l_scratch[...] = jnp.zeros_like(l_scratch)
         acc_scratch[...] = jnp.zeros_like(acc_scratch)
 
+    strips = _strips(tiles, causal, window,
+                     has_segments or bool(dropout_rate))
+
+    def _update(r0, c0, mask, stair=0, q_start=None, k_start=None):
+        """One step of the online softmax: the sub-tile at ``(r0, c0)``,
+        whole or (``stair``) as a staircase of that many pieces
+        (:func:`_pieces`), each stage for every piece before the next
+        stage, so that nothing orders one piece's chain of products and
+        exponentials behind another's; ``mask`` is the whole tile's, or
+        the square of a piece's last keys."""
+        pieces = _pieces(sub_q, stair)
+        rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
+        q, k, vt = _operands(
+            q_ref[0, rows, :], k_ref[0, cols, :], vt_ref[0, :, cols],
+            rows=sub_q,
+        )
+        s_t = [
+            jax.lax.dot_general(
+                _part(k, ks, 0), _part(q, qs, 0), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) for qs, ks in pieces
+        ]  # [keys, queries] each
+        if s_scale != 1.0:
+            s_t = [x * s_scale for x in s_t]
+        if mask is not None:
+            s_t = [_cut(mask, x, _NEG_INF, axis=0) for x in s_t]
+
+        # The statistics, a piece's queries at a time: [1, queries].
+        spans = [_span(r0, qs, sub_q) for qs, _ in pieces]
+        m_prev = [m_scratch[:1, x] for x in spans]
+        m_new = [jnp.maximum(m, jnp.max(x, axis=0, keepdims=True))
+                 for m, x in zip(m_prev, s_t)]
+        alpha = [jnp.exp(m - n) for m, n in zip(m_prev, m_new)]
+        p_t = [jnp.exp(x - n) for x, n in zip(s_t, m_new)]
+        if mask is not None and (window is not None or has_segments):
+            # A query with no kept key so far has m_new = -1e30, and
+            # its masked scores would exponentiate to 1. Under the
+            # causal mask alone no such query exists (the first
+            # sub-tile visited holds key 0, which every query sees),
+            # so exp has already zeroed what s_t masked.
+            p_t = [jnp.where(mask, x, 0.0) for x in p_t]
+        # Softmax normalization (l) accumulates UNdropped probabilities
+        # — dropout applies after normalization (flax semantics); only
+        # the value accumulation sees the dropped, 1/keep_prob-scaled
+        # tile.
+        l_new = [l_scratch[:1, x] * a + jnp.sum(p, axis=0, keepdims=True)
+                 for x, a, p in zip(spans, alpha, p_t)]
+        if dropout_rate:
+            kp = 1.0 - dropout_rate
+            keep = _tile_keep((sub_k, sub_q), seed_ref[0, 0], bh, q_start,
+                              k_start, kp, transposed=True)
+            p_t = [jnp.where(keep, x / kp, 0.0) for x in p_t]
+
+        # p is narrowed to the operand dtype only as an operand of
+        # its own product; statistics and accumulators stay f32.
+        pv = [
+            jax.lax.dot_general(
+                _part(vt, ks, 1), x.astype(vt.dtype),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) for x, (_, ks) in zip(p_t, pieces)
+        ]  # [d, queries] each
+        for x, a, y, m, l in zip(spans, alpha, pv, m_new, l_new):
+            acc_scratch[:, x] = acc_scratch[:, x] * a + y
+            m_scratch[:, x] = jnp.broadcast_to(m, (_SUBLANES, x.size))
+            l_scratch[:, x] = jnp.broadcast_to(l, (_SUBLANES, x.size))
+
     def _tile(r0, c0, masked):
+        if masked and strips:
+            # The diagonal sub-tile, as a staircase of query strips.
+            w = sub_q // strips
+            _update(r0, c0, _pos_mask((w, w), 0, transposed=True), strips)
+            return
         rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
         q_start = qi * block_q + r0
         k_start = kj * block_k + c0
@@ -386,60 +524,12 @@ def _flash_kernel(
             # sublane-replicated → [1, sub_q] row.
             sm = _seg_mask(qseg_ref[0, :1, rows], kseg_ref[0, cols, :1])
             mask = sm if mask is None else jnp.logical_and(mask, sm)
-
-        def _compute():
-            q, k, vt = _operands(
-                q_ref[0, rows, :], k_ref[0, cols, :], vt_ref[0, :, cols],
-                rows=sub_q,
-            )
-            s_t = jax.lax.dot_general(
-                k, q, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [sub_k, sub_q]
-            if s_scale != 1.0:
-                s_t = s_t * s_scale
-            if mask is not None:
-                s_t = jnp.where(mask, s_t, _NEG_INF)
-
-            m_prev = m_scratch[:1, rows]  # [1, sub_q]
-            l_prev = l_scratch[:1, rows]
-            m_new = jnp.maximum(m_prev, jnp.max(s_t, axis=0, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p_t = jnp.exp(s_t - m_new)
-            if mask is not None and (window is not None or has_segments):
-                # A query with no kept key so far has m_new = -1e30, and
-                # its masked scores would exponentiate to 1. Under the
-                # causal mask alone no such query exists (the first
-                # sub-tile visited holds key 0, which every query sees),
-                # so exp has already zeroed what s_t masked.
-                p_t = jnp.where(mask, p_t, 0.0)
-            # Softmax normalization (l) accumulates UNdropped probabilities
-            # — dropout applies after normalization (flax semantics); only
-            # the value accumulation sees the dropped, 1/keep_prob-scaled
-            # tile.
-            l_new = l_prev * alpha + jnp.sum(p_t, axis=0, keepdims=True)
-            if dropout_rate:
-                kp = 1.0 - dropout_rate
-                keep = _tile_keep(p_t.shape, seed_ref[0, 0], bh, q_start,
-                                  k_start, kp, transposed=True)
-                p_t = jnp.where(keep, p_t / kp, 0.0)
-
-            # p is narrowed to the operand dtype only as an operand of
-            # its own product; statistics and accumulators stay f32.
-            acc_scratch[:, rows] = acc_scratch[:, rows] * alpha + (
-                jax.lax.dot_general(
-                    vt, p_t.astype(vt.dtype), (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-            )  # [d, sub_q]
-            m_scratch[:, rows] = jnp.broadcast_to(m_new, (_SUBLANES, sub_q))
-            l_scratch[:, rows] = jnp.broadcast_to(l_new, (_SUBLANES, sub_q))
-
+        update = functools.partial(_update, r0, c0, mask, 0, q_start, k_start)
         if has_segments:
             # Block-sparse skip of fully-masked / fully-padded sub-tiles.
-            pl.when(jnp.any(mask))(_compute)
+            pl.when(jnp.any(mask))(update)
         else:
-            _compute()
+            update()
 
     _walk(_tile, qi, kj, tiles, causal, window,
           has_segments or bool(dropout_rate))
@@ -488,7 +578,68 @@ def _flash_bwd_dq_kernel(
     def _init():
         dq_scratch[...] = jnp.zeros_like(dq_scratch)
 
+    strips = _strips(tiles, causal, window,
+                     has_segments or bool(dropout_rate))
+
+    def _update(r0, c0, mask, stair=0, q_start=None, k_start=None):
+        """``dq += ds @ K`` for the sub-tile at ``(r0, c0)``, whole or
+        (``stair``) in that many pieces (:func:`_pieces`), stage by stage
+        as in the forward; ``mask`` is the whole tile's, or the square of
+        a piece's last keys."""
+        pieces = _pieces(sub_q, stair)
+        rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
+        q, k, v, do = _operands(
+            q_ref[0, rows, :], k_ref[0, cols, :], v_ref[0, cols, :],
+            do_ref[0, rows, :],
+        )
+        # lse/dterm arrive sublane-replicated ([8, block_q] rows — the
+        # 8× layout, ADVICE r3 #2); one in-register transpose per
+        # sub-tile gives the [sub_q, 1] column the score math
+        # broadcasts against.
+        lse = jnp.transpose(lse_ref[0, :1, rows])  # [sub_q, 1]
+        dterm = jnp.transpose(dterm_ref[0, :1, rows])  # delta - dlse
+
+        s = [
+            jax.lax.dot_general(
+                _part(q, qs, 0), _part(k, ks, 0), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) for qs, ks in pieces
+        ]  # [queries, keys] each
+        if s_scale != 1.0:
+            s = [x * s_scale for x in s]
+        # normalized probabilities
+        p = [jnp.exp(x - _part(lse, qs, 0)) for x, (qs, _) in zip(s, pieces)]
+        if mask is not None:
+            p = [_cut(mask, x, 0.0, axis=1) for x in p]
+        dp = [
+            jax.lax.dot_general(
+                _part(do, qs, 0), _part(v, ks, 0), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) for qs, ks in pieces
+        ]  # [queries, keys] each
+        if dropout_rate:
+            # ds = w ∘ (d∘dp/kp − delta): the dropout mask lands on
+            # dp; the delta term (rowsum dO∘O) already carries the
+            # dropped forward.
+            kp = 1.0 - dropout_rate
+            keep = _tile_keep((sub_q, sub_k), seed_ref[0, 0], bh, q_start,
+                              k_start, kp)
+            dp = [jnp.where(keep, x / kp, 0.0) for x in dp]
+        ds = [x * (y - _part(dterm, qs, 0))
+              for x, y, (qs, _) in zip(p, dp, pieces)]
+        dq_scratch[rows, :] += _join([
+            jax.lax.dot_general(
+                x.astype(k.dtype), _part(k, ks, 0), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) for x, (_, ks) in zip(ds, pieces)
+        ], 0)
+
     def _tile(r0, c0, masked):
+        if masked and strips:
+            # The diagonal sub-tile, as a staircase of query strips.
+            w = sub_q // strips
+            _update(r0, c0, _pos_mask((w, w), 0), strips)
+            return
         rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
         q_start = qi * block_q + r0
         k_start = kj * block_k + c0
@@ -498,50 +649,11 @@ def _flash_bwd_dq_kernel(
         if has_segments:
             sm = _seg_mask(qseg_ref[0, rows, :1], kseg_ref[0, :1, cols])
             mask = sm if mask is None else jnp.logical_and(mask, sm)
-
-        def _compute():
-            q, k, v, do = _operands(
-                q_ref[0, rows, :], k_ref[0, cols, :], v_ref[0, cols, :],
-                do_ref[0, rows, :],
-            )
-            # lse/dterm arrive sublane-replicated ([8, block_q] rows — the
-            # 8× layout, ADVICE r3 #2); one in-register transpose per
-            # sub-tile gives the [sub_q, 1] column the score math
-            # broadcasts against.
-            lse = jnp.transpose(lse_ref[0, :1, rows])  # [sub_q, 1]
-            dterm = jnp.transpose(dterm_ref[0, :1, rows])  # delta - dlse
-
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [sub_q, sub_k]
-            if s_scale != 1.0:
-                s = s * s_scale
-            p = jnp.exp(s - lse)  # normalized probabilities
-            if mask is not None:
-                p = jnp.where(mask, p, 0.0)
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [sub_q, sub_k]
-            if dropout_rate:
-                # ds = w ∘ (d∘dp/kp − delta): the dropout mask lands on
-                # dp; the delta term (rowsum dO∘O) already carries the
-                # dropped forward.
-                kp = 1.0 - dropout_rate
-                keep = _tile_keep(dp.shape, seed_ref[0, 0], bh, q_start,
-                                  k_start, kp)
-                dp = jnp.where(keep, dp / kp, 0.0)
-            ds = p * (dp - dterm)
-            dq_scratch[rows, :] += jax.lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-
+        update = functools.partial(_update, r0, c0, mask, 0, q_start, k_start)
         if has_segments:
-            pl.when(jnp.any(mask))(_compute)
+            pl.when(jnp.any(mask))(update)
         else:
-            _compute()
+            update()
 
     _walk(_tile, qi, kj, tiles, causal, window,
           has_segments or bool(dropout_rate), unroll=True)
@@ -600,8 +712,79 @@ def _flash_bwd_dkv_kernel(
         dk_scratch[...] = jnp.zeros_like(dk_scratch)
         dv_scratch[...] = jnp.zeros_like(dv_scratch)
 
+    strips = _strips(tiles, causal, window,
+                     has_segments or bool(dropout_rate))
+
+    def _update(r0, c0, mask, stair=0, q_start=None, k_start=None):
+        """``dv += pᵀ @ dO`` and ``dk += dsᵀ @ Q`` for the [keys, queries]
+        sub-tile at ``(r0, c0)``, whole or (``stair``) in that many
+        pieces by KEY strips (:func:`_pieces`), stage by stage as in the
+        forward; ``mask`` is the whole tile's, or the square of a piece's
+        FIRST queries."""
+        pieces = _pieces(sub_k, stair, key_strips=True)
+        rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
+        q, k, v, do = _operands(
+            q_ref[0, rows, :], k_ref[0, cols, :], v_ref[0, cols, :],
+            do_ref[0, rows, :],
+        )
+        # [1, queries] (sublane-replicated), a piece's queries at a time.
+        spans = [_span(r0, qs, sub_q) for qs, _ in pieces]
+        lse = [lse_ref[0, :1, x] for x in spans]
+        dterm = [dterm_ref[0, :1, x] for x in spans]
+
+        s_t = [
+            jax.lax.dot_general(
+                _part(k, ks, 0), _part(q, qs, 0), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) for qs, ks in pieces
+        ]  # [keys, queries] each
+        if s_scale != 1.0:
+            s_t = [x * s_scale for x in s_t]
+        p_t = [jnp.exp(x - y) for x, y in zip(s_t, lse)]
+        if mask is not None:
+            p_t = [_cut(mask, x, 0.0, axis=1, last=False) for x in p_t]
+        if dropout_rate:
+            # One hash per tile, applied twice: dV sees the dropped,
+            # rescaled probabilities (the forward's value path); dK's
+            # ds keeps undropped w with the same mask landing on dp —
+            # the transposed twin of the dq kernel's math.
+            kp = 1.0 - dropout_rate
+            keep_t = _tile_keep((sub_k, sub_q), seed_ref[0, 0], bh_q,
+                                q_start, k_start, kp, transposed=True)
+            p_t_drop = [jnp.where(keep_t, x / kp, 0.0) for x in p_t]
+        else:
+            p_t_drop = p_t
+        dv_scratch[cols, :] += _join([
+            jax.lax.dot_general(
+                x.astype(do.dtype), _part(do, qs, 0),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) for x, (qs, _) in zip(p_t_drop, pieces)
+        ], 0)  # [sub_k, d]
+        dp_t = [
+            jax.lax.dot_general(
+                _part(v, ks, 0), _part(do, qs, 0), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) for qs, ks in pieces
+        ]  # [keys, queries] each
+        if dropout_rate:
+            dp_t = [jnp.where(keep_t, x / kp, 0.0) for x in dp_t]
+        ds_t = [x * (y - z) for x, y, z in zip(p_t, dp_t, dterm)]
+        dk_scratch[cols, :] += _join([
+            jax.lax.dot_general(
+                x.astype(q.dtype), _part(q, qs, 0), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) for x, (qs, _) in zip(ds_t, pieces)
+        ], 0)
+
     def _tile(r0, c0, masked):
         # [keys, queries] tiles: queries ride the lane axis.
+        if masked and strips:
+            # The diagonal sub-tile, as a staircase of KEY strips (row
+            # blocks of dk and dv).
+            w = sub_k // strips
+            _update(r0, c0, _pos_mask((w, w), 0, transposed=True), strips)
+            return
         rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
         q_start = qi * block_q + r0
         k_start = kj * block_k + c0
@@ -615,55 +798,11 @@ def _flash_bwd_dkv_kernel(
             # of the fwd/dq layouts.
             sm = _seg_mask(qseg_ref[0, :1, rows], kseg_ref[0, cols, :1])
             mask = sm if mask is None else jnp.logical_and(mask, sm)
-
-        def _compute():
-            q, k, v, do = _operands(
-                q_ref[0, rows, :], k_ref[0, cols, :], v_ref[0, cols, :],
-                do_ref[0, rows, :],
-            )
-            lse = lse_ref[0, :1, rows]  # [1, sub_q] (sublane-replicated)
-            dterm = dterm_ref[0, :1, rows]  # [1, sub_q]
-
-            s_t = jax.lax.dot_general(
-                k, q, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [sub_k, sub_q]
-            if s_scale != 1.0:
-                s_t = s_t * s_scale
-            p_t = jnp.exp(s_t - lse)
-            if mask is not None:
-                p_t = jnp.where(mask, p_t, 0.0)
-            if dropout_rate:
-                # One hash per tile, applied twice: dV sees the dropped,
-                # rescaled probabilities (the forward's value path); dK's
-                # ds keeps undropped w with the same mask landing on dp —
-                # the transposed twin of the dq kernel's math.
-                kp = 1.0 - dropout_rate
-                keep_t = _tile_keep(p_t.shape, seed_ref[0, 0], bh_q,
-                                    q_start, k_start, kp, transposed=True)
-                p_t_drop = jnp.where(keep_t, p_t / kp, 0.0)
-            else:
-                p_t_drop = p_t
-            dv_scratch[cols, :] += jax.lax.dot_general(
-                p_t_drop.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [sub_k, d]
-            dp_t = jax.lax.dot_general(
-                v, do, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [sub_k, sub_q]
-            if dropout_rate:
-                dp_t = jnp.where(keep_t, dp_t / kp, 0.0)
-            ds_t = p_t * (dp_t - dterm)
-            dk_scratch[cols, :] += jax.lax.dot_general(
-                ds_t.astype(q.dtype), q, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-
+        update = functools.partial(_update, r0, c0, mask, 0, q_start, k_start)
         if has_segments:
-            pl.when(jnp.any(mask))(_compute)
+            pl.when(jnp.any(mask))(update)
         else:
-            _compute()
+            update()
 
     # Query sub-tiles entirely in the past of a key sub-tile, or entirely
     # beyond the window's future edge, are not visited.
@@ -1174,6 +1313,16 @@ _TILES = {  # kernel -> (query sub-tiles, key sub-tiles) a block, at most
 # A forward step holds K and V^T of a head twice over (double-buffered):
 # 4 x 2 MiB of the 16 MiB a kernel may use.
 _KV_BLOCK_BYTES = 2 * 1024 * 1024
+# Strips a diagonal sub-tile is worked in (:func:`_strips`). Source:
+# scripts/flash_sweep.py --strips 1,2,4 on a TPU v5e (PERF.md §6, PR 42),
+# ms a call at 1 (the generic masked body) / 2 / 4 strips: gpt2m-train's
+# shape forward 0.496 / 0.455 / 0.430, dq 0.599 / 0.544 / 0.499, dkv 0.876 /
+# 0.771 / 0.733; trinity-mini-serve's full layer at 1,024 tokens 0.194 /
+# 0.167 / 0.158, at 8,192 5.48 / 5.30 / 5.25. Strip by strip (each strip's
+# products, exponentials and accumulator update before the next strip's)
+# the same four strips LOSE: forward 0.504 -> 0.621, the chip's compiler
+# schedules each strip's chain behind the one before.
+_STRIPS = 4
 # Up to here a length no _SUB divides is one whole tile in the forward
 # (gpt2m-serve's 640 and 768: 0.134 -> 0.090, 0.126 -> 0.094).
 _WHOLE_MAX = 1024
@@ -1205,6 +1354,52 @@ def _tile_rule(kernel: str, sq: int, sk: int, d: int, dtype):
     block_q = _auto_block(sq, _WHOLE_CAPS[0], _LANES)
     block_k = _auto_block(sk, _WHOLE_CAPS[1], _LANES)
     return block_q, block_k, block_q, block_k
+
+
+def _strips(tiles, causal: bool, window: int | None,
+            always_masked: bool) -> int:
+    """How many strips a cut sub-tile is worked in, or 0 where it keeps
+    the generic masked body. Under the causal mask alone (no window,
+    segments or dropout) and square sub-tiles, a cut sub-tile is the
+    diagonal one, its first query ON its first key (:func:`_walk`), known
+    while tracing: strip ``i`` of its queries multiplies, exponentiates
+    and sums the keys ``[0, (i + 1) * sub / n)`` only, and the position
+    mask cuts the strip's last square alone. ``(n + 1) / 2n`` of the
+    whole sub-tile's work, in ONE body that takes the strips through
+    each stage together (:func:`_pieces`); a strip is a whole number of
+    128-lane tiles or there is none."""
+    sub_q, sub_k = tiles[2:]
+    if (causal and window is None and not always_masked and sub_q == sub_k
+            and _STRIPS > 1 and sub_q % (_STRIPS * _LANES) == 0):
+        return _STRIPS
+    return 0
+
+
+def _visited_pairs(tiles, sq: int, sk: int, causal: bool,
+                   window: int | None, always_masked: bool = False):
+    """``(kept, worked)``: query-key pairs the position mask keeps, and
+    pairs the walk of ``tiles`` (one kernel's, :func:`_tile_rule`)
+    multiplies to get them: whole sub-tiles, but the staircase's strips
+    on a diagonal one. Their ratio is the most of its roofline a kernel
+    can reach while the benchmark counts the kept pairs as ideal."""
+    sub_q, sub_k = tiles[2:]
+    strips = _strips(tiles, causal, window, always_masked)
+    q = np.arange(sq)  # each query's first and last kept key
+    first = np.maximum(q - window + 1, 0) if window is not None else 0 * q
+    last = np.minimum(q, sk - 1) if causal else 0 * q + sk - 1
+    kept = int(np.maximum(last - first + 1, 0).sum())
+    worked = 0
+    for q in range(0, sq, sub_q):
+        for k in range(0, sk, sub_k):
+            if causal and k > q + sub_q - 1:
+                continue
+            if window is not None and q - (k + sub_k - 1) >= window:
+                continue
+            if strips and k + sub_k - 1 > q:
+                worked += (sub_q // strips) ** 2 * strips * (strips + 1) // 2
+            else:
+                worked += sub_q * sub_k
+    return kept, worked
 
 
 def _check_dropout(dropout_rate, dropout_seed):

@@ -10,22 +10,31 @@ so nothing but the kernel and its head folds runs; host clock around the
 loop, best of three), and ``lower_s``, what ``jit(call).lower()`` takes in
 Python (tracing the kernel and lowering it from Pallas: paid at EVERY
 start of a program that holds the kernel, before its compile-cache key
-exists). A tile the chip's compiler refuses is reported as ``error``.
+exists), and ``pairs_worked_pct``, the pairs the mask keeps over the pairs
+the kernel's walk multiplies (``_visited_pairs``: counted from the shapes,
+not measured; the most of its roofline the kernel can reach). A tile the
+chip's compiler refuses is reported as ``error``.
 
 The tile is the checkout's own (its ``_prepare``) unless ``--tiles`` gives
 candidates: ``block_q,block_k`` for any checkout, or
 ``block_q,block_k,sub_q,sub_k`` (all three kernels) for one whose kernels
 work a block in sub-tiles. ``--tree DIR`` times another checkout's
 kernels (one from before the sub-tiles too), for a before and after on one
-chip; both go through ``_fwd_pallas`` / ``_bwd_pallas`` on 4-D operands,
+chip; ``--strips 1,2,4`` times a cut sub-tile worked in that many strips
+(1: the generic masked body), by setting this script's copy of the
+module's ``_STRIPS``, which the package itself offers no option for;
+both go through ``_fwd_pallas`` / ``_bwd_pallas`` on 4-D operands,
 and dq and dkv are told apart by which gradient is kept (XLA drops the
 other kernel). The tile rule's table in ``ops/flash_attention.py`` is
 read off such sweeps.
 
-What this reads is a kernel ALONE: inside a training program the same
-kernels take 1.1 to 1.8 times as long and can rank differently (PERF.md
-§6, PR 28), so a candidate from here is confirmed by a traced run of the
-cell. On the CPU (``JAX_PLATFORMS=cpu``) the same loops run in interpret
+What this reads is a kernel ALONE, under the causal mask (and a window)
+and nothing else: a program that hands the kernels segment ids or
+dropout runs other bodies (until PR 42 ``TransformerLM`` handed them its
+causal mask as one segment id a token, and gpt2m-train's kernels read
+1.1 to 1.5 times these: PERF.md §6), so a candidate from here is
+confirmed by a traced run of the cell. On the CPU
+(``JAX_PLATFORMS=cpu``) the same loops run in interpret
 mode at ``--shapes tiny``: a rehearsal of the control flow, never a time.
 """
 
@@ -34,6 +43,7 @@ from __future__ import annotations
 import argparse
 import importlib
 import inspect
+import itertools
 import json
 import os
 import sys
@@ -58,6 +68,7 @@ SHAPES = {
     # against the contiguous cache.
     "decode": (8, 12, 12, 64, None, (1024,), ("fwd",), 1),
     "tiny": (1, 2, 1, 32, 48, (256,), ("fwd", "dq", "dkv")),
+    "tiny-causal": (1, 2, 1, 32, None, (256,), ("fwd", "dq", "dkv")),
 }
 
 
@@ -137,6 +148,9 @@ def main(argv=None) -> int:
     ap.add_argument("--tiles", default=None,
                     help="candidates, ';'-separated: bq,bk or bq,bk,sq,sk; "
                     "0 stands for the whole length")
+    ap.add_argument("--strips", default=None,
+                    help="strip counts of a cut sub-tile, comma-separated "
+                    "(default: the checkout's own)")
     ap.add_argument("--tree", default=None,
                     help="time this checkout's kernels (default: this one's)")
     ap.add_argument("--out", default="chiprun_out/flash_sweep.json")
@@ -157,6 +171,8 @@ def main(argv=None) -> int:
     candidates = [None] if args.tiles is None else [
         tuple(int(x) for x in t.split(",")) for t in args.tiles.split(";")]
     only = args.lengths and {int(x) for x in args.lengths.split(",")}
+    strip_counts = [getattr(fa, "_STRIPS", None)] if args.strips is None else [
+        int(x) for x in args.strips.split(",")]
     rows = []
     for name in args.shapes.split(","):
         b, h, h_kv, d, window, lengths, kinds, *q_len = SHAPES[name]
@@ -173,12 +189,20 @@ def main(argv=None) -> int:
                             blk % sub for blk, sub in zip(tile, tile[2:])):
                         continue
                 tile_args, shown = _tile_args(fa, data, tile, interpret)
-                for kind in kinds:
+                for kind, strips in itertools.product(kinds, strip_counts):
                     if kind not in args.kernels.split(","):
                         continue
                     row = dict(shape=name, dtype=args.dtype, seq=s,
                                kernel=kind, tile=list(shown[kind]),
                                tree=os.path.relpath(tree, here))
+                    if strips is not None:
+                        # Read while a kernel is traced: each row's
+                        # ``jit`` is new, so each traces its own.
+                        fa._STRIPS = row["strips"] = strips
+                        kept, worked = fa._visited_pairs(
+                            shown[kind], data[0].shape[1], s, not q_len,
+                            window)
+                        row["pairs_worked_pct"] = 100.0 * kept / worked
                     try:
                         sec, row["lower_s"] = _time(
                             jax, _call(fa, kind, tile_args, window,

@@ -1,7 +1,8 @@
 """The flash kernels' sub-tile walk and tile rule (interpret mode, CPU):
 operands in the caller's dtype, the loops between the window's far edge
-and the causal frontier, the mask on cut sub-tiles only, against a dense
-reference; the rule's tiles for every prefill bucket the benchmark's
+and the causal frontier, the mask on cut sub-tiles only, a diagonal
+sub-tile as a staircase of strips under the causal mask alone, against a
+dense reference; the rule's tiles for every prefill bucket the benchmark's
 serve cells compile; the kernel's traced size, which must not grow with
 the sequence (every start of a program pays it: PERF.md §6, PR 29)."""
 
@@ -65,8 +66,38 @@ def _segments(s):
 # ``tiles`` = (block_q, block_k, sub_q, sub_k) for all three kernels,
 # through the private entry; None goes through the public one and takes
 # the rule's. Lengths the frontier cuts unevenly; windows narrower and
-# wider than a sub-tile.
+# wider than a sub-tile. ``d`` is the head_dim (32 unless given);
+# ``stair`` says, by dtype, whether the forward's cut sub-tiles are worked
+# as a staircase (``_strips``) or keep the generic masked body: the
+# ``stair_*`` cases are the benchmark cells' shapes through the rule
+# (bfloat16: 512-sub-tiles; float32: the 512 x 1,024 pair, square where
+# 1,024 does not divide the length), the ``generic_*`` ones 512-sub-tiles
+# that a window, segments, dropout or an unequal pair keeps off it.
 _CASES = {
+    "stair_512": (512, 2, 2, dict(causal=True, stair=(True, True)), None),
+    "stair_1536_d64": (1536, 2, 2, dict(causal=True, d=64,
+                                        stair=(True, True)), None),
+    "stair_1024_16_over_2_d128": (1024, 16, 2, dict(
+        causal=True, d=128, stair=(False, True)), None),
+    "stair_512_32_over_4_d128": (512, 32, 4, dict(
+        causal=True, d=128, stair=(True, True)), None),
+    "whole_640_d64": (640, 2, 2, dict(causal=True, d=64,
+                                      stair=(False, False)), None),
+    "whole_768_d64": (768, 2, 2, dict(causal=True, d=64,
+                                      stair=(False, False)), None),
+    "generic_window_1024": (1024, 2, 1, dict(
+        causal=True, window=600, stair=(False, False)),
+        (512, 1024, 512, 512)),
+    "generic_segments_1024": (1024, 2, 1, dict(
+        causal=True, segments=True, stair=(False, False)),
+        (512, 1024, 512, 512)),
+    "generic_dropout_1024": (1024, 2, 2, dict(
+        causal=True, dropout=0.25, stair=(False, False)),
+        (512, 1024, 512, 512)),
+    "generic_unequal_1024": (1024, 2, 1, dict(
+        causal=True, stair=(False, False)), (512, 1024, 512, 256)),
+    "stair_blocks_1024": (1024, 2, 1, dict(
+        causal=True, stair=(True, True)), (1024, 1024, 512, 512)),
     "causal_768": (768, 2, 2, dict(causal=True), (768, 768, 384, 256)),
     "window_narrow_768": (768, 2, 1, dict(causal=True, window=96),
                           (768, 768, 384, 256)),
@@ -84,7 +115,7 @@ _CASES = {
                  (256, 768, 256, 256)),
     "full_768": (768, 2, 1, dict(causal=False), (768, 768, 384, 384)),
     "rule_2560_window": (2560, 1, 1, dict(causal=True, window=1000), None),
-    "rule_1024": (1024, 2, 1, dict(causal=True), None),
+    "rule_1024": (1024, 2, 1, dict(causal=True, stair=(False, True)), None),
     "rule_2048_window": (2048, 1, 1, dict(causal=True, window=700), None),
 }
 
@@ -99,7 +130,14 @@ def test_sub_tiled_kernels_match_dense(case, dtype):
     window = kwargs.pop("window", None)
     dropout = kwargs.pop("dropout", 0.0)
     seg = _segments(s) if kwargs.pop("segments", False) else None
-    d, seed = 32, 77
+    d, seed = kwargs.pop("d", 32), 77
+    stair = kwargs.pop("stair", None)
+    if stair is not None:
+        fwd = tiles or fa._tile_rule("fwd", s, s, d, dtype)
+        strips = fa._strips(fwd, causal, window, seg is not None
+                            or bool(dropout))
+        assert strips == (
+            fa._STRIPS if stair[dtype == jnp.bfloat16] else 0)
     keys = jax.random.split(jax.random.PRNGKey(s + h), 4)
     q = jax.random.normal(keys[0], (1, s, h, d), jnp.float32).astype(dtype)
     k = jax.random.normal(keys[1], (1, s, h_kv, d), jnp.float32).astype(dtype)
@@ -210,6 +248,78 @@ def test_tile_rule_is_legal_at_every_serve_bucket(d, s):
             assert tiles == (1024, 1024, 512, 512)
         else:
             assert tiles == (*_before(s), *_before(s))
+
+
+# (cell, head_dim, length, window, kernels) -> pairs worked at 1 (the
+# generic masked body), 2 and 4 strips a diagonal sub-tile: every
+# training and prefill shape of the benchmark's cells.
+_PAIRS = [
+    ("gpt2m-train", 64, 1024, None, ("fwd", "dq", "dkv"),
+     (786432, 655360, 589824)),
+    ("gpt2m-serve", 64, 128, None, ("fwd",), (16384, 16384, 16384)),
+    ("gpt2m-serve", 64, 256, None, ("fwd",), (65536, 49152, 65536)),
+    ("gpt2m-serve", 64, 384, None, ("fwd",), (147456, 147456, 147456)),
+    ("gpt2m-serve", 64, 512, None, ("fwd",), (262144, 196608, 163840)),
+    ("gpt2m-serve", 64, 640, None, ("fwd",), (409600, 409600, 409600)),
+    ("gpt2m-serve", 64, 768, None, ("fwd",), (589824, 442368, 589824)),
+    ("trinity-mini-serve", 128, 512, None, ("fwd",),
+     (262144, 196608, 163840)),
+    ("trinity-mini-serve", 128, 2048, None, ("fwd",),
+     (2621440, 2359296, 2228224)),
+    ("trinity-mini-serve", 128, 8192, None, ("fwd",),
+     (35651584, 34603008, 34078720)),
+    ("trinity-mini-serve", 128, 2560, 2048, ("fwd",), (3932160,) * 3),
+    ("trinity-mini-serve", 128, 8192, 2048, ("fwd",), (18350080,) * 3),
+    ("sarvam-105b-serve", 192, 1024, None, ("fwd",), (1048576,) * 3),
+    ("sarvam-105b-serve", 192, 16384, None, ("fwd",), (142606336,) * 3),
+    ("granite-4.0-h-small-serve", 128, 256, None, ("fwd",),
+     (65536, 49152, 65536)),
+    ("nemotron-3-nano-serve", 128, 1024, None, ("fwd",),
+     (786432, 655360, 589824)),
+    ("granite-4.0-h-small-serve", 128, 2048, None, ("fwd",),
+     (2621440, 2359296, 2228224)),
+]
+
+
+@pytest.mark.parametrize(
+    "cell, d, s, window, kernels, worked", _PAIRS,
+    ids=[f"{c}-{s}-{w}" for c, _, s, w, _, _ in _PAIRS])
+def test_visited_pairs_at_the_cells_shapes(monkeypatch, cell, d, s, window,
+                                           kernels, worked):
+    """The engagement counter: pairs the mask keeps over pairs the walk
+    multiplies, a pure function of the static shapes. A window, keys of
+    192 (the 512 x 1,024 pair) and a whole tile whose strips would be no
+    whole lane tiles keep the generic body's count at any strip count."""
+    q = np.arange(s)
+    kept = int((q + 1 - (0 if window is None
+                         else np.maximum(q - window + 1, 0))).sum())
+    for kernel in kernels:
+        tiles = fa._tile_rule(kernel, s, s, d, jnp.bfloat16)
+        for strips, pairs in zip((1, 2, 4), worked):
+            monkeypatch.setattr(fa, "_STRIPS", strips)
+            assert fa._visited_pairs(tiles, s, s, True, window) == (
+                kept, pairs)
+        # Segments or dropout mask every sub-tile whole.
+        assert fa._visited_pairs(tiles, s, s, True, window, True) == (
+            kept, worked[0])
+
+
+def test_the_staircase_works_the_share_of_the_pairs_the_table_says():
+    """gpt2m-train's shape: 66.7% of the pairs the walk multiplied were
+    kept before the staircase, 89.0% at the table's four strips."""
+    assert fa._STRIPS == 4
+    tiles = fa._tile_rule("dkv", 1024, 1024, 64, jnp.bfloat16)
+    kept, worked = fa._visited_pairs(tiles, 1024, 1024, True, None)
+    assert (kept, worked) == (524800, 589824)
+    assert round(100 * kept / worked, 1) == 89.0
+    # Not causal: every pair kept, every sub-tile whole.
+    assert fa._visited_pairs(tiles, 1024, 1024, False, None) == (
+        1024 * 1024,) * 2
+    # A band of 1 keeps a query's own key and all ahead: the sub-tile
+    # wholly behind its queries is never visited.
+    kept, worked = fa._visited_pairs(tiles, 1024, 1024, False, 1)
+    assert kept == sum(1024 - q for q in range(1024))
+    assert worked == 1024 * 1024 - 512 * 512
 
 
 def test_tile_rule_bounds_the_resident_keys():

@@ -739,6 +739,48 @@ def test_attention_switch_flash_matches_naive_oracle(world):
     )
 
 
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr and of the jaxprs in it."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (tuple, list))
+                        else (value,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    found += _pallas_calls(inner)
+    return found
+
+
+def test_flash_lm_hands_its_kernels_causality_and_no_segment_ids(world):
+    """The LM's own causal mask says nothing the flash path's ``causal``
+    does not: with ``attention="flash"`` no mask reaches the attention
+    function, so the kernels take q, k, v alone (the forward) and work
+    the diagonal as a staircase, where a mask would come back as one
+    segment id a token, compared on every tile (PR 42). A caller's own
+    ``attention_fn`` still gets the mask."""
+    from fluxmpi_tpu.models import TransformerLM
+    from fluxmpi_tpu.ops import flash_attention_fn
+
+    lm = TransformerLM(vocab_size=32, max_len=256, num_layers=1,
+                       d_model=32, num_heads=2, d_ff=64, attention="flash")
+    x = jnp.zeros((1, 256), jnp.int32)
+    variables = jax.eval_shape(
+        lambda: lm.init(jax.random.PRNGKey(0), x, train=False))
+
+    def kernel_operands(model):
+        jaxpr = jax.make_jaxpr(
+            lambda p: model.apply(p, x, train=True))(variables)
+        return [len(eqn.invars) for eqn in _pallas_calls(jaxpr.jaxpr)]
+
+    assert kernel_operands(lm) == [3]
+    masked = lm.clone(attention="naive",
+                      attention_fn=flash_attention_fn(causal=True))
+    assert kernel_operands(masked) == [5]  # ... and the two segment rows
+
+
 def test_attention_switch_validation(world):
     """Switch error paths: an unknown mode raises at apply time,
     attention='flash' conflicts with an explicit attention_fn, and

@@ -156,6 +156,44 @@ def test_flash_prefill_tiles_compile_for_v5e(topo, s, window):
     assert text.count("tpu_custom_call") == 1
 
 
+# The staircase a diagonal sub-tile is worked as under the causal mask
+# alone (``_strips``: four strips of 128 at the rule's 512-sub-tiles), in
+# all three kernels: gpt2m-train's shape; head_dim 128 with grouped heads
+# (trinity-mini-serve's full layer, two query blocks a grid row; nemotron's
+# 16 query heads a K/V head); and gpt2m-serve's whole tiles of 640 and 768,
+# whose strips would be no whole lane tiles and which keep the generic
+# masked body. name -> (q shape, kv heads, the forward's strips).
+_STAIRS = {
+    "gpt2m_train": ((8, 1024, 16, 64), 16, 4),
+    "trinity_full_2048": ((1, 2048, 32, 128), 4, 4),
+    "nemotron_1024": ((1, 1024, 32, 128), 2, 4),
+    "whole_640": ((1, 640, 16, 64), 16, 0),
+    "whole_768": ((1, 768, 16, 64), 16, 0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_STAIRS))
+def test_flash_staircase_compiles_for_v5e(topo, name):
+    import importlib
+
+    fa = importlib.import_module("fluxmpi_tpu.ops.flash_attention")
+    (b, s, h, d), h_kv, strips = _STAIRS[name]
+    dev = topo.devices[0]
+    tiles = fa._tile_rule("fwd", s, s, d, jnp.bfloat16)
+    assert fa._strips(tiles, True, None, False) == strips
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, interpret=False, causal=True).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        _sds((b, s, h, d), jnp.bfloat16, dev),
+        _sds((b, s, h_kv, d), jnp.bfloat16, dev),
+        _sds((b, s, h_kv, d), jnp.bfloat16, dev),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+
+
 # name -> (slots, heads, head_dim, layers, pool blocks, block_size,
 # table width, dtype): the benchmark's serve cell (GPT-2 medium, 32 slots
 # of 8 blocks of 128), the smoke's engine (GPT-2 small at the engine's
