@@ -15,7 +15,8 @@ not that block. :class:`DecoderLM` reads its block from a
   ``head_dim`` (independent of ``hidden_size``), RMSNorm over ``head_dim``
   on ``q`` and ``k``, a sigmoid output gate (``output_gate``), as
   ``"sliding_attention"`` (rotary positions, the mask ``0 <= i - j <
-  sliding_window``) or ``"full_attention"`` (the causal mask, no rotary).
+  sliding_window``) or ``"full_attention"`` (the causal mask; rotary
+  positions only where ``full_attention_rope``).
   The third, ``"latent_attention"`` (multi-head latent attention, MLA),
   keeps ONE row a token: a latent ``c`` of ``kv_lora_rank`` (RMSNormed)
   and one rotary key ``k_r`` of ``qk_rope_head_dim`` shared by all heads;
@@ -29,7 +30,12 @@ not that block. :class:`DecoderLM` reads its block from a
   causal convolution; over a call's own tokens the recurrence runs as a
   chunked scan, against a cache one step a token. A full layer may run
   without the head norms (``qk_norm``) and at a configured softmax scale
-  (``attention_multiplier``);
+  (``attention_multiplier``). A fifth kind of layer, ``"mamba_attention"``,
+  runs TWO of them side by side on the one normed input, a Mamba-2 mixer
+  and full attention: ``h = x + ssm_out_multiplier * Mamba(ssm_in_multiplier
+  * N(x)) + attention_out_multiplier * Attn(attention_in_multiplier *
+  N(x))``, one norm and one residual join, so the layer keeps a state AND
+  keys and values;
 - a gated (SwiGLU) MLP in the first ``num_dense_layers`` layers and an
   :class:`ExpertMLP` in the rest (scores ``sigmoid(u Wr)`` normalised
   over the chosen, or ``score_func="softmax"``: the largest logits,
@@ -47,7 +53,12 @@ not that block. :class:`DecoderLM` reads its block from a
   ``sqrt(hidden_size)`` where ``mup_enabled`` and by
   ``embedding_multiplier``, each sublayer's result by
   ``residual_multiplier`` as it joins the stream, the logits divided by
-  ``logits_scaling``.
+  ``logits_scaling``; and the other muP multipliers a configuration may
+  carry, each applied in the forward where its model applies it and not
+  at all at 1.0: ``key_multiplier``, the five ``ssm_multipliers`` over the
+  segments ``[z; x; B; C; dt]`` of a Mamba mixer's input projection, the
+  two ``mlp_multipliers`` (a gated MLP's gate, inside the activation, and
+  its result), ``lm_head_multiplier``.
 
 ``num_experts`` counts the experts a layer HOLDS; where the router is
 wider (``num_routed_experts``: this chip's share of an expert-parallel
@@ -69,7 +80,9 @@ each token) and, where the function attends a cache
 row)`` with the absorbed queries instead; a Mamba layer hands a prefill's
 function ``keep_state(tail, state)`` and asks a cache's for
 ``conv_tail()`` and ``state_update(tail, x, step, decay, B, C)``;
-:meth:`DecoderLM.cache_layers` says what each layer keeps in a cache.
+:meth:`DecoderLM.cache_layers` says what each keeping sublayer keeps in
+a cache, in the order their calls come (a ``"mamba_attention"`` layer's
+Mamba mixer first, then its attention).
 ``pos_offset`` (``[batch]``) places each row at its own
 position and ``head_at`` (``[batch]``) takes the head at one position a
 row: a prefill never builds ``[prompt, vocab]`` logits. ``token_mask``
@@ -96,6 +109,9 @@ __all__ = ["DecoderConfig", "DecoderLM", "ExpertMLP", "LatentAttention",
 SLIDING, FULL = "sliding_attention", "full_attention"
 LATENT = "latent_attention"
 MAMBA = "mamba"
+# Two mixers side by side on one normed input: a Mamba-2 mixer and full
+# attention, both results joined to the stream at once.
+PARALLEL = "mamba_attention"
 # Not a mixer: a layer of a ``block="single"`` model that is its routed
 # experts alone.
 EXPERTS = "experts"
@@ -169,15 +185,36 @@ class DecoderConfig:
     mamba_n_groups: int = 1
     mamba_d_conv: int = 4
     mamba_chunk_size: int = 256
+    # Rotary positions on the full layers too (window layers always).
+    full_attention_rope: bool = False
+    # muP multipliers of a ``"mamba_attention"`` layer's two branches (on
+    # the normed input each reads and on its result as both join the
+    # stream), on the keys, on the five segments ``[z; x; B; C; dt]`` of a
+    # Mamba mixer's input projection, on a gated MLP's gate (inside the
+    # activation) and result, and on the logits. 1.0: not applied.
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple[float, ...] = (1.0,) * 5
+    mlp_multipliers: tuple[float, float] = (1.0, 1.0)
+    lm_head_multiplier: float = 1.0
 
     def __post_init__(self):
         if self.block not in ("pair", "single"):
             raise ValueError(f"unknown block {self.block!r}")
+        if len(self.ssm_multipliers) != 5 or len(self.mlp_multipliers) != 2:
+            raise ValueError(
+                "ssm_multipliers are five ([z; x; B; C; dt]) and "
+                "mlp_multipliers two (gate, down); got "
+                f"{self.ssm_multipliers} and {self.mlp_multipliers}"
+            )
         if self.mlp_activation not in ("swiglu", "relu2"):
             raise ValueError(
                 f"unknown mlp_activation {self.mlp_activation!r}")
         unknown = set(self.layer_types) - {SLIDING, FULL, LATENT, MAMBA} - (
-            {EXPERTS} if self.block == "single" else set())
+            {EXPERTS} if self.block == "single" else {PARALLEL})
         if unknown:
             raise ValueError(f"unknown layer types {sorted(unknown)}")
         if SLIDING in self.layer_types and not self.sliding_window:
@@ -213,7 +250,7 @@ class DecoderConfig:
             )
         if self.score_func not in ("sigmoid", "softmax"):
             raise ValueError(f"unknown score_func {self.score_func!r}")
-        if MAMBA in self.layer_types:
+        if {MAMBA, PARALLEL} & set(self.layer_types):
             if not (self.mamba_n_heads and self.mamba_d_head
                     and self.mamba_d_state):
                 raise ValueError(
@@ -285,8 +322,14 @@ class DecoderConfig:
         ``num_local_experts`` experts of ``intermediate_size`` chosen by
         the largest router logits and weighed by a softmax over the
         chosen; a shared MLP of ``shared_intermediate_size``; pre-norm;
-        the three multipliers; a tied head); keys this class does not
-        know are left alone."""
+        the three multipliers; a tied head) or ``"falcon_h1"`` (every
+        layer a ``"mamba_attention"`` pair of mixers on one normed input,
+        rotary full attention without head norms or gate, a dense SwiGLU
+        MLP; ``mamba_d_ssm`` the heads side by side; the muP multipliers
+        under their published names; pre-norm; it raises for what this
+        class cannot build: attention in some layers only, a Mamba mixer
+        without its MLP, a norm before the gate, a projection bias, a
+        rotary scaling); keys this class does not know are left alone."""
         names = {f.name for f in dataclasses.fields(cls)}
         known = {k: v for k, v in cfg.items() if k in names and v is not None}
         if cfg.get("model_type") == "sarvam_mla":
@@ -341,11 +384,43 @@ class DecoderConfig:
                 mamba_chunk_size=cfg["chunk_size"],
                 norm_placement="pre", output_gate=False, qk_norm=False,
             )
+        elif cfg.get("model_type") == "falcon_h1":
+            known.update(_falcon_h1_fields(cfg))
         else:
             known["layer_types"] = tuple(cfg["layer_types"])
         if known.get("rope_scaling") is not None:
             known["rope_scaling"] = tuple(sorted(cfg["rope_scaling"].items()))
         return cls(**known)
+
+
+def _falcon_h1_fields(cfg: dict) -> dict:
+    """What ``model_type: "falcon_h1"`` fixes beyond the keys it shares
+    with :class:`DecoderConfig` by name (the Mamba sizes, the
+    multipliers); raises for a value this class cannot build."""
+    as_built = {
+        "attn_layer_indices": None, "mamba_use_mlp": True,
+        "mamba_norm_before_gate": False, "mamba_rms_norm": True,
+        "mamba_conv_bias": True, "mamba_proj_bias": False,
+        "attention_bias": False, "mlp_bias": False, "projectors_bias": False,
+        "hidden_act": "silu", "rope_scaling": None,
+    }
+    odd = {k: cfg[k] for k, v in as_built.items() if k in cfg and cfg[k] != v}
+    inner = cfg["mamba_n_heads"] * cfg["mamba_d_head"]
+    if cfg.get("mamba_d_ssm", inner) != inner:
+        odd["mamba_d_ssm"] = cfg["mamba_d_ssm"]
+    if odd:
+        raise ValueError(
+            f"falcon_h1 as served: {as_built}, mamba_d_ssm = mamba_n_heads "
+            f"* mamba_d_head; got {odd}"
+        )
+    return dict(
+        layer_types=(PARALLEL,) * cfg["num_hidden_layers"],
+        num_dense_layers=cfg["num_hidden_layers"],  # no expert anywhere
+        norm_placement="pre", output_gate=False, qk_norm=False,
+        full_attention_rope=True,
+        ssm_multipliers=tuple(cfg["ssm_multipliers"]),
+        mlp_multipliers=tuple(cfg["mlp_multipliers"]),
+    )
 
 
 def _dot(x, w, dtype):
@@ -375,7 +450,7 @@ def _rotary(x, positions, theta: float):
     ``positions`` ``[batch, seq]``: the two halves of a head rotated by
     ``position * theta ** (-2i / head_dim)``, float32."""
     half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    freq = float(theta) ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angle = positions.astype(jnp.float32)[..., None, None] * freq
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     x32 = x.astype(jnp.float32)
@@ -506,6 +581,8 @@ class Attention(nn.Module):
         wo = self.param("wo", init, (heads * hd, d))
         q = _dot(u, wq, self.dtype).reshape(b, s, heads, hd)
         k = _dot(u, wk, self.dtype).reshape(b, s, kvh, hd)
+        if c.key_multiplier != 1.0:
+            k = k * c.key_multiplier
         v = _dot(u, wv, self.dtype).reshape(b, s, kvh, hd).astype(self.dtype)
         if c.qk_norm:
             q_scale = self.param("q_norm", nn.initializers.ones, (hd,))
@@ -517,9 +594,8 @@ class Attention(nn.Module):
             # a configured scale rides on the queries, applied before
             # their one rounding to ``dtype``.
             q = q * (c.attention_multiplier * hd ** 0.5)
-        window = None
-        if self.layer_type == SLIDING:
-            window = c.sliding_window
+        window = c.sliding_window if self.layer_type == SLIDING else None
+        if window is not None or c.full_attention_rope:
             with jax.named_scope("rope"):
                 q = _rotary(q, positions, c.rope_theta)
                 k = _rotary(k, positions, c.rope_theta)
@@ -702,6 +778,11 @@ class MambaMixer(nn.Module):
         # one row a tile.
         with jax.named_scope("ssm_in_proj"):
             proj = _dot(u.reshape(b * s, d), w_in, self.dtype)
+            if any(m != 1.0 for m in c.ssm_multipliers):
+                # One multiplier a segment of ``[z; x; B; C; dt]``.
+                proj = proj * np.repeat(
+                    np.asarray(c.ssm_multipliers, np.float32),
+                    [inner, inner, groups * n, groups * n, heads])
             z = proj[:, :inner]
             xbc = proj[:, inner:inner + conv_dim].astype(self.dtype)
             step = jax.nn.softplus(
@@ -790,10 +871,14 @@ def _slabs(tokens: int, width: int) -> int:
 
 
 class GatedMLP(nn.Module):
-    """``(silu(u W1) * (u W3)) W2``."""
+    """``(silu(g u W1) * (u W3)) W2 * r``: ``g`` (``gate_multiplier``)
+    inside the activation and ``r`` (``down_multiplier``) on the result,
+    both 1.0 and not applied unless the configuration has them."""
 
     width: int
     dtype: Any
+    gate_multiplier: float = 1.0
+    down_multiplier: float = 1.0
 
     @nn.compact
     def __call__(self, u):
@@ -804,8 +889,14 @@ class GatedMLP(nn.Module):
         w2 = self.param("w2", init, (self.width, d))
 
         def mlp(u):
-            h = jax.nn.silu(_dot(u, w1, self.dtype)) * _dot(u, w3, self.dtype)
-            return _dot(h, w2, self.dtype).astype(self.dtype)
+            gate = _dot(u, w1, self.dtype)
+            if self.gate_multiplier != 1.0:
+                gate = gate * self.gate_multiplier
+            out = _dot(jax.nn.silu(gate) * _dot(u, w3, self.dtype), w2,
+                       self.dtype)
+            if self.down_multiplier != 1.0:
+                out = out * self.down_multiplier
+            return out.astype(self.dtype)
 
         tokens = math.prod(u.shape[:-1])
         slabs = _slabs(tokens, self.width)
@@ -1049,7 +1140,29 @@ class DecoderLayer(nn.Module):
         sandwich = c.norm_placement == "sandwich"
         kind = c.layer_types[self.index]
 
+        def scaled(v, by):
+            """``by * v`` in float32, rounded once (``v`` itself at 1.0)."""
+            return v if by == 1.0 else (
+                v.astype(jnp.float32) * by).astype(self.dtype)
+
         def mixer(u):
+            if kind == PARALLEL:
+                # Both mixers read the one normed input, the Mamba mixer
+                # first: the order their calls of ``attention_fn`` come in
+                # (:meth:`DecoderLM.cache_layers`).
+                with jax.named_scope("ssm_branch"):
+                    m = MambaMixer(
+                        c, self.dtype, self.attention_fn, name="mamba"
+                    )(scaled(u, c.ssm_in_multiplier), token_mask)
+                with jax.named_scope("attn_branch"):
+                    a = Attention(
+                        c, FULL, self.dtype, self.attention,
+                        self.attention_fn, name="attn",
+                    )(scaled(u, c.attention_in_multiplier), positions)
+                with jax.named_scope("mixer_join"):
+                    return (c.ssm_out_multiplier * m.astype(jnp.float32)
+                            + c.attention_out_multiplier
+                            * a.astype(jnp.float32)).astype(self.dtype)
             if kind == MAMBA:
                 return MambaMixer(
                     c, self.dtype, self.attention_fn, name="mamba"
@@ -1066,9 +1179,13 @@ class DecoderLayer(nn.Module):
 
         def feed_forward(u):
             if self.index not in c.expert_layer_ids:
-                dense = (GatedMLP if c.mlp_activation == "swiglu"
-                         else ReluSquaredMLP)
-                return dense(c.intermediate_size, self.dtype, name="mlp")(u)
+                with jax.named_scope("mlp"):
+                    if c.mlp_activation == "swiglu":
+                        return GatedMLP(
+                            c.intermediate_size, self.dtype,
+                            *c.mlp_multipliers, name="mlp")(u)
+                    return ReluSquaredMLP(
+                        c.intermediate_size, self.dtype, name="mlp")(u)
             routed, held = _held_experts(c, self.expert_range)
             return ExpertMLP(
                 num_experts=routed, top_k=c.num_experts_per_tok,
@@ -1118,26 +1235,29 @@ class DecoderLM(nn.Module):
         return self.config.num_layers
 
     def cache_layers(self) -> tuple[tuple, ...]:
-        """What each layer keeps of a sequence: ``(kv_heads, head_dim,
-        window)``, ``window`` None where a layer attends its whole
+        """What each KEEPING SUBLAYER keeps of a sequence, in the order
+        their calls of ``attention_fn`` come: ``(kv_heads, head_dim,
+        window)``, ``window`` None where it attends its whole
         context; a latent layer ``(None, row, None)``: no K/V heads, ONE
         row of ``kv_lora_rank + qk_rope_head_dim`` a token and no V; a
-        Mamba layer ``("state", (heads, head_dim, d_state), (d_conv - 1,
+        Mamba mixer ``("state", (heads, head_dim, d_state), (d_conv - 1,
         conv_dim))``: nothing a token, ONE state and one tail of
         pre-convolution columns a SEQUENCE, whatever its length; a layer
         that is its experts alone None: it keeps NOTHING of a sequence
-        (and never calls ``attention_fn``)."""
+        (and never calls ``attention_fn``). A layer with one mixer is one
+        entry; a ``"mamba_attention"`` layer is TWO, its state and then
+        its K/V heads, so the tuple is as long as the model's keeping
+        sublayers (and its Nones), not as its layers."""
         c = self.config
         state = ("state", (c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state),
                  (c.mamba_d_conv - 1, c.mamba_conv_dim))
-        return tuple(
-            None if kind == EXPERTS else
-            state if kind == MAMBA else
-            (None, c.latent_row, None) if kind == LATENT else
-            (c.num_key_value_heads, c.head_dim,
-             c.sliding_window if kind == SLIDING else None)
-            for kind in c.layer_types
-        )
+        full = (c.num_key_value_heads, c.head_dim, None)
+        kept = {
+            EXPERTS: (None,), MAMBA: (state,), PARALLEL: (state, full),
+            LATENT: ((None, c.latent_row, None),), FULL: (full,),
+            SLIDING: ((*full[:2], c.sliding_window),),
+        }
+        return tuple(sub for kind in c.layer_types for sub in kept[kind])
 
     def expert_row_tile(self, tokens: int) -> int | None:
         """The rows of one row tile of the expert layers' grouped matmul
@@ -1200,12 +1320,16 @@ class DecoderLM(nn.Module):
                 x, jnp.asarray(head_at)[:, None, None], axis=1
             )[:, 0]
         x = RMSNorm(c.rms_norm_eps, self.dtype, name="norm_out")(x)
-        if c.tie_word_embeddings:
-            logits = jnp.einsum(
-                "...d,vd->...v", x.astype(self.dtype),
-                embed.astype(self.dtype), preferred_element_type=jnp.float32,
-            )
-        else:
-            head = self.param("head", init, (c.hidden_size, c.vocab_size))
-            logits = _dot(x, head, self.dtype)
+        with jax.named_scope("lm_head"):
+            if c.tie_word_embeddings:
+                logits = jnp.einsum(
+                    "...d,vd->...v", x.astype(self.dtype),
+                    embed.astype(self.dtype),
+                    preferred_element_type=jnp.float32,
+                )
+            else:
+                head = self.param("head", init, (c.hidden_size, c.vocab_size))
+                logits = _dot(x, head, self.dtype)
+            if c.lm_head_multiplier != 1.0:
+                logits = logits * c.lm_head_multiplier
         return logits if c.logits_scaling == 1.0 else logits / c.logits_scaling

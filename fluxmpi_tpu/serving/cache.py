@@ -30,7 +30,13 @@ positions are cut into fixed-size **blocks**,
   :mod:`fluxmpi_tpu.serving.engine`).
 
 **Four kinds of layer** (:attr:`BlockKVCache.kinds`: full, window,
-latent, state), each with its pool(s), its free list and its tables.
+latent, state), each with its pool(s), its free list and its tables. A
+"layer" here is one KEEPING SUBLAYER of the model, in the order their
+calls come, not one of its layers: a layer that keeps nothing is none, and
+a layer that runs a state-space mixer and attention side by side is two,
+one of the state kind and one of the full kind, so a sequence then holds
+an entry of the state pool AND blocks of the K/V pool, and
+:meth:`BlockKVCache.can_alloc` says no as soon as either runs out.
 
 **Layers that attend a window keep a ring.** A model whose layers are
 not all alike (``layer_windows``: some attend their whole context, some
@@ -153,7 +159,8 @@ class BlockKVCache:
 
     Args:
       num_layers, num_heads, head_dim: the model's cache geometry
-        (``num_heads`` K/V heads of ``head_dim`` a layer).
+        (``num_layers`` keeping sublayers, see above; ``num_heads`` K/V
+        heads of ``head_dim`` each of those that keep rows).
       num_blocks: total pool blocks INCLUDING the reserved trash block
         (capacity = ``(num_blocks - 1) * block_size`` tokens).
       block_size: cache positions per block.

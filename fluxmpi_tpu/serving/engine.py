@@ -63,9 +63,17 @@ request whatever its length.
 
 A layer may keep **nothing** (``cache_layers()`` says None: a layer that
 is its routed experts alone, in a model whose layers are one sublayer
-each). It has no pool, no table and no call of ``attention_fn``; the
-cache's layers are the keeping ones in their order, and every count of
-blocks, states or context speaks of those. The ``expert_*`` counters and
+each). It has no pool, no table and no call of ``attention_fn``. A layer
+may keep **both** a state and K/V rows (``cache_layers()`` says the two,
+state first: a Mamba-2 mixer and attention side by side on one normed
+input): it makes two keeping calls of ``attention_fn`` (``conv_tail`` /
+``state_update`` or ``keep_state``, then ``__call__``), owns an entry of
+the state pool AND blocks of the K/V pool, and a request is admitted only
+while both kinds have room. So the cache's layers are the model's KEEPING
+SUBLAYERS in the order their calls come, not its layers: a count of blocks
+speaks of the K/V (or latent) sublayers, a count of states of the state
+sublayers (``stats()["kv_sublayers"]``, ``["state_sublayers"]``), and
+context is counted once a request whatever their number. The ``expert_*`` counters and
 span arguments speak of the layers that HAVE experts (the ones that sow
 ``expert_tokens``), wherever they stand in the model.
 
@@ -473,8 +481,8 @@ class _Tick:
 
 
 def _cache_layers(model) -> tuple[tuple, ...]:
-    """Per layer ``(kv_heads, head_dim, window)``: what the model says it
-    keeps of a sequence (``cache_layers()``, the protocol of
+    """Per KEEPING SUBLAYER ``(kv_heads, head_dim, window)``: what the
+    model says it keeps of a sequence (``cache_layers()``, the protocol of
     :class:`~fluxmpi_tpu.models.DecoderLM`; ``kv_heads`` None: a latent
     layer, ONE row of ``head_dim`` a token and no V; ``("state", state
     shape, tail shape)``: a layer that keeps one recurrent state and one
@@ -482,8 +490,9 @@ def _cache_layers(model) -> tuple[tuple, ...]:
     :class:`~fluxmpi_tpu.models.TransformerLM`'s ``num_heads`` heads of
     ``d_model // num_heads`` over the whole context. A layer the model
     says None of keeps NOTHING (a feed-forward alone): it never calls
-    ``attention_fn``, so it is left out here and the cache's layers are
-    the keeping ones, numbered in the order their calls come."""
+    ``attention_fn``, so it is left out here; a layer of two mixers is
+    two entries. The cache's layers are these keeping sublayers,
+    numbered in the order their calls come."""
     layers = getattr(model, "cache_layers", None)
     if layers is not None:
         return tuple(layer for layer in layers() if layer is not None)
@@ -511,7 +520,9 @@ class _PagedDecodeAttention:
     decode step. flax's attention sublayer hands it the new token's
     ``query`` / ``key`` / ``value`` (``[slots, 1, heads, head_dim]``, from
     the model's own ``attn/{query,key,value}`` projections); each call —
-    one per layer, in layer order — writes the key and value rows into
+    one per keeping sublayer, in call order (:attr:`layer` steps once a
+    call that keeps something, so a layer of two mixers steps it twice) —
+    writes the key and value rows into
     the pools at ``(table[pos // block_size], pos % block_size)`` and then
     attends through the block tables. Idle slots carry all-trash tables:
     their rows land in the trash block and their length is 0. Layers of a
@@ -663,7 +674,8 @@ class _PrefillAttention:
     """The prefill program's ``attention_fn`` for a model that speaks the
     ``cache_layers()`` protocol: causal attention over the padded prompt
     (within the layer's window; the flash kernels with ``kernel``), and
-    each layer's keys and values kept for the pool's ``kv_write``; of a
+    each keeping sublayer's keys and values, in call order, kept for the
+    pool's ``kv_write``; of a
     latent layer, which rebuilt ``key`` and ``value`` from its ``row``,
     the row alone; of a state layer (:meth:`keep_state`) the convolution
     tail and the state after the prompt's last real token."""
@@ -671,7 +683,8 @@ class _PrefillAttention:
     def __init__(self, windows, kernel: bool):
         self.windows = windows
         self.kernel = kernel
-        # One entry a layer, in layer order; a state layer's are None.
+        # One entry a keeping sublayer, in call order (what ``windows`` is
+        # indexed by); a state sublayer's are None.
         self.keys: list = []
         self.values: list = []
         # Of the state layers, in their order.
@@ -919,6 +932,12 @@ class InferenceEngine:
         # summed over decode ticks.
         self._states_live = 0
         self._states_held = 0
+        # Of the cache's layers (the model's keeping sublayers): how many
+        # keep rows a token and how many a state a sequence. A block
+        # count speaks of the first, a state count of the second.
+        self._kv_sublayers = sum(
+            kind.layers for kind in self.cache.kinds if kind.state is None)
+        self._state_sublayers = self.cache.num_layers - self._kv_sublayers
         # Routed experts (a model with expert layers): (token, expert)
         # pairs, (layer, expert) cells with at least one, and cells in
         # all, summed over decode ticks.
@@ -1494,7 +1513,9 @@ class InferenceEngine:
             self._kv_blocks_live += live
             self._kv_blocks_tabled += tabled
             self._context_tokens += context
-            said = {"context_tokens": context}
+            said = {"context_tokens": context,
+                    "kv_sublayers": self._kv_sublayers,
+                    "state_sublayers": self._state_sublayers}
             if tabled:
                 said["live_blocks_pct"] = 100.0 * live / tabled
             if self.cache.state_kind is not None:
@@ -1722,10 +1743,16 @@ class InferenceEngine:
         decode steps) and ``kv_blocks_tabled`` (``slots *
         max_blocks_per_seq`` a step: what the slots' tables span; the
         ratio is the share of the reserved cache a tick touches; both
-        count a block once a layer that has it, and a window layer only
-        the blocks that meet its window); ``context_tokens`` (the
-        positions the decode attention read: every active slot's length,
-        summed over decode steps). A model with window layers
+        count a block once a K/V (or latent) SUBLAYER that has it, and a
+        window layer only the blocks that meet its window);
+        ``context_tokens`` (the positions the decode attention read:
+        every active slot's length, summed over decode steps, once a
+        request however many sublayers read it). ``kv_sublayers`` and
+        ``state_sublayers``: of the model's keeping sublayers (the
+        cache's layers: a layer of two mixers is two), those that keep
+        rows a token, which the block counts speak of, and those that
+        keep a state a sequence, which the state counts speak of (plain
+        numbers, not summed). A model with window layers
         also counts the layer-blocks its slots HOLD, summed over decode
         steps: ``kv_blocks_full`` and ``kv_blocks_window`` by kind of
         layer, beside ``kv_blocks_uniform``, what one pool of one shape
@@ -1788,6 +1815,8 @@ class InferenceEngine:
             "expert_row_tiles": self._expert_row_tiles,
             "decode_steps_overlapped": self._steps_overlapped,
             "tokens_discarded": self._tokens_discarded,
+            "kv_sublayers": self._kv_sublayers,
+            "state_sublayers": self._state_sublayers,
             "state_entries": self._states_held,
             "state_entries_used": self._states_live,
             "state_bytes": 2 * self._states_live

@@ -230,7 +230,7 @@ _ENGINE_COUNTERS = (
     "experts_touched", "expert_slots", "expert_weight_visits",
     "expert_row_tiles_worked", "expert_row_tiles", "decode_steps_overlapped", "tokens_discarded", "state_entries",
     "state_entries_used", "state_bytes", "gaps", "gaps_stalled",
-    "gap_seconds", "gap_stalled_seconds",
+    "gap_seconds", "gap_stalled_seconds", "kv_sublayers", "state_sublayers",
 )
 
 
@@ -320,11 +320,18 @@ _TRACED = {
                             "expert_row_tiles_worked_pct",
                             "decode_context_tokens", "decode_ticks_in_flight",
                             "ssm_states_read_pct"),
+    # Two mixers a layer: every layer's state AND its K/V blocks, which
+    # ``ssm_update_roofline`` and ``paged_decode_roofline`` read; no
+    # expert layer, so none of their arguments.
+    "tiny-falcon-h1-serve": ("decode_host_ms", "decode_active_slots",
+                             "kv_blocks_read_pct", "decode_context_tokens",
+                             "decode_ticks_in_flight", "ssm_states_read_pct"),
 }
 
 
 @pytest.mark.parametrize("name", ["tiny-sarvam-serve", "tiny-granite-serve",
-                                  "tiny-nemotron-serve"])
+                                  "tiny-nemotron-serve",
+                                  "tiny-falcon-h1-serve"])
 def test_untraced_rehearsal_reports_its_end_to_end_metrics(name, tmp_path):
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env.update(JAX_PLATFORMS="cpu", HOME=str(tmp_path), TMPDIR=str(tmp_path))
@@ -383,12 +390,30 @@ def test_traced_rehearsal_run_exits_0_and_reads_its_spans(name, tmp_path):
                 "moe_device_pct"} <= reported
         assert "moe_weight_stream_roofline" not in reported
         assert "cpu_rehearsal.relu2_expert_stream_roofline" not in metrics
+    if name == "tiny-falcon-h1-serve":
+        # The cell it stands for reports three rooflines off the device's
+        # trace: their readers ran and, on a CPU's trace, found no kernel
+        # and no decode program to read; their inputs from the spans (the
+        # live states and the live blocks of the keeping sublayers) are
+        # there.
+        reported = {m["name"] for m in manifest.Cell(name).per_layer()}
+        rooflines = {"dense_weight_stream_roofline", "ssm_update_roofline",
+                     "paged_decode_roofline"}
+        assert rooflines | {"ssm_update_device_pct"} <= reported
+        assert not any(m.startswith(("moe_", "expert", "relu2_", "latent_"))
+                       for m in reported)
+        for metric in rooflines:
+            assert f"cpu_rehearsal.{metric}" not in metrics
+        assert 0.0 < metrics["cpu_rehearsal.ssm_states_read_pct"][
+            "value"] <= 100.0
+        assert 0.0 < metrics["cpu_rehearsal.kv_blocks_read_pct"][
+            "value"] <= 100.0
     visits = metrics.get("cpu_rehearsal.expert_weight_visits_per_touched")
     assert visits is None or visits["value"] >= 1.0
     # Every expert held: every row tile worked; a model without expert
     # layers has no such span argument to read.
     worked = metrics.get("cpu_rehearsal.expert_row_tiles_worked_pct")
-    if name == "tiny-lm-serve":
+    if name in ("tiny-lm-serve", "tiny-falcon-h1-serve"):
         assert worked is None
     elif name == "tiny-trinity-serve":
         assert worked["value"] == 100.0

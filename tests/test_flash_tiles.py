@@ -81,6 +81,10 @@ _CASES = {
         causal=True, d=128, stair=(False, True)), None),
     "stair_512_32_over_4_d128": (512, 32, 4, dict(
         causal=True, d=128, stair=(True, True)), None),
+    # 5 query heads a K/V head: the first prefill bucket of a model of 20
+    # over 4 heads of 128.
+    "stair_512_20_over_4_d128": (512, 20, 4, dict(
+        causal=True, d=128, stair=(True, True)), None),
     "whole_640_d64": (640, 2, 2, dict(causal=True, d=64,
                                       stair=(False, False)), None),
     "whole_768_d64": (768, 2, 2, dict(causal=True, d=64,

@@ -354,7 +354,10 @@ def test_engine_stats_agree_with_delivery(tiny_lm):
                           "tokens_discarded", "state_entries",
                           "state_entries_used", "state_bytes", "gaps",
                           "gaps_stalled", "gap_seconds",
-                          "gap_stalled_seconds"}
+                          "gap_stalled_seconds", "kv_sublayers",
+                          "state_sublayers"}
+    # The cache's layers are the model's two attention sublayers.
+    assert (stats["kv_sublayers"], stats["state_sublayers"]) == (2, 0)
     # Every tick but the two started from an empty engine (the third
     # request waits for a slot) went out behind the one in flight.
     assert stats["decode_steps_overlapped"] == stats["decode_steps"] - 2

@@ -144,8 +144,10 @@ def _ring_case(length, head_dim, window, seed, q_heads=Q_HEADS):
 
 
 # 8 query heads a K/V head, and 16 (32 over 2: a pool 256 lanes wide at
-# head_dim 128).
-@pytest.mark.parametrize("q_heads", [Q_HEADS, 32], ids=["8_to_1", "16_to_1"])
+# head_dim 128), and 5 (10 over 2: query rows that fill no whole sublane
+# tile and are padded to one).
+@pytest.mark.parametrize("q_heads", [Q_HEADS, 32, 10],
+                         ids=["8_to_1", "16_to_1", "5_to_1"])
 @pytest.mark.parametrize("window", [WINDOW, None], ids=["window", "full"])
 @pytest.mark.parametrize("head_dim", [16, 128])
 @pytest.mark.parametrize("length", sorted(RING_LENGTHS), ids=sorted(RING_LENGTHS))

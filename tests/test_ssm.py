@@ -25,12 +25,17 @@ def _operands(seed=0, dtype=jnp.float32, groups=None):
     full of noise, so that an entry written by mistake shows. With
     ``groups``, B and C carry a group axis and a head is 64 wide: 512
     lanes, a group 256 or 128 of them (whole tiles of the kernel's
-    walk)."""
+    walk). ``groups`` ``(groups, d_state)``: a state of ``d_state`` (256:
+    two lane tiles on the sublanes) under heads of 128, a group four
+    tiles of the walk."""
+    d_state = D_STATE
     head_dim = HEAD_DIM if groups is None else 64
-    grouped = (SLOTS, D_STATE) if groups is None else (SLOTS, groups, D_STATE)
+    if isinstance(groups, tuple):
+        (groups, d_state), head_dim = groups, 128
+    grouped = (SLOTS, d_state) if groups is None else (SLOTS, groups, d_state)
     keys = jax.random.split(jax.random.PRNGKey(seed), 8)
     pool = jax.random.normal(
-        keys[0], (LAYERS, ENTRIES, D_STATE, HEADS * head_dim)).astype(dtype)
+        keys[0], (LAYERS, ENTRIES, d_state, HEADS * head_dim)).astype(dtype)
     tail_pool = jax.random.normal(
         keys[5], (LAYERS, ENTRIES, TILES, 128)).astype(jnp.bfloat16)
     tail = jax.random.normal(keys[6], (SLOTS, *TAIL))
@@ -57,9 +62,10 @@ def _by_hand(pool, entry, slot, operands, layer):
                             for v in operands[1:])
     state = np.asarray(ssm.from_pool_layout(pool[layer, entry], HEADS),
                        np.float64)
+    d_state = pool.shape[2]
     # Head h reads its group's B and C: group h // (heads / groups).
-    b, c = (np.repeat(v.reshape(-1, D_STATE), HEADS // v.reshape(
-        -1, D_STATE).shape[0], axis=0) for v in (b, c))
+    b, c = (np.repeat(v.reshape(-1, d_state), HEADS // v.reshape(
+        -1, d_state).shape[0], axis=0) for v in (b, c))
     moved = (decay[:, None, None] * state
              + (step[:, None] * x)[:, :, None] * b[:, None, :])
     return (np.einsum("hpn,hn->hp", moved, c),
@@ -74,9 +80,10 @@ LIVE = {"none_live": (0, 0, 0, 0), "one_live": (0, 0, 3, 0),
 
 
 # One group for all heads (no group axis), two groups of four heads, four
-# of two.
-@pytest.mark.parametrize("groups", [None, 2, 4],
-                         ids=["one_group", "two_groups", "four_groups"])
+# of two; two groups of four heads of 128 over a state of 256.
+@pytest.mark.parametrize("groups", [None, 2, 4, (2, 256)],
+                         ids=["one_group", "two_groups", "four_groups",
+                              "two_groups_state_256"])
 @pytest.mark.parametrize("entries", LIVE.values(), ids=LIVE.keys())
 @pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "reference"])
 def test_state_update_moves_live_states_and_leaves_the_rest(entries, kernel,
@@ -115,7 +122,9 @@ def test_state_update_moves_live_states_and_leaves_the_rest(entries, kernel,
         np.testing.assert_array_equal(tails[layer, entry], want_tails[slot])
 
 
-@pytest.mark.parametrize("groups", [None, 4], ids=["one_group", "four_groups"])
+@pytest.mark.parametrize("groups", [None, 4, (2, 256)],
+                         ids=["one_group", "four_groups",
+                              "two_groups_state_256"])
 @pytest.mark.parametrize("entries", LIVE.values(), ids=LIVE.keys())
 def test_kernel_and_reference_write_the_same_pools(entries, groups):
     """One contract: what the chip runs and what runs anywhere else leave

@@ -877,6 +877,140 @@ def test_nemotron_3_nano_serving_programs_compile_for_v5e(topo, as_on_tpu):
                 < 14.5e9)
 
 
+def test_ssm_state_update_kernel_with_a_state_of_256_compiles_for_v5e(topo):
+    """The decode tick's state update at ``falcon-h1-34b``'s widths: 32
+    heads of 128 over a state of 256 (``d_state`` twice a lane tile on the
+    sublanes, 4,096 lanes: 2 groups of 16 tiles), 4 sublayers x 97 entries
+    of float32, the tails 120 whole lane tiles an entry."""
+    from fluxmpi_tpu.ops.ssm import ssm_state_update
+
+    dev = topo.devices[0]
+    slots, layers, heads, head_dim, d_state, groups = 96, 4, 32, 128, 256, 2
+    pool = (layers, slots + 1, d_state, heads * head_dim)
+    tails = (layers, slots + 1, 120, 128)
+
+    def update(pool, tail_pool, entries, tail, x, step, decay, b, c):
+        return ssm_state_update(pool, tail_pool, entries, tail, x, step,
+                                decay, b, c, layer=2, interpret=False)
+
+    compiled = jax.jit(update, donate_argnums=(0, 1)).lower(
+        _sds(pool, jnp.float32, dev), _sds(tails, jnp.bfloat16, dev),
+        _sds((slots,), jnp.int32, dev),
+        _sds((slots, 3, 5120), jnp.bfloat16, dev),
+        _sds((slots, heads, head_dim), jnp.float32, dev),
+        _sds((slots, heads), jnp.float32, dev),
+        _sds((slots, heads), jnp.float32, dev),
+        _sds((slots, groups, d_state), jnp.float32, dev),
+        _sds((slots, groups, d_state), jnp.float32, dev),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(re.findall(r"%ssm_state_update[.\d]* = ", text)) == 1
+    in_place = {"custom-call", "parameter", "get-tuple-element"}
+    assert set(_pool_sized(text, pool)) <= in_place
+    # The tail pool is small enough here (11.9 MB) that the compiler
+    # prefetches it whole into fast memory and writes it back (an
+    # asynchronous copy each way, 15 us at the chip's bandwidth); no
+    # synchronous copy, no change of tiling.
+    assert set(_pool_sized(text, tails)) <= in_place | {"copy-done"}
+    memory = compiled.memory_analysis()
+    pool_bytes = layers * (slots + 1) * (
+        heads * head_dim * d_state * 4 + 120 * 128 * 2)
+    assert memory.alias_size_in_bytes >= pool_bytes  # in place
+    assert memory.temp_size_in_bytes < 2**24
+
+
+def test_falcon_h1_34b_serving_programs_compile_for_v5e(topo, as_on_tpu):
+    """The ``falcon-h1-34b-serve`` cell's decode program and its LONGEST
+    prefill at the published widths (4 layers, each a Mamba-2 mixer of 32
+    heads of 128 over a state of 256 in 2 groups BESIDE attention of 20
+    over 4 heads of 128, an MLP of 21,504, an untied head of 261,120; the
+    cell's slots x 3,072 positions in 512-blocks): a layer's TWO keeping
+    sublayers are one state-update kernel and one paged decode kernel a
+    tick, both pools of both kinds updated in place, and everything under
+    14.5 GB beside 8.79 GB of bfloat16 weights."""
+    import json
+
+    from fluxmpi_tpu.serving import InferenceEngine
+
+    prog, configs = _load_config_module("falcon.program.py")
+    ref, _ = _load_config_module("falcon.reference.py")
+    with open(os.path.join(configs, "falcon-h1-34b.json"),
+              encoding="utf-8") as f:
+        cfg = json.load(f)
+    with open(os.path.join(os.path.dirname(configs), "workloads",
+                           "falcon-h1-34b-serve.json"),
+              encoding="utf-8") as f:
+        geometry = json.load(f)["engine"]
+    dev = topo.devices[0]
+    params = jax.tree_util.tree_map(
+        lambda x: _sds(x.shape, x.dtype, dev),
+        jax.eval_shape(
+            lambda key: prog.to_program(ref.make_weights(cfg, key), cfg)[0],
+            jax.random.PRNGKey(0),
+        ),
+    )
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert 8.78e9 < weights < 8.80e9
+    slots, bucket = geometry["slots"], 2048
+    engine = InferenceEngine(
+        prog.build_model(cfg, "naive"), params, attention="flash",
+        slots=slots, block_size=geometry["block_size"],
+        max_len=geometry["max_len"], check_memory=False,
+    )
+    try:
+        cache = engine.cache
+        assert cache.num_layers == 8  # the keeping sublayers
+        assert cache.pool_shapes == [(4, 1 + slots * 6, 512, 512),
+                                     (4, 1 + slots, 256, 4096)]
+        state, tail = 32 * 128 * 256 * 4, 3 * 5120 * 2
+        assert cache.pool_bytes == (
+            2 * 4 * (1 + slots * 6) * 512 * 512 * 2
+            + (1 + slots) * 4 * (state + tail))
+        k_pools = (_sds(cache.pool_shapes[0], jnp.bfloat16, dev),
+                   _sds(cache.pool_shapes[1], jnp.float32, dev))
+        tails = (4, 1 + slots, cache.tail_tiles, 128)
+        assert cache.tail_tiles == 120  # whole tiles, no padding
+        v_pools = (_sds(cache.pool_shapes[0], jnp.bfloat16, dev),
+                   _sds(tails, jnp.bfloat16, dev))
+        decode = engine._decode_step.lower(
+            params, k_pools, v_pools,
+            tuple(_sds((slots, k.entries), jnp.int32, dev)
+                  for k in cache.kinds),
+            _sds((slots,), jnp.int32, dev), _sds((slots,), jnp.int32, dev),
+            _sds((slots,), jnp.int32, dev), _sds((slots,), jnp.bool_, dev),
+        ).compile()
+        prefill = engine._prefill_step(bucket).lower(
+            params, k_pools, v_pools, _sds((bucket,), jnp.int32, dev),
+            _sds((), jnp.int32, dev),
+            tuple(_sds((k.entries,), jnp.int32, dev) for k in cache.kinds),
+        ).compile()
+    finally:
+        engine.close()
+    text = decode.as_text()
+    assert text.count("tpu_custom_call") == 4 + 4
+    assert "slice-start" not in text  # operands prefetched whole
+    assert len(re.findall(r"%ssm_state_update[.\d]* = ", text)) == 4
+    in_place = {"custom-call", "parameter", "get-tuple-element"}
+    assert set(_pool_sized(text, cache.pool_shapes[1])) <= in_place
+    # The tails (11.9 MB in all) are prefetched whole into fast memory
+    # and written back, asynchronously: see the kernel's own test.
+    assert set(_pool_sized(text, tails)) <= in_place | {"copy-done"}
+    assert "copy" not in _pool_sized(text, *cache.pool_shapes, tails)
+    # The prefill: one flash forward a layer, the chunked scan in plain
+    # XLA, no state-update kernel.
+    text = prefill.as_text()
+    assert text.count("tpu_custom_call") == 4
+    assert not re.findall(r"%ssm_state_update[.\d]* = ", text)
+    for program, temporaries in ((decode, 2**28), (prefill, 3 * 2**29)):
+        memory = program.memory_analysis()
+        assert memory.temp_size_in_bytes < temporaries
+        assert memory.alias_size_in_bytes >= cache.pool_bytes  # in place
+        assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                < 14.5e9)
+
+
 def _lm_state(cfg, optimizer):
     model = chip_smoke._lm(cfg)
     params = jax.eval_shape(
