@@ -64,11 +64,9 @@ _NEG_INF = -1e30
 # or the full array dim. 1-D per-row operands therefore travel
 # sublane-replicated ([.., 8, s], read as a [1, block] row — lse/dterm
 # everywhere, at 8× HBM) or lane-replicated ([.., s, 128], read as a
-# [block, 1] column — the segment ids of whichever side a kernel's tiles
-# put on sublanes: queries in dq, keys in the forward and dkv),
-# matching the orientation each kernel consumes them in; the dq kernel's
-# lse/dterm reads pay one in-register row→column transpose per tile
-# instead of a 128× lane-replicated buffer (ADVICE r3 #2).
+# [block, 1] column — the keys' segment ids: every kernel's tiles are
+# [keys, queries], keys on sublanes), matching the orientation the
+# kernels consume them in (ADVICE r3 #2).
 _LANES = 128
 _SUBLANES = 8
 
@@ -85,11 +83,11 @@ def _as_row(x):
 
 
 def _pos_mask(shape, offset, window: int | None = None,
-              causal: bool = True, transposed: bool = False):
-    """Positional mask of a tile of ``shape`` = [queries, keys] (or
-    [keys, queries] with ``transposed``, the dkv kernel's tiles) whose
-    first query sits ``offset`` positions after its first key: True =
-    attend. With ``causal``, requires ``q_pos >= k_pos``; with ``window``,
+              causal: bool = True):
+    """Positional mask of a tile of ``shape`` = [keys, queries] (every
+    kernel's tiles: queries ride the lane axis) whose first query sits
+    ``offset`` positions after its first key: True = attend. With
+    ``causal``, requires ``q_pos >= k_pos``; with ``window``,
     additionally requires ``q_pos - k_pos < window`` (sliding-window /
     local attention, Mistral-style). ``causal=False`` with a window is the
     band-only mode: only the upper displacement bound applies — the
@@ -99,10 +97,9 @@ def _pos_mask(shape, offset, window: int | None = None,
 
     ``q_pos - k_pos`` is the in-tile difference of two iotas plus the
     scalar ``offset``, so each bound costs one compare an element."""
-    q_dim = 1 if transposed else 0
     diff = jax.lax.broadcasted_iota(
-        jnp.int32, shape, q_dim
-    ) - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
+        jnp.int32, shape, 1
+    ) - jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     mask = diff >= -offset if causal else None
     if window is not None:
         band = diff < window - offset
@@ -320,6 +317,15 @@ def _operands(*tiles, rows: int = _LANES):
     return tuple(t.astype(dtype) for t in tiles)
 
 
+def _dot(a, b, transpose_b: bool = False):
+    """``a @ b`` (``a @ b.T`` with ``transpose_b``: both contract their
+    minor dimension), accumulated in float32."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1 if transpose_b else 0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _split_scale(d: int):
     """``(q_scale, s_scale)`` with ``q_scale * s_scale == 1 / sqrt(d)``:
     a power of two (head_dim 16, 64, 256) multiplies Q exactly in any
@@ -332,8 +338,7 @@ def _split_scale(d: int):
 
 def _seg_mask(qseg, kseg):
     """Segment mask: attend iff same segment and key is not padding (id 0).
-    ``qseg`` [bq, 1] and ``kseg`` [1, bk] int32 → bool [bq, bk]; a
-    [keys, queries] tile passes a [1, bq] row and a [bk, 1] column."""
+    ``qseg`` [1, bq] and ``kseg`` [bk, 1] int32 → bool [bk, bq]."""
     return (qseg == kseg) & (kseg != 0)
 
 
@@ -376,15 +381,12 @@ def _dropout_keep(seed, bh, q_pos, k_pos, keep_prob):
     return bits < threshold
 
 
-def _tile_keep(shape, seed, bh, q_start, k_start, keep_prob,
-               transposed=False):
-    """The deterministic dropout keep-mask of a tile of ``shape`` whose
-    first query and key sit at ``q_start`` / ``k_start``.
-    ``transposed=True`` is the [keys, queries] tile the dkv kernel uses
-    (same (q, k) hash inputs, swapped iota orientation)."""
-    q_dim = 1 if transposed else 0
-    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
-    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
+def _tile_keep(shape, seed, bh, q_start, k_start, keep_prob):
+    """The deterministic dropout keep-mask of a [keys, queries] tile of
+    ``shape`` whose first query and key sit at ``q_start`` /
+    ``k_start``."""
+    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     return _dropout_keep(seed, bh, q_pos, k_pos, keep_prob)
 
 
@@ -400,6 +402,41 @@ def _unpack_refs(refs, has_segments: bool, dropout_rate: float):
         seed_ref = refs[pos]
         pos += 1
     return (*refs[:3], qseg_ref, kseg_ref, seed_ref, refs[pos:])
+
+
+def _tile_fn(update, tiles, qi, kj, causal: bool, window: int | None,
+             strips: int, qseg_ref, kseg_ref):
+    """The ``tile(r0, c0, masked)`` a kernel hands :func:`_walk`, one for
+    all three since all work [keys, queries] tiles: the sub-tile at
+    ``(r0, c0)`` of grid step ``(qi, kj)`` gets its mask (positions where
+    ``masked``; segment ids, kseg lane-replicated → a [sub_k, 1] column,
+    qseg sublane-replicated → a [1, sub_q] row) and goes to
+    ``update(r0, c0, mask, stair, q_start, k_start)``; a sub-tile whose
+    segments keep no pair is skipped (block-sparse), and a cut sub-tile
+    where ``strips`` (:func:`_strips`: the diagonal one, square) goes as
+    a staircase of that many pieces with the mask of ONE cut square."""
+    block_q, block_k, sub_q, sub_k = tiles
+
+    def tile(r0, c0, masked):
+        if masked and strips:
+            w = sub_q // strips
+            update(r0, c0, _pos_mask((w, w), 0), strips)
+            return
+        rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
+        q_start = qi * block_q + r0
+        k_start = kj * block_k + c0
+        mask = None
+        if masked and (causal or window is not None):
+            mask = _pos_mask((sub_k, sub_q), q_start - k_start, window, causal)
+        if qseg_ref is None:
+            update(r0, c0, mask, 0, q_start, k_start)
+            return
+        sm = _seg_mask(qseg_ref[0, :1, rows], kseg_ref[0, cols, :1])
+        mask = sm if mask is None else jnp.logical_and(mask, sm)
+        pl.when(jnp.any(mask))(
+            functools.partial(update, r0, c0, mask, 0, q_start, k_start))
+
+    return tile
 
 
 def _flash_kernel(
@@ -420,10 +457,12 @@ def _flash_kernel(
     row statistics ``m`` and ``l`` are lane-dense [1, sub_q] rows (a
     [sub_q, 1] column costs a register for every 8 queries, in every
     statistic's every operation), and the reductions run over sublanes.
-    V arrives transposed ([d, keys]) and the output leaves transposed
-    ([d, queries]); Q arrives carrying whatever part of the softmax scale
-    is not ``s_scale`` (:func:`_split_scale`)."""
-    (q_ref, k_ref, vt_ref, qseg_ref, kseg_ref, seed_ref,
+    Q and V arrive transposed ([d, queries], [d, keys]: the sequence on
+    the lanes, as the projections' matmuls write them) and the output
+    leaves transposed ([d, queries]); only K is read row-major. Q arrives
+    carrying whatever part of the softmax scale is not ``s_scale``
+    (:func:`_split_scale`)."""
+    (qt_ref, k_ref, vt_ref, qseg_ref, kseg_ref, seed_ref,
      (o_ref, lse_ref, m_scratch, l_scratch, acc_scratch)) = _unpack_refs(
         refs, has_segments, dropout_rate)
     block_q, block_k, sub_q, sub_k = tiles
@@ -451,16 +490,12 @@ def _flash_kernel(
         the square of a piece's last keys."""
         pieces = _pieces(sub_q, stair)
         rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
-        q, k, vt = _operands(
-            q_ref[0, rows, :], k_ref[0, cols, :], vt_ref[0, :, cols],
+        qt, k, vt = _operands(
+            qt_ref[0, :, rows], k_ref[0, cols, :], vt_ref[0, :, cols],
             rows=sub_q,
         )
-        s_t = [
-            jax.lax.dot_general(
-                _part(k, ks, 0), _part(q, qs, 0), (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) for qs, ks in pieces
-        ]  # [keys, queries] each
+        s_t = [_dot(_part(k, ks, 0), _part(qt, qs, 1))
+               for qs, ks in pieces]  # [keys, queries] each
         if s_scale != 1.0:
             s_t = [x * s_scale for x in s_t]
         if mask is not None:
@@ -489,50 +524,22 @@ def _flash_kernel(
         if dropout_rate:
             kp = 1.0 - dropout_rate
             keep = _tile_keep((sub_k, sub_q), seed_ref[0, 0], bh, q_start,
-                              k_start, kp, transposed=True)
+                              k_start, kp)
             p_t = [jnp.where(keep, x / kp, 0.0) for x in p_t]
 
         # p is narrowed to the operand dtype only as an operand of
         # its own product; statistics and accumulators stay f32.
-        pv = [
-            jax.lax.dot_general(
-                _part(vt, ks, 1), x.astype(vt.dtype),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) for x, (_, ks) in zip(p_t, pieces)
-        ]  # [d, queries] each
+        pv = [_dot(_part(vt, ks, 1), x.astype(vt.dtype))
+              for x, (_, ks) in zip(p_t, pieces)]  # [d, queries] each
         for x, a, y, m, l in zip(spans, alpha, pv, m_new, l_new):
             acc_scratch[:, x] = acc_scratch[:, x] * a + y
             m_scratch[:, x] = jnp.broadcast_to(m, (_SUBLANES, x.size))
             l_scratch[:, x] = jnp.broadcast_to(l, (_SUBLANES, x.size))
 
-    def _tile(r0, c0, masked):
-        if masked and strips:
-            # The diagonal sub-tile, as a staircase of query strips.
-            w = sub_q // strips
-            _update(r0, c0, _pos_mask((w, w), 0, transposed=True), strips)
-            return
-        rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
-        q_start = qi * block_q + r0
-        k_start = kj * block_k + c0
-        mask = None
-        if masked and (causal or window is not None):
-            mask = _pos_mask((sub_k, sub_q), q_start - k_start, window,
-                             causal, transposed=True)
-        if has_segments:
-            # kseg lane-replicated → [sub_k, 1] column; qseg
-            # sublane-replicated → [1, sub_q] row.
-            sm = _seg_mask(qseg_ref[0, :1, rows], kseg_ref[0, cols, :1])
-            mask = sm if mask is None else jnp.logical_and(mask, sm)
-        update = functools.partial(_update, r0, c0, mask, 0, q_start, k_start)
-        if has_segments:
-            # Block-sparse skip of fully-masked / fully-padded sub-tiles.
-            pl.when(jnp.any(mask))(update)
-        else:
-            update()
-
-    _walk(_tile, qi, kj, tiles, causal, window,
-          has_segments or bool(dropout_rate))
+    # A diagonal sub-tile under ``strips``: a staircase of query strips.
+    _walk(_tile_fn(_update, tiles, qi, kj, causal, window, strips, qseg_ref,
+                   kseg_ref),
+          qi, kj, tiles, causal, window, has_segments or bool(dropout_rate))
 
     @_when(kj == num_k_blocks - 1)
     def _finish():
@@ -561,12 +568,16 @@ def _flash_bwd_dq_kernel(
     dropout_rate: float = 0.0,
 ):
     """dQ pass: for each Q block, sweep K/V blocks (innermost grid dim) in
-    sub-tiles (the forward's :func:`_walk`, on [queries, keys] tiles),
-    recompute probabilities from the saved lse, accumulate
-    ``dq += (p ∘ (dp - dterm)) @ K`` in VMEM scratch; ``sm_scale``
-    multiplies the sum once, in ``_finish``."""
-    (q_ref, k_ref, v_ref, qseg_ref, kseg_ref, seed_ref,
-     (do_ref, lse_ref, dterm_ref, dq_ref, dq_scratch)) = _unpack_refs(
+    sub-tiles (the forward's :func:`_walk`, on the forward's
+    [keys, queries] tiles, so lse and dterm are read as the rows they are
+    stored as), recompute probabilities from the saved lse, accumulate
+    ``dq_t += K^T @ (p ∘ (dp - dterm))^T`` in VMEM scratch ([d, queries]:
+    Q, dO and dq cross HBM transposed, as in the forward); ``sm_scale``
+    multiplies the sum once, in ``_finish``. K arrives twice: row-major
+    for the scores, and transposed ([d, keys], the key projection's own
+    layout) for the product that makes dq."""
+    (qt_ref, k_ref, v_ref, qseg_ref, kseg_ref, seed_ref,
+     (kt_ref, dot_ref, lse_ref, dterm_ref, dq_ref, dq_scratch)) = _unpack_refs(
         refs, has_segments, dropout_rate)
     block_q, block_k, sub_q, sub_k = tiles
 
@@ -582,81 +593,48 @@ def _flash_bwd_dq_kernel(
                      has_segments or bool(dropout_rate))
 
     def _update(r0, c0, mask, stair=0, q_start=None, k_start=None):
-        """``dq += ds @ K`` for the sub-tile at ``(r0, c0)``, whole or
-        (``stair``) in that many pieces (:func:`_pieces`), stage by stage
-        as in the forward; ``mask`` is the whole tile's, or the square of
-        a piece's last keys."""
+        """``dq_t += K^T @ ds_t`` for the sub-tile at ``(r0, c0)``, whole
+        or (``stair``) in that many pieces (:func:`_pieces`), stage by
+        stage as in the forward; ``mask`` is the whole tile's, or the
+        square of a piece's last keys."""
         pieces = _pieces(sub_q, stair)
         rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
-        q, k, v, do = _operands(
-            q_ref[0, rows, :], k_ref[0, cols, :], v_ref[0, cols, :],
-            do_ref[0, rows, :],
+        qt, k, v, kt, dot = _operands(
+            qt_ref[0, :, rows], k_ref[0, cols, :], v_ref[0, cols, :],
+            kt_ref[0, :, cols], dot_ref[0, :, rows],
         )
-        # lse/dterm arrive sublane-replicated ([8, block_q] rows — the
-        # 8× layout, ADVICE r3 #2); one in-register transpose per
-        # sub-tile gives the [sub_q, 1] column the score math
-        # broadcasts against.
-        lse = jnp.transpose(lse_ref[0, :1, rows])  # [sub_q, 1]
-        dterm = jnp.transpose(dterm_ref[0, :1, rows])  # delta - dlse
+        # [1, queries] (sublane-replicated), a piece's queries at a time.
+        spans = [_span(r0, qs, sub_q) for qs, _ in pieces]
+        lse = [lse_ref[0, :1, x] for x in spans]
+        dterm = [dterm_ref[0, :1, x] for x in spans]  # delta - dlse
 
-        s = [
-            jax.lax.dot_general(
-                _part(q, qs, 0), _part(k, ks, 0), (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) for qs, ks in pieces
-        ]  # [queries, keys] each
+        s_t = [_dot(_part(k, ks, 0), _part(qt, qs, 1))
+               for qs, ks in pieces]  # [keys, queries] each
         if s_scale != 1.0:
-            s = [x * s_scale for x in s]
+            s_t = [x * s_scale for x in s_t]
         # normalized probabilities
-        p = [jnp.exp(x - _part(lse, qs, 0)) for x, (qs, _) in zip(s, pieces)]
+        p_t = [jnp.exp(x - y) for x, y in zip(s_t, lse)]
         if mask is not None:
-            p = [_cut(mask, x, 0.0, axis=1) for x in p]
-        dp = [
-            jax.lax.dot_general(
-                _part(do, qs, 0), _part(v, ks, 0), (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) for qs, ks in pieces
-        ]  # [queries, keys] each
+            p_t = [_cut(mask, x, 0.0, axis=0) for x in p_t]
+        dp_t = [_dot(_part(v, ks, 0), _part(dot, qs, 1))
+                for qs, ks in pieces]  # [keys, queries] each
         if dropout_rate:
             # ds = w ∘ (d∘dp/kp − delta): the dropout mask lands on
             # dp; the delta term (rowsum dO∘O) already carries the
             # dropped forward.
             kp = 1.0 - dropout_rate
-            keep = _tile_keep((sub_q, sub_k), seed_ref[0, 0], bh, q_start,
+            keep = _tile_keep((sub_k, sub_q), seed_ref[0, 0], bh, q_start,
                               k_start, kp)
-            dp = [jnp.where(keep, x / kp, 0.0) for x in dp]
-        ds = [x * (y - _part(dterm, qs, 0))
-              for x, y, (qs, _) in zip(p, dp, pieces)]
-        dq_scratch[rows, :] += _join([
-            jax.lax.dot_general(
-                x.astype(k.dtype), _part(k, ks, 0), (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) for x, (_, ks) in zip(ds, pieces)
-        ], 0)
+            dp_t = [jnp.where(keep, x / kp, 0.0) for x in dp_t]
+        ds_t = [x * (y - z) for x, y, z in zip(p_t, dp_t, dterm)]
+        for x, y, (_, ks) in zip(spans, ds_t, pieces):
+            dq_scratch[:, x] += _dot(_part(kt, ks, 1), y.astype(kt.dtype))
 
-    def _tile(r0, c0, masked):
-        if masked and strips:
-            # The diagonal sub-tile, as a staircase of query strips.
-            w = sub_q // strips
-            _update(r0, c0, _pos_mask((w, w), 0), strips)
-            return
-        rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
-        q_start = qi * block_q + r0
-        k_start = kj * block_k + c0
-        mask = None
-        if masked and (causal or window is not None):
-            mask = _pos_mask((sub_q, sub_k), q_start - k_start, window, causal)
-        if has_segments:
-            sm = _seg_mask(qseg_ref[0, rows, :1], kseg_ref[0, :1, cols])
-            mask = sm if mask is None else jnp.logical_and(mask, sm)
-        update = functools.partial(_update, r0, c0, mask, 0, q_start, k_start)
-        if has_segments:
-            pl.when(jnp.any(mask))(update)
-        else:
-            update()
-
-    _walk(_tile, qi, kj, tiles, causal, window,
-          has_segments or bool(dropout_rate), unroll=True)
+    # A diagonal sub-tile under ``strips``: a staircase of query strips.
+    _walk(_tile_fn(_update, tiles, qi, kj, causal, window, strips, qseg_ref,
+                   kseg_ref),
+          qi, kj, tiles, causal, window, has_segments or bool(dropout_rate),
+          unroll=True)
 
     @_when(kj == num_k_blocks - 1)
     def _finish():
@@ -680,15 +658,17 @@ def _flash_bwd_dkv_kernel(
     """dK/dV pass: for each K/V block, sweep Q blocks — and, under GQA, the
     whole query-head group — in the innermost grid dim, each (K, Q) block
     pair in sub-tiles (:func:`_walk`, key sub-tiles outermost),
-    accumulating ``dv += pᵀ @ dO`` and
-    ``dk += (p ∘ (dp - dterm))ᵀ @ Q`` in f32 VMEM scratch (transposed forms
-    computed directly to keep the contraction on the MXU). One grid row
+    accumulating ``dv_t += dO^T @ p`` and
+    ``dk_t += Q^T @ (p ∘ (dp - dterm))`` in f32 VMEM scratch, [d, keys]:
+    Q and dO arrive transposed ([d, queries]) and dk and dv leave
+    transposed, the sequence on the lanes as the projections' matmuls
+    hold them; K and V are read row-major. One grid row
     per KV head: the group-summed gradient is written once, full f32
     accumulation, no q-head-granularity HBM temporaries. Q arrives
     carrying whatever part of the softmax scale is not ``s_scale``, so dK
     needs ``s_scale`` alone, in ``_finish``."""
-    (q_ref, k_ref, v_ref, qseg_ref, kseg_ref, seed_ref,
-     (do_ref, lse_ref, dterm_ref, dk_ref, dv_ref,
+    (qt_ref, k_ref, v_ref, qseg_ref, kseg_ref, seed_ref,
+     (dot_ref, lse_ref, dterm_ref, dk_ref, dv_ref,
       dk_scratch, dv_scratch)) = _unpack_refs(
         refs, has_segments, dropout_rate)
     block_q, block_k, sub_q, sub_k = tiles
@@ -716,28 +696,24 @@ def _flash_bwd_dkv_kernel(
                      has_segments or bool(dropout_rate))
 
     def _update(r0, c0, mask, stair=0, q_start=None, k_start=None):
-        """``dv += pᵀ @ dO`` and ``dk += dsᵀ @ Q`` for the [keys, queries]
-        sub-tile at ``(r0, c0)``, whole or (``stair``) in that many
-        pieces by KEY strips (:func:`_pieces`), stage by stage as in the
-        forward; ``mask`` is the whole tile's, or the square of a piece's
-        FIRST queries."""
+        """``dv_t += dO^T @ p`` and ``dk_t += Q^T @ ds`` for the
+        [keys, queries] sub-tile at ``(r0, c0)``, whole or (``stair``) in
+        that many pieces by KEY strips (:func:`_pieces`), stage by stage
+        as in the forward; ``mask`` is the whole tile's, or the square of
+        a piece's FIRST queries."""
         pieces = _pieces(sub_k, stair, key_strips=True)
         rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
-        q, k, v, do = _operands(
-            q_ref[0, rows, :], k_ref[0, cols, :], v_ref[0, cols, :],
-            do_ref[0, rows, :],
+        qt, k, v, dot = _operands(
+            qt_ref[0, :, rows], k_ref[0, cols, :], v_ref[0, cols, :],
+            dot_ref[0, :, rows],
         )
         # [1, queries] (sublane-replicated), a piece's queries at a time.
         spans = [_span(r0, qs, sub_q) for qs, _ in pieces]
         lse = [lse_ref[0, :1, x] for x in spans]
         dterm = [dterm_ref[0, :1, x] for x in spans]
 
-        s_t = [
-            jax.lax.dot_general(
-                _part(k, ks, 0), _part(q, qs, 0), (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) for qs, ks in pieces
-        ]  # [keys, queries] each
+        s_t = [_dot(_part(k, ks, 0), _part(qt, qs, 1))
+               for qs, ks in pieces]  # [keys, queries] each
         if s_scale != 1.0:
             s_t = [x * s_scale for x in s_t]
         p_t = [jnp.exp(x - y) for x, y in zip(s_t, lse)]
@@ -750,64 +726,32 @@ def _flash_bwd_dkv_kernel(
             # the transposed twin of the dq kernel's math.
             kp = 1.0 - dropout_rate
             keep_t = _tile_keep((sub_k, sub_q), seed_ref[0, 0], bh_q,
-                                q_start, k_start, kp, transposed=True)
+                                q_start, k_start, kp)
             p_t_drop = [jnp.where(keep_t, x / kp, 0.0) for x in p_t]
         else:
             p_t_drop = p_t
-        dv_scratch[cols, :] += _join([
-            jax.lax.dot_general(
-                x.astype(do.dtype), _part(do, qs, 0),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) for x, (qs, _) in zip(p_t_drop, pieces)
-        ], 0)  # [sub_k, d]
-        dp_t = [
-            jax.lax.dot_general(
-                _part(v, ks, 0), _part(do, qs, 0), (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) for qs, ks in pieces
-        ]  # [keys, queries] each
+        dv_scratch[:, cols] += _join([
+            _dot(_part(dot, qs, 1), x.astype(dot.dtype), transpose_b=True)
+            for x, (qs, _) in zip(p_t_drop, pieces)
+        ], 1)  # [d, sub_k]
+        dp_t = [_dot(_part(v, ks, 0), _part(dot, qs, 1))
+                for qs, ks in pieces]  # [keys, queries] each
         if dropout_rate:
             dp_t = [jnp.where(keep_t, x / kp, 0.0) for x in dp_t]
         ds_t = [x * (y - z) for x, y, z in zip(p_t, dp_t, dterm)]
-        dk_scratch[cols, :] += _join([
-            jax.lax.dot_general(
-                x.astype(q.dtype), _part(q, qs, 0), (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) for x, (qs, _) in zip(ds_t, pieces)
-        ], 0)
+        dk_scratch[:, cols] += _join([
+            _dot(_part(qt, qs, 1), x.astype(qt.dtype), transpose_b=True)
+            for x, (qs, _) in zip(ds_t, pieces)
+        ], 1)
 
-    def _tile(r0, c0, masked):
-        # [keys, queries] tiles: queries ride the lane axis.
-        if masked and strips:
-            # The diagonal sub-tile, as a staircase of KEY strips (row
-            # blocks of dk and dv).
-            w = sub_k // strips
-            _update(r0, c0, _pos_mask((w, w), 0, transposed=True), strips)
-            return
-        rows, cols = pl.ds(r0, sub_q), pl.ds(c0, sub_k)
-        q_start = qi * block_q + r0
-        k_start = kj * block_k + c0
-        mask = None
-        if masked and (causal or window is not None):
-            mask = _pos_mask((sub_k, sub_q), q_start - k_start, window,
-                             causal, transposed=True)
-        if has_segments:
-            # Here kseg arrives lane-replicated (→ [sub_k, 1] column) and
-            # qseg sublane-replicated (→ [1, sub_q] row) — the transpose
-            # of the fwd/dq layouts.
-            sm = _seg_mask(qseg_ref[0, :1, rows], kseg_ref[0, cols, :1])
-            mask = sm if mask is None else jnp.logical_and(mask, sm)
-        update = functools.partial(_update, r0, c0, mask, 0, q_start, k_start)
-        if has_segments:
-            pl.when(jnp.any(mask))(update)
-        else:
-            update()
-
-    # Query sub-tiles entirely in the past of a key sub-tile, or entirely
-    # beyond the window's future edge, are not visited.
-    _walk(_tile, qi, kj, tiles, causal, window,
-          has_segments or bool(dropout_rate), k_outer=True, unroll=True)
+    # A diagonal sub-tile under ``strips``: a staircase of KEY strips
+    # (column blocks of dk_t and dv_t). Query sub-tiles entirely in the
+    # past of a key sub-tile, or entirely beyond the window's future
+    # edge, are not visited.
+    _walk(_tile_fn(_update, tiles, qi, kj, causal, window, strips, qseg_ref,
+                   kseg_ref),
+          qi, kj, tiles, causal, window, has_segments or bool(dropout_rate),
+          k_outer=True, unroll=True)
 
     @pl.when(it == total_q_iters - 1)
     def _finish():
@@ -818,21 +762,59 @@ def _flash_bwd_dkv_kernel(
         dv_ref[0] = dv_scratch[...].astype(dv_ref.dtype)
 
 
-def _fold_heads(x):
-    """(b, s, h, d) → (b·h, s, d)."""
+# The layout each kernel's operands cross HBM in (``bh``: batch x heads,
+# folded; gradients heads outermost, :func:`_grad_row`), for
+# scripts/flash_sweep.py's rows: [bh, d, s] is what a projection's matmul
+# writes and its weight-gradient matmul reads on the chip, at no copy;
+# [bh, s, d] costs a copy of the array each way (PERF.md §6, PR 44).
+_LAYOUTS = {
+    "fwd": "q v out [bh,d,s]; k [bh,s,d]",
+    "dq": "q do k_t dq [bh,d,s]; k v [bh,s,d]",
+    "dkv": "q do dk dv [bh,d,s]; k v [bh,s,d]",
+}
+
+
+def _fold_rows(x):
+    """(b, s, h, d) → (b·h, s, d): a head's rows one after the other, its
+    ``d`` columns on the lanes. What the kernels read K in (and V, in the
+    backward); from a projection's result it is a copy."""
     b, s, h, d = x.shape
     return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
 
-def _unfold_heads(x, b, h):
-    bh, s, d = x.shape
-    return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+def _fold_cols(x):
+    """(b, s, h, d) → (b·h, d, s): the sequence on the lanes, which is how
+    a projection's matmul writes its result on the chip (``d`` of 64 on
+    the lanes would be padded to 128), so the transpose stays logical:
+    XLA folds it into the producer's layout and nothing is copied."""
+    b, s, h, d = x.shape
+    return x.transpose(0, 2, 3, 1).reshape(b * h, d, s)
 
 
 def _fold_q(q):
-    """Q folded, carrying the exact part of the softmax scale."""
+    """Q folded (:func:`_fold_cols`), carrying the exact part of the
+    softmax scale."""
     q_scale = _split_scale(q.shape[-1])[0]
-    return _fold_heads(q if q_scale == 1.0 else q * q_scale)
+    return _fold_cols(q if q_scale == 1.0 else q * q_scale)
+
+
+def _unfold_grad(x, b, h):
+    """A gradient as its kernel wrote it, (h·b, d, s) with the HEADS
+    outermost (:func:`_grad_row`), → (b, s, h, d), logically."""
+    _, d, s = x.shape
+    return x.reshape(h, b, d, s).transpose(1, 3, 0, 2)
+
+
+def _grad_row(b: int, h: int):
+    """Row of a gradient for the folded row ``b_idx·h + h_idx`` its grid
+    step works: ``h_idx·b + b_idx``, heads outermost, the order in which
+    the projections' weight-gradient matmuls take it without a copy (the
+    order of the folded rows is the kernel's to choose; read off the
+    step compiled for a described v5e, PERF.md §6, PR 44)."""
+    def row(bh):
+        return _rem(bh, h) * b + _div(bh, h)
+
+    return row
 
 
 def _kv_row(h: int, h_kv: int):
@@ -910,10 +892,9 @@ def _fwd_pallas(q, k, v, qseg, kseg, seed, causal, window, tiles, interpret,
     num_k_blocks = sk // block_k
     has_segments = qseg is not None
 
-    # V and the output travel transposed ([.., dv, s]: _flash_kernel); the
-    # transposes are the head folds', with another permutation.
-    qr, kr = _fold_q(q), _fold_heads(k)
-    vtr = v.transpose(0, 2, 3, 1).reshape(b * h_kv, dv, sk)
+    # Q, V and the output travel with the sequence on the lanes
+    # ([.., d, s]: _flash_kernel), K row-major.
+    qt, kr, vt = _fold_q(q), _fold_rows(k), _fold_cols(v)
 
     kernel = functools.partial(
         _flash_kernel,
@@ -932,13 +913,13 @@ def _fwd_pallas(q, k, v, qseg, kseg, seed, causal, window, tiles, interpret,
                              window)
 
     in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
+        pl.BlockSpec((1, d, block_q), lambda bh, qi, kj: (bh, 0, qi)),
         pl.BlockSpec((1, block_k, d),
                      lambda bh, qi, kj: (kv_row(bh), live(qi, kj), 0)),
         pl.BlockSpec((1, dv, block_k),
                      lambda bh, qi, kj: (kv_row(bh), 0, live(qi, kj))),
     ]
-    operands = [qr, kr, vtr]
+    operands = [qt, kr, vt]
     if has_segments:
         # qseg sublane-replicated row, kseg lane-replicated column.
         # Segments are per batch row: the index map divides the head
@@ -983,53 +964,60 @@ def _fwd_pallas(q, k, v, qseg, kseg, seed, causal, window, tiles, interpret,
     return out, lse[:, 0, :].reshape(b, h, sq)
 
 
-def _dq_pallas(qr, kr, vr, dor, lse_row, dterm_row, qseg, kseg, seed, *,
+def _dq_pallas(qt, kr, vr, kt, dot, lse_row, dterm_row, qseg, kseg, seed, *,
                h, h_kv, causal, window, tiles, interpret, dropout_rate):
-    """dQ over folded operands (``[b·h, s, d]``; lse and dterm
-    sublane-replicated rows)."""
+    """dQ over folded operands: Q, dO and K a second time as
+    :func:`_fold_cols` folds them, K and V as :func:`_fold_rows` does,
+    lse and dterm sublane-replicated rows. Returns dq ``[h·b, d, s]``
+    (:func:`_grad_row`)."""
     from jax.experimental.pallas import tpu as pltpu
 
     block_q, block_k = tiles[:2]
-    bh, sq, d = qr.shape
+    bh, d, sq = qt.shape
     sk = kr.shape[1]
     kv_row = _kv_row(h, h_kv)
+    grad_row = _grad_row(bh // h, h)
     num_k_blocks = sk // block_k
     has_segments = qseg is not None
     q_scale, s_scale = _split_scale(d)
 
+    def live(qi, kj):
+        return _live_k_block(qi, kj, block_q, block_k, num_k_blocks, causal,
+                             window)
+
     def kv_index(bh, qi, kj):
-        return (
-            kv_row(bh),
-            _live_k_block(qi, kj, block_q, block_k, num_k_blocks, causal,
-                          window),
-            0,
-        )
+        return (kv_row(bh), live(qi, kj), 0)
+
+    def q_index(bh, qi, kj):
+        return (bh, 0, qi)
 
     in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
+        pl.BlockSpec((1, d, block_q), q_index),
         pl.BlockSpec((1, block_k, d), kv_index),
         pl.BlockSpec((1, block_k, d), kv_index),
     ]
-    operands = [qr, kr, vr]
+    operands = [qt, kr, vr]
     if has_segments:
-        # [queries, keys] tiles: qseg lane-replicated column, kseg
-        # sublane-replicated row, per batch row as in the forward.
+        # qseg sublane-replicated row, kseg lane-replicated column, per
+        # batch row as in the forward.
         in_specs += [
-            pl.BlockSpec((1, block_q, _LANES),
-                         lambda bh, qi, kj: (_div(bh, h), qi, 0)),
-            pl.BlockSpec((1, _SUBLANES, block_k),
-                         lambda bh, qi, kj: (_div(bh, h), 0, kj)),
+            pl.BlockSpec((1, _SUBLANES, block_q),
+                         lambda bh, qi, kj: (_div(bh, h), 0, qi)),
+            pl.BlockSpec((1, block_k, _LANES),
+                         lambda bh, qi, kj: (_div(bh, h), kj, 0)),
         ]
-        operands += [_as_col(qseg), _as_row(kseg)]
+        operands += [_as_row(qseg), _as_col(kseg)]
     if dropout_rate:
         in_specs.append(_seed_spec())
         operands.append(_seed_operand(seed))
     in_specs += [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
-        pl.BlockSpec((1, _SUBLANES, block_q), lambda bh, qi, kj: (bh, 0, qi)),
-        pl.BlockSpec((1, _SUBLANES, block_q), lambda bh, qi, kj: (bh, 0, qi)),
+        pl.BlockSpec((1, d, block_k),
+                     lambda bh, qi, kj: (kv_row(bh), 0, live(qi, kj))),
+        pl.BlockSpec((1, d, block_q), q_index),
+        pl.BlockSpec((1, _SUBLANES, block_q), q_index),
+        pl.BlockSpec((1, _SUBLANES, block_q), q_index),
     ]
-    operands += [dor, lse_row, dterm_row]
+    operands += [kt, dot, lse_row, dterm_row]
 
     return pl.pallas_call(
         functools.partial(
@@ -1046,9 +1034,10 @@ def _dq_pallas(qr, kr, vr, dor, lse_row, dterm_row, qseg, kseg, seed, *,
         ),
         grid=(bh, sq // block_q, num_k_blocks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), qr.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        out_specs=pl.BlockSpec((1, d, block_q),
+                               lambda bh, qi, kj: (grad_row(bh), 0, qi)),
+        out_shape=jax.ShapeDtypeStruct((bh, d, sq), qt.dtype),
+        scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32)],
         compiler_params=pallas_tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
@@ -1056,15 +1045,17 @@ def _dq_pallas(qr, kr, vr, dor, lse_row, dterm_row, qseg, kseg, seed, *,
     )(*operands)
 
 
-def _dkv_pallas(qr, kr, vr, dor, lse_row, dterm_row, qseg, kseg, seed, *,
+def _dkv_pallas(qt, kr, vr, dot, lse_row, dterm_row, qseg, kseg, seed, *,
                 h, h_kv, causal, window, tiles, interpret, dropout_rate):
-    """dK/dV over folded operands, one grid row a KV head."""
+    """dK/dV over folded operands (:func:`_dq_pallas`'s), one grid row a
+    KV head. Returns dk and dv ``[h_kv·b, d, s]`` (:func:`_grad_row`)."""
     from jax.experimental.pallas import tpu as pltpu
 
     block_q, block_k = tiles[:2]
-    sq, d = qr.shape[1:]
+    d, sq = qt.shape[1:]
     bh_kv, sk, _ = kr.shape
     num_q_blocks = sq // block_q
+    grad_row = _grad_row(bh_kv // h_kv, h_kv)
     has_segments = qseg is not None
 
     # GQA-aware grid: one row per KV head; the innermost "arbitrary" dim
@@ -1084,20 +1075,19 @@ def _dkv_pallas(qr, kr, vr, dor, lse_row, dterm_row, qseg, kseg, seed, *,
                              num_q_blocks, causal, window)
 
     def q_index(g0, g1, g2):
-        return (q_row(g0, g2), q_blk(g1, g2), 0)
-
-    def q_row_index(g0, g1, g2):
         return (q_row(g0, g2), 0, q_blk(g1, g2))
 
+    def grad_index(g0, g1, g2):
+        return (grad_row(g0), 0, g1)
+
     in_specs = [
-        pl.BlockSpec((1, block_q, d), q_index),
+        pl.BlockSpec((1, d, block_q), q_index),
         pl.BlockSpec((1, block_k, d), lambda g0, g1, g2: (g0, g1, 0)),
         pl.BlockSpec((1, block_k, d), lambda g0, g1, g2: (g0, g1, 0)),
     ]
-    operands = [qr, kr, vr]
+    operands = [qt, kr, vr]
     if has_segments:
-        # Transposed layouts for the transposed kernel: qseg
-        # sublane-replicated row, kseg lane-replicated column. Batch
+        # qseg sublane-replicated row, kseg lane-replicated column. Batch
         # decodes from the kv-head-major grid row.
         in_specs += [
             pl.BlockSpec(
@@ -1114,11 +1104,11 @@ def _dkv_pallas(qr, kr, vr, dor, lse_row, dterm_row, qseg, kseg, seed, *,
         in_specs.append(_seed_spec())
         operands.append(_seed_operand(seed))
     in_specs += [
-        pl.BlockSpec((1, block_q, d), q_index),
-        pl.BlockSpec((1, _SUBLANES, block_q), q_row_index),
-        pl.BlockSpec((1, _SUBLANES, block_q), q_row_index),
+        pl.BlockSpec((1, d, block_q), q_index),
+        pl.BlockSpec((1, _SUBLANES, block_q), q_index),
+        pl.BlockSpec((1, _SUBLANES, block_q), q_index),
     ]
-    operands += [dor, lse_row, dterm_row]
+    operands += [dot, lse_row, dterm_row]
 
     return pl.pallas_call(
         functools.partial(
@@ -1138,16 +1128,16 @@ def _dkv_pallas(qr, kr, vr, dor, lse_row, dterm_row, qseg, kseg, seed, *,
         grid=(bh_kv, sk // block_k, total_q_iters),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda g0, g1, g2: (g0, g1, 0)),
-            pl.BlockSpec((1, block_k, d), lambda g0, g1, g2: (g0, g1, 0)),
+            pl.BlockSpec((1, d, block_k), grad_index),
+            pl.BlockSpec((1, d, block_k), grad_index),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh_kv, sk, d), kr.dtype),
-            jax.ShapeDtypeStruct((bh_kv, sk, d), vr.dtype),
+            jax.ShapeDtypeStruct((bh_kv, d, sk), kr.dtype),
+            jax.ShapeDtypeStruct((bh_kv, d, sk), vr.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((d, block_k), jnp.float32),
+            pltpu.VMEM((d, block_k), jnp.float32),
         ],
         compiler_params=pallas_tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -1173,21 +1163,20 @@ def _bwd_pallas(
     dterm = (delta - dlse.astype(jnp.float32)).reshape(b * h, sq)
 
     # Both backward kernels consume the sublane-replicated [bh, 8, s] row
-    # layout (the dq kernel transposes in-register) — the lane-replicated
-    # [bh, s, 128] f32 temporaries this used to materialize were 16× bigger
-    # (ADVICE r3 #2: multiple transient GB at 32k sequence length).
-    folded = (
-        _fold_q(q), _fold_heads(k), _fold_heads(v), _fold_heads(do),
-        _as_row(lse.reshape(b * h, sq)), _as_row(dterm), qseg, kseg, seed,
-    )
+    # layout — the lane-replicated [bh, s, 128] f32 temporaries this used
+    # to materialize were 16× bigger (ADVICE r3 #2: multiple transient GB
+    # at 32k sequence length).
+    qt, kr, vr, dot = _fold_q(q), _fold_rows(k), _fold_rows(v), _fold_cols(do)
+    rest = (_as_row(lse.reshape(b * h, sq)), _as_row(dterm), qseg, kseg, seed)
     static = dict(h=h, h_kv=h_kv, causal=causal, window=window,
                   interpret=interpret, dropout_rate=dropout_rate)
-    dq = _dq_pallas(*folded, tiles=tiles[1], **static)
-    dk, dv = _dkv_pallas(*folded, tiles=tiles[2], **static)
+    dq = _dq_pallas(qt, kr, vr, _fold_cols(k), dot, *rest, tiles=tiles[1],
+                    **static)
+    dk, dv = _dkv_pallas(qt, kr, vr, dot, *rest, tiles=tiles[2], **static)
     return (
-        _unfold_heads(dq, b, h),
-        _unfold_heads(dk, b, h_kv),
-        _unfold_heads(dv, b, h_kv),
+        _unfold_grad(dq, b, h),
+        _unfold_grad(dk, b, h_kv),
+        _unfold_grad(dv, b, h_kv),
     )
 
 

@@ -6,14 +6,25 @@
 Per shape, length and kernel (``fwd``, ``dq``, ``dkv``), as JSON rows:
 ``ms``, the time of a call on the device (a jitted ``fori_loop`` chains
 ``--reps`` calls, each output feeding the next call's same-shaped input,
-so nothing but the kernel and its head folds runs; host clock around the
-loop, best of three), and ``lower_s``, what ``jit(call).lower()`` takes in
-Python (tracing the kernel and lowering it from Pallas: paid at EVERY
-start of a program that holds the kernel, before its compile-cache key
-exists), and ``pairs_worked_pct``, the pairs the mask keeps over the pairs
-the kernel's walk multiplies (``_visited_pairs``: counted from the shapes,
-not measured; the most of its roofline the kernel can reach). A tile the
-chip's compiler refuses is reported as ``error``.
+so nothing but the kernel and the head folds of a row-major
+``[b, s, h, d]`` carry runs; host clock around the loop, best of three),
+``ms_in_program``, the call WITH the layout copies its wrapper implies
+where a model calls it: the same loop around the call jitted between a
+projection-shaped producer (``x @ W`` for Q, K, V, ``g @ Wo^T`` for dO)
+and consumer (``out @ Wo``; the weight-gradient matmuls ``x^T @ dq``),
+LESS the same loop with the call replaced by a stand-in that moves
+nothing (so the matmuls' own time cancels and XLA is free to hand each
+operand over in the layout its matmul writes: what is left is the kernel
+and every copy the wrapper's layout forces; ``--in-program 0`` skips it),
+``layout``, which layout each operand crosses HBM in (the checkout's own
+``_LAYOUTS``; a checkout from before PR 44 folds everything row-major but
+V and the output of the forward), ``lower_s``, what ``jit(call).lower()``
+takes in Python (tracing the kernel and lowering it from Pallas: paid at
+EVERY start of a program that holds the kernel, before its compile-cache
+key exists), and ``pairs_worked_pct``, the pairs the mask keeps over the
+pairs the kernel's walk multiplies (``_visited_pairs``: counted from the
+shapes, not measured; the most of its roofline the kernel can reach). A
+tile the chip's compiler refuses is reported as ``error``.
 
 The tile is the checkout's own (its ``_prepare``) unless ``--tiles`` gives
 candidates: ``block_q,block_k`` for any checkout, or
@@ -28,12 +39,14 @@ and dq and dkv are told apart by which gradient is kept (XLA drops the
 other kernel). The tile rule's table in ``ops/flash_attention.py`` is
 read off such sweeps.
 
-What this reads is a kernel ALONE, under the causal mask (and a window)
-and nothing else: a program that hands the kernels segment ids or
+What this reads is a kernel under the causal mask (and a window) and
+nothing else: a program that hands the kernels segment ids or
 dropout runs other bodies (until PR 42 ``TransformerLM`` handed them its
 causal mask as one segment id a token, and gpt2m-train's kernels read
-1.1 to 1.5 times these: PERF.md §6), so a candidate from here is
-confirmed by a traced run of the cell. On the CPU
+1.1 to 1.5 times these: PERF.md §6), and until PR 44 ``ms`` left out the
+seven head-fold copies a layer the program paid around the kernels (a
+tenth of gpt2m-train's step), so a candidate from here is read in
+``ms_in_program`` and confirmed by a traced run of the cell. On the CPU
 (``JAX_PLATFORMS=cpu``) the same loops run in interpret
 mode at ``--shapes tiny``: a rehearsal of the control flow, never a time.
 """
@@ -99,6 +112,65 @@ def _call(fa, kind, tile_args, window, interpret, causal=True):
     return run
 
 
+# What a checkout from before PR 44 (no ``_LAYOUTS``) hands its kernels.
+_LAYOUTS_BEFORE = {
+    "fwd": "q k [bh,s,d]; v out [bh,d,s]",
+    "dq": "q k v do dq [bh,s,d]",
+    "dkv": "q k v do dk dv [bh,s,d]",
+}
+
+
+def _in_program(fa, jnp, kind, tile_args, window, interpret, causal, kernel):
+    """``carry -> carry`` for one kernel of the checkout ``fa`` between a
+    projection-shaped producer and consumer; with ``kernel`` False, the
+    same with a stand-in for the call that moves nothing. The carry is
+    ``(x, g, wq, wk, wv, wo)``: activations ``[b, s, h * d]`` and weights
+    ``[h * d, heads, d]`` (``wo``: ``[h, d, h * d]``)."""
+    def project(x, w):
+        return jnp.einsum("bsm,mhd->bshd", x, w).astype(x.dtype)
+
+    def run(x, g, wq, wk, wv, wo):
+        q, k, v = project(x, wq), project(x, wk), project(x, wv)
+        if kind == "fwd":
+            out = q
+            if kernel:
+                out, _ = fa._fwd_pallas(q, k, v, None, None, None, causal,
+                                        window, *tile_args["fwd"], interpret,
+                                        0.0)
+            y = jnp.einsum("bshd,hdm->bsm", out, wo).astype(x.dtype)
+            return y, g, wq, wk, wv, wo
+        do = jnp.einsum("bsm,hdm->bshd", g, wo).astype(x.dtype)
+        dq, dk, dv = do, k, v
+        if kernel:
+            b, s, h, _ = q.shape
+            lse = jnp.full((b, h, s), 7.0, jnp.float32)
+            dq, dk, dv = fa._bwd_pallas(
+                q, k, v, None, None, None, q, lse, do, lse * 0.0, causal,
+                window, *tile_args["bwd"], interpret, 0.0)
+
+        def weight_grad(dy):
+            return jnp.einsum("bsm,bshd->mhd", x, dy).astype(x.dtype)
+
+        if kind == "dq":
+            return x, g, weight_grad(dq), wk, wv, wo
+        return x, g, wq, weight_grad(dk), weight_grad(dv), wo
+    return run
+
+
+def _program_inputs(jax, jnp, b, s, h, h_kv, d, dtype):
+    keys = jax.random.split(jax.random.PRNGKey(1), 6)
+    m = h * d
+
+    def normal(key, shape, scale):
+        return (scale * jax.random.normal(key, shape, jnp.float32)).astype(
+            dtype)
+
+    x, g = (normal(key, (b, s, m), 1.0) for key in keys[:2])
+    wq = normal(keys[2], (m, h, d), m ** -0.5)
+    wk, wv = (normal(key, (m, h_kv, d), m ** -0.5) for key in keys[3:5])
+    return x, g, wq, wk, wv, normal(keys[5], (h, d, m), m ** -0.5)
+
+
 def _tile_args(fa, data, tile, interpret):
     """How this checkout's ``_fwd_pallas`` / ``_bwd_pallas`` take a tile,
     and the tile as a row reports it."""
@@ -121,11 +193,13 @@ def _tile_args(fa, data, tile, interpret):
         ("fwd", "dq", "dkv"), tuple(tile))
 
 
-def _time(jax, fn, args, reps):
+def _time(jax, fn, args, reps, lower=True):
     """(seconds a call on the device, seconds ``lower()`` took)."""
-    start = time.perf_counter()
-    jax.jit(fn).lower(*args)
-    lower_s = time.perf_counter() - start
+    lower_s = None
+    if lower:
+        start = time.perf_counter()
+        jax.jit(fn).lower(*args)
+        lower_s = time.perf_counter() - start
     loop = jax.jit(lambda *a: jax.lax.fori_loop(
         0, reps, lambda _, carry: fn(*carry), a))
     jax.block_until_ready(loop(*args))  # compile, warm up
@@ -151,6 +225,8 @@ def main(argv=None) -> int:
     ap.add_argument("--strips", default=None,
                     help="strip counts of a cut sub-tile, comma-separated "
                     "(default: the checkout's own)")
+    ap.add_argument("--in-program", type=int, default=1,
+                    help="0: skip ms_in_program (two more compiles a row)")
     ap.add_argument("--tree", default=None,
                     help="time this checkout's kernels (default: this one's)")
     ap.add_argument("--out", default="chiprun_out/flash_sweep.json")
@@ -171,6 +247,7 @@ def main(argv=None) -> int:
     candidates = [None] if args.tiles is None else [
         tuple(int(x) for x in t.split(",")) for t in args.tiles.split(";")]
     only = args.lengths and {int(x) for x in args.lengths.split(",")}
+    layouts = getattr(fa, "_LAYOUTS", _LAYOUTS_BEFORE)
     strip_counts = [getattr(fa, "_STRIPS", None)] if args.strips is None else [
         int(x) for x in args.strips.split(",")]
     rows = []
@@ -181,6 +258,11 @@ def main(argv=None) -> int:
                 continue
             data = _inputs(jax, jnp, b, s, h, h_kv, d, jnp.dtype(args.dtype),
                            *q_len)
+            # One query row against a cache has no projection of its own
+            # length to sit between.
+            between = None if q_len or not args.in_program else (
+                _program_inputs(jax, jnp, b, s, h, h_kv, d,
+                                jnp.dtype(args.dtype)))
             for tile in candidates:
                 if tile is not None:
                     tile = tuple(x or s for x in tile)  # 0: the length
@@ -194,6 +276,7 @@ def main(argv=None) -> int:
                         continue
                     row = dict(shape=name, dtype=args.dtype, seq=s,
                                kernel=kind, tile=list(shown[kind]),
+                               layout=layouts[kind],
                                tree=os.path.relpath(tree, here))
                     if strips is not None:
                         # Read while a kernel is traced: each row's
@@ -209,6 +292,14 @@ def main(argv=None) -> int:
                                        interpret, causal=not q_len),
                             data, args.reps)
                         row["ms"] = 1e3 * sec
+                        if between is not None:
+                            with_call, without = (_time(
+                                jax, _in_program(fa, jnp, kind, tile_args,
+                                                 window, interpret, True,
+                                                 kernel),
+                                between, args.reps, lower=False)[0]
+                                for kernel in (True, False))
+                            row["ms_in_program"] = 1e3 * (with_call - without)
                     except Exception as exc:  # the compiler's refusal
                         row["error"] = f"{type(exc).__name__}: " + str(
                             exc).strip().splitlines()[0][:160]

@@ -53,12 +53,14 @@ def _dense(q, k, v, *, causal, window, qseg, kseg, dropout, seed):
     return jnp.einsum("bhqk,bkhd->bqhd", p, vf), lse
 
 
-def _segments(s):
-    """Three documents and a padded tail, none on a sub-tile's edge."""
-    ids = np.zeros((1, s), np.int32)
-    ids[0, : s // 3 + 5] = 1
-    ids[0, s // 3 + 5: s // 2 + 9] = 2
-    ids[0, s // 2 + 9: s - 11] = 3
+def _segments(s, b=1):
+    """Three documents and a padded tail, none on a sub-tile's edge; each
+    further batch row's edges 17 positions later."""
+    ids = np.zeros((b, s), np.int32)
+    for i in range(b):
+        ids[i, : s // 3 + 5 + 17 * i] = 1
+        ids[i, s // 3 + 5 + 17 * i: s // 2 + 9 + 17 * i] = 2
+        ids[i, s // 2 + 9 + 17 * i: s - 11] = 3
     return jnp.asarray(ids)
 
 
@@ -72,8 +74,27 @@ def _segments(s):
 # ``stair_*`` cases are the benchmark cells' shapes through the rule
 # (bfloat16: 512-sub-tiles; float32: the 512 x 1,024 pair, square where
 # 1,024 does not divide the length), the ``generic_*`` ones 512-sub-tiles
-# that a window, segments, dropout or an unequal pair keeps off it.
+# that a window, segments, dropout or an unequal pair keeps off it. The
+# ``layout_*`` cases have a batch (``b``) over one: Q, dO and the three
+# gradients cross HBM as ``[rows, d, s]`` and a gradient's rows lie heads
+# outermost (``_grad_row``), which a batch of one cannot tell from the
+# operands' batch-outermost order; head widths 64 and 128, grouped heads,
+# a window, segment ids (a batch row's own) and kernel dropout (the hash
+# is keyed by the folded QUERY row, which the dkv kernel rebuilds).
 _CASES = {
+    "layout_b2_d64": (512, 4, 4, dict(causal=True, d=64, b=2), None),
+    "layout_b3_8_over_2_d128": (512, 8, 2, dict(causal=True, d=128, b=3),
+                                None),
+    "layout_b2_window_d64": (768, 4, 2, dict(causal=True, window=200, d=64,
+                                             b=2), (384, 768, 384, 256)),
+    "layout_b3_segments_d64": (768, 4, 2, dict(causal=True, segments=True,
+                                               d=64, b=3),
+                               (768, 384, 384, 128)),
+    "layout_b2_dropout_d128": (512, 4, 2, dict(causal=True, dropout=0.1,
+                                               d=128, b=2),
+                               (256, 512, 256, 256)),
+    "layout_b2_full_d64": (512, 2, 1, dict(causal=False, d=64, b=2),
+                           (256, 256, 256, 128)),
     "stair_512": (512, 2, 2, dict(causal=True, stair=(True, True)), None),
     "stair_1536_d64": (1536, 2, 2, dict(causal=True, d=64,
                                         stair=(True, True)), None),
@@ -133,7 +154,8 @@ def test_sub_tiled_kernels_match_dense(case, dtype):
     causal = kwargs.pop("causal")
     window = kwargs.pop("window", None)
     dropout = kwargs.pop("dropout", 0.0)
-    seg = _segments(s) if kwargs.pop("segments", False) else None
+    b = kwargs.pop("b", 1)
+    seg = _segments(s, b) if kwargs.pop("segments", False) else None
     d, seed = kwargs.pop("d", 32), 77
     stair = kwargs.pop("stair", None)
     if stair is not None:
@@ -143,10 +165,10 @@ def test_sub_tiled_kernels_match_dense(case, dtype):
         assert strips == (
             fa._STRIPS if stair[dtype == jnp.bfloat16] else 0)
     keys = jax.random.split(jax.random.PRNGKey(s + h), 4)
-    q = jax.random.normal(keys[0], (1, s, h, d), jnp.float32).astype(dtype)
-    k = jax.random.normal(keys[1], (1, s, h_kv, d), jnp.float32).astype(dtype)
-    v = jax.random.normal(keys[2], (1, s, h_kv, d), jnp.float32).astype(dtype)
-    w = jax.random.normal(keys[3], (1, s, h, d), jnp.float32)
+    q = jax.random.normal(keys[0], (b, s, h, d), jnp.float32).astype(dtype)
+    k = jax.random.normal(keys[1], (b, s, h_kv, d), jnp.float32).astype(dtype)
+    v = jax.random.normal(keys[2], (b, s, h_kv, d), jnp.float32).astype(dtype)
+    w = jax.random.normal(keys[3], (b, s, h, d), jnp.float32)
 
     def flash(q, k, v):
         if tiles is None:
@@ -187,6 +209,43 @@ def test_sub_tiled_kernels_match_dense(case, dtype):
             np.asarray(g, np.float32) / scale,
             np.asarray(g_d, np.float32) / scale, atol=tol,
             err_msg=f"d{name}")
+
+
+# The forward with keys and values of their own widths (latent attention's
+# un-absorbed prefill: keys of 192, values of 128), forward only: name ->
+# (batch, length, heads, kv heads, window, tiles or None).
+_WIDTHS = {
+    "rule_1024": (1, 1024, 2, 2, None, None),
+    "b2_grouped_512": (2, 512, 4, 2, None, None),
+    "b2_window_768": (2, 768, 2, 1, 300, (384, 768, 384, 256)),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(_WIDTHS))
+def test_forward_with_keys_of_192_and_values_of_128_matches_dense(case, dtype):
+    b, s, h, h_kv, window, tiles = _WIDTHS[case]
+    keys = jax.random.split(jax.random.PRNGKey(s + b), 3)
+    q = jax.random.normal(keys[0], (b, s, h, 192), jnp.float32).astype(dtype)
+    k = jax.random.normal(keys[1], (b, s, h_kv, 192),
+                          jnp.float32).astype(dtype)
+    v = jax.random.normal(keys[2], (b, s, h_kv, 128),
+                          jnp.float32).astype(dtype)
+    if tiles is None:
+        out, lse = fa.flash_attention_with_lse(q, k, v, causal=True,
+                                               window=window)
+    else:
+        out, lse = fa._flash(q, k, v, None, None, None, True, window,
+                             (tiles,) * 3, True, 0.0)
+    out_d, lse_d = _dense(q, k, v, causal=True, window=window, qseg=None,
+                          kseg=None, dropout=0.0, seed=0)
+    assert out.shape == (b, s, h, 128) and out.dtype == dtype
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(out_d), atol=tol)
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(lse_d), atol=tol, rtol=1e-6)
 
 
 @pytest.mark.parametrize("tiles", [(256, 256, 256, 256), (512, 512, 256, 256),
@@ -396,3 +455,30 @@ def test_forward_kernel_does_not_grow_with_the_sequence(window):
     # ... and to less than twice the one-body kernel of float32 operands,
     # whose blocks are worked whole.
     assert at_1024 < 2 * _forward_equations(1024, window, jnp.float32)
+
+
+def test_the_sweep_script_says_what_layout_it_timed(tmp_path):
+    """``scripts/flash_sweep.py`` on the CPU (a rehearsal, never a time):
+    every row names the layout its kernel's operands cross HBM in, and
+    times the call between a projection-shaped producer and consumer
+    too, where the layout copies its wrapper implies show."""
+    import json
+    import os
+    import sys
+
+    scripts = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts")
+    sys.path.insert(0, scripts)
+    try:
+        sweep = importlib.import_module("flash_sweep")
+    finally:
+        sys.path.remove(scripts)
+    out = tmp_path / "sweep.json"
+    assert sweep.main(["--shapes", "tiny-causal", "--reps", "1",
+                       "--out", str(out)]) == 0
+    rows = json.loads(out.read_text())["rows"]
+    assert [row["kernel"] for row in rows] == ["fwd", "dq", "dkv"]
+    for row in rows:
+        assert "error" not in row, row
+        assert row["layout"] == fa._LAYOUTS[row["kernel"]]
+        assert row["ms"] > 0 and "ms_in_program" in row

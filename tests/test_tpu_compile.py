@@ -58,6 +58,32 @@ def _sds(shape, dtype, device):
     )
 
 
+def _layout_copies(text, b, h, s, d):
+    """``copy`` instructions of a compiled program that produce a bfloat16
+    array of a head fold's shape (the dimensions ``b, h, s, d`` in any
+    order and any layout, dimensions of one dropped, batch and heads
+    perhaps one dimension): what XLA inserts where a producer cannot
+    write, or a consumer read, the layout a Mosaic kernel's operand is
+    fixed to (docs/gotchas.md, "Counting layout copies")."""
+    folds = {tuple(sorted(x for x in dims if x != 1))
+             for dims in ((b, h, s, d), (b * h, s, d))}
+    shapes = re.findall(r"%copy[.\d]* = bf16\[([\d,]+)\]\{", text)
+    return sum(
+        tuple(sorted(int(x) for x in shape.split(",") if x != "1")) in folds
+        for shape in shapes)
+
+
+# Programs more than one test reads, compiled once a session (every test
+# of this file runs in one process).
+_COMPILED = {}
+
+
+def _once(name, build):
+    if name not in _COMPILED:
+        _COMPILED[name] = build()
+    return _COMPILED[name]
+
+
 # (b, s, h, d) = (8, 1024, 12, 64): GPT-2 small's attention at the
 # smoke's batch. name -> (h_kv, flash_attention keyword arguments).
 _VARIANTS = {
@@ -283,16 +309,16 @@ def test_expert_combine_kernel_compiles_for_v5e(topo, as_on_tpu, tokens,
     assert compiled.memory_analysis().temp_size_in_bytes < 2**21
 
 
-def test_gpt2_medium_serving_programs_compile_for_v5e(topo, as_on_tpu):
-    """The serve cell's decode program and a prefill bucket — GPT-2
-    medium, 32 slots x 1,024 positions in 128-token blocks, bf16 pools
-    of 1.6 GB each — hold one paged kernel a layer and no copy of a pool
-    or of a slot's reserved cache (the programs they replace held 15.3 GB
-    and 3.3 GB of temporaries)."""
+_GPT2_MEDIUM = {**chip_smoke.GPT2_SMALL, "num_layers": 24, "d_model": 1024,
+                "num_heads": 16, "d_ff": 4096}
+
+
+def _gpt2_medium_serving_programs(topo):
+    """``(decode, prefill)`` of the ``gpt2m-serve`` cell's engine, the
+    prefill at its 256-token bucket."""
     from fluxmpi_tpu.serving import InferenceEngine
 
-    cfg = {**chip_smoke.GPT2_SMALL, "num_layers": 24, "d_model": 1024,
-           "num_heads": 16, "d_ff": 4096}
+    cfg = _GPT2_MEDIUM
     dev = topo.devices[0]
     model = chip_smoke._lm(cfg)
     params = jax.tree_util.tree_map(
@@ -320,6 +346,18 @@ def test_gpt2_medium_serving_programs_compile_for_v5e(topo, as_on_tpu):
         ).compile()
     finally:
         engine.close()
+    return decode, prefill
+
+
+def test_gpt2_medium_serving_programs_compile_for_v5e(topo, as_on_tpu):
+    """The serve cell's decode program and a prefill bucket — GPT-2
+    medium, 32 slots x 1,024 positions in 128-token blocks, bf16 pools
+    of 1.6 GB each — hold one paged kernel a layer and no copy of a pool
+    or of a slot's reserved cache (the programs they replace held 15.3 GB
+    and 3.3 GB of temporaries)."""
+    cfg = _GPT2_MEDIUM
+    decode, prefill = _once(
+        "gpt2m-serve", lambda: _gpt2_medium_serving_programs(topo))
     assert decode.as_text().count("tpu_custom_call") == cfg["num_layers"]
     # Each weight is prefetched whole (the compiler's default cuts it in
     # four): a tick launches 1,352 operations where it launched 2,198.
@@ -330,14 +368,9 @@ def test_gpt2_medium_serving_programs_compile_for_v5e(topo, as_on_tpu):
         assert memory.alias_size_in_bytes >= 2 * 1.6e9  # pools in place
 
 
-def test_trinity_mini_serving_programs_compile_for_v5e(topo, as_on_tpu):
-    """The ``trinity-mini-serve`` cell's decode program and one prefill
-    bucket at the published widths (32 query over 4 K/V heads of 128, 128
-    experts top-8, vocabulary 200,192; 64 slots x 8,704 positions in
-    512-token blocks): one paged kernel a layer, grouped and windowed,
-    the grouped matmul's kernel (``ops/grouped_matmul.py``) three times an
-    expert layer, the ring and the full pool updated in place, and
-    bfloat16 weights of 8.5 GB beside them within one chip."""
+def _trinity_mini_serving_programs(topo):
+    """``(decode, prefill, pool bytes)`` of the ``trinity-mini-serve``
+    cell's engine, the prefill at its 2,560-token bucket."""
     import importlib.util
     import json
 
@@ -391,6 +424,19 @@ def test_trinity_mini_serving_programs_compile_for_v5e(topo, as_on_tpu):
         ).compile()
     finally:
         engine.close()
+    return decode, prefill, cache.pool_bytes
+
+
+def test_trinity_mini_serving_programs_compile_for_v5e(topo, as_on_tpu):
+    """The ``trinity-mini-serve`` cell's decode program and one prefill
+    bucket at the published widths (32 query over 4 K/V heads of 128, 128
+    experts top-8, vocabulary 200,192; 64 slots x 8,704 positions in
+    512-token blocks): one paged kernel a layer, grouped and windowed,
+    the grouped matmul's kernel (``ops/grouped_matmul.py``) three times an
+    expert layer, the ring and the full pool updated in place, and
+    bfloat16 weights of 8.5 GB beside them within one chip."""
+    decode, prefill, pool_bytes = _once(
+        "trinity-mini-serve", lambda: _trinity_mini_serving_programs(topo))
     # 5 paged kernels; 4 expert layers x 3 grouped matmuls, each named
     # as the benchmark's readers find XLA's own (``^ragged-dot``).
     text = decode.as_text()
@@ -402,9 +448,32 @@ def test_trinity_mini_serving_programs_compile_for_v5e(topo, as_on_tpu):
     for program, temporaries in ((decode, 2**27), (prefill, 2**30)):
         memory = program.memory_analysis()
         assert memory.temp_size_in_bytes < temporaries
-        assert memory.alias_size_in_bytes >= cache.pool_bytes  # in place
+        assert memory.alias_size_in_bytes >= pool_bytes  # in place
         assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
                 < 14e9)
+
+
+@pytest.mark.parametrize("cell", ["gpt2m-serve", "trinity-mini-serve"])
+def test_serve_prefill_folds_no_query_for_v5e(topo, as_on_tpu, cell):
+    """A prefill hands the flash forward its queries as the projection's
+    matmul writes them: no ``copy`` makes a head-shaped array of them,
+    whatever the heads (16 of 64; 32 query over 4 K/V heads of 128 with a
+    window, after the rotation), and at most one a layer of the keys,
+    which the kernel reads row-major (GPT-2's keys have the queries'
+    shape: one a layer for both)."""
+    if cell == "gpt2m-serve":
+        prefill = _once(
+            cell, lambda: _gpt2_medium_serving_programs(topo))[1]
+        layers, heads, kv_heads, s, d = 24, 16, 16, 256, 64
+    else:
+        prefill = _once(
+            cell, lambda: _trinity_mini_serving_programs(topo))[1]
+        layers, heads, kv_heads, s, d = 5, 32, 4, 2560, 128
+    text = prefill.as_text()
+    assert text.count("tpu_custom_call") >= layers  # the flash forward
+    if heads != kv_heads:
+        assert _layout_copies(text, 1, heads, s, d) == 0
+    assert _layout_copies(text, 1, kv_heads, s, d) <= layers
 
 
 def _load_config_module(name):
@@ -1039,6 +1108,38 @@ def test_gpt2_small_train_step_compiles_for_v5e(topo, as_on_tpu):
     # Forward, dq and dkv for each of the 12 layers.
     assert compiled.as_text().count("tpu_custom_call") == 36
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2**30
+
+
+def test_gpt2_medium_train_step_folds_heads_without_copies_for_v5e(
+        topo, as_on_tpu):
+    """The ``gpt2m-train`` cell's own step (GPT-2 medium through
+    ``benchmarks/configs/gpt2.program.py``, AdamW, 8 x 1,024 tokens):
+    three kernels a layer, and of the seven ``copy`` instructions a layer
+    that folded Q, K, V, dO, dq, dk and dv row-major for them (5.7% of
+    the step, PERF.md §6, PR 44) at most two: K for the forward, V for
+    the backward."""
+    import json
+
+    prog, configs = _load_config_module("gpt2.program.py")
+    with open(os.path.join(configs, "gpt2-medium.json"),
+              encoding="utf-8") as f:
+        cfg = json.load(f)
+    model = prog.build_model(cfg)
+    optimizer = prog.make_optimizer({"learning_rate": 1e-4})
+    params = jax.eval_shape(
+        lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32), train=False),
+        jax.random.PRNGKey(0),
+    )
+    state = jax.eval_shape(lambda p: TrainState.create(p, optimizer), params)
+    tokens = jax.ShapeDtypeStruct((8, cfg["n_positions"]), jnp.int32)
+    mesh = Mesh(np.asarray(topo.devices[:1]), (config.DP_AXIS_NAME,))
+    step = make_train_step(prog.make_loss(model), optimizer, mesh=mesh)
+    text = step.lower(state, (tokens, tokens)).compile().as_text()
+    layers, heads = cfg["n_layer"], cfg["n_head"]
+    assert text.count("tpu_custom_call") == 3 * layers
+    copies = _layout_copies(
+        text, 8, heads, cfg["n_positions"], cfg["n_embd"] // heads)
+    assert copies <= 2 * layers
 
 
 def test_dp4_step_compiles_for_v5e(topo, as_on_tpu):
