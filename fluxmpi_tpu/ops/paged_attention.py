@@ -28,18 +28,35 @@ Contract (shared by the kernel and its plain reference,
   overwrites what the window has left behind (the ring must hold
   ``window + block_size`` positions). Only the blocks that meet the
   window are visited; the first one's head is masked by position.
-- Blocks at or past ``ceil(length / block_size)`` do no work: their grid
-  steps neither compute nor fetch (the index map holds the last live
-  block, which the pipeline does not fetch twice). The tail of the last
-  live block is masked by position. So whatever the table's unused
-  entries point at (the trash block) and whatever finite garbage lies
-  past a length never reaches the output.
-- A slot of length 0 (an idle slot: all-trash table) outputs zeros.
+- The kernel WALKS THE LIVE BLOCKS ONLY (:func:`live_block_walk`): one
+  flat list of visits ``(slot, pool block, block index)``, every live
+  slot's live blocks in order (a window layer's: those that meet the
+  window), scalar prefetched, under a grid bound that is the list's
+  COUNT, so a call's steps follow what is live and not ``slots x table
+  width`` (a step that neither computed nor fetched still cost ~0.19 us:
+  4,000 of them a tick were a seventh of it). The K/V index map reads
+  the visit's block, so the pipeline fetches the next slot's first block
+  behind a slot's last; a slot's first visit resets the statistics, its
+  last writes its rows. The tail of the last live block is masked by
+  position. So whatever the table's unused entries point at (the trash
+  block) and whatever finite garbage lies past a length never reaches
+  the output, and an all-trash table is never visited (where NOTHING is
+  live the call's one step fetches the first table's first entry, the
+  trash block, and computes nothing). The list is made
+  on the device from ``lengths`` (and the window): once a call, or once
+  a tick for every layer that shares the table where the caller hands
+  it in (``walk=``). At full tables the walk is the old grid. Pallas
+  interpret mode takes no dynamic grid bound: there the grid is the
+  list's whole length and the steps past the count do nothing.
+- A slot of length 0 (an idle slot: all-trash table) outputs zeros: the
+  output is ONE block, zeroed by the call's first step and written back
+  once, and no visit names a dead slot (where nothing is live the call
+  is that one step).
 - Softmax statistics and accumulators are float32 over operands in the
   pool's dtype, as in :mod:`fluxmpi_tpu.ops.flash_attention`; the output
   has ``q``'s dtype.
 
-All heads of a slot share one grid step. The per-head products come out
+All heads of a slot share one visit. The per-head products come out
 of two plain matmuls over the folded ``kv_heads * head_dim`` lanes: the
 query rows are laid out block-diagonally (``[heads, kv_heads *
 head_dim]``, head ``h``'s query in its K/V head's lanes, zeros
@@ -78,8 +95,9 @@ from jax.experimental import pallas as pl
 from ..parallel._compat import pallas_tpu_compiler_params
 from .flash_attention import _LANES, _NEG_INF, _SUBLANES
 
-__all__ = ["paged_decode_attention", "paged_decode_reference",
-           "paged_latent_decode_attention", "paged_latent_decode_reference"]
+__all__ = ["live_block_walk", "paged_decode_attention",
+           "paged_decode_reference", "paged_latent_decode_attention",
+           "paged_latent_decode_reference"]
 
 # The chip's compiler names a Mosaic call's instruction by the last
 # component of its path: the latent kernel's jitted wrapper and its
@@ -88,24 +106,116 @@ __all__ = ["paged_decode_attention", "paged_decode_reference",
 _LATENT_KERNEL_NAME = "paged_latent_decode"
 
 
+def live_block_walk(tables, lengths, *, window: int | None = None,
+                    block_size: int):
+    """The visits one decode kernel call makes, in order: ``(slot, block,
+    index, count)``, the first three ``[slots * tables.shape[1]]`` int32
+    (what a call over full tables walks), ``count`` ``[1]``. Visit ``i <
+    count[0]`` reads pool block ``block[i]``, which holds positions
+    ``index[i] * block_size ...`` of slot ``slot[i]``: every slot's live
+    blocks (with ``window``: those that meet it, found on the ring), slot
+    by slot, first block first. Past ``count`` the arrays hold the last
+    visit (all 0, and the table's first entry, where nothing is live).
+    One call a tick serves every layer that shares the table."""
+    slots, num_j = tables.shape
+    tables, lengths = tables.astype(jnp.int32), lengths.astype(jnp.int32)
+    with jax.named_scope("kv_walk"):
+        first = _first_block(lengths, block_size, window)
+        visits = -(-lengths // block_size) - first  # a slot's; 0: idle
+        ends = jnp.cumsum(visits)
+        starts, count = ends - visits, ends[-1]
+        at = jnp.minimum(jnp.arange(slots * num_j, dtype=jnp.int32),
+                         jnp.maximum(count - 1, 0))[:, None]
+        # The one slot whose visits hold ``at`` (none where nothing is
+        # live: slot 0, index 0), and what the visit reads of it.
+        mine = (starts[None, :] <= at) & (at < ends[None, :])
+
+        def of_slot(values):
+            return jnp.sum(jnp.where(mine, values[None, :], 0), axis=1)
+
+        slot = of_slot(jnp.arange(slots, dtype=jnp.int32))
+        index = of_slot(first - starts) + at[:, 0]
+        # A window's table is a ring.
+        block = tables.reshape(-1)[slot * num_j + index % num_j]
+        return slot, block, index, count[None]
+
+
+def _first_block(length, block_size, window):
+    """The first block a slot of ``length`` positions attends."""
+    if window is None:
+        return jnp.zeros_like(length)
+    return jnp.maximum(length - window, 0) // block_size
+
+
+def _online_softmax(s, live, m_scratch, l_scratch):
+    """One block's scores ``s`` ``[rows, block_size]`` (``live``: the
+    positions that count) into the running maximum and sum; returns the
+    block's weights ``p`` and the factor ``[rows, 1]`` that carries the
+    accumulator over to the new maximum."""
+    s = jnp.where(live, s, _NEG_INF)
+    m_prev = m_scratch[...]  # [rows, 128], value replicated over lanes
+    m_new = jnp.maximum(
+        m_prev, jnp.broadcast_to(jnp.max(s, axis=1, keepdims=True),
+                                 m_prev.shape))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(live, jnp.exp(s - m_new[:, :1]), 0.0)
+    l_scratch[...] = l_scratch[...] * alpha + jnp.broadcast_to(
+        jnp.sum(p, axis=1, keepdims=True), alpha.shape)
+    m_scratch[...] = m_new
+    return p, alpha[:, :1]
+
+
+def _visit(slot_ref, index_ref, count_ref, lengths_ref, o_ref, scratch,
+           block_size, window, attend, result):
+    """One grid step of a walk (:func:`live_block_walk`): ``attend(base,
+    length)`` folds the visited block, whose first position is ``base``,
+    into the ``scratch`` statistics and accumulator, which a slot's
+    first visit resets and after whose last ``result()`` is written to
+    the slot's rows of ``o_ref``. ``o_ref`` is the WHOLE
+    output, zeroed by the first step: a slot no visit names keeps
+    zeros."""
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _zero():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    # Interpret mode takes no dynamic grid bound: there the grid is the
+    # walk's whole length, and the steps past the count do nothing.
+    @pl.when(i < count_ref[0])
+    def _live():
+        slot = slot_ref[i]
+        length = lengths_ref[slot]
+        base = index_ref[i] * block_size
+        m_scratch, l_scratch, acc_scratch = scratch
+
+        @pl.when(index_ref[i] == _first_block(length, block_size, window))
+        def _init():
+            m_scratch[...] = jnp.full_like(m_scratch, _NEG_INF)
+            l_scratch[...] = jnp.zeros_like(l_scratch)
+            acc_scratch[...] = jnp.zeros_like(acc_scratch)
+
+        attend(base, length)
+
+        @pl.when(base + block_size >= length)
+        def _finish():
+            o_ref[slot] = result().astype(o_ref.dtype)
+
+
+def _normalised(l_scratch, acc_scratch):
+    l_final = l_scratch[...][:, :1]
+    return acc_scratch[...] / jnp.where(l_final == 0.0, 1.0, l_final)
+
+
 def _paged_decode_kernel(
-    tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
-    m_scratch, l_scratch, acc_scratch,
-    *, sm_scale: float, head_dim: int, block_size: int, num_j: int,
-    group: int, window: int | None,
+    slot_ref, block_ref, index_ref, count_ref, lengths_ref,
+    q_ref, k_ref, v_ref, o_ref, m_scratch, l_scratch, acc_scratch,
+    *, sm_scale: float, head_dim: int, block_size: int, group: int,
+    window: int | None,
 ):
-    del tables_ref  # read by the index maps
-    slot = pl.program_id(0)
-    j = pl.program_id(1)
-    length = lengths_ref[slot]
+    del block_ref  # read by the index maps
     # heads (padded to whole sublane tiles), kv_heads * head_dim
     rows, width = acc_scratch.shape
-    # The first position of the block this step visits: block j of the
-    # table, or the j-th block that meets the window.
-    if window is None:
-        base = j * block_size
-    else:
-        base = (jnp.maximum(length - window, 0) // block_size + j) * block_size
 
     def own_lanes():
         # own[h, c]: lane c of the folded minor dimension belongs to the
@@ -116,15 +226,8 @@ def _paged_decode_kernel(
         lane = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
         return (lane >= head * head_dim) & (lane < (head + 1) * head_dim)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scratch[...] = jnp.full_like(m_scratch, _NEG_INF)
-        l_scratch[...] = jnp.zeros_like(l_scratch)
-        acc_scratch[...] = jnp.zeros_like(acc_scratch)
-
-    @pl.when(base < length)
-    def _compute():
-        k = k_ref[...]  # [block_size, width]
+    def attend(base, length):
+        k = k_ref[...]  # [block_size, width]; q_ref: the visit's slot's
         q = q_ref[0].astype(jnp.float32)
         if group > 1:
             # [rows, head_dim] -> every K/V head's lanes hold the row.
@@ -140,39 +243,26 @@ def _paged_decode_kernel(
         live = pos < length
         if window is not None:
             live &= pos >= length - window
-        s = jnp.where(live, s, _NEG_INF)
-
-        m_prev = m_scratch[...]  # [rows, 128], value replicated over lanes
-        l_prev = l_scratch[...]
-        m_cur = jnp.broadcast_to(
-            jnp.max(s, axis=1, keepdims=True), m_prev.shape
-        )
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(live, jnp.exp(s - m_new[:, :1]), 0.0)
-        l_scratch[...] = l_prev * alpha + jnp.broadcast_to(
-            jnp.sum(p, axis=1, keepdims=True), l_prev.shape
-        )
+        p, alpha = _online_softmax(s, live, m_scratch, l_scratch)
         # [rows, width]: every head's values; each keeps its own lanes.
         pv = jax.lax.dot_general(
             p, v_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        acc_scratch[...] = acc_scratch[...] * alpha[:, :1] + pv
-        m_scratch[...] = m_new
+        acc_scratch[...] = acc_scratch[...] * alpha + pv
 
-    @pl.when(j == num_j - 1)
-    def _finish():
-        l_final = l_scratch[...][:, :1]
-        l_safe = jnp.where(l_final == 0.0, 1.0, l_final)
-        out = jnp.where(own_lanes(), acc_scratch[...] / l_safe, 0.0)
+    def result():
+        out = jnp.where(own_lanes(), _normalised(l_scratch, acc_scratch), 0.0)
         if group > 1:
             # Row h keeps its K/V head's head_dim lanes: [rows, head_dim].
-            o_ref[0] = sum(
+            return sum(
                 out[:, c:c + head_dim] for c in range(0, width, head_dim)
-            ).astype(o_ref.dtype)
-        else:
-            o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+            )
+        return jnp.sum(out, axis=0, keepdims=True)
+
+    _visit(slot_ref, index_ref, count_ref, lengths_ref, o_ref,
+           (m_scratch, l_scratch, acc_scratch), block_size, window,
+           attend, result)
 
 
 def _check_shapes(q, k_pool, v_pool, tables, lengths, layer, window):
@@ -211,6 +301,56 @@ def _check_shapes(q, k_pool, v_pool, tables, lengths, layer, window):
     return heads // kv_heads
 
 
+def _walk_call(kernel, walk, lengths, operands, in_specs, out_shape,
+               scratch, interpret, **kwargs):
+    """``kernel`` once a visit of ``walk`` (:func:`live_block_walk`; one
+    step where nothing is live: the output is zeroed there). The walk's
+    four arrays and ``lengths`` are scalar prefetched ahead of
+    ``operands``, so an index map of ``in_specs`` sees ``(i, slot, block,
+    index, count, lengths)``. The output is ONE block, written back once.
+    ``scratch``: the rows and lanes of the float32 accumulator, under the
+    running maximum and sum."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    slot, block, index, count = walk
+    rows, lanes = scratch
+    # The chip walks ``count`` steps; interpret mode takes no dynamic
+    # bound and walks the whole list.
+    steps = slot.shape[0] if interpret else jnp.maximum(count[0], 1)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(steps,),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                out_shape.shape, lambda i, *_: (0,) * len(out_shape.shape)),
+            scratch_shapes=[
+                pltpu.VMEM((rows, _LANES), jnp.float32),
+                pltpu.VMEM((rows, _LANES), jnp.float32),
+                pltpu.VMEM((rows, lanes), jnp.float32),
+            ],
+        ),
+        out_shape=out_shape,
+        compiler_params=pallas_tpu_compiler_params(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+        **kwargs,
+    )(slot, block, index, count, lengths.astype(jnp.int32), *operands)
+
+
+def _visited_rows(i, slot_ref, *_):
+    return slot_ref[i], 0, 0
+
+
+def _visited_block(layer, block_shape):
+    """The pool block a visit reads, of ``layer``."""
+    return pl.BlockSpec(
+        (None, None, *block_shape),
+        lambda i, slot_ref, block_ref, *_: (layer, block_ref[i], 0, 0))
+
+
 @functools.partial(jax.jit, static_argnames=("layer", "window", "interpret"))
 def paged_decode_attention(
     q: jnp.ndarray,
@@ -221,70 +361,44 @@ def paged_decode_attention(
     *,
     layer: int = 0,
     window: int | None = None,
+    walk=None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """One query row per slot against its paged cache; see the module
     docstring for the contract. Returns ``[slots, heads, head_dim]`` in
-    ``q``'s dtype. ``interpret=None`` runs the compiled kernel on a TPU
-    backend and Pallas interpret mode elsewhere."""
-    from jax.experimental.pallas import tpu as pltpu
-
+    ``q``'s dtype. ``walk``: :func:`live_block_walk` of ``tables``,
+    ``lengths`` and ``window`` where the caller has it (one call a tick
+    for all layers that share the table). ``interpret=None`` runs the
+    compiled kernel on a TPU backend and Pallas interpret mode
+    elsewhere."""
     group = _check_shapes(q, k_pool, v_pool, tables, lengths, layer, window)
     slots, heads, head_dim = q.shape
     _, _, block_size, width = k_pool.shape
-    num_j = tables.shape[1]
     rows = -(-heads // _SUBLANES) * _SUBLANES
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if walk is None:
+        walk = live_block_walk(tables, lengths, window=window,
+                               block_size=block_size)
 
-    def kv_index(slot, j, tables_ref, lengths_ref):
-        # Past the live blocks the index stays on the last live one: an
-        # unchanged block index is not fetched again.
-        length = lengths_ref[slot]
-        last = jnp.maximum((length + block_size - 1) // block_size - 1, 0)
-        if window is None:
-            return layer, tables_ref[slot, jnp.minimum(j, last)], 0, 0
-        first = jnp.maximum(length - window, 0) // block_size
-        entry = jnp.minimum(first + j, last) % num_j
-        return layer, tables_ref[slot, entry], 0, 0
-
-    def row_index(slot, j, tables_ref, lengths_ref):
-        return slot, 0, 0
-
-    kv_spec = pl.BlockSpec((None, None, block_size, width), kv_index)
+    kv_spec = _visited_block(layer, (block_size, width))
     if group > 1:
         # One query row a head, each head_dim wide, padded to whole
         # sublane tiles.
-        row_spec = pl.BlockSpec((1, rows, head_dim), row_index)
         q_rows = jnp.pad(q, ((0, 0), (0, rows - heads), (0, 0)))
     else:
-        row_spec = pl.BlockSpec((1, 1, width), row_index)
         q_rows = q.reshape(slots, 1, width)
-    out = pl.pallas_call(
+    out = _walk_call(
         functools.partial(
             _paged_decode_kernel, sm_scale=1.0 / (head_dim**0.5),
-            head_dim=head_dim, block_size=block_size, num_j=num_j,
-            group=group, window=window,
+            head_dim=head_dim, block_size=block_size, group=group,
+            window=window,
         ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(slots, num_j),
-            in_specs=[row_spec, kv_spec, kv_spec],
-            out_specs=row_spec,
-            scratch_shapes=[
-                pltpu.VMEM((rows, _LANES), jnp.float32),
-                pltpu.VMEM((rows, _LANES), jnp.float32),
-                pltpu.VMEM((rows, width), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct(q_rows.shape, q.dtype),
-        compiler_params=pallas_tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(
-        tables.astype(jnp.int32), lengths.astype(jnp.int32),
-        q_rows, k_pool, v_pool,
+        walk, lengths, (q_rows, k_pool, v_pool),
+        [pl.BlockSpec((1, *q_rows.shape[1:]), _visited_rows), kv_spec,
+         kv_spec],
+        jax.ShapeDtypeStruct(q_rows.shape, q.dtype), (rows, width),
+        interpret,
     )
     if group > 1:
         return out[:, :heads]
@@ -341,24 +455,13 @@ def paged_decode_reference(
 
 
 def _paged_latent_kernel(
-    tables_ref, lengths_ref, qa_ref, qr_ref, kv_ref, o_ref,
-    m_scratch, l_scratch, acc_scratch,
-    *, rank: int, block_size: int, num_j: int,
+    slot_ref, block_ref, index_ref, count_ref, lengths_ref,
+    qa_ref, qr_ref, kv_ref, o_ref, m_scratch, l_scratch, acc_scratch,
+    *, rank: int, block_size: int,
 ):
-    del tables_ref  # read by the index map
-    slot = pl.program_id(0)
-    j = pl.program_id(1)
-    length = lengths_ref[slot]
-    base = j * block_size
+    del block_ref  # read by the index map
 
-    @pl.when(j == 0)
-    def _init():
-        m_scratch[...] = jnp.full_like(m_scratch, _NEG_INF)
-        l_scratch[...] = jnp.zeros_like(l_scratch)
-        acc_scratch[...] = jnp.zeros_like(acc_scratch)
-
-    @pl.when(base < length)
-    def _compute():
+    def attend(base, length):
         c = kv_ref[:, :rank]  # [block_size, rank]: key and value
         k_rope = kv_ref[:, rank:]
         contract_last = (((1,), (1,)), ((), ()))
@@ -369,34 +472,18 @@ def _paged_latent_kernel(
             preferred_element_type=jnp.float32,
         )  # [rows, block_size]
         pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        live = pos < length
-        s = jnp.where(live, s, _NEG_INF)
-
-        m_prev = m_scratch[...]  # [rows, 128], value replicated over lanes
-        l_prev = l_scratch[...]
-        m_cur = jnp.broadcast_to(
-            jnp.max(s, axis=1, keepdims=True), m_prev.shape
-        )
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(live, jnp.exp(s - m_new[:, :1]), 0.0)
-        l_scratch[...] = l_prev * alpha + jnp.broadcast_to(
-            jnp.sum(p, axis=1, keepdims=True), l_prev.shape
-        )
+        p, alpha = _online_softmax(s, pos < length, m_scratch, l_scratch)
         # p is narrowed to the pool's dtype only as an operand of its own
         # product; statistics and the accumulator stay float32.
         pv = jax.lax.dot_general(
             p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [rows, rank]
-        acc_scratch[...] = acc_scratch[...] * alpha[:, :1] + pv
-        m_scratch[...] = m_new
+        acc_scratch[...] = acc_scratch[...] * alpha + pv
 
-    @pl.when(j == num_j - 1)
-    def _finish():
-        l_final = l_scratch[...][:, :1]
-        l_safe = jnp.where(l_final == 0.0, 1.0, l_final)
-        o_ref[0] = (acc_scratch[...] / l_safe).astype(o_ref.dtype)
+    _visit(slot_ref, index_ref, count_ref, lengths_ref, o_ref,
+           (m_scratch, l_scratch, acc_scratch), block_size, None, attend,
+           lambda: _normalised(l_scratch, acc_scratch))
 
 
 def _check_latent_shapes(q_abs, q_rope, pool, tables, lengths, layer):
@@ -423,60 +510,33 @@ def _check_latent_shapes(q_abs, q_rope, pool, tables, lengths, layer):
 
 @functools.lru_cache(maxsize=None)
 def _latent_jitted(layer: int, interpret: bool):
-    from jax.experimental.pallas import tpu as pltpu
-
-    def paged_latent_decode(q_abs, q_rope, pool, tables, lengths):
+    def paged_latent_decode(q_abs, q_rope, pool, tables, lengths, walk):
         slots, heads, rank = q_abs.shape
         block_size, width = pool.shape[2:]
         rope = width - rank  # the pool's lanes past the latent
-        num_j = tables.shape[1]
         rows = -(-heads // _SUBLANES) * _SUBLANES
         pad = (0, 0), (0, rows - heads)
+        if walk is None:
+            walk = live_block_walk(tables, lengths, block_size=block_size)
 
-        def kv_index(slot, j, tables_ref, lengths_ref):
-            # Past the live blocks the index stays on the last live one:
-            # an unchanged block index is not fetched again.
-            last = jnp.maximum(
-                (lengths_ref[slot] + block_size - 1) // block_size - 1, 0
-            )
-            return layer, tables_ref[slot, jnp.minimum(j, last)], 0, 0
-
-        def row_index(slot, j, tables_ref, lengths_ref):
-            return slot, 0, 0
-
-        out = pl.pallas_call(
+        out = _walk_call(
             functools.partial(
-                _paged_latent_kernel, rank=rank, block_size=block_size,
-                num_j=num_j,
+                _paged_latent_kernel, rank=rank, block_size=block_size),
+            walk, lengths,
+            (
+                jnp.pad(q_abs, (*pad, (0, 0))).astype(pool.dtype),
+                jnp.pad(
+                    q_rope, (*pad, (0, rope - q_rope.shape[2]))
+                ).astype(pool.dtype),
+                pool,
             ),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
-                grid=(slots, num_j),
-                in_specs=[
-                    pl.BlockSpec((1, rows, rank), row_index),
-                    pl.BlockSpec((1, rows, rope), row_index),
-                    pl.BlockSpec((None, None, block_size, width), kv_index),
-                ],
-                out_specs=pl.BlockSpec((1, rows, rank), row_index),
-                scratch_shapes=[
-                    pltpu.VMEM((rows, _LANES), jnp.float32),
-                    pltpu.VMEM((rows, _LANES), jnp.float32),
-                    pltpu.VMEM((rows, rank), jnp.float32),
-                ],
-            ),
-            out_shape=jax.ShapeDtypeStruct((slots, rows, rank), q_abs.dtype),
-            compiler_params=pallas_tpu_compiler_params(
-                dimension_semantics=("parallel", "arbitrary"),
-            ),
-            interpret=interpret,
-            name=_LATENT_KERNEL_NAME,
-        )(
-            tables.astype(jnp.int32), lengths.astype(jnp.int32),
-            jnp.pad(q_abs, (*pad, (0, 0))).astype(pool.dtype),
-            jnp.pad(
-                q_rope, (*pad, (0, rope - q_rope.shape[2]))
-            ).astype(pool.dtype),
-            pool,
+            [
+                pl.BlockSpec((1, rows, rank), _visited_rows),
+                pl.BlockSpec((1, rows, rope), _visited_rows),
+                _visited_block(layer, (block_size, width)),
+            ],
+            jax.ShapeDtypeStruct((slots, rows, rank), q_abs.dtype),
+            (rows, rank), interpret, name=_LATENT_KERNEL_NAME,
         )
         return out[:, :heads]
 
@@ -491,19 +551,19 @@ def paged_latent_decode_attention(
     lengths: jnp.ndarray,
     *,
     layer: int = 0,
+    walk=None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """The absorbed queries of one token per slot against its paged cache
     of latent rows; see the module docstring for the contract. Returns
-    ``[slots, heads, rank]`` in ``q_abs``'s dtype. ``interpret=None`` runs
-    the compiled kernel on a TPU backend and Pallas interpret mode
-    elsewhere. Tiles come from the shapes alone: one block of the pool a
-    grid step."""
+    ``[slots, heads, rank]`` in ``q_abs``'s dtype. ``walk`` and
+    ``interpret`` as :func:`paged_decode_attention` takes them. Tiles come
+    from the shapes alone: one block of the pool a grid step."""
     _check_latent_shapes(q_abs, q_rope, pool, tables, lengths, layer)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return _latent_jitted(int(layer), bool(interpret))(
-        q_abs, q_rope, pool, tables, lengths
+        q_abs, q_rope, pool, tables, lengths, walk
     )
 
 

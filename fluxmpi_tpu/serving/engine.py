@@ -543,6 +543,8 @@ class _PagedDecodeAttention:
                  positions, kernel: bool):
         import jax.numpy as jnp
 
+        from ..ops.paged_attention import live_block_walk
+
         # One pool, one table and one written block a kind of layer.
         self.k_pools, self.v_pools = list(k_pools), list(v_pools)
         self.tables = tables
@@ -564,6 +566,14 @@ class _PagedDecodeAttention:
         )
         self.kernel = kernel
         self.layer = 0
+        # What the paged kernels visit, a kind of K/V layer: its slots'
+        # live blocks, listed once for every layer that shares the table.
+        self.walks = [
+            live_block_walk(table, self.lengths, window=kind.window,
+                            block_size=cache.block_size)
+            if kernel and kind.state is None else None
+            for table, kind in zip(tables, cache.kinds)
+        ]
         # A state kind's table is one entry wide: each slot's pool entry
         # (the trash entry: an idle slot), and the live ones compacted
         # once for every layer's update.
@@ -625,14 +635,16 @@ class _PagedDecodeAttention:
             v_pool = self.v_pools[kind] = v_pool.at[rows].set(
                 value.reshape(slots, -1).astype(v_pool.dtype)
             )
-        attend = (
-            paged_decode_attention if self.kernel else paged_decode_reference
-        )
+        args = (query[:, 0], k_pool, v_pool, self.tables[kind], self.lengths)
         with jax.named_scope("decode_attention"):
-            out = attend(
-                query[:, 0], k_pool, v_pool, self.tables[kind],
-                self.lengths, layer=layer, window=self.windows[kind],
-            )
+            if self.kernel:
+                out = paged_decode_attention(
+                    *args, layer=layer, window=self.windows[kind],
+                    walk=self.walks[kind],
+                )
+            else:
+                out = paged_decode_reference(
+                    *args, layer=layer, window=self.windows[kind])
         return out[:, None]
 
     def latent(self, q_abs, q_rope, row):
@@ -658,15 +670,14 @@ class _PagedDecodeAttention:
             pool = self.k_pools[kind] = pool.at[
                 (layer, self.blocks[kind], self.offset)
             ].set(row.astype(pool.dtype))
-        attend = (
-            paged_latent_decode_attention if self.kernel
-            else paged_latent_decode_reference
-        )
+        args = (q_abs[:, 0], q_rope[:, 0], pool, self.tables[kind],
+                self.lengths)
         with jax.named_scope("decode_attention"):
-            out = attend(
-                q_abs[:, 0], q_rope[:, 0], pool, self.tables[kind],
-                self.lengths, layer=layer,
-            )
+            if self.kernel:
+                out = paged_latent_decode_attention(
+                    *args, layer=layer, walk=self.walks[kind])
+            else:
+                out = paged_latent_decode_reference(*args, layer=layer)
         return out[:, None]
 
 
@@ -915,9 +926,11 @@ class InferenceEngine:
         self._admissions = 0
         self._evictions = 0
         # Blocks the decode kernel read (live positions only) against the
-        # blocks the slots' tables span, both summed over decode ticks.
+        # blocks the slots' tables span and the grid steps its calls were
+        # handed, all summed over decode ticks.
         self._kv_blocks_live = 0
         self._kv_blocks_tabled = 0
+        self._kv_kernel_steps = 0
         # Positions the decode attention read: every active slot's length,
         # summed over decode ticks.
         self._context_tokens = 0
@@ -1477,7 +1490,9 @@ class InferenceEngine:
             positions = np.zeros((self.slots,), np.int32)
             tokens = np.zeros((self.slots,), np.int32)
             use_prev = np.zeros((self.slots,), bool)
-            live = 0  # layer-blocks the decode kernel reads this tick
+            # Blocks the decode kernels read this tick, by kind: a layer's
+            # walk (``ops.paged_attention.live_block_walk``).
+            visits = [0] * len(kinds)
             context = 0  # positions it reads: the live slots' lengths
             # With window layers: layer-blocks the slots hold, by kind,
             # and would hold in one pool of one shape.
@@ -1504,20 +1519,27 @@ class InferenceEngine:
                                   + 1)
                     else:
                         blocks = blocks_for_tokens(reach, self.block_size)
-                    live += kind.layers * blocks
+                    visits[at] += blocks
                 if windowed:
                     uniform += (self.cache.num_layers
                                 * max(map(len, slot.blocks)))
             tabled = self.slots * sum(
                 k.layers * k.entries for k in kinds if k.state is None)
+            kv = [(kind.layers, n) for kind, n in zip(kinds, visits)
+                  if kind.state is None]
+            live = sum(layers * n for layers, n in kv)
+            # One grid step a visit, and one a call whatever is live.
+            steps = sum(layers * max(n, 1) for layers, n in kv)
             self._kv_blocks_live += live
             self._kv_blocks_tabled += tabled
+            self._kv_kernel_steps += steps
             self._context_tokens += context
             said = {"context_tokens": context,
                     "kv_sublayers": self._kv_sublayers,
                     "state_sublayers": self._state_sublayers}
             if tabled:
                 said["live_blocks_pct"] = 100.0 * live / tabled
+                said["kernel_steps_per_live_block"] = steps / live
             if self.cache.state_kind is not None:
                 # The states this tick's update reads and writes, of
                 # those the pool holds.
@@ -1745,6 +1767,10 @@ class InferenceEngine:
         ratio is the share of the reserved cache a tick touches; both
         count a block once a K/V (or latent) SUBLAYER that has it, and a
         window layer only the blocks that meet its window);
+        ``kv_kernel_steps`` (the grid steps the paged decode kernels
+        were handed, summed like the two: one a live block, at least one
+        a call; before the kernels walked the live blocks only it was
+        ``kv_blocks_tabled``);
         ``context_tokens`` (the positions the decode attention read:
         every active slot's length, summed over decode steps, once a
         request however many sublayers read it). ``kv_sublayers`` and
@@ -1803,6 +1829,7 @@ class InferenceEngine:
             "evictions": self._evictions,
             "kv_blocks_live": self._kv_blocks_live,
             "kv_blocks_tabled": self._kv_blocks_tabled,
+            "kv_kernel_steps": self._kv_kernel_steps,
             "context_tokens": self._context_tokens,
             "kv_blocks_full": self._kv_blocks_full,
             "kv_blocks_window": self._kv_blocks_window,

@@ -402,9 +402,11 @@ HOT_PATH_SPAN_ARGS: dict[str, tuple[str, ...]] = {
 }
 
 # Arguments a hot-path span carries only where they have a meaning (a
-# stalled gap, a model with expert layers, the grouped matmul's kernel):
+# stalled gap, a model with expert layers, the grouped matmul's kernel,
+# a model with K/V or latent layers):
 # documented beside the required ones, held by no validator.
 HOT_PATH_SPAN_OPTIONAL_ARGS: dict[str, tuple[str, ...]] = {
+    "serve.decode.prepare": ("kernel_steps_per_live_block",),
     "serve.decode.deliver": ("stalled_by", "experts_touched_pct",
                              "expert_load_max_over_mean",
                              "expert_weight_visits_per_touched",

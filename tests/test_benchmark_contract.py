@@ -225,7 +225,8 @@ def test_tiny_train_cell_summary_and_record_keys(world, own_runtime):
 # driver reads.
 _ENGINE_COUNTERS = (
     "decode_steps", "tokens", "slot_steps_active", "admissions", "evictions",
-    "kv_blocks_live", "kv_blocks_tabled", "context_tokens", "kv_blocks_full",
+    "kv_blocks_live", "kv_blocks_tabled", "kv_kernel_steps", "context_tokens",
+    "kv_blocks_full",
     "kv_blocks_window", "kv_blocks_uniform", "expert_tokens",
     "experts_touched", "expert_slots", "expert_weight_visits",
     "expert_row_tiles_worked", "expert_row_tiles", "decode_steps_overlapped", "tokens_discarded", "state_entries",

@@ -325,6 +325,12 @@ def test_engine_spans(tiny_lm, capture, request):
     # until position 8 opens the second.
     assert max(shares) == pytest.approx(100.0 * 2 * 4 / tabled)
     assert min(shares) == pytest.approx(100.0 * 2 * 1 / tabled)
+    # kernel_steps_per_live_block: the grid steps the tick's paged
+    # kernels were handed over those blocks. A walk visits the live
+    # blocks only, and a slot always rides here: one step a block.
+    assert stats["kv_kernel_steps"] == stats["kv_blocks_live"]
+    assert {float(s[3]["kernel_steps_per_live_block"])
+            for s in ticks["prepare"]} == {1.0}
     chain = _named(spans, "request.prefill")
     if request.node.callspec.params["capture"] == "ring":
         # The ring also holds the request chain (written when a request
@@ -345,7 +351,8 @@ def test_engine_stats_agree_with_delivery(tiny_lm):
     assert 0 < stats["slot_steps_active"] <= 2 * stats["decode_steps"]
     assert set(stats) == {"decode_steps", "tokens", "slot_steps_active",
                           "admissions", "evictions", "kv_blocks_live",
-                          "kv_blocks_tabled", "context_tokens",
+                          "kv_blocks_tabled", "kv_kernel_steps",
+                          "context_tokens",
                           "kv_blocks_full",
                           "kv_blocks_window", "kv_blocks_uniform",
                           "expert_tokens", "experts_touched", "expert_slots",

@@ -303,3 +303,133 @@ def test_paged_latent_decode_rejects_mismatched_shapes():
     with pytest.raises(ValueError, match="outside the pool"):
         paged_latent_decode_attention(q_abs, q_rope, pool, tables, lengths,
                                       layer=LAYERS)
+
+
+# ---------------------------------------------------------------------------
+# The walk: the kernels visit the live slots' live blocks and nothing else
+# ---------------------------------------------------------------------------
+
+WALK_BLOCK = 8
+WALK_ENTRIES = 5  # a table of 5 blocks: 40 positions, or a ring for 32
+
+# What a walk can get wrong, as the slots' lengths.
+WALKS = {
+    "nothing_live": [0] * 6,
+    "one_live_of_48": [0] * 29 + [19] + [0] * 18,
+    "all_full_tables": [WALK_BLOCK * WALK_ENTRIES] * 6,
+    "one_token_last_blocks": [1, WALK_BLOCK + 1, 2 * WALK_BLOCK + 1, 0,
+                              4 * WALK_BLOCK + 1, 3 * WALK_BLOCK + 1],
+    "dead_between_live": [9, 0, 0, 17, 0, 40, 0],
+    "first_and_last_dead": [0, 0, 12, 8, 0],
+}
+# The shapes the walk serves: GPT-2's (a K/V head a query head), 5 query
+# rows a K/V head padded to 8 (falcon-h1: 20 over 4 of 128), 8 a K/V
+# head under a window on a ring (trinity-mini: 32 over 4 of 128; the
+# ring holds the window and one block), and the latent pool at its served
+# lanes (576 of 640 live, 512 of them the value).
+WALK_KERNELS = {
+    "one_to_one": dict(heads=4, kv_heads=4, head_dim=64),
+    "five_to_one": dict(heads=20, kv_heads=4, head_dim=128),
+    "ring": dict(heads=32, kv_heads=4, head_dim=128,
+                 window=(WALK_ENTRIES - 1) * WALK_BLOCK),
+    "latent": dict(heads=16, rank=512, rope=64, width=640),
+}
+
+
+def _walk_case(lengths, shape, seed):
+    """Sequences written position by position into scrambled blocks (a
+    ring under a window: lengths past the table are stretched so that it
+    wraps), garbage wherever no live position lies. Returns the kernel,
+    its reference, their arguments and the keyword arguments."""
+    from fluxmpi_tpu.ops.paged_attention import (
+        paged_latent_decode_attention,
+        paged_latent_decode_reference,
+    )
+
+    rng = np.random.default_rng(seed)
+    window = shape.get("window")
+    lengths = np.asarray(lengths, np.int32)
+    if window:
+        # Every third block of length becomes two turns of the ring more.
+        lengths = np.where(lengths > 2 * WALK_BLOCK,
+                           lengths + 2 * WALK_ENTRIES * WALK_BLOCK, lengths)
+    slots = len(lengths)
+    latent = "rank" in shape
+    width = shape["width"] if latent else shape["kv_heads"] * shape["head_dim"]
+    live_lanes = shape["rank"] + shape["rope"] if latent else width
+    num_blocks = 1 + slots * WALK_ENTRIES + 2
+    pools = np.full((1 if latent else 2, LAYERS, num_blocks, WALK_BLOCK,
+                     width), GARBAGE, np.float32)
+    pools[..., live_lanes:] = 0.0
+    order = iter(rng.permutation(np.arange(1, num_blocks)))
+    tables = np.full((slots, WALK_ENTRIES), TRASH_BLOCK, np.int32)
+    for slot, n in enumerate(lengths):
+        for p in range(int(n)):
+            entry = (p // WALK_BLOCK) % WALK_ENTRIES
+            if tables[slot, entry] == TRASH_BLOCK:
+                tables[slot, entry] = next(order)
+            pools[:, :, tables[slot, entry], p % WALK_BLOCK, :live_lanes] = (
+                rng.normal(size=(len(pools), LAYERS, live_lanes)))
+    tables, lengths = jnp.asarray(tables), jnp.asarray(lengths)
+    if latent:
+        q = [0.3 * rng.normal(size=(slots, shape["heads"], shape[k]))
+             for k in ("rank", "rope")]
+        args = (*(jnp.asarray(a, jnp.float32) for a in q),
+                jnp.asarray(pools[0]), tables, lengths)
+        return (paged_latent_decode_attention, paged_latent_decode_reference,
+                args, {})
+    q = rng.normal(size=(slots, shape["heads"], shape["head_dim"]))
+    args = (jnp.asarray(q, jnp.float32), jnp.asarray(pools[0]),
+            jnp.asarray(pools[1]), tables, lengths)
+    return (paged_decode_attention, paged_decode_reference, args,
+            {"window": window})
+
+
+@pytest.mark.parametrize("kernel", sorted(WALK_KERNELS))
+@pytest.mark.parametrize("walk", sorted(WALKS))
+def test_paged_kernels_walk_the_live_blocks_only(walk, kernel):
+    """Both kernels (interpret mode) against their references over the
+    tables a walk can get wrong; a dead slot, wherever it lies, returns
+    zeros, and no garbage reaches a live one. The list a caller hands in
+    (one a tick for all layers) serves as the kernel's own does."""
+    from fluxmpi_tpu.ops.paged_attention import live_block_walk
+
+    attend, reference, args, kw = _walk_case(
+        WALKS[walk], WALK_KERNELS[kernel], seed=len(walk))
+    tables, lengths = args[-2:]
+    got = np.asarray(attend(*args, layer=1, **kw))
+    want = np.asarray(reference(*args, layer=1, **kw))
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert float(np.max(np.abs(got))) < 10.0
+    np.testing.assert_array_equal(got[np.asarray(lengths) == 0], 0.0)
+    handed = live_block_walk(tables, lengths, block_size=WALK_BLOCK, **kw)
+    np.testing.assert_array_equal(
+        np.asarray(attend(*args, layer=1, walk=handed, **kw)), got)
+
+
+@pytest.mark.parametrize("window", [None, (WALK_ENTRIES - 1) * WALK_BLOCK],
+                         ids=["full", "ring"])
+@pytest.mark.parametrize("walk", sorted(WALKS))
+def test_live_block_walk_lists_each_live_block_once(walk, window):
+    """The list against a loop over the slots: every live slot's blocks
+    (those that meet the window, found on the ring), slot by slot, first
+    block first; ``count`` of them; the last one held past the count."""
+    from fluxmpi_tpu.ops.paged_attention import live_block_walk
+
+    *_, args, kw = _walk_case(
+        WALKS[walk], {**WALK_KERNELS["one_to_one"], "window": window}, seed=1)
+    tables, lengths = (np.asarray(a) for a in args[-2:])
+    slot, block, index, count = (np.asarray(a) for a in live_block_walk(
+        *args[-2:], block_size=WALK_BLOCK, **kw))
+    want = [
+        (s, tables[s, j % WALK_ENTRIES], j)
+        for s, n in enumerate(lengths)
+        for j in range(max(n - window, 0) // WALK_BLOCK if window else 0,
+                       -(-n // WALK_BLOCK))
+    ]
+    assert count.tolist() == [len(want)]
+    assert slot.shape == (tables.size,)
+    got = list(zip(slot.tolist(), block.tolist(), index.tolist()))
+    assert got[:len(want)] == want
+    assert set(got[len(want):]) <= {want[-1] if want else (0, TRASH_BLOCK, 0)}
+    assert TRASH_BLOCK not in block[:len(want)]

@@ -255,6 +255,72 @@ def test_paged_decode_kernel_compiles_for_v5e(topo, name):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
+# The serve cells' decode attention: slots, table entries, block size,
+# query heads, then K/V heads, head_dim and a window (trinity-mini's full
+# layer and a window layer's ring), or the latent rank, rotary lanes and
+# the pool's padded width.
+_SERVE_CELLS = {
+    "gpt2m": (32, 8, 128, 16, 16, 64, None),
+    "trinity": (64, 17, 512, 32, 4, 128, None),
+    "trinity_ring": (64, 5, 512, 32, 4, 128, 2048),
+    "sarvam": (48, 17, 1024, 64, 512, 64, 640),
+    "granite": (128, 10, 256, 32, 8, 128, None),
+    "nemotron": (192, 24, 256, 32, 2, 128, None),
+    "falcon": (96, 6, 512, 20, 4, 128, None),
+}
+# What the benchmark's readers find the two kernels by: the instruction
+# names of their calls, and of nothing else in a program.
+_PAGED_CALLS = r"%(paged_decode_attention|paged_latent_decode)[\w.\-]* = "
+
+
+@pytest.mark.parametrize("cell", sorted(_SERVE_CELLS))
+def test_paged_kernels_walk_compiles_at_the_serve_cells_shapes(topo, cell):
+    """Both decode kernels at the six serve cells' shapes: the walk's
+    lists (``slots x entries`` int32, scalar prefetched), the dynamic
+    grid bound and the whole-output block are taken by the chip's
+    compiler, the call keeps the name the readers match, and the walk's
+    list-making carries neither name."""
+    from fluxmpi_tpu.ops.paged_attention import (
+        paged_decode_attention,
+        paged_latent_decode_attention,
+    )
+
+    slots, entries, block, heads, *rest = _SERVE_CELLS[cell]
+    dev = topo.devices[0]
+    blocks = 1 + slots * entries
+    tables = _sds((slots, entries), jnp.int32, dev)
+    lengths = _sds((slots,), jnp.int32, dev)
+    if cell == "sarvam":
+        rank, rope, width = rest
+
+        def attend(q_abs, q_rope, pool, tables, lengths):
+            return paged_latent_decode_attention(
+                q_abs, q_rope, pool, tables, lengths, layer=1,
+                interpret=False)
+
+        args = (_sds((slots, heads, rank), jnp.bfloat16, dev),
+                _sds((slots, heads, rope), jnp.bfloat16, dev),
+                _sds((2, blocks, block, width), jnp.bfloat16, dev))
+        name = "paged_latent_decode"
+    else:
+        kv_heads, head_dim, window = rest
+
+        def attend(q, k_pool, v_pool, tables, lengths):
+            return paged_decode_attention(
+                q, k_pool, v_pool, tables, lengths, layer=1, window=window,
+                interpret=False)
+
+        pool = _sds((2, blocks, block, kv_heads * head_dim), jnp.bfloat16,
+                    dev)
+        args = (_sds((slots, heads, head_dim), jnp.bfloat16, dev), pool, pool)
+        name = "paged_decode_attention"
+    compiled = jax.jit(attend).lower(*args, tables, lengths).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert re.findall(_PAGED_CALLS, text) == [name]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**23
+
+
 # trinity-mini-serve's expert layer: 128 experts of [2048, 1024] up and
 # [1024, 2048] down; 64 slots x top-8 rows in the decode tick, a prompt
 # bucket's tokens x 8 in a prefill (the shortest, one past the window,
@@ -359,6 +425,10 @@ def test_gpt2_medium_serving_programs_compile_for_v5e(topo, as_on_tpu):
     decode, prefill = _once(
         "gpt2m-serve", lambda: _gpt2_medium_serving_programs(topo))
     assert decode.as_text().count("tpu_custom_call") == cfg["num_layers"]
+    # One call a layer under the name the readers match, and the walk's
+    # list (made once a tick) under neither name.
+    assert re.findall(_PAGED_CALLS, decode.as_text()) == [
+        "paged_decode_attention"] * cfg["num_layers"]
     # Each weight is prefetched whole (the compiler's default cuts it in
     # four): a tick launches 1,352 operations where it launched 2,198.
     assert "slice-start" not in decode.as_text()
@@ -441,6 +511,8 @@ def test_trinity_mini_serving_programs_compile_for_v5e(topo, as_on_tpu):
     # as the benchmark's readers find XLA's own (``^ragged-dot``).
     text = decode.as_text()
     assert text.count("tpu_custom_call") == 5 + 4 * 3
+    # The full layer's walk and the ring's are made once a tick each.
+    assert re.findall(_PAGED_CALLS, text) == ["paged_decode_attention"] * 5
     assert "slice-start" not in text  # operands prefetched whole
     assert len(re.findall(r"%ragged-dot-gmm[.\d]* = ", text)) == 4 * 3
     assert len(re.findall(
@@ -512,8 +584,9 @@ def test_paged_latent_decode_kernel_compiles_for_v5e(topo):
     # One findable name, the jitted wrapper's and the kernel's.
     assert len(re.findall(r"%paged_latent_decode[.\d]* = ", text)) == 1
     # The pool is read where it lies: nothing pool-sized is made (a pool
-    # of 576-lane rows is copied whole: 7.1 GB at 1,089 blocks).
-    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+    # of 576-lane rows is copied whole: 7.1 GB at 1,089 blocks; the
+    # queries, prefetched while the walk's list is made, are 1.9 MB).
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**22
 
 
 @pytest.mark.parametrize("s", [1024, 5120, 16384])
@@ -595,6 +668,7 @@ def test_sarvam_105b_serving_programs_compile_for_v5e(topo, as_on_tpu):
     assert text.count("tpu_custom_call") == 5 + 4 * 4
     assert "slice-start" not in text  # operands prefetched whole
     assert len(re.findall(r"%paged_latent_decode[.\d]* = ", text)) == 5
+    assert re.findall(_PAGED_CALLS, text) == ["paged_latent_decode"] * 5
     assert len(re.findall(r"%ragged-dot-gmm[.\d]* = ", text)) == 4 * 3
     # 16 of the router's 128 experts held: the combine reads live tiles.
     assert len(re.findall(r"%expert-combine[.\d]* = ", text)) == 4
@@ -738,6 +812,7 @@ def test_granite_4_0_h_small_serving_programs_compile_for_v5e(topo, as_on_tpu):
         engine.close()
     text = decode.as_text()
     assert text.count("tpu_custom_call") == 9 + 1 + 10 * 4
+    assert re.findall(_PAGED_CALLS, text) == ["paged_decode_attention"]
     assert "slice-start" not in text  # operands prefetched whole
     assert len(re.findall(r"%ssm_state_update[.\d]* = ", text)) == 9
     assert len(re.findall(r"%ragged-dot-gmm[.\d]* = ", text)) == 10 * 3
@@ -917,6 +992,7 @@ def test_nemotron_3_nano_serving_programs_compile_for_v5e(topo, as_on_tpu):
         engine.close()
     text = decode.as_text()
     assert text.count("tpu_custom_call") == 4 + 1 + 4 * 3
+    assert re.findall(_PAGED_CALLS, text) == ["paged_decode_attention"]
     assert "slice-start" not in text  # operands prefetched whole
     assert len(re.findall(r"%ssm_state_update[.\d]* = ", text)) == 4
     assert len(re.findall(r"%ragged-dot-gmm[.\d]* = ", text)) == 4 * 2
@@ -1059,6 +1135,7 @@ def test_falcon_h1_34b_serving_programs_compile_for_v5e(topo, as_on_tpu):
         engine.close()
     text = decode.as_text()
     assert text.count("tpu_custom_call") == 4 + 4
+    assert re.findall(_PAGED_CALLS, text) == ["paged_decode_attention"] * 4
     assert "slice-start" not in text  # operands prefetched whole
     assert len(re.findall(r"%ssm_state_update[.\d]* = ", text)) == 4
     in_place = {"custom-call", "parameter", "get-tuple-element"}
