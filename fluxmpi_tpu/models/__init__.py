@@ -36,7 +36,7 @@ from .resnet import (  # noqa: F401
 )
 from .deq import DEQ, fixed_point_solve  # noqa: F401
 from .transformer import TransformerEncoder, TransformerLM  # noqa: F401
-from .decoder import DecoderConfig, DecoderLM, ExpertMLP  # noqa: F401
+from .decoder import DecoderConfig, DecoderLM, ExpertMLP, Keeps  # noqa: F401
 from .generate import beam_search, generate  # noqa: F401
 from .hf_gpt2 import lm_from_gpt2  # noqa: F401
 from .vit import ViT  # noqa: F401

@@ -70,19 +70,14 @@ float32 accumulation; the norms, the router, the rotary angles and the
 softmax statistics are float32. Parameters are held in the dtype they
 are given in.
 
-The serving engine's seam is ``attention_fn`` (as in
-:class:`TransformerLM`): where set, every layer hands it ``(query, key,
-value)`` (``[batch, seq, heads | kv_heads, head_dim]``, after the head
-norms and the rotary) in layer order and takes ``[batch, seq, heads,
-head_dim]`` back; a latent layer adds ``row=`` (what its cache keeps of
-each token) and, where the function attends a cache
-(``attention_fn.from_cache``), calls ``attention_fn.latent(q_abs, q_rope,
-row)`` with the absorbed queries instead; a Mamba layer hands a prefill's
-function ``keep_state(tail, state)`` and asks a cache's for
-``conv_tail()`` and ``state_update(tail, x, step, decay, B, C)``;
-:meth:`DecoderLM.cache_layers` says what each keeping sublayer keeps in
-a cache, in the order their calls come (a ``"mamba_attention"`` layer's
-Mamba mixer first, then its attention).
+The serving engine's seam is the call argument ``cache``: a view of a
+serving cache that hands each KEEPING sublayer the handle of its own
+number, ``cache.sublayer(n)``, ``n`` its place in
+:meth:`DecoderLM.cache_layers` with the Nones left out (static: known
+from ``layer_types``). A handle offers what its kind of mixer needs and
+nothing else, and a mixer handed one of another kind raises while it is
+traced; :mod:`fluxmpi_tpu.serving.cache` states that protocol, once, and
+holds its two implementations (a prefill's, a decode tick's).
 ``pos_offset`` (``[batch]``) places each row at its own
 position and ``head_at`` (``[batch]``) takes the head at one position a
 row: a prefill never builds ``[prompt, vocab]`` logits. ``token_mask``
@@ -94,7 +89,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Callable
+from typing import Any, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -103,8 +98,8 @@ import numpy as np
 
 from .transformer import _resolve_attention_mode
 
-__all__ = ["DecoderConfig", "DecoderLM", "ExpertMLP", "LatentAttention",
-           "MambaMixer", "causal_attention"]
+__all__ = ["DecoderConfig", "DecoderLM", "ExpertMLP", "Keeps",
+           "LatentAttention", "MambaMixer", "causal_attention"]
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 LATENT = "latent_attention"
@@ -115,6 +110,37 @@ PARALLEL = "mamba_attention"
 # Not a mixer: a layer of a ``block="single"`` model that is its routed
 # experts alone.
 EXPERTS = "experts"
+
+
+class Keeps(NamedTuple):
+    """What ONE keeping sublayer keeps of a sequence in a serving cache
+    (:meth:`DecoderLM.cache_layers`; :mod:`fluxmpi_tpu.serving.cache`
+    builds its pools from these and names its handles by ``kind``).
+    ``"full"``: ``heads`` K/V heads of ``width`` a token, the whole
+    context; ``"window"``: the same within the newest ``window`` positions;
+    ``"latent"``: ONE row of ``width`` a token, read as key and as value,
+    and no V; ``"state"``: nothing a token, one recurrent ``state``
+    ``(heads, head_dim, d_state)`` and one ``tail`` ``(d_conv - 1,
+    conv_dim)`` of pre-convolution columns a SEQUENCE, whatever its
+    length."""
+
+    kind: str
+    heads: int = 1
+    width: int = 1
+    window: int | None = None
+    state: tuple[int, ...] | None = None
+    tail: tuple[int, ...] | None = None
+
+
+def _own(handle, *kinds: str):
+    """``handle`` (a sublayer's of a serving cache, or None), which must
+    be of one of ``kinds``: no mixer reads what another kind keeps."""
+    if handle is not None and handle.kind not in kinds:
+        raise TypeError(
+            f"a sublayer that keeps {' or '.join(kinds)} was handed the "
+            f"cache handle of a {handle.kind!r} sublayer"
+        )
+    return handle
 
 
 @dataclasses.dataclass(frozen=True)
@@ -298,6 +324,21 @@ class DecoderConfig:
         """The width of the MLP every token passes beside the experts."""
         return (self.shared_intermediate_size
                 or self.num_shared_experts * self.moe_intermediate_size)
+
+    def kept_by(self, layer_type: str) -> tuple[Keeps | None, ...]:
+        """What a layer of ``layer_type`` keeps of a sequence, a sublayer:
+        see :meth:`DecoderLM.cache_layers`."""
+        state = Keeps(
+            "state",
+            state=(self.mamba_n_heads, self.mamba_d_head, self.mamba_d_state),
+            tail=(self.mamba_d_conv - 1, self.mamba_conv_dim))
+        full = Keeps("full", self.num_key_value_heads, self.head_dim)
+        return {
+            EXPERTS: (None,), MAMBA: (state,), PARALLEL: (state, full),
+            LATENT: (Keeps("latent", width=self.latent_row),), FULL: (full,),
+            SLIDING: (full._replace(kind="window",
+                                    window=self.sliding_window),),
+        }[layer_type]
 
     @classmethod
     def from_hf(cls, cfg: dict) -> "DecoderConfig":
@@ -564,11 +605,12 @@ class Attention(nn.Module):
     layer_type: str
     dtype: Any
     attention: str = "naive"
-    attention_fn: Callable | None = None
 
     @nn.compact
-    def __call__(self, u, positions):
+    def __call__(self, u, positions, cache=None):
         c = self.config
+        cache = _own(
+            cache, "window" if self.layer_type == SLIDING else "full")
         heads, kvh, hd = (c.num_attention_heads, c.num_key_value_heads,
                           c.head_dim)
         init = nn.initializers.normal(0.02)
@@ -600,8 +642,8 @@ class Attention(nn.Module):
                 q = _rotary(q, positions, c.rope_theta)
                 k = _rotary(k, positions, c.rope_theta)
         q, k = q.astype(self.dtype), k.astype(self.dtype)
-        if self.attention_fn is not None:
-            out = self.attention_fn(q, k, v)
+        if cache is not None:
+            out = cache.attend(q, k, v)
         else:
             out = causal_attention(
                 q, k, v, window=window,
@@ -623,8 +665,8 @@ class LatentAttention(nn.Module):
     Un-absorbed (a call over its own tokens: the plain forward, a
     prefill): ``[k_nope_h; v_h] = c Wkvb``, ``k_h = [k_nope_h; k_r]``,
     causal softmax attention of scale ``s`` (:func:`latent_scales`),
-    ``out = [o_1 .. o_H] Wo``. Absorbed (against a cache of rows,
-    ``attention_fn.from_cache``): ``q~_h = Wkvb[K, h]^T q_nope_h``, scores
+    ``out = [o_1 .. o_H] Wo``. Absorbed (against a cache of rows: a
+    handle that ``reads_pool``): ``q~_h = Wkvb[K, h]^T q_nope_h``, scores
     ``s * (q~_h . c + q_rope_h . k_r)``, ``o~_h = sum p c``, ``o_h = Wkvb[V,
     h] o~_h``: the same numbers, each cached row read once as key and as
     value. Both forms rebuild from the row AS STORED (``dtype``)."""
@@ -632,11 +674,11 @@ class LatentAttention(nn.Module):
     config: DecoderConfig
     dtype: Any
     attention: str = "naive"
-    attention_fn: Callable | None = None
 
     @nn.compact
-    def __call__(self, u, positions):
+    def __call__(self, u, positions, cache=None):
         c = self.config
+        cache = _own(cache, "latent")
         heads, rank = c.num_attention_heads, c.kv_lora_rank
         nope, rope, vd = c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
         init = nn.initializers.normal(0.02)
@@ -663,16 +705,16 @@ class LatentAttention(nn.Module):
         q_nope = q[..., :nope]
         row = jnp.concatenate([latent, k_rope], axis=-1).astype(self.dtype)
         w = wkvb.astype(self.dtype).reshape(rank, heads, nope + vd)
-        fn = self.attention_fn
-        if fn is not None and getattr(fn, "from_cache", False):
+        if cache is not None and cache.reads_pool:
             rest = (nope + rope) ** -0.5  # the scale's other part
             with jax.named_scope("latent_absorb"):
                 q_abs = jnp.einsum(
                     "bshn,chn->bshc", q_nope, w[..., :nope],
                     preferred_element_type=jnp.float32,
                 )
-            ctx = fn.latent((q_abs * rest).astype(self.dtype),
-                            (q_rope * rest).astype(self.dtype), row)
+            ctx = cache.attend_absorbed(
+                (q_abs * rest).astype(self.dtype),
+                (q_rope * rest).astype(self.dtype), row)
             with jax.named_scope("latent_expand"):
                 out = jnp.einsum(
                     "bshc,chv->bshv", ctx.astype(self.dtype), w[..., nope:],
@@ -692,8 +734,8 @@ class LatentAttention(nn.Module):
             v = expand(w[..., nope:])
             qf = jnp.concatenate(
                 [q_nope, q_rope.astype(self.dtype)], axis=-1)
-            if fn is not None:
-                out = fn(qf, k, v, row=row)
+            if cache is not None:
+                out = cache.attend(qf, k, v, row)
             else:
                 out = causal_attention(
                     qf, k, v, window=None,
@@ -740,24 +782,23 @@ class MambaMixer(nn.Module):
     (:func:`~fluxmpi_tpu.ops.ssm.ssd_chunk_scan`) computes the
     recurrence; positions that ``token_mask`` leaves out get ``D_t = 0``,
     so the state after a padded prompt is the state after its last real
-    token, and where ``attention_fn`` is set it is handed what a cache
-    keeps of the sequence: ``attention_fn.keep_state(tail, state)``, the
-    last ``d_conv - 1`` real PRE-convolution ``xBC`` columns (zeros
-    before the start) and the final state. Against a cache
-    (``attention_fn.from_cache``, one token a row): ``tail =
-    attention_fn.conv_tail()``, one convolution step over ``[tail; xBC]``,
-    and ``attention_fn.state_update(new tail, x, D, a, B, C)`` moves the
+    token, and a prefill's handle is handed what a cache keeps of the
+    sequence: ``cache.keep(tail, state)``, the last ``d_conv - 1`` real
+    PRE-convolution ``xBC`` columns (zeros before the start) and the final
+    state. Against a cache (a handle that ``reads_pool``, one token a
+    row): ``tail = cache.tail()``, one convolution step over ``[tail;
+    xBC]``, and ``cache.update(new tail, x, D, a, B, C)`` moves the
     row's state in its pool and returns ``H_t C_t``. ``xBC`` is rounded
     to ``dtype`` where it leaves the projection: a cached tail holds what
     the prefill's convolution read."""
 
     config: DecoderConfig
     dtype: Any
-    attention_fn: Callable | None = None
 
     @nn.compact
-    def __call__(self, u, token_mask=None):
+    def __call__(self, u, token_mask=None, cache=None):
         c = self.config
+        cache = _own(cache, "state")
         heads, hd, n, taps = (c.mamba_n_heads, c.mamba_d_head,
                               c.mamba_d_state, c.mamba_d_conv)
         groups = c.mamba_n_groups
@@ -790,11 +831,10 @@ class MambaMixer(nn.Module):
             if token_mask is not None:
                 step = jnp.where(token_mask.reshape(-1, 1), step, 0.0)
         a_rate = -jnp.exp(a_log.astype(f32))
-        fn = self.attention_fn
-        cached = fn is not None and getattr(fn, "from_cache", False)
+        cached = cache is not None and cache.reads_pool
         with jax.named_scope("ssm_conv"):
             if cached:  # seq is 1: the tail, then the token
-                before = fn.conv_tail().astype(self.dtype)
+                before = cache.tail().astype(self.dtype)
             else:
                 before = jnp.zeros((b, taps - 1, conv_dim), self.dtype)
             padded = jnp.concatenate(
@@ -813,7 +853,7 @@ class MambaMixer(nn.Module):
                           conv[:, inner + groups * n:]))
         if cached:
             with jax.named_scope("ssm_update"):
-                y = fn.state_update(
+                y = cache.update(
                     padded[:, 1:], x.reshape(b, heads, hd), step,
                     jnp.exp(step * a_rate), state_in, state_out,
                 )
@@ -828,13 +868,13 @@ class MambaMixer(nn.Module):
                     state_out.reshape(b, s, *state_out.shape[1:]),
                     chunk=c.mamba_chunk_size,
                 )
-            if fn is not None:
+            if cache is not None:
                 # The last ``taps - 1`` real columns: ``padded`` holds
                 # position p at p + taps - 1, zeros before the start.
                 length = (jnp.full((b,), s) if token_mask is None
                           else jnp.sum(token_mask, axis=1))
                 at = length[:, None] + jnp.arange(taps - 1)[None]
-                fn.keep_state(
+                cache.keep(
                     jnp.take_along_axis(padded, at[..., None], axis=1), state)
         with jax.named_scope("ssm_gate_norm"):
             y = y.reshape(b * s, inner) + jnp.repeat(
@@ -1120,12 +1160,19 @@ class DecoderLayer(nn.Module):
     index: int
     dtype: Any
     attention: str = "naive"
-    attention_fn: Callable | None = None
     expert_range: tuple[int, int] | None = None
 
     @nn.compact
-    def __call__(self, x, positions, token_mask=None):
+    def __call__(self, x, positions, token_mask=None, cache=None):
         c = self.config
+        # This layer's keeping sublayers are numbered from the count of
+        # those before it (:meth:`DecoderLM.cache_layers` without its
+        # Nones): known from ``layer_types``, whatever order calls come in.
+        first = sum(kept is not None for before in c.layer_types[:self.index]
+                    for kept in c.kept_by(before))
+
+        def handle(offset):
+            return None if cache is None else cache.sublayer(first + offset)
 
         def norm(name):
             return RMSNorm(c.rms_norm_eps, self.dtype, name=name)
@@ -1147,35 +1194,30 @@ class DecoderLayer(nn.Module):
 
         def mixer(u):
             if kind == PARALLEL:
-                # Both mixers read the one normed input, the Mamba mixer
-                # first: the order their calls of ``attention_fn`` come in
-                # (:meth:`DecoderLM.cache_layers`).
+                # Both mixers read the one normed input; the state is the
+                # layer's first keeping sublayer, the K/V rows its second.
                 with jax.named_scope("ssm_branch"):
-                    m = MambaMixer(
-                        c, self.dtype, self.attention_fn, name="mamba"
-                    )(scaled(u, c.ssm_in_multiplier), token_mask)
+                    m = MambaMixer(c, self.dtype, name="mamba")(
+                        scaled(u, c.ssm_in_multiplier), token_mask, handle(0))
                 with jax.named_scope("attn_branch"):
                     a = Attention(
-                        c, FULL, self.dtype, self.attention,
-                        self.attention_fn, name="attn",
-                    )(scaled(u, c.attention_in_multiplier), positions)
+                        c, FULL, self.dtype, self.attention, name="attn",
+                    )(scaled(u, c.attention_in_multiplier), positions,
+                      handle(1))
                 with jax.named_scope("mixer_join"):
                     return (c.ssm_out_multiplier * m.astype(jnp.float32)
                             + c.attention_out_multiplier
                             * a.astype(jnp.float32)).astype(self.dtype)
             if kind == MAMBA:
-                return MambaMixer(
-                    c, self.dtype, self.attention_fn, name="mamba"
-                )(u, token_mask)
+                return MambaMixer(c, self.dtype, name="mamba")(
+                    u, token_mask, handle(0))
             if kind == LATENT:
                 return LatentAttention(
-                    c, self.dtype, self.attention, self.attention_fn,
-                    name="attn",
-                )(u, positions)
+                    c, self.dtype, self.attention, name="attn",
+                )(u, positions, handle(0))
             return Attention(
-                c, kind, self.dtype, self.attention, self.attention_fn,
-                name="attn",
-            )(u, positions)
+                c, kind, self.dtype, self.attention, name="attn",
+            )(u, positions, handle(0))
 
         def feed_forward(u):
             if self.index not in c.expert_layer_ids:
@@ -1219,7 +1261,6 @@ class DecoderLM(nn.Module):
     config: DecoderConfig
     dtype: Any = jnp.float32
     attention: str = "naive"
-    attention_fn: Callable | None = None
     expert_range: tuple[int, int] | None = None
 
     @property
@@ -1234,30 +1275,15 @@ class DecoderLM(nn.Module):
     def num_layers(self) -> int:
         return self.config.num_layers
 
-    def cache_layers(self) -> tuple[tuple, ...]:
-        """What each KEEPING SUBLAYER keeps of a sequence, in the order
-        their calls of ``attention_fn`` come: ``(kv_heads, head_dim,
-        window)``, ``window`` None where it attends its whole
-        context; a latent layer ``(None, row, None)``: no K/V heads, ONE
-        row of ``kv_lora_rank + qk_rope_head_dim`` a token and no V; a
-        Mamba mixer ``("state", (heads, head_dim, d_state), (d_conv - 1,
-        conv_dim))``: nothing a token, ONE state and one tail of
-        pre-convolution columns a SEQUENCE, whatever its length; a layer
-        that is its experts alone None: it keeps NOTHING of a sequence
-        (and never calls ``attention_fn``). A layer with one mixer is one
-        entry; a ``"mamba_attention"`` layer is TWO, its state and then
-        its K/V heads, so the tuple is as long as the model's keeping
-        sublayers (and its Nones), not as its layers."""
+    def cache_layers(self) -> tuple[Keeps | None, ...]:
+        """What each sublayer keeps of a sequence in a serving cache
+        (None: a layer that is its experts alone keeps NOTHING). A layer
+        with one mixer is one entry; a ``"mamba_attention"`` layer is TWO,
+        its state and then its K/V heads. The n-th entry that is not None
+        is the sublayer ``cache.sublayer(n)`` serves."""
         c = self.config
-        state = ("state", (c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state),
-                 (c.mamba_d_conv - 1, c.mamba_conv_dim))
-        full = (c.num_key_value_heads, c.head_dim, None)
-        kept = {
-            EXPERTS: (None,), MAMBA: (state,), PARALLEL: (state, full),
-            LATENT: ((None, c.latent_row, None),), FULL: (full,),
-            SLIDING: ((*full[:2], c.sliding_window),),
-        }
-        return tuple(sub for kind in c.layer_types for sub in kept[kind])
+        return tuple(kept for kind in c.layer_types
+                     for kept in c.kept_by(kind))
 
     def expert_row_tile(self, tokens: int) -> int | None:
         """The rows of one row tile of the expert layers' grouped matmul
@@ -1296,7 +1322,7 @@ class DecoderLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, *, train: bool = False, pos_offset=None,
-                 head_at=None, token_mask=None):
+                 head_at=None, token_mask=None, cache=None):
         del train  # no dropout, no state: one forward for both
         c = self.config
         init = nn.initializers.normal(0.02)
@@ -1312,9 +1338,9 @@ class DecoderLM(nn.Module):
             x = x * jnp.asarray(c.embedding_multiplier, self.dtype)
         for i in range(c.num_layers):
             x = DecoderLayer(
-                c, i, self.dtype, self.attention, self.attention_fn,
-                self.expert_range, name=f"layer_{i}",
-            )(x, positions, token_mask)
+                c, i, self.dtype, self.attention, self.expert_range,
+                name=f"layer_{i}",
+            )(x, positions, token_mask, cache)
         if head_at is not None:
             x = jnp.take_along_axis(
                 x, jnp.asarray(head_at)[:, None, None], axis=1

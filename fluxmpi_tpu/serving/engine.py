@@ -23,59 +23,31 @@ Phase split:
 - **decode** — ONE fixed-shape jitted step per engine iteration runs
   every active batch slot one token forward: the model runs ONCE over
   ``[slots, 1]`` tokens, every slot at its own position (the position
-  embedding is a gather), through its own blocks; each layer's
-  attention writes the new token's K/V row into the donated pool at
-  ``(table[pos // block_size], pos % block_size)`` and then reads the
-  slot's keys and values **in place, block by block through the block
-  table** (:func:`fluxmpi_tpu.ops.paged_attention.paged_decode_attention`;
-  ``lengths = position + 1``, 0 for an idle slot, whose table is all
-  trash). No per-slot copy of a cache is built and no flax cache is
-  rebuilt; the argmax tokens come back. Shapes depend only on the
+  embedding is a gather), through its own blocks; each keeping sublayer
+  writes the new token's row into the donated pools and reads the slot's
+  past **in place** through the cache's decode view. No per-slot copy of
+  a cache is built and no flax cache is rebuilt; the argmax tokens come
+  back. Shapes depend only on the
   engine geometry ``(slots, max_blocks_per_seq, block_size)`` — never
   on which requests are active — so **requests join and leave the
   batch with zero retrace** (the compile monitor asserts this in the
   tests and the benchmark's ``compiles_in_window.serve``).
 
-A model whose layers keep a **latent** (``cache_layers()`` says ``(None,
-row, None)``: multi-head latent attention) is served by the same two
-programs over the cache's latent kind, one pool of one row a token: the
-prefill runs the layer's un-absorbed form over the prompt (keys and
-values rebuilt from the rows, causal flash attention) and writes only the
-rows; the decode step writes the new token's row and hands the layer's
-ABSORBED queries to
-:func:`fluxmpi_tpu.ops.paged_attention.paged_latent_decode_attention`,
-which reads each live block once as key and as value. The layer type
-decides; no option does.
-
-A model whose layers keep a **state** (``cache_layers()`` says
-``("state", state shape, tail shape)``: Mamba-2) is served by the same two
-programs over the cache's state kind, one pool entry a SEQUENCE: the
-prefill runs the recurrence over the padded prompt as a chunked scan
-(positions past the prompt's length take a step of 0, so the state after
-the bucket is the state after the prompt) and overwrites the request's
-entry whole with that state and the last ``d_conv - 1`` real inputs of
-the convolution; the decode step takes one convolution step from the tail
-and, in ONE walk over the LIVE slots, moves their states where they lie
-and writes their new tails over the old
-(:func:`fluxmpi_tpu.ops.ssm.ssm_state_update`: idle slots' entries are
-neither read nor written, state or tail). Admission takes one entry a
-request whatever its length.
-
-A layer may keep **nothing** (``cache_layers()`` says None: a layer that
-is its routed experts alone, in a model whose layers are one sublayer
-each). It has no pool, no table and no call of ``attention_fn``. A layer
-may keep **both** a state and K/V rows (``cache_layers()`` says the two,
-state first: a Mamba-2 mixer and attention side by side on one normed
-input): it makes two keeping calls of ``attention_fn`` (``conv_tail`` /
-``state_update`` or ``keep_state``, then ``__call__``), owns an entry of
-the state pool AND blocks of the K/V pool, and a request is admitted only
-while both kinds have room. So the cache's layers are the model's KEEPING
-SUBLAYERS in the order their calls come, not its layers: a count of blocks
-speaks of the K/V (or latent) sublayers, a count of states of the state
-sublayers (``stats()["kv_sublayers"]``, ``["state_sublayers"]``), and
-context is counted once a request whatever their number. The ``expert_*`` counters and
-span arguments speak of the layers that HAVE experts (the ones that sow
-``expert_tokens``), wherever they stand in the model.
+What a layer KEEPS of a sequence is the cache's business, not this
+file's: a model says it in records (``cache_layers()``), the cache sorts
+them into kinds (full, window ring, latent, state: each with its pools
+and the device code that knows its rows), and a step hands the model ONE
+view of the cache (``cache=``: :mod:`fluxmpi_tpu.serving.cache` holds the
+protocol). The same two programs serve every kind; the layer type
+decides, no option does. The cache's layers are the model's KEEPING SUBLAYERS, not its
+layers (a layer that is its routed experts alone is none, a Mamba-2 mixer
+beside attention is two, and a request is admitted only while every kind
+has room): a count of blocks speaks of the K/V (or latent) sublayers, a
+count of states of the state sublayers (``stats()["kv_sublayers"]``,
+``["state_sublayers"]``), and context is counted once a request whatever
+their number. The ``expert_*`` counters and span arguments speak of the
+layers that HAVE experts (the ones that sow ``expert_tokens``), wherever
+they stand in the model.
 
 The decode loop is **host-driven** (``lax.scan``-free) with **one tick
 in flight**: an iteration dispatches tick N+1 from what the host knows
@@ -112,11 +84,14 @@ from typing import Any, Callable
 
 import numpy as np
 
+import jax
+import jax.numpy as jnp
+
 from ..errors import RequestRejectedError
 from ..telemetry import tracing as _tracing
 from ..telemetry.registry import MetricsRegistry, get_registry
 from . import observe as _observe_mod
-from .cache import BlockKVCache, TRASH_BLOCK, blocks_for_tokens
+from .cache import BlockKVCache, DecodeView, PrefillView, blocks_for_tokens
 
 __all__ = [
     "InferenceEngine",
@@ -480,24 +455,18 @@ class _Tick:
         self.riders = riders
 
 
-def _cache_layers(model) -> tuple[tuple, ...]:
-    """Per KEEPING SUBLAYER ``(kv_heads, head_dim, window)``: what the
-    model says it keeps of a sequence (``cache_layers()``, the protocol of
-    :class:`~fluxmpi_tpu.models.DecoderLM`; ``kv_heads`` None: a latent
-    layer, ONE row of ``head_dim`` a token and no V; ``("state", state
-    shape, tail shape)``: a layer that keeps one recurrent state and one
-    convolution tail a SEQUENCE), else
-    :class:`~fluxmpi_tpu.models.TransformerLM`'s ``num_heads`` heads of
-    ``d_model // num_heads`` over the whole context. A layer the model
-    says None of keeps NOTHING (a feed-forward alone): it never calls
-    ``attention_fn``, so it is left out here; a layer of two mixers is
-    two entries. The cache's layers are these keeping sublayers,
-    numbered in the order their calls come."""
+def _cache_layers(model) -> tuple:
+    """What each sublayer of ``model`` keeps of a sequence
+    (:class:`~fluxmpi_tpu.models.decoder.Keeps` records): the model's own
+    word (``cache_layers()``), else a TransformerLM's ``num_heads`` heads
+    of ``d_model // num_heads`` over the whole context in every layer."""
     layers = getattr(model, "cache_layers", None)
     if layers is not None:
-        return tuple(layer for layer in layers() if layer is not None)
+        return layers()
+    from ..models.decoder import Keeps
+
     heads = int(model.num_heads)
-    return ((heads, int(model.d_model) // heads, None),) * int(
+    return (Keeps("full", heads, int(model.d_model) // heads),) * int(
         model.num_layers
     )
 
@@ -505,8 +474,6 @@ def _cache_layers(model) -> tuple[tuple, ...]:
 def _expert_counts(state) -> list:
     """Every ``expert_tokens`` array a model's expert layers sowed into
     ``intermediates`` (``[num_experts]`` int32 each), in tree order."""
-    import jax
-
     return [
         leaf for path, leaf in jax.tree_util.tree_flatten_with_path(
             state.get("intermediates", {})
@@ -515,217 +482,21 @@ def _expert_counts(state) -> list:
     ]
 
 
-class _PagedDecodeAttention:
-    """The decode program's ``attention_fn``: the K/V state of one traced
-    decode step. flax's attention sublayer hands it the new token's
-    ``query`` / ``key`` / ``value`` (``[slots, 1, heads, head_dim]``, from
-    the model's own ``attn/{query,key,value}`` projections); each call —
-    one per keeping sublayer, in call order (:attr:`layer` steps once a
-    call that keeps something, so a layer of two mixers steps it twice) —
-    writes the key and value rows into
-    the pools at ``(table[pos // block_size], pos % block_size)`` and then
-    attends through the block tables. Idle slots carry all-trash tables:
-    their rows land in the trash block and their length is 0. Layers of a
-    kind (:attr:`BlockKVCache.kinds`) share a pool and a table; a window
-    kind's table is a ring. A latent layer calls :meth:`latent` instead:
-    its one row a token goes into its kind's one pool, and the absorbed
-    queries attend the rows through the same tables. A state layer
-    (Mamba-2) calls :meth:`conv_tail` and then :meth:`state_update`: its
-    kind's pools hold one state and one convolution tail a sequence, and
-    the update moves the live slots' states where they lie. The step
-    reads the updated pools back from :attr:`k_pools` / :attr:`v_pools`."""
+class _FlaxAttentionFn:
+    """:class:`~fluxmpi_tpu.models.TransformerLM`'s way into a decode
+    view: flax's ``attention_fn``, the only seam its ``EncoderBlock`` has.
+    flax does not say which layer calls, the layers call in order, and
+    every one keeps K/V rows: the n-th call is sublayer n's. (The one call
+    counter left; it goes with the second decoder tree, ROADMAP D3.)"""
 
-    # What a layer asks before it chooses its form: this function attends
-    # a cache, not the call's own tokens.
-    from_cache = True
-
-    def __init__(self, cache: BlockKVCache, k_pools, v_pools, tables,
-                 positions, kernel: bool):
-        import jax.numpy as jnp
-
-        from ..ops.paged_attention import live_block_walk
-
-        # One pool, one table and one written block a kind of layer.
-        self.k_pools, self.v_pools = list(k_pools), list(v_pools)
-        self.tables = tables
-        self.windows = [kind.window for kind in cache.kinds]
-        self.layer_kind = cache.layer_kind
-        entry = positions // cache.block_size
-        self.blocks = [
-            jnp.take_along_axis(
-                table,
-                # A window kind's table is a ring.
-                (entry if window is None else entry % table.shape[1])[:, None],
-                axis=1,
-            )[:, 0]
-            for table, window in zip(tables, self.windows)
-        ]
-        self.offset = positions % cache.block_size
-        self.lengths = jnp.where(
-            tables[0][:, 0] != TRASH_BLOCK, positions + 1, 0
-        )
-        self.kernel = kernel
-        self.layer = 0
-        # What the paged kernels visit, a kind of K/V layer: its slots'
-        # live blocks, listed once for every layer that shares the table.
-        self.walks = [
-            live_block_walk(table, self.lengths, window=kind.window,
-                            block_size=cache.block_size)
-            if kernel and kind.state is None else None
-            for table, kind in zip(tables, cache.kinds)
-        ]
-        # A state kind's table is one entry wide: each slot's pool entry
-        # (the trash entry: an idle slot), and the live ones compacted
-        # once for every layer's update.
-        self.entries = self.live = None
-        at = cache.state_kind
-        if at is not None:
-            from ..ops.ssm import live_entries
-
-            self.entries = tables[at][:, 0]
-            self.live = live_entries(self.entries)
-            self.tail_shape = cache.kinds[at].state[1]
-
-    def conv_tail(self):
-        """A state layer's first call: its slots' convolution tails
-        ``[slots, d_conv - 1, conv_dim]`` out of the pool (an idle
-        slot's: the trash entry's, whatever it holds)."""
-        from ..ops.ssm import tail_from_pool_layout
-
-        kind, layer = self.layer_kind[self.layer]
-        return tail_from_pool_layout(
-            self.v_pools[kind][layer, self.entries], self.tail_shape)
-
-    def state_update(self, tail, x, step, decay, b, c):
-        """A state layer's second call: the LIVE slots' states moved one
-        token where they lie and their new ``tail`` ``[slots, d_conv - 1,
-        conv_dim]`` written over the old, in one walk over them (``x``
-        ``[slots, heads, head_dim]``, ``step`` and ``decay`` ``[slots,
-        heads]``, ``b`` and ``c`` ``[slots, d_state]``); ``H_t C_t``
-        ``[slots, heads, head_dim]`` back, zero for idle slots."""
-        from ..ops.ssm import ssm_state_update
-
-        kind, layer = self.layer_kind[self.layer]
-        self.layer += 1
-        # The update chooses its own form from the backend and the
-        # pool's shape: the kernel on a TPU, its plain twin elsewhere.
-        out, self.k_pools[kind], self.v_pools[kind] = ssm_state_update(
-            self.k_pools[kind], self.v_pools[kind], self.entries, tail, x,
-            step, decay, b, c, layer=layer, live=self.live,
-        )
-        return out
+    def __init__(self, view):
+        self.view = view
+        self.calls = 0
 
     def __call__(self, query, key, value):
-        import jax
-
-        from ..ops.paged_attention import (
-            paged_decode_attention,
-            paged_decode_reference,
-        )
-
-        kind, layer = self.layer_kind[self.layer]
-        self.layer += 1
-        slots = query.shape[0]
-        k_pool, v_pool = self.k_pools[kind], self.v_pools[kind]
-        with jax.named_scope("kv_write"):
-            rows = (layer, self.blocks[kind], self.offset)
-            k_pool = self.k_pools[kind] = k_pool.at[rows].set(
-                key.reshape(slots, -1).astype(k_pool.dtype)
-            )
-            v_pool = self.v_pools[kind] = v_pool.at[rows].set(
-                value.reshape(slots, -1).astype(v_pool.dtype)
-            )
-        args = (query[:, 0], k_pool, v_pool, self.tables[kind], self.lengths)
-        with jax.named_scope("decode_attention"):
-            if self.kernel:
-                out = paged_decode_attention(
-                    *args, layer=layer, window=self.windows[kind],
-                    walk=self.walks[kind],
-                )
-            else:
-                out = paged_decode_reference(
-                    *args, layer=layer, window=self.windows[kind])
-        return out[:, None]
-
-    def latent(self, q_abs, q_rope, row):
-        """A latent layer's call: ``row`` ``[slots, 1, width]`` into the
-        pool (padded with zeros to the pool's lanes), then the absorbed
-        queries (``[slots, 1, heads, rank | rope]``) against the slot's
-        rows; ``[slots, 1, heads, rank]`` back."""
-        import jax
-        import jax.numpy as jnp
-
-        from ..ops.paged_attention import (
-            paged_latent_decode_attention,
-            paged_latent_decode_reference,
-        )
-
-        kind, layer = self.layer_kind[self.layer]
-        self.layer += 1
-        pool = self.k_pools[kind]
-        with jax.named_scope("kv_write"):
-            row = jnp.pad(
-                row[:, 0], ((0, 0), (0, pool.shape[3] - row.shape[2]))
-            )
-            pool = self.k_pools[kind] = pool.at[
-                (layer, self.blocks[kind], self.offset)
-            ].set(row.astype(pool.dtype))
-        args = (q_abs[:, 0], q_rope[:, 0], pool, self.tables[kind],
-                self.lengths)
-        with jax.named_scope("decode_attention"):
-            if self.kernel:
-                out = paged_latent_decode_attention(
-                    *args, layer=layer, walk=self.walks[kind])
-            else:
-                out = paged_latent_decode_reference(*args, layer=layer)
-        return out[:, None]
-
-
-class _PrefillAttention:
-    """The prefill program's ``attention_fn`` for a model that speaks the
-    ``cache_layers()`` protocol: causal attention over the padded prompt
-    (within the layer's window; the flash kernels with ``kernel``), and
-    each keeping sublayer's keys and values, in call order, kept for the
-    pool's ``kv_write``; of a
-    latent layer, which rebuilt ``key`` and ``value`` from its ``row``,
-    the row alone; of a state layer (:meth:`keep_state`) the convolution
-    tail and the state after the prompt's last real token."""
-
-    def __init__(self, windows, kernel: bool):
-        self.windows = windows
-        self.kernel = kernel
-        # One entry a keeping sublayer, in call order (what ``windows`` is
-        # indexed by); a state sublayer's are None.
-        self.keys: list = []
-        self.values: list = []
-        # Of the state layers, in their order.
-        self.tails: list = []
-        self.states: list = []
-
-    def keep_state(self, tail, state):
-        """A state layer's call: what the cache keeps of the sequence."""
-        self.keys.append(None)
-        self.values.append(None)
-        self.tails.append(tail)
-        self.states.append(state)
-
-    def __call__(self, query, key, value, row=None):
-        import jax
-
-        from ..models.decoder import causal_attention
-
-        window = self.windows[len(self.keys)]
-        if row is None:
-            self.keys.append(key)
-            self.values.append(value)
-        else:
-            self.keys.append(row[:, :, None])  # one "head" of the row
-            self.values.append(None)
-        with jax.named_scope("prefill_attention"):
-            return causal_attention(
-                query, key, value, window=window,
-                mode="flash" if self.kernel else "naive",
-            )
+        handle = self.view.sublayer(self.calls)
+        self.calls += 1
+        return handle.attend(query, key, value)
 
 
 # ---------------------------------------------------------------------------
@@ -859,38 +630,17 @@ class InferenceEngine:
                 "expert capacity when serving such checkpoints",
                 stacklevel=2,
             )
-        # A model that says what its layers cache (``cache_layers()``)
-        # is served through its ``attention_fn`` / ``head_at`` /
-        # ``token_mask`` protocol; else it is TransformerLM-shaped.
+        # A model that says what its layers keep (``cache_layers()``) is
+        # served through ``cache=`` / ``head_at`` / ``token_mask``; else
+        # it is TransformerLM-shaped.
         self._protocol = hasattr(model, "cache_layers")
-        layers = _cache_layers(model)
-        # Layers that keep a state a sequence, and those that keep rows a
-        # token (K/V heads or a latent row, of one shape a model).
-        states = [layer[1:] if layer[0] == "state" else None
-                  for layer in layers]
-        rows = [layer for layer, state in zip(layers, states)
-                if state is None]
-        if len({(heads, dim) for heads, dim, _ in rows}) > 1:
-            shapes = sorted({(h, d) for h, d, _ in rows}, key=str)
-            raise ValueError(
-                f"every layer must cache K/V heads (or a latent row) of "
-                f"one shape; got {shapes}"
-            )
-        heads, dim, _ = rows[0] if rows else (1, 1, None)
         self.cache = BlockKVCache(
-            num_layers=len(layers),
-            num_heads=heads or 1,
-            head_dim=dim,
+            _cache_layers(model),
             num_blocks=nb,
             block_size=self.block_size,
             max_blocks_per_seq=self.max_blocks_per_seq,
             # The attention sublayer computes K and V in the model's dtype.
             dtype=model.dtype,
-            layer_windows=[None if state else layer[2]
-                           for layer, state in zip(layers, states)],
-            layer_latent=[state is None and layer[0] is None
-                          for layer, state in zip(layers, states)],
-            layer_state=states,
         )
         if check_memory:
             fits, detail = self.cache.fits_device()
@@ -1018,15 +768,12 @@ class InferenceEngine:
 
     def _build_decode_step(self):
         """ONE fixed-shape program advancing every slot a token: the
-        model once over ``[slots, 1]`` tokens at per-slot positions,
-        attention through :class:`_PagedDecodeAttention` (each layer
-        writes its new K/V row into the donated pool, then reads the
-        slot's blocks in place), argmax the next tokens. A slot whose
-        last token the host has not fetched yet (``use_prev``) takes it
-        from ``prev``, the previous step's output, on the device."""
-        import jax
-        import jax.numpy as jnp
-
+        model once over ``[slots, 1]`` tokens at per-slot positions
+        through a decode view of the cache (each keeping sublayer writes
+        its new row into the donated pool, then reads the slot's blocks
+        in place), argmax the next tokens. A slot whose last token the
+        host has not fetched yet (``use_prev``) takes it from ``prev``,
+        the previous step's output, on the device."""
         from ..models.transformer import _resolve_attention_mode
 
         model = self.model
@@ -1042,22 +789,20 @@ class InferenceEngine:
             # (tables[kind]: [slots, entries]); positions / tokens /
             # use_prev: [slots]; prev: the previous step's whole ``nxt``.
             tokens = jnp.where(use_prev, prev[:slots], tokens)
-            attend = _PagedDecodeAttention(
-                cache, k_pools, v_pools, tables, positions, kernel
-            )
+            view = DecodeView(
+                cache, k_pools, v_pools, tables, positions, kernel)
             # The model's own blocks (make_ff included) around the paged
             # attention; K/V state lives in the pool, not in a flax cache.
             if protocol:
-                logits, state = model.clone(attention_fn=attend).apply(
+                logits, state = model.apply(
                     {"params": params["params"]}, tokens[:, None],
-                    pos_offset=positions,
-                    token_mask=(tables[0][:, :1] != TRASH_BLOCK),
-                    mutable=["intermediates"],
+                    pos_offset=positions, token_mask=view.token_mask,
+                    cache=view, mutable=["intermediates"],
                 )
             else:
                 paged = model.clone(
-                    decode=False, attention="naive", attention_fn=attend,
-                    dropout=0.0,
+                    decode=False, attention="naive",
+                    attention_fn=_FlaxAttentionFn(view), dropout=0.0,
                 )
                 logits = paged.apply(
                     {"params": params["params"]}, tokens[:, None],
@@ -1072,7 +817,7 @@ class InferenceEngine:
                 # layers is known once the step is traced.)
                 expert_layers[0] = len(counts)
                 nxt = jnp.concatenate([nxt, *counts])
-            return nxt, tuple(attend.k_pools), tuple(attend.v_pools)
+            return nxt, *view.pools()
 
         # On the chip the compiler prefetches every large operand into
         # fast memory in up to four slices (a `slice-start` /
@@ -1085,122 +830,47 @@ class InferenceEngine:
 
     def _prefill_step(self, bucket: int):
         """The per-bucket prefill program: one causal forward over the
-        padded prompt, K/V scattered straight into the pool blocks
-        (masked positions land in the trash block), first generated
-        token argmax'd from the last real position's logits."""
+        padded prompt through a prefill view of the cache, which then
+        writes what every sublayer keeps of it straight into the
+        sequence's pool blocks (masked positions land in the trash
+        block); the first generated token argmax'd from the last real
+        position's logits."""
         fn = self._prefill_steps.get(bucket)
         if fn is not None:
             return fn
-        import jax
-        import jax.numpy as jnp
-
         from ..models.generate import prefill_kv
         from ..models.transformer import _resolve_attention_mode
         from ..ops.flash_attention import attention_scope
 
         model = self.model
         cache = self.cache
-        bs = self.block_size
         kernel = _resolve_attention_mode(model.attention) == "flash"
         protocol = self._protocol
-
-        def write(pool, rows, table, length, window):
-            """``rows`` ``[layers, bucket, heads * head_dim]`` into the
-            layers' pool through the sequence's ``table``; positions past
-            ``length`` (and, in a window kind's ring, before what the
-            window keeps) land in the trash block."""
-            pos = jnp.arange(rows.shape[1])
-            keep = pos < length
-            entry = pos // bs
-            if window is not None:
-                ring = table.shape[0]
-                keep &= entry > (length - 1) // bs - ring
-                entry = entry % ring
-            blk = jnp.where(keep, table[entry], jnp.int32(TRASH_BLOCK))
-            if rows.shape[2] != pool.shape[3]:
-                # A latent row, padded to the pool's whole lane tiles.
-                rows = jnp.pad(
-                    rows, ((0, 0), (0, 0), (0, pool.shape[3] - rows.shape[2]))
-                )
-            # One row per (layer, position), indexed on every leading
-            # dimension: a window over the layers makes XLA move the
-            # whole pool into a layers-minor layout and back.
-            layers = jnp.arange(rows.shape[0])[:, None]
-            return pool.at[(layers, blk[None], (pos % bs)[None])].set(
-                rows.astype(pool.dtype)
-            )
 
         def prefill(params, k_pools, v_pools, tokens, length, tables):
             # tokens: [bucket]; length: true prompt length; k_pools /
             # v_pools / tables ([entries]): one entry a kind of layer.
             # The head runs at the last real position only.
+            view = PrefillView(
+                cache, k_pools, v_pools, tables, length, kernel)
             if protocol:
-                attend = _PrefillAttention(
-                    [cache.kinds[at].window for at, _ in cache.layer_kind],
-                    kernel,
-                )
-                last = model.clone(attention_fn=attend).apply(
+                last = model.apply(
                     {"params": params["params"]}, tokens[None],
                     head_at=(length - 1)[None],
                     token_mask=(jnp.arange(tokens.shape[0]) < length)[None],
+                    cache=view,
                 )[0]
-                # The layers that keep rows a token, in layer order. A
-                # latent layer keeps rows and no values (such layers are
-                # of one shape: __init__).
-                stacked = [i for i, key in enumerate(attend.keys)
-                           if key is not None]
-                k = v = None
-                if stacked:
-                    k = jnp.stack([attend.keys[i] for i in stacked])
-                    if attend.values[stacked[0]] is not None:
-                        v = jnp.stack([attend.values[i] for i in stacked])
             else:
-                stacked = list(range(cache.num_layers))
                 with attention_scope("prefill_attention"):
                     k, v, logits = prefill_kv(
                         model, params, tokens[None],
                         head_at=(length - 1)[None],
                     )
+                view.keep_rows(k, v)
                 last = logits[0]
-            with jax.named_scope("kv_write"):
-                # [layers, bucket, heads * head_dim]: the pool's row.
-                if k is not None:
-                    k = k[:, 0].reshape(k.shape[0], k.shape[2], -1)
-                if v is not None:
-                    v = v[:, 0].reshape(v.shape[0], v.shape[2], -1)
-                k_pools, v_pools = list(k_pools), list(v_pools)
-                for i, kind in enumerate(cache.kinds):
-                    if kind.state is not None:
-                        continue
-                    # Every stacked layer (the only such kind), or this
-                    # kind's among them.
-                    mine = (slice(None)
-                            if len(kind.layer_ids) == len(stacked)
-                            else np.asarray([stacked.index(layer)
-                                             for layer in kind.layer_ids]))
-                    k_pools[i] = write(k_pools[i], k[mine], tables[i],
-                                       length, kind.window)
-                    if v is not None:
-                        v_pools[i] = write(v_pools[i], v[mine], tables[i],
-                                           length, kind.window)
-            at = cache.state_kind
-            if at is not None:
-                # The sequence's one entry, every state layer's, whole:
-                # nothing of the entry's last holder is left.
-                from ..ops.ssm import tail_to_pool_layout, to_pool_layout
-
-                with jax.named_scope("state_write"):
-                    entry = tables[at][0]
-                    states = [to_pool_layout(s) for s in attend.states]
-                    tails = [tail_to_pool_layout(t) for t in attend.tails]
-                    for pool, kept in ((k_pools, states), (v_pools, tails)):
-                        rows = jnp.stack(kept)  # [layers, 1, ...]
-                        pool[at] = jax.lax.dynamic_update_slice(
-                            pool[at], rows.astype(pool[at].dtype),
-                            (0, entry) + (0,) * (rows.ndim - 2),
-                        )
+            k_pools, v_pools = view.pools()
             first = jnp.argmax(last, axis=-1).astype(jnp.int32)
-            return first, tuple(k_pools), tuple(v_pools)
+            return first, k_pools, v_pools
 
         fn = jax.jit(prefill, donate_argnums=(1, 2))
         self._prefill_steps[bucket] = fn
@@ -1224,8 +894,6 @@ class InferenceEngine:
                 "stop() first (new prefill buckets also compile "
                 "on-demand at admission)"
             )
-        import jax.numpy as jnp
-
         buckets = {self._bucket(max(1, int(p))) for p in prompt_lengths}
         buckets.add(self.block_size)
         cache = self.cache
@@ -1248,8 +916,6 @@ class InferenceEngine:
     def _idle_tick(self):
         """``tables, positions, tokens`` of a decode step that carries no
         slot: every write lands in the trash block."""
-        import jax.numpy as jnp
-
         zeros = jnp.zeros((self.slots,), jnp.int32)
         return tuple(
             jnp.zeros((self.slots, kind.entries), jnp.int32)
@@ -1262,9 +928,6 @@ class InferenceEngine:
         model's expert layers append their counts to the tokens): one
         abstract trace, so that the step compiles ONE signature."""
         if self._prev is None:
-            import jax
-            import jax.numpy as jnp
-
             cache = self.cache
             out = jax.eval_shape(
                 self._decode_step, self.params, cache.k_pools, cache.v_pools,
@@ -1397,8 +1060,6 @@ class InferenceEngine:
         return admitted
 
     def _admit(self, req: ServingRequest, slot_ix: int, total: int) -> None:
-        import jax.numpy as jnp
-
         plen = int(req.prompt.shape[0])
         bucket = self._bucket(plen)
         # Every active slot stalls for the length of this span.
@@ -1468,8 +1129,6 @@ class InferenceEngine:
         feeds the host's. A slot whose request has an ``eos_token`` rides
         speculatively: if the tick in flight ends it, this tick's token
         for it is discarded at delivery. None when no slot rides."""
-        import jax.numpy as jnp
-
         from .. import faults
 
         riders = [

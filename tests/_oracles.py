@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from fluxmpi_tpu.ops import ssm
+from fluxmpi_tpu.serving.cache import DecodeView
 
 
 def dense_seg_attention(q, k, v, qseg, kseg, causal=False, window=None):
@@ -51,11 +52,11 @@ def poisoned_past_the_groups(grouped):
 
 
 class DenseState:
-    """An ``attention_fn`` that keeps a cache: ONE sequence's state and
-    tail, moved a token a call (what the engine's pools hold an entry
+    """A state sublayer's handle that reads a pool: ONE sequence's state
+    and tail, moved a token a call (what the engine's pools hold an entry
     of)."""
 
-    from_cache = True
+    kind, reads_pool = "state", True
 
     def __init__(self, config):
         heads, hd, n = (config.mamba_n_heads, config.mamba_d_head,
@@ -65,14 +66,10 @@ class DenseState:
         self.tails = jnp.zeros(
             (1, 2, *ssm.tail_to_pool_layout(jnp.zeros(self.tail_shape)).shape))
 
-    @property
     def tail(self):
         return ssm.tail_from_pool_layout(self.tails[0, 1:], self.tail_shape)
 
-    def conv_tail(self):
-        return self.tail
-
-    def state_update(self, tail, x, step, decay, b, c):
+    def update(self, tail, x, step, decay, b, c):
         y, self.pool, self.tails = ssm.ssm_state_update_reference(
             self.pool, self.tails, jnp.ones((1,), jnp.int32), tail, x, step,
             decay, b, c)
@@ -80,20 +77,21 @@ class DenseState:
 
 
 class Kept:
-    """An ``attention_fn`` of a prefill: what the layer hands a cache."""
+    """A state sublayer's handle of a prefill: what the layer hands a
+    cache."""
 
-    def keep_state(self, tail, state):
+    kind, reads_pool = "state", False
+
+    def keep(self, tail, state):
         self.tail, self.state = tail, state
 
 
 def served_logits(eng, variables, prompt, ticks):
     """The logits the engine's own programs give: the prefill program
     over ``prompt`` (into slot 1's blocks and state entry), then
-    ``ticks`` decode ticks over the engine's pools through
-    :class:`_PagedDecodeAttention` as the decode step builds it, each fed
-    the token the last put first. ``(tokens, logits [ticks, vocab])``."""
-    from fluxmpi_tpu.serving.engine import _PagedDecodeAttention
-
+    ``ticks`` decode ticks over the engine's pools through the cache's
+    decode view as the decode step builds it, each fed the token the last
+    put first. ``(tokens, logits [ticks, vocab])``."""
     cache, model = eng.cache, eng.model
     total = len(prompt) + ticks + 1
     kinds = range(len(cache.kinds))
@@ -113,14 +111,14 @@ def served_logits(eng, variables, prompt, ticks):
     @jax.jit
     def tick(k_pools, v_pools, position, token):
         positions = jnp.stack([jnp.int32(0), position])
-        attend = _PagedDecodeAttention(
+        view = DecodeView(
             cache, k_pools, v_pools, slot_tables, positions, kernel=False)
-        logits = model.clone(attention_fn=attend).apply(
+        logits = model.apply(
             variables, jnp.stack([jnp.int32(0), token])[:, None],
-            pos_offset=positions, token_mask=jnp.asarray([[False], [True]]),
+            pos_offset=positions, token_mask=view.token_mask, cache=view,
             mutable=["intermediates"],
         )[0]
-        return logits[1, 0], tuple(attend.k_pools), tuple(attend.v_pools)
+        return logits[1, 0], *view.pools()
 
     tokens, rows = [int(first)], []
     for t in range(ticks):

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from _oracles import Kept as _Kept, served_logits as _served_logits
-from fluxmpi_tpu.models import DecoderConfig
+from fluxmpi_tpu.models import DecoderConfig, Keeps
 from fluxmpi_tpu.models.decoder import MambaMixer
 from fluxmpi_tpu.serving import InferenceEngine
 
@@ -163,27 +163,41 @@ def test_falcon_parameter_tree_and_the_keeping_sublayers_in_call_order():
     assert params["layer_0"]["mamba"]["w_in"].shape == (
         64, 64 + (64 + 2 * 2 * 16) + 8)
     # Each layer keeps a STATE a sequence and K/V rows a token: two
-    # keeping sublayers, the Mamba mixer's call first.
-    state = ("state", (8, 8, 16), (3, 64 + 2 * 2 * 16))
-    assert model.cache_layers() == (state, (2, 16, None)) * 3
+    # keeping sublayers, the state numbered first.
+    state = Keeps("state", state=(8, 8, 16), tail=(3, 64 + 2 * 2 * 16))
+    assert model.cache_layers() == (state, Keeps("full", 2, 16)) * 3
 
-    class Order:
-        """Which of ``attention_fn``'s calls came, in order."""
+    class Numbered:
+        """A view whose handles say which sublayer's they are and what
+        their mixer did with them."""
 
-        def __init__(self):
-            self.calls = []
+        def __init__(self, kinds):
+            self.kinds, self.used = kinds, {}
 
-        def keep_state(self, tail, state):
-            self.calls.append("state")
+        def sublayer(self, number):
+            view = self
 
-        def __call__(self, q, k, v):
-            self.calls.append("kv")
-            return jnp.zeros_like(q)
+            class Handle:
+                kind, reads_pool = view.kinds[number], False
 
-    order = Order()
-    model.clone(attention_fn=order).apply(
-        variables, jnp.zeros((1, 8), jnp.int32))
-    assert order.calls == ["state", "kv"] * 3
+                def keep(self, tail, state):
+                    view.used[number] = "state"
+
+                def attend(self, q, k, v):
+                    view.used[number] = "kv"
+                    return jnp.zeros_like(q)
+
+            return Handle()
+
+    # Each sublayer received the handle of its own number.
+    view = Numbered(["state", "full"] * 3)
+    model.apply(variables, jnp.zeros((1, 8), jnp.int32), cache=view)
+    assert view.used == {n: ("state", "kv")[n % 2] for n in range(6)}
+    # A model that numbers its sublayers otherwise than its cache does
+    # fails while it is traced, and reads no other sublayer's rows.
+    with pytest.raises(TypeError, match="keeps state was handed.*'full'"):
+        model.apply(variables, jnp.zeros((1, 8), jnp.int32),
+                    cache=Numbered(["full", "state"] * 3))
 
 
 # ---------------------------------------------------------------------------
@@ -207,10 +221,10 @@ def test_a_padded_prompt_leaves_the_state_and_tail_of_the_unpadded(length):
                           (bucket, cfg["hidden_size"]))
     mask = (jnp.arange(bucket) < length)[None]
     padded, plain = _Kept(), _Kept()
-    out = MambaMixer(config, jnp.float32, padded).apply(
-        variables, u[None], mask)[0]
-    want = MambaMixer(config, jnp.float32, plain).apply(
-        variables, u[None, :length])[0]
+    out = MambaMixer(config, jnp.float32).apply(
+        variables, u[None], mask, padded)[0]
+    want = MambaMixer(config, jnp.float32).apply(
+        variables, u[None, :length], cache=plain)[0]
     np.testing.assert_allclose(out[:length], want, rtol=0, atol=2e-6)
     np.testing.assert_allclose(padded.state, plain.state, rtol=0, atol=2e-6)
     np.testing.assert_array_equal(padded.tail, plain.tail)

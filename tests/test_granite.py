@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from _oracles import (DenseState as _DenseState, Kept as _Kept,
                       poisoned_past_the_groups,
                       served_logits as _served_logits)
-from fluxmpi_tpu.models import DecoderConfig, ExpertMLP
+from fluxmpi_tpu.models import DecoderConfig, ExpertMLP, Keeps
 from fluxmpi_tpu.models.decoder import MambaMixer
 from fluxmpi_tpu.ops.ssm import from_pool_layout, tail_from_pool_layout
 from fluxmpi_tpu.serving import InferenceEngine
@@ -135,8 +135,9 @@ def test_granite_parameter_tree_and_cache_layers():
                                       "moe"}
     assert set(params["layer_2"]["attn"]) == {"wq", "wk", "wv", "wo"}
     # A Mamba layer keeps a STATE a sequence, not rows a token.
-    state = ("state", (8, 16, 16), (3, 8 * 16 + 2 * 16))
-    assert model.cache_layers() == (state, state, (2, 16, None), state)
+    state = Keeps("state", state=(8, 16, 16), tail=(3, 8 * 16 + 2 * 16))
+    assert model.cache_layers() == (
+        state, state, Keeps("full", 2, 16), state)
 
 
 def test_mamba_scalars_start_where_mamba2_publishes_them():
@@ -179,20 +180,22 @@ def test_mixer_chunked_equals_recurrence_equals_reference(seq):
     want, want_state, want_tail = ref.mamba(u, w, cfg, state_out=True)
     # Over its own tokens: the chunked scan (chunks of 8).
     kept = _Kept()
-    got = MambaMixer(config, jnp.float32, kept).apply(variables, u[None])[0]
+    got = MambaMixer(config, jnp.float32).apply(
+        variables, u[None], cache=kept)[0]
     # float32 on both sides; the chunked sums in another order.
     np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
     np.testing.assert_allclose(kept.state[0], want_state, rtol=0, atol=2e-5)
     np.testing.assert_allclose(kept.tail[0], want_tail, rtol=0, atol=1e-6)
     # A token a call against a cache: the recurrence itself.
     cache = _DenseState(config)
-    layer = MambaMixer(config, jnp.float32, cache)
+    layer = MambaMixer(config, jnp.float32)
     steps = jnp.concatenate(
-        [layer.apply(variables, u[None, t:t + 1])[0] for t in range(seq)])
+        [layer.apply(variables, u[None, t:t + 1], cache=cache)[0]
+         for t in range(seq)])
     np.testing.assert_allclose(steps, want, rtol=0, atol=2e-5)
     np.testing.assert_allclose(from_pool_layout(cache.pool[0, 1], 8),
                                want_state, rtol=0, atol=2e-5)
-    np.testing.assert_allclose(cache.tail[0], want_tail, rtol=0, atol=1e-6)
+    np.testing.assert_allclose(cache.tail()[0], want_tail, rtol=0, atol=1e-6)
 
 
 # Shorter than the convolution reaches, inside a chunk, on a block's edge.
@@ -205,10 +208,10 @@ def test_a_padded_prompt_leaves_the_state_and_tail_of_the_unpadded(length):
                           (bucket, cfg["hidden_size"]))
     mask = (jnp.arange(bucket) < length)[None]
     padded, plain = _Kept(), _Kept()
-    out = MambaMixer(config, jnp.float32, padded).apply(
-        variables, u[None], mask)[0]
-    want = MambaMixer(config, jnp.float32, plain).apply(
-        variables, u[None, :length])[0]
+    out = MambaMixer(config, jnp.float32).apply(
+        variables, u[None], mask, padded)[0]
+    want = MambaMixer(config, jnp.float32).apply(
+        variables, u[None, :length], cache=plain)[0]
     np.testing.assert_allclose(out[:length], want, rtol=0, atol=1e-6)
     # The state after the bucket IS the state after the prompt; the tail
     # its last three real columns, zeros before the start.
@@ -644,16 +647,16 @@ def test_a_model_without_state_layers_says_nothing_of_states():
 # ---------------------------------------------------------------------------
 
 STATE = ((128, 64, 128), (3, 8448))
+KEEPS_STATE = Keeps("state", state=STATE[0], tail=STATE[1])
 
 
 def _cache(**kw):
     # The cell's first period: 9 state layers around one attention layer
     # of 8 K/V heads of 128; 4 sequences of 10 blocks of 256.
-    layers = [STATE] * 5 + [None] + [STATE] * 4
+    layers = [KEEPS_STATE] * 5 + [Keeps("full", 8, 128)] + [KEEPS_STATE] * 4
     return BlockKVCache(
-        num_layers=10, num_heads=8, head_dim=128, num_blocks=1 + 4 * 10,
-        block_size=256, max_blocks_per_seq=10, dtype=jnp.bfloat16,
-        layer_state=layers, **kw)
+        layers, num_blocks=1 + 4 * 10, block_size=256, max_blocks_per_seq=10,
+        dtype=jnp.bfloat16, **kw)
 
 
 @pytest.mark.parametrize("tokens", [1, 256, 257, 2560])
@@ -701,21 +704,17 @@ def test_state_kind_counts_float32_states_and_tails_by_their_bytes():
 
 
 def test_state_kind_refuses_what_it_cannot_hold():
-    with pytest.raises(ValueError, match="layer_state names 2 layers"):
-        BlockKVCache(num_layers=3, num_heads=1, head_dim=8, num_blocks=9,
-                     block_size=4, max_blocks_per_seq=2,
-                     layer_state=[STATE, None])
+    geometry = dict(num_blocks=9, block_size=4, max_blocks_per_seq=2)
     with pytest.raises(ValueError, match="one state shape a model"):
-        BlockKVCache(num_layers=2, num_heads=1, head_dim=8, num_blocks=9,
-                     block_size=4, max_blocks_per_seq=2,
-                     layer_state=[STATE, ((2, 2, 2), (3, 8))])
-    with pytest.raises(ValueError, match="keeps no token's rows"):
-        BlockKVCache(num_layers=2, num_heads=1, head_dim=8, num_blocks=9,
-                     block_size=4, max_blocks_per_seq=2,
-                     layer_state=[STATE, None], layer_windows=[16, None])
-    # A model of state layers alone: one kind, live slots told by it.
-    alone = BlockKVCache(num_layers=2, num_heads=1, head_dim=1, num_blocks=9,
-                         block_size=4, max_blocks_per_seq=2,
-                         layer_state=[STATE, STATE])
+        BlockKVCache([KEEPS_STATE,
+                      Keeps("state", state=(2, 2, 2), tail=(3, 8))],
+                     **geometry)
+    with pytest.raises(ValueError, match="and no other, names its window"):
+        BlockKVCache([KEEPS_STATE._replace(window=16), Keeps("full", 1, 8)],
+                     **geometry)
+    # A model of state layers alone: one kind, live slots told by it; a
+    # sublayer that keeps nothing is no layer of the cache.
+    alone = BlockKVCache([KEEPS_STATE, None, KEEPS_STATE], **geometry)
+    assert alone.num_layers == 2
     assert [k.state for k in alone.kinds] == [STATE]
     assert alone.kinds[0].num_blocks == 1 + 4

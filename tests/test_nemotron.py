@@ -20,7 +20,7 @@ import jax.numpy as jnp
 
 from _oracles import (DenseState as _DenseState, Kept as _Kept,
                       served_logits as _served_logits)
-from fluxmpi_tpu.models import DecoderConfig, ExpertMLP
+from fluxmpi_tpu.models import DecoderConfig, ExpertMLP, Keeps
 from fluxmpi_tpu.models.decoder import MambaMixer
 from fluxmpi_tpu.ops.ssm import from_pool_layout
 from fluxmpi_tpu.serving import InferenceEngine
@@ -164,8 +164,9 @@ def test_nemotron_parameter_tree_and_cache_layers():
         96, 128 + (128 + 2 * 2 * 16) + 8)
     # A Mamba layer keeps a STATE a sequence, the attention layer rows a
     # token, an expert layer NOTHING.
-    state = ("state", (8, 16, 16), (3, 8 * 16 + 2 * 2 * 16))
-    assert model.cache_layers() == (state, None, state, (2, 16, None), None)
+    state = Keeps("state", state=(8, 16, 16), tail=(3, 8 * 16 + 2 * 2 * 16))
+    assert model.cache_layers() == (
+        state, None, state, Keeps("full", 2, 16), None)
     assert model.expert_row_tile(4) is None  # a CPU: ragged_dot
 
 
@@ -192,18 +193,20 @@ def test_mixer_chunked_equals_recurrence_equals_reference(seq, groups):
     u = jax.random.normal(jax.random.PRNGKey(seq), (seq, cfg["hidden_size"]))
     want, want_state, want_tail = ref.mamba(u, w, cfg, state_out=True)
     kept = _Kept()
-    got = MambaMixer(config, jnp.float32, kept).apply(variables, u[None])[0]
+    got = MambaMixer(config, jnp.float32).apply(
+        variables, u[None], cache=kept)[0]
     np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
     np.testing.assert_allclose(kept.state[0], want_state, rtol=0, atol=2e-5)
     np.testing.assert_allclose(kept.tail[0], want_tail, rtol=0, atol=1e-6)
     cache = _DenseState(config)
-    layer = MambaMixer(config, jnp.float32, cache)
+    layer = MambaMixer(config, jnp.float32)
     steps = jnp.concatenate(
-        [layer.apply(variables, u[None, t:t + 1])[0] for t in range(seq)])
+        [layer.apply(variables, u[None, t:t + 1], cache=cache)[0]
+         for t in range(seq)])
     np.testing.assert_allclose(steps, want, rtol=0, atol=2e-5)
     np.testing.assert_allclose(from_pool_layout(cache.pool[0, 1], 8),
                                want_state, rtol=0, atol=2e-5)
-    np.testing.assert_allclose(cache.tail[0], want_tail, rtol=0, atol=1e-6)
+    np.testing.assert_allclose(cache.tail()[0], want_tail, rtol=0, atol=1e-6)
 
 
 def test_groups_matter_and_one_group_is_still_granites_numbers():
@@ -244,10 +247,10 @@ def test_a_padded_prompt_leaves_the_state_and_tail_of_the_unpadded(length):
                           (bucket, cfg["hidden_size"]))
     mask = (jnp.arange(bucket) < length)[None]
     padded, plain = _Kept(), _Kept()
-    out = MambaMixer(config, jnp.float32, padded).apply(
-        variables, u[None], mask)[0]
-    want = MambaMixer(config, jnp.float32, plain).apply(
-        variables, u[None, :length])[0]
+    out = MambaMixer(config, jnp.float32).apply(
+        variables, u[None], mask, padded)[0]
+    want = MambaMixer(config, jnp.float32).apply(
+        variables, u[None, :length], cache=plain)[0]
     np.testing.assert_allclose(out[:length], want, rtol=0, atol=1e-6)
     np.testing.assert_allclose(padded.state, plain.state, rtol=0, atol=1e-6)
     np.testing.assert_array_equal(padded.tail, plain.tail)

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from _oracles import poisoned_past_the_groups
-from fluxmpi_tpu.models import DecoderConfig, ExpertMLP
+from fluxmpi_tpu.models import DecoderConfig, ExpertMLP, Keeps
 from fluxmpi_tpu.models import decoder as decoder_mod
 from fluxmpi_tpu.models.decoder import LatentAttention
 from fluxmpi_tpu.serving import InferenceEngine
@@ -158,16 +158,16 @@ def test_rotary_pairs_rotate_consecutive_lanes_where_they_lie():
 
 
 class _DenseRows:
-    """An ``attention_fn`` that attends a cache: every position's row of
-    one sequence, handed over whole; position ``t``'s absorbed queries
+    """A latent sublayer's handle that reads a pool: every position's row
+    of one sequence, handed over whole; position ``t``'s absorbed queries
     meet rows ``0 .. t`` (what the paged kernel does block by block)."""
 
-    from_cache = True
+    kind, reads_pool = "latent", True
 
     def __init__(self, rows, rank):
         self.rows, self.rank = rows, rank  # [seq, width]
 
-    def latent(self, q_abs, q_rope, row):
+    def attend_absorbed(self, q_abs, q_rope, row):
         t = q_abs.shape[0]  # one token a "slot": [seq, 1, heads, .]
         q = jnp.concatenate([q_abs, q_rope], axis=-1)[:, 0]
         s = jnp.einsum("thc,kc->thk", q, self.rows)
@@ -195,24 +195,25 @@ def test_latent_layer_absorbed_equals_unabsorbed_equals_reference(scaling):
         np.testing.assert_allclose(got, want, rtol=0, atol=2e-6,
                                    err_msg=mode)
 
-    # The rows a cache would keep: what the prefill's seam is handed.
-    kept = []
+    # The rows a cache would keep: what the prefill's handle is handed.
+    class Keep:
+        kind, reads_pool = "latent", False
 
-    def keep(q, k, v, row=None):
-        kept.append(row)
-        assert q.shape[-1] == k.shape[-1] == 24 and v.shape[-1] == 16
-        return decoder_mod.causal_attention(q, k, v, window=None,
-                                            mode="naive")
+        def attend(self, q, k, v, row):
+            self.row = row
+            assert q.shape[-1] == k.shape[-1] == 24 and v.shape[-1] == 16
+            return decoder_mod.causal_attention(q, k, v, window=None,
+                                                mode="naive")
 
-    LatentAttention(config, jnp.float32, attention_fn=keep).apply(
-        params, u[None], positions)
-    rows = kept[0][0]
+    kept = Keep()
+    LatentAttention(config, jnp.float32).apply(
+        params, u[None], positions, kept)
+    rows = kept.row[0]
     assert rows.shape == (seq, config.latent_row)
     # Absorbed: every position a batch row of one token at its position.
-    absorbed = LatentAttention(
-        config, jnp.float32,
-        attention_fn=_DenseRows(rows, config.kv_lora_rank),
-    ).apply(params, u[:, None], jnp.arange(seq)[:, None])[:, 0]
+    absorbed = LatentAttention(config, jnp.float32).apply(
+        params, u[:, None], jnp.arange(seq)[:, None],
+        _DenseRows(rows, config.kv_lora_rank))[:, 0]
     np.testing.assert_allclose(absorbed, want, rtol=0, atol=2e-6)
 
 
@@ -258,7 +259,7 @@ def test_sarvam_parameter_tree_and_cache_layers():
     assert layer["moe"]["router"].shape == (64, 16)
     assert layer["moe"]["w1"].shape == (4, 64, 32)
     # One row of 32 + 8 a token, no K/V heads, no window.
-    assert model.cache_layers() == ((None, 40, None),) * 3
+    assert model.cache_layers() == (Keeps("latent", width=40),) * 3
 
 
 def test_sarvam_runs_in_bfloat16_and_a_lower_precision_is_further_off():
@@ -547,9 +548,8 @@ def test_decode_tick_counts_held_experts_only_and_says_its_context():
 
 def _cache(**kw):
     return BlockKVCache(
-        num_layers=3, num_heads=1, head_dim=576, num_blocks=1 + 2 * 16,
-        block_size=BLOCK, max_blocks_per_seq=16,
-        layer_latent=[True] * 3, **kw
+        [Keeps("latent", width=576)] * 3, num_blocks=1 + 2 * 16,
+        block_size=BLOCK, max_blocks_per_seq=16, **kw
     )
 
 
@@ -582,23 +582,23 @@ def test_latent_kind_counts_one_padded_row_a_token_and_no_v_pool():
     cache.alloc(128), cache.alloc(128)
     assert not cache.can_alloc(1)
     # Beside K/V layers a latent layer is a third kind, after the others.
+    full = Keeps("full", 1, 128)
     mixed = BlockKVCache(
-        num_layers=4, num_heads=2, head_dim=64, num_blocks=9,
-        block_size=4, max_blocks_per_seq=4, dtype=jnp.bfloat16,
-        layer_windows=[None, 8, None, None],
-        layer_latent=[False, False, True, False],
+        [full, full._replace(kind="window", window=8),
+         Keeps("latent", width=128), full],
+        num_blocks=9, block_size=4, max_blocks_per_seq=4, dtype=jnp.bfloat16,
     )
     assert [(k.layer_ids, k.window, k.latent) for k in mixed.kinds] == [
         ((0, 3), None, False), ((1,), 8, False), ((2,), None, True)]
     assert mixed.layer_kind == [(0, 0), (1, 0), (2, 0), (0, 1)]
     assert mixed.pool_bytes == 2 * (2 * (2 * 9 + 1 * 7) + 1 * 9) * 4 * 128
-    with pytest.raises(ValueError, match="whole context"):
-        BlockKVCache(num_layers=1, num_heads=1, head_dim=8, num_blocks=9,
-                     block_size=4, max_blocks_per_seq=4, layer_windows=[8],
-                     layer_latent=[True])
-    with pytest.raises(ValueError, match="layer_latent names"):
-        BlockKVCache(num_layers=2, num_heads=1, head_dim=8, num_blocks=9,
-                     block_size=4, max_blocks_per_seq=4, layer_latent=[True])
+    with pytest.raises(ValueError, match="and no other, names its window"):
+        BlockKVCache([Keeps("latent", width=8, window=8)], num_blocks=9,
+                     block_size=4, max_blocks_per_seq=4)
+    # A prefill stacks every sublayer's rows for one scatter a kind.
+    with pytest.raises(ValueError, match="of one shape"):
+        BlockKVCache([Keeps("full", 2, 64), Keeps("latent", width=128)],
+                     num_blocks=9, block_size=4, max_blocks_per_seq=4)
 
 
 def test_flash_forward_takes_values_of_their_own_width():

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import fluxmpi_tpu as fm
 from fluxmpi_tpu import faults, runtime, serving
 from fluxmpi_tpu.errors import FaultInjectedError, RequestRejectedError
-from fluxmpi_tpu.models import TransformerLM
+from fluxmpi_tpu.models import Keeps, TransformerLM
 from fluxmpi_tpu.models.generate import generate
 from fluxmpi_tpu.serving import BlockKVCache, InferenceEngine, blocks_for_tokens
 from fluxmpi_tpu.serving import observe
@@ -75,7 +75,7 @@ def _prompt(rng, n):
 
 
 def test_free_list_round_trip():
-    cache = BlockKVCache(num_layers=2, num_heads=4, head_dim=8,
+    cache = BlockKVCache([Keeps("full", 4, 8)] * 2,
                          num_blocks=9, block_size=16, max_blocks_per_seq=4)
     assert cache.free_blocks == 8  # block 0 is the reserved trash block
     assert cache.capacity_tokens == 8 * 16
@@ -95,7 +95,7 @@ def test_free_list_round_trip():
 
 
 def test_allocator_rejects_bad_frees_and_exhaustion():
-    cache = BlockKVCache(num_layers=1, num_heads=1, head_dim=4,
+    cache = BlockKVCache([Keeps("full", 1, 4)],
                          num_blocks=4, block_size=8, max_blocks_per_seq=3)
     blocks = cache.alloc(24)  # all 3
     assert not cache.can_alloc(1)
@@ -115,7 +115,7 @@ def test_blocks_for_tokens_math():
 
 
 def test_table_row_pads_with_trash():
-    cache = BlockKVCache(num_layers=1, num_heads=1, head_dim=4,
+    cache = BlockKVCache([Keeps("full", 1, 4)],
                          num_blocks=8, block_size=8, max_blocks_per_seq=5)
     row = cache.table_row([3, 1])
     assert row.tolist() == [3, 1, 0, 0, 0]
@@ -262,9 +262,10 @@ def test_flash_decode_masks_trash_block_garbage(model, engine_factory):
     lm, variables = model
     eng = engine_factory(slots=2, attention="flash")
     eng.warmup(prompt_lengths=(4, 6))
-    poison = jnp.full_like(eng.cache.k_pool[:, 0], 1e6)
-    eng.cache.k_pool = eng.cache.k_pool.at[:, 0].set(poison)
-    eng.cache.v_pool = eng.cache.v_pool.at[:, 0].set(poison)
+    (k_pool,), (v_pool,) = eng.cache.k_pools, eng.cache.v_pools
+    poison = jnp.full_like(k_pool[:, 0], 1e6)
+    eng.cache.k_pools = (k_pool.at[:, 0].set(poison),)
+    eng.cache.v_pools = (v_pool.at[:, 0].set(poison),)
     rng = np.random.default_rng(11)
     # plen + max_new <= 2 blocks each: most of every gathered row is
     # trash-block garbage.
@@ -1075,7 +1076,7 @@ def test_kv_high_watermark_and_fragmentation():
     """The forensics gauges: the watermark is a pool-lifetime peak (it
     never comes back down), fragmentation measures free-list scatter —
     1 - longest contiguous free run / free blocks."""
-    cache = BlockKVCache(num_layers=2, num_heads=4, head_dim=8,
+    cache = BlockKVCache([Keeps("full", 4, 8)] * 2,
                          num_blocks=9, block_size=8, max_blocks_per_seq=8)
     assert cache.high_watermark_blocks == 0
     assert cache.fragmentation == 0.0  # pristine free list is one run

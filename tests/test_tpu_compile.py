@@ -399,7 +399,7 @@ def _gpt2_medium_serving_programs(topo):
     engine = InferenceEngine(model, params, attention="flash", slots=32,
                              block_size=128, check_memory=False)
     try:
-        pool = _sds(engine.cache.pool_shape, jnp.bfloat16, dev)
+        pool = _sds(engine.cache.pool_shapes[0], jnp.bfloat16, dev)
         mb = engine.max_blocks_per_seq
         decode = engine._decode_step.lower(
             params, (pool,), (pool,), (_sds((32, mb), jnp.int32, dev),),

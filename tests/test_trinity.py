@@ -14,7 +14,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from fluxmpi_tpu.models import DecoderConfig, DecoderLM, ExpertMLP
+from fluxmpi_tpu.models import DecoderConfig, DecoderLM, ExpertMLP, Keeps
 from fluxmpi_tpu.serving import InferenceEngine
 from fluxmpi_tpu.serving.cache import BlockKVCache
 
@@ -96,9 +96,8 @@ def test_decoder_lm_parameter_tree_is_the_mapped_reference_layout():
     )
     shapes = lambda tree: jax.tree_util.tree_map(lambda x: x.shape, tree)
     assert shapes(made) == shapes(variables)
-    assert model.cache_layers() == (
-        (2, 16, WINDOW), (2, 16, WINDOW), (2, 16, None), (2, 16, WINDOW)
-    )
+    ring = Keeps("window", 2, 16, WINDOW)
+    assert model.cache_layers() == (ring, ring, Keeps("full", 2, 16), ring)
 
 
 def test_decoder_lm_runs_in_bfloat16_with_float32_logits():
@@ -315,10 +314,10 @@ def test_engine_tick_returns_expert_counts_with_the_tokens_in_one_array():
 
 
 def _cache(**kw):
+    ring, full = Keeps("window", 2, 16, WINDOW), Keeps("full", 2, 16)
     return BlockKVCache(
-        num_layers=4, num_heads=2, head_dim=16, num_blocks=1 + 3 * 16,
-        block_size=BLOCK, max_blocks_per_seq=16,
-        layer_windows=[WINDOW, WINDOW, None, WINDOW], **kw
+        [ring, ring, full, ring], num_blocks=1 + 3 * 16, block_size=BLOCK,
+        max_blocks_per_seq=16, **kw
     )
 
 
@@ -359,9 +358,8 @@ def test_cache_pools_follow_the_kinds():
     cache.free(held[0], 1)
     assert cache.can_alloc(128)
     with pytest.raises(ValueError, match="one window size"):
-        BlockKVCache(num_layers=2, num_heads=1, head_dim=4, num_blocks=9,
-                     block_size=4, max_blocks_per_seq=4,
-                     layer_windows=[8, 16])
+        BlockKVCache([Keeps("window", 1, 4, 8), Keeps("window", 1, 4, 16)],
+                     num_blocks=9, block_size=4, max_blocks_per_seq=4)
 
 
 def test_engine_counts_blocks_held_by_kind_against_one_uniform_pool():
